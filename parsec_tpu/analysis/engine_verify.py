@@ -886,10 +886,11 @@ def verify_engine(legs: Sequence[str] = ("abi", "model", "conformance",
     out: List[Finding] = []
     stats: Dict[str, object] = {}
     if "abi" in legs:
-        from ..native import _LIB_PATH, _SRC_DIR, abi
+        from ..native import _SRC_DIR, abi, lib_path
 
-        fs = abi.abi_findings(_LIB_PATH if os.path.exists(_LIB_PATH)
-                              else None, _SRC_DIR)
+        lib = lib_path()
+        fs = abi.abi_findings(lib if os.path.exists(lib) else None,
+                              _SRC_DIR)
         out.extend(fs)
         stats["abi"] = {"symbols": len(abi.SPEC), "findings": len(fs)}
     if "model" in legs:

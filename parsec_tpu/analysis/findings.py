@@ -150,9 +150,10 @@ CODES = {
                       "at the ctypes boundary corrupts silently)"),
     "ENG004": (ERROR, "ABI: the spec declares an entry point that "
                       "native/src/ does not define"),
-    "ENG005": (WARNING, "ABI: the built native library is older than "
-                        "native/src/ (stale build — rebuild before "
-                        "trusting any engine behavior)"),
+    "ENG005": (WARNING, "ABI: the native library was not built from "
+                        "this native/src/ and these flags (its name "
+                        "lacks their digest — rebuild before trusting "
+                        "any engine behavior)"),
     "ENG006": (ERROR, "ABI: trace record layout drift between the "
                       "spec, trace.cpp's struct Record, and the "
                       "Python .pbt reader (on-disk corruption)"),

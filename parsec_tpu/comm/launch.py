@@ -6,6 +6,14 @@ Here the launcher spawns N Python processes, hands each a rank via the
 environment, and lets them rendezvous through a shared directory; it works
 unchanged across hosts when ``rendezvous_dir`` sits on a shared filesystem
 or an explicit ``host:port`` peer list is given.
+
+Ranks started here are **CPU-device ranks**.  A chip belongs to one
+process, and children inheriting the parent's environment would each
+claim every chip of the host — so the launcher refuses to start unless
+the children's environment pins ``JAX_PLATFORMS=cpu``.  One process
+drives several chips through the in-process form instead
+(:func:`parsec_tpu.multirank.run_multirank_perf`: one ``Context`` per
+rank over ``InprocFabric``, rank r on ``jax.local_devices()[r]``).
 """
 
 from __future__ import annotations
@@ -28,8 +36,19 @@ def launch(
 ) -> List[subprocess.CompletedProcess]:
     """Run ``python argv...`` once per rank; returns per-rank results.
 
-    Raises on nonzero exit (with the failing rank's stderr attached).
+    Raises on nonzero exit (with the failing rank's stderr attached),
+    and before starting anything when the ranks could reach an
+    accelerator (see the module docstring).
     """
+    platforms = {**os.environ, **(env or {})}.get("JAX_PLATFORMS", "")
+    if platforms.strip().lower() != "cpu":
+        raise RuntimeError(
+            f"comm.launch: refusing to start {nranks} device ranks "
+            f"(JAX_PLATFORMS={platforms!r}): every child would claim every "
+            "chip of this host.  Multi-process ranks are CPU-device only — "
+            "set JAX_PLATFORMS=cpu (env= or the environment); to drive "
+            "several chips use one process with "
+            "parsec_tpu.multirank.run_multirank_perf")
     rdv = rendezvous_dir or tempfile.mkdtemp(prefix="parsec_tpu_rdv_")
     pkg_root = os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
     procs = []

@@ -1,7 +1,7 @@
 """Persistent AOT executable cache + cross-rank compile distribution.
 
-Compile time is the worst number in the bench trajectory
-(``runtime_qr_compile_s`` hit 460 s in BENCH_r03 while the factorization
+Compile time is the worst number in the bench trajectory (a QR program
+set took 460 s to compile on the chip in round 3 while the factorization
 itself runs in seconds), and on an N-rank mesh every rank pays its own
 XLA compile for every (kernel, shape) pair — the PR 4 ``tpu_wave_batch``
 auto-disable works around exactly that explosion.  This module kills the
@@ -17,12 +17,13 @@ cold start in three layers:
 * **content-addressed disk store** — programs whose trace+lower cost at
   least ``runtime_compile_cache_min_share_s`` are serialized with
   ``jax.export`` (StableHLO; device-portable) and written atomically
-  under ``PARSEC_TPU_COMPILE_CACHE`` (default ``~/.cache/parsec_tpu``).
-  Loads are corruption-safe: a bad magic / truncated blob / checksum
-  mismatch logs one warning and falls back to a fresh compile — never a
-  crash.  The same root also hosts XLA's own persistent compilation
-  cache (``<root>/xla``), so the backend-compile half of a warm load is
-  a disk read too;
+  under ``<cache_root>/exe`` (see :func:`cache_root`: the directory
+  ``JAX_COMPILATION_CACHE_DIR`` names, else one fixed git-ignored path
+  inside the checkout).  Loads are corruption-safe: a bad magic /
+  truncated blob / checksum mismatch logs one warning and falls back to
+  a fresh compile — never a crash.  The root itself is XLA's own
+  persistent compilation cache, so the backend-compile half of a warm
+  load is a disk read too;
 
 * **compile-once-ship-serialized** — on a multi-rank mesh the rank that
   compiles a new program broadcasts the serialized executable to its
@@ -33,14 +34,13 @@ cold start in three layers:
   instead of N.  Received blobs install into the peer's preload map and
   its disk store.
 
-Serialization notes (measured on this jax/jaxlib): executing a
-DESERIALIZED exported module requires the backend custom-call targets
-(LAPACK et al.) to be registered first or jaxlib segfaults —
-:func:`_ensure_custom_call_targets` runs once before any deserialized
-execution.  Donation survives the export round-trip (re-applied via
-``donate_argnums`` at AOT compile).  Programs that fail to export
-(e.g. Pallas custom calls) simply stay process-local: counted, never
-fatal.
+Serialization notes (jax/jaxlib 0.9.0): on the CPU backend, executing
+a DESERIALIZED exported module before any LAPACK lowering rule ran in
+the process segfaults jaxlib — :func:`_ensure_custom_call_targets` runs
+once before any deserialized execution.  Donation survives the export
+round-trip (re-applied via ``donate_argnums`` at AOT compile).  Programs
+that fail to export (e.g. host callbacks) simply stay process-local:
+counted, never fatal.
 """
 
 from __future__ import annotations
@@ -62,7 +62,7 @@ from .utils import debug, mca_param
 #: bump when the entry layout / fingerprint recipe changes: old entries
 #: simply stop matching (they are garbage-collected by ``tools cache
 #: purge --stale``)
-CACHE_FORMAT = 1
+CACHE_FORMAT = 2
 _MAGIC = b"PZEXE1"
 _CTL_OP = "compile"
 
@@ -241,33 +241,23 @@ _cct_lock = threading.Lock()
 
 
 def _ensure_custom_call_targets() -> None:
-    """Executing a DESERIALIZED exported module before the backend's
-    custom-call targets are registered segfaults jaxlib (the lowering
-    rules that register LAPACK targets never ran in this process).
-    Force the registration once, cheaply, before any deserialized
-    call."""
+    """On the CPU backend, executing a DESERIALIZED exported module
+    before LAPACK's kernels are initialized segfaults jaxlib: the
+    lowering rules that initialize them never ran in this process.
+    Lower (not compile) one tiny cholesky once so the rule runs.  Other
+    backends have no LAPACK custom calls; nothing to do there."""
     global _cct_done
     if _cct_done:
         return
     with _cct_lock:
         if _cct_done:
             return
-        try:
-            import jaxlib.lapack as _lapack
+        if _platform() == "cpu":
+            import jax
+            import jax.numpy as jnp
 
-            _lapack._lapack.initialize()
-        except Exception:
-            # fallback: trace one tiny cholesky so the lowering rule
-            # registers the targets itself
-            try:
-                import jax
-                import jax.numpy as jnp
-
-                jax.jit(jnp.linalg.cholesky).lower(
-                    jax.ShapeDtypeStruct((2, 2), jnp.float32))
-            except Exception as e:  # pragma: no cover
-                debug.verbose(2, "compile_cache",
-                              "custom-call pre-registration failed: %s", e)
+            jax.jit(jnp.linalg.cholesky).lower(
+                jax.ShapeDtypeStruct((2, 2), jnp.float32))
         _cct_done = True
 
 
@@ -275,18 +265,31 @@ def _ensure_custom_call_targets() -> None:
 # on-disk store
 # ---------------------------------------------------------------------------
 
+#: where every cache lives when ``JAX_COMPILATION_CACHE_DIR`` is unset:
+#: one fixed, git-ignored directory inside the checkout.  The path is
+#: part of XLA's cache key, so it is never a temporary name, pid or time.
+_CHECKOUT_CACHE = os.path.join(
+    os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
+    ".parsec_tpu_cache")
+
+
 def cache_root() -> Optional[str]:
-    """Resolved cache directory, or None when disabled.
-    ``PARSEC_TPU_COMPILE_CACHE``: unset -> ``~/.cache/parsec_tpu``;
-    ``0``/empty -> disabled; anything else -> that directory."""
-    v = os.environ.get("PARSEC_TPU_COMPILE_CACHE")
-    if v is None:
-        return os.path.join(os.path.expanduser("~"), ".cache",
-                            "parsec_tpu")
-    v = v.strip()
-    if v in ("", "0"):
+    """The ONE directory every cache of this runtime lives under — XLA's
+    persistent compilation cache in the root itself, the executable
+    store in ``<root>/exe``, the tuning store in ``<root>/autotune`` —
+    or None when the disk layer is disabled
+    (``PARSEC_TPU_COMPILE_CACHE=0``).  The root is placed from outside:
+    ``JAX_COMPILATION_CACHE_DIR`` when set, else :data:`_CHECKOUT_CACHE`."""
+    switch = os.environ.get("PARSEC_TPU_COMPILE_CACHE", "1").strip()
+    if switch in ("", "0"):
         return None
-    return os.path.expanduser(v)
+    if switch != "1":
+        raise ValueError(
+            f"PARSEC_TPU_COMPILE_CACHE={switch!r}: only 0 (disable the "
+            "disk layer) or 1 is accepted; the cache directory is placed "
+            "with JAX_COMPILATION_CACHE_DIR")
+    placed = os.environ.get("JAX_COMPILATION_CACHE_DIR", "").strip()
+    return os.path.expanduser(placed) if placed else _CHECKOUT_CACHE
 
 
 class DiskStore:
@@ -315,12 +318,8 @@ class DiskStore:
     def path(self, fp: str) -> str:
         return os.path.join(self.dir, f"{fp}.exe")
 
-    def store(self, fp: str, blob: bytes, meta: Dict[str, Any],
-              native: Optional[bytes] = None) -> bool:
-        """Write one entry: the portable (``jax.export``) blob, plus an
-        optional platform-native serialized executable (machine code —
-        loads in milliseconds where recompiling the portable form costs
-        the whole backend codegen)."""
+    def store(self, fp: str, blob: bytes, meta: Dict[str, Any]) -> bool:
+        """Write one entry: the portable (``jax.export``) blob."""
         if not self._ensure_dir():
             return False
         path = self.path(fp)
@@ -330,10 +329,6 @@ class DiskStore:
         header["format"] = CACHE_FORMAT
         header["sha256"] = hashlib.sha256(blob).hexdigest()
         header["blob_len"] = len(blob)
-        native = native or b""
-        header["native_len"] = len(native)
-        if native:
-            header["native_sha256"] = hashlib.sha256(native).hexdigest()
         header["created"] = time.time()
         tmp = f"{path}.tmp.{os.getpid()}.{threading.get_ident()}"
         try:
@@ -342,7 +337,6 @@ class DiskStore:
                 f.write(json.dumps(header, sort_keys=True).encode())
                 f.write(b"\n")
                 f.write(blob)
-                f.write(native)
             os.replace(tmp, path)
             return True
         except OSError as e:
@@ -353,40 +347,7 @@ class DiskStore:
                 pass
             return False
 
-    def add_native(self, fp: str, native: bytes,
-                   meta_updates: Dict[str, Any]) -> bool:
-        """Attach a native executable to an existing entry (a process
-        that loaded the portable form and paid the backend compile saves
-        the result for the next process on this host).  Atomic rewrite;
-        a concurrent identical writer is harmless."""
-        loaded = self.load(fp)
-        if loaded is None:
-            return False
-        header, blob, _old_native = loaded
-        header.update(meta_updates)
-        path = self.path(fp)
-        header["native_len"] = len(native)
-        header["native_sha256"] = hashlib.sha256(native).hexdigest()
-        tmp = f"{path}.tmp.{os.getpid()}.{threading.get_ident()}"
-        try:
-            with open(tmp, "wb") as f:
-                f.write(_MAGIC)
-                f.write(json.dumps(header, sort_keys=True).encode())
-                f.write(b"\n")
-                f.write(blob)
-                f.write(native)
-            os.replace(tmp, path)
-            return True
-        except OSError as e:
-            debug.verbose(2, "compile_cache",
-                          "native attach of %s failed: %s", fp, e)
-            try:
-                os.unlink(tmp)
-            except OSError:
-                pass
-            return False
-
-    def _read(self, path: str) -> Tuple[Dict[str, Any], bytes, bytes]:
+    def _read(self, path: str) -> Tuple[Dict[str, Any], bytes]:
         """Parse + validate one entry file; raises ValueError on any
         corruption."""
         with open(path, "rb") as f:
@@ -400,22 +361,15 @@ class DiskStore:
             if header.get("format") != CACHE_FORMAT:
                 raise ValueError(f"format {header.get('format')} != "
                                  f"{CACHE_FORMAT}")
-            blob = f.read(int(header.get("blob_len", 0)))
-            native = f.read()
+            blob = f.read()
         if len(blob) != header.get("blob_len"):
             raise ValueError(f"blob length {len(blob)} != "
                              f"{header.get('blob_len')} (truncated?)")
         if hashlib.sha256(blob).hexdigest() != header.get("sha256"):
             raise ValueError("blob checksum mismatch")
-        if len(native) != int(header.get("native_len", 0)):
-            raise ValueError("native section truncated")
-        if native and hashlib.sha256(native).hexdigest() \
-                != header.get("native_sha256"):
-            raise ValueError("native checksum mismatch")
-        return header, blob, native
+        return header, blob
 
-    def load(self, fp: str) -> Optional[Tuple[Dict[str, Any], bytes,
-                                              bytes]]:
+    def load(self, fp: str) -> Optional[Tuple[Dict[str, Any], bytes]]:
         """Validated load; a corrupt entry is logged, removed
         (best-effort) and reported as a miss — a bad cache file must
         cost one recompile, never a crash."""
@@ -501,10 +455,11 @@ _stores: Dict[str, DiskStore] = {}
 
 def default_store() -> Optional[DiskStore]:
     """Process-wide store singleton for the resolved cache root (None
-    when the disk layer is disabled).  Also points XLA's own persistent
-    compilation cache at ``<root>/xla`` — unless the user already
-    configured one — so the backend-compile half of a warm load comes
-    off disk too."""
+    when the disk layer is disabled).  XLA's own persistent compilation
+    cache is the root itself, so the backend-compile half of a warm load
+    comes off disk too: where ``JAX_COMPILATION_CACHE_DIR`` placed it jax
+    already knows; otherwise it is pointed at the fixed checkout path
+    here, once."""
     root = cache_root()
     if root is None:
         return None
@@ -512,24 +467,18 @@ def default_store() -> Optional[DiskStore]:
         store = _stores.get(root)
         if store is None:
             store = _stores[root] = DiskStore(os.path.join(root, "exe"))
-            try:
-                import jax
+            import jax
+            from jax.experimental.compilation_cache import \
+                compilation_cache as xla_cache
 
-                if jax.config.jax_compilation_cache_dir is None:
-                    jax.config.update("jax_compilation_cache_dir",
-                                      os.path.join(root, "xla"))
-                    # jax's default floor (1.0 s of backend compile)
-                    # skips exactly the mid-size programs our min_share_s
-                    # threshold selects for sharing — align the floors.
-                    # Only touched when the user has not configured it.
-                    if jax.config.jax_persistent_cache_min_compile_time_secs \
-                            == 1.0:
-                        jax.config.update(
-                            "jax_persistent_cache_min_compile_time_secs",
-                            0.1)
-            except Exception as e:
-                debug.verbose(2, "compile_cache",
-                              "xla cache wiring skipped: %s", e)
+            if jax.config.jax_compilation_cache_dir is None:
+                xla_cache.set_cache_dir(root)
+            # jax's default floor (1.0 s of backend compile) skips
+            # exactly the mid-size programs min_share_s selects for
+            # sharing — align the floors unless the user configured one
+            if jax.config.jax_persistent_cache_min_compile_time_secs == 1.0:
+                jax.config.update(
+                    "jax_persistent_cache_min_compile_time_secs", 0.1)
         return store
 
 
@@ -721,14 +670,12 @@ class ExecutableCache:
                 meta: Optional[Dict[str, Any]] = None) -> None:
         """Install a serialized executable received from a peer: it
         satisfies the next local request for ``fp`` without a trace.
-        When a disk store is available the blob lands there (full entry
-        semantics: callconv meta, native attach on first compile); the
+        When a disk store is available the blob lands there; the
         in-memory preload map is the storeless fallback."""
         if persist and self.store is not None:
             m = dict(meta or ())
             m.setdefault("versions", _versions())
             m["origin"] = "bcast"
-            m.pop("native_meta", None)  # the sender's, not ours
             if self.store.store(fp, blob, m):
                 self.stats["bytes_written"] += len(blob)
             # the entry exists (just written, or content-addressed and
@@ -791,45 +738,19 @@ class ExecutableCache:
     def _resolve_slow(self, cf: _CachedFunction, fp: str, args: Tuple):
         # 1) a blob a peer shipped / disk already holds
         blob = None
-        header: Dict[str, Any] = {}
-        native = b""
         with self._lock:
             blob = self._preloaded.pop(fp, None)
         src = "bcast"
         if blob is None and self.store is not None:
             loaded = self.store.load(fp)
             if loaded is not None:
-                header, blob, native = loaded
+                _header, blob = loaded
                 src = "disk"
-                self.stats["bytes_read"] += len(blob) + len(native)
+                self.stats["bytes_read"] += len(blob)
         if blob is not None:
-            # fast path: a platform-native executable for this exact
-            # jax/jaxlib/backend/device — machine code, loads in
-            # milliseconds (the portable form re-runs backend codegen).
-            # NEVER for donating programs: the executable bakes in
-            # input/output buffer aliasing, and raw PJRT execution
-            # skips the jax dispatch layer that makes donation safe
-            # (unique-ownership copies, deleted-array marking) — the
-            # donated input races the runtime's concurrent buffer
-            # bookkeeping and intermittently corrupts live tiles
-            # (seen as a deterministic-value wrong factorization at
-            # ~1/6 rate in the LU suite).  Donating programs take the
-            # portable form, where jax.jit re-applies donation safely.
-            if native and not cf.donate:
-                exe = self._load_native(header, native, args)
-                if exe is not None:
-                    self.stats["hits_" + src] += 1
-                    self.stats["native_loads"] += 1
-                    return exe, "hit_" + src
             exe = self._compile_blob(blob, cf, args)
             if exe is not None:
                 self.stats["hits_" + src] += 1
-                if src == "disk" and not native and not cf.donate:
-                    # we just paid the backend compile for a portable
-                    # entry: attach the native form so the NEXT process
-                    # on this host loads machine code instead (skipped
-                    # for donating programs — never loaded, see above)
-                    self._attach_native(fp, exe, header)
                 return exe, "hit_" + src
             self.stats["blob_errors"] += 1
         # 2) full trace + compile — ONE trace for both the sharing
@@ -860,9 +781,7 @@ class ExecutableCache:
             try:
                 import jax.export as jex
 
-                exp = jex.export(jitted)(*args)
-                blob = bytes(exp.serialize())
-                callconv = _callconv_of(exp)
+                blob = bytes(jex.export(jitted)(*args).serialize())
             except Exception as e:
                 # the graceful process-local path: the program still gets
                 # the per-process LRU (and, where jit's own lowering can
@@ -896,7 +815,7 @@ class ExecutableCache:
             if blob is not None \
                     and (fused
                          or time.perf_counter() - t0 >= self.min_disk_s):
-                exe = self._share_blob(cf, fp, args, blob, callconv, t0)
+                exe = self._share_blob(cf, fp, args, blob, t0)
                 if exe is not None:
                     return exe, "miss"
         return jitted.lower(*args).compile(), "miss"
@@ -921,71 +840,8 @@ class ExecutableCache:
                           type(e).__name__, e)
             return None
 
-    # -- platform-native executables -------------------------------------
-    @staticmethod
-    def _target_device(args):
-        import jax
-
-        for a in args:
-            d = getattr(a, "device", None)
-            if d is not None and hasattr(d, "client"):
-                return d
-        return jax.devices()[0]
-
-    @classmethod
-    def _native_meta(cls, device) -> Dict[str, Any]:
-        return {"versions": _versions(), "platform": _platform(),
-                "device_kind": str(getattr(device, "device_kind", "?")),
-                "device_id": int(getattr(device, "id", 0))}
-
-    def _native_blob(self, exe, device) -> Optional[bytes]:
-        """Serialize the compiled executable's machine code (PJRT
-        ``serialize_executable``); None when the runtime has no support
-        for it."""
-        try:
-            client = device.client
-            rt = exe.runtime_executable()
-            return bytes(client.serialize_executable(rt))
-        except Exception as e:
-            debug.verbose(2, "compile_cache",
-                          "native serialization unavailable: %s", e)
-            return None
-
-    def _attach_native(self, fp: str, exe, header: Dict[str, Any]) -> None:
-        if self.store is None or not header.get("callconv"):
-            return
-        device = self._target_device(())
-        native = self._native_blob(exe, device)
-        if native:
-            self.store.add_native(fp, native,
-                                  {"native_meta": self._native_meta(device)})
-
-    def _load_native(self, header: Dict[str, Any], native: bytes,
-                     args: Tuple):
-        """Deserialize a platform-native executable — ONLY when the
-        recorded jax/jaxlib/backend/device fingerprint matches exactly
-        (a mismatched native blob is undefined behavior, not an error
-        code).  Any failure returns None and the portable form takes
-        over."""
-        callconv = header.get("callconv")
-        nmeta = header.get("native_meta")
-        if not callconv or not nmeta:
-            return None
-        device = self._target_device(args)
-        if nmeta != self._native_meta(device):
-            return None
-        try:
-            _ensure_custom_call_targets()
-            le = device.client.deserialize_executable(bytes(native), None)
-            return _NativeExec(le, device, callconv)
-        except Exception as e:
-            debug.verbose(1, "compile_cache",
-                          "native executable load failed (%s: %s); using "
-                          "the portable form", type(e).__name__, e)
-            return None
-
     def _share_blob(self, cf: _CachedFunction, fp: str, args: Tuple,
-                    blob: bytes, callconv, t0: float):
+                    blob: bytes, t0: float):
         """Compile an already-serialized program through its own
         serialized form (one shared XLA-cache key for cold, warm and
         peer ranks), store + announce.  Returns the executable, or None
@@ -997,16 +853,10 @@ class ExecutableCache:
         meta = {"key": _short(cf.key), "versions": _versions(),
                 "backend": _platform(),
                 "compile_s": round(time.perf_counter() - t0, 3),
-                "rank": self.rank, "callconv": callconv}
+                "rank": self.rank}
         if self.store is not None:
-            native = None
-            if callconv is not None and not cf.donate:
-                device = self._target_device(args)
-                native = self._native_blob(exe, device)
-                if native:
-                    meta["native_meta"] = self._native_meta(device)
-            if self.store.store(fp, blob, meta, native=native):
-                self.stats["bytes_written"] += len(blob) + len(native or b"")
+            if self.store.store(fp, blob, meta):
+                self.stats["bytes_written"] += len(blob)
             self._warm = True
         if self.bcast_enabled:
             self._announce(fp, blob, meta)
@@ -1225,122 +1075,6 @@ class _BlobPull:
 def _short(key: Any) -> str:
     s = _scrub(repr(key))
     return s if len(s) <= 120 else s[:117] + "..."
-
-
-def _flatten_args(args) -> List[Any]:
-    """The flat buffer list a compiled module consumes: positional
-    args minus the ``None`` (guarded-off optional flow) holes, nested
-    tuples flattened in order — jax's own pytree flattening for the
-    argument shapes this runtime produces."""
-    out: List[Any] = []
-    for a in args:
-        if a is None:
-            continue
-        if isinstance(a, (tuple, list)):
-            out.extend(_flatten_args(a))
-        else:
-            out.append(a)
-    return out
-
-
-def _callconv_of(exp) -> Optional[Dict[str, Any]]:
-    """JSON-able calling convention of an exported module: per-input
-    aval dtypes (scalar canonicalization for raw execution) and the
-    output structure.  None when the output tree is not the flat
-    single/tuple shape this runtime's bodies produce — such programs
-    keep the portable path only."""
-    try:
-        import jax.tree_util as jtu
-
-        n_out = len(exp.out_avals)
-        out_tree = exp.out_tree
-        if out_tree == jtu.tree_structure(tuple(range(n_out))):
-            kind = "tuple"
-        elif n_out == 1 and out_tree == jtu.tree_structure(0):
-            kind = "single"
-        else:
-            return None
-        return {"in": [[list(a.shape), str(a.dtype)]
-                       for a in exp.in_avals],
-                "out": kind, "n_out": n_out}
-    except Exception:
-        return None
-
-
-class _NativeExec:
-    """Raw PJRT execution of a deserialized native executable: the
-    callable the cache hands out when a machine-code load succeeded.
-    Argument handling mirrors what ``jax.jit`` dispatch would have done
-    for these exact avals — arrays pass through (re-placed onto the
-    executable's device if needed), scalars canonicalize to the recorded
-    aval dtype.  Any mismatch raises loudly; the wrapper above falls
-    back to a plain ``jax.jit``."""
-
-    __slots__ = ("le", "device", "in_dtypes", "out_kind", "n_out",
-                 "_scalar_memo")
-
-    #: scalar-buffer memo cap — task locals span a parameter space, so
-    #: distinct (value, dtype) pairs are few; the cap only guards a
-    #: pathological caller streaming unbounded distinct scalars
-    _SCALAR_MEMO_MAX = 4096
-
-    def __init__(self, le, device, callconv: Dict[str, Any]):
-        self.le = le
-        self.device = device
-        self.in_dtypes = [spec[1] for spec in callconv["in"]]
-        self.out_kind = callconv["out"]
-        self.n_out = int(callconv["n_out"])
-        # (value, dtype) -> device buffer for Python/numpy scalar args.
-        # Task locals (tile indices) repeat across thousands of
-        # dispatches; converting + uploading them per call dominated the
-        # dispatch-bound profile (ISSUE 18).  Executables on this path
-        # never donate (the cache only hands out _NativeExec when
-        # ``not cf.donate``), so a cached input buffer is read-only and
-        # reuse is safe.
-        self._scalar_memo: Dict[Tuple[Any, str], Any] = {}
-
-    def _scalar_buf(self, a, dt):
-        import jax
-        import jax.numpy as jnp
-
-        key = (a, dt)
-        buf = self._scalar_memo.get(key)
-        if buf is None:
-            buf = jax.device_put(jnp.asarray(a, dtype=dt), self.device)
-            if len(self._scalar_memo) < self._SCALAR_MEMO_MAX:
-                self._scalar_memo[key] = buf
-        return buf
-
-    def __call__(self, *args):
-        import jax
-        import jax.numpy as jnp
-
-        leaves = _flatten_args(args)
-        if len(leaves) != len(self.in_dtypes):
-            raise ValueError(
-                f"native executable expects {len(self.in_dtypes)} "
-                f"buffers, got {len(leaves)}")
-        bufs = []
-        for a, dt in zip(leaves, self.in_dtypes):
-            if not isinstance(a, jax.Array):
-                if isinstance(a, (int, float, bool, np.number)):
-                    a = self._scalar_buf(a, dt)
-                else:
-                    a = jax.device_put(jnp.asarray(a, dtype=dt),
-                                       self.device)
-            else:
-                try:
-                    if a.device != self.device:
-                        a = jax.device_put(a, self.device)
-                except Exception:
-                    pass  # sharded array: let execute validate it
-            bufs.append(a)
-        outs = self.le.execute(bufs)
-        if len(outs) != self.n_out:
-            raise ValueError(
-                f"native executable returned {len(outs)} outputs, "
-                f"expected {self.n_out}")
-        return tuple(outs) if self.out_kind == "tuple" else outs[0]
 
 
 # ---------------------------------------------------------------------------

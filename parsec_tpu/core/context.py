@@ -221,17 +221,18 @@ class Context:
         the engine is used.  A missing library is not a finding — the
         pure-Python fallback never crosses the boundary."""
         from ..analysis.findings import LintError, errors_of
-        from ..native import _LIB_PATH, _SRC_DIR
+        from ..native import _SRC_DIR, lib_path
         from ..native import abi as _abi
 
-        if not os.path.exists(_LIB_PATH):
+        lib = lib_path()
+        if not os.path.exists(lib):
             return
-        findings = _abi.abi_findings(_LIB_PATH, _SRC_DIR)
+        findings = _abi.abi_findings(lib, _SRC_DIR)
         for f in findings:
             debug.warning("abi-check: %s", f)
         if strict and errors_of(findings):
             raise LintError(
-                f"PARSEC_TPU_ABI_CHECK=strict: {_LIB_PATH} drifted from "
+                f"PARSEC_TPU_ABI_CHECK=strict: {lib} drifted from "
                 f"the ABI spec ({len(findings)} finding(s))", findings)
 
     def add_taskpool(self, tp: Taskpool) -> None:

@@ -17,7 +17,8 @@ def native_ready_queue(policy: str, quantum: int = 0):
     :class:`parsec_tpu.native.NativeReadyQueue` whose pop order is
     bit-identical to the Python discipline (``pz_rq_*`` entry points run
     the same SchedQ the pump scheduler uses), or None when the mirror is
-    off or the native core is unavailable.  Ownership handoff: the
+    off.  Asking for it without a native core is an error, not a silent
+    Python queue.  Ownership handoff: the
     scheduler keeps the Task OBJECTS in a handle-keyed dict and only the
     ordering state crosses into C++ — a popped handle transfers the task
     back exactly once."""
@@ -32,7 +33,9 @@ def native_ready_queue(policy: str, quantum: int = 0):
     from ... import native
 
     if not native.available():
-        return None
+        raise RuntimeError(
+            "sched_native_queue=1 but the native core is unavailable: "
+            f"{native.build_error()}")
     return native.NativeReadyQueue(policy=policy, quantum=quantum)
 
 

@@ -130,16 +130,19 @@ def attach_devices(context: "Context", names: Optional[List[str]] = None) -> Lis
         if sel is not None and not explicit and cls.mca_name != "cpu":
             continue
         # explicit naming trumps the availability probe (a module that is
-        # inert by default, like template, still attaches when asked for;
-        # a truly missing backend fails in attach() and is skipped below)
+        # inert by default, like template, still attaches when asked for)
         if not cls.available() and not explicit:
             continue
+        # a module that was asked for — by name, or by reporting itself
+        # available — and cannot attach is an ERROR: continuing without
+        # it would silently run every device chore somewhere else
         try:
             dev = cls(context, len(devices))
             dev.attach()
-            devices.append(dev)
         except Exception as e:
-            debug.warning("device %s failed to attach: %s", cls.mca_name, e)
+            raise RuntimeError(
+                f"device module {cls.mca_name!r} failed to attach: {e}") from e
+        devices.append(dev)
     if not devices or devices[0].device_type != DEV_CPU:
         raise RuntimeError("CPU device must attach first")
     context._device_skew = mca_param.register(

@@ -345,7 +345,8 @@ class WritebackCommitter:
             t0 = time.perf_counter()
         hosts = dev._d2h_batch([p for (_d, p, _v) in snaps])
         for (data, _payload, version), host in zip(snaps, hosts):
-            if dev._commit_host(data, version, host):
+            # host is None: a donating task consumed that version
+            if host is not None and dev._commit_host(data, version, host):
                 self.stats["committed"] += 1
             else:
                 self.stats["dropped_stale"] += 1

@@ -83,8 +83,9 @@ class TemplateDevice(Device):
 
     # -- lifecycle -------------------------------------------------------
     def attach(self) -> None:
-        """Probe your hardware here; raise to be skipped (the registry
-        logs and continues, ``attach_devices``)."""
+        """Bind your hardware here.  Report a missing backend from
+        ``available()``: a selected module that raises in ``attach``
+        fails the context (``attach_devices``)."""
 
     def detach(self) -> None:
         """Flush dirty copies home, release handles."""

@@ -4,7 +4,7 @@ whole-DAG capture.
 
 Every dispatch-bound number in the trajectory points at task
 granularity: per-task dynamic dispatch pays ~0.5 ms/task of host-side
-bookkeeping (BASELINE round 5) and the task-graph flash attention ran at
+bookkeeping (round-5 wave A/B on the chip) and the task-graph flash attention ran at
 0.40x of the one-program SPMD loop (round 11), while whole-DAG
 ``GraphExecutor`` capture forfeits multi-pool composition, serving, and
 comm overlap.  This module adds the middle regime, in the spirit of

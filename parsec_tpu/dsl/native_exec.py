@@ -1187,9 +1187,13 @@ class NativeExecutor:
             self._ng = None
             return
         ng = getattr(self, "_ng", None)
-        if ng is not None:
-            ng.close()
-            self._ng = None
+        if ng is None:
+            # already closed (or never built): idempotent — ``__del__``
+            # calls this again, possibly after the collector finalized
+            # parts of a shared device the first call already flushed
+            return
+        ng.close()
+        self._ng = None
         dev = getattr(self, "device", None)
         if dev is not None:
             # flush dirty device tiles home so host-side readers (e.g.

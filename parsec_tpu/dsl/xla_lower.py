@@ -76,7 +76,8 @@ class GraphExecutor:
         816 tasks, with the gap widening superlinearly). The gather/
         scatter around each group costs extra HBM traffic — measured
         ~2.6x slower at N=8192 — so this is the compile-scalability mode
-        for very large NT, not the default perf path (BASELINE.md).
+        for very large NT, not the default perf path (round-1 chip
+        run).
         Ragged members fall back to per-task emission automatically."""
         import jax
 
@@ -284,7 +285,8 @@ class GraphExecutor:
             # taskpool rebuilt in this process is a dictionary hit and a
             # rebuild in a NEW process reloads the serialized executable
             # from the persistent store instead of paying the full XLA
-            # cold compile (the BENCH_r03 460 s `runtime_qr_compile_s`)
+            # cold compile (a QR program set took 460 s to compile on
+            # the chip in round 3)
             from ..compile_cache import default_cache
 
             self.cache = cache if cache is not None else default_cache()

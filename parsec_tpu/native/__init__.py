@@ -14,8 +14,11 @@ TPU framework's native core — a C++ shared library built on demand from
   topological ``order()`` used for whole-DAG XLA lowering (reference
   role: ``parsec/scheduling.c`` + ``mca/sched``).
 
-``available()`` reports whether the toolchain produced the library;
-every consumer has a pure-Python fallback path.
+``available()`` reports whether the toolchain produced the library.
+Consumers that merely prefer it (graph ordering, the binary tracer) have
+a pure-Python path; whoever ASKED for the native engine —
+``NativeExecutor``, pump mode, the ``sched_native_queue`` mirror, the HBM
+zone of a device bound to a real chip — raises when it is missing.
 """
 
 from __future__ import annotations
@@ -25,7 +28,7 @@ import itertools
 import os
 import subprocess
 import threading
-from typing import Any, Callable, List, Optional, Sequence
+from typing import Any, Callable, List, Optional
 
 from ..profiling import pins
 from . import abi
@@ -43,63 +46,85 @@ _BUILD_DIR = os.path.join(_REPO, "native", "build")
 #: §10 "Checking your runtime").
 _TSAN = bool(os.environ.get("PARSEC_TPU_NATIVE_TSAN"))
 _TSAN_SUPP = os.path.join(_REPO, "native", "tsan.supp")
-_LIB_PATH = os.path.join(
-    _BUILD_DIR, "libparsec_core_tsan.so" if _TSAN else "libparsec_core.so")
 
 _lib = None
 _lib_lock = threading.Lock()
 _build_error: Optional[str] = None
 
-_SOURCES = ["zone.cpp", "graph.cpp", "trace.cpp"]
+_CXX = "g++"
+_FLAGS = ["-O2", "-g", "-std=c++17", "-fPIC", "-shared", "-pthread"]
+_TSAN_FLAGS = ["-fsanitize=thread"]
 
 #: every C entry point the bindings require — a DERIVED view of the
 #: declarative ABI contract (:mod:`parsec_tpu.native.abi`; one spec
 #: generates the bindings, this list, and the engine-verify ABI lint).
-#: Checked explicitly at load so a stale
-#: ``native/build/libparsec_core.so`` (e.g. sources updated but the
-#: rebuild failed or was skipped) produces ONE readable error via
-#: :func:`build_error` instead of a ctypes ``AttributeError`` deep
-#: inside a consumer.  ``missing_symbols()`` is the CI smoke hook.
+#: Checked explicitly at load so a library that drifted from the spec
+#: produces ONE readable error via :func:`build_error` instead of a
+#: ctypes ``AttributeError`` deep inside a consumer.
+#: ``missing_symbols()`` is the CI smoke hook.
 REQUIRED_SYMBOLS = abi.required_symbols()
 
 
-def _newest_mtime(paths: Sequence[str]) -> float:
-    return max(os.path.getmtime(p) for p in paths)
+def build_command(tsan: bool = _TSAN) -> List[str]:
+    """Compiler + flags of one flavor (everything but ``-o`` and the
+    sources)."""
+    return [_CXX, *_FLAGS, *(_TSAN_FLAGS if tsan else [])]
 
 
-def _compile(out_path: str, extra_flags: Sequence[str] = (),
-             timeout: int = 300) -> str:
-    """One compile pipeline for every flavor (default + TSan): source
-    check, mtime staleness test, g++ invocation, per-process temp file,
-    atomic publish.  Returns ``out_path``; raises RuntimeError with the
-    compiler output on failure."""
-    srcs = [os.path.join(_SRC_DIR, s) for s in _SOURCES]
+def lib_path(tsan: bool = _TSAN) -> str:
+    """Path of the shared library for THIS source tree and flavor.  The
+    file name carries :func:`abi.source_digest`, so the identity of what
+    loads is the content it was built from: a library left behind by
+    other sources or flags (``native/build/`` is git-ignored but travels
+    with a copied directory) has another name and is never opened."""
+    stem = "libparsec_core_tsan" if tsan else "libparsec_core"
+    digest = abi.source_digest(build_command(tsan))
+    return os.path.join(_BUILD_DIR, f"{stem}-{digest}.so")
+
+
+def compiler_version() -> str:
+    """First line of ``g++ --version`` (the toolchain the library is
+    built with), or a description of why it cannot be run."""
+    try:
+        proc = subprocess.run([_CXX, "--version"], capture_output=True,
+                              text=True, timeout=30)
+    except (OSError, subprocess.TimeoutExpired) as e:
+        return f"{_CXX} unavailable: {e}"
+    return (proc.stdout.splitlines() or [f"{_CXX}: no output"])[0]
+
+
+def build_library(*, tsan: bool = _TSAN, force: bool = False,
+                  timeout: int = 300) -> str:
+    """Compile ``native/src`` into :func:`lib_path` unless that exact
+    content-addressed file already exists (``force`` recompiles
+    regardless).  Per-process temp file + atomic publish: concurrent
+    builds (multi-process TCP ranks on one host) cannot interleave.
+    Returns the path; raises RuntimeError with the compiler output on
+    failure.  Does not load the library."""
+    srcs = [os.path.join(_SRC_DIR, s) for s in abi.SOURCES]
     missing = [s for s in srcs if not os.path.exists(s)]
     if missing:
         raise RuntimeError(f"sources missing under {_SRC_DIR}: {missing}")
-    if os.path.exists(out_path) \
-            and os.path.getmtime(out_path) >= _newest_mtime(srcs):
+    out_path = lib_path(tsan)
+    if os.path.exists(out_path) and not force:
         return out_path
     os.makedirs(_BUILD_DIR, exist_ok=True)
-    # per-process temp: concurrent builds (multi-process TCP ranks on one
-    # host) must not interleave writes before the atomic publish
     tmp = f"{out_path}.{os.getpid()}.tmp"
-    cmd = ["g++", "-O2", "-g", "-std=c++17", "-fPIC", "-shared", "-pthread",
-           *extra_flags, "-o", tmp, *srcs]
+    cmd = [*build_command(tsan), "-o", tmp, *srcs]
     try:
         proc = subprocess.run(cmd, capture_output=True, text=True,
                               timeout=timeout)
     except (OSError, subprocess.TimeoutExpired) as e:
-        raise RuntimeError(f"g++ invocation failed: {e}")
+        raise RuntimeError(f"{_CXX} invocation failed: {e}")
     if proc.returncode != 0:
-        raise RuntimeError(f"g++ failed:\n{proc.stderr[-2000:]}")
+        raise RuntimeError(f"{_CXX} failed:\n{proc.stderr[-2000:]}")
     os.replace(tmp, out_path)
     return out_path
 
 
 def _build() -> Optional[str]:
-    """Compile the shared library if missing/stale; returns its path or
-    None (recording the failure for diagnostics)."""
+    """Compile the shared library if missing; returns its path or None
+    (recording the failure for diagnostics)."""
     global _build_error
     if os.environ.get("PARSEC_TPU_NATIVE_DISABLE"):
         # CI fallback-path leg / debugging: pretend no toolchain exists so
@@ -107,8 +132,7 @@ def _build() -> Optional[str]:
         _build_error = "disabled via PARSEC_TPU_NATIVE_DISABLE"
         return None
     try:
-        return _compile(
-            _LIB_PATH, extra_flags=["-fsanitize=thread"] if _TSAN else ())
+        return build_library()
     except RuntimeError as e:
         _build_error = str(e)
         return None
@@ -127,9 +151,9 @@ def _load():
         if missing:
             global _build_error
             _build_error = (
-                f"stale native library at {path}: missing symbol(s) "
-                f"{', '.join(missing)} — delete native/build/ (or touch "
-                "native/src/*.cpp) to force a rebuild")
+                f"native library {path} lacks symbol(s) "
+                f"{', '.join(missing)} that parsec_tpu.native.abi "
+                "declares: the spec and native/src have drifted")
             return None
         # restype/argtypes for every entry point are GENERATED from the
         # declarative ABI contract — the spec that also feeds
@@ -143,7 +167,7 @@ def _load():
 def missing_symbols() -> List[str]:
     """Symbols from :data:`REQUIRED_SYMBOLS` absent from the built
     library (empty when healthy).  The build smoke test asserts this is
-    empty so a stale ``native/build`` fails CI with a readable message."""
+    empty so spec/source drift fails CI with a readable message."""
     lib = _load()
     if lib is None:
         return list(REQUIRED_SYMBOLS)
@@ -161,14 +185,13 @@ def tsan_suppressions_path() -> str:
 
 
 def build_tsan_library(timeout: int = 300) -> str:
-    """Compile the ThreadSanitizer flavor unconditionally (the CI smoke
-    leg: "the TSan build of the async engine still compiles").  Returns
-    the .so path; raises RuntimeError with the compiler output when the
-    toolchain lacks ``-fsanitize=thread`` or the sources fail under its
+    """Compile the ThreadSanitizer flavor (the CI smoke leg: "the TSan
+    build of the async engine still compiles").  Returns the .so path;
+    raises RuntimeError with the compiler output when the toolchain
+    lacks ``-fsanitize=thread`` or the sources fail under its
     instrumentation.  Does NOT load the library into this process — a
     TSan .so needs the sanitizer runtime preloaded."""
-    return _compile(os.path.join(_BUILD_DIR, "libparsec_core_tsan.so"),
-                    extra_flags=["-fsanitize=thread"], timeout=timeout)
+    return build_library(tsan=True, timeout=timeout)
 
 
 def build_error() -> Optional[str]:
@@ -188,30 +211,41 @@ class ZoneAllocator:
         if not self._z:
             raise MemoryError("zone allocation failed")
 
+    def _handle(self):
+        """The live native zone.  A closed allocator RAISES: passing the
+        cleared handle on would dereference NULL inside the library —
+        reachable in practice when the cycle collector finalizes this
+        object before a device whose ``detach()`` still consults it (a
+        SIGSEGV in ``pthread_mutex_lock`` on the chip)."""
+        z = self._z
+        if not z:
+            raise RuntimeError("zone allocator is closed")
+        return z
+
     def alloc(self, nbytes: int, align: int = 256) -> Optional[int]:
         """Returns a byte offset, or None when fragmented/full."""
-        off = self._lib.pz_zone_alloc(self._z, nbytes, align)
+        off = self._lib.pz_zone_alloc(self._handle(), nbytes, align)
         return None if off < 0 else off
 
     def release(self, offset: int) -> None:
-        if self._lib.pz_zone_release(self._z, offset) != 0:
+        if self._lib.pz_zone_release(self._handle(), offset) != 0:
             raise ValueError(f"unknown offset {offset}")
 
     @property
     def used(self) -> int:
-        return self._lib.pz_zone_used(self._z)
+        return self._lib.pz_zone_used(self._handle())
 
     @property
     def capacity(self) -> int:
-        return self._lib.pz_zone_capacity(self._z)
+        return self._lib.pz_zone_capacity(self._handle())
 
     @property
     def largest_free(self) -> int:
-        return self._lib.pz_zone_largest_free(self._z)
+        return self._lib.pz_zone_largest_free(self._handle())
 
     @property
     def num_live(self) -> int:
-        return self._lib.pz_zone_num_live(self._z)
+        return self._lib.pz_zone_num_live(self._handle())
 
     def close(self) -> None:
         if getattr(self, "_z", None):

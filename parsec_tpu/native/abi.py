@@ -7,8 +7,8 @@ ownership/threading contract.  Everything else derives from it:
 * :func:`bind` *generates* the ctypes ``restype``/``argtypes`` bindings
   (``native.__init__._load`` calls it; there is no hand-maintained
   binding block to drift),
-* :func:`required_symbols` is the derived view the stale-.so load check
-  and the CI smokes key on (the old hand-written ``REQUIRED_SYMBOLS``),
+* :func:`required_symbols` is the derived view the load-time symbol
+  check and the CI smokes key on,
 * :func:`abi_findings` is the engine-verify ABI lint
   (``tools engine-verify --abi``): it cross-checks the spec against the
   ``extern "C"`` prototypes actually in ``native/src/*.cpp`` (signature
@@ -25,6 +25,7 @@ this module plays the header's role and the lint plays the compiler's.
 from __future__ import annotations
 
 import ctypes
+import hashlib
 import os
 import re
 import struct as _struct
@@ -34,6 +35,21 @@ _HERE = os.path.dirname(os.path.abspath(__file__))
 _REPO = os.path.dirname(os.path.dirname(_HERE))
 SRC_DIR = os.path.join(_REPO, "native", "src")
 SOURCES = ["zone.cpp", "graph.cpp", "trace.cpp"]
+_DIGEST_RE = re.compile(r"-([0-9a-f]{16})\.so$")
+
+
+def source_digest(command: Sequence[str],
+                  src_dir: Optional[str] = None) -> str:
+    """Identity of one build: a digest of the compile command (compiler
+    + flags) and the content of every source file.  The built library
+    carries it in its file name (``native.lib_path``), so what loads is
+    provably what these sources produce — mtimes prove nothing once a
+    directory has been copied."""
+    h = hashlib.sha256(" ".join(command).encode())
+    for name in SOURCES:
+        with open(os.path.join(src_dir or SRC_DIR, name), "rb") as f:
+            h.update(name.encode() + b"\0" + f.read() + b"\0")
+    return h.hexdigest()[:16]
 
 # ---------------------------------------------------------------------------
 # type tokens
@@ -337,7 +353,7 @@ def abi_findings(lib_path: Optional[str] = None,
       the spec does not declare;
     * spec vs the built ``.so`` (when ``lib_path`` names one) — ENG001
       declared symbol missing from the library, ENG002 undeclared
-      export, ENG005 library older than its sources (stale build);
+      export, ENG005 library not built from these sources and flags;
     * trace record layout vs trace.cpp and the Python reader — ENG006.
     """
     from ..analysis.findings import Finding
@@ -390,18 +406,21 @@ def abi_findings(lib_path: Optional[str] = None,
                         f"{os.path.basename(lib_path)} exports {name} "
                         "with no ABI spec entry (undeclared export)",
                         task=name))
+        m = _DIGEST_RE.search(lib_path)
         try:
-            srcs = [os.path.join(src_dir or SRC_DIR, s) for s in SOURCES]
-            newest = max(os.path.getmtime(p) for p in srcs
-                         if os.path.exists(p))
-            if os.path.getmtime(lib_path) < newest:
-                out.append(Finding(
-                    "ENG005",
-                    f"{os.path.basename(lib_path)} is older than "
-                    "native/src/ (stale build: delete native/build/ or "
-                    "touch the sources to force a rebuild)"))
-        except (OSError, ValueError):
-            pass
+            from . import build_command
+
+            want = source_digest(build_command("tsan" in
+                                               os.path.basename(lib_path)),
+                                 src_dir)
+        except OSError:
+            want = None  # sources unreadable: ENG004 already says so
+        if want is not None and (m is None or m.group(1) != want):
+            out.append(Finding(
+                "ENG005",
+                f"{os.path.basename(lib_path)} was not built from these "
+                f"sources and flags (their digest is {want}): rebuild "
+                "with parsec_tpu.native.build_library()"))
     out.extend(_record_layout_findings(src_dir))
     return out
 

@@ -1,7 +1,7 @@
 """Tiled Cholesky factorization (dpotrf) as a PTG — the flagship taskpool.
 
 The reference runtime's headline dense-linear-algebra consumer is DPLASMA's
-dpotrf over a 2D block-cyclic matrix (north star in BASELINE.md). The
+dpotrf over a 2D block-cyclic matrix (north star in BASELINE.json). The
 reference repo itself contains no Cholesky (SURVEY.md §6); this is the
 classic right-looking tiled algorithm expressed in the PTG DSL:
 
@@ -45,7 +45,7 @@ def cholesky_ptg(*, use_tpu: bool = True, use_cpu: bool = True,
     nb=512) — the classic GPU-dpotrf critical-path trade. Pays off when
     per-task dispatch latency matters (dynamic path) or solves sit on
     the critical path; in the whole-DAG captured program XLA already
-    overlaps the solves, so there it measures neutral (BASELINE.md).
+    overlaps the solves, so there it measured neutral (round-2 chip run).
     CPU chores then need the ``TILE_SHAPE``/``TILE_DTYPE`` constants
     for the NEW-flow scratch (device chores are functional and ignore
     it).

@@ -20,9 +20,10 @@ Kernels here:
   ``(acc, m, l) x (q, k, v) -> (acc, m, l)`` (the ring-attention step
   BODY; never materialises the S x S matrix).
 
-Every wrapper takes ``interpret=None`` meaning "auto": real compilation
-on TPU backends, Pallas interpreter elsewhere (so the CPU test suite
-exercises identical kernel code).
+Every wrapper takes ``interpret=None`` meaning "by the platform the
+enclosing program is lowered for": Mosaic compilation on a TPU — always —
+and the Pallas interpreter elsewhere (so the CPU test suite exercises
+identical kernel code).
 """
 
 from __future__ import annotations
@@ -32,6 +33,7 @@ from typing import Optional
 
 import jax
 import jax.numpy as jnp
+from jax import lax
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
@@ -44,10 +46,20 @@ __all__ = [
 ]
 
 
-def _auto_interpret(interpret: Optional[bool]) -> bool:
+def _pallas(interpret: Optional[bool], args, kernel, **call_kwargs):
+    """``pl.pallas_call(kernel, **call_kwargs)(*args)``.  With
+    ``interpret=None`` the choice is made where it cannot be wrong: at
+    LOWERING, per platform (``lax.platform_dependent``) — a program
+    lowered for a TPU carries the Mosaic kernel whatever the process's
+    default backend is called, and no other platform is asked to
+    compile one."""
+    def build(interp: bool):
+        return pl.pallas_call(kernel, interpret=interp, **call_kwargs)
+
     if interpret is not None:
-        return interpret
-    return jax.default_backend() != "tpu"
+        return build(interpret)(*args)
+    return lax.platform_dependent(*args, tpu=build(False),
+                                  default=build(True))
 
 
 def _block(dim: int, want: int, align: int) -> int:
@@ -131,8 +143,8 @@ def matmul_update(C, A, B, *, alpha: float = -1.0, transpose_b: bool = True,
             o_ref[:] += alpha * jnp.dot(
                 a, b, preferred_element_type=o_ref.dtype)
 
-    return pl.pallas_call(
-        kernel,
+    return _pallas(
+        interpret, (C, A, B), kernel,
         out_shape=jax.ShapeDtypeStruct((m, n), C.dtype),
         grid=grid,
         in_specs=[
@@ -141,7 +153,6 @@ def matmul_update(C, A, B, *, alpha: float = -1.0, transpose_b: bool = True,
             b_spec,                                             # B
         ],
         out_specs=pl.BlockSpec((bm_, bn_), lambda i, j, k: (i, j)),
-        interpret=_auto_interpret(interpret),
         cost_estimate=pl.CostEstimate(
             flops=(3 if split_f32 else 1) * 2 * m * n * ka + m * n,
             # per-operand dtypes: mixed-precision callers pass bf16 A/B
@@ -150,7 +161,7 @@ def matmul_update(C, A, B, *, alpha: float = -1.0, transpose_b: bool = True,
                             + n * ka * B.dtype.itemsize
                             + 2 * m * n * C.dtype.itemsize),
             transcendentals=0),
-    )(C, A, B)
+    )
 
 
 @functools.partial(jax.jit, static_argnames=("transpose_b", "interpret",
@@ -185,8 +196,8 @@ def matmul(A, B, *, transpose_b: bool = True,
         o_ref[:] += jnp.dot(a_ref[:], b_op(b_ref[:]),
                             preferred_element_type=o_ref.dtype)
 
-    return pl.pallas_call(
-        kernel,
+    return _pallas(
+        interpret, (A, B), kernel,
         out_shape=jax.ShapeDtypeStruct((m, n), A.dtype),
         grid=grid,
         in_specs=[
@@ -194,12 +205,11 @@ def matmul(A, B, *, transpose_b: bool = True,
             b_spec_shape(bn_, bk_),
         ],
         out_specs=pl.BlockSpec((bm_, bn_), lambda i, j, k: (i, j)),
-        interpret=_auto_interpret(interpret),
         cost_estimate=pl.CostEstimate(
             flops=2 * m * n * ka,
             bytes_accessed=(m * ka + n * ka + m * n) * A.dtype.itemsize,
             transcendentals=0),
-    )(A, B)
+    )
 
 
 # -- 2D 5-point stencil -----------------------------------------------------
@@ -226,13 +236,12 @@ def stencil_5pt(old, up, down, left, right, *, interpret: Optional[bool] = None)
     """
     h, w = old.shape
     specs = [pl.BlockSpec(memory_space=pltpu.VMEM)] * 5
-    return pl.pallas_call(
-        _stencil_kernel,
+    return _pallas(
+        interpret, (old, up, down, left, right), _stencil_kernel,
         out_shape=jax.ShapeDtypeStruct((h, w), old.dtype),
         in_specs=specs,
         out_specs=pl.BlockSpec(memory_space=pltpu.VMEM),
-        interpret=_auto_interpret(interpret),
-    )(old, up, down, left, right)
+    )
 
 
 @functools.partial(jax.jit, static_argnames=("iters", "interpret"))
@@ -267,14 +276,13 @@ def stencil_5pt_fused(grid, iters: int, *, interpret: Optional[bool] = None):
         jax.lax.fori_loop(0, iters, step, ())
         o_ref[:] = scratch[:]
 
-    return pl.pallas_call(
-        kernel,
+    return _pallas(
+        interpret, (grid,), kernel,
         out_shape=jax.ShapeDtypeStruct((h, w), grid.dtype),
         in_specs=[pl.BlockSpec(memory_space=pltpu.VMEM)],
         out_specs=pl.BlockSpec(memory_space=pltpu.VMEM),
         scratch_shapes=[pltpu.VMEM((h, w), grid.dtype)],
-        interpret=_auto_interpret(interpret),
-    )(grid)
+    )
 
 
 # -- flash attention block update ------------------------------------------
@@ -324,8 +332,8 @@ def flash_attention_block(q, k, v, acc, m, l, q_off, k_off, *,
             p, v_ref[:].astype(jnp.float32), preferred_element_type=jnp.float32)
 
     row = lambda i: (i, 0)
-    out = pl.pallas_call(
-        kernel,
+    return _pallas(
+        interpret, (offs, q, k, v, acc, m, l), kernel,
         grid=grid,
         out_shape=(
             jax.ShapeDtypeStruct((Sq, D), jnp.float32),
@@ -346,6 +354,4 @@ def flash_attention_block(q, k, v, acc, m, l, q_off, k_off, *,
             pl.BlockSpec((bq_, 1), row),
             pl.BlockSpec((bq_, 1), row),
         ),
-        interpret=_auto_interpret(interpret),
-    )(offs, q, k, v, acc, m, l)
-    return out
+    )

@@ -2,7 +2,7 @@
 
 The whole-DAG ``GraphExecutor`` jits one XLA op per task — unbeatable at
 NT<=16 but O(tasks) compile (intractable at NT=64, ~45k tasks).  This
-module is the TPU-native answer for large NT (BASELINE north star:
+module is the TPU-native answer for large NT (BASELINE.json north star:
 N=32768, nb=512): the right-looking factorization becomes NT *panel
 steps*, each a jitted program whose shapes depend only on the trailing
 size rounded UP to a bucket — so XLA compiles O(#buckets) programs
@@ -68,7 +68,7 @@ def _panel_step(A, k0, *, R: int, nb: int, bf16: bool, strip: int = 0):
     D = lax.dynamic_slice(A, (k0, k0), (nb, nb))
     L = jnp.linalg.cholesky(D)
     # trsm-as-matmul: invert the nb x nb factor once (off the MXU, tiny)
-    # and turn the panel solve into one MXU gemm (BASELINE.md trsm row)
+    # and turn the panel solve into one MXU gemm
     W = lax.linalg.triangular_solve(
         L, jnp.eye(nb, dtype=f32), lower=True, left_side=True)
     A = lax.dynamic_update_slice(A, jnp.tril(L), (k0, k0))

@@ -88,7 +88,7 @@ def _make_panel_body(n: int, nb: int, bf16: bool, strip: int, kt: int):
         D = M[k0:k0 + nb, k0:k0 + nb].astype(f32)
         L = jnp.linalg.cholesky(D)
         # trsm-as-matmul: invert the nb x nb factor once (off the MXU)
-        # and turn the panel solve into one MXU gemm (BASELINE.md)
+        # and turn the panel solve into one MXU gemm
         W = lax.linalg.triangular_solve(
             L, jnp.eye(nb, dtype=f32), lower=True, left_side=True)
         M = M.at[k0:k0 + nb, k0:k0 + nb].set(jnp.tril(L).astype(M.dtype))
@@ -361,11 +361,10 @@ class SegmentedCholesky:
     def __call__(self, A_np: np.ndarray) -> np.ndarray:
         from ..device.tpu import private_device_put
 
-        A = jnp.asarray(np.ascontiguousarray(A_np))
-        if self.store_bf16:
-            A = A.astype(jnp.bfloat16)
         # guard=A_np: the donating in-place pipeline must never write
-        # through a zero-copy transfer into the CALLER's matrix
-        A = private_device_put(A, self.device.jdev, guard=A_np)
+        # through a zero-copy transfer into the CALLER's matrix (run()
+        # casts to bf16 on the device in storage mode)
+        A = private_device_put(np.ascontiguousarray(A_np),
+                               self.device.jdev, guard=A_np)
         out = np.asarray(jax.device_get(self.run(A)), dtype=np.float32)
         return np.tril(out)

@@ -1,7 +1,7 @@
 """Iterative 5-point stencil over a tile grid, as a PTG.
 
 Reference: ``/root/reference/tests/apps/stencil/`` (stencil test app,
-``testing_stencil_1D.c``) and the BASELINE "Stencil 2D5pt, comm/compute
+``testing_stencil_1D.c``) and the BASELINE.json "Stencil 2D5pt, comm/compute
 overlap" config. Each iteration's tile task consumes its own previous
 value plus the four neighbours' previous values (halo exchange expressed
 purely as dataflow), so the runtime overlaps neighbour communication with

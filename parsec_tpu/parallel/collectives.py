@@ -24,21 +24,13 @@ from jax import lax
 from ..utils import mca_param
 
 
-def axis_size(axis: str) -> int:
-    # lax.axis_size is a newer API; on older jax lax.psum(1, axis) inside
-    # shard_map constant-folds to the same static int
-    if hasattr(lax, "axis_size"):
-        return lax.axis_size(axis)
-    return lax.psum(1, axis)
-
-
 def my_index(axis: str) -> jax.Array:
     return lax.axis_index(axis)
 
 
 def shift(x, axis: str, offset: int = 1):
     """Ring rotation by ``offset`` along a mesh axis (ICI neighbour hop)."""
-    n = axis_size(axis)
+    n = lax.axis_size(axis)
     perm = [(i, (i + offset) % n) for i in range(n)]
     return lax.ppermute(x, axis, perm)
 
@@ -55,7 +47,7 @@ def bcast_chain(x, axis: str, root: int = 0):
     """Chain-pipeline broadcast: n-1 neighbour hops; each round forwards to
     the next rank (reference chain topology, best for large payloads on a
     ring interconnect)."""
-    n = axis_size(axis)
+    n = lax.axis_size(axis)
     cur = x
     for r in range(n - 1):
         src = (root + r) % n
@@ -69,7 +61,7 @@ def bcast_binomial(x, axis: str, root: int = 0):
     """Binomial-tree broadcast: ceil(log2 n) rounds, round r has the first
     2^r holders forward to holders 2^r..2^(r+1)-1 (reference binomial
     topology, latency-optimal for small activation messages)."""
-    n = axis_size(axis)
+    n = lax.axis_size(axis)
     rounds = max(1, math.ceil(math.log2(n))) if n > 1 else 0
     cur = x
     for r in range(rounds):

@@ -44,7 +44,7 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 from jax.sharding import Mesh, PartitionSpec as P
-from ._compat import no_vma_check_kwargs, shard_map
+from jax import shard_map
 
 _NEG_BIG = -1e30  # finite "-inf" for running-max init (keeps exp() NaN-free)
 
@@ -149,9 +149,8 @@ def ring_attention(
     # pallas_call's out_shape carries no varying-manual-axes info, so the
     # vma consistency check cannot see through it — disable it for this
     # path (numerics are covered by the oracle tests)
-    kw = no_vma_check_kwargs() if use_pallas else {}
     f = shard_map(kernel, mesh=mesh, in_specs=(spec, spec, spec),
-                  out_specs=spec, **kw)
+                  out_specs=spec, check_vma=not use_pallas)
     return jax.jit(f)(q, k, v)
 
 
@@ -188,11 +187,5 @@ def ulysses_attention(
 
 
 def _varying(x, axis):
-    """Mark a constant as device-varying inside shard_map (pvary was
-    deprecated in favour of pcast; jax builds predating both don't
-    require the annotation at all)."""
-    if hasattr(lax, "pcast"):
-        return lax.pcast(x, axis, to="varying")
-    if hasattr(lax, "pvary"):
-        return lax.pvary(x, (axis,))
-    return x
+    """Mark a constant as device-varying inside shard_map."""
+    return lax.pcast(x, axis, to="varying")

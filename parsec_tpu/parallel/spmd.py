@@ -28,7 +28,7 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
-from ._compat import shard_map
+from jax import shard_map
 
 from .mesh import block_sharding
 

@@ -1,6 +1,6 @@
 """Multi-chip 2D 5-point stencil: halo exchange over the device mesh.
 
-The BASELINE-tracked "Stencil 2D5pt, comm/compute overlap" configuration
+The BASELINE.json "Stencil 2D5pt, comm/compute overlap" configuration
 (reference app: ``/root/reference/tests/apps/stencil/``). The reference
 gets overlap from its comm thread progressing halo messages while workers
 compute interiors; the TPU-native equivalent expresses each iteration's
@@ -22,7 +22,7 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 from jax.sharding import Mesh, PartitionSpec as P
-from ._compat import shard_map
+from jax import shard_map
 
 __all__ = ["spmd_stencil_5pt"]
 

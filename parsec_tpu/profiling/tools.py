@@ -671,8 +671,7 @@ def cmd_cache(args) -> int:
         rows = store.entries()
         for r in rows:
             meta = r.get("meta") or {}
-            state = "CORRUPT" if r.get("corrupt") else (
-                "native+hlo" if meta.get("native_meta") else "hlo")
+            state = "CORRUPT" if r.get("corrupt") else "hlo"
             print(f"{r['fp']}  {r.get('size', 0):>10}  {state:<10} "
                   f"{meta.get('backend', '?'):<6} "
                   f"{meta.get('compile_s', '?'):>8}s  "
@@ -684,13 +683,10 @@ def cmd_cache(args) -> int:
         rows = store.entries()
         total = sum(r.get("size", 0) for r in rows)
         corrupt = sum(1 for r in rows if r.get("corrupt"))
-        native = sum(1 for r in rows
-                     if (r.get("meta") or {}).get("native_meta"))
         saved = sum((r.get("meta") or {}).get("compile_s", 0) or 0
                     for r in rows)
         print(f"store:          {store.dir}")
-        print(f"entries:        {len(rows)} ({corrupt} corrupt, "
-              f"{native} with native executables)")
+        print(f"entries:        {len(rows)} ({corrupt} corrupt)")
         print(f"bytes:          {total}")
         print(f"compile_s sum:  {saved:.1f}  (cold cost the store "
               "amortizes)")
@@ -918,7 +914,7 @@ def main(argv=None) -> int:
     pe = sub.add_parser(
         "cache", help="persistent executable cache maintenance: list "
         "entries, stats, purge, integrity verify "
-        "(PARSEC_TPU_COMPILE_CACHE governs the store location)")
+        "(JAX_COMPILATION_CACHE_DIR places the cache root)")
     pe.add_argument("op", choices=("ls", "stats", "purge", "verify"))
     pe.add_argument("--dir", help="inspect an explicit cache root "
                     "instead of the resolved default")

@@ -10,9 +10,10 @@ reload from the store on every later run — including the production run
 that finally uses the winner.
 
 Layout: one JSON file per tuning key under ``<cache_root>/autotune/``
-(``PARSEC_TPU_COMPILE_CACHE`` governs the root, like the executable
-store).  Entries record every candidate's measured seconds, the winner,
-and enough metadata to judge staleness.  Corrupt files read as absent.
+(:func:`parsec_tpu.compile_cache.cache_root` — placed by
+``JAX_COMPILATION_CACHE_DIR``, like the executable store).  Entries
+record every candidate's measured seconds, the winner, and enough
+metadata to judge staleness.  Corrupt files read as absent.
 
 CLI: ``python -m parsec_tpu.profiling.tools autotune --op dpotrf
 --n 1024 --nb 64,128,256`` (see ``tools autotune --help``).
@@ -64,6 +65,10 @@ class TuningStore:
     def __init__(self, directory: str):
         self.dir = directory
         self._lock = threading.Lock()
+        #: entries :func:`resolve_nb` found here — a run whose behaviour
+        #: a stored winner could change (``nb="auto"``, fusion's
+        #: ``max_tasks``) can say so
+        self.found = 0
 
     def _path(self, key: str) -> str:
         return os.path.join(self.dir, f"{key}.json")
@@ -129,8 +134,7 @@ _memory_docs: Dict[str, Dict[str, Any]] = {}
 
 class _MemoryStore(TuningStore):
     def __init__(self):
-        self.dir = "<memory>"
-        self._lock = threading.Lock()
+        super().__init__("<memory>")
 
     def load(self, key):
         return _memory_docs.get(key)
@@ -184,6 +188,7 @@ def resolve_nb(op: str, n: int, dtype="float32", *, device=None,
     doc = st.load(tune_key(op, n, dtype, _device_kind(device), param))
     if doc is None:
         return default
+    st.found += 1
     best = doc.get("best")
     if not isinstance(best, int) or best <= 0:
         return default

@@ -117,7 +117,7 @@ def test_native_conformance_certifies_real_pump_runs():
 def test_shipped_abi_is_clean():
     from parsec_tpu import native
 
-    lib = native._LIB_PATH if os.path.exists(native._LIB_PATH) else None
+    lib = native.lib_path() if os.path.exists(native.lib_path()) else None
     assert abi.abi_findings(lib, native._SRC_DIR) == []
 
 

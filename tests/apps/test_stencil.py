@@ -1,4 +1,4 @@
-"""Stencil 2D5pt app test (reference tests/apps/stencil + BASELINE
+"""Stencil 2D5pt app test (reference tests/apps/stencil + BASELINE.json
 'Stencil 2D5pt' tracked config)."""
 
 import numpy as np

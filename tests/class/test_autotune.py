@@ -92,7 +92,7 @@ def test_segmented_cholesky_nb_auto_uses_tuned_winner(monkeypatch,
     """ops.* pick the tuned nb by default: seed a winner for
     (dpotrf_seg, N, f32, this device generation), construct with
     nb="auto", and the driver must adopt it."""
-    monkeypatch.setenv("PARSEC_TPU_COMPILE_CACHE", str(tmp_path))
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
     from parsec_tpu import Context
     from parsec_tpu.ops.segmented_chol import SegmentedCholesky
 
@@ -117,7 +117,7 @@ def test_segmented_cholesky_nb_auto_uses_tuned_winner(monkeypatch,
 def test_tools_autotune_cli_real_dpotrf(monkeypatch, tmp_path, capsys):
     """End-to-end: the CLI times real (tiny) dynamic dpotrf runs per nb
     candidate and persists a winner nb='auto' resolves."""
-    monkeypatch.setenv("PARSEC_TPU_COMPILE_CACHE", str(tmp_path))
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
     from parsec_tpu.profiling.tools import main as tools_main
 
     rc = tools_main(["autotune", "--op", "dpotrf", "--n", "64",

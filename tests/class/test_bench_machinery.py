@@ -60,3 +60,47 @@ def test_over_budget_threshold(monkeypatch):
                         lambda: bench._T_START + 90.0)
     assert bench._over_budget(0.85, "x") is True
     assert bench._over_budget(0.95, "x") is False
+
+
+def test_bench_refuses_to_measure_without_a_chip(monkeypatch):
+    """No silent CPU run: off the chip the bench starts only when the CI
+    smoke asks for the CPU backend by name."""
+    monkeypatch.delenv("BENCH_PLATFORM", raising=False)
+    with pytest.raises(SystemExit) as exc:
+        bench.main()
+    assert "BENCH_PLATFORM=cpu" in str(exc.value)
+
+
+def _main_with_legs(monkeypatch, capsys, legs):
+    monkeypatch.setenv("BENCH_PLATFORM", "cpu")
+    monkeypatch.delenv("BENCH_JSON_OUT", raising=False)
+    monkeypatch.setattr(
+        bench, "_rest_of_main",
+        lambda *a: a[-1].update(legs))  # fields is the last argument
+    try:
+        bench.main()
+        rc = 0
+    except SystemExit as e:
+        rc = e.code
+    import json
+
+    return rc, json.loads(capsys.readouterr().out.strip().splitlines()[-1])
+
+
+def test_bench_names_its_device_and_passes_when_every_leg_did(
+        monkeypatch, capsys):
+    rc, out = _main_with_legs(monkeypatch, capsys, {"graph_gflops": 1.0})
+    assert rc == 0
+    assert out["device"]["platform"] == "cpu"
+    assert out["device"]["kind"] and out["device"]["count"] >= 1
+
+
+def test_bench_exits_nonzero_when_a_leg_recorded_an_error(
+        monkeypatch, capsys):
+    """The JSON line still carries everything measured — and the exit
+    code says a leg failed."""
+    rc, out = _main_with_legs(
+        monkeypatch, capsys,
+        {"graph_gflops": 1.0, "dynamic_error": "RuntimeError: boom"})
+    assert rc == 1
+    assert out["graph_gflops"] == 1.0 and "dynamic_error" in out

@@ -157,7 +157,7 @@ def _the_entry(store):
 
 
 @pytest.mark.parametrize("damage", ["truncate", "flip", "garbage",
-                                    "native_flip"])
+                                    "tail_flip", "trailing_bytes"])
 def test_corrupt_entry_falls_back_to_recompile(store, damage, capfd):
     from parsec_tpu.utils import debug
 
@@ -174,9 +174,15 @@ def test_corrupt_entry_falls_back_to_recompile(store, damage, capfd):
         cut = raw.index(b"\n") + 10
         open(path, "wb").write(
             raw[:cut] + bytes([raw[cut] ^ 0xFF]) + raw[cut + 1:])
-    elif damage == "native_flip":
+    elif damage == "tail_flip":
+        # the entry ends with the portable blob (no machine-code section
+        # follows it any more): a flip near the end is a checksum miss
         open(path, "wb").write(raw[:-10] + bytes([raw[-10] ^ 0xFF])
                                + raw[-9:])
+    elif damage == "trailing_bytes":
+        # e.g. an entry written by the retired format with a native
+        # section appended after the blob
+        open(path, "wb").write(raw + b"\x7fELF-machine-code")
     else:
         open(path, "wb").write(b"not an executable at all")
     c2 = cc.ExecutableCache(store=store, min_disk_s=0.0)

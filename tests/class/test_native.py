@@ -59,6 +59,21 @@ def test_zone_unknown_offset_rejected():
     z.close()
 
 
+def test_zone_use_after_close_raises_instead_of_crashing():
+    """A closed zone must refuse every call: handing its cleared handle
+    to the library dereferences NULL (seen on the chip as a SIGSEGV when
+    the cycle collector finalized the zone before a device whose
+    detach() still read ``used``)."""
+    z = native.ZoneAllocator(1024)
+    z.close()
+    for call in (lambda: z.alloc(16), lambda: z.release(0), lambda: z.used,
+                 lambda: z.capacity, lambda: z.largest_free,
+                 lambda: z.num_live):
+        with pytest.raises(RuntimeError, match="closed"):
+            call()
+    z.close()  # idempotent
+
+
 def test_zone_threaded_stress():
     z = native.ZoneAllocator(1 << 22)
     errs = []

@@ -4,6 +4,7 @@
 the code TSan exists to watch.  Loading a TSan .so needs the sanitizer
 runtime preloaded, so the smoke stops at compile + symbol check."""
 
+import os
 import shutil
 import subprocess
 
@@ -26,7 +27,8 @@ def test_tsan_flavor_compiles_with_engine_symbols(tmp_path):
     if not _tsan_supported():
         pytest.skip("toolchain lacks -fsanitize=thread")
     path = native.build_tsan_library()
-    assert path.endswith("libparsec_core_tsan.so")
+    assert path == native.lib_path(tsan=True)
+    assert os.path.basename(path).startswith("libparsec_core_tsan-")
     nm = subprocess.run(["nm", "-D", path], capture_output=True, text=True)
     assert nm.returncode == 0
     # the async engine the sanitizer is wired for must be in the flavor,

@@ -59,8 +59,8 @@ def test_partition_dpotrf_chains_and_waves():
                 node = g.nodes[m]
                 assert len({s for (_f, s, _sf) in node.out_edges}) == 1
                 assert node.remote_out == 0
-    # the syrk column chains end in their potrf (the hand-fused tail
-    # panels of BASELINE round 2, now automatic)
+    # the syrk column chains end in their potrf (the tail panels round 2
+    # fused by hand, now automatic)
     assert any(r.members[-1][0] == "potrf" for r in regions
                if r.kind == "chain")
 
@@ -326,7 +326,7 @@ def test_fused_programs_hit_cache_across_processes(tmp_path):
     ZERO recompiles — every fused program reloads from the persistent
     store (fused program key = member fingerprints + region shape)."""
     env = dict(os.environ, JAX_PLATFORMS="cpu",
-               PARSEC_TPU_COMPILE_CACHE=str(tmp_path / "exe"))
+               JAX_COMPILATION_CACHE_DIR=str(tmp_path / "cache"))
     out = []
     for _ in range(2):
         p = subprocess.run([sys.executable, "-c", _CHILD],

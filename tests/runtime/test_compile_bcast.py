@@ -216,7 +216,7 @@ def test_wave_autodisable_ab_cold_vs_warm(monkeypatch, tmp_path):
     from parsec_tpu import Context
 
     # A: cold store -> auto-disabled
-    monkeypatch.setenv("PARSEC_TPU_COMPILE_CACHE", str(tmp_path / "cold"))
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path / "cold"))
     ctx = Context(nb_cores=1, rank=0, nranks=2)
     try:
         assert _tpu_dev(ctx)._wave_min == 0
@@ -227,7 +227,7 @@ def test_wave_autodisable_ab_cold_vs_warm(monkeypatch, tmp_path):
     # this process) -> default stays enabled; an entry only a different
     # jax build could load must NOT lift the workaround
     warm_root = tmp_path / "warm"
-    monkeypatch.setenv("PARSEC_TPU_COMPILE_CACHE", str(warm_root))
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(warm_root))
     st = cc.DiskStore(str(warm_root / "exe"))
     st.store("e" * 40, b"stale", {"versions": "jax-0.0.0/jaxlib-0.0.0",
                                   "backend": cc._platform()})
@@ -245,7 +245,7 @@ def test_wave_autodisable_ab_cold_vs_warm(monkeypatch, tmp_path):
         ctx.fini()
 
     # C: explicit setting beats both directions
-    monkeypatch.setenv("PARSEC_TPU_COMPILE_CACHE", str(tmp_path / "cold2"))
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path / "cold2"))
     mca_param.set_param("device", "tpu_wave_batch", 3)
     try:
         ctx = Context(nb_cores=1, rank=0, nranks=2)
@@ -262,7 +262,7 @@ def test_single_rank_keeps_wave_batching(monkeypatch, tmp_path):
     contexts keep the default wave batching even with a cold cache."""
     from parsec_tpu import Context
 
-    monkeypatch.setenv("PARSEC_TPU_COMPILE_CACHE", str(tmp_path))
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
     ctx = Context(nb_cores=1)
     try:
         assert _tpu_dev(ctx)._wave_min > 0

@@ -63,7 +63,7 @@ def test_persistent_submit_failure_fails_pool(ctx):
 
 
 def test_transient_submit_failure_retried_once(ctx):
-    """The first submit raising (a flaky tunnel RPC) must not zero the
+    """The first submit raising (a transient PJRT error) must not zero the
     run: one retry with fresh state completes the task normally."""
     dev = tpu_dev(ctx)
     d = data_create("y", payload=np.full(8, 1.0))

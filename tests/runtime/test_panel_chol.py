@@ -1,5 +1,5 @@
 """Panel-wise / whole-program Cholesky (ops/panel_chol.py) — the
-compile-scalable path to the BASELINE north star (N=32768, nb=512).
+compile-scalable path to the BASELINE.json north star (N=32768, nb=512).
 
 Correctness strategy: f64 runs must match numpy's factorization to
 machine precision (catches structural bugs that f32 rounding would

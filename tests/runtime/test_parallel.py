@@ -7,7 +7,7 @@ import pytest
 import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
-from parsec_tpu.parallel._compat import no_vma_check_kwargs, shard_map
+from jax import shard_map
 
 from parsec_tpu.parallel import (
     best_grid,
@@ -61,7 +61,7 @@ def test_collective_wrappers():
     x = jnp.arange(8.0).reshape(8, 1)
     f = shard_map(kern, mesh=mesh, in_specs=P("x", None),
                   out_specs=(P("x", None), P(None, None)),
-                  **no_vma_check_kwargs())
+                  check_vma=False)
     s, g = jax.jit(f)(x)
     assert float(np.asarray(s)[0, 0]) == 28.0
     np.testing.assert_allclose(np.asarray(g).ravel(), np.arange(8.0))
@@ -120,7 +120,7 @@ def test_spmd_cholesky_sharded():
 
 def test_spmd_stencil_matches_reference():
     """Halo-exchange stencil on a 2D device mesh == the dense oracle
-    (the BASELINE 'stencil 2D5pt comm/compute overlap' config)."""
+    (the BASELINE.json 'stencil 2D5pt comm/compute overlap' config)."""
     import jax.numpy as jnp
 
     from parsec_tpu.parallel import make_mesh, spmd_stencil_5pt

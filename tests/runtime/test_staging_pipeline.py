@@ -450,3 +450,57 @@ def test_dynamic_digests_identical_pipeline_on_vs_off():
     assert on == off, "staging pipeline changed numerics"
     L = np.tril(A.to_array())
     np.testing.assert_allclose(L @ L.T, S, rtol=1e-10, atol=1e-10)
+
+
+# ---------------------------------------------------------------------------
+# donation vs deferred write-back (found on the chip, PR 21)
+# ---------------------------------------------------------------------------
+
+def test_donating_chain_is_not_handed_to_the_committer(ctx):
+    """The segmented factorizations thread ONE whole-matrix INOUT flow
+    through donating programs: each step's successor consumes the very
+    buffer the epilog just committed.  Eagerly writing every
+    intermediate version home stalled the chain behind a 4 GiB get per
+    step on the chip (or read a deleted array and failed the pool), so
+    a donating program's outputs stay dirty-resident instead."""
+    from parsec_tpu.ops.segmented_chol import SegmentedCholesky
+
+    dev = tpu_dev(ctx)
+    assert dev.stage_depth > 1  # the pipeline is on by default
+    n, nb = 128, 32
+    rng = np.random.default_rng(5)
+    M = rng.standard_normal((n, n)).astype(np.float32)
+    spd = M @ M.T + n * np.eye(n, dtype=np.float32)
+    sc = SegmentedCholesky(ctx, n, nb, strip=64, tail=0)
+    assert sc.nt_tasks == 4  # a real chain, not one fused task
+    L = sc(spd)
+    np.testing.assert_allclose(L @ L.T, spd, rtol=2e-4, atol=2e-3)
+    com = dev._committer
+    assert com is None or com.stats["enqueued"] == 0
+    assert dev.stats["bytes_out"] == 0  # no intermediate version went home
+
+
+def test_committer_drops_a_version_consumed_by_a_donating_task(ctx):
+    """A snapshot whose device array a donating task consumed before the
+    get is a superseded version: dropped as stale, never a dead
+    committer."""
+    import jax.numpy as jnp
+
+    dev = tpu_dev(ctx)
+    live, gone = jnp.ones(8), jnp.ones(8)
+    gone.delete()
+    hosts = dev._d2h_batch([live, gone])
+    assert hosts[1] is None
+    np.testing.assert_allclose(hosts[0], 1.0)
+
+    com = WritebackCommitter(dev)
+    try:
+        d = data_create("donated", payload=np.zeros(8))
+        c = d.attach_copy(dev.data_index, gone)
+        c.version = 2
+        com.enqueue(d)
+        com.flush()
+        assert com.healthy
+        assert com.stats["dropped_stale"] == 1 and com.stats["committed"] == 0
+    finally:
+        com.close()

@@ -85,8 +85,8 @@ def test_tcp_send_then_immediate_close():
 
 def test_tcp_perf_smoke():
     """RTT/bandwidth through the real AM path (rtt.jdf/bandwidth.jdf
-    shape). Not pinned — loose sanity floors; the measured numbers land
-    in BASELINE.md."""
+    shape). Not pinned — loose sanity floors; the measured numbers are
+    printed."""
     out = run_scenario("perf", 2)
     r0 = next(o for o in out if o["rank"] == 0)
     print(f"\ntcp perf: rtt={r0['rtt_us']} us, bw={r0['mb_s']} MB/s")
