@@ -31,9 +31,12 @@ Every dpotrf stage runs twice: cold (compiles included) and warm (the
 same device, no compile allowed).  Timings printed here are observations
 for ``CHANGES.md``; they are not benchmark numbers.
 
-The last line of stdout is one JSON object: ``{"ok": true, "device":
-{...}, ..., "claim": null}``.  Without a TPU, or when a stage fails, the
-exit code is non-zero and no such line is printed.
+The last two lines of stdout are JSON objects: the run's summary
+(``{"setup": ..., "kernels": ..., ..., "claim": null}`` — what each stage
+measured and counted, for ``CHANGES.md``) and then, last, the result with
+exactly these keys: ``{"ok": true, "device": {"platform": "tpu", "kind":
+"...", "count": N}}``.  Without a TPU, or when a stage fails, the exit
+code is non-zero and neither line is printed.
 """
 
 from __future__ import annotations
@@ -650,6 +653,16 @@ def stage_setup() -> Dict[str, Any]:
     }
 
 
+def final_lines(device: Dict[str, Any], report: Dict[str, Any]):
+    """The last two stdout lines of a passing run: the summary, then the
+    result the driver parses — ``ok`` and ``device`` and nothing else."""
+    return (json.dumps({**report, "claim": None}),
+            json.dumps({"ok": True, "device": {
+                "platform": str(device["platform"]),
+                "kind": str(device["kind"]),
+                "count": int(device["count"])}}))
+
+
 def main(sizes: Sizes = Sizes()) -> int:
     t_start = time.perf_counter()
     import jax
@@ -691,8 +704,8 @@ def main(sizes: Sizes = Sizes()) -> int:
     report["peak_bytes_in_use"] = [
         (d.memory_stats() or {}).get("peak_bytes_in_use") for d in devices]
     report["wall_s"] = round(time.perf_counter() - t_start, 1)
-    print(json.dumps({"ok": True, "device": device, **report,
-                      "claim": None}))
+    for line in final_lines(device, report):
+        print(line, flush=True)
     return 0
 
 

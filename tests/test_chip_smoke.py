@@ -9,6 +9,7 @@ interprets when lowered for a TPU, and the device path raises where it
 used to carry on.
 """
 
+import json
 import os
 import shutil
 import subprocess
@@ -114,6 +115,17 @@ def test_alone_in_a_directory_it_fails(tmp_path):
     env = {k: v for k, v in os.environ.items() if k != "PYTHONPATH"}
     p = _run_smoke(tmp_path, dict(env, JAX_PLATFORMS="cpu"))
     assert p.returncode != 0 and '"ok"' not in p.stdout
+
+
+def test_last_line_is_the_result_object_and_nothing_else():
+    summary, result = cs.final_lines(
+        {"platform": "tpu", "kind": "TPU v5 lite", "count": 1},
+        {"setup": {}, "wall_s": 1.0})
+    assert json.loads(result) == {
+        "ok": True,
+        "device": {"platform": "tpu", "kind": "TPU v5 lite", "count": 1}}
+    assert "ok" not in json.loads(summary)
+    assert summary.endswith('"claim": null}')
 
 
 # ---------------------------------------------------------------------------
