@@ -1371,10 +1371,10 @@ def staging_overlap_ab_leg(fields: dict) -> None:
         def probe(ex):
             orig = ex.device.submit_batch
 
-            def submit(batch):
+            def submit(batch, **kw):
                 t0 = time.perf_counter()
                 try:
-                    return orig(batch)
+                    return orig(batch, **kw)
                 finally:
                     iv_submit.append((t0, time.perf_counter()))
 

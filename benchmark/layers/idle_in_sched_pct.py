@@ -1,0 +1,14 @@
+"""layer: device.  source: the program's ``parsec:*`` spans in the
+profiler's trace.  moves: ``tile_solve_s``.
+Share of the idlest chip's idle time that lies under the pump, the
+scheduling core and attach (``pump:*``, ``core:*``, ``attach:*``).  On
+each thread the innermost span counts; where threads disagree the classes
+win in the order dispatch, submit, transfer, scheduler
+(``benchmark/trace/spans.py``).  The five ``idle_*_pct`` sum to 100."""
+
+from benchmark.trace import spans
+
+
+def read(run):
+    s = spans.of_run(run)
+    return None if s is None else s.idle_pct("sched")

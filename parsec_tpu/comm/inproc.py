@@ -183,26 +183,20 @@ class InprocComm(CommEngine):
             self.stats["frames_coalesced"] += 1
             self.stats["msgs_coalesced"] += len(batch)
         # transport span: bytes + peer + receiver queue depth measured AT
-        # the wire (per-rank tracing routes on the ``rank`` field); the
-        # byte re-walk only happens when someone is listening
-        wire = pins.active(pins.COMM_SEND_BEGIN)
-        if wire:
-            nbytes = sum(_payload_bytes(p) for _t, p in batch)
-            pins.fire(pins.COMM_SEND_BEGIN, None,
-                      {"rank": self.rank, "peer": dst_rank,
-                       "bytes": nbytes, "coalesced": len(batch),
-                       "qdepth": self.fabric.inboxes[dst_rank].qsize()})
-        if pins.active(pins.HB_FRAME_SEND):
-            # happens-before edge source: everything this rank did before
-            # the frame left is visible to whatever its delivery triggers
-            pins.fire(pins.HB_FRAME_SEND, None,
-                      {"rank": self.rank, "peer": dst_rank, "frame": fid})
-        self.fabric.inboxes[dst_rank].put(
-            (self.rank, batch, self._pb_outgoing(), fid))
-        if wire:
-            pins.fire(pins.COMM_SEND_END, None,
-                      {"rank": self.rank, "peer": dst_rank,
-                       "bytes": nbytes})
+        # the wire (per-rank tracing routes on the ``rank`` field)
+        with pins.span("comm:send", rank=self.rank, peer=dst_rank,
+                       bytes=sum(_payload_bytes(p) for _t, p in batch),
+                       coalesced=len(batch),
+                       qdepth=self.fabric.inboxes[dst_rank].qsize()):
+            if pins.active(pins.HB_FRAME_SEND):
+                # happens-before edge source: everything this rank did
+                # before the frame left is visible to whatever its
+                # delivery triggers
+                pins.fire(pins.HB_FRAME_SEND, None,
+                          {"rank": self.rank, "peer": dst_rank,
+                           "frame": fid})
+            self.fabric.inboxes[dst_rank].put(
+                (self.rank, batch, self._pb_outgoing(), fid))
         peer = self.fabric.engines[dst_rank]
         if peer is not None and peer.context is not None:
             peer.context._notify_work()
@@ -290,18 +284,13 @@ class InprocComm(CommEngine):
                                   {"rank": self.rank, "peer": src,
                                    "frame": fid})
                     self._pb_incoming(src, pb)
-                    nbytes = sum(_payload_bytes(p) for _t, p in batch)
                     # recv span: covers the frame's dispatch
                     # (deserialize-free on this fabric, so the span is
                     # the handlers' own work)
-                    wire = pins.active(pins.COMM_RECV_BEGIN)
-                    if wire:
-                        pins.fire(pins.COMM_RECV_BEGIN, None,
-                                  {"rank": self.rank, "peer": src,
-                                   "bytes": nbytes,
-                                   "coalesced": len(batch),
-                                   "qdepth": inbox.qsize()})
-                    try:
+                    with pins.span(
+                            "comm:recv", rank=self.rank, peer=src,
+                            bytes=sum(_payload_bytes(p) for _t, p in batch),
+                            coalesced=len(batch), qdepth=inbox.qsize()):
                         for tag, payload in batch:
                             self._termdet_note_recv(tag)
                             cb = self._am.get(tag)
@@ -321,10 +310,6 @@ class InprocComm(CommEngine):
                                 traceback.print_exc()
                             n += 1
                             self.stats[f"am_recv_{tag}"] += 1
-                    finally:
-                        if wire:
-                            pins.fire(pins.COMM_RECV_END, None,
-                                      {"rank": self.rank, "peer": src})
         finally:
             self._progress_lock.release()
         return n

@@ -642,28 +642,23 @@ class TCPComm(CommEngine):
             return
         # transport span on the comm thread's stream: one frame on the
         # wire, with bytes, peer, and the command-queue depth behind it
-        wire = pins.active(pins.COMM_SEND_BEGIN)
-        if wire:
-            pins.fire(pins.COMM_SEND_BEGIN, None,
-                      {"rank": self.rank, "peer": dst,
-                       "bytes": frame_bytes, "coalesced": len(batch),
-                       "qdepth": self._cmds.qsize()})
         try:
-            # byte-tracked sends: sendall on a non-blocking socket can
-            # transmit part of the frame before raising, with no way to
-            # learn how much — that would corrupt the framed stream on
-            # retry, so every segment goes through the tracker
-            self._send_tracked(sock, head)
-            for b in bufs:
-                self._send_tracked(sock, b)
-            if wire:
-                pins.fire(pins.COMM_SEND_END, None,
-                          {"rank": self.rank, "peer": dst,
-                           "bytes": frame_bytes})
+            with pins.span("comm:send", rank=self.rank, peer=dst,
+                           bytes=frame_bytes, coalesced=len(batch),
+                           qdepth=self._cmds.qsize()) as sp:
+                # byte-tracked sends: sendall on a non-blocking socket
+                # can transmit part of the frame before raising, with no
+                # way to learn how much — that would corrupt the framed
+                # stream on retry, so every segment goes through the
+                # tracker
+                try:
+                    self._send_tracked(sock, head)
+                    for b in bufs:
+                        self._send_tracked(sock, b)
+                except OSError:
+                    sp.end({"rank": self.rank, "peer": dst, "bytes": 0})
+                    raise
         except OSError as e:
-            if wire:
-                pins.fire(pins.COMM_SEND_END, None,
-                          {"rank": self.rank, "peer": dst, "bytes": 0})
             if not self._closing.is_set():
                 debug.error("rank %d: send to %d failed: %s", self.rank, dst, e)
             else:
@@ -854,20 +849,12 @@ class TCPComm(CommEngine):
         # as of (at latest) this frame's messages
         # recv span: one frame's dispatch (unpickle already done above;
         # the span is the AM handlers' own work — release_deps etc.)
-        wire = pins.active(pins.COMM_RECV_BEGIN)
-        if wire:
-            pins.fire(pins.COMM_RECV_BEGIN, None,
-                      {"rank": self.rank, "peer": src,
-                       "bytes": len(st.ctl) + sum(st.lens)})
         n = 0
-        try:
+        with pins.span("comm:recv", rank=self.rank, peer=src,
+                       bytes=len(st.ctl) + sum(st.lens)):
             for tag, payload in batch:
                 self._dispatch(tag, src, payload)
                 n += 1
-        finally:
-            if wire:
-                pins.fire(pins.COMM_RECV_END, None,
-                          {"rank": self.rank, "peer": src})
         return n
 
     def _rx_abort(self, st: _RecvState) -> None:

@@ -711,27 +711,19 @@ class ExecutableCache:
         from .profiling import pins
 
         t0 = time.perf_counter()
-        span = pins.active(pins.COMPILE_BEGIN)
+        kind = "miss"
         # job trace context (profiling.jobtrace): a compile triggered
         # from inside a task body inherits the running job's trace id
         # off the worker thread — the merged timeline shows WHOSE job a
         # cold compile stalled
-        trace = jobtrace.current()
-        if span:
-            pins.fire(pins.COMPILE_BEGIN, None,
-                      {"rank": self.rank, "fp": fp, "key": _short(cf.key),
-                       "trace": trace})
-        kind = "miss"
-        try:
-            exe, kind = self._resolve_slow(cf, fp, args)
-        finally:
-            dt = time.perf_counter() - t0
-            self.stats["compile_ns_total"] += int(dt * 1e9)
-            if span:
-                pins.fire(pins.COMPILE_END, None,
-                          {"rank": self.rank, "fp": fp,
-                           "key": _short(cf.key), "kind": kind,
-                           "seconds": dt, "trace": trace})
+        with pins.span("cc:compile", rank=self.rank, fp=fp,
+                       key=_short(cf.key), trace=jobtrace.current()) as sp:
+            try:
+                exe, kind = self._resolve_slow(cf, fp, args)
+            finally:
+                dt = time.perf_counter() - t0
+                self.stats["compile_ns_total"] += int(dt * 1e9)
+                sp.note(kind=kind, seconds=dt)
         self._lru_put(fp, exe)
         return exe
 

@@ -239,6 +239,12 @@ class Context:
         """Reference ``parsec_context_add_taskpool`` (scheduling.c:832):
         register, notify comm layer, run the startup hook, enqueue the
         initially-ready tasks."""
+        from ..profiling import pins
+
+        with pins.span("attach:build", pool=tp.taskpool_id, rank=self.rank):
+            self._add_taskpool(tp)
+
+    def _add_taskpool(self, tp: Taskpool) -> None:
         # Distributed termdet monitors (fourcounter) bind to the comm
         # engine and are driven from the idle loop (_progress_comm); one
         # distributed monitor per CE at a time — the TERMDET tag and
@@ -407,9 +413,9 @@ class Context:
             # cancelled DAG here after abort() ran
         from ..profiling import pins
 
-        pins.fire(pins.SELECT_BEGIN, es, None)
-        task = self.scheduler.select(es)
-        pins.fire(pins.SELECT_END, es, task)
+        with pins.span("core:select", es, rank=self.rank) as sp:
+            task = self.scheduler.select(es)
+            sp.end(task)
         # a task of an aborted pool may linger in a queue (its release was
         # in flight during the abort's scheduler reset): discard, don't run
         while task is not None and task.taskpool.failed:

@@ -34,18 +34,21 @@ def schedule_ready(context: "Context", es: Optional["ExecutionStream"], tasks: I
         if tp.auto_count and not t.counted:
             t.counted = True
             tp.tdm.taskpool_addto_nb_tasks(tp, 1)
-    pins.fire(pins.SCHEDULE_BEGIN, es, batch)
-    if es is not None and es.next_task is None and distance == 0:
-        best = max(range(len(batch)), key=lambda i: batch[i].priority)
-        es.next_task = batch.pop(best)
-    if batch:
-        context.scheduler.schedule(es, batch, distance)
-        # only a task actually pushed to the scheduler warrants waking the
-        # idle threads: a kept-next successor is run by THIS worker, and
-        # waking everyone per completion makes the idle pack churn the
-        # GIL against the running worker's async device dispatch
-        context._notify_work()
-    pins.fire(pins.SCHEDULE_END, es, batch)
+    # BEGIN sees the full batch, END what was pushed (the kept-next task
+    # popped): the same list, as the sites' subscribers expect
+    with pins.span("core:schedule", es, batch, pool=getattr(tp, "taskpool_id", 0),
+                   rank=context.rank, n=len(batch)):
+        if es is not None and es.next_task is None and distance == 0:
+            best = max(range(len(batch)), key=lambda i: batch[i].priority)
+            es.next_task = batch.pop(best)
+        if batch:
+            context.scheduler.schedule(es, batch, distance)
+            # only a task actually pushed to the scheduler warrants waking
+            # the idle threads: a kept-next successor is run by THIS
+            # worker, and waking everyone per completion makes the idle
+            # pack churn the GIL against the running worker's async
+            # device dispatch
+            context._notify_work()
 
 
 def execute(context: "Context", es: "ExecutionStream", task: "Task") -> HookReturn:
@@ -83,26 +86,28 @@ def complete_execution(context: "Context", es: Optional["ExecutionStream"], task
     task.status = TaskStatus.PREPARE_OUTPUT
     if tc.prepare_output is not None:
         tc.prepare_output(es, task)
-    pins.fire(pins.COMPLETE_EXEC_BEGIN, es, task)
-    task.status = TaskStatus.COMPLETE
-    if tc.complete_execution is not None:
-        tc.complete_execution(es, task)
-    ready: Iterable["Task"] = ()
-    if tc.release_deps is not None:
-        pins.fire(pins.RELEASE_DEPS_BEGIN, es, task)
-        ready = tc.release_deps(es, task) or ()
-        # payload carries (task, released successors): the DOT grapher and
-        # iterator checkers consume the edge list
-        pins.fire(pins.RELEASE_DEPS_END, es, (task, ready))
-    if task.on_complete is not None:
-        task.on_complete(task)
-    if tc.release_task is not None:
-        tc.release_task(task)
-    pins.fire(pins.COMPLETE_EXEC_END, es, task)
+    tp = task.taskpool
+    # (a stand-in pool of the native path or of a test has no id)
+    pool, rank = getattr(tp, "taskpool_id", 0), context.rank
+    with pins.span("core:complete_exec", es, task, pool=pool, rank=rank):
+        task.status = TaskStatus.COMPLETE
+        if tc.complete_execution is not None:
+            tc.complete_execution(es, task)
+        ready: Iterable["Task"] = ()
+        if tc.release_deps is not None:
+            with pins.span("core:release_deps", es, task, pool=pool,
+                           rank=rank) as sp:
+                ready = tc.release_deps(es, task) or ()
+                # END carries (task, released successors): the DOT
+                # grapher and iterator checkers consume the edge list
+                sp.end((task, ready))
+        if task.on_complete is not None:
+            task.on_complete(task)
+        if tc.release_task is not None:
+            tc.release_task(task)
     if task.selected_device is not None:
         task.selected_device.sub_load(task.prof.get("est", 0.0))
         task.selected_device.stats["executed_tasks"] += 1
-    tp = task.taskpool
     task.retired = True
     schedule_ready(context, es, ready)
     tp.task_done(task)
@@ -137,9 +142,10 @@ def task_progress(context: "Context", es: "ExecutionStream", task: "Task") -> Ho
     tc = task.task_class
     task.status = TaskStatus.PREPARE_INPUT
     if tc.prepare_input is not None:
-        pins.fire(pins.PREPARE_INPUT_BEGIN, es, task)
-        rc = tc.prepare_input(es, task)
-        pins.fire(pins.PREPARE_INPUT_END, es, task)
+        with pins.span("core:prepare_input", es, task,
+                       pool=getattr(task.taskpool, "taskpool_id", 0),
+                       rank=context.rank):
+            rc = tc.prepare_input(es, task)
         if rc == HookReturn.ASYNC:
             return rc  # awaiting data (reshape future / remote arrival)
         if rc == HookReturn.AGAIN:

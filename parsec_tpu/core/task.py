@@ -144,6 +144,7 @@ class Task:
         "_tpu_completed",
         "_tpu_attempts",
         "_tpu_effects",
+        "_tpu_enq",
     )
 
     def __init__(
@@ -189,6 +190,9 @@ class Task:
         #: retired the task (guards the manager's error-containment fallback
         #: against double-completion)
         self._tpu_completed = False
+        #: ``perf_counter_ns`` at the moment the device module queued the
+        #: task (its ready-queue wait: the ``waited_us`` of ``dev:wave``)
+        self._tpu_enq = 0
 
     @property
     def key(self) -> Any:

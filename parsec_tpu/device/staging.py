@@ -68,10 +68,12 @@ class _StageJob:
     """One prestage request: a ready batch whose input tiles the lane
     stages while earlier waves compute."""
 
-    __slots__ = ("batch", "done", "error")
+    __slots__ = ("batch", "seq", "done", "error")
 
-    def __init__(self, batch: List[Any]):
+    def __init__(self, batch: List[Any], seq: int = 0):
         self.batch = batch
+        #: the pump's number for this batch (the lane's span carries it)
+        self.seq = seq
         self.done = threading.Event()
         self.error: Optional[BaseException] = None
 
@@ -98,8 +100,8 @@ class StageLane:
             target=self._run, name=f"stage-lane:{dev.name}", daemon=True)
         self._thread.start()
 
-    def stage(self, batch: List[Any]) -> _StageJob:
-        job = _StageJob(batch)
+    def stage(self, batch: List[Any], seq: int = 0) -> _StageJob:
+        job = _StageJob(batch, seq)
         with self._cv:
             if self._stop:
                 job.done.set()  # closed lane: submit path stages
@@ -117,7 +119,7 @@ class StageLane:
                     return
                 job = self._jobs.popleft()
             try:
-                self._dev.prestage_batch(job.batch)
+                self._dev.prestage_batch(job.batch, job.seq)
             except BaseException as e:  # must never kill the lane
                 job.error = e
             finally:
@@ -163,6 +165,8 @@ class WritebackCommitter:
             help="max tiles per committer drain batch (one device sync "
                  "+ coalesced D2H gets per batch)")))
         self._tickets = itertools.count(1)
+        #: (pool, batch) of the newest enqueue: the cause a commit names
+        self._cause = (0, 0)
         self._kick = False
         self._flushing = False
         self._stop = False
@@ -175,14 +179,17 @@ class WritebackCommitter:
         self._thread.start()
 
     # -- producer side ---------------------------------------------------
-    def enqueue(self, data) -> int:
-        """Queue a deferred write-back of ``data``'s dirty device copy.
+    def enqueue(self, data, pool: int = 0, batch: int = 0) -> int:
+        """Queue a deferred write-back of ``data``'s dirty device copy
+        (``pool`` and ``batch``: the batch whose epilog wrote it, which
+        the ``dev:writeback`` span of the commit names as its cause).
         Deduplicated per tile; bounded by a capacity wait at 4x the
         drain watermark so a stalled committer applies backpressure
         instead of accumulating unbounded dirty state.  Raises the
         stored committer error if the committer died — the caller's
         fail-loudly discipline turns that into a pool failure."""
         ticket = next(self._tickets)
+        self._cause = (pool, batch)
         if pins.active(pins.HB_WB_ENQUEUE):
             # release edge: the enqueuing thread just committed this
             # task's epilog — its clock must reach the commit
@@ -335,29 +342,24 @@ class WritebackCommitter:
             tickets.extend(tks)
         if not snaps:
             return
-        total = sum(int(getattr(p, "nbytes", 0)) for (_d, p, _v) in snaps)
-        span = pins.active(pins.WRITEBACK_BEGIN)
-        if span:
-            info = {"rank": getattr(dev.context, "rank", 0),
-                    "id": next(_SPAN_SEQ), "tiles": len(snaps),
-                    "bytes": total}
-            pins.fire(pins.WRITEBACK_BEGIN, None, info)
-            t0 = time.perf_counter()
-        hosts = dev._d2h_batch([p for (_d, p, _v) in snaps])
-        for (data, _payload, version), host in zip(snaps, hosts):
-            # host is None: a donating task consumed that version
-            if host is not None and dev._commit_host(data, version, host):
-                self.stats["committed"] += 1
-            else:
-                self.stats["dropped_stale"] += 1
-        if pins.active(pins.HB_WB_COMMIT) and tickets:
-            # acquire edge: the committer joins every enqueue that fed
-            # this batch — exec happens-before write-back commit
-            pins.fire(pins.HB_WB_COMMIT, None, {"tickets": tickets})
-        if span:
-            info = dict(info)
-            info["seconds"] = time.perf_counter() - t0
-            pins.fire(pins.WRITEBACK_END, None, info)
+        pool, batch = self._cause  # the newest; earlier ones ride along
+        with pins.span("dev:writeback", pool=pool,
+                       rank=getattr(dev.context, "rank", 0),
+                       id=next(_SPAN_SEQ), tiles=len(snaps), batch=batch,
+                       bytes=sum(int(getattr(p, "nbytes", 0))
+                                 for (_d, p, _v) in snaps)):
+            hosts = dev._d2h_batch([p for (_d, p, _v) in snaps])
+            for (data, _payload, version), host in zip(snaps, hosts):
+                # host is None: a donating task consumed that version
+                if host is not None and dev._commit_host(data, version,
+                                                         host):
+                    self.stats["committed"] += 1
+                else:
+                    self.stats["dropped_stale"] += 1
+            if pins.active(pins.HB_WB_COMMIT) and tickets:
+                # acquire edge: the committer joins every enqueue that
+                # fed this batch — exec happens-before write-back commit
+                pins.fire(pins.HB_WB_COMMIT, None, {"tickets": tickets})
         self.stats["batches"] += 1
 
     def close(self, flush: bool = True) -> None:

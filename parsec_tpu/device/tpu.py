@@ -38,6 +38,7 @@ from __future__ import annotations
 import collections
 import contextlib
 import threading
+import time
 import weakref
 from typing import Any, Deque, Dict, List, Optional, Tuple
 
@@ -94,6 +95,12 @@ def private_device_put(x, jdev=None, *, guard=None):
     if guard is None:
         return arr
     return _unalias(arr, x, guard, jdev)
+
+
+def _pool_of(task: Task) -> int:
+    """The ``pool`` a span of ``task`` carries: its taskpool's id (0 for
+    a stand-in pool that has none)."""
+    return getattr(task.taskpool, "taskpool_id", 0)
 
 
 class _InFlight:
@@ -172,6 +179,13 @@ class TpuDevice(Device):
                     "set device_tpu_hbm_budget_mb explicitly")
             self.hbm_budget = int(limit * 0.85) if limit else 4 << 30
         self.hbm_used = 0
+        #: what this module's spans carry (``docs/TRACING.md``): the
+        #: context's rank, and the pool and number of the newest batch
+        #: submitted — a span on the committer thread names them as the
+        #: batch that caused it
+        self._rank = getattr(context, "rank", 0)
+        self._span_pool = 0
+        self._span_batch = 0
         #: device index used in Data.copies — assigned at attach
         self.data_index = index
         self.gflops_rating = 100.0  # strongly favour the MXU for eligible tasks
@@ -301,6 +315,11 @@ class TpuDevice(Device):
         #: synchronous fallback (satellite: capacity wait, not a hang)
         self._wb_wait = 60.0
 
+    def _span(self, name: str, **info):
+        """A ``pins.span`` of this module, with the ``pool`` and ``rank``
+        every span carries."""
+        return pins.span(name, pool=self._span_pool, rank=self._rank, **info)
+
     @property
     def hbm_budget(self) -> int:
         return self._hbm_budget
@@ -331,6 +350,7 @@ class TpuDevice(Device):
     def kernel_scheduler(self, es, task: Task) -> HookReturn:
         """Reference ``parsec_device_kernel_scheduler``
         (device_gpu.c:2510-2730)."""
+        task._tpu_enq = time.perf_counter_ns()  # ready-queue wait starts
         with self._lock:
             self._pending.append(task)
             if self._manager_active:
@@ -357,24 +377,9 @@ class TpuDevice(Device):
             with self._lock:
                 while self._pending:
                     drained.append(self._pending.popleft())
-            # one O(n) bucketing pass: signature computed ONCE per task,
-            # waves emitted in arrival order of their first member
-            units: List[Tuple[str, Any]] = []
-            buckets: Dict[Any, List[Task]] = {}
-            for task in drained:
-                if getattr(task.taskpool, "failed", False):
-                    continue  # pool already failed: discard, never execute
-                sig = (self._wave_signature(task)
-                       if self._wave_min > 0 else None)
-                if sig is None:
-                    units.append(("single", task))
-                    continue
-                key = (id(task.taskpool), sig)
-                group = buckets.get(key)
-                if group is None:
-                    group = buckets[key] = []
-                    units.append(("wave", group))
-                group.append(task)
+            drained_ns = time.perf_counter_ns()  # ready-queue wait ends
+            self._span_batch += 1
+            units = self._units_of(drained)
             # completions issued below run release_deps inline: a
             # coalescing window batches every activation this drained
             # batch produces into one frame per destination rank (the
@@ -384,30 +389,10 @@ class TpuDevice(Device):
             win = comm.coalesce() if comm is not None \
                 else contextlib.nullcontext()
             with win:
-                for kind, item in units:
-                    if kind == "single":
-                        self._submit_one(item, es)
-                        continue
-                    group = item
-                    if len(group) >= max(2, self._wave_min):
-                        try:
-                            self._submit_wave(group, es)
-                            continue
-                        except Exception as e:
-                            # only pre-dispatch failures escape _submit_wave
-                            # (staging/trace/enqueue — no task side effects
-                            # yet); per-task epilog/completion errors are
-                            # contained inside it with a loud pool fail
-                            self.stats["wave_fallbacks"] += 1
-                            debug.warning(
-                                "wave submit of %d tasks failed (%s); "
-                                "falling back per-task", len(group), e)
-                    for t in group:
-                        if not getattr(t, "_tpu_completed", False) \
-                                and not getattr(t.taskpool, "failed", False):
-                            self._submit_one(t, es)
+                self._submit_units(units, es, True, drained_ns)
             # phase: get_data_out — retire ready computations in order
-            progressed = self._poll_lanes(es)
+            with self._span("dev:poll"):
+                progressed = self._poll_lanes(es)
             with self._lock:
                 if not self._pending and all(not l for l in self._lanes):
                     self._manager_active = False
@@ -417,23 +402,16 @@ class TpuDevice(Device):
                 # (the reference polls events; jax lets us wait cheaply)
                 oldest = next((l[0] for l in self._lanes if l), None)
                 if oldest is not None:
-                    try:
-                        oldest.outputs[0].block_until_ready()
-                    except Exception:
-                        pass
+                    with self._span("dev:block"):
+                        try:
+                            oldest.outputs[0].block_until_ready()
+                        except Exception:
+                            pass
 
-    # ------------------------------------------------------------------
-    # pump-mode batch dispatch (native scheduler, zero-entry lifecycle)
-    # ------------------------------------------------------------------
-    def submit_batch(self, tasks: List[Task], es=None) -> None:
-        """Dispatch one native-popped ready batch synchronously WITHOUT
-        per-task completion: the pump loop (dsl.native_exec) retires the
-        whole batch afterwards with one ``pz_graph_done_batch`` call, so
-        successor release happens in the native engine, not here.  The
-        execution side — staging, wave grouping, JIT dispatch, epilog,
-        failure discipline — is the manager loop's, reused with
-        ``complete=False``; only ``scheduling.complete_execution`` /
-        ``on_complete`` are skipped."""
+    def _units_of(self, tasks: List[Task]) -> List[Tuple[str, Any]]:
+        """One O(n) bucketing pass: the signature computed ONCE per task,
+        waves emitted in arrival order of their first member; tasks of a
+        pool that already failed are discarded, never executed."""
         units: List[Tuple[str, Any]] = []
         buckets: Dict[Any, List[Task]] = {}
         for task in tasks:
@@ -450,16 +428,24 @@ class TpuDevice(Device):
                 group = buckets[key] = []
                 units.append(("wave", group))
             group.append(task)
+        return units
+
+    def _submit_units(self, units: List[Tuple[str, Any]], es,
+                      complete: bool, drained_ns: int = 0) -> None:
         for kind, item in units:
             if kind == "single":
-                self._submit_one(item, es, complete=False)
+                self._submit_one(item, es, complete, drained_ns)
                 continue
             group = item
             if len(group) >= max(2, self._wave_min):
                 try:
-                    self._submit_wave(group, es, complete=False)
+                    self._submit_wave(group, es, complete, drained_ns)
                     continue
                 except Exception as e:
+                    # only pre-dispatch failures escape _submit_wave
+                    # (staging/trace/enqueue — no task side effects
+                    # yet); per-task epilog/completion errors are
+                    # contained inside it with a loud pool fail
                     self.stats["wave_fallbacks"] += 1
                     debug.warning(
                         "wave submit of %d tasks failed (%s); "
@@ -467,20 +453,41 @@ class TpuDevice(Device):
             for t in group:
                 if not getattr(t, "_tpu_completed", False) \
                         and not getattr(t.taskpool, "failed", False):
-                    self._submit_one(t, es, complete=False)
-        # a transient-submit retry re-queues through ``_pending`` (the
-        # manager loop's channel); there is no manager in pump mode, so
-        # drain retries here before handing the batch back for retirement
-        while True:
-            with self._lock:
-                if not self._pending:
-                    return
-                retry = list(self._pending)
-                self._pending.clear()
-            for t in retry:
-                if not getattr(t, "_tpu_completed", False) \
-                        and not getattr(t.taskpool, "failed", False):
-                    self._submit_one(t, es, complete=False)
+                    self._submit_one(t, es, complete, drained_ns)
+
+    # ------------------------------------------------------------------
+    # pump-mode batch dispatch (native scheduler, zero-entry lifecycle)
+    # ------------------------------------------------------------------
+    def submit_batch(self, tasks: List[Task], es=None,
+                     batch_no: int = 0) -> None:
+        """Dispatch one native-popped ready batch synchronously WITHOUT
+        per-task completion: the pump loop (dsl.native_exec) retires the
+        whole batch afterwards with one ``pz_graph_done_batch`` call, so
+        successor release happens in the native engine, not here.  The
+        execution side — staging, wave grouping, JIT dispatch, epilog,
+        failure discipline — is the manager loop's, reused with
+        ``complete=False``; only ``scheduling.complete_execution`` /
+        ``on_complete`` are skipped.  ``batch_no`` is the pump's number
+        for the batch: the ``dev:submit_batch`` span carries it."""
+        if tasks:
+            self._span_pool = _pool_of(tasks[0])
+        self._span_batch = batch_no
+        with self._span("dev:submit_batch", batch=batch_no, n=len(tasks)):
+            self._submit_units(self._units_of(tasks), es, False)
+            # a transient-submit retry re-queues through ``_pending``
+            # (the manager loop's channel); there is no manager in pump
+            # mode, so drain retries here before handing the batch back
+            # for retirement
+            while True:
+                with self._lock:
+                    if not self._pending:
+                        return
+                    retry = list(self._pending)
+                    self._pending.clear()
+                for t in retry:
+                    if not getattr(t, "_tpu_completed", False) \
+                            and not getattr(t.taskpool, "failed", False):
+                        self._submit_one(t, es, complete=False)
 
     @staticmethod
     def _fire_exec(task: Task, site: str, wave: int = 0) -> None:
@@ -518,17 +525,27 @@ class TpuDevice(Device):
         """One compile path for every device program: the in-device
         ``_jit_cache`` keeps the fast id-keyed lookup the dispatch loop
         had, while the executable cache behind it adds the persistent
-        disk store and the cross-rank compile broadcast."""
-        jitted = self._jit_cache.get(local_key)
-        if jitted is None:
-            jitted = self._jit_cache[local_key] = self._ccache.jit(
-                fn, key=content_key, donate_argnums=tuple(donate))
+        disk store and the cross-rank compile broadcast.  The
+        ``dev:jit`` span notes ``miss=1`` on a program's first use by
+        this device; the compile or load itself comes at its first call,
+        as a ``cc:compile`` span under ``dev:dispatch``."""
+        with self._span("dev:jit") as sp:
+            jitted = self._jit_cache.get(local_key)
+            if jitted is None:
+                sp.note(miss=1)
+                jitted = self._jit_cache[local_key] = self._ccache.jit(
+                    fn, key=content_key, donate_argnums=tuple(donate))
         return jitted
 
-    def _submit_one(self, task: Task, es, complete: bool = True) -> None:
+    def _submit_one(self, task: Task, es, complete: bool = True,
+                    drained_ns: int = 0) -> None:
         """Per-task submit with the retry/fail-loudly discipline."""
+        self._span_pool = _pool_of(task)
+        waited = (drained_ns - task._tpu_enq) // 1000 if drained_ns else 0
         try:
-            self._submit(task, es, complete=complete)
+            with self._span("dev:submit_one", cls=task.task_class.name, n=1,
+                            batch=self._span_batch, waited_us=waited):
+                self._submit(task, es, complete=complete)
         except Exception as e:
             debug.error("tpu submit of %r failed: %s", task, e)
             import traceback
@@ -556,6 +573,7 @@ class TpuDevice(Device):
                                              False):
                 debug.warning("retrying device submit of %r", task)
                 self.stats["submit_retries"] += 1
+                task._tpu_enq = time.perf_counter_ns()
                 with self._lock:
                     self._pending.append(task)
                 return
@@ -629,8 +647,8 @@ class TpuDevice(Device):
                 sig.append((kind,))
         return tuple(sig)
 
-    def _submit_wave(self, tasks: List[Task], es,
-                     complete: bool = True) -> None:
+    def _submit_wave(self, tasks: List[Task], es, complete: bool = True,
+                     drained_ns: int = 0) -> None:
         """Submit a same-signature ready wave as one (or a few
         power-of-2) jitted multi-body programs: ONE device enqueue per
         chunk instead of one per task (round-4 VERDICT #6).
@@ -653,61 +671,79 @@ class TpuDevice(Device):
         fail (the same discipline as ``_submit_one``'s completed
         branch): a half-committed task must be neither retried
         (double-apply) nor silently skipped (wait() would hang to
-        timeout)."""
-        from ..core import scheduling
+        timeout).
 
+        One ``dev:wave`` span per chunk, that is per device program,
+        with the children ``dev:stage_args``, ``dev:jit``,
+        ``dev:dispatch`` (the host's enqueue of the program, not the
+        chip's execution of it) and ``dev:epilog``."""
         body = tasks[0].selected_chore.body_fn
+        cls = tasks[0].task_class.name
+        self._span_pool = _pool_of(tasks[0])
         # the body OBJECT (not id(body)): an id-keyed entry outlives the
         # body it described, and a recycled id would serve a dead body's
         # wave program — keying on the object pins it alive instead,
         # matching the per-task path below
         base_key = getattr(body, "_jit_key", None) or body
-        arity: Optional[int] = None
-        nout: Optional[int] = None
         start = 0
         remaining = len(tasks)
         while remaining:
             cnt = 1 << (remaining.bit_length() - 1)  # largest pow2 chunk
             grp = tasks[start:start + cnt]
+            start += cnt
+            remaining -= cnt
+            waited = sum(drained_ns - t._tpu_enq
+                         for t in grp) // 1000 if drained_ns else 0
+            with self._span("dev:wave", cls=cls, n=cnt,
+                            batch=self._span_batch, waited_us=waited):
+                self._submit_chunk(grp, body, base_key, es, complete)
+
+    def _submit_chunk(self, grp: List[Task], body, base_key, es,
+                      complete: bool) -> None:
+        """One power-of-2 chunk of a wave: stage, look the program up,
+        dispatch it, commit every task's outputs."""
+        from ..core import scheduling
+
+        cnt = len(grp)
+        with self._span("dev:stage_args") as sp:
+            tally = [0, 0, 0]  # host tiles, their bytes, tiles staged
             if self.stage_depth > 1:
                 # tentpole (c): coalesce this chunk's host->device tile
                 # transfers into one batched put; staging stays PER
                 # CHUNK (PR 1 invariant above), and _stage_task_args
                 # below finds the tiles already resident so the per-tile
                 # path degenerates to cache hits
-                self._stage_in_batch(self._collect_stage_tiles(grp))
-            gst = [self._stage_task_args(t, body) for t in grp]
-            if arity is None:
-                arity = len(gst[0][0])
-                nout = len(gst[0][1])
-            start += cnt
-            remaining -= cnt
-            def _wave(*flat, _body=body, _arity=arity, _cnt=cnt):
-                outs: List[Any] = []
-                for t in range(_cnt):
-                    o = _body(*flat[t * _arity:(t + 1) * _arity])
-                    outs.extend(o if isinstance(o, (tuple, list))
-                                else (o,))
-                return tuple(outs)
-            jitted = self._cached_jit(
-                ("wave", base_key, arity, nout, cnt),
-                ("wave", self._content_fp(body), arity, nout, cnt),
-                _wave)
-            flat = [a for (dargs, _, _) in gst for a in dargs]
-            for t in grp:
-                self._fire_exec(t, pins.EXEC_BEGIN, wave=cnt)
+                self._stage_in_batch(self._collect_stage_tiles(grp), tally)
+            gst = [self._stage_task_args(t, body, tally) for t in grp]
+            sp.note(host_tiles=tally[0], bytes=tally[1], tiles=tally[2])
+        arity, nout = len(gst[0][0]), len(gst[0][1])
+
+        def _wave(*flat, _body=body, _arity=arity, _cnt=cnt):
+            outs: List[Any] = []
+            for t in range(_cnt):
+                o = _body(*flat[t * _arity:(t + 1) * _arity])
+                outs.extend(o if isinstance(o, (tuple, list))
+                            else (o,))
+            return tuple(outs)
+        jitted = self._cached_jit(
+            ("wave", base_key, arity, nout, cnt),
+            ("wave", self._content_fp(body), arity, nout, cnt),
+            _wave)
+        flat = [a for (dargs, _, _) in gst for a in dargs]
+        for t in grp:
+            self._fire_exec(t, pins.EXEC_BEGIN, wave=cnt)
+        with self._span("dev:dispatch"):
             outs = jitted(*flat)
-            for t in grp:
-                self._fire_exec(t, pins.EXEC_END, wave=cnt)
-            if len(outs) != nout * cnt:
-                raise ValueError(
-                    f"wave of {tasks[0].task_class.name}: bodies returned "
-                    f"{len(outs)} outputs for {nout * cnt} writable flows")
-            self.stats["wave_submits"] = self.stats.get("wave_submits",
-                                                        0) + 1
-            self.stats["wave_tasks"] = self.stats.get("wave_tasks",
-                                                      0) + cnt
-            pos = 0
+        for t in grp:
+            self._fire_exec(t, pins.EXEC_END, wave=cnt)
+        if len(outs) != nout * cnt:
+            raise ValueError(
+                f"wave of {grp[0].task_class.name}: bodies returned "
+                f"{len(outs)} outputs for {nout * cnt} writable flows")
+        self.stats["wave_submits"] = self.stats.get("wave_submits", 0) + 1
+        self.stats["wave_tasks"] = self.stats.get("wave_tasks", 0) + cnt
+        pos = 0
+        with self._span("dev:epilog"):
             for task, (dargs, ospecs, ohooks) in zip(grp, gst):
                 inflight = _InFlight(task, list(outs[pos:pos + nout]),
                                      ospecs, ohooks)
@@ -735,11 +771,14 @@ class TpuDevice(Device):
                     lane.append(inflight)
                     task._tpu_completed = True  # owned by the lane now
 
-    def _stage_task_args(self, task: Task, body):
+    def _stage_task_args(self, task: Task, body,
+                         tally: Optional[List[int]] = None):
         """kernel_push: stage every flow of ``task`` onto this device and
         return ``(dev_args, out_specs, out_hooks)`` (reference
         device_gpu.c:2015-2164 stage-in phase, factored out so the wave
-        path shares it)."""
+        path shares it).  ``tally`` counts for the ``dev:stage_args``
+        span: ``[tiles copied from the host, their bytes, tiles
+        staged]``."""
         # per-flow custom staging (reference stage_in/stage_out device
         # hooks, device_gpu.h:62-94), keyed by data-arg order
         si_hooks = getattr(body, "_stage_in", None) or {}
@@ -774,7 +813,9 @@ class TpuDevice(Device):
                     # transfer (reference skips stage-in for OUT-only flows)
                     arr = self._out_placeholder(payload)
                 else:
-                    arr = self._stage_in(payload)
+                    arr = self._stage_in(payload, tally)
+                if tally is not None:
+                    tally[2] += 1
                 payload.transfer_ownership(self.data_index, rw)
                 dev_args.append(arr)
                 if mode & AccessMode.OUT:
@@ -794,7 +835,11 @@ class TpuDevice(Device):
         if body is None:
             # DTD/PTG store the raw device body on the chore at build time
             raise RuntimeError(f"chore of {task!r} has no body_fn for device execution")
-        dev_args, out_specs, out_hooks = self._stage_task_args(task, body)
+        with self._span("dev:stage_args") as sp:
+            tally = [0, 0, 0]
+            dev_args, out_specs, out_hooks = self._stage_task_args(
+                task, body, tally)
+            sp.note(host_tiles=tally[0], bytes=tally[1], tiles=tally[2])
 
         base_key = getattr(body, "_jit_key", body)
         # opt-in body attributes (set by the DSL body author):
@@ -840,7 +885,8 @@ class TpuDevice(Device):
             # buffers: the task is no longer safely retryable
             task._tpu_effects = bool(donate)
             self._fire_exec(task, pins.EXEC_BEGIN)
-            outputs = jitted(*arr_args)
+            with self._span("dev:dispatch"):
+                outputs = jitted(*arr_args)
             self._fire_exec(task, pins.EXEC_END)
         else:
             # fused supertasks carry an explicit content key (member body
@@ -867,7 +913,8 @@ class TpuDevice(Device):
                 body, donate=donate)
             task._tpu_effects = bool(donate)
             self._fire_exec(task, pins.EXEC_BEGIN)
-            outputs = jitted(*dev_args)
+            with self._span("dev:dispatch"):
+                outputs = jitted(*dev_args)
             self._fire_exec(task, pins.EXEC_END)
         if not isinstance(outputs, (tuple, list)):
             outputs = (outputs,)
@@ -884,10 +931,11 @@ class TpuDevice(Device):
             # the epilog mutates output tiles one by one (rebind +
             # version bump): once entered, a retry would double-apply
             task._tpu_effects = True
-            self._epilog(inflight)
-            task._tpu_completed = True
-            if complete:
-                scheduling.complete_execution(self.context, es, task)
+            with self._span("dev:epilog"):
+                self._epilog(inflight)
+                task._tpu_completed = True
+                if complete:
+                    scheduling.complete_execution(self.context, es, task)
             return
         lane = self._lanes[self._rr % self._nlanes]
         self._rr += 1
@@ -946,7 +994,8 @@ class TpuDevice(Device):
             self._lru_touch(data, dirty=False)
             return arr
 
-    def _stage_in(self, data: Data) -> Any:
+    def _stage_in(self, data: Data,
+                  tally: Optional[List[int]] = None) -> Any:
         """Materialize the newest version of ``data`` on this device."""
         with self._res_lock:
             mine = data.get_copy(self.data_index)
@@ -973,12 +1022,17 @@ class TpuDevice(Device):
                 self.stats["bytes_d2d"] += newest.payload.nbytes
             else:
                 host = np.asarray(newest.payload)
-                self._hbm_realloc(data, old, host.nbytes)
-                # guard: the host copy RETAINS this buffer at version v — a
-                # zero-copy put followed by a donating task would overwrite
-                # it in place while its version still claims v
-                arr = private_device_put(host, self.jdev, guard=host)
+                with self._span("dev:h2d", tiles=1, bytes=host.nbytes):
+                    self._hbm_realloc(data, old, host.nbytes)
+                    # guard: the host copy RETAINS this buffer at version
+                    # v — a zero-copy put followed by a donating task
+                    # would overwrite it in place while its version still
+                    # claims v
+                    arr = private_device_put(host, self.jdev, guard=host)
                 self.stats["bytes_in"] += host.nbytes
+                if tally is not None:
+                    tally[0] += 1
+                    tally[1] += host.nbytes
             c = data.attach_copy(self.data_index, arr)
             c.version = newest.version
             self._lru_touch(data, dirty=False)
@@ -1013,12 +1067,14 @@ class TpuDevice(Device):
                 out.append(payload)
         return out
 
-    def _stage_in_batch(self, datas: List[Data]) -> int:
+    def _stage_in_batch(self, datas: List[Data],
+                        tally: Optional[List[int]] = None) -> int:
         """Batched :meth:`_stage_in`: resident tiles are touched, stale
         host-side tiles are coalesced into ONE ``jax.device_put`` call
         (tentpole (c) — one enqueue RPC for the wave's transfers instead
         of one per tile), each result re-checked against the per-tile
-        aliasing guard.  Returns bytes moved host->device."""
+        aliasing guard.  Returns bytes moved host->device; the put is
+        the ``dev:h2d`` span."""
         moved = 0
         with self._res_lock:
             puts: List[Tuple[Data, np.ndarray, int]] = []
@@ -1052,17 +1108,22 @@ class TpuDevice(Device):
                 self._hbm_realloc(data, old, host.nbytes)
                 puts.append((data, host, newest.version))
             if puts:
-                try:
-                    arrs = jax.device_put([h for (_d, h, _v) in puts],
-                                          self.jdev)
-                except Exception:
-                    # backend rejected the coalesced put: per-tile path
-                    self.stats["stage_batch_fallbacks"] += 1
-                    arrs = [private_device_put(h, self.jdev, guard=h)
-                            for (_d, h, _v) in puts]
-                else:
-                    arrs = [_unalias(a, h, h, self.jdev)
-                            for a, (_d, h, _v) in zip(arrs, puts)]
+                nbytes = sum(h.nbytes for (_d, h, _v) in puts)
+                with self._span("dev:h2d", tiles=len(puts), bytes=nbytes):
+                    try:
+                        arrs = jax.device_put([h for (_d, h, _v) in puts],
+                                              self.jdev)
+                    except Exception:
+                        # backend rejected the coalesced put: per-tile path
+                        self.stats["stage_batch_fallbacks"] += 1
+                        arrs = [private_device_put(h, self.jdev, guard=h)
+                                for (_d, h, _v) in puts]
+                    else:
+                        arrs = [_unalias(a, h, h, self.jdev)
+                                for a, (_d, h, _v) in zip(arrs, puts)]
+                if tally is not None:
+                    tally[0] += len(puts)
+                    tally[1] += nbytes
                 for (data, host, ver), arr in zip(puts, arrs):
                     self.stats["bytes_in"] += host.nbytes
                     c = data.attach_copy(self.data_index, arr)
@@ -1094,33 +1155,23 @@ class TpuDevice(Device):
             total += int(getattr(newest.payload, "nbytes", 0))
         return total
 
-    def prestage_batch(self, tasks: List[Task]) -> None:
+    def prestage_batch(self, tasks: List[Task], batch_no: int = 0) -> None:
         """Transfer-lane half of the double-buffered pipeline: stage the
         NEXT ready batch's input tiles while the current wave computes,
-        so the pump's submit pass reuse-hits them.  Fired as a
-        ``stage_in`` span (critpath's transfer bucket) and publishes the
-        lane's clock into each task's hb token — stage_in happens-before
-        exec."""
+        so the pump's submit pass reuse-hits them.  A ``dev:stage_in``
+        span (critpath's transfer bucket; ``batch`` is the pump's number
+        for the batch) and publishes the lane's clock into each task's hb
+        token — stage_in happens-before exec."""
         from .staging import _SPAN_SEQ
 
         datas = self._collect_stage_tiles(tasks)
-        span = pins.active(pins.STAGE_IN_BEGIN)
-        if span:
-            import time
-
-            info = {"rank": getattr(self.context, "rank", 0),
-                    "id": next(_SPAN_SEQ), "tiles": len(datas),
-                    "bytes": 0}
-            pins.fire(pins.STAGE_IN_BEGIN, None, info)
-            t0 = time.perf_counter()
-        moved = self._stage_in_batch(datas)
+        if tasks:  # the lane runs ahead of the batch's own submit
+            self._span_pool = _pool_of(tasks[0])
+        with self._span("dev:stage_in", id=next(_SPAN_SEQ),
+                        tiles=len(datas), batch=batch_no) as sp:
+            sp.note(bytes=self._stage_in_batch(datas))
         self.stats["prefetched_tiles"] = \
             self.stats.get("prefetched_tiles", 0) + len(datas)
-        if span:
-            info = dict(info)
-            info["bytes"] = moved
-            info["seconds"] = time.perf_counter() - t0
-            pins.fire(pins.STAGE_IN_END, None, info)
         if pins.active(pins.HB_STAGE_IN):
             for task in tasks:
                 pins.fire(pins.HB_STAGE_IN, None, {"task": task})
@@ -1147,7 +1198,8 @@ class TpuDevice(Device):
         raw tile copies.  A no-op in the synchronous regime."""
         com = self._committer
         if com is not None:
-            com.flush(timeout=timeout)
+            with self._span("dev:flush"):
+                com.flush(timeout=timeout)
 
     # ------------------------------------------------------------------
     # HBM budget + dual LRU eviction
@@ -1195,7 +1247,7 @@ class TpuDevice(Device):
         com = self._committer
         if com is not None and com.healthy:
             try:
-                com.enqueue(victim)
+                com.enqueue(victim, self._span_pool, self._span_batch)
             except Exception:
                 # committer died between the check and the enqueue: the
                 # sync fallback still flushes the victim; the sticky
@@ -1359,26 +1411,17 @@ class TpuDevice(Device):
                 snaps.append((d, s[0], s[1]))
         if not snaps:
             return 0
-        span = pins.active(pins.WRITEBACK_BEGIN)
-        if span:
-            import time
-
-            info = {"rank": getattr(self.context, "rank", 0),
-                    "id": next(_SPAN_SEQ), "tiles": len(snaps),
-                    "bytes": sum(int(getattr(p, "nbytes", 0))
-                                 for (_d, p, _v) in snaps)}
-            pins.fire(pins.WRITEBACK_BEGIN, None, info)
-            t0 = time.perf_counter()
-        hosts = self._d2h_batch([p for (_d, p, _v) in snaps])
-        committed = 0
-        for (data, _p, version), host in zip(snaps, hosts):
-            if host is not None and self._commit_host(data, version, host):
-                committed += 1
+        with self._span("dev:writeback", id=next(_SPAN_SEQ),
+                        tiles=len(snaps), batch=self._span_batch,
+                        bytes=sum(int(getattr(p, "nbytes", 0))
+                                  for (_d, p, _v) in snaps)):
+            hosts = self._d2h_batch([p for (_d, p, _v) in snaps])
+            committed = 0
+            for (data, _p, version), host in zip(snaps, hosts):
+                if host is not None and self._commit_host(data, version,
+                                                          host):
+                    committed += 1
         self.stats["wb_batches"] = self.stats.get("wb_batches", 0) + 1
-        if span:
-            info = dict(info)
-            info["seconds"] = time.perf_counter() - t0
-            pins.fire(pins.WRITEBACK_END, None, info)
         return committed
 
     def _lru_touch(self, data: Data, *, dirty: bool) -> None:
@@ -1478,7 +1521,7 @@ class TpuDevice(Device):
             # dirty-resident; detach/flush/eviction carry the final
             # version home through the synchronous guarded path.
             for (_pos, data) in inflight.out_specs:
-                com.enqueue(data)
+                com.enqueue(data, self._span_pool, self._span_batch)
 
     # ------------------------------------------------------------------
     def data_advise(self, data: Data, advice: int) -> None:
@@ -1535,6 +1578,10 @@ class TpuDevice(Device):
         return total
 
     def detach(self) -> None:
+        with self._span("dev:detach"):
+            self._detach()
+
+    def _detach(self) -> None:
         # drain the async committer FIRST: its flush() barrier is what
         # lets host-side readers (detach, redistribute, remote sends)
         # see committed tiles.  A committer that died mid-run surfaces

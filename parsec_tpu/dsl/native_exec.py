@@ -117,6 +117,9 @@ class _NativePoolShim:
     def __init__(self, executor: "NativeExecutor", name: str):
         self._ex = executor
         self.name = name
+        #: the taskpool's id: what every span of this solve carries as
+        #: ``pool`` (the device module reads it off its tasks' pool)
+        self.taskpool_id = executor.taskpool.taskpool_id
         self.failed = False
         self.fail_reason: Optional[str] = None
         self.context = None
@@ -232,7 +235,7 @@ def _pump_failure(shims) -> Optional[str]:
 
 def _pump_loop(ng, dev, pump_index: Dict[int, Any], stats: Dict[str, int],
                shims, ev: Optional[_EventDrain] = None,
-               retire_cb=None) -> int:
+               retire_cb=None, pool: int = 0) -> int:
     """The zero-interpreter hot loop, shared by :class:`NativeExecutor`
     and :class:`NativeServeExecutor`.  Per iteration: ONE ``pop_batch``
     ctypes call returns up to ``runtime_native_drain`` ready native ids,
@@ -253,7 +256,14 @@ def _pump_loop(ng, dev, pump_index: Dict[int, Any], stats: Dict[str, int],
     pop buffer shrinks to ``cap // stage_depth``: one wide ready wave
     splits into ``stage_depth`` chunks and pipelines INTRA-wave.  A
     prestage failure is non-fatal — the submit path restages the tile
-    synchronously and fails loudly if the data is truly bad."""
+    synchronously and fails loudly if the data is truly bad.
+
+    Every step is one ``pins.span`` per BATCH (``pump:pop``,
+    ``pump:stage_wait``, ``pump:land``, ``pump:retire``, ``pump:done``,
+    ``pump:events``; ``dev:submit_batch`` is the device module's),
+    carrying ``pool`` (0 when several pools share the pump), ``rank`` and
+    the batch's number ``batch``, which the transfer lane's
+    ``dev:stage_in`` repeats on its thread."""
     import ctypes
     from collections import deque
 
@@ -270,7 +280,9 @@ def _pump_loop(ng, dev, pump_index: Dict[int, Any], stats: Dict[str, int],
         depth = 1
     chunk = max(1, cap // depth) if lane is not None else cap
     free = deque((ctypes.c_int64 * chunk)() for _ in range(depth))
-    window: deque = deque()  # (buf, n, batch, stage_job|None)
+    window: deque = deque()  # (buf, n, batch, stage_job|None, seq)
+    rank = getattr(dev.context, "rank", 0)
+    seq = 0  # number of the next batch handed to the window
     done = 0
     try:
         while True:
@@ -278,13 +290,16 @@ def _pump_loop(ng, dev, pump_index: Dict[int, Any], stats: Dict[str, int],
             # stage-in transfers before the oldest batch submits
             while free and len(window) < depth:
                 buf = free.popleft()
-                n = ng.pop_batch(buf)
+                with pins.span("pump:pop", pool=pool, rank=rank,
+                               batch=seq) as sp:
+                    n = ng.pop_batch(buf)
+                    batch = [pump_index[buf[i]] for i in range(n)]
+                    sp.note(n=n)
                 if n == 0:
                     free.appendleft(buf)
                     break
                 stats["pop_batches"] += 1
                 stats["pumped_tasks"] += n
-                batch = [pump_index[buf[i]] for i in range(n)]
                 if (lane is not None and not window and free and n >= 4
                         and dev.prestage_bytes(batch)
                         >= getattr(dev, "stage_split_bytes", 1 << 18)):
@@ -311,14 +326,16 @@ def _pump_loop(ng, dev, pump_index: Dict[int, Any], stats: Dict[str, int],
                             b[i] = ids[off + i]
                         sub = batch[off:off + k]
                         off += k
-                        window.append((b, k, sub, lane.stage(sub)))
+                        window.append((b, k, sub, lane.stage(sub, seq), seq))
+                        seq += 1
                         stats["prefetched_batches"] += 1
                     continue
                 job = None
                 if lane is not None:
-                    job = lane.stage(batch)
+                    job = lane.stage(batch, seq)
                     stats["prefetched_batches"] += 1
-                window.append((buf, n, batch, job))
+                window.append((buf, n, batch, job, seq))
+                seq += 1
             if not window:
                 why = _pump_failure(shims)
                 if why is not None:
@@ -329,24 +346,32 @@ def _pump_loop(ng, dev, pump_index: Dict[int, Any], stats: Dict[str, int],
                     f"native pump stalled: ready queue empty with {done} "
                     f"retired and {ng.sched_pending()} queued "
                     "(cycle or missing commit?)")
-            buf, n, batch, job = window.popleft()
+            buf, n, batch, job, b = window.popleft()
             if job is not None:
-                job.wait()  # logs prestage errors; submit restages
-            dev.submit_batch(batch)
+                with pins.span("pump:stage_wait", pool=pool, rank=rank,
+                               batch=b, n=n):
+                    job.wait()  # logs prestage errors; submit restages
+            dev.submit_batch(batch, batch_no=b)
             why = _pump_failure(shims)
             if why is not None:
                 raise RuntimeError(f"native device run failed: {why}")
-            for t in batch:
-                for (src, home) in t._wbs:
-                    land_into_home(home, src.newest_copy().payload)
-            scheduling.retire_native(batch, dev)
-            done += ng.done_batch(buf, n)
-            stats["done_batches"] += 1
-            free.append(buf)
-            if retire_cb is not None:
-                retire_cb(batch)
+            with pins.span("pump:land", pool=pool, rank=rank, batch=b, n=n):
+                for t in batch:
+                    for (src, home) in t._wbs:
+                        land_into_home(home, src.newest_copy().payload)
+            with pins.span("pump:retire", pool=pool, rank=rank, batch=b,
+                           n=n):
+                scheduling.retire_native(batch, dev)
+            with pins.span("pump:done", pool=pool, rank=rank, batch=b, n=n):
+                done += ng.done_batch(buf, n)
+                stats["done_batches"] += 1
+                free.append(buf)
+                if retire_cb is not None:
+                    retire_cb(batch)
             if ev is not None:
-                stats["events_drained"] += ev.drain()
+                with pins.span("pump:events", pool=pool, rank=rank,
+                               batch=b):
+                    stats["events_drained"] += ev.drain()
     finally:
         if lane is not None:
             lane.close()
@@ -414,6 +439,14 @@ class NativeExecutor:
             if device is None:
                 self.device = self._make_device()
             self._pool_shim = _NativePoolShim(self, f"native:{tp.ptg.name}")
+        with pins.span("attach:build", pool=tp.taskpool_id, rank=0) as sp:
+            self._attach(tp, graph, fusion)
+            sp.note(tasks=len(self.graph.nodes), regions=len(self._regions))
+
+    def _attach(self, tp: PTGTaskpool, graph: Optional[TaskGraph],
+                fusion: Optional[str]) -> None:
+        """Capture the DAG, partition it into fused regions and build the
+        native graph: the ``attach:build`` span."""
         self.graph = graph if graph is not None else capture(tp, ranks=[0])
         self._new_tiles: Dict[Tuple, np.ndarray] = {}
         self._new_data: Dict[Tuple, Any] = {}
@@ -430,7 +463,8 @@ class NativeExecutor:
         self._regions: List[Any] = []
         self._region_of: Dict[Tuple, Any] = {}
         if self.native_device:
-            self._partition_regions(fusion)
+            with pins.span("attach:partition", pool=tp.taskpool_id, rank=0):
+                self._partition_regions(fusion)
         self._build()
 
     def _partition_regions(self, fusion: Optional[str]) -> None:
@@ -1086,7 +1120,8 @@ class NativeExecutor:
                 int(getattr(t, "fused_n", 1) or 1) for t in batch))
 
         n = _pump_loop(ng, self.device, self._pump_index, self.stats,
-                       (self._pool_shim,), ev, retire_cb)
+                       (self._pool_shim,), ev, retire_cb,
+                       pool=tp.taskpool_id)
         if capture is not None:
             self._certify_drain(capture)
         return n

@@ -52,6 +52,21 @@ PHASES = {0: "B", 1: "E", 2: "i", 3: "C"}
 _PBT_TOKEN_SEQ = itertools.count(1)
 
 
+#: the spans that exist only as :class:`pins.span` (``docs/TRACING.md``,
+#: "Spans on the profiler's clock"), each with the payload field a
+#: record's ``info`` keeps; ``event_id`` is the span's ``batch``.  ONE
+#: table: a span added to the runtime reaches the rank traces, the flight
+#: recorder and the merged timeline by a line here, not by a subscriber.
+SPAN_KEYWORDS = {
+    "attach:build": "tasks", "attach:partition": None,
+    "pump:pop": "n", "pump:stage_wait": "n", "pump:land": "n",
+    "pump:retire": "n", "pump:done": "n", "pump:events": None,
+    "dev:submit_batch": "n", "dev:wave": "n", "dev:submit_one": "n",
+    "dev:stage_args": "host_tiles", "dev:h2d": "bytes", "dev:jit": "miss",
+    "dev:dispatch": None, "dev:epilog": None, "dev:poll": None,
+    "dev:block": None, "dev:flush": None, "dev:detach": None}
+
+
 def _sync_points_for(rank: int):
     """Clock re-sync samples for one rank (lazy import: merge <-> binary
     already import each other lazily in the other direction)."""
@@ -257,7 +272,8 @@ class RankTraceSet:
               # bytes) feed critpath's ``transfer`` bucket; the hb_*
               # instants carry the pipeline's ordering edges
               "stage_in", "writeback",
-              "hb_stage_in", "hb_wb_enqueue", "hb_wb_commit")}
+              "hb_stage_in", "hb_wb_enqueue", "hb_wb_commit",
+              *SPAN_KEYWORDS)}
             for t in self.traces]
         self._steals_seen: Dict[int, int] = {}
         self._subs: List[Any] = []
@@ -653,6 +669,23 @@ class RankTraceSet:
         sub(pins.STAGE_IN_END, stage_cb("stage_in", "end"))
         sub(pins.WRITEBACK_BEGIN, stage_cb("writeback", "begin"))
         sub(pins.WRITEBACK_END, stage_cb("writeback", "end"))
+
+        # the spans of SPAN_KEYWORDS, all through one subscriber: the
+        # payload is the span's keyword arguments
+        def span_cb(name, phase, field):
+            def cb(es, p):
+                tr = self._trace_of(p.get("rank", self.base_rank))
+                if tr is None:
+                    tr = self.traces[0]
+                getattr(tr, phase)(
+                    self._k[tr.rank - self.base_rank][name],
+                    int(p.get("batch", 0)),
+                    int(p.get(field, 0)) if field else 0)
+            return cb
+
+        for name, field in SPAN_KEYWORDS.items():
+            sub(name + "_begin", span_cb(name, "begin", field))
+            sub(name + "_end", span_cb(name, "end", field))
 
         # staging-pipeline hb edges: hb_stage_in's event_id is the TASK
         # token (same space as the exec spans, so the offline analyzer

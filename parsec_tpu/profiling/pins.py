@@ -99,10 +99,11 @@ COMPILE_END = "compile_end"
 # staging-pipeline spans (device/staging.py): one begin/end pair per
 # host->device prefetch batch (STAGE_IN, fired on the transfer lane)
 # and per device->host commit batch (WRITEBACK, fired on the committer
-# thread or around a batched detach flush).  Payload {"rank","id",
-# "tiles","bytes"} (+ "seconds" on END).  Recorded as ``stage_in`` /
-# ``writeback`` spans in binary traces; profiling.critpath attributes
-# gap time under them to the ``transfer`` bucket.
+# thread or around a batched detach flush).  Payload {"pool","rank",
+# "id","tiles","batch"} and "bytes" (STAGE_IN: on END).  Recorded as
+# ``stage_in`` / ``writeback`` spans in binary traces;
+# profiling.critpath attributes gap time under them to the ``transfer``
+# bucket.
 STAGE_IN_BEGIN = "stage_in_begin"
 STAGE_IN_END = "stage_in_end"
 WRITEBACK_BEGIN = "writeback_begin"
@@ -119,6 +120,18 @@ HB_WB_ENQUEUE = "hb_wb_enqueue"
 HB_WB_COMMIT = "hb_wb_commit"
 
 ALL_SITES = [v for k, v in list(globals().items()) if k.isupper() and isinstance(v, str)]
+
+#: spans whose begin/end pair predates :class:`span` keep the site names
+#: their subscribers know; every other span fires ``<name>_begin`` /
+#: ``<name>_end`` (``docs/TRACING.md`` "Spans on the profiler's clock"
+#: lists the names, ``binary.SPAN_KEYWORDS`` records the new ones)
+_LEGACY_SPAN_SITES = {
+    "core:select": "select", "core:prepare_input": "prepare_input",
+    "core:complete_exec": "complete_exec",
+    "core:release_deps": "release_deps", "core:schedule": "schedule",
+    "dev:stage_in": "stage_in", "dev:writeback": "writeback",
+    "cc:compile": "compile", "comm:send": "comm_send",
+    "comm:recv": "comm_recv"}
 
 #: site -> TUPLE of callbacks.  The value is immutable and replaced
 #: wholesale on every (un)subscribe — copy-on-write, so a concurrent
@@ -164,6 +177,107 @@ def fire(site: str, es: Any, payload: Any) -> None:
             from ..utils import debug
 
             debug.warning("pins callback for %s raised: %s", site, e)
+
+
+#: span name -> (profiler name, begin site, end site), built once per name
+_span_names: Dict[str, Tuple[str, str, str]] = {}
+_annotation: Any = None
+
+
+def _tracing() -> bool:
+    """True while a profiler session records host events.  The first
+    call imports jax (``import parsec_tpu`` stays free of it) and rebinds
+    the name to ``TraceAnnotation.is_enabled`` itself."""
+    global _annotation, _tracing
+    from jax.profiler import TraceAnnotation
+
+    _annotation = TraceAnnotation
+    _tracing = TraceAnnotation.is_enabled
+    return _tracing()
+
+
+def _names_of(name: str) -> Tuple[str, str, str]:
+    base = _LEGACY_SPAN_SITES.get(name, name)
+    names = _span_names[name] = ("parsec:" + name, base + "_begin",
+                                 base + "_end")
+    return names
+
+
+class _Span:
+    """A span that at least one sink receives (see :func:`span`)."""
+
+    __slots__ = ("_names", "_es", "_payload", "_ann")
+
+    def __init__(self, name: str, es: Any, payload: Any,
+                 info: Dict[str, Any]):
+        names = self._names = _span_names.get(name) or _names_of(name)
+        self._es = es
+        self._payload = info if payload is None else payload
+        self._ann = _annotation(names[0], **info)
+
+    def __enter__(self) -> "_Span":
+        self._ann.__enter__()
+        if _enabled and _subscribers.get(self._names[1]):
+            fire(self._names[1], self._es, self._payload)
+        return self
+
+    def note(self, **more: Any) -> None:
+        self._ann.set_metadata(**more)
+        if _enabled and isinstance(self._payload, dict):
+            # a fresh dict: a subscriber may have kept BEGIN's
+            self._payload = {**self._payload, **more}
+
+    def end(self, payload: Any) -> None:
+        self._payload = payload
+
+    def __exit__(self, *exc: Any) -> bool:
+        if _enabled and _subscribers.get(self._names[2]):
+            fire(self._names[2], self._es, self._payload)
+        self._ann.__exit__(*exc)
+        return False
+
+
+class _QuietSpan:
+    """What :func:`span` hands out while nobody listens: one shared
+    object, nothing recorded, nobody called."""
+
+    __slots__ = ()
+
+    def __enter__(self) -> "_QuietSpan":
+        return self
+
+    def note(self, **more: Any) -> None:
+        pass
+
+    def end(self, payload: Any) -> None:
+        pass
+
+    def __exit__(self, *exc: Any) -> bool:
+        return False
+
+
+_QUIET = _QuietSpan()
+
+
+def span(name: str, es: Any = None, payload: Any = None, **info: Any):
+    """One begin/end pair with two sinks.
+
+    ``with pins.span("dev:wave", pool=.., rank=.., n=..) as sp:`` enters
+    a ``jax.profiler.TraceAnnotation("parsec:dev:wave", ...)`` — an
+    event on the clock the device's ``XLA Ops`` line is on, whenever a
+    profiler session runs (``jax.profiler.trace`` / ``start_trace``:
+    the session is the only switch) — and fires the PINS sites
+    ``<name>_begin`` / ``<name>_end`` when, and only when, somebody
+    subscribed.  The payload of both sites is ``payload`` (a task, where
+    the site carries one) or else the keyword arguments.  ``sp.note``
+    adds counts known only at the end to both sinks; ``sp.end`` gives
+    the END site a payload of its own (``release_deps_end``'s ``(task,
+    ready)``).  With no session and no subscriber at all the span is a
+    shared no-op (0.4-0.6 us; 1.4-1.8 us with a sink on; CPU of the
+    sandbox, a count of the constant)."""
+    if _tracing() or _enabled:  # (_tracing first: it loads jax's class)
+        return _Span(name, es, payload, info)
+    return _QUIET
 
 
 def clear() -> None:

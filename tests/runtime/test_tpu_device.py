@@ -412,9 +412,9 @@ def test_wave_staging_is_per_chunk(ctx):
 
     orig_stage = dev._stage_task_args
 
-    def recording_stage(task, body):
+    def recording_stage(task, body, *tally):
         events.append(("stage", id(task)))
-        return orig_stage(task, body)
+        return orig_stage(task, body, *tally)
 
     dev._stage_task_args = recording_stage
 
