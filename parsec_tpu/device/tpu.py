@@ -50,8 +50,10 @@ from ..core.lifecycle import AccessMode, HookReturn, DEV_TPU
 from ..core.task import Task
 from ..profiling import pins
 from ..utils import debug, mca_param, register_component
+from ..compile_cache import argsig
 from ..data.data import Coherency, Data, DataCopy
 from .device import Device
+from .value_args import ValuePlan
 
 
 def _unalias(arr, x, guard, jdev):
@@ -148,6 +150,10 @@ class TpuDevice(Device):
         #: place of the intended one, 0 on a healthy run
         self.stats.update(wave_fallbacks=0, submit_retries=0,
                           stage_batch_fallbacks=0)
+        #: tasks' value arguments by what became of them
+        #: (device/value_args.py)
+        self.stats.update(value_args_dropped=0, value_args_packed=0,
+                          value_args_positional=0)
         # rank → chip binding: each rank's runtime drives its OWN device
         # (reference: one CUDA module instance per visible GPU with
         # per-rank visibility, device_gpu.c).  Only process-addressable
@@ -521,21 +527,43 @@ class TpuDevice(Device):
                 pass
         return fp
 
-    def _cached_jit(self, local_key, content_key, fn, donate=()):
+    def _cached_jit(self, local_key, build):
         """One compile path for every device program: the in-device
         ``_jit_cache`` keeps the fast id-keyed lookup the dispatch loop
         had, while the executable cache behind it adds the persistent
-        disk store and the cross-rank compile broadcast.  The
+        disk store and the cross-rank compile broadcast.  An entry is
+        ``(program, ValuePlan or None)``; ``build()`` gives a new one's
+        ``(content key, function, donated positions, plan)``.  The
         ``dev:jit`` span notes ``miss=1`` on a program's first use by
         this device; the compile or load itself comes at its first call,
         as a ``cc:compile`` span under ``dev:dispatch``."""
         with self._span("dev:jit") as sp:
-            jitted = self._jit_cache.get(local_key)
-            if jitted is None:
+            entry = self._jit_cache.get(local_key)
+            if entry is None:
                 sp.note(miss=1)
-                jitted = self._jit_cache[local_key] = self._ccache.jit(
-                    fn, key=content_key, donate_argnums=tuple(donate))
-        return jitted
+                content_key, fn, donate, plan = build()
+                entry = self._jit_cache[local_key] = (self._ccache.jit(
+                    fn, key=content_key, donate_argnums=tuple(donate)),
+                    plan)
+        return entry
+
+    @staticmethod
+    def _value_plan(task: Task, body, dev_args) -> ValuePlan:
+        """The :class:`ValuePlan` of the program that runs ``body`` on
+        tasks staged like ``task``."""
+        return ValuePlan(body, dev_args, sum(
+            1 for s in task.body_args or () if s[0] == "value"))
+
+    def _count_values(self, plan: ValuePlan, ntasks: int, sp) -> None:
+        """``ntasks`` tasks went out under ``plan``: the counters, and
+        the same three on the ``dev:wave`` / ``dev:submit_one`` span."""
+        drop, pack, pos = (plan.dropped * ntasks, plan.packed * ntasks,
+                           plan.positional * ntasks)
+        self.stats["value_args_dropped"] += drop
+        self.stats["value_args_packed"] += pack
+        self.stats["value_args_positional"] += pos
+        if sp is not None:
+            sp.note(vdrop=drop, vpack=pack, vpos=pos)
 
     def _submit_one(self, task: Task, es, complete: bool = True,
                     drained_ns: int = 0) -> None:
@@ -544,8 +572,8 @@ class TpuDevice(Device):
         waited = (drained_ns - task._tpu_enq) // 1000 if drained_ns else 0
         try:
             with self._span("dev:submit_one", cls=task.task_class.name, n=1,
-                            batch=self._span_batch, waited_us=waited):
-                self._submit(task, es, complete=complete)
+                            batch=self._span_batch, waited_us=waited) as sp:
+                self._submit(task, es, complete=complete, span=sp)
         except Exception as e:
             debug.error("tpu submit of %r failed: %s", task, e)
             import traceback
@@ -695,13 +723,15 @@ class TpuDevice(Device):
             waited = sum(drained_ns - t._tpu_enq
                          for t in grp) // 1000 if drained_ns else 0
             with self._span("dev:wave", cls=cls, n=cnt,
-                            batch=self._span_batch, waited_us=waited):
-                self._submit_chunk(grp, body, base_key, es, complete)
+                            batch=self._span_batch, waited_us=waited) as sp:
+                self._submit_chunk(grp, body, base_key, es, complete, sp)
 
     def _submit_chunk(self, grp: List[Task], body, base_key, es,
-                      complete: bool) -> None:
+                      complete: bool, wave_span) -> None:
         """One power-of-2 chunk of a wave: stage, look the program up,
-        dispatch it, commit every task's outputs."""
+        dispatch it, commit every task's outputs.  The program's
+        arguments are the tasks' tiles; their values reach the bodies as
+        the program's :class:`ValuePlan` says."""
         from ..core import scheduling
 
         cnt = len(grp)
@@ -716,26 +746,30 @@ class TpuDevice(Device):
                 self._stage_in_batch(self._collect_stage_tiles(grp), tally)
             gst = [self._stage_task_args(t, body, tally) for t in grp]
             sp.note(host_tiles=tally[0], bytes=tally[1], tiles=tally[2])
-        arity, nout = len(gst[0][0]), len(gst[0][1])
+        args0, nout = gst[0][0], len(gst[0][1])
 
-        def _wave(*flat, _body=body, _arity=arity, _cnt=cnt):
-            outs: List[Any] = []
-            for t in range(_cnt):
-                o = _body(*flat[t * _arity:(t + 1) * _arity])
-                outs.extend(o if isinstance(o, (tuple, list))
-                            else (o,))
-            return tuple(outs)
-        jitted = self._cached_jit(
-            ("wave", base_key, arity, nout, cnt),
-            ("wave", self._content_fp(body), arity, nout, cnt),
-            _wave)
-        flat = [a for (dargs, _, _) in gst for a in dargs]
+        def build():
+            plan = self._value_plan(grp[0], body, args0)
+
+            def _wave(*flat):
+                outs: List[Any] = []
+                for args in plan.bodies_args(flat, cnt):
+                    o = body(*args)
+                    outs.extend(o if isinstance(o, (tuple, list))
+                                else (o,))
+                return tuple(outs)
+            return (("wave", self._content_fp(body), len(args0), nout, cnt)
+                    + plan.tag, _wave, (), plan)
+        jitted, plan = self._cached_jit(
+            ("wave", base_key, argsig(args0), nout, cnt), build)
+        flat = plan.flatten([dargs for (dargs, _, _) in gst])
         for t in grp:
             self._fire_exec(t, pins.EXEC_BEGIN, wave=cnt)
         with self._span("dev:dispatch"):
             outs = jitted(*flat)
         for t in grp:
             self._fire_exec(t, pins.EXEC_END, wave=cnt)
+        self._count_values(plan, cnt, wave_span)
         if len(outs) != nout * cnt:
             raise ValueError(
                 f"wave of {grp[0].task_class.name}: bodies returned "
@@ -829,8 +863,10 @@ class TpuDevice(Device):
             # other kinds (e.g. "ctl") contribute no argument
         return dev_args, out_specs, out_hooks
 
-    def _submit(self, task: Task, es=None, complete: bool = True) -> None:
-        """Stage + body dispatch (reference device_gpu.c:2015-2164)."""
+    def _submit(self, task: Task, es=None, complete: bool = True,
+                span=None) -> None:
+        """Stage + body dispatch (reference device_gpu.c:2015-2164);
+        ``span`` is the caller's ``dev:submit_one``."""
         body = task.selected_chore.body_fn
         if body is None:
             # DTD/PTG store the raw device body on the chore at build time
@@ -877,10 +913,10 @@ class TpuDevice(Device):
 
             def _bound(*arrs, _body=body, _vals=vals):
                 return _body(*arrs, *_vals)
-            jitted = self._cached_jit(
+            jitted, _ = self._cached_jit(
                 (base_key, vals),
-                ("static", self._content_fp(body), vals),
-                _bound, donate=donate)
+                lambda: (("static", self._content_fp(body), vals),
+                         _bound, donate, None))
             # a donating call that raises may have invalidated its input
             # buffers: the task is no longer safely retryable
             task._tpu_effects = bool(donate)
@@ -889,13 +925,6 @@ class TpuDevice(Device):
                 outputs = jitted(*arr_args)
             self._fire_exec(task, pins.EXEC_END)
         else:
-            # fused supertasks carry an explicit content key (member body
-            # fingerprints + region shape, dsl.fusion.FusedPlan.digest):
-            # fingerprinting the program CLOSURE would hash plan
-            # structures instead of member code, so the override is the
-            # cross-process cache identity
-            content_key = getattr(body, "_content_key", None) \
-                or ("body", self._content_fp(body))
             fused_n = int(getattr(body, "_fused_n", 0) or 0)
             if fused_n > 1:
                 self.stats["fused_submits"] = \
@@ -908,14 +937,33 @@ class TpuDevice(Device):
                 sde.counter_add(sde.FUSION_REGIONS_DISPATCHED, 1)
                 sde.counter_add(sde.FUSION_TASKS_FUSED, fused_n)
                 sde.counter_add(sde.FUSION_DISPATCH_SAVED, fused_n - 1)
-            jitted = self._cached_jit(
-                base_key, content_key,
-                body, donate=donate)
+
+            def build():
+                plan = self._value_plan(task, body, dev_args)
+
+                def _one(*flat):
+                    args, = plan.bodies_args(flat, 1)
+                    return body(*args)
+                _one.__name__ = getattr(body, "__name__", "_one")
+                # fused supertasks carry an explicit content key (member
+                # body fingerprints + region shape, dsl.fusion.FusedPlan.
+                # digest): fingerprinting the program CLOSURE would hash
+                # plan structures instead of member code, so the override
+                # is the cross-process cache identity
+                content_key = getattr(body, "_content_key", None) \
+                    or ("body", self._content_fp(body))
+                # a body with no scalar value is its own program, under
+                # its own name, as it always was
+                return (content_key + plan.tag, _one if plan.tag else body,
+                        plan.donate(donate), plan)
+            jitted, plan = self._cached_jit(
+                (base_key, argsig(dev_args)), build)
             task._tpu_effects = bool(donate)
             self._fire_exec(task, pins.EXEC_BEGIN)
             with self._span("dev:dispatch"):
-                outputs = jitted(*dev_args)
+                outputs = jitted(*plan.flatten((dev_args,)))
             self._fire_exec(task, pins.EXEC_END)
+            self._count_values(plan, 1, span)
         if not isinstance(outputs, (tuple, list)):
             outputs = (outputs,)
         outputs = list(outputs)

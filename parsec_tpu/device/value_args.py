@@ -1,0 +1,144 @@
+"""How a task's value arguments reach its body inside a device program.
+
+A task's ``("value", v, VALUE)`` specs used to ride every call of its
+program as one positional Python scalar each, and the executable's call
+path copies each such scalar to the device on its own (a ``DevicePut`` of
+200-360 us on a v5e: 1,903 of them a solve of the 816-task dpotrf, for
+integers no kernel reads).  A :class:`ValuePlan` decides, once per
+(body, argument signature), what becomes of each ``int``/``float``/
+``bool`` value instead:
+
+* **dropped** — the body's trace does not read it: it is no argument of
+  the program; inside the trace the body gets a placeholder of the
+  value's own Python type;
+* **packed** — the trace reads it: all such ``int``/``bool`` values of
+  the program's tasks travel in ONE host integer vector, all ``float``
+  values in one floating vector, and the trace hands the body the
+  element as the abstract value a Python scalar traces to (shape ``()``,
+  weak-typed), so promotion inside bodies is what it was;
+* **positional** — a value of any other type (a numpy scalar, an array)
+  stays an argument of its own.
+
+The plan never leans on ``jit``'s own pruning of unused arguments: a
+program compiled through its serialized form (``compile_cache.
+_compile_blob``) keeps every argument of ``Exported.call``.
+"""
+
+from typing import Any, Iterator, List, Sequence, Tuple
+
+import numpy as np
+
+import jax
+from jax import lax
+
+_DROP, _INT, _BOOL, _FLOAT = "d", "i", "b", "f"
+
+
+def _read_leaves(body, args: Sequence[Any]) -> List[bool]:
+    """For each argument of ``body``: does the trace read any of its
+    leaves?  One abstract trace (``make_jaxpr`` moves no data); an
+    argument that is input to no equation and is no output is unread."""
+    jaxpr = jax.make_jaxpr(body)(*args).jaxpr
+    used = {id(v) for eqn in jaxpr.eqns for v in eqn.invars}
+    used.update(id(v) for v in jaxpr.outvars)
+    read, at = [], 0
+    for a in args:
+        n = len(jax.tree_util.tree_leaves(a))
+        read.append(any(id(v) in used for v in jaxpr.invars[at:at + n]))
+        at += n
+    return read
+
+
+class ValuePlan:
+    """The calling convention of one device program: which positions of
+    a task's argument list are arguments of the program (``keep``), and
+    how every other position is rebuilt inside the trace."""
+
+    __slots__ = ("routes", "keep", "int_at", "float_at", "dropped",
+                 "packed", "positional", "tag")
+
+    def __init__(self, body, args: Sequence[Any], nvalues: int):
+        """``args``: one task's staged argument list (``_stage_task_
+        args``); ``nvalues``: how many of them are value specs.  A Python
+        scalar among ``args`` can only be a value."""
+        scalars = [i for i, a in enumerate(args)
+                   if type(a) in (int, float, bool)]
+        read = _read_leaves(body, args) if scalars else ()
+        #: per position: None (an argument of the program), or (how,
+        #: index in its vector | placeholder)
+        routes: List[Any] = [None] * len(args)
+        int_at: List[int] = []
+        float_at: List[int] = []
+        for i in scalars:
+            t = type(args[i])
+            if not read[i]:
+                routes[i] = (_DROP, t())
+            elif t is float:
+                routes[i] = (_FLOAT, len(float_at))
+                float_at.append(i)
+            else:
+                routes[i] = (_BOOL if t is bool else _INT, len(int_at))
+                int_at.append(i)
+        self.routes = tuple(routes)
+        #: positions that ride the integer / the floating vector
+        self.int_at, self.float_at = tuple(int_at), tuple(float_at)
+        self.keep = tuple(i for i, r in enumerate(routes) if r is None)
+        self.packed = len(int_at) + len(float_at)
+        self.dropped = len(scalars) - self.packed
+        self.positional = nvalues - len(scalars)
+        #: part of the program's content key: an executable stored for
+        #: another argument list is never loaded for this one.  Empty
+        #: when every argument is passed as it always was.
+        self.tag = ("vargs", "".join(
+            r[0] if r else "-" for r in routes)) if scalars else ()
+
+    def flatten(self, tasks_args: Sequence[Sequence[Any]]) -> List[Any]:
+        """The program's argument list for these tasks: each task's kept
+        arguments in order, then the integer vector, then the floating
+        one (each only if the plan packs such values)."""
+        keep = self.keep
+        flat = [a[i] for a in tasks_args for i in keep]
+        for at, pytype in ((self.int_at, int), (self.float_at, float)):
+            if at:
+                # the dtype such a scalar traces to under the process's
+                # x64 setting; an int beyond it raises here as it did
+                # at the call
+                flat.append(np.array(
+                    [a[i] for a in tasks_args for i in at],
+                    dtype=jax.dtypes.canonicalize_dtype(pytype)))
+        return flat
+
+    def bodies_args(self, flat: Sequence[Any],
+                    ntasks: int) -> Iterator[List[Any]]:
+        """Inside the trace: each task's full argument list, rebuilt
+        from the program's arguments."""
+        nkeep, nint, nfloat = (len(self.keep), len(self.int_at),
+                               len(self.float_at))
+        vecs = flat[ntasks * nkeep:]
+        ivec = vecs[0] if nint else None
+        fvec = vecs[-1] if nfloat else None
+        for t in range(ntasks):
+            kept = iter(flat[t * nkeep:(t + 1) * nkeep])
+            args = []
+            for how, x in (r or (None, None) for r in self.routes):
+                if how is None:
+                    args.append(next(kept))
+                elif how == _DROP:
+                    args.append(x)
+                elif how == _FLOAT:
+                    args.append(_weak(fvec[t * nfloat + x]))
+                elif how == _BOOL:  # a Python bool traces strong-typed
+                    args.append(ivec[t * nint + x] != 0)
+                else:
+                    args.append(_weak(ivec[t * nint + x]))
+            yield args
+
+    def donate(self, argnums: Tuple[int, ...]) -> Tuple[int, ...]:
+        """A one-task program's donated positions, in its argument list."""
+        return tuple(self.keep.index(i) for i in argnums if i in self.keep)
+
+
+def _weak(x):
+    """``x`` as the weak-typed scalar a Python number traces to."""
+    return lax.convert_element_type_p.bind(
+        x, new_dtype=x.dtype, weak_type=True, sharding=None)

@@ -254,3 +254,90 @@ def test_native_device_use_globals_value_order():
     for k in range(4):
         np.testing.assert_allclose(stage_to_cpu(coll.data_of(k)),
                                    10.0 * k + 100.0)
+
+
+# ---------------------------------------------------------------------------
+# value arguments through the pump (device/value_args.py)
+# ---------------------------------------------------------------------------
+
+def _device_over(store_dir, exported):
+    """A device whose executable cache takes the exported path for every
+    program (``min_share_s`` 0 over a disk store, as
+    ``tests/runtime/test_compile_bcast.py`` sets it up) or for none."""
+    import types
+
+    from parsec_tpu import compile_cache as cc
+    from parsec_tpu.device.tpu import TpuDevice
+
+    cache = cc.ExecutableCache(store=cc.DiskStore(str(store_dir)),
+                               min_disk_s=0.0 if exported else 1e9)
+    dev = TpuDevice(types.SimpleNamespace(rank=0, nranks=1, devices=[],
+                                          compile_cache=cache), index=1)
+    dev.attach()
+    return dev
+
+
+def _reading_taskpool():
+    """A class whose body reads a parameter (``int``), a floating and a
+    boolean global: all three ride the program's two host vectors."""
+    from parsec_tpu.data import LocalCollection
+    from parsec_tpu.dsl.ptg import PTG
+
+    coll = LocalCollection("A", shape=(4,), dtype=np.float32)
+    ptg = PTG("values_read")
+    tc = ptg.task_class("t", k="0 .. 7")
+    tc.affinity("A(k)")
+    tc.flow("X", AccessMode.INOUT, "<- A(k)", "-> A(k)")
+    tc.use_globals("G", "UP")
+
+    def body(X, k, G, UP):
+        import jax.numpy as jnp
+
+        return jnp.where(UP, X + 10.0 * k + G, X)
+
+    tc.body(tpu=body)
+    return coll, ptg.taskpool(A=coll, G=0.5, UP=True)
+
+
+@pytest.mark.parametrize("exported", [False, True],
+                         ids=["plain_lowering", "exported_path"])
+@pytest.mark.parametrize("graph", ["dpotrf_ignores_its_values",
+                                   "body_reads_its_values"])
+def test_native_device_values_dropped_or_packed(tmp_path, exported, graph):
+    """Through the pump, on both ways a program is compiled: the dpotrf
+    tile bodies read none of their parameters (NT=8: 8·1 + 28·2 + 28·2 +
+    56·3 = 288 values, every one dropped, no scalar in any call); a body
+    that reads its values gets them packed, with the numbers right."""
+    from parsec_tpu.dsl.dtd import stage_to_cpu
+    from parsec_tpu.dsl.native_exec import NativeExecutor
+
+    dev = _device_over(tmp_path, exported)
+    try:
+        if graph == "dpotrf_ignores_its_values":
+            S, A, tp = _dpotrf_taskpool(256, 32, seed=4)
+        else:
+            coll, tp = _reading_taskpool()
+        ex = NativeExecutor(tp, native_device=True, device=dev)
+        ran = ex.run(nthreads=2)
+        ex.close()
+        sigs = [sig for (cf, _plan) in dev._jit_cache.values()
+                for sig in cf._memo]
+        assert not any(e[0] == "s" for sig in sigs for e in sig), sigs
+        if graph == "dpotrf_ignores_its_values":
+            assert ran == 120
+            L = np.tril(A.to_array())
+            np.testing.assert_allclose(L @ L.T, S, rtol=1e-10, atol=1e-10)
+            counted = (288, 0)
+        else:
+            assert ran == 8
+            for k in range(8):
+                np.testing.assert_array_equal(
+                    stage_to_cpu(coll.data_of(k)),
+                    np.float32(10.0 * k + 0.5))
+            counted = (0, 24)
+        assert (dev.stats["value_args_dropped"],
+                dev.stats["value_args_packed"]) == counted
+        assert dev.stats["value_args_positional"] == 0
+        assert bool(dev._ccache.stats["bytes_written"]) == exported
+    finally:
+        dev.detach()

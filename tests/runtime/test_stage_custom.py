@@ -162,7 +162,9 @@ def test_packed_copy_never_served_as_home_layout(ctx):
     tp = ptg.taskpool(A=dc)
     ctx.add_taskpool(tp)
     assert tp.wait(timeout=60)
-    assert seen_shapes == [(N, N // 2)]  # the body saw the packed tile
+    # the body saw the packed tile, in every trace of it (the device
+    # traces a body once more, abstractly, to see which values it reads)
+    assert seen_shapes and set(seen_shapes) == {(N, N // 2)}
     # now a plain device task on the same tile: must see FULL layout
     from parsec_tpu.dsl import DTDTaskpool, INOUT
 

@@ -480,3 +480,212 @@ def test_body_fingerprint_memo_is_weak(ctx):
     # wherever the allocator places it
     b2 = make(2.0)
     assert dev._content_fp(b2) != fp1
+
+
+# ---------------------------------------------------------------------------
+# value arguments: dropped, packed or positional (device/value_args.py)
+# ---------------------------------------------------------------------------
+
+def _value_tasks(body, rows):
+    """Device tasks over ``rows`` of ``(tile array, out array, *values)``:
+    flows ``x`` (IN) and ``o`` (INOUT), then the values, PTG layout."""
+    from parsec_tpu.core.lifecycle import AccessMode
+    from parsec_tpu.core.task import Chore, TaskClass
+    from parsec_tpu.dsl.native_exec import _NativeDeviceTask
+    from types import SimpleNamespace
+
+    pool = SimpleNamespace(failed=False, task_done=lambda t=None: None,
+                           context=None)
+    tclass = TaskClass("valuetest")
+    chore = Chore(DEV_TPU, hook=lambda es, t: None)
+    chore.body_fn = body
+    tasks = []
+    for i, (x, o, *values) in enumerate(rows):
+        t = _NativeDeviceTask(pool, tclass, (i,), 0)
+        t.selected_chore = chore
+        t.body_args = [("data", data_create(("vx", id(body), i), payload=x),
+                        IN),
+                       ("data", data_create(("vo", id(body), i), payload=o),
+                        INOUT)]
+        t.body_args += [("value", v, AccessMode.VALUE) for v in values]
+        t.on_complete = lambda task: None
+        tasks.append(t)
+    return tasks
+
+
+def _run_value_tasks(dev, tasks, alone):
+    """Through the wave path (a power of two: ONE program) or one by
+    one; returns the newest device payload of every ``o`` tile."""
+    if alone:
+        for t in tasks:
+            dev._submit_one(t, None)
+    else:
+        dev._submit_wave(tasks, None)
+    return [t.body_args[1][1].get_copy(dev.data_index).payload
+            for t in tasks]
+
+
+def _call_signatures(dev):
+    """The argument signature of every call a program of ``dev`` got."""
+    return [sig for (cf, _plan) in dev._jit_cache.values()
+            for sig in cf._memo]
+
+
+@pytest.mark.parametrize("exported", [False, True],
+                         ids=["plain_lowering", "exported_path"])
+@pytest.mark.parametrize("alone", [False, True], ids=["wave8", "alone"])
+def test_unread_values_are_no_program_argument(monkeypatch, tmp_path,
+                                               exported, alone):
+    """A body that ignores its values gets a program whose argument list
+    holds tiles only — whichever way ``compile_cache`` compiles it: the
+    plain lowering prunes unused arguments by itself, the program
+    compiled through its serialized form keeps every argument of
+    ``Exported.call`` (one host-to-device copy a scalar a call)."""
+    from parsec_tpu.utils import mca_param
+
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+    mca_param.set_param("runtime", "compile_cache_min_share_s",
+                        0.0 if exported else 1e9)
+    ctx = Context(nb_cores=1)
+    try:
+        dev = tpu_dev(ctx)
+
+        def body(x, o, m, n, k):
+            return o + x
+
+        rows = [(np.full((8, 8), i, np.float32), np.ones((8, 8), np.float32),
+                 i, i + 1, 7) for i in range(8)]
+        outs = _run_value_tasks(dev, _value_tasks(body, rows), alone)
+        for i, o in enumerate(outs):
+            np.testing.assert_array_equal(np.asarray(o), i + 1.0)
+        sigs = _call_signatures(dev)
+        assert len(sigs) == 1
+        assert len(sigs[0]) == (2 if alone else 16)  # tiles only
+        assert all(e[0] == "a" for e in sigs[0]), sigs[0]
+        assert dev.stats["value_args_dropped"] == 24
+        assert dev.stats["value_args_packed"] == 0
+        assert dev.stats["value_args_positional"] == 0
+        # the path asked for is the path taken
+        assert bool(ctx.compile_cache.stats["bytes_written"]) == exported
+    finally:
+        ctx.fini()
+        mca_param.params.unset("runtime", "compile_cache_min_share_s")
+
+
+@pytest.mark.parametrize("dtype", ["bfloat16", "int8", "float32"])
+@pytest.mark.parametrize("alone", [False, True], ids=["wave8", "alone"])
+def test_read_values_ride_one_vector_a_kind(ctx, dtype, alone):
+    """A body that reads an ``int``, a ``float`` and a ``bool`` value:
+    the program takes tiles plus ONE integer and ONE floating vector,
+    and every output is bitwise (dtype included: the packed element is
+    weak-typed like the Python scalar it replaces) what the positional
+    call ``jax.jit(body)(x, o, i, f, b)`` gives."""
+    import jax
+
+    dev = tpu_dev(ctx)
+
+    def body(x, o, i, f, b):
+        return jnp.where(b, x * i + f, x - i)
+
+    rng = np.random.default_rng(5)
+    rows = [(jnp.asarray(rng.integers(-9, 9, (8, 8)), dtype=dtype),
+             np.zeros((8, 8), np.float32), i - 3, 0.5 * i, i % 2 == 0)
+            for i in range(8)]
+    want = [jax.jit(body)(*row) for row in rows]
+    outs = _run_value_tasks(dev, _value_tasks(body, rows), alone)
+    for got, ref in zip(outs, want):
+        assert got.dtype == ref.dtype
+        np.testing.assert_array_equal(np.asarray(got), np.asarray(ref))
+    if dtype == "bfloat16":
+        assert want[0].dtype == jnp.bfloat16  # a strong f32 would widen it
+    n = 1 if alone else 8
+    sigs = _call_signatures(dev)
+    assert len(sigs) == 1 and len(sigs[0]) == 2 * n + 2
+    # (int, bool) share the integer vector; the dtypes are those a
+    # Python scalar traces to (the suite runs with x64 on)
+    assert sigs[0][-2:] == (("a", (2 * n,), "int64", False),
+                            ("a", (n,), "float64", False)), sigs[0]
+    assert dev.stats["value_args_packed"] == 24
+    assert dev.stats["value_args_dropped"] == 0
+    assert dev.stats["value_args_positional"] == 0
+
+
+@pytest.mark.parametrize("alone", [False, True], ids=["wave8", "alone"])
+def test_other_value_types_stay_positional_and_are_counted(ctx, alone):
+    """A numpy scalar and an array passed as values keep an argument of
+    their own; the unread Python int beside them is still dropped."""
+    dev = tpu_dev(ctx)
+
+    def body(x, o, s, v, k):
+        return x * s + v
+
+    rows = [(np.ones((4, 4), np.float32), np.zeros((4, 4), np.float32),
+             np.float32(i), np.arange(4, dtype=np.float32), i)
+            for i in range(8)]
+    outs = _run_value_tasks(dev, _value_tasks(body, rows), alone)
+    for i, o in enumerate(outs):
+        np.testing.assert_array_equal(
+            np.asarray(o), i + np.tile(np.arange(4, dtype=np.float32), (4, 1)))
+    assert dev.stats["value_args_positional"] == 16
+    assert dev.stats["value_args_dropped"] == 8
+    assert dev.stats["value_args_packed"] == 0
+    sig, = _call_signatures(dev)
+    assert len(sig) == (4 if alone else 32)
+
+
+@pytest.mark.parametrize("alone", [False, True], ids=["wave8", "alone"])
+def test_store_written_under_the_old_key_is_not_hit(monkeypatch, tmp_path,
+                                                    alone):
+    """The calling convention is part of the program's content key: an
+    executable the parent stored (every value a positional scalar) is
+    never loaded for the new argument list, and what the change stores
+    IS hit by the next process."""
+    from parsec_tpu import compile_cache as cc
+    from parsec_tpu.utils import mca_param
+
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+    mca_param.set_param("runtime", "compile_cache_min_share_s", 0.0)
+
+    def body(x, o, m, k):
+        return o + x * k
+
+    def rows():
+        return [(np.full((8, 8), i, np.float32),
+                 np.ones((8, 8), np.float32), i, 2) for i in range(8)]
+
+    try:
+        ctx = Context(nb_cores=1)
+        try:
+            dev = tpu_dev(ctx)
+            n = 1 if alone else 8
+
+            # the parent's program and call, into the same store
+            def _wave(*flat):
+                return tuple(body(*flat[4 * t:4 * t + 4]) for t in range(n))
+            old_key = ("body", dev._content_fp(body)) if alone else \
+                ("wave", dev._content_fp(body), 4, 1, 8)
+            old = ctx.compile_cache.jit(body if alone else _wave, key=old_key)
+            old(*[jnp.asarray(a) if isinstance(a, np.ndarray) else a
+                  for row in rows()[:n] for a in row])
+            stored = ctx.compile_cache.store.count()
+            assert stored == 1
+            ctx.compile_cache.clear_memory()
+            before = dict(ctx.compile_cache.stats)
+            outs = _run_value_tasks(dev, _value_tasks(body, rows()), alone)
+            np.testing.assert_array_equal(np.asarray(outs[3]), 7.0)
+            after = ctx.compile_cache.stats
+            assert after["hits_disk"] == before.get("hits_disk", 0)
+            assert after["misses"] == before["misses"] + 1
+            assert ctx.compile_cache.store.count() == stored + 1
+        finally:
+            ctx.fini()
+        ctx = Context(nb_cores=1)
+        try:
+            dev = tpu_dev(ctx)
+            _run_value_tasks(dev, _value_tasks(body, rows()), alone)
+            assert ctx.compile_cache.stats["hits_disk"] == 1
+            assert ctx.compile_cache.stats["misses"] == 0
+        finally:
+            ctx.fini()
+    finally:
+        mca_param.params.unset("runtime", "compile_cache_min_share_s")
