@@ -823,7 +823,14 @@ class ExecutableCache:
 
             _ensure_custom_call_targets()
             exp = jex.deserialize(bytearray(blob))
-            exe = jax.jit(exp.call, donate_argnums=cf.donate) \
+
+            def call(*a):
+                return exp.call(*a)
+            # under the function's own name, as the plain lowering has
+            # it: a device trace tells programs apart by their module
+            # names, whichever way they were compiled
+            call.__name__ = getattr(cf.fn, "__name__", "call")
+            exe = jax.jit(call, donate_argnums=cf.donate) \
                 .lower(*args).compile()
             return exe
         except Exception as e:

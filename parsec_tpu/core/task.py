@@ -145,6 +145,8 @@ class Task:
         "_tpu_attempts",
         "_tpu_effects",
         "_tpu_enq",
+        "_tpu_scratch",
+        "_tpu_home",
     )
 
     def __init__(
@@ -193,6 +195,13 @@ class Task:
         #: ``perf_counter_ns`` at the moment the device module queued the
         #: task (its ready-queue wait: the ``waited_us`` of ``dev:wave``)
         self._tpu_enq = 0
+        #: the scratch tiles among the task's flows, as the device module
+        #: staged them: it releases one user of each in the task's epilog
+        self._tpu_scratch: Tuple = ()
+        #: positions in ``body_args`` of the outputs that go home (the
+        #: device module's write-back committer takes only these); None
+        #: where whoever built the task does not know: then every one
+        self._tpu_home: Optional[Tuple[int, ...]] = None
 
     @property
     def key(self) -> Any:

@@ -92,6 +92,7 @@ class Data:
         "lock",
         "data_id",
         "user",
+        "scratch",
         "__weakref__",
     )
 
@@ -115,6 +116,9 @@ class Data:
         self.lock = threading.RLock()
         self.data_id = next(self._ids)
         self.user: Any = None
+        #: None for a tile that has a home; for a scratch tile, the Data
+        #: of a ``NEW`` flow, its declared users left (device/scratch.py)
+        self.scratch: Optional[int] = None
 
     # -- copy management --------------------------------------------------
     def attach_copy(self, device_index: int, payload: Any) -> DataCopy:

@@ -52,6 +52,7 @@ from ..profiling import pins
 from ..utils import debug, mca_param, register_component
 from ..compile_cache import argsig
 from ..data.data import Coherency, Data, DataCopy
+from . import scratch
 from .device import Device
 from .value_args import ValuePlan
 
@@ -97,6 +98,15 @@ def private_device_put(x, jdev=None, *, guard=None):
     if guard is None:
         return arr
     return _unalias(arr, x, guard, jdev)
+
+
+def _placeholders_at(dev_args) -> Tuple[int, ...]:
+    """Positions of a staged argument list that stand for a tile there
+    was nothing to stage for (``TpuDevice._placeholder``): part of a
+    program's local key, since ``argsig`` reads such a stand-in as the
+    array it is not."""
+    return tuple(i for i, a in enumerate(dev_args)
+                 if isinstance(a, jax.ShapeDtypeStruct))
 
 
 def _pool_of(task: Task) -> int:
@@ -153,7 +163,13 @@ class TpuDevice(Device):
         #: tasks' value arguments by what became of them
         #: (device/value_args.py)
         self.stats.update(value_args_dropped=0, value_args_packed=0,
-                          value_args_positional=0)
+                          value_args_positional=0, tile_args_dropped=0)
+        #: scratch tiles (device/scratch.py): first written / dropped
+        #: with their last user on this device, and the bytes of them
+        #: that crossed the host after all (0 unless one was evicted or
+        #: a CPU body wrote it)
+        self.stats.update(scratch_tiles_born=0, scratch_tiles_freed=0,
+                          scratch_bytes_in=0, scratch_bytes_out=0)
         # rank → chip binding: each rank's runtime drives its OWN device
         # (reference: one CUDA module instance per visible GPU with
         # per-rank visibility, device_gpu.c).  Only process-addressable
@@ -554,16 +570,21 @@ class TpuDevice(Device):
         return ValuePlan(body, dev_args, sum(
             1 for s in task.body_args or () if s[0] == "value"))
 
-    def _count_values(self, plan: ValuePlan, ntasks: int, sp) -> None:
+    def _count_values(self, plan: ValuePlan, ntasks: int, sp,
+                      nouts: int = 0) -> None:
         """``ntasks`` tasks went out under ``plan``: the counters, and
-        the same three on the ``dev:wave`` / ``dev:submit_one`` span."""
-        drop, pack, pos = (plan.dropped * ntasks, plan.packed * ntasks,
-                           plan.positional * ntasks)
+        the same four on the ``dev:wave`` / ``dev:submit_one`` span with
+        ``outs``, the outputs its epilog commits."""
+        drop, pack, pos, tdrop = (
+            plan.dropped * ntasks, plan.packed * ntasks,
+            plan.positional * ntasks, plan.tiles_dropped * ntasks)
         self.stats["value_args_dropped"] += drop
         self.stats["value_args_packed"] += pack
         self.stats["value_args_positional"] += pos
+        self.stats["tile_args_dropped"] += tdrop
         if sp is not None:
-            sp.note(vdrop=drop, vpack=pack, vpos=pos)
+            sp.note(vdrop=drop, vpack=pack, vpos=pos, tdrop=tdrop,
+                    outs=nouts)
 
     def _submit_one(self, task: Task, es, complete: bool = True,
                     drained_ns: int = 0) -> None:
@@ -657,6 +678,12 @@ class TpuDevice(Device):
                 if payload is None:
                     sig.append(("none",))
                     continue
+                if scratch.unborn(payload):
+                    # no argument of the program: never in one wave with
+                    # a task whose tile of this flow has been written
+                    sig.append(("unborn", tuple(payload.shape),
+                                str(payload.dtype), int(mode)))
+                    continue
                 shape, dtype = payload.shape, payload.dtype
                 if shape is None or dtype is None:
                     newest = payload.newest_copy()
@@ -735,6 +762,7 @@ class TpuDevice(Device):
         from ..core import scheduling
 
         cnt = len(grp)
+        cls = grp[0].task_class.name
         with self._span("dev:stage_args") as sp:
             tally = [0, 0, 0]  # host tiles, their bytes, tiles staged
             if self.stage_depth > 1:
@@ -758,10 +786,14 @@ class TpuDevice(Device):
                     outs.extend(o if isinstance(o, (tuple, list))
                                 else (o,))
                 return tuple(outs)
-            return (("wave", self._content_fp(body), len(args0), nout, cnt)
-                    + plan.tag, _wave, (), plan)
+            # the task class in the program's name: the device trace's
+            # ``XLA Modules`` line then splits the chip's time by class
+            _wave.__name__ = f"_wave_{cls}"
+            return (("wave", cls, self._content_fp(body), len(args0), nout,
+                     cnt) + plan.tag, _wave, (), plan)
         jitted, plan = self._cached_jit(
-            ("wave", base_key, argsig(args0), nout, cnt), build)
+            ("wave", cls, base_key, argsig(args0), _placeholders_at(args0), nout,
+             cnt), build)
         flat = plan.flatten([dargs for (dargs, _, _) in gst])
         for t in grp:
             self._fire_exec(t, pins.EXEC_BEGIN, wave=cnt)
@@ -769,7 +801,7 @@ class TpuDevice(Device):
             outs = jitted(*flat)
         for t in grp:
             self._fire_exec(t, pins.EXEC_END, wave=cnt)
-        self._count_values(plan, cnt, wave_span)
+        self._count_values(plan, cnt, wave_span, len(outs))
         if len(outs) != nout * cnt:
             raise ValueError(
                 f"wave of {grp[0].task_class.name}: bodies returned "
@@ -820,6 +852,7 @@ class TpuDevice(Device):
         dev_args: List[Any] = []
         out_specs: List[Tuple[int, Data]] = []
         out_hooks: List[Any] = []
+        held: List[Data] = []  # scratch tiles: one user each, see _epilog
         data_idx = -1
         for pos, spec in enumerate(task.body_args or ()):
             kind, payload, mode = spec
@@ -838,14 +871,19 @@ class TpuDevice(Device):
                     raise RuntimeError(
                         f"{task!r}: stage_in on writable flow requires a "
                         "matching stage_out hook")
+                if payload.scratch is not None:
+                    held.append(payload)
                 if si is not None:
                     # custom staging: the hook's result IS the flow's
                     # device copy (pack/convert — reference stage_custom)
                     arr = self._stage_in_custom(payload, si)
-                elif rw == AccessMode.OUT:
-                    # write-only: the body overwrites it — skip the H2D
-                    # transfer (reference skips stage-in for OUT-only flows)
-                    arr = self._out_placeholder(payload)
+                elif scratch.unborn(payload) or rw == AccessMode.OUT:
+                    # a NEW flow's tile nobody has written, or a
+                    # write-only flow the body overwrites (reference
+                    # skips stage-in for OUT-only flows): nothing to
+                    # stage and no argument of the program — the plan
+                    # gives the body zeros inside the trace
+                    arr = self._placeholder(payload)
                 else:
                     arr = self._stage_in(payload, tally)
                 if tally is not None:
@@ -861,6 +899,7 @@ class TpuDevice(Device):
                 shape, dtype = payload
                 dev_args.append(jnp.zeros(shape, dtype, device=self.jdev))
             # other kinds (e.g. "ctl") contribute no argument
+        task._tpu_scratch = held
         return dev_args, out_specs, out_hooks
 
     def _submit(self, task: Task, es=None, complete: bool = True,
@@ -909,7 +948,12 @@ class TpuDevice(Device):
                     "trail all data args (PTG layout); this task "
                     f"interleaves them ({specs})")
             split = len(dev_args) - nval
-            arr_args, vals = dev_args[:split], tuple(dev_args[split:])
+            # no plan on this path: a placeholder becomes zeros on the
+            # device (created ON this rank's device, not the default one)
+            arr_args = [jnp.zeros(a.shape, a.dtype, device=self.jdev)
+                        if isinstance(a, jax.ShapeDtypeStruct) else a
+                        for a in dev_args[:split]]
+            vals = tuple(dev_args[split:])
 
             def _bound(*arrs, _body=body, _vals=vals):
                 return _body(*arrs, *_vals)
@@ -957,13 +1001,13 @@ class TpuDevice(Device):
                 return (content_key + plan.tag, _one if plan.tag else body,
                         plan.donate(donate), plan)
             jitted, plan = self._cached_jit(
-                (base_key, argsig(dev_args)), build)
+                (base_key, argsig(dev_args), _placeholders_at(dev_args)), build)
             task._tpu_effects = bool(donate)
             self._fire_exec(task, pins.EXEC_BEGIN)
             with self._span("dev:dispatch"):
                 outputs = jitted(*plan.flatten((dev_args,)))
             self._fire_exec(task, pins.EXEC_END)
-            self._count_values(plan, 1, span)
+            self._count_values(plan, 1, span, len(out_specs))
         if not isinstance(outputs, (tuple, list)):
             outputs = (outputs,)
         outputs = list(outputs)
@@ -989,16 +1033,19 @@ class TpuDevice(Device):
         self._rr += 1
         lane.append(inflight)
 
-    def _out_placeholder(self, data: Data) -> Any:
-        """Device-side zeros standing in for a write-only tile."""
+    def _placeholder(self, data: Data) -> Any:
+        """What stands in for a tile there is nothing to stage for: its
+        shape and dtype alone (``device/value_args.py`` turns that into
+        zeros inside the trace)."""
         newest = data.newest_copy()
-        shape = data.shape if data.shape is not None else getattr(newest.payload, "shape", None)
-        dtype = data.dtype if data.dtype is not None else getattr(newest.payload, "dtype", None)
+        held = getattr(newest, "payload", None)
+        shape = data.shape if data.shape is not None \
+            else getattr(held, "shape", None)
+        dtype = data.dtype if data.dtype is not None \
+            else getattr(held, "dtype", None)
         if shape is None or dtype is None:
             return self._stage_in(data)  # shape unknown: fall back
-        # created ON this rank's device: an uncommitted zeros array
-        # would pull the computation onto the process default device
-        return jnp.zeros(shape, dtype, device=self.jdev)
+        return jax.ShapeDtypeStruct(tuple(shape), np.dtype(dtype))
 
     def _stage_in_custom(self, data: Data, hook) -> Any:
         """Stage via a user hook: ``hook(data, device) -> jax.Array``.
@@ -1078,6 +1125,8 @@ class TpuDevice(Device):
                     # claims v
                     arr = private_device_put(host, self.jdev, guard=host)
                 self.stats["bytes_in"] += host.nbytes
+                if data.scratch is not None:
+                    self.stats["scratch_bytes_in"] += host.nbytes
                 if tally is not None:
                     tally[0] += 1
                     tally[1] += host.nbytes
@@ -1109,6 +1158,8 @@ class TpuDevice(Device):
                     continue
                 if (mode & AccessMode.INOUT) == AccessMode.OUT:
                     continue  # write-only: no H2D needed
+                if scratch.unborn(payload):
+                    continue  # a scratch tile nobody has written
                 if payload.data_id in seen:
                     continue
                 seen.add(payload.data_id)
@@ -1174,6 +1225,8 @@ class TpuDevice(Device):
                     tally[1] += nbytes
                 for (data, host, ver), arr in zip(puts, arrs):
                     self.stats["bytes_in"] += host.nbytes
+                    if data.scratch is not None:
+                        self.stats["scratch_bytes_in"] += host.nbytes
                     c = data.attach_copy(self.data_index, arr)
                     c.version = ver
                     self._lru_touch(data, dirty=False)
@@ -1411,6 +1464,8 @@ class TpuDevice(Device):
             hc.version = version
             hc.coherency = Coherency.SHARED
         self.stats["bytes_out"] += host.nbytes
+        if data.scratch is not None:  # spilled by an eviction
+            self.stats["scratch_bytes_out"] += host.nbytes
         return True
 
     def _d2h_batch(self, payloads: List[Any]) -> List[Optional[np.ndarray]]:
@@ -1535,6 +1590,8 @@ class TpuDevice(Device):
                     self.stats["custom_stage_out"] = self.stats.get("custom_stage_out", 0) + 1
                 c = data.get_copy(self.data_index)
                 old = c.nbytes if c is not None else 0
+                if scratch.unborn(data):
+                    self.stats["scratch_tiles_born"] += 1
                 if c is None:
                     c = data.attach_copy(self.data_index, arr)
                 else:
@@ -1549,6 +1606,15 @@ class TpuDevice(Device):
             # already evicted during allocation)
             if self._zone is None:
                 self._reserve(0)
+            # this task was one declared user of each scratch tile among
+            # its flows: with the last one the tile's copy here is
+            # dropped (a program already enqueued keeps its buffer)
+            for data in inflight.task._tpu_scratch:
+                if scratch.release(data):
+                    self._lru_clean.pop(data.data_id, None)
+                    self._lru_dirty.pop(data.data_id, None)
+                    self._drop_copy(data, evicted=False)
+                    self.stats["scratch_tiles_freed"] += 1
         com = None if inflight.donated else self._wb_committer()
         if com is not None:
             # tentpole (b): hand the just-committed outputs to the async
@@ -1568,8 +1634,18 @@ class TpuDevice(Device):
             # the race and reads a deleted array.  Such tiles stay
             # dirty-resident; detach/flush/eviction carry the final
             # version home through the synchronous guarded path.
-            for (_pos, data) in inflight.out_specs:
-                com.enqueue(data, self._span_pool, self._span_batch)
+            # NOT a scratch tile either: it has no home to go to.  Nor,
+            # where the task's builder knows the DAG (``_tpu_home``), a
+            # version that a later task overwrites.
+            home = inflight.task._tpu_home
+            for (pos, data) in inflight.out_specs:
+                if data.scratch is None and (home is None or pos in home):
+                    com.enqueue(data, self._span_pool, self._span_batch)
+            if home:
+                # a last version has no later one to wait for: the
+                # committer starts on it now, below its watermark (which
+                # exists to let a tile that is rewritten commit once)
+                com.kick()
 
     # ------------------------------------------------------------------
     def data_advise(self, data: Data, advice: int) -> None:
@@ -1649,7 +1725,9 @@ class TpuDevice(Device):
             # get (satellite 2) — the version guard makes tiles the
             # committer already landed a no-op, so each dirty tile
             # commits exactly once
-            self._writeback_batch([d for _, d in list(self._lru_dirty.items())])
+            # (a scratch tile has no home: it is dropped, never copied)
+            self._writeback_batch([d for d in self._lru_dirty.values()
+                                   if d.scratch is None])
             self._lru_dirty.clear()
             self._lru_clean.clear()
             # release residency ACCOUNTING with the LRUs: the payloads stay

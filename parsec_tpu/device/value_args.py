@@ -1,4 +1,5 @@
-"""How a task's value arguments reach its body inside a device program.
+"""How a task's value arguments reach its body inside a device program,
+and which of its tile arguments the program takes at all.
 
 A task's ``("value", v, VALUE)`` specs used to ride every call of its
 program as one positional Python scalar each, and the executable's call
@@ -19,6 +20,15 @@ integers no kernel reads).  A :class:`ValuePlan` decides, once per
 * **positional** — a value of any other type (a numpy scalar, an array)
   stays an argument of its own.
 
+The same trace decides a task's TILE arguments.  A tile the device
+module has nothing to stage for arrives as a ``jax.ShapeDtypeStruct``
+(a scratch tile no task has written yet, ``device/scratch.py``; a
+write-only flow): it is
+never an argument of the program, and inside the trace the body gets
+zeros of that shape — a body that reads them reads zeros, one that does
+not costs nothing.  A staged tile the trace never reads is dropped from
+the program's arguments the same way.  Both count as ``tiles_dropped``.
+
 The plan never leans on ``jit``'s own pruning of unused arguments: a
 program compiled through its serialized form (``compile_cache.
 _compile_blob``) keeps every argument of ``Exported.call``.
@@ -29,9 +39,11 @@ from typing import Any, Iterator, List, Sequence, Tuple
 import numpy as np
 
 import jax
+import jax.numpy as jnp
 from jax import lax
 
 _DROP, _INT, _BOOL, _FLOAT = "d", "i", "b", "f"
+_UNBORN, _UNREAD = "z", "t"   # a tile: nothing was staged / never read
 
 
 def _read_leaves(body, args: Sequence[Any]) -> List[bool]:
@@ -55,7 +67,7 @@ class ValuePlan:
     how every other position is rebuilt inside the trace."""
 
     __slots__ = ("routes", "keep", "int_at", "float_at", "dropped",
-                 "packed", "positional", "tag")
+                 "packed", "positional", "tiles_dropped", "tag")
 
     def __init__(self, body, args: Sequence[Any], nvalues: int):
         """``args``: one task's staged argument list (``_stage_task_
@@ -63,7 +75,10 @@ class ValuePlan:
         scalar among ``args`` can only be a value."""
         scalars = [i for i, a in enumerate(args)
                    if type(a) in (int, float, bool)]
-        read = _read_leaves(body, args) if scalars else ()
+        unborn = [i for i, a in enumerate(args)
+                  if isinstance(a, jax.ShapeDtypeStruct)]
+        tiles = [i for i, a in enumerate(args) if isinstance(a, jax.Array)]
+        read = _read_leaves(body, args) if scalars or tiles else ()
         #: per position: None (an argument of the program), or (how,
         #: index in its vector | placeholder)
         routes: List[Any] = [None] * len(args)
@@ -79,6 +94,12 @@ class ValuePlan:
             else:
                 routes[i] = (_BOOL if t is bool else _INT, len(int_at))
                 int_at.append(i)
+        for i in unborn:
+            routes[i] = (_UNBORN, args[i])
+        for i in tiles:
+            if not read[i]:
+                routes[i] = (_UNREAD, jax.ShapeDtypeStruct(
+                    args[i].shape, args[i].dtype))
         self.routes = tuple(routes)
         #: positions that ride the integer / the floating vector
         self.int_at, self.float_at = tuple(int_at), tuple(float_at)
@@ -86,11 +107,21 @@ class ValuePlan:
         self.packed = len(int_at) + len(float_at)
         self.dropped = len(scalars) - self.packed
         self.positional = nvalues - len(scalars)
+        #: tile arguments that are no argument of the program
+        self.tiles_dropped = sum(
+            1 for r in routes if r and r[0] in (_UNBORN, _UNREAD))
         #: part of the program's content key: an executable stored for
         #: another argument list is never loaded for this one.  Empty
         #: when every argument is passed as it always was.
+        #: (A tile that is no argument is not in the call's signature:
+        #: its shape and dtype, which the zeros inside the trace take,
+        #: are named here.)
         self.tag = ("vargs", "".join(
-            r[0] if r else "-" for r in routes)) if scalars else ()
+            r[0] if r else "-" for r in routes)) if any(routes) else ()
+        if self.tiles_dropped:
+            self.tag += tuple(
+                (tuple(r[1].shape), str(r[1].dtype)) for r in routes
+                if r and r[0] in (_UNBORN, _UNREAD))
 
     def flatten(self, tasks_args: Sequence[Sequence[Any]]) -> List[Any]:
         """The program's argument list for these tasks: each task's kept
@@ -125,6 +156,8 @@ class ValuePlan:
                     args.append(next(kept))
                 elif how == _DROP:
                     args.append(x)
+                elif how in (_UNBORN, _UNREAD):
+                    args.append(jnp.zeros(x.shape, x.dtype))
                 elif how == _FLOAT:
                     args.append(_weak(fvec[t * nfloat + x]))
                 elif how == _BOOL:  # a Python bool traces strong-typed

@@ -129,6 +129,12 @@ def copy_home(src: Data, dst: Data) -> None:
 def stage_to_cpu(data: Data) -> np.ndarray:
     """Materialize the newest version of ``data`` as the CPU copy."""
     newest = data.newest_copy()
+    if data.scratch is not None and (newest is None
+                                     or newest.payload is None):
+        # a scratch tile nobody has written: it is born here, zeroed
+        from ..device import scratch
+
+        return scratch.host_zeros(data)
     if newest is None:
         raise RuntimeError(f"{data!r} has no valid copy")
     if newest.device_index == 0:

@@ -679,6 +679,7 @@ class NativeExecutor:
             ("data", data_of_slot(k),
              AccessMode(m) if m else AccessMode.IN)
             for k, m in zip(plan.slot_keys, plan.slot_modes)]
+        self._use_scratch(s[1] for s in task.body_args)
         task.native_id = native_id
 
         # cross-tile write-backs of EVERY member, landed at the one
@@ -697,6 +698,7 @@ class NativeExecutor:
         task._wbs = [(src_data,
                       self.taskpool.constants[cname2].data_of(*key))
                      for (src_data, cname2, key) in wbs]
+        self._use_scratch(src for (src, _c, _k) in wbs)  # never released
         self._pump_index[native_id] = task
 
         def on_complete(t: Task) -> None:
@@ -769,8 +771,11 @@ class NativeExecutor:
 
     def _data_for(self, srckey: Tuple):
         """Data object behind a resolved flow chain (the device-path
-        sibling of :meth:`_payload`)."""
-        from ..data.data import data_create
+        sibling of :meth:`_payload`).  A chain that starts at ``NEW`` is
+        a scratch tile (device/scratch.py): no payload, born where its
+        first task runs; every task built over it declares itself one
+        of its users (:meth:`_use_scratch`)."""
+        from ..device import scratch
 
         if srckey[0] == "remote":
             raise RuntimeError(
@@ -783,10 +788,37 @@ class NativeExecutor:
         if d is None:
             _, (pc_name, _locs), fname = srckey
             shape, dtype = self.taskpool.new_tile_spec(pc_name, fname)
-            d = self._new_data[srckey] = data_create(
-                ("native_new",) + tuple(srckey[1:]),
-                payload=np.zeros(shape, dtype))
+            d = self._new_data[srckey] = scratch.new(
+                ("native_new",) + tuple(srckey[1:]), shape, dtype)
         return d
+
+    @staticmethod
+    def _use_scratch(datas) -> None:
+        """Declare one user of each scratch tile among ``datas``.  A
+        device task releases it in its epilog; a user that never does (a
+        CPU body, a write-back of the tile into a collection) keeps the
+        tile for as long as its Data lives."""
+        from ..device import scratch
+
+        for d in datas:
+            if d is not None and d.scratch is not None:
+                scratch.add_users(d)
+
+    def _home_flows(self, pc, node) -> Tuple[int, ...]:
+        """Positions (in ``body_args``) of the flows whose output is the
+        version of its tile that the DAG sends home: the flow declares a
+        write-back into the collection and no successor takes it on to
+        write it again.  The device module hands only these to its
+        write-back committer; a version some later task overwrites stays
+        on the device (``detach`` still flushes whatever is dirty and
+        not home, so a flow this rule misses is late, never lost)."""
+        classes = self.taskpool.ptg.classes
+        rewritten = {
+            f for (f, succ, sf) in node.out_edges
+            if next(x.mode for x in classes[succ[0]].flows
+                    if x.name == sf) & AccessMode.OUT}
+        home = {f for (f, _c, _k) in node.write_backs} - rewritten
+        return tuple(f.index for f in pc.flows if f.name in home)
 
     def _scalars_of(self, pc, locs) -> Dict[str, Any]:
         consts = self.taskpool.constants
@@ -865,6 +897,8 @@ class NativeExecutor:
         for name in pc.param_names + pc.def_names + pc.body_globals:
             specs.append(("value", scalars[name], AccessMode.VALUE))
         task.body_args = specs
+        self._use_scratch(s[1] for s in specs if s[0] == "data")
+        task._tpu_home = self._home_flows(pc, node)
 
         wbs = self._write_back_plan(tid)
         ng = self._ng
@@ -872,6 +906,7 @@ class NativeExecutor:
         task._wbs = [(src_data,
                       self.taskpool.constants[cname2].data_of(*key))
                      for (src_data, cname2, key) in wbs]
+        self._use_scratch(src for (src, _c, _k) in wbs)  # never released
 
         def on_complete(t: Task) -> None:
             # the ONLY per-task Python on the completion side (legacy
@@ -917,6 +952,8 @@ class NativeExecutor:
         flow_specs = self._flow_data(tid, pc)
         scalars = self._scalars_of(pc, locs)
         wbs = self._write_back_plan(tid)
+        self._use_scratch([d for (_f, d, _m) in flow_specs]
+                          + [src for (src, _c, _k) in wbs])  # never released
         info = _TaskInfo(cname, locs)
         self._trace_objs[tid] = info
 

@@ -1188,14 +1188,49 @@ class PTGTaskpool(Taskpool):
         return tuple(shape), dtype
 
     def _new_tile(self, pc: PTGTaskClass, f: _PTGFlow, locals_: Tuple) -> Data:
+        """The scratch tile (device/scratch.py) of a flow with no source:
+        no payload, born where this task runs, its users declared from
+        the flow's out-dependencies."""
+        from ..device import scratch
+
         key = (pc.name, tuple(locals_), f.name)
         with self._new_lock:
             d = self._new_tiles.get(key)
             if d is None:
                 shape, dtype = self.new_tile_spec(pc.name, f.name)
-                d = data_create(key, payload=np.zeros(shape, dtype))
+                d = scratch.new(key, shape, dtype)
+                scratch.add_users(d, self._scratch_users(pc, f, locals_))
                 self._new_tiles[key] = d
             return d
+
+    def _scratch_users(self, pc: PTGTaskClass, f: _PTGFlow,
+                       locals_: Tuple) -> int:
+        """The tasks on this rank that will use the tile of ``f``: this
+        one and, down the chain of its out-dependencies, every successor
+        (the enumeration of :meth:`_release_deps_core`).  One more,
+        never released, where a task writes the tile into a collection
+        or sends it to another rank: that is read after its epilog."""
+        env = pc.env_of(locals_, self.constants)
+        myrank = self.context.rank if self.context else 0
+        n, kept = 1, False
+        for dep in f.deps_out:
+            t = dep.target(env)
+            if t is None or isinstance(t, (_NoneRef, _NewRef)):
+                continue
+            if isinstance(t, _DataRef):
+                kept = True
+                continue
+            succ_pc = self.ptg.classes[t.class_name]
+            sf = next(x for x in succ_pc.flows if x.name == t.flow_name)
+            for locs in _expand_args(t.args, env):
+                if len(locs) != len(succ_pc.param_names) \
+                        or not succ_pc.valid(locs, self.constants):
+                    continue
+                if succ_pc.rank_of(locs, self.constants) != myrank:
+                    kept = True  # sent from release_deps, like a write-back
+                    continue
+                n += self._scratch_users(succ_pc, sf, locs)
+        return n + kept
 
     # -- completion / successor release ----------------------------------
     def _make_release_deps(self, pc: PTGTaskClass):

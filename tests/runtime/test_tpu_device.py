@@ -600,7 +600,8 @@ def test_read_values_ride_one_vector_a_kind(ctx, dtype, alone):
         assert want[0].dtype == jnp.bfloat16  # a strong f32 would widen it
     n = 1 if alone else 8
     sigs = _call_signatures(dev)
-    assert len(sigs) == 1 and len(sigs[0]) == 2 * n + 2
+    # (``o`` is written, never read: no argument of the program either)
+    assert len(sigs) == 1 and len(sigs[0]) == n + 2
     # (int, bool) share the integer vector; the dtypes are those a
     # Python scalar traces to (the suite runs with x64 on)
     assert sigs[0][-2:] == (("a", (2 * n,), "int64", False),
@@ -630,7 +631,7 @@ def test_other_value_types_stay_positional_and_are_counted(ctx, alone):
     assert dev.stats["value_args_dropped"] == 8
     assert dev.stats["value_args_packed"] == 0
     sig, = _call_signatures(dev)
-    assert len(sig) == (4 if alone else 32)
+    assert len(sig) == (3 if alone else 24)   # x, s, v: ``o`` is unread
 
 
 @pytest.mark.parametrize("alone", [False, True], ids=["wave8", "alone"])
@@ -685,6 +686,59 @@ def test_store_written_under_the_old_key_is_not_hit(monkeypatch, tmp_path,
             _run_value_tasks(dev, _value_tasks(body, rows()), alone)
             assert ctx.compile_cache.stats["hits_disk"] == 1
             assert ctx.compile_cache.stats["misses"] == 0
+        finally:
+            ctx.fini()
+    finally:
+        mca_param.params.unset("runtime", "compile_cache_min_share_s")
+
+
+# ---------------------------------------------------------------------------
+# tile arguments the program does not take; programs named by class
+# ---------------------------------------------------------------------------
+
+@pytest.mark.parametrize("alone", [False, True], ids=["wave8", "alone"])
+def test_an_unread_tile_is_no_program_argument(ctx, alone):
+    dev = tpu_dev(ctx)
+
+    def body(x, o, k):
+        return o + 1.0          # reads neither x nor k
+
+    rows = [(np.full((8, 8), i, np.float32), np.full((8, 8), i, np.float32),
+             i) for i in range(8)]
+    outs = _run_value_tasks(dev, _value_tasks(body, rows), alone)
+    np.testing.assert_array_equal(np.asarray(outs[5]), 6.0)
+    assert dev.stats["tile_args_dropped"] == 8
+    assert dev.stats["value_args_dropped"] == 8
+    per_task = {len(sig) // (1 if alone else 8)
+                for sig in _call_signatures(dev)}
+    assert per_task == {1}      # ``o`` alone
+
+
+@pytest.mark.parametrize("exported", [False, True],
+                         ids=["plain_lowering", "exported_path"])
+def test_a_wave_program_carries_its_class_in_its_module_name(
+        monkeypatch, tmp_path, exported):
+    """``jit__wave_<class>`` on the device trace's ``XLA Modules`` line,
+    also for a program compiled through its serialized form."""
+    from parsec_tpu.utils import mca_param
+
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+    mca_param.set_param("runtime", "compile_cache_min_share_s",
+                        0.0 if exported else 1e9)
+    try:
+        ctx = Context(nb_cores=1)
+        try:
+            dev = tpu_dev(ctx)
+
+            def body(x, o, k):
+                return o + x * k
+            rows = [(np.ones((8, 8), np.float32),
+                     np.ones((8, 8), np.float32), 2) for _ in range(4)]
+            _run_value_tasks(dev, _value_tasks(body, rows), alone=False)
+            (cf, _plan), = dev._jit_cache.values()
+            exe, = cf._memo.values()
+            assert exe.as_text().startswith("HloModule jit__wave_valuetest")
+            assert ctx.compile_cache.store.count() == int(exported)
         finally:
             ctx.fini()
     finally:
