@@ -41,7 +41,8 @@ class Chore:
     ``parsec_internal.h:396-402``): a device type + hook, with an optional
     ``evaluate`` predicate deciding applicability per task."""
 
-    __slots__ = ("device_type", "hook", "evaluate", "enabled", "time_estimate", "body_fn")
+    __slots__ = ("device_type", "hook", "evaluate", "enabled", "time_estimate", "body_fn",
+                 "wave_key")
 
     def __init__(
         self,
@@ -58,6 +59,9 @@ class Chore:
         #: raw functional body for device execution (set by front-ends for
         #: accelerator chores; the device module jits and dispatches it)
         self.body_fn = None
+        #: the device module's memo for this chore: ``(body_fn it was
+        #: worked out for, what a wave signature starts with)``
+        self.wave_key: Optional[Tuple[Any, Any]] = None
 
 
 class TaskClass:
@@ -147,6 +151,7 @@ class Task:
         "_tpu_enq",
         "_tpu_scratch",
         "_tpu_home",
+        "_tpu_sig",
     )
 
     def __init__(
@@ -202,6 +207,10 @@ class Task:
         #: device module's write-back committer takes only these); None
         #: where whoever built the task does not know: then every one
         self._tpu_home: Optional[Tuple[int, ...]] = None
+        #: the task's wave signature, once the device module has worked
+        #: it out (a ready task's flows no longer change): None when it
+        #: cannot ride a wave, False until somebody asked
+        self._tpu_sig: Any = False
 
     @property
     def key(self) -> Any:

@@ -24,6 +24,9 @@ if TYPE_CHECKING:  # pragma: no cover
     from .collection import DataCollection
 
 
+_OUT = int(AccessMode.OUT)
+
+
 class Coherency(enum.Enum):
     """Reference PARSEC_DATA_COHERENCY_* (data.h:39-44)."""
 
@@ -155,6 +158,24 @@ class Data:
                     best = c
             return best
 
+    def current_copy(self, device_index: int) -> Optional[DataCopy]:
+        """The copy on ``device_index`` when it holds the tile at the
+        newest valid version (what :meth:`get_copy` and
+        :meth:`newest_copy` say together, under one hold of the lock);
+        None when that device has to stage the tile in first — also over
+        a custom-staged copy, which holds a packed representation and
+        not the tile."""
+        with self.lock:
+            mine = self.copies.get(device_index)
+            if mine is None or mine.payload is None \
+                    or mine.staged_by is not None:
+                return None
+            newest = -1
+            for c in self.copies.values():
+                if c.version > newest and c.coherency is not Coherency.INVALID:
+                    newest = c.version
+            return mine if 0 <= newest <= mine.version else None
+
     # -- coherency protocol ----------------------------------------------
     def transfer_ownership(self, device_index: int, access: AccessMode) -> DataCopy:
         """MOESI-like ownership transition before ``device_index`` touches
@@ -166,7 +187,7 @@ class Data:
             if copy is None:
                 copy = DataCopy(self, device_index)
                 self.copies[device_index] = copy
-            if access & AccessMode.OUT:
+            if int(access) & _OUT:  # plain ints: no enum arithmetic
                 # writer: invalidate all other replicas, become OWNED
                 for di, c in self.copies.items():
                     if di != device_index:
@@ -183,12 +204,19 @@ class Data:
                 copy.readers += 1
             return copy
 
-    def version_bump(self, device_index: int) -> int:
+    def version_bump(self, device_index: int,
+                     listening: Optional[bool] = None) -> int:
         """After a write completes on ``device_index``: new authoritative
-        version (reference: epilog version bump, ``device_gpu.c:2343``)."""
+        version (reference: epilog version bump, ``device_gpu.c:2343``).
+        ``listening``: whether ``DATA_VERSION_BUMP`` has a subscriber,
+        from a caller that asked once for many bumps."""
         with self.lock:
             copy = self.copies[device_index]
-            newv = max((c.version for c in self.copies.values()), default=0) + 1
+            newv = 0
+            for c in self.copies.values():
+                if c.version > newv:
+                    newv = c.version
+            newv += 1
             copy.version = newv
             copy.coherency = Coherency.OWNED
             self.owner_device = device_index
@@ -196,7 +224,9 @@ class Data:
         # checker flags two bumps with no dependency/completion/frame
         # path between them (RT001) — the version counter itself is
         # lock-serialized, but the payload writes it summarizes are not.
-        if pins.active(pins.DATA_VERSION_BUMP):
+        if listening is None:
+            listening = pins.active(pins.DATA_VERSION_BUMP)
+        if listening:
             pins.fire(pins.DATA_VERSION_BUMP, None,
                       {"data": self.data_id, "key": self.key,
                        "version": newv, "device": device_index})

@@ -68,10 +68,13 @@ class _StageJob:
     """One prestage request: a ready batch whose input tiles the lane
     stages while earlier waves compute."""
 
-    __slots__ = ("batch", "seq", "done", "error")
+    __slots__ = ("batch", "seq", "tiles", "done", "error")
 
-    def __init__(self, batch: List[Any], seq: int = 0):
+    def __init__(self, batch: List[Any], seq: int, tiles: List[Any]):
         self.batch = batch
+        #: the batch's tiles that are not on the device yet
+        #: (``TpuDevice.prestage_tiles``)
+        self.tiles = tiles
         #: the pump's number for this batch (the lane's span carries it)
         self.seq = seq
         self.done = threading.Event()
@@ -100,8 +103,9 @@ class StageLane:
             target=self._run, name=f"stage-lane:{dev.name}", daemon=True)
         self._thread.start()
 
-    def stage(self, batch: List[Any], seq: int = 0) -> _StageJob:
-        job = _StageJob(batch, seq)
+    def stage(self, batch: List[Any], seq: int,
+              tiles: List[Any]) -> _StageJob:
+        job = _StageJob(batch, seq, tiles)
         with self._cv:
             if self._stop:
                 job.done.set()  # closed lane: submit path stages
@@ -119,7 +123,7 @@ class StageLane:
                     return
                 job = self._jobs.popleft()
             try:
-                self._dev.prestage_batch(job.batch, job.seq)
+                self._dev.prestage_batch(job.batch, job.seq, job.tiles)
             except BaseException as e:  # must never kill the lane
                 job.error = e
             finally:
@@ -180,40 +184,58 @@ class WritebackCommitter:
 
     # -- producer side ---------------------------------------------------
     def enqueue(self, data, pool: int = 0, batch: int = 0) -> int:
-        """Queue a deferred write-back of ``data``'s dirty device copy
-        (``pool`` and ``batch``: the batch whose epilog wrote it, which
-        the ``dev:writeback`` span of the commit names as its cause).
+        """:meth:`enqueue_all` of one tile; returns its ticket."""
+        return self.enqueue_all((data,), pool, batch)[0]
+
+    def enqueue_all(self, datas, pool: int = 0, batch: int = 0,
+                    kick: bool = False) -> List[int]:
+        """Queue deferred write-backs of the dirty device copies of
+        ``datas`` in ONE round of the condition variable (``pool`` and
+        ``batch``: the batch whose epilog wrote them, which the
+        ``dev:writeback`` span of the commit names as its cause;
+        ``kick``: drain them now, below the watermark).
         Deduplicated per tile; bounded by a capacity wait at 4x the
         drain watermark so a stalled committer applies backpressure
         instead of accumulating unbounded dirty state.  Raises the
         stored committer error if the committer died — the caller's
-        fail-loudly discipline turns that into a pool failure."""
-        ticket = next(self._tickets)
+        fail-loudly discipline turns that into a pool failure.
+        Returns one ticket a tile."""
         self._cause = (pool, batch)
-        if pins.active(pins.HB_WB_ENQUEUE):
-            # release edge: the enqueuing thread just committed this
-            # task's epilog — its clock must reach the commit
-            pins.fire(pins.HB_WB_ENQUEUE, None,
-                      {"ticket": ticket, "data": data.data_id})
-        c = data.get_copy(self._dev.data_index)
-        nb = c.nbytes if c is not None else 0
+        heard = pins.active(pins.HB_WB_ENQUEUE)
+        index = self._dev.data_index
+        tickets: List[int] = []
+        entries = []
+        for data in datas:
+            ticket = next(self._tickets)
+            if heard:
+                # release edge: the enqueuing thread just committed this
+                # task's epilog — its clock must reach the commit
+                pins.fire(pins.HB_WB_ENQUEUE, None,
+                          {"ticket": ticket, "data": data.data_id})
+            c = data.get_copy(index)
+            entries.append((data, ticket, c.nbytes if c is not None else 0))
+            tickets.append(ticket)
+        cap = 4 * self._window
         with self._cv:
             self._raise_if_dead()
-            cap = 4 * self._window
-            while (self._pending_bytes + nb > cap and self._pending
-                   and self.error is None and not self._stop):
-                self.stats["capacity_waits"] += 1
-                self._cv.wait(timeout=1.0)
-            self._raise_if_dead()
-            entry = self._pending.get(data.data_id)
-            if entry is None:
-                self._pending[data.data_id] = (data, [ticket], nb)
-                self._pending_bytes += nb
-            else:
-                entry[1].append(ticket)
-            self.stats["enqueued"] += 1
+            for data, ticket, nb in entries:
+                while (self._pending_bytes + nb > cap and self._pending
+                       and self.error is None and not self._stop):
+                    self.stats["capacity_waits"] += 1
+                    self._cv.notify_all()  # what is queued may drain
+                    self._cv.wait(timeout=1.0)
+                self._raise_if_dead()
+                entry = self._pending.get(data.data_id)
+                if entry is None:
+                    self._pending[data.data_id] = (data, [ticket], nb)
+                    self._pending_bytes += nb
+                else:
+                    entry[1].append(ticket)
+                self.stats["enqueued"] += 1
+            if kick:
+                self._kick = True
             self._cv.notify_all()
-        return ticket
+        return tickets
 
     def _raise_if_dead(self) -> None:
         if self.error is not None:

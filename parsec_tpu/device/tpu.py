@@ -54,7 +54,8 @@ from ..compile_cache import argsig
 from ..data.data import Coherency, Data, DataCopy
 from . import scratch
 from .device import Device
-from .value_args import ValuePlan
+from .value_args import (ABSENT, PLACEHOLDER, SCRATCH, VALUE, FlowPlan,
+                         ValuePlan)
 
 
 def _unalias(arr, x, guard, jdev):
@@ -100,6 +101,9 @@ def private_device_put(x, jdev=None, *, guard=None):
     return _unalias(arr, x, guard, jdev)
 
 
+_OUT = int(AccessMode.OUT)
+
+
 def _placeholders_at(dev_args) -> Tuple[int, ...]:
     """Positions of a staged argument list that stand for a tile there
     was nothing to stage for (``TpuDevice._placeholder``): part of a
@@ -113,6 +117,11 @@ def _pool_of(task: Task) -> int:
     """The ``pool`` a span of ``task`` carries: its taskpool's id (0 for
     a stand-in pool that has none)."""
     return getattr(task.taskpool, "taskpool_id", 0)
+
+
+#: one task of a wave chunk as ``_stage_chunk`` leaves it: the task, its
+#: staged argument list, its ``(position in body_args, tile)`` outputs
+_Staged = Tuple[Task, List[Any], List[Tuple[int, Data]]]
 
 
 class _InFlight:
@@ -170,6 +179,15 @@ class TpuDevice(Device):
         #: a CPU body wrote it)
         self.stats.update(scratch_tiles_born=0, scratch_tiles_freed=0,
                           scratch_bytes_in=0, scratch_bytes_out=0)
+        #: who committed the outputs: chunks by the wave epilog, tasks
+        #: one by one (together: ``executed_tasks``); and the pump's
+        #: batches whose tiles were all resident, with nothing in them
+        #: for the transfer lane to move
+        self.stats.update(wave_commits=0, task_commits=0,
+                          prestage_skipped=0)
+        #: one :class:`FlowPlan` per distinct list of flows a wave
+        #: signature names (bounded by the task classes' layouts)
+        self._flow_plans: Dict[Any, FlowPlan] = {}
         # rank → chip binding: each rank's runtime drives its OWN device
         # (reference: one CUDA module instance per visible GPU with
         # per-rank visibility, device_gpu.c).  Only process-addressable
@@ -439,8 +457,7 @@ class TpuDevice(Device):
         for task in tasks:
             if getattr(task.taskpool, "failed", False):
                 continue
-            sig = (self._wave_signature(task)
-                   if self._wave_min > 0 else None)
+            sig = self._signature_of(task) if self._wave_min > 0 else None
             if sig is None:
                 units.append(("single", task))
                 continue
@@ -655,52 +672,83 @@ class TpuDevice(Device):
     # ------------------------------------------------------------------
     # stage_in / submit
     # ------------------------------------------------------------------
-    def _wave_signature(self, task: Task):
-        """Hashable batching signature, or None when the task cannot ride
-        a wave: bodies with baked static values (per-task traces),
-        donation (aliasing across a shared program is unsafe), or custom
-        staging hooks are excluded; data args must have knowable shapes.
-        Two tasks with equal signatures trace identically through the
-        shared wave program."""
-        body = task.selected_chore.body_fn if task.selected_chore else None
-        if body is None or getattr(body, "_static_values", False) \
+    def _signature_of(self, task: Task):
+        """:meth:`_wave_signature`, asked once a task: the flows of a
+        ready task no longer change (whoever writes one of its tiles
+        completed before it became ready), so the pump's look ahead and
+        the submit share the answer."""
+        sig = task._tpu_sig
+        if sig is False:
+            sig = task._tpu_sig = self._wave_signature(task)
+        return sig
+
+    @staticmethod
+    def _wave_body_key(body):
+        """What a wave signature starts with, or None for a body whose
+        tasks go out alone: bodies with baked static values (per-task
+        traces), donation (aliasing across a shared program is unsafe)
+        or custom staging hooks; fused supertasks (dsl.fusion) are
+        already coarse-grained multi-body programs with their own cache
+        key — re-batching them into waves would nest programs for no
+        dispatch win."""
+        if getattr(body, "_static_values", False) \
                 or getattr(body, "_donate_args", None) \
                 or getattr(body, "_stage_in", None) \
                 or getattr(body, "_stage_out", None) \
                 or getattr(body, "_fused_n", 0):
-            # fused supertasks (dsl.fusion) are already coarse-grained
-            # multi-body programs with their own cache key — re-batching
-            # them into waves would nest programs for no dispatch win
             return None
-        sig: List[Any] = [getattr(body, "_jit_key", None) or id(body)]
+        return getattr(body, "_jit_key", None) or id(body)
+
+    def _wave_signature(self, task: Task):
+        """Hashable batching signature ``(body key, FlowPlan)``, or None
+        when the task cannot ride a wave (:meth:`_wave_body_key`; data
+        args must have knowable shapes).  Two tasks with equal
+        signatures trace identically through the shared wave program.
+        What the body fixes is asked once a chore; shapes, dtypes and
+        modes are compared as the objects they are, and the list of
+        flows is interned as its :class:`FlowPlan`, so a signature
+        hashes and compares by identity from then on."""
+        chore = task.selected_chore
+        body = chore.body_fn if chore is not None else None
+        if body is None:
+            return None
+        memo = chore.wave_key
+        if memo is None or memo[0] is not body:
+            memo = chore.wave_key = (body, self._wave_body_key(body))
+        if memo[1] is None:
+            return None
+        flows: List[Any] = []
         for kind, payload, mode in (task.body_args or ()):
             if kind == "data":
                 if payload is None:
-                    sig.append(("none",))
-                    continue
-                if scratch.unborn(payload):
-                    # no argument of the program: never in one wave with
-                    # a task whose tile of this flow has been written
-                    sig.append(("unborn", tuple(payload.shape),
-                                str(payload.dtype), int(mode)))
+                    flows.append(None)
                     continue
                 shape, dtype = payload.shape, payload.dtype
+                if payload.scratch is not None and scratch.unborn(payload):
+                    # no argument of the program: never in one wave with
+                    # a task whose tile of this flow has been written
+                    flows.append(("unborn", tuple(shape), dtype, mode))
+                    continue
                 if shape is None or dtype is None:
                     newest = payload.newest_copy()
                     p = getattr(newest, "payload", None)
                     shape = getattr(p, "shape", None)
                     dtype = getattr(p, "dtype", None)
-                if shape is None or dtype is None:
-                    return None
-                sig.append(("data", tuple(shape), str(dtype), int(mode)))
+                    if shape is None or dtype is None:
+                        return None
+                flows.append((tuple(shape), dtype, mode))
             elif kind == "value":
                 # traced runtime arg: the TYPE shapes the trace
-                sig.append(("value", type(payload).__name__))
+                flows.append(type(payload))
             elif kind == "scratch":
-                sig.append(("scratch", tuple(payload[0]), str(payload[1])))
+                flows.append(("scratch", tuple(payload[0]), payload[1]))
             else:
-                sig.append((kind,))
-        return tuple(sig)
+                flows.append(kind)
+        key = tuple(flows)
+        plan = self._flow_plans.get(key)
+        if plan is None:
+            plan = self._flow_plans[key] = FlowPlan(key)
+        return (memo[1], plan)
 
     def _submit_wave(self, tasks: List[Task], es, complete: bool = True,
                      drained_ns: int = 0) -> None:
@@ -722,11 +770,15 @@ class TpuDevice(Device):
         epilogs by then — the fallback does not double-run them only
         because each committed task is marked ``_tpu_completed``, which
         the manager-loop fallback checks before resubmitting.  Once a
-        task's epilog begins, errors are contained HERE with a loud pool
-        fail (the same discipline as ``_submit_one``'s completed
-        branch): a half-committed task must be neither retried
+        chunk's commit begins, errors are contained HERE with a loud
+        pool fail (the same discipline as ``_submit_one``'s completed
+        branch): a half-committed chunk must be neither retried
         (double-apply) nor silently skipped (wait() would hang to
         timeout).
+
+        What the wave's tasks share is asked ONCE: their signature's
+        :class:`FlowPlan` drives one residency pass (``_stage_chunk``)
+        and one commit (``_commit_chunk``) per chunk.
 
         One ``dev:wave`` span per chunk, that is per device program,
         with the children ``dev:stage_args``, ``dev:jit``,
@@ -735,6 +787,10 @@ class TpuDevice(Device):
         body = tasks[0].selected_chore.body_fn
         cls = tasks[0].task_class.name
         self._span_pool = _pool_of(tasks[0])
+        sig = self._signature_of(tasks[0])
+        if sig is None:
+            raise ValueError(f"{tasks[0]!r} cannot ride a wave")
+        plan = sig[1]
         # the body OBJECT (not id(body)): an id-keyed entry outlives the
         # body it described, and a recycled id would serve a dead body's
         # wave program — keying on the object pins it alive instead,
@@ -751,30 +807,24 @@ class TpuDevice(Device):
                          for t in grp) // 1000 if drained_ns else 0
             with self._span("dev:wave", cls=cls, n=cnt,
                             batch=self._span_batch, waited_us=waited) as sp:
-                self._submit_chunk(grp, body, base_key, es, complete, sp)
+                self._submit_chunk(grp, body, base_key, plan, es, complete,
+                                   sp)
 
-    def _submit_chunk(self, grp: List[Task], body, base_key, es,
-                      complete: bool, wave_span) -> None:
+    def _submit_chunk(self, grp: List[Task], body, base_key,
+                      fplan: FlowPlan, es, complete: bool, wave_span) -> None:
         """One power-of-2 chunk of a wave: stage, look the program up,
         dispatch it, commit every task's outputs.  The program's
         arguments are the tasks' tiles; their values reach the bodies as
         the program's :class:`ValuePlan` says."""
-        from ..core import scheduling
-
         cnt = len(grp)
         cls = grp[0].task_class.name
         with self._span("dev:stage_args") as sp:
-            tally = [0, 0, 0]  # host tiles, their bytes, tiles staged
-            if self.stage_depth > 1:
-                # tentpole (c): coalesce this chunk's host->device tile
-                # transfers into one batched put; staging stays PER
-                # CHUNK (PR 1 invariant above), and _stage_task_args
-                # below finds the tiles already resident so the per-tile
-                # path degenerates to cache hits
-                self._stage_in_batch(self._collect_stage_tiles(grp), tally)
-            gst = [self._stage_task_args(t, body, tally) for t in grp]
-            sp.note(host_tiles=tally[0], bytes=tally[1], tiles=tally[2])
-        args0, nout = gst[0][0], len(gst[0][1])
+            # host tiles, their bytes, tiles staged, residency hits
+            tally = [0, 0, 0, 0]
+            staged = self._stage_chunk(grp, fplan, tally)
+            sp.note(host_tiles=tally[0], bytes=tally[1], tiles=tally[2],
+                    hits=tally[3])
+        args0, nout = staged[0][1], fplan.nout
 
         def build():
             plan = self._value_plan(grp[0], body, args0)
@@ -794,13 +844,15 @@ class TpuDevice(Device):
         jitted, plan = self._cached_jit(
             ("wave", cls, base_key, argsig(args0), _placeholders_at(args0), nout,
              cnt), build)
-        flat = plan.flatten([dargs for (dargs, _, _) in gst])
-        for t in grp:
-            self._fire_exec(t, pins.EXEC_BEGIN, wave=cnt)
+        flat = plan.flatten([args for (_t, args, _o) in staged])
+        if pins.active(pins.EXEC_BEGIN):
+            for t in grp:
+                self._fire_exec(t, pins.EXEC_BEGIN, wave=cnt)
         with self._span("dev:dispatch"):
             outs = jitted(*flat)
-        for t in grp:
-            self._fire_exec(t, pins.EXEC_END, wave=cnt)
+        if pins.active(pins.EXEC_END):
+            for t in grp:
+                self._fire_exec(t, pins.EXEC_END, wave=cnt)
         self._count_values(plan, cnt, wave_span, len(outs))
         if len(outs) != nout * cnt:
             raise ValueError(
@@ -808,34 +860,104 @@ class TpuDevice(Device):
                 f"{len(outs)} outputs for {nout * cnt} writable flows")
         self.stats["wave_submits"] = self.stats.get("wave_submits", 0) + 1
         self.stats["wave_tasks"] = self.stats.get("wave_tasks", 0) + cnt
-        pos = 0
-        with self._span("dev:epilog"):
-            for task, (dargs, ospecs, ohooks) in zip(grp, gst):
-                inflight = _InFlight(task, list(outs[pos:pos + nout]),
-                                     ospecs, ohooks)
-                pos += nout
+        with self._span("dev:epilog") as sp:
+            if self._eager:
+                self._commit_chunk(staged, outs, nout, es, complete, sp)
+                return
+            for k, (task, _args, ospecs) in enumerate(staged):
                 if getattr(task.taskpool, "failed", False):
                     continue  # a sibling's failure already took the pool
-                if self._eager:
-                    task._tpu_effects = True
-                    try:
-                        self._epilog(inflight)
-                        task._tpu_completed = True
-                        if complete:
-                            scheduling.complete_execution(self.context, es,
-                                                          task)
-                    except Exception as e:
-                        debug.error("wave epilog/completion of %r "
-                                    "failed: %s", task, e)
-                        self._fail_task_pool(
-                            task,
-                            f"device epilog/completion raised: {e!r}")
-                        task._tpu_completed = True  # never resubmit
+                lane = self._lanes[self._rr % self._nlanes]
+                self._rr += 1
+                lane.append(_InFlight(
+                    task, list(outs[k * nout:(k + 1) * nout]), ospecs))
+                task._tpu_completed = True  # owned by the lane now
+
+    def _stage_chunk(self, grp: List[Task], fplan: FlowPlan,
+                     tally: List[int]) -> List[_Staged]:
+        """kernel_push for one chunk of a wave, in ONE pass under ONE
+        hold of the residency lock: ``(task, dev_args, out_specs)`` per
+        task, by the signature's :class:`FlowPlan`.  A tile that is
+        resident and current yields its payload and one LRU touch a
+        chunk; the others go into the one coalesced put
+        (``_stage_in_batch``; tile by tile in the synchronous regime);
+        ownership moves only once every tile of the chunk is resident,
+        so an error in here raises with no task of the chunk touched.
+        ``tally`` counts for the ``dev:stage_args`` span: ``[tiles
+        copied from the host, their bytes, tiles staged, of them found
+        resident]``."""
+        idx = self.data_index
+        steps = fplan.steps
+        staged: List[_Staged] = []
+        owns: List[Tuple[Data, int]] = []
+        found: Dict[int, Any] = {}  # data_id -> payload on this device
+        #: data_id -> [tile, (argument list, position) it still misses in]
+        missing: Dict[int, List[Any]] = {}
+        ntiles = nread = nmiss = 0
+        with self._res_lock:
+            for task in grp:
+                specs = task.body_args
+                args: List[Any] = []
+                ospecs: List[Tuple[int, Data]] = []
+                mine: List[Data] = []  # scratch tiles: one user each
+                for how, pos, access, extra in steps:
+                    if how == VALUE:
+                        args.append(specs[pos][1])
+                        continue
+                    if how == SCRATCH:
+                        args.append(jnp.zeros(extra[0], extra[1],
+                                              device=self.jdev))
+                        continue
+                    if how == ABSENT:
+                        args.append(None)
+                        continue
+                    data = specs[pos][1]
+                    if data.scratch is not None:
+                        mine.append(data)
+                    if how == PLACEHOLDER:
+                        arr = extra
+                    else:
+                        nread += 1
+                        did = data.data_id
+                        arr = found.get(did)
+                        if arr is None:
+                            c = data.current_copy(idx)
+                            if c is not None:
+                                arr = found[did] = c.payload
+                                self._lru_touch(
+                                    data,
+                                    dirty=c.coherency is Coherency.OWNED)
+                            else:
+                                slot = missing.get(did)
+                                if slot is None:
+                                    slot = missing[did] = [data]
+                                slot.append((args, len(args)))
+                    args.append(arr)
+                    ntiles += 1
+                    owns.append((data, access))
+                    if access & _OUT:
+                        ospecs.append((pos, data))
+                staged.append((task, args, ospecs))
+                task._tpu_scratch = mine
+            if missing:
+                tiles = [slot[0] for slot in missing.values()]
+                if self.stage_depth > 1:
+                    # tentpole (c) of the staging pipeline: the chunk's
+                    # host->device transfers as one batched put
+                    self._stage_in_batch(tiles, tally)
                 else:
-                    lane = self._lanes[self._rr % self._nlanes]
-                    self._rr += 1
-                    lane.append(inflight)
-                    task._tpu_completed = True  # owned by the lane now
+                    for data in tiles:
+                        self._stage_in(data, tally)
+                for slot in missing.values():
+                    arr = slot[0].get_copy(idx).payload
+                    for args, at in slot[1:]:
+                        args[at] = arr
+                    nmiss += len(slot) - 1
+            for data, access in owns:
+                data.transfer_ownership(idx, access)
+        tally[2] += ntiles
+        tally[3] += nread - nmiss
+        return staged
 
     def _stage_task_args(self, task: Task, body,
                          tally: Optional[List[int]] = None):
@@ -1023,8 +1145,9 @@ class TpuDevice(Device):
             # the epilog mutates output tiles one by one (rebind +
             # version bump): once entered, a retry would double-apply
             task._tpu_effects = True
-            with self._span("dev:epilog"):
-                self._epilog(inflight)
+            with self._span("dev:epilog") as sp:
+                sp.note(n=1, outs=len(out_specs),
+                        home=self._epilog(inflight))
                 task._tpu_completed = True
                 if complete:
                     scheduling.complete_execution(self.context, es, task)
@@ -1173,12 +1296,22 @@ class TpuDevice(Device):
         (tentpole (c) — one enqueue RPC for the wave's transfers instead
         of one per tile), each result re-checked against the per-tile
         aliasing guard.  Returns bytes moved host->device; the put is
-        the ``dev:h2d`` span."""
+        the ``dev:h2d`` span.
+
+        The residency lock is held to decide what moves and to make room
+        for it, and again to attach what arrived — NOT over the put: the
+        transfer lane's put of the next batch (10 ms for 27 tiles of 1
+        MiB on a v5e) used to hold the pump's staging and epilog of the
+        current one for its whole length (``PERF.md`` §6, PR 27).  A tile
+        that somebody else staged or wrote in between keeps their copy.
+        (The pump's own call, from ``_stage_chunk``, holds the lock
+        around all of it, as it always did: nobody waits for it.)"""
         moved = 0
+        idx = self.data_index
+        puts: List[Tuple[Data, np.ndarray, int]] = []
         with self._res_lock:
-            puts: List[Tuple[Data, np.ndarray, int]] = []
             for data in datas:
-                mine = data.get_copy(self.data_index)
+                mine = data.get_copy(idx)
                 if mine is not None and getattr(mine, "staged_by", None) is not None:
                     self._drop_copy(data, evicted=False)
                     mine = None
@@ -1198,7 +1331,7 @@ class TpuDevice(Device):
                     self._hbm_realloc(data, old, newest.payload.nbytes)
                     arr = jax.device_put(newest.payload, self.jdev)
                     self.stats["bytes_d2d"] += newest.payload.nbytes
-                    c = data.attach_copy(self.data_index, arr)
+                    c = data.attach_copy(idx, arr)
                     c.version = newest.version
                     self._lru_touch(data, dirty=False)
                     moved += newest.payload.nbytes
@@ -1206,73 +1339,115 @@ class TpuDevice(Device):
                 host = np.asarray(newest.payload)
                 self._hbm_realloc(data, old, host.nbytes)
                 puts.append((data, host, newest.version))
-            if puts:
-                nbytes = sum(h.nbytes for (_d, h, _v) in puts)
-                with self._span("dev:h2d", tiles=len(puts), bytes=nbytes):
-                    try:
-                        arrs = jax.device_put([h for (_d, h, _v) in puts],
-                                              self.jdev)
-                    except Exception:
-                        # backend rejected the coalesced put: per-tile path
-                        self.stats["stage_batch_fallbacks"] += 1
-                        arrs = [private_device_put(h, self.jdev, guard=h)
-                                for (_d, h, _v) in puts]
-                    else:
-                        arrs = [_unalias(a, h, h, self.jdev)
-                                for a, (_d, h, _v) in zip(arrs, puts)]
-                if tally is not None:
-                    tally[0] += len(puts)
-                    tally[1] += nbytes
-                for (data, host, ver), arr in zip(puts, arrs):
-                    self.stats["bytes_in"] += host.nbytes
-                    if data.scratch is not None:
-                        self.stats["scratch_bytes_in"] += host.nbytes
-                    c = data.attach_copy(self.data_index, arr)
-                    c.version = ver
-                    self._lru_touch(data, dirty=False)
-                    moved += host.nbytes
-                self.stats["stage_batched_puts"] = \
-                    self.stats.get("stage_batched_puts", 0) + 1
-                self.stats["stage_batched_tiles"] = \
-                    self.stats.get("stage_batched_tiles", 0) + len(puts)
+        if not puts:
+            return moved
+        nbytes = sum(h.nbytes for (_d, h, _v) in puts)
+        try:
+            with self._span("dev:h2d", tiles=len(puts), bytes=nbytes):
+                try:
+                    arrs = jax.device_put([h for (_d, h, _v) in puts],
+                                          self.jdev)
+                except Exception:
+                    # backend rejected the coalesced put: per-tile path
+                    self.stats["stage_batch_fallbacks"] += 1
+                    arrs = [private_device_put(h, self.jdev, guard=h)
+                            for (_d, h, _v) in puts]
+                else:
+                    arrs = [_unalias(a, h, h, self.jdev)
+                            for a, (_d, h, _v) in zip(arrs, puts)]
+        except BaseException:
+            with self._res_lock:  # the room made for what never arrived
+                for (data, _h, _v) in puts:
+                    mine = data.get_copy(idx)
+                    if mine is None or mine.payload is None:
+                        self._hbm_free(data, 0)
+            raise
+        if tally is not None:
+            tally[0] += len(puts)
+            tally[1] += nbytes
+        with self._res_lock:
+            for (data, host, ver), arr in zip(puts, arrs):
+                self.stats["bytes_in"] += host.nbytes
+                if data.scratch is not None:
+                    self.stats["scratch_bytes_in"] += host.nbytes
+                moved += host.nbytes
+                mine = data.get_copy(idx)
+                if mine is not None and mine.payload is not None \
+                        and mine.version >= ver \
+                        and getattr(mine, "staged_by", None) is None:
+                    continue  # staged or written meanwhile: theirs stands
+                c = data.attach_copy(idx, arr)
+                c.version = ver
+                self._lru_touch(data, dirty=False)
+            self.stats["stage_batched_puts"] = \
+                self.stats.get("stage_batched_puts", 0) + 1
+            self.stats["stage_batched_tiles"] = \
+                self.stats.get("stage_batched_tiles", 0) + len(puts)
         return moved
 
-    def prestage_bytes(self, tasks: List[Task]) -> int:
-        """Cheap upper bound on the host->device bytes a prestage of
-        ``tasks`` would move — the pump's intra-wave split heuristic:
-        re-slicing a ready batch across the prefetch window only pays
-        when there is real transfer work to hide.  Deliberately
-        lock-free: a stale read merely mis-sizes the hint."""
-        total = 0
-        for data in self._collect_stage_tiles(tasks):
-            mine = data.get_copy(self.data_index)
+    def prestage_tiles(self, tasks: List[Task]) -> Tuple[List[Data], int]:
+        """The pump's look ahead at a ready batch: the tiles a prestage
+        of ``tasks`` would move — read by one of them and not current on
+        this device — and their bytes.  A batch with none has nothing
+        for the transfer lane; the bytes are the pump's intra-wave split
+        heuristic (re-slicing a ready batch across the prefetch window
+        only pays when there is real transfer work to hide).  One pass
+        by the tasks' signatures, without the residency lock: a stale
+        read merely mis-sizes the hint, and the submit path stages what
+        the lane did not."""
+        idx = self.data_index
+        seen = set()
+        alone: List[Task] = []
+        tiles: List[Data] = []
+        for task in tasks:
+            sig = self._signature_of(task)
+            if sig is None:
+                alone.append(task)
+                continue
+            specs = task.body_args
+            for pos in sig[1].reads:
+                data = specs[pos][1]
+                if data.data_id not in seen:
+                    seen.add(data.data_id)
+                    tiles.append(data)
+        # a task that goes out alone (hooks, donation, ...) names its
+        # plain input tiles the long way
+        tiles += [d for d in self._collect_stage_tiles(alone)
+                  if d.data_id not in seen]
+        moving: List[Data] = []
+        nbytes = 0
+        for data in tiles:
+            if data.current_copy(idx) is not None:
+                continue  # residency hit: no transfer
             newest = data.newest_copy()
             if newest is None or newest.payload is None:
                 continue
-            if mine is not None and mine.payload is not None \
-                    and getattr(mine, "staged_by", None) is None \
-                    and mine.version >= newest.version:
-                continue  # residency hit: no transfer
-            total += int(getattr(newest.payload, "nbytes", 0))
-        return total
+            moving.append(data)
+            nbytes += int(getattr(newest.payload, "nbytes", 0))
+        return moving, nbytes
 
-    def prestage_batch(self, tasks: List[Task], batch_no: int = 0) -> None:
+    def prestage_batch(self, tasks: List[Task], batch_no: int,
+                       tiles: List[Data]) -> None:
         """Transfer-lane half of the double-buffered pipeline: stage the
-        NEXT ready batch's input tiles while the current wave computes,
-        so the pump's submit pass reuse-hits them.  A ``dev:stage_in``
-        span (critpath's transfer bucket; ``batch`` is the pump's number
-        for the batch) and publishes the lane's clock into each task's hb
-        token — stage_in happens-before exec."""
+        NEXT ready batch's input tiles (``tiles``: what
+        :meth:`prestage_tiles` found missing) while the current wave
+        computes, so the pump's submit pass reuse-hits them.  A
+        ``dev:stage_in`` span (critpath's transfer bucket; ``batch`` is
+        the pump's number for the batch) and publishes the lane's clock
+        into each task's hb token — stage_in happens-before exec."""
         from .staging import _SPAN_SEQ
 
-        datas = self._collect_stage_tiles(tasks)
         if tasks:  # the lane runs ahead of the batch's own submit
             self._span_pool = _pool_of(tasks[0])
         with self._span("dev:stage_in", id=next(_SPAN_SEQ),
-                        tiles=len(datas), batch=batch_no) as sp:
-            sp.note(bytes=self._stage_in_batch(datas))
+                        tiles=len(tiles), batch=batch_no) as sp:
+            # a batch with nothing to move: the span, for whoever reads
+            # the lane by batch, and neither the lock nor a walk
+            sp.note(bytes=self._stage_in_batch(tiles) if tiles else 0)
+        if not tiles:
+            return
         self.stats["prefetched_tiles"] = \
-            self.stats.get("prefetched_tiles", 0) + len(datas)
+            self.stats.get("prefetched_tiles", 0) + len(tiles)
         if pins.active(pins.HB_STAGE_IN):
             for task in tasks:
                 pins.fire(pins.HB_STAGE_IN, None, {"task": task})
@@ -1372,6 +1547,13 @@ class TpuDevice(Device):
 
     def _hbm_realloc_locked(self, data: Data, old_nbytes: int,
                             new_nbytes: int) -> None:
+        held = (self._offsets.get(data.data_id, (0, 0))[1]
+                if self._zone is not None
+                else self._accounted.get(data.data_id, 0))
+        if new_nbytes > 0 and held == new_nbytes:
+            # the same bytes rebound (an epilog's output over its input):
+            # the slot stays, nothing is allocated, nobody is evicted
+            return
         # the allocatee must not be its own eviction victim (either mode):
         # callers re-touch the LRU right after accounting
         self._lru_clean.pop(data.data_id, None)
@@ -1567,18 +1749,55 @@ class TpuDevice(Device):
                 progressed = True
         return progressed
 
-    def _epilog(self, inflight: _InFlight) -> None:
-        """Commit outputs: rebind device copies, bump versions, keep tiles
-        resident & dirty (reference kernel_epilog device_gpu.c:2343 — data
-        stays OWNED on device; host pulls on demand).  A flow's custom
-        stage_out hook transforms the body output first (scatter a packed
-        subtile back — reference stage_custom.jdf)."""
+    def _commit_output(self, data: Data, arr, nbytes: int,
+                       bumps_heard: bool) -> None:
+        """One output of one task, committed (the caller holds the
+        residency lock): rebind the device copy, account its residency,
+        bump the version, keep the tile resident and dirty.  Shared by
+        the per-task epilog and the wave's."""
+        idx = self.data_index
+        if data.scratch is not None and scratch.unborn(data):
+            self.stats["scratch_tiles_born"] += 1
+        c = data.get_copy(idx)
+        if c is None:
+            c = data.attach_copy(idx, arr)
+        else:
+            c.payload = arr
+        # the committed value is HOME-layout (stage_out already
+        # unpacked): a packed stage_in marker must not survive it
+        c.staged_by = None
+        self._hbm_realloc_locked(data, 0, nbytes)
+        data.version_bump(idx, bumps_heard)
+        self._lru_touch(data, dirty=True)
+
+    def _release_scratch(self, tiles) -> None:
+        """A task that was one declared user of each of these scratch
+        tiles has committed (the caller holds the residency lock): with
+        the last user the tile's copy here is dropped (a program already
+        enqueued keeps its buffer)."""
+        for data in tiles:
+            if scratch.release(data):
+                self._lru_clean.pop(data.data_id, None)
+                self._lru_dirty.pop(data.data_id, None)
+                self._drop_copy(data, evicted=False)
+                self.stats["scratch_tiles_freed"] += 1
+
+    def _epilog(self, inflight: _InFlight) -> int:
+        """Commit ONE task's outputs: rebind device copies, bump
+        versions, keep tiles resident & dirty (reference kernel_epilog
+        device_gpu.c:2343 — data stays OWNED on device; host pulls on
+        demand).  A flow's custom stage_out hook transforms the body
+        output first (scatter a packed subtile back — reference
+        stage_custom.jdf).  The path of whatever is not a wave: a task
+        that went out alone, a donating program, hooks, the lanes.
+        Returns the number of outputs handed to the committer."""
         if pins.active(pins.DEVICE_EPILOG_BEGIN):
             # happens-before join point: the manager thread is about to
             # commit this task's outputs (version bumps) — hb-check must
             # order them after the task's exec, which may have run on a
             # different (worker) thread (analysis/hb.py)
             pins.fire(pins.DEVICE_EPILOG_BEGIN, None, inflight.task)
+        bumps_heard = pins.active(pins.DATA_VERSION_BUMP)
         with self._res_lock:
             for (pos, data), arr, so in zip(inflight.out_specs,
                                             inflight.outputs,
@@ -1588,45 +1807,15 @@ class TpuDevice(Device):
                     # would otherwise land on the process default device
                     arr = jax.device_put(so(arr, data, self), self.jdev)
                     self.stats["custom_stage_out"] = self.stats.get("custom_stage_out", 0) + 1
-                c = data.get_copy(self.data_index)
-                old = c.nbytes if c is not None else 0
-                if scratch.unborn(data):
-                    self.stats["scratch_tiles_born"] += 1
-                if c is None:
-                    c = data.attach_copy(self.data_index, arr)
-                else:
-                    c.payload = arr
-                # the committed value is HOME-layout (stage_out already
-                # unpacked): a packed stage_in marker must not survive it
-                c.staged_by = None
-                self._hbm_realloc(data, old, arr.nbytes)
-                data.version_bump(self.data_index)
-                self._lru_touch(data, dirty=True)
+                self._commit_output(data, arr, arr.nbytes, bumps_heard)
             # outputs grew residency: re-settle under the budget (zone mode
             # already evicted during allocation)
             if self._zone is None:
                 self._reserve(0)
-            # this task was one declared user of each scratch tile among
-            # its flows: with the last one the tile's copy here is
-            # dropped (a program already enqueued keeps its buffer)
-            for data in inflight.task._tpu_scratch:
-                if scratch.release(data):
-                    self._lru_clean.pop(data.data_id, None)
-                    self._lru_dirty.pop(data.data_id, None)
-                    self._drop_copy(data, evicted=False)
-                    self.stats["scratch_tiles_freed"] += 1
-        com = None if inflight.donated else self._wb_committer()
-        if com is not None:
-            # tentpole (b): hand the just-committed outputs to the async
-            # committer OUTSIDE _res_lock (its capacity wait must not
-            # stall residency).  The committer dedups per data_id and
-            # drains on its byte watermark, so a tile rewritten by a
-            # later task commits its FINAL version once; the version
-            # guard drops anything superseded in flight.  A sticky
-            # committer error re-raises here and propagates to the
-            # caller's _fail_task_pool discipline: pool failure, not a
-            # hang (satellite 3).
-            # NOT for a donating program's outputs: the successor of an
+            self._release_scratch(inflight.task._tpu_scratch)
+        self.stats["task_commits"] += 1
+        if inflight.donated:
+            # NOT a donating program's outputs: the successor of an
             # in-place chain consumes this very buffer, so an eager get
             # either stalls the chain behind a device->host copy of
             # every intermediate version (on the chip: 4 GiB per panel
@@ -1634,18 +1823,108 @@ class TpuDevice(Device):
             # the race and reads a deleted array.  Such tiles stay
             # dirty-resident; detach/flush/eviction carry the final
             # version home through the synchronous guarded path.
-            # NOT a scratch tile either: it has no home to go to.  Nor,
-            # where the task's builder knows the DAG (``_tpu_home``), a
-            # version that a later task overwrites.
-            home = inflight.task._tpu_home
-            for (pos, data) in inflight.out_specs:
-                if data.scratch is None and (home is None or pos in home):
-                    com.enqueue(data, self._span_pool, self._span_batch)
-            if home:
-                # a last version has no later one to wait for: the
-                # committer starts on it now, below its watermark (which
-                # exists to let a tile that is rewritten commit once)
-                com.kick()
+            return 0
+        home = inflight.task._tpu_home
+        return self._send_home(
+            [data for (pos, data) in inflight.out_specs
+             if data.scratch is None and (home is None or pos in home)],
+            bool(home))
+
+    def _send_home(self, going: List[Data], last: bool) -> int:
+        """Tentpole (b) of the staging pipeline: hand just-committed
+        outputs to the async committer OUTSIDE _res_lock (its capacity
+        wait must not stall residency), in one call.  The committer
+        dedups per data_id and drains on its byte watermark, so a tile
+        rewritten by a later task commits its FINAL version once; the
+        version guard drops anything superseded in flight.  A sticky
+        committer error re-raises here and propagates to the caller's
+        _fail_task_pool discipline: pool failure, not a hang.
+        The callers leave out a scratch tile (it has no home to go to)
+        and, where the task's builder knows the DAG (``_tpu_home``), a
+        version that a later task overwrites; ``last`` says such a last
+        version is among ``going``: it has no later one to wait for, so
+        the committer starts on it now, below its watermark (which
+        exists to let a tile that is rewritten commit once).  Returns
+        the number handed over."""
+        com = self._wb_committer()
+        if com is None:
+            return 0
+        if going:
+            com.enqueue_all(going, self._span_pool, self._span_batch,
+                            kick=last)
+        elif last:
+            com.kick()
+        return len(going)
+
+    def _commit_chunk(self, staged, outs, nout: int, es, complete: bool,
+                      sp) -> None:
+        """The epilog of one chunk of a wave: ONE hold of the residency
+        lock commits every output of every task (``_commit_output``, as
+        the per-task epilog does) and releases the scratch tiles whose
+        last user was here; outside the lock the outputs that go home
+        reach the committer in ONE call; then, and only then, the tasks
+        complete, in order (a task's outputs are committed before its
+        ``complete_execution``).  The tools' sites fire as they did — an
+        epilog a task, a bump an output, a ticket an enqueue — asked once
+        a chunk whether anybody listens.
+
+        Once the commit has begun nothing here may raise: an error fails
+        the pool loudly and marks the chunk's tasks completed, so that
+        the per-task fallback neither retries (double-apply) nor hangs."""
+        from ..core import scheduling
+
+        epilogs_heard = pins.active(pins.DEVICE_EPILOG_BEGIN)
+        bumps_heard = pins.active(pins.DATA_VERSION_BUMP)
+        # the k-th output of every task has one shape: its bytes, once
+        sizes = [o.nbytes for o in outs[:nout]]
+        going: List[Data] = []
+        kick = False
+        done: List[Task] = []
+        try:
+            with self._res_lock:
+                for k, (task, _args, ospecs) in enumerate(staged):
+                    if getattr(task.taskpool, "failed", False):
+                        continue  # a sibling's failure already took the pool
+                    task._tpu_effects = True
+                    if epilogs_heard:
+                        pins.fire(pins.DEVICE_EPILOG_BEGIN, None, task)
+                    home = task._tpu_home
+                    at = k * nout
+                    for j, (pos, data) in enumerate(ospecs):
+                        self._commit_output(data, outs[at + j], sizes[j],
+                                            bumps_heard)
+                        if data.scratch is None \
+                                and (home is None or pos in home):
+                            going.append(data)
+                    if home:
+                        kick = True
+                    if task._tpu_scratch:
+                        self._release_scratch(task._tpu_scratch)
+                    done.append(task)
+                if self._zone is None:
+                    self._reserve(0)
+            self.stats["wave_commits"] += 1
+            sp.note(n=len(done), outs=len(done) * nout,
+                    home=self._send_home(going, kick))
+        except Exception as e:
+            debug.error("wave epilog of %d x %r failed: %s",
+                        len(staged), staged[0][0].task_class.name, e)
+            for (task, _args, _o) in staged:
+                if not getattr(task.taskpool, "failed", False):
+                    self._fail_task_pool(
+                        task, f"device epilog/completion raised: {e!r}")
+                task._tpu_completed = True  # never resubmit
+            return
+        for task in done:
+            task._tpu_completed = True
+            if not complete or getattr(task.taskpool, "failed", False):
+                continue
+            try:
+                scheduling.complete_execution(self.context, es, task)
+            except Exception as e:
+                debug.error("wave completion of %r failed: %s", task, e)
+                self._fail_task_pool(
+                    task, f"device epilog/completion raised: {e!r}")
 
     # ------------------------------------------------------------------
     def data_advise(self, data: Data, advice: int) -> None:

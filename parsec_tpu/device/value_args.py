@@ -32,6 +32,13 @@ the program's arguments the same way.  Both count as ``tiles_dropped``.
 The plan never leans on ``jit``'s own pruning of unused arguments: a
 program compiled through its serialized form (``compile_cache.
 _compile_blob``) keeps every argument of ``Exported.call``.
+
+A :class:`FlowPlan` is the same idea one step earlier, for the device
+module's own host work: what the tasks of one wave signature share about
+their argument lists (which positions are tiles to stage, which are
+written, which are no argument at all) is worked out once a signature,
+in plain ints and bools, and the staging walk and the epilog of every
+chunk read it instead of asking each task's ``AccessMode`` again.
 """
 
 from typing import Any, Iterator, List, Sequence, Tuple
@@ -41,6 +48,8 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 from jax import lax
+
+from ..core.lifecycle import AccessMode
 
 _DROP, _INT, _BOOL, _FLOAT = "d", "i", "b", "f"
 _UNBORN, _UNREAD = "z", "t"   # a tile: nothing was staged / never read
@@ -59,6 +68,57 @@ def _read_leaves(body, args: Sequence[Any]) -> List[bool]:
         read.append(any(id(v) in used for v in jaxpr.invars[at:at + n]))
         at += n
     return read
+
+
+#: what a :class:`FlowPlan` step does with one position of ``body_args``
+VALUE, SCRATCH, ABSENT, PLACEHOLDER, READ = range(5)
+_OUT, _INOUT = int(AccessMode.OUT), int(AccessMode.INOUT)
+
+
+class FlowPlan:
+    """What one wave signature (``TpuDevice._wave_signature``) fixes
+    about its tasks' ``body_args``, for the staging walk of a chunk.
+
+    ``steps`` has one ``(how, position, access, extra)`` per
+    position that contributes an argument, in order: a ``VALUE`` rides
+    as it is; ``SCRATCH`` (a per-task scratch allocation) becomes zeros
+    of ``extra = (shape, dtype)``; ``ABSENT`` is a guarded-off flow
+    (``None``); ``PLACEHOLDER`` is a tile there is nothing to stage for
+    (a scratch tile nobody has written, a write-only flow): ``extra`` is
+    the ``jax.ShapeDtypeStruct`` every task hands the program's
+    :class:`ValuePlan`; ``READ`` is a tile to find on the device or stage
+    in.  ``access`` is the flow's ``IN``/``OUT`` bits as a plain int:
+    with the ``OUT`` bit the epilog commits an output for it."""
+
+    __slots__ = ("steps", "nout", "reads")
+
+    def __init__(self, flows: Sequence[Any]):
+        """``flows``: the signature without its body key."""
+        steps: List[Tuple[int, int, int, Any]] = []
+        for pos, f in enumerate(flows):
+            if f is None:
+                steps.append((ABSENT, pos, 0, None))
+            elif isinstance(f, type):
+                steps.append((VALUE, pos, 0, None))
+            elif isinstance(f, str):
+                continue  # "ctl" and the like: no argument
+            elif f[0] == "scratch":
+                steps.append((SCRATCH, pos, 0, (f[1], f[2])))
+            else:
+                unborn = f[0] == "unborn"
+                shape, dtype, mode = f[1:] if unborn else f
+                access = int(mode) & _INOUT
+                if unborn or access == _OUT:
+                    how, extra = PLACEHOLDER, jax.ShapeDtypeStruct(
+                        shape, np.dtype(dtype))
+                else:
+                    how, extra = READ, None
+                steps.append((how, pos, access, extra))
+        self.steps = tuple(steps)
+        #: outputs a task's epilog commits
+        self.nout = sum(1 for s in steps if s[2] & _OUT)
+        #: positions of the tiles a task needs resident before it runs
+        self.reads = tuple(s[1] for s in steps if s[0] == READ)
 
 
 class ValuePlan:

@@ -251,7 +251,10 @@ def _pump_loop(ng, dev, pump_index: Dict[int, Any], stats: Dict[str, int],
     -submitted batches: each freshly popped batch's input tiles are
     handed to the device's transfer lane (``prestage_batch``) the moment
     it is popped, so batch N+1's host->device transfers overlap batch
-    N's compute (ROADMAP 5(b) double buffering).  To keep the window
+    N's compute (ROADMAP 5(b) double buffering).  The pump looks first
+    (``prestage_tiles``): a batch whose tiles are all resident costs the
+    lane its ``dev:stage_in`` span and nothing else, and the pump does
+    not wait for it.  To keep the window
     meaningful when the whole ready frontier fits one ``pop_batch``, the
     pop buffer shrinks to ``cap // stage_depth``: one wide ready wave
     splits into ``stage_depth`` chunks and pipelines INTRA-wave.  A
@@ -273,7 +276,7 @@ def _pump_loop(ng, dev, pump_index: Dict[int, Any], stats: Dict[str, int],
     cap = max(1, _drain_batch())
     depth = max(1, int(getattr(dev, "stage_depth", 1) or 1))
     lane = None
-    if depth > 1 and hasattr(dev, "prestage_batch"):
+    if depth > 1 and hasattr(dev, "prestage_tiles"):
         from ..device.staging import StageLane
         lane = StageLane(dev)
     else:
@@ -284,6 +287,17 @@ def _pump_loop(ng, dev, pump_index: Dict[int, Any], stats: Dict[str, int],
     rank = getattr(dev.context, "rank", 0)
     seq = 0  # number of the next batch handed to the window
     done = 0
+
+    def prestage(batch, tiles):
+        """The lane's job for ``batch`` (numbered ``seq``): the tiles
+        the pump found missing, which may be none."""
+        if lane is None:
+            return None
+        if tiles:
+            stats["prefetched_batches"] += 1
+        else:
+            dev.stats["prestage_skipped"] += 1
+        return lane.stage(batch, seq, tiles)
     try:
         while True:
             # fill the prefetch window: pop ready batches and kick their
@@ -300,8 +314,10 @@ def _pump_loop(ng, dev, pump_index: Dict[int, Any], stats: Dict[str, int],
                     break
                 stats["pop_batches"] += 1
                 stats["pumped_tasks"] += n
-                if (lane is not None and not window and free and n >= 4
-                        and dev.prestage_bytes(batch)
+                tiles, nbytes = (dev.prestage_tiles(batch)
+                                 if lane is not None else ((), 0))
+                if (tiles and not window and free and n >= 4
+                        and nbytes
                         >= getattr(dev, "stage_split_bytes", 1 << 18)):
                     # the whole ready frontier fit ONE buffer, the
                     # window is otherwise idle, and there is REAL
@@ -326,15 +342,11 @@ def _pump_loop(ng, dev, pump_index: Dict[int, Any], stats: Dict[str, int],
                             b[i] = ids[off + i]
                         sub = batch[off:off + k]
                         off += k
-                        window.append((b, k, sub, lane.stage(sub, seq), seq))
+                        window.append((b, k, sub, prestage(
+                            sub, dev.prestage_tiles(sub)[0]), seq))
                         seq += 1
-                        stats["prefetched_batches"] += 1
                     continue
-                job = None
-                if lane is not None:
-                    job = lane.stage(batch, seq)
-                    stats["prefetched_batches"] += 1
-                window.append((buf, n, batch, job, seq))
+                window.append((buf, n, batch, prestage(batch, tiles), seq))
                 seq += 1
             if not window:
                 why = _pump_failure(shims)
@@ -350,7 +362,10 @@ def _pump_loop(ng, dev, pump_index: Dict[int, Any], stats: Dict[str, int],
             if job is not None:
                 with pins.span("pump:stage_wait", pool=pool, rank=rank,
                                batch=b, n=n):
-                    job.wait()  # logs prestage errors; submit restages
+                    # (the span is one a batch for whoever counts them;
+                    # a job without tiles is nothing to wait for)
+                    if job.tiles:
+                        job.wait()  # logs prestage errors; submit restages
             dev.submit_batch(batch, batch_no=b)
             why = _pump_failure(shims)
             if why is not None:

@@ -227,11 +227,11 @@ def test_only_the_version_the_dag_sends_home_goes_to_the_committer(
     from parsec_tpu.ops import cholesky_ptg
 
     seen = []
-    enqueue = WritebackCommitter.enqueue
+    enqueue_all = WritebackCommitter.enqueue_all  # every hand-off's way in
     monkeypatch.setattr(
-        WritebackCommitter, "enqueue",
-        lambda self, data, *a, **kw: (seen.append(data.key),
-                                      enqueue(self, data, *a, **kw))[1])
+        WritebackCommitter, "enqueue_all",
+        lambda self, datas, *a, **kw: (seen.extend(d.key for d in datas),
+                                       enqueue_all(self, datas, *a, **kw))[1])
     a, A = _matrix(4, seed=6)
     if dag == "geqrf":
         tp, tiles = _taskpool(A), 16

@@ -399,8 +399,8 @@ def test_wave_staging_is_per_chunk(ctx):
     """ADVICE round-5 #1 pin: _submit_wave stages each pow2 chunk's
     inputs immediately before THAT chunk's dispatch — never the whole
     wave up front — so peak HBM holds one chunk's inputs, not the
-    wave's.  Observed through the stage hook + the native-path EXEC
-    pins: a 6-task wave (chunks 4+2) must interleave stage(4) →
+    wave's.  Observed through the chunk's staging walk + the native-path
+    EXEC pins: a 6-task wave (chunks 4+2) must interleave stage(4) →
     dispatch(4) → stage(2) → dispatch(2)."""
     from parsec_tpu.core.task import Chore, TaskClass
     from parsec_tpu.dsl.native_exec import _NativeDeviceTask
@@ -410,13 +410,14 @@ def test_wave_staging_is_per_chunk(ctx):
     dev = tpu_dev(ctx)
     events = []
 
-    orig_stage = dev._stage_task_args
+    orig_stage = dev._stage_chunk
 
-    def recording_stage(task, body, *tally):
-        events.append(("stage", id(task)))
-        return orig_stage(task, body, *tally)
+    def recording_stage(grp, fplan, tally):
+        # the chunk's ONE residency pass: one event a task it stages
+        events.extend(("stage", id(task)) for task in grp)
+        return orig_stage(grp, fplan, tally)
 
-    dev._stage_task_args = recording_stage
+    dev._stage_chunk = recording_stage
 
     def on_exec(es, task):
         events.append(("dispatch", task.prof.get("wave")))
@@ -439,7 +440,7 @@ def test_wave_staging_is_per_chunk(ctx):
     try:
         dev._submit_wave(tasks, None)
     finally:
-        dev._stage_task_args = orig_stage
+        dev._stage_chunk = orig_stage
         pins.unsubscribe(pins.EXEC_BEGIN, on_exec)
 
     kinds = [k for (k, _v) in events]
@@ -447,6 +448,361 @@ def test_wave_staging_is_per_chunk(ctx):
     assert kinds == (["stage"] * 4 + ["dispatch"] * 4
                      + ["stage"] * 2 + ["dispatch"] * 2), kinds
     assert [v for (k, v) in events if k == "dispatch"] == [4, 4, 4, 4, 2, 2]
+
+
+# ---------------------------------------------------------------------------
+# the wave commit: one residency pass and one epilog a chunk
+# ---------------------------------------------------------------------------
+
+def _commit_tasks(layout, n, home):
+    """``n`` independent device tasks of one class over 8x8 tiles, by
+    ``layout``; returns ``(stages, tiles)``: the task lists in the order
+    they become ready, and every tile by a name of its own.
+
+    * ``potrf1`` — dpotrf-like: ``x`` (IN), ``o`` (INOUT): one output;
+    * ``qr2`` — geqrt-like: ``a`` (INOUT) and a scratch ``q`` nobody has
+      written (INOUT, ``<- NEW``): two outputs, one of them born here;
+    * ``qr3`` — the ``qr2`` stage, then tsmqr-like consumers: ``c1``,
+      ``c2`` (INOUT), the born scratch ``q`` (IN, its last user) and an
+      unborn scratch ``w`` (INOUT): three outputs.
+
+    ``home``: the pump's knowledge of which outputs go home (every
+    non-scratch one here); False leaves ``_tpu_home`` None, as the
+    Context path does."""
+    from parsec_tpu.core.lifecycle import AccessMode
+    from parsec_tpu.core.task import Chore, TaskClass
+    from parsec_tpu.device import scratch
+    from parsec_tpu.dsl.native_exec import _NativeDeviceTask
+    from types import SimpleNamespace
+
+    pool = SimpleNamespace(failed=False, task_done=lambda t=None: None,
+                           context=None)
+    tiles = {}
+
+    def tile(name, i, fill):
+        d = tiles[(name, i)] = data_create(
+            (layout, name, i), payload=np.full((8, 8), fill, np.float32))
+        return d
+
+    def new(name, i, users):
+        d = tiles[(name, i)] = scratch.new((layout, name, i), (8, 8),
+                                           np.float32)
+        scratch.add_users(d, users)
+        return d
+
+    def stage(cls, body, rows):
+        tclass = TaskClass(cls)
+        chore = Chore(DEV_TPU, hook=lambda es, t: None)
+        chore.body_fn = body
+        out = []
+        for i, flows in enumerate(rows):
+            t = _NativeDeviceTask(pool, tclass, (i,), 0)
+            t.selected_chore = chore
+            t.body_args = [("data", d, mode) for (d, mode) in flows]
+            t.body_args.append(("value", i, AccessMode.VALUE))
+            if home:
+                t._tpu_home = tuple(
+                    pos for pos, (d, mode) in enumerate(flows)
+                    if d.scratch is None and mode & AccessMode.OUT)
+            t.on_complete = lambda task: None
+            out.append(t)
+        return out
+
+    if layout == "potrf1":
+        return [stage("potrf1", lambda x, o, i: o + 2.0 * x,
+                      [[(tile("x", i, i + 1.0), IN),
+                        (tile("o", i, 0.5), INOUT)] for i in range(n)])], \
+            tiles
+    first = stage("qr2", lambda a, q, i: (a * 2.0, q + a + 1.0),
+                  [[(tile("a", i, i + 1.0), INOUT),
+                    (new("q", i, 1 if layout == "qr2" else 2), INOUT)]
+                   for i in range(n)])
+    if layout == "qr2":
+        return [first], tiles
+    second = stage("qr3", lambda c1, c2, q, w, i: (c1 + q, c2 - q, w + q),
+                   [[(tile("c1", i, 1.0), INOUT), (tile("c2", i, 2.0), INOUT),
+                     (tiles[("q", i)], IN), (new("w", i, 1), INOUT)]
+                    for i in range(n)])
+    return [first, second], tiles
+
+
+def _run_commit_tasks(layout, n, complete, waves):
+    """The tasks of ``_commit_tasks`` through a fresh device, as waves or
+    one by one; returns everything the two ways must agree on."""
+    from parsec_tpu.core import scheduling
+
+    c = Context(nb_cores=1)
+    try:
+        dev = tpu_dev(c)
+        last = {}
+        commit = dev._commit_output
+
+        def recording_commit(data, arr, *rest):
+            last[data.data_id] = arr
+            return commit(data, arr, *rest)
+
+        dev._commit_output = recording_commit
+        stages, tiles = _commit_tasks(layout, n, home=not complete)
+        for tasks in stages:
+            for t in tasks:
+                t.selected_device = dev   # where a completion is counted
+            if waves:
+                dev._submit_units(dev._units_of(tasks), None, complete)
+            else:
+                for t in tasks:
+                    dev._submit_one(t, None, complete)
+            assert all(t._tpu_completed for t in tasks)
+            if not complete:
+                scheduling.retire_native(tasks, dev)
+        com = dev._committer
+        dev.flush()   # the committer runs beside us: settle it first
+        names = {d.data_id: k for k, d in tiles.items()}
+        seen = {"resident": {}, "tile": {}}
+        for k, d in tiles.items():
+            mine = d.get_copy(dev.data_index)
+            if mine is not None and mine.payload is not None:
+                # the device copy IS what the program returned for it
+                assert mine.payload is last.get(d.data_id, mine.payload)
+                seen["resident"][k] = np.asarray(mine.payload).tolist()
+            seen["tile"][k] = (
+                d.owner_device == dev.data_index,
+                sorted((i == dev.data_index, cp.coherency, cp.version,
+                        cp.payload is None) for i, cp in d.copies.items()))
+        seen.update(
+            hbm_used=dev.hbm_used,
+            clean=sorted(names[i] for i in dev._lru_clean),
+            dirty=sorted(names[i] for i in dev._lru_dirty),
+            enqueued=com.stats["enqueued"], committed=com.stats["committed"],
+            host={k: None if d.scratch is not None
+                  else np.asarray(d.get_copy(0).payload).tolist()
+                  for k, d in tiles.items()},
+            stats={k: dev.stats[k] for k in (
+                "executed_tasks", "bytes_in", "bytes_out",
+                "scratch_tiles_born", "scratch_tiles_freed",
+                "scratch_bytes_in", "scratch_bytes_out", "evictions",
+                "wave_fallbacks", "submit_retries")})
+        counts = {k: dev.stats.get(k, 0) for k in (
+            "wave_commits", "wave_submits", "wave_tasks", "task_commits")}
+        return seen, counts
+    finally:
+        c.fini()
+
+
+@pytest.mark.parametrize("complete", [False, True], ids=["pump", "context"])
+@pytest.mark.parametrize("n", [2, 4, 64, 7])
+@pytest.mark.parametrize("layout", ["potrf1", "qr2", "qr3"])
+def test_wave_commit_leaves_what_the_per_task_epilog_leaves(layout, n,
+                                                            complete):
+    """One commit a chunk (``_commit_chunk``) against one a task
+    (``_epilog``): after the same tasks every tile's version, owner,
+    coherency and payload, the residency accounting and the LRUs'
+    membership, the scratch counters, the bytes that went home and the
+    committer's dedup read the same; the two commit counters add up to
+    the tasks executed."""
+    nstages = 2 if layout == "qr3" else 1
+    wave, counts = _run_commit_tasks(layout, n, complete, waves=True)
+    alone, alone_counts = _run_commit_tasks(layout, n, complete, waves=False)
+    assert wave == alone
+    ntasks = nstages * n
+    assert wave["stats"]["executed_tasks"] == ntasks
+    assert wave["stats"]["scratch_tiles_born"] \
+        == wave["stats"]["scratch_tiles_freed"] \
+        == {"potrf1": 0, "qr2": n, "qr3": 2 * n}[layout]
+    assert wave["stats"]["scratch_bytes_in"] \
+        == wave["stats"]["scratch_bytes_out"] == 0
+    # every tile with a home went there once, whoever enqueued it
+    homes = sum(1 for v in wave["host"].values() if v is not None)
+    written = {"potrf1": n, "qr2": n, "qr3": 3 * n}[layout]
+    assert wave["enqueued"] == wave["committed"] == written
+    assert wave["stats"]["bytes_out"] == written * 8 * 8 * 4 and homes >= written
+    # a chunk a power of two: 7 = 4 + 2 + 1
+    chunks = nstages * bin(n).count("1")
+    assert counts == {"wave_commits": chunks, "wave_submits": chunks,
+                      "wave_tasks": ntasks, "task_commits": 0}
+    assert alone_counts == {"wave_commits": 0, "wave_submits": 0,
+                            "wave_tasks": 0, "task_commits": ntasks}
+
+
+@pytest.mark.parametrize("where", ["staging", "trace"])
+def test_a_wave_that_fails_before_dispatch_touches_no_task(ctx, where):
+    """An error in a chunk's staging or in its trace raises before ANY of
+    its tasks has an effect; the per-task fallback then runs each task
+    exactly once: one fallback counted, no version bumped twice."""
+    dev = tpu_dev(ctx)
+    (tasks,), tiles = _commit_tasks("potrf1", 4, home=True)
+    state = {"failed": 0}
+
+    def once(fn):
+        def failing(*a, **k):
+            if not state["failed"]:
+                state["failed"] = 1
+                raise RuntimeError(f"injected {where} failure")
+            return fn(*a, **k)
+        return failing
+
+    if where == "staging":
+        dev._stage_in_batch = once(dev._stage_in_batch)
+    else:
+        chore = tasks[0].selected_chore
+        chore.body_fn = once(chore.body_fn)
+    dev._submit_units(dev._units_of(tasks), None, False)
+    assert state["failed"] == 1
+    assert dev.stats["wave_fallbacks"] == 1
+    assert dev.stats["wave_commits"] == 0 and dev.stats["task_commits"] == 4
+    assert dev.stats["submit_retries"] == 0
+    for (name, i), d in tiles.items():
+        c = d.get_copy(dev.data_index)
+        assert c.version == (1 if name == "o" else 0), (name, i)
+        if name == "o":
+            np.testing.assert_allclose(np.asarray(c.payload),
+                                       0.5 + 2.0 * (i + 1.0))
+
+
+def test_an_error_inside_the_wave_commit_fails_the_pool_loudly():
+    """Once a chunk's commit has begun nothing is retried: a committer
+    that died (its error is sticky) fails the pool at the chunk's
+    hand-off, ``wait()`` returns False, and no task of the chunk runs a
+    second time."""
+    c = Context(nb_cores=2)
+    try:
+        dev = tpu_dev(c)
+        with dev._lock:   # hold the manager role: the tasks pile up
+            dev._manager_active = True
+        com = dev._wb_committer()
+        com.error = RuntimeError("injected committer death")
+        tp = DTDTaskpool(c)
+        tiles = [data_create(("dead", i), payload=np.zeros((8, 8), np.float32))
+                 for i in range(4)]
+
+        def body(x):
+            return x + 1.0
+
+        body._jit_key = ("dead_committer_body",)
+        for t in tiles:
+            tp.insert_task({dev.device_type: body}, (t, INOUT))
+        with dev._lock:
+            dev._manager_active = False
+        assert tp.wait(timeout=60) is False
+        assert tp.failed
+        assert dev.stats["submit_retries"] == 0
+        assert dev.stats["wave_fallbacks"] == 0
+        # committed once or (behind the failure) not at all: never twice
+        assert all(t.newest_copy().version <= 1 for t in tiles)
+        com.error = None   # teardown flushes through it
+    finally:
+        c.fini()
+
+
+def test_the_tools_hear_every_event_of_a_wave_commit(ctx):
+    """With hb-check listening a wave of n tasks with k outputs fires n
+    ``DEVICE_EPILOG_BEGIN``, n*k ``DATA_VERSION_BUMP`` and one
+    ``HB_WB_ENQUEUE`` a ticket, each task's bumps after its own epilog
+    site, in an order the checker accepts."""
+    from parsec_tpu.analysis.hb import HBRecorder
+    from parsec_tpu.profiling import pins
+
+    dev = tpu_dev(ctx)
+    (tasks,), tiles = _commit_tasks("qr2", 4, home=True)
+    heard = []
+    sites = {pins.DEVICE_EPILOG_BEGIN: "epilog",
+             pins.DATA_VERSION_BUMP: "bump", pins.HB_WB_ENQUEUE: "ticket"}
+    subs = [(site, lambda es, payload, kind=kind: heard.append(
+        (kind, payload))) for site, kind in sites.items()]
+    for site, cb in subs:
+        pins.subscribe(site, cb)
+    try:
+        with HBRecorder() as rec:
+            dev._submit_units(dev._units_of(tasks), None, False)
+            dev.flush()
+        assert rec.analyze() == []
+    finally:
+        for site, cb in subs:
+            pins.unsubscribe(site, cb)
+    kinds = [k for k, _p in heard]
+    assert kinds.count("epilog") == 4 and kinds.count("bump") == 8
+    tickets = [p["ticket"] for k, p in heard if k == "ticket"]
+    # the home outputs (a), never the scratch ones (q): a ticket each
+    assert len(tickets) == len(set(tickets)) == 4
+    assert {p["data"] for k, p in heard if k == "ticket"} \
+        == {tiles[("a", i)].data_id for i in range(4)}
+    # epilog(t), its two bumps, epilog(t+1), ...; the tickets after
+    assert kinds == ["epilog", "bump", "bump"] * 4 + ["ticket"] * 4
+    assert [p for k, p in heard if k == "epilog"] == tasks
+
+
+def test_the_lane_and_the_pump_share_residency_without_losing_a_tile(ctx):
+    """The transfer lane's put runs OUTSIDE the residency lock, beside
+    the pump's staging and commits of other (and of the same) tiles:
+    more threads than this needs, a switch interval that interleaves
+    them everywhere, a bounded time.  Whoever wins, every tile ends with
+    one device copy at its newest version, one LRU entry and one
+    accounted slot."""
+    import sys
+    import threading
+    import time
+
+    dev = tpu_dev(ctx)
+    rounds, n = 12, 8
+    stages = [_commit_tasks("potrf1", n, home=False)[0][0]
+              for _ in range(rounds)]
+    # round r+1 reads what round r wrote: the lane stages tiles that the
+    # pump is committing
+    tiles = {}
+    for r, tasks in enumerate(stages):
+        for i, t in enumerate(tasks):
+            if r:
+                t.body_args[0] = ("data", tiles[("o", r - 1, i)], IN)
+            tiles[("x", r, i)] = t.body_args[0][1]
+            tiles[("o", r, i)] = t.body_args[1][1]
+    stop = threading.Event()
+    errors = []
+
+    def lane(k):
+        r = 0
+        while not stop.is_set():
+            tasks = stages[(r + k) % rounds]
+            r += 1
+            try:
+                dev.prestage_batch(tasks, r, dev.prestage_tiles(tasks)[0])
+            except Exception as e:  # pragma: no cover - the failure
+                errors.append(e)
+                return
+
+    old = sys.getswitchinterval()
+    sys.setswitchinterval(1e-5)
+    threads = [threading.Thread(target=lane, args=(k,)) for k in range(4)]
+    try:
+        for th in threads:
+            th.start()
+        deadline = time.monotonic() + 20
+        for tasks in stages:
+            dev._submit_units(dev._units_of(tasks), None, False)
+            assert time.monotonic() < deadline
+    finally:
+        stop.set()
+        for th in threads:
+            th.join(timeout=20)
+        sys.setswitchinterval(old)
+    assert not errors and not any(th.is_alive() for th in threads)
+    unique = {d.data_id: d for d in tiles.values()}
+    resident = 0
+    for d in unique.values():
+        mine = d.get_copy(dev.data_index)
+        if mine is None or mine.payload is None:
+            continue
+        resident += 1
+        assert mine.version == d.newest_copy().version, d
+        assert (d.data_id in dev._lru_clean) != (d.data_id in dev._lru_dirty)
+    assert resident == len(dev._lru_clean) + len(dev._lru_dirty)
+    slots = dev._offsets if dev._zone is not None else dev._accounted
+    assert set(slots) == set(dev._lru_clean) | set(dev._lru_dirty)
+    assert dev.hbm_used == resident * 8 * 8 * 4
+    for i in range(n):   # o(r) = 0.5 + 2 o(r-1), o(-1) = x = i + 1
+        want = i + 1.0
+        for _ in range(rounds):
+            want = 0.5 + 2.0 * want
+        got = tiles[("o", rounds - 1, i)].get_copy(dev.data_index).payload
+        np.testing.assert_allclose(np.asarray(got), want, rtol=1e-6)
 
 
 def test_body_fingerprint_memo_is_weak(ctx):
