@@ -60,8 +60,9 @@ class Chore:
         #: accelerator chores; the device module jits and dispatches it)
         self.body_fn = None
         #: the device module's memo for this chore: ``(body_fn it was
-        #: worked out for, what a wave signature starts with)``
-        self.wave_key: Optional[Tuple[Any, Any]] = None
+        #: worked out for, what a wave signature starts with, the body's
+        #: per-flow staging hooks)``
+        self.wave_key: Optional[Tuple[Any, Any, Any]] = None
 
 
 class TaskClass:
@@ -207,9 +208,10 @@ class Task:
         #: device module's write-back committer takes only these); None
         #: where whoever built the task does not know: then every one
         self._tpu_home: Optional[Tuple[int, ...]] = None
-        #: the task's wave signature, once the device module has worked
-        #: it out (a ready task's flows no longer change): None when it
-        #: cannot ride a wave, False until somebody asked
+        #: the task's signature ``(wave key, FlowPlan)``, once the device
+        #: module has worked it out (a ready task's flows no longer
+        #: change): the key is None when it cannot ride a wave; None
+        #: without a device body; False until somebody asked
         self._tpu_sig: Any = False
 
     @property

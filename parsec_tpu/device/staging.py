@@ -1,31 +1,26 @@
-"""Asynchronous host<->device staging pipeline.
+"""Host<->device staging: every byte that moves between the host and one
+device moves here, synchronously or on the pipeline's two threads.
 
-PR 18 made the task lifecycle native, but every transfer still ran
-synchronously on the dispatch thread: ``_stage_in`` blocked the pump on
-each H2D put, ``_writeback`` blocked eviction on a D2H get, and
-``detach()`` flushed dirty tiles home one at a time.  This module is
-the asynchronous half of the staging layer (ROADMAP item 5(b); the
-data-transfer overlap story of AXI4MLIR and the tiled-transfer
-scheduling of "Design in Tiles", PAPERS.md):
-
+* :class:`StageIn` — the host->device half: decide, make room, put,
+  attach, for one tile or for a batch in one coalesced ``device_put``.
+* :class:`HostWriter` — the write-back halves: version-guarded snapshot,
+  batched device->host get, guarded commit of the host copy.
 * :class:`StageLane` — a dedicated transfer thread the native pump
-  hands the NEXT ready batch to while the current wave computes.  The
-  lane prestages input tiles through the device's batched stage-in
-  (coalesced ``device_put``), so by the time the pump submits the
-  batch every plain input is a residency hit.  Bounded by the
-  ``runtime_stage_depth`` MCA param (1 = synchronous, 2 =
+  hands the NEXT ready batch to while the current wave computes, so by
+  the time the pump submits the batch every plain input is a residency
+  hit.  Bounded by ``runtime_stage_depth`` (1 = synchronous, 2 =
   double-buffered default).
-
-* :class:`WritebackCommitter` — a background thread draining
-  version-guarded deferred write-backs.  Completed outputs enqueue at
-  epilog (deduplicated per tile, so a re-dirtied tile commits its
-  NEWEST version once); the committer drains in batched D2H gets when
-  the pending-bytes watermark (``runtime_wb_window_mb``) is crossed,
-  when an eviction needs a victim committed (:meth:`kick`), or at the
-  :meth:`flush` barrier ``detach()``/redistribute/remote sends take.
-  The PR 3 version guard makes a stale commit safe to drop, so the
-  committer never takes the device residency lock — commits are pure
-  Data-level operations and cannot deadlock against eviction waits.
+* :class:`WritebackCommitter` — a background thread draining deferred
+  write-backs.  Completed outputs enqueue at the commit (deduplicated
+  per tile, so a re-dirtied tile goes home ONCE, at its newest version);
+  it drains in batched gets when the pending-bytes watermark
+  (``runtime_wb_window_mb``) is crossed, on :meth:`~WritebackCommitter.
+  kick` (an eviction needs a victim home; a last version has no later
+  one to wait for), or at the :meth:`~WritebackCommitter.flush` barrier
+  ``detach()``/redistribute/remote sends take.  The version guard makes
+  a stale commit safe to drop, so the committer never takes the device
+  residency lock — commits are pure Data-level operations and cannot
+  deadlock against eviction waits.
 
 A committer failure is STICKY: the stored exception re-raises on the
 next ``enqueue`` (failing the task pool through the device layer's
@@ -33,6 +28,9 @@ fail-loudly discipline) and on ``flush`` (failing ``detach()``), so a
 dead committer surfaces as a pool failure, never a silent hang.  The
 watchdog counts :meth:`WritebackCommitter.drained` in its progress
 epoch and diagnoses a wedged committer as finding OBS011.
+
+Nothing here imports the device module or calls into it: what it needs
+of a device (its residency, its counters, its span maker) is handed in.
 """
 
 from __future__ import annotations
@@ -43,11 +41,21 @@ import threading
 import time
 from typing import Any, Deque, Dict, List, Optional, Tuple
 
+import jax
+import jax.numpy as jnp
+import numpy as np
+
+from ..data.data import Coherency, Data
 from ..profiling import pins
 from ..utils import debug, mca_param
 
 #: process-wide span ids for STAGE_IN/WRITEBACK begin/end pairing
 _SPAN_SEQ = itertools.count(1)
+
+
+def span_id() -> int:
+    """The ``id`` of the next ``dev:stage_in`` / ``dev:writeback`` span."""
+    return next(_SPAN_SEQ)
 
 
 def stage_depth_param() -> int:
@@ -140,6 +148,364 @@ class StageLane:
                 self._jobs.popleft().done.set()
 
 
+def unalias(arr, x, guard, jdev):
+    """Rerun a host->device transfer from a throwaway copy when the
+    result aliases ``guard`` (shared by :func:`private_device_put` and
+    the batched stage-in path — the guard contract must be identical
+    whether a tile travelled alone or coalesced)."""
+    plat = getattr(jdev, "platform", None)
+    if plat is None:
+        try:
+            plat = arr.devices().pop().platform
+        except Exception:
+            plat = "cpu"  # unknown: err on the safe side
+    if plat != "cpu":
+        return arr
+    try:
+        if np.shares_memory(np.asarray(arr), guard):
+            priv = np.array(np.asarray(x), copy=True)
+            arr = jax.device_put(priv, jdev) if jdev is not None \
+                else jnp.asarray(priv)
+    except Exception:
+        pass
+    return arr
+
+
+def private_device_put(x, jdev=None, *, guard=None):
+    """``jax.device_put`` whose result is guaranteed NOT to alias
+    ``guard`` (a host numpy array someone retains).  On the CPU backend
+    PJRT zero-copies suitably-aligned host buffers, so a DONATED
+    execution of the transferred array writes straight through the
+    retained memory — the caller's reference matrix, or a version-v
+    host copy whose bytes must outlive the bump to v+1.  Whether a
+    given buffer zero-copies depends on its heap alignment, which makes
+    the clobber a per-allocation coin flip (seen as a suite flake:
+    the LU reconstruct test intermittently compared against its own
+    overwritten input).  When aliasing is detected the transfer reruns
+    from a throwaway copy — the only memory jax then aliases is
+    jax-private.  Non-CPU platforms always copy host→HBM; the check is
+    skipped there (``np.asarray`` on such arrays would be a D2H pull)."""
+    arr = jax.device_put(x, jdev) if jdev is not None else jnp.asarray(x)
+    if guard is None:
+        return arr
+    return unalias(arr, x, guard, jdev)
+
+
+class StageIn:
+    """The host->device half of one device — decide, make room, put,
+    attach — into its residency (``device/residency.py``): the one
+    implementation behind a chunk's batched put, the synchronous
+    regime's put a tile, ``data_advise`` and the transfer lane."""
+
+    def __init__(self, res, writer: "HostWriter", jdev, stats, span):
+        """``res``: the device's :class:`~.residency.Residency`;
+        ``writer``: its write-back halves; ``span``: what opens one of
+        its spans (``dev:h2d``)."""
+        self.res, self.writer, self.jdev = res, writer, jdev
+        self.stats, self.span, self.index = stats, span, res.index
+
+    def one(self, data, tally: Optional[List[int]] = None) -> Any:
+        """Materialize the newest version of ``data`` on the device: the
+        one-tile case of :meth:`batch`, with the residency lock held
+        over the put (the synchronous regime's way, and
+        ``data_advise``'s: nobody waits for it)."""
+        got: Dict[int, Any] = {}
+        with self.res.lock:
+            self.batch((data,), tally, coalesce=False, got=got)
+        return got[data.data_id]
+
+    def batch(self, datas, tally: Optional[List[int]] = None,
+              coalesce: bool = True,
+              got: Optional[Dict[int, Any]] = None) -> int:
+        """Resident tiles are touched, stale host-side tiles are
+        coalesced into ONE ``jax.device_put`` call (one enqueue RPC for
+        a wave's transfers instead of one per tile; ``coalesce=False``:
+        a put a tile, as :meth:`one` asks), each result re-checked
+        against the per-tile aliasing guard.  Returns bytes moved
+        host->device, and in ``got`` each tile's array here by data_id;
+        ``tally[0:2]`` count the tiles put and their bytes; the put is
+        the ``dev:h2d`` span.
+
+        The residency lock is held to decide what moves and to make room
+        for it, and again to attach what arrived — NOT over the put: the
+        transfer lane's put of the next batch (10 ms for 27 tiles of 1
+        MiB on a v5e) used to hold the pump's staging and epilog of the
+        current one for its whole length (``PERF.md`` §6, PR 27).  A tile
+        that somebody else staged or wrote in between keeps their copy.
+        (The pump's own call, from the staging walk, holds the lock
+        around all of it, as it always did: nobody waits for it.)"""
+        moved = 0
+        idx, res, jdev, stats = self.index, self.res, self.jdev, self.stats
+        if got is None:
+            got = {}
+        puts: List[Tuple[Data, np.ndarray, int]] = []
+        with res.lock:
+            for data in datas:
+                mine = data.get_copy(idx)
+                if mine is not None and getattr(mine, "staged_by", None) is not None:
+                    # a custom-staged PACKED representation must never be
+                    # served as the home layout: drop it and restage from
+                    # the host copy (which :meth:`custom` flushed to
+                    # the same version)
+                    res.drop(data, evicted=False)
+                    mine = None
+                newest = data.newest_copy()
+                if mine is not None and newest is not None \
+                        and mine.version >= newest.version \
+                        and mine.payload is not None:
+                    res.touch(data, dirty=mine.coherency is Coherency.OWNED)
+                    got[data.data_id] = mine.payload
+                    continue
+                if newest is None:
+                    raise RuntimeError(f"{data!r}: no valid copy to stage in")
+                # (re-staging over a stale device copy replaces it: the
+                # accounting charges the delta)
+                if isinstance(newest.payload, jax.Array):
+                    # device-resident arrival (device-capable fabric):
+                    # land it with a direct device_put — device-to-device,
+                    # ICI-class on multi-chip, no host numpy bounce
+                    # (SURVEY §5.8), uncoalesced
+                    res.account(data, newest.payload.nbytes)
+                    arr = got[data.data_id] = jax.device_put(
+                        newest.payload, jdev)
+                    stats["bytes_d2d"] += newest.payload.nbytes
+                    c = data.attach_copy(idx, arr)
+                    c.version = newest.version
+                    res.touch(data, dirty=False)
+                    moved += newest.payload.nbytes
+                    continue
+                host = np.asarray(newest.payload)
+                res.account(data, host.nbytes)
+                puts.append((data, host, newest.version))
+        if not puts:
+            return moved
+        nbytes = sum(h.nbytes for (_d, h, _v) in puts)
+        try:
+            with self.span("dev:h2d", tiles=len(puts), bytes=nbytes):
+                hosts = [h for (_d, h, _v) in puts]
+                arrs = None
+                if coalesce:
+                    try:
+                        arrs = jax.device_put(hosts, jdev)
+                    except Exception:
+                        # backend rejected the coalesced put: per tile
+                        stats["stage_batch_fallbacks"] += 1
+                # guard: the host copy RETAINS each buffer at version v —
+                # a zero-copy put followed by a donating task would
+                # overwrite it in place while its version still claims v
+                if arrs is None:
+                    arrs = [private_device_put(h, jdev, guard=h)
+                            for h in hosts]
+                else:
+                    arrs = [unalias(a, h, h, jdev)
+                            for a, h in zip(arrs, hosts)]
+        except BaseException:
+            with res.lock:  # the room made for what never arrived
+                for (data, _h, _v) in puts:
+                    mine = data.get_copy(idx)
+                    if mine is None or mine.payload is None:
+                        res.free(data)
+            raise
+        if tally is not None:
+            tally[0] += len(puts)
+            tally[1] += nbytes
+        with res.lock:
+            for (data, host, ver), arr in zip(puts, arrs):
+                stats["bytes_in"] += host.nbytes
+                if data.scratch is not None:
+                    stats["scratch_bytes_in"] += host.nbytes
+                moved += host.nbytes
+                mine = data.get_copy(idx)
+                if mine is not None and mine.payload is not None \
+                        and mine.version >= ver \
+                        and getattr(mine, "staged_by", None) is None:
+                    # staged or written meanwhile: theirs stands
+                    got[data.data_id] = mine.payload
+                    continue
+                c = data.attach_copy(idx, arr)
+                c.version = ver
+                got[data.data_id] = arr
+                res.touch(data, dirty=False)
+            if coalesce:
+                stats["stage_batched_puts"] = \
+                    stats.get("stage_batched_puts", 0) + 1
+                stats["stage_batched_tiles"] = \
+                    stats.get("stage_batched_tiles", 0) + len(puts)
+        return moved
+
+    def custom(self, data, hook, owner) -> Any:
+        """Stage via a user hook: ``hook(data, owner) -> jax.Array`` (``owner``:
+        the device module).
+        The hook's result becomes the flow's device copy (the reference's
+        stage_in writes into the GPU copy buffer the same way); residency
+        is accounted at the STAGED size, which may differ from the home
+        tile's (packed subtile)."""
+        with self.res.lock:
+            mine = data.get_copy(self.index)
+            newest = data.newest_copy()
+            if mine is not None and newest is not None \
+                    and mine.version >= newest.version and mine.payload is not None \
+                    and getattr(mine, "staged_by", None) is hook:
+                # reusable ONLY if this same hook produced it: a current
+                # device copy staged by the default path (prefetch, a prior
+                # epilog) holds the HOME representation, not the packed one
+                self.res.touch(data, dirty=mine.coherency is Coherency.OWNED)
+                return mine.payload
+            if mine is not None and mine.payload is not None \
+                    and getattr(mine, "staged_by", None) is None:
+                host = data.get_copy(0)
+                if host is None or host.payload is None \
+                        or host.version < mine.version:
+                    # the device copy is the ONLY up-to-date home-layout
+                    # replica: flush it home BEFORE the packed staging
+                    # replaces it, or that data exists nowhere (and the
+                    # hook itself typically reads the host copy).  A
+                    # deferred commit may still be pending for this tile —
+                    # the synchronous flush lands the same version first
+                    # and the committer's guarded commit drops as stale.
+                    self.writer.writeback(data)
+            arr = hook(data, owner)
+            self.res.account(data, arr.nbytes)
+            arr = jax.device_put(arr, self.jdev)
+            self.stats["bytes_in"] += arr.nbytes
+            self.stats["custom_stage_in"] = self.stats.get("custom_stage_in", 0) + 1
+            c = data.attach_copy(self.index, arr)
+            c.version = newest.version if newest is not None else 0
+            c.staged_by = hook
+            self.res.touch(data, dirty=False)
+            return arr
+
+
+class HostWriter:
+    """The write-back halves of one device: snapshot a dirty device copy
+    under the version guard, get it (alone or as one batch), land it as
+    the host copy.  Pure Data-level operations — no residency lock, no
+    task, no program — shared by the committer, eviction, ``detach()``
+    and the custom stage-in's pre-flush."""
+
+    def __init__(self, data_index: int, stats, name: str = "",
+                 rank: int = 0):
+        """``data_index``: the device's slot in ``Data.copies``;
+        ``stats``: the device's counters (``bytes_out``,
+        ``scratch_bytes_out``, ``wb_batches``); ``name`` and ``rank``:
+        what the committer's thread and the spans carry."""
+        self.index = data_index
+        self.stats = stats
+        self.name = name
+        self.rank = rank
+
+    def snapshot(self, data):
+        """Version-guarded snapshot of a dirty device copy: returns
+        ``(payload, version)`` to commit home, or None when the commit
+        would be wrong or redundant.  Taken under the Data lock so a
+        concurrent epilog rebind cannot tear payload from version."""
+        with data.lock:
+            c = data.get_copy(self.index)
+            if c is None or c.payload is None:
+                return None
+            if getattr(c, "staged_by", None) is not None:
+                # packed custom-staged representation: flushing it home
+                # would corrupt the home tile; the host copy already holds
+                # the same version in home layout (the custom stage-in
+                # pre-flushes)
+                return None
+            hc = data.get_copy(0)
+            if hc is not None and hc.payload is not None \
+                    and hc.version >= c.version:
+                # the host already holds this version OR NEWER (a CPU body
+                # consumed the device output and bumped past it — the mixed
+                # native_device DAG shape): flushing the stale device copy
+                # would roll the tile back
+                return None
+            return (c.payload, c.version)
+
+    def commit(self, data, version: int, host) -> bool:
+        """Land a D2H'd payload as the host copy at ``version``.  The
+        guard re-checks under the Data lock: a newer commit that landed
+        while our get was in flight wins and ours drops (stale commits
+        are safe to drop — the version guard).  Deliberately NO
+        version_bump: the committed value is the same write the device
+        epilog already bumped for, and a second bump would make every
+        deferred commit an RT001 unordered-writer false positive."""
+        if not host.flags.writeable:
+            host = host.copy()  # host copies must be mutable for CPU bodies
+        with data.lock:
+            hc = data.get_copy(0)
+            if hc is not None and hc.payload is not None \
+                    and hc.version >= version:
+                return False
+            hc = data.attach_copy(0, host)
+            hc.version = version
+            hc.coherency = Coherency.SHARED
+        self.stats["bytes_out"] += host.nbytes
+        if data.scratch is not None:  # spilled by an eviction
+            self.stats["scratch_bytes_out"] += host.nbytes
+        return True
+
+    def d2h_batch(self, payloads: List[Any]) -> List[Optional[np.ndarray]]:
+        """Batched device->host gets: ONE device sync for the whole
+        batch, then the (now-ready) buffers convert without further
+        blocking.  A payload
+        that a donating task consumed since it was snapshotted comes
+        back as None: that version no longer exists anywhere, and the
+        consumer's own output supersedes it."""
+        try:
+            jax.block_until_ready(payloads)
+        except Exception:
+            pass  # non-jax or consumed payloads: asarray below decides
+        hosts: List[Optional[np.ndarray]] = []
+        for p in payloads:
+            try:
+                hosts.append(np.asarray(p))
+            except RuntimeError:
+                if not (isinstance(p, jax.Array) and p.is_deleted()):
+                    raise
+                hosts.append(None)
+        return hosts
+
+    def writeback(self, data) -> None:
+        """Synchronous write-back-to-rest of a dirty tile (reference w2r
+        tasks, ``parsec_gpu_create_w2r_task``); the deferred path shares
+        its snapshot/commit halves."""
+        snap = self.snapshot(data)
+        if snap is not None:
+            self.commit(data, snap[1], np.asarray(snap[0]))  # D2H
+
+    def writeback_batch(self, datas, pool: int = 0, batch: int = 0,
+                        tickets=()) -> Tuple[int, int]:
+        """One batch home: snapshot every tile (version guard), ONE
+        device sync + coalesced gets, guarded commits — under one
+        ``dev:writeback`` span that names ``(pool, batch)`` as its
+        cause.  ``tickets``: per tile, the hb tickets of the enqueues
+        that fed it (the committer's).  Returns ``(tiles committed, tiles
+        got)``: the others were stale, or consumed by a donating task."""
+        snaps = []
+        joined: List[int] = []
+        for k, d in enumerate(datas):
+            s = self.snapshot(d)
+            if s is not None:
+                snaps.append((d, s[0], s[1]))
+                if tickets:
+                    joined.extend(tickets[k])
+        if not snaps:
+            return 0, 0
+        committed = 0
+        with pins.span("dev:writeback", pool=pool, rank=self.rank,
+                       id=span_id(), tiles=len(snaps), batch=batch,
+                       bytes=sum(int(getattr(p, "nbytes", 0))
+                                 for (_d, p, _v) in snaps)):
+            hosts = self.d2h_batch([p for (_d, p, _v) in snaps])
+            for (data, _p, version), host in zip(snaps, hosts):
+                # host is None: a donating task consumed that version
+                if host is not None and self.commit(data, version, host):
+                    committed += 1
+            if joined and pins.active(pins.HB_WB_COMMIT):
+                # acquire edge: the committer joins every enqueue that
+                # fed this batch — exec happens-before write-back commit
+                pins.fire(pins.HB_WB_COMMIT, None, {"tickets": joined})
+        return committed, len(snaps)
+
+
 class WritebackCommitter:
     """Background committer for version-guarded deferred write-backs.
 
@@ -151,8 +517,8 @@ class WritebackCommitter:
     of dirty bytes are pending — plus on :meth:`kick` (eviction wants a
     victim home NOW) and at the :meth:`flush` barrier."""
 
-    def __init__(self, dev):
-        self._dev = dev
+    def __init__(self, writer: HostWriter):
+        self._writer = writer
         self._cv = threading.Condition()
         #: data_id -> (Data, [hb tickets], nbytes at enqueue)
         self._pending: "collections.OrderedDict[int, Tuple[Any, List[int], int]]" = \
@@ -179,7 +545,8 @@ class WritebackCommitter:
             "enqueued": 0, "committed": 0, "dropped_stale": 0,
             "batches": 0, "capacity_waits": 0}
         self._thread = threading.Thread(
-            target=self._run, name=f"wb-committer:{dev.name}", daemon=True)
+            target=self._run, name=f"wb-committer:{writer.name}",
+            daemon=True)
         self._thread.start()
 
     # -- producer side ---------------------------------------------------
@@ -202,7 +569,7 @@ class WritebackCommitter:
         Returns one ticket a tile."""
         self._cause = (pool, batch)
         heard = pins.active(pins.HB_WB_ENQUEUE)
-        index = self._dev.data_index
+        index = self._writer.index
         tickets: List[int] = []
         entries = []
         for data in datas:
@@ -349,40 +716,17 @@ class WritebackCommitter:
                 or self._flushing or self._stop)
 
     def _commit(self, entries) -> None:
-        """One drain batch: snapshot (version guard), ONE device sync +
-        coalesced D2H gets, guarded host commits.  Runs entirely at the
-        Data level — never takes the device residency lock."""
-        dev = self._dev
-        snaps = []
-        tickets: List[int] = []
-        for (data, tks, _nb) in entries:
-            snap = dev._wb_snapshot(data)
-            if snap is None:
-                self.stats["dropped_stale"] += 1
-                continue
-            snaps.append((data, snap[0], snap[1]))
-            tickets.extend(tks)
-        if not snaps:
-            return
+        """One drain batch (:meth:`HostWriter.writeback_batch`).  Runs
+        entirely at the Data level — never takes the device residency
+        lock."""
         pool, batch = self._cause  # the newest; earlier ones ride along
-        with pins.span("dev:writeback", pool=pool,
-                       rank=getattr(dev.context, "rank", 0),
-                       id=next(_SPAN_SEQ), tiles=len(snaps), batch=batch,
-                       bytes=sum(int(getattr(p, "nbytes", 0))
-                                 for (_d, p, _v) in snaps)):
-            hosts = dev._d2h_batch([p for (_d, p, _v) in snaps])
-            for (data, _payload, version), host in zip(snaps, hosts):
-                # host is None: a donating task consumed that version
-                if host is not None and dev._commit_host(data, version,
-                                                         host):
-                    self.stats["committed"] += 1
-                else:
-                    self.stats["dropped_stale"] += 1
-            if pins.active(pins.HB_WB_COMMIT) and tickets:
-                # acquire edge: the committer joins every enqueue that
-                # fed this batch — exec happens-before write-back commit
-                pins.fire(pins.HB_WB_COMMIT, None, {"tickets": tickets})
-        self.stats["batches"] += 1
+        committed, got = self._writer.writeback_batch(
+            [data for (data, _tks, _nb) in entries], pool, batch,
+            [tks for (_data, tks, _nb) in entries])
+        self.stats["committed"] += committed
+        self.stats["dropped_stale"] += len(entries) - committed
+        if got:
+            self.stats["batches"] += 1
 
     def close(self, flush: bool = True) -> None:
         if flush and self.error is None:

@@ -13,10 +13,27 @@ This is the TPU-native re-design of the reference's generic GPU layer
   eviction with write-back (``device_gpu.h:240-243``); the reference's
   ``zone_malloc`` slab is replaced by byte-budget accounting against the
   PJRT allocator, which owns real HBM placement;
-* **streams as async lanes** — JAX dispatch is asynchronous; in-flight
-  computations are tracked in per-lane in-order queues polled for
-  completion via ``jax.Array.is_ready()``, mirroring the per-stream event
-  queues (``parsec_device_progress_stream``, ``device_gpu.c:1879-1999``).
+* **one in-order device queue** — JAX dispatch is asynchronous and runs
+  one queue in order; a task completes at dispatch, or (with
+  ``tpu_eager_complete=0``) from one in-order queue of computations
+  polled via ``jax.Array.is_ready()`` (the reference's per-stream event
+  queues, ``parsec_device_progress_stream``, ``device_gpu.c:1879-1999``).
+
+The module is four boxes whose arrows point one way::
+
+    tpu.py  (manager loop / submit_batch, signatures, program cache,
+      |      THE staging walk, THE commit, detach)
+      +--> residency.py   (lock, dual LRU, accounting, reserve / evict /
+      |                    drop, advise)         imports nothing of tpu.py
+      +--> staging.py     (transfer lane, committer, AND the write-back
+      |                    halves)               imports nothing of tpu.py
+      +--> value_args.py, scratch.py   (pure: FlowPlan, ValuePlan,
+                                        scratch tiles)
+
+One path per job: every task — a chunk of a wave or one that goes out
+alone — is staged by ``_stage_chunk`` (by its signature's
+:class:`FlowPlan`) and committed by ``_commit_chunk``; every tile that
+has to come from the host goes through ``staging.StageIn.batch``.
 
 Departures from the reference, by TPU design:
 * no device pointers — payloads are ``jax.Array``s; "allocation" is
@@ -40,65 +57,24 @@ import contextlib
 import threading
 import time
 import weakref
-from typing import Any, Deque, Dict, List, Optional, Tuple
+from typing import Any, Deque, Dict, List, Tuple
 
 import jax
 import jax.numpy as jnp
-import numpy as np
 
 from ..core.lifecycle import AccessMode, HookReturn, DEV_TPU
 from ..core.task import Task
 from ..profiling import pins
 from ..utils import debug, mca_param, register_component
 from ..compile_cache import argsig
-from ..data.data import Coherency, Data, DataCopy
+from ..data.data import Coherency, Data
 from . import scratch
 from .device import Device
-from .value_args import (ABSENT, PLACEHOLDER, SCRATCH, VALUE, FlowPlan,
-                         ValuePlan)
-
-
-def _unalias(arr, x, guard, jdev):
-    """Rerun a host->device transfer from a throwaway copy when the
-    result aliases ``guard`` (shared by :func:`private_device_put` and
-    the batched stage-in path — the guard contract must be identical
-    whether a tile travelled alone or coalesced)."""
-    plat = getattr(jdev, "platform", None)
-    if plat is None:
-        try:
-            plat = arr.devices().pop().platform
-        except Exception:
-            plat = "cpu"  # unknown: err on the safe side
-    if plat != "cpu":
-        return arr
-    try:
-        if np.shares_memory(np.asarray(arr), guard):
-            priv = np.array(np.asarray(x), copy=True)
-            arr = jax.device_put(priv, jdev) if jdev is not None \
-                else jnp.asarray(priv)
-    except Exception:
-        pass
-    return arr
-
-
-def private_device_put(x, jdev=None, *, guard=None):
-    """``jax.device_put`` whose result is guaranteed NOT to alias
-    ``guard`` (a host numpy array someone retains).  On the CPU backend
-    PJRT zero-copies suitably-aligned host buffers, so a DONATED
-    execution of the transferred array writes straight through the
-    retained memory — the caller's reference matrix, or a version-v
-    host copy whose bytes must outlive the bump to v+1.  Whether a
-    given buffer zero-copies depends on its heap alignment, which makes
-    the clobber a per-allocation coin flip (seen as a suite flake:
-    the LU reconstruct test intermittently compared against its own
-    overwritten input).  When aliasing is detected the transfer reruns
-    from a throwaway copy — the only memory jax then aliases is
-    jax-private.  Non-CPU platforms always copy host→HBM; the check is
-    skipped there (``np.asarray`` on such arrays would be a D2H pull)."""
-    arr = jax.device_put(x, jdev) if jdev is not None else jnp.asarray(x)
-    if guard is None:
-        return arr
-    return _unalias(arr, x, guard, jdev)
+from .residency import Residency, native_zone
+from .staging import (HostWriter, StageIn, WritebackCommitter, span_id,
+                      stage_depth_param)
+from .value_args import (ABSENT, HOOKED, LATE, PLACEHOLDER, READ, SCRATCH,
+                         VALUE, FlowPlan, ValuePlan)
 
 
 _OUT = int(AccessMode.OUT)
@@ -106,7 +82,7 @@ _OUT = int(AccessMode.OUT)
 
 def _placeholders_at(dev_args) -> Tuple[int, ...]:
     """Positions of a staged argument list that stand for a tile there
-    was nothing to stage for (``TpuDevice._placeholder``): part of a
+    was nothing to stage for (a ``FlowPlan`` ``PLACEHOLDER``): part of a
     program's local key, since ``argsig`` reads such a stand-in as the
     array it is not."""
     return tuple(i for i, a in enumerate(dev_args)
@@ -119,33 +95,15 @@ def _pool_of(task: Task) -> int:
     return getattr(task.taskpool, "taskpool_id", 0)
 
 
-#: one task of a wave chunk as ``_stage_chunk`` leaves it: the task, its
-#: staged argument list, its ``(position in body_args, tile)`` outputs
+#: one task as ``_stage_chunk`` leaves it: the task, its staged argument
+#: list, its ``(position in body_args, tile)`` outputs
 _Staged = Tuple[Task, List[Any], List[Tuple[int, Data]]]
 
 
-class _InFlight:
-    """One submitted computation: outputs pending on a lane (the analogue
-    of a recorded stream event)."""
-
-    __slots__ = ("task", "outputs", "out_specs", "out_hooks", "host_inputs",
-                 "donated")
-
-    def __init__(self, task: Task, outputs: List[Any],
-                 out_specs: List[Tuple[int, Any]],
-                 out_hooks: Optional[List[Any]] = None,
-                 donated: bool = False):
-        self.task = task
-        self.outputs = outputs
-        self.out_specs = out_specs  # (flow position in body_args, Data)
-        #: per-output custom stage_out hooks (None = default commit)
-        self.out_hooks = out_hooks or [None] * len(out_specs)
-        #: the program aliased its outputs onto donated inputs: an
-        #: in-place chain whose successor will consume these buffers
-        self.donated = donated
-
-    def ready(self) -> bool:
-        return all(o.is_ready() for o in self.outputs)
+#: one task whose commit waits for its program (``tpu_eager_complete=0``;
+#: the analogue of a recorded stream event), as ``_commit_chunk`` takes it:
+#: ``(_Staged without its arguments, outputs, stage_out hooks, donated)``
+_InFlight = Tuple[_Staged, List[Any], Any, bool]
 
 
 @register_component("device")
@@ -210,15 +168,14 @@ class TpuDevice(Device):
             "device", "tpu_hbm_budget_mb", 0,
             help="HBM bytes (MB) managed for resident tiles (0=auto)")
         if budget:
-            self.hbm_budget = budget * (1 << 20)
+            budget *= 1 << 20
         else:
             limit = (self.jdev.memory_stats() or {}).get("bytes_limit", 0)
             if not limit and self.jdev.platform == "tpu":
                 raise RuntimeError(
                     f"{self.jdev}: memory_stats() reports no bytes_limit; "
                     "set device_tpu_hbm_budget_mb explicitly")
-            self.hbm_budget = int(limit * 0.85) if limit else 4 << 30
-        self.hbm_used = 0
+            budget = int(limit * 0.85) if limit else 4 << 30
         #: what this module's spans carry (``docs/TRACING.md``): the
         #: context's rank, and the pool and number of the newest batch
         #: submitted — a span on the committer thread names them as the
@@ -236,19 +193,16 @@ class TpuDevice(Device):
         self._manager_active = False
         self._lock = threading.Lock()
         self._pending: Deque[Task] = collections.deque()
-        #: in-order in-flight queues ("compute lanes"); JAX executes one
-        #: device queue, lanes model completion-poll order
-        self._nlanes = mca_param.register(
-            "device", "tpu_exec_streams", 2,
-            help="number of round-robin async submission lanes")
-        self._lanes: List[Deque[_InFlight]] = [collections.deque() for _ in range(self._nlanes)]
-        self._rr = 0
+        #: computations whose commit waits for the program, in submit
+        #: order (``tpu_eager_complete=0``): JAX executes one device
+        #: queue in order, so one queue is what there is to poll
+        self._deferred: Deque[_InFlight] = collections.deque()
         #: eager completion: a single-controller JAX device queue already
         #: orders computations by data dependencies, so successor release
         #: does not need to wait for device events — the runtime completes
         #: the task at dispatch and the whole DAG streams asynchronously
-        #: (one sync at taskpool wait). 0 restores reference-style per-lane
-        #: event polling (device_gpu.c:1879-1999), which pays a full
+        #: (one sync at taskpool wait). 0 restores reference-style event
+        #: polling (device_gpu.c:1879-1999), which pays a full
         #: host<->device round-trip per completion.
         self._eager = bool(mca_param.register(
             "device", "tpu_eager_complete", 1,
@@ -303,40 +257,18 @@ class TpuDevice(Device):
                 # (and new ones ship serialized to peers), so the
                 # per-rank explosion the auto-disable dodged is gone.
                 self._wave_min = 0
-        #: dual LRU of resident Data keyed by data_id (reference
-        #: gpu_mem_lru / gpu_mem_owned_lru)
-        self._lru_clean: "collections.OrderedDict[int, Data]" = collections.OrderedDict()
-        self._lru_dirty: "collections.OrderedDict[int, Data]" = collections.OrderedDict()
         self._jit_cache: Dict[Any, Any] = {}
-        #: native zone allocator models HBM segments (alignment +
-        #: fragmentation) inside the budget — the reference's zone_malloc
-        #: slab, offset-based since PJRT owns the real device memory
-        self._zone = None
-        self._offsets: Dict[int, Tuple[int, int]] = {}  # data_id -> (off, nbytes)
-        self._accounted: Dict[int, int] = {}  # data_id -> accounted nbytes (non-zone)
-        if mca_param.register("device", "tpu_native_zone", 1,
-                              help="use the native zone allocator for HBM accounting"):
-            from .. import native
-
-            if native.available():
-                self._zone = native.ZoneAllocator(self.hbm_budget)
-            elif self.jdev.platform == "tpu":
-                # on the CPU backend byte-counter accounting stands in
-                # (the native-off CI leg); on a chip the configured
-                # allocator missing is a broken installation
-                raise RuntimeError(
-                    "device_tpu_native_zone=1 but the native core is "
-                    f"unavailable: {native.build_error()}")
-        # -- async staging pipeline (device/staging.py) ------------------
-        #: residency lock: LRU/zone/accounting mutations are no longer
-        #: single-threaded once the transfer lane prestages wave N+1
-        #: while the pump thread commits wave N's epilogs.  RLock — the
-        #: stage/evict/realloc paths nest.  Order: _lock -> _res_lock ->
-        #: Data.lock; the committer takes only Data.lock, so an eviction
-        #: waiting on it under _res_lock cannot deadlock.
-        self._res_lock = threading.RLock()
-        from .staging import stage_depth_param
-
+        # -- residency and the staging pipeline ---------------------------
+        #: the write-back halves (device/staging.py) and the resident
+        #: tiles with their accounting (device/residency.py); the lock
+        #: order is residency.py's: _lock -> _res.lock -> Data.lock
+        self._wb = HostWriter(self.data_index, self.stats, self.name,
+                              self._rank)
+        self._res = Residency(self.data_index, budget, self.stats,
+                              self._writeback_evict,
+                              zone=native_zone(self.jdev.platform))
+        self._h2d = StageIn(self._res, self._wb, self.jdev, self.stats,
+                            self._span)
         #: pipeline depth (runtime_stage_depth): 1 = synchronous
         #: transfers (no prefetch lane, no committer — the A/B OFF arm);
         #: >= 2 arms the prefetch window and the write-back committer
@@ -352,7 +284,7 @@ class TpuDevice(Device):
                  "prefetch window (intra-wave double buffering)"))) << 10
         self._committer = None
         #: eviction's bounded wait for an async victim commit before the
-        #: synchronous fallback (satellite: capacity wait, not a hang)
+        #: synchronous fallback (a capacity wait, not a hang)
         self._wb_wait = 60.0
 
     def _span(self, name: str, **info):
@@ -362,27 +294,21 @@ class TpuDevice(Device):
 
     @property
     def hbm_budget(self) -> int:
-        return self._hbm_budget
+        return self._res.budget
 
     @hbm_budget.setter
     def hbm_budget(self, value: int) -> None:
-        """Budget changes rebuild the zone, migrating live residency slots
-        (slots that no longer fit fall out of segment accounting)."""
-        self._hbm_budget = int(value)
-        if getattr(self, "_zone", None) is None:
-            return
-        from .. import native
+        self._res.budget = value  # rebuilds the zone, migrating slots
 
-        fresh = native.ZoneAllocator(self._hbm_budget)
-        migrated: Dict[int, Tuple[int, int]] = {}
-        for did, (_off, nb) in self._offsets.items():
-            noff = fresh.alloc(nb)
-            if noff is not None:
-                migrated[did] = (noff, nb)
-        self._zone.close()
-        self._zone = fresh
-        self._offsets = migrated
-        self.hbm_used = fresh.used
+    @property
+    def hbm_used(self) -> int:
+        return self._res.used
+
+    @property
+    def _zone(self):
+        """The native zone allocator, or None where the byte counter
+        accounts (the drivers count that as a fallback on a chip)."""
+        return self._res.zone
 
     # ------------------------------------------------------------------
     # entry point from the scheduling core (chore hook delegates here)
@@ -432,21 +358,19 @@ class TpuDevice(Device):
                 self._submit_units(units, es, True, drained_ns)
             # phase: get_data_out — retire ready computations in order
             with self._span("dev:poll"):
-                progressed = self._poll_lanes(es)
+                progressed = self._poll_deferred(es)
             with self._lock:
-                if not self._pending and all(not l for l in self._lanes):
+                if not self._pending and not self._deferred:
                     self._manager_active = False
                     return
-            if not progressed:
+            if not progressed and self._deferred:
                 # nothing completed this spin: block on the oldest event
                 # (the reference polls events; jax lets us wait cheaply)
-                oldest = next((l[0] for l in self._lanes if l), None)
-                if oldest is not None:
-                    with self._span("dev:block"):
-                        try:
-                            oldest.outputs[0].block_until_ready()
-                        except Exception:
-                            pass
+                with self._span("dev:block"):
+                    try:
+                        self._deferred[0][1][0].block_until_ready()
+                    except Exception:
+                        pass
 
     def _units_of(self, tasks: List[Task]) -> List[Tuple[str, Any]]:
         """One O(n) bucketing pass: the signature computed ONCE per task,
@@ -458,7 +382,7 @@ class TpuDevice(Device):
             if getattr(task.taskpool, "failed", False):
                 continue
             sig = self._signature_of(task) if self._wave_min > 0 else None
-            if sig is None:
+            if sig is None or sig[0] is None:
                 units.append(("single", task))
                 continue
             key = (id(task.taskpool), sig)
@@ -700,26 +624,34 @@ class TpuDevice(Device):
         return getattr(body, "_jit_key", None) or id(body)
 
     def _wave_signature(self, task: Task):
-        """Hashable batching signature ``(body key, FlowPlan)``, or None
-        when the task cannot ride a wave (:meth:`_wave_body_key`; data
-        args must have knowable shapes).  Two tasks with equal
-        signatures trace identically through the shared wave program.
-        What the body fixes is asked once a chore; shapes, dtypes and
-        modes are compared as the objects they are, and the list of
-        flows is interned as its :class:`FlowPlan`, so a signature
-        hashes and compares by identity from then on."""
+        """``(wave key, FlowPlan)`` of a task with a device body (None
+        without one).  Two tasks with equal signatures and a key trace
+        identically through a shared wave program; the key is None for
+        a task that goes out alone (:meth:`_wave_body_key`; a data arg
+        whose shape nothing says), and its plan still drives THE staging
+        walk and THE commit.  What the body fixes is asked once a chore;
+        shapes, dtypes and modes are compared as the objects they are,
+        and the list of flows is interned as its :class:`FlowPlan`, so a
+        signature hashes and compares by identity from then on."""
         chore = task.selected_chore
         body = chore.body_fn if chore is not None else None
         if body is None:
             return None
         memo = chore.wave_key
         if memo is None or memo[0] is not body:
-            memo = chore.wave_key = (body, self._wave_body_key(body))
-        if memo[1] is None:
-            return None
+            # per-flow custom staging (reference stage_in/stage_out
+            # device hooks, device_gpu.h:62-94), keyed by data-arg order
+            si = getattr(body, "_stage_in", None) or {}
+            so = getattr(body, "_stage_out", None) or {}
+            memo = chore.wave_key = (
+                body, self._wave_body_key(body),
+                {n: (si.get(n), so.get(n)) for n in {*si, *so}} or None)
+        _body, wave_key, hooks = memo
         flows: List[Any] = []
+        nth = -1
         for kind, payload, mode in (task.body_args or ()):
             if kind == "data":
+                nth += 1
                 if payload is None:
                     flows.append(None)
                     continue
@@ -727,16 +659,20 @@ class TpuDevice(Device):
                 if payload.scratch is not None and scratch.unborn(payload):
                     # no argument of the program: never in one wave with
                     # a task whose tile of this flow has been written
-                    flows.append(("unborn", tuple(shape), dtype, mode))
-                    continue
-                if shape is None or dtype is None:
-                    newest = payload.newest_copy()
-                    p = getattr(newest, "payload", None)
-                    shape = getattr(p, "shape", None)
-                    dtype = getattr(p, "dtype", None)
+                    flow = ("unborn", tuple(shape), dtype, mode)
+                else:
                     if shape is None or dtype is None:
-                        return None
-                flows.append((tuple(shape), dtype, mode))
+                        p = getattr(payload.newest_copy(), "payload", None)
+                        shape = getattr(p, "shape", None)
+                        dtype = getattr(p, "dtype", None)
+                    if shape is None or dtype is None:
+                        wave_key = None
+                        flow = (None, None, mode)
+                    else:
+                        flow = (tuple(shape), dtype, mode)
+                if hooks is not None and nth in hooks:
+                    flow += hooks[nth]
+                flows.append(flow)
             elif kind == "value":
                 # traced runtime arg: the TYPE shapes the trace
                 flows.append(type(payload))
@@ -748,19 +684,18 @@ class TpuDevice(Device):
         plan = self._flow_plans.get(key)
         if plan is None:
             plan = self._flow_plans[key] = FlowPlan(key)
-        return (memo[1], plan)
+        return (wave_key, plan)
 
     def _submit_wave(self, tasks: List[Task], es, complete: bool = True,
                      drained_ns: int = 0) -> None:
         """Submit a same-signature ready wave as one (or a few
         power-of-2) jitted multi-body programs: ONE device enqueue per
-        chunk instead of one per task (round-4 VERDICT #6).
+        chunk instead of one per task.
 
         Inputs are staged PER CHUNK, immediately before that chunk's
         dispatch: peak HBM holds one chunk's inputs plus its in-flight
         outputs, never the whole wave's — a large wave of large tiles
-        must not OOM where per-task dispatch would not (ADVICE.md
-        round 5, items 1-2).
+        must not OOM where per-task dispatch would not.
 
         Failure containment is a PER-CHUNK invariant: a chunk's
         staging/trace/enqueue errors RAISE before any task of THAT chunk
@@ -776,10 +711,6 @@ class TpuDevice(Device):
         (double-apply) nor silently skipped (wait() would hang to
         timeout).
 
-        What the wave's tasks share is asked ONCE: their signature's
-        :class:`FlowPlan` drives one residency pass (``_stage_chunk``)
-        and one commit (``_commit_chunk``) per chunk.
-
         One ``dev:wave`` span per chunk, that is per device program,
         with the children ``dev:stage_args``, ``dev:jit``,
         ``dev:dispatch`` (the host's enqueue of the program, not the
@@ -788,13 +719,12 @@ class TpuDevice(Device):
         cls = tasks[0].task_class.name
         self._span_pool = _pool_of(tasks[0])
         sig = self._signature_of(tasks[0])
-        if sig is None:
+        if sig is None or sig[0] is None:
             raise ValueError(f"{tasks[0]!r} cannot ride a wave")
         plan = sig[1]
         # the body OBJECT (not id(body)): an id-keyed entry outlives the
         # body it described, and a recycled id would serve a dead body's
-        # wave program — keying on the object pins it alive instead,
-        # matching the per-task path below
+        # wave program — keying on the object pins it alive instead
         base_key = getattr(body, "_jit_key", None) or body
         start = 0
         remaining = len(tasks)
@@ -818,12 +748,7 @@ class TpuDevice(Device):
         the program's :class:`ValuePlan` says."""
         cnt = len(grp)
         cls = grp[0].task_class.name
-        with self._span("dev:stage_args") as sp:
-            # host tiles, their bytes, tiles staged, residency hits
-            tally = [0, 0, 0, 0]
-            staged = self._stage_chunk(grp, fplan, tally)
-            sp.note(host_tiles=tally[0], bytes=tally[1], tiles=tally[2],
-                    hits=tally[3])
+        staged = self._stage_span(grp, fplan)
         args0, nout = staged[0][1], fplan.nout
 
         def build():
@@ -860,33 +785,55 @@ class TpuDevice(Device):
                 f"{len(outs)} outputs for {nout * cnt} writable flows")
         self.stats["wave_submits"] = self.stats.get("wave_submits", 0) + 1
         self.stats["wave_tasks"] = self.stats.get("wave_tasks", 0) + cnt
+        self._finish(staged, outs, nout, es, complete)
+
+    def _stage_span(self, grp: List[Task], fplan: FlowPlan) -> List[_Staged]:
+        """:meth:`_stage_chunk` under its ``dev:stage_args`` span."""
+        with self._span("dev:stage_args") as sp:
+            # host tiles, their bytes, tiles staged, residency hits
+            tally = [0, 0, 0, 0]
+            staged = self._stage_chunk(grp, fplan, tally)
+            sp.note(host_tiles=tally[0], bytes=tally[1], tiles=tally[2],
+                    hits=tally[3])
+        return staged
+
+    def _finish(self, staged: List[_Staged], outs, nout: int, es,
+                complete: bool, *, out_hooks=None, donated: bool = False,
+                alone: bool = False) -> None:
+        """A program has been enqueued: commit its tasks' outputs now
+        (the ``dev:epilog`` span), or — ``tpu_eager_complete=0`` — once
+        the chip has run it (:meth:`_poll_deferred`)."""
         with self._span("dev:epilog") as sp:
             if self._eager:
-                self._commit_chunk(staged, outs, nout, es, complete, sp)
+                self._commit_chunk(staged, outs, nout, es, complete, sp,
+                                   out_hooks=out_hooks, donated=donated,
+                                   alone=alone)
                 return
-            for k, (task, _args, ospecs) in enumerate(staged):
-                if getattr(task.taskpool, "failed", False):
+            for k, one in enumerate(staged):
+                if getattr(one[0].taskpool, "failed", False):
                     continue  # a sibling's failure already took the pool
-                lane = self._lanes[self._rr % self._nlanes]
-                self._rr += 1
-                lane.append(_InFlight(
-                    task, list(outs[k * nout:(k + 1) * nout]), ospecs))
-                task._tpu_completed = True  # owned by the lane now
+                self._deferred.append((
+                    (one[0], None, one[2]),
+                    list(outs[k * nout:(k + 1) * nout]), out_hooks, donated))
+                one[0]._tpu_completed = True  # the queue's from here on
 
     def _stage_chunk(self, grp: List[Task], fplan: FlowPlan,
                      tally: List[int]) -> List[_Staged]:
-        """kernel_push for one chunk of a wave, in ONE pass under ONE
-        hold of the residency lock: ``(task, dev_args, out_specs)`` per
-        task, by the signature's :class:`FlowPlan`.  A tile that is
-        resident and current yields its payload and one LRU touch a
-        chunk; the others go into the one coalesced put
-        (``_stage_in_batch``; tile by tile in the synchronous regime);
-        ownership moves only once every tile of the chunk is resident,
-        so an error in here raises with no task of the chunk touched.
-        ``tally`` counts for the ``dev:stage_args`` span: ``[tiles
-        copied from the host, their bytes, tiles staged, of them found
-        resident]``."""
+        """kernel_push (reference device_gpu.c:2015-2164 stage-in
+        phase) — THE staging walk: for the tasks of one chunk of a wave,
+        or for one task that goes out alone, in ONE pass under ONE hold
+        of the residency lock: ``(task, dev_args, out_specs)`` per task,
+        by the signature's :class:`FlowPlan`.  A tile that is resident
+        and current yields its payload and one LRU touch a chunk; the
+        others go into the one coalesced put (``StageIn.batch``; tile
+        by tile in the synchronous regime); a flow with a custom
+        ``stage_in`` hook gets the hook's result; ownership moves only
+        once every tile of the chunk is resident, so an error in here
+        raises with no task of the chunk touched.  ``tally`` counts for
+        the ``dev:stage_args`` span: ``[tiles copied from the host,
+        their bytes, tiles staged, of them found resident]``."""
         idx = self.data_index
+        res = self._res
         steps = fplan.steps
         staged: List[_Staged] = []
         owns: List[Tuple[Data, int]] = []
@@ -894,7 +841,7 @@ class TpuDevice(Device):
         #: data_id -> [tile, (argument list, position) it still misses in]
         missing: Dict[int, List[Any]] = {}
         ntiles = nread = nmiss = 0
-        with self._res_lock:
+        with res.lock:
             for task in grp:
                 specs = task.body_args
                 args: List[Any] = []
@@ -914,9 +861,7 @@ class TpuDevice(Device):
                     data = specs[pos][1]
                     if data.scratch is not None:
                         mine.append(data)
-                    if how == PLACEHOLDER:
-                        arr = extra
-                    else:
+                    if how == READ:
                         nread += 1
                         did = data.data_id
                         arr = found.get(did)
@@ -924,7 +869,7 @@ class TpuDevice(Device):
                             c = data.current_copy(idx)
                             if c is not None:
                                 arr = found[did] = c.payload
-                                self._lru_touch(
+                                res.touch(
                                     data,
                                     dirty=c.coherency is Coherency.OWNED)
                             else:
@@ -932,6 +877,26 @@ class TpuDevice(Device):
                                 if slot is None:
                                     slot = missing[did] = [data]
                                 slot.append((args, len(args)))
+                    elif how == PLACEHOLDER:
+                        # a NEW flow's tile nobody has written, or a
+                        # write-only flow the body overwrites (reference
+                        # skips stage-in for OUT-only flows): nothing to
+                        # stage and no argument of the program — the plan
+                        # gives the body zeros inside the trace
+                        arr = extra
+                    elif how == HOOKED:
+                        # custom staging: the hook's result IS the flow's
+                        # device copy (pack/convert — reference
+                        # stage_custom)
+                        arr = self._h2d.custom(data, extra, self)
+                    elif how == LATE:
+                        # no shape to make a placeholder of: the tile
+                        # itself, staged
+                        arr = self._h2d.one(data)
+                    else:  # UNPAIRED
+                        raise RuntimeError(
+                            f"{task!r}: stage_in on writable flow requires "
+                            "a matching stage_out hook")
                     args.append(arr)
                     ntiles += 1
                     owns.append((data, access))
@@ -941,17 +906,18 @@ class TpuDevice(Device):
                 task._tpu_scratch = mine
             if missing:
                 tiles = [slot[0] for slot in missing.values()]
+                # (the arrays as they arrive, not read back from the
+                # tiles: under a budget smaller than the chunk the room
+                # for one tile is made at the expense of its neighbour)
                 if self.stage_depth > 1:
-                    # tentpole (c) of the staging pipeline: the chunk's
-                    # host->device transfers as one batched put
-                    self._stage_in_batch(tiles, tally)
+                    # the chunk's host->device transfers as one batched put
+                    self._h2d.batch(tiles, tally, got=found)
                 else:
                     for data in tiles:
-                        self._stage_in(data, tally)
-                for slot in missing.values():
-                    arr = slot[0].get_copy(idx).payload
+                        found[data.data_id] = self._h2d.one(data, tally)
+                for did, slot in missing.items():
                     for args, at in slot[1:]:
-                        args[at] = arr
+                        args[at] = found[did]
                     nmiss += len(slot) - 1
             for data, access in owns:
                 data.transfer_ownership(idx, access)
@@ -959,84 +925,18 @@ class TpuDevice(Device):
         tally[3] += nread - nmiss
         return staged
 
-    def _stage_task_args(self, task: Task, body,
-                         tally: Optional[List[int]] = None):
-        """kernel_push: stage every flow of ``task`` onto this device and
-        return ``(dev_args, out_specs, out_hooks)`` (reference
-        device_gpu.c:2015-2164 stage-in phase, factored out so the wave
-        path shares it).  ``tally`` counts for the ``dev:stage_args``
-        span: ``[tiles copied from the host, their bytes, tiles
-        staged]``."""
-        # per-flow custom staging (reference stage_in/stage_out device
-        # hooks, device_gpu.h:62-94), keyed by data-arg order
-        si_hooks = getattr(body, "_stage_in", None) or {}
-        so_hooks = getattr(body, "_stage_out", None) or {}
-        dev_args: List[Any] = []
-        out_specs: List[Tuple[int, Data]] = []
-        out_hooks: List[Any] = []
-        held: List[Data] = []  # scratch tiles: one user each, see _epilog
-        data_idx = -1
-        for pos, spec in enumerate(task.body_args or ()):
-            kind, payload, mode = spec
-            if kind == "data":
-                data_idx += 1
-                if payload is None:  # optional (guarded-off) flow
-                    dev_args.append(None)
-                    continue
-                rw = mode & AccessMode.INOUT
-                si = si_hooks.get(data_idx)
-                if si is not None and (mode & AccessMode.OUT) \
-                        and so_hooks.get(data_idx) is None:
-                    # the body would compute on the PACKED representation
-                    # and the epilog would commit it as the home-layout
-                    # tile — silently wrong; loud is the contract
-                    raise RuntimeError(
-                        f"{task!r}: stage_in on writable flow requires a "
-                        "matching stage_out hook")
-                if payload.scratch is not None:
-                    held.append(payload)
-                if si is not None:
-                    # custom staging: the hook's result IS the flow's
-                    # device copy (pack/convert — reference stage_custom)
-                    arr = self._stage_in_custom(payload, si)
-                elif scratch.unborn(payload) or rw == AccessMode.OUT:
-                    # a NEW flow's tile nobody has written, or a
-                    # write-only flow the body overwrites (reference
-                    # skips stage-in for OUT-only flows): nothing to
-                    # stage and no argument of the program — the plan
-                    # gives the body zeros inside the trace
-                    arr = self._placeholder(payload)
-                else:
-                    arr = self._stage_in(payload, tally)
-                if tally is not None:
-                    tally[2] += 1
-                payload.transfer_ownership(self.data_index, rw)
-                dev_args.append(arr)
-                if mode & AccessMode.OUT:
-                    out_specs.append((pos, payload))
-                    out_hooks.append(so_hooks.get(data_idx))
-            elif kind == "value":
-                dev_args.append(payload)
-            elif kind == "scratch":
-                shape, dtype = payload
-                dev_args.append(jnp.zeros(shape, dtype, device=self.jdev))
-            # other kinds (e.g. "ctl") contribute no argument
-        task._tpu_scratch = held
-        return dev_args, out_specs, out_hooks
-
     def _submit(self, task: Task, es=None, complete: bool = True,
                 span=None) -> None:
-        """Stage + body dispatch (reference device_gpu.c:2015-2164);
-        ``span`` is the caller's ``dev:submit_one``."""
-        body = task.selected_chore.body_fn
-        if body is None:
+        """Stage + body dispatch of a task that goes out alone
+        (reference device_gpu.c:2015-2164); ``span`` is the caller's
+        ``dev:submit_one``."""
+        sig = self._signature_of(task)
+        if sig is None:
             # DTD/PTG store the raw device body on the chore at build time
             raise RuntimeError(f"chore of {task!r} has no body_fn for device execution")
-        with self._span("dev:stage_args") as sp:
-            tally = [0, 0, 0]
-            dev_args, out_specs, out_hooks = self._stage_task_args(
-                task, body, tally)
-            sp.note(host_tiles=tally[0], bytes=tally[1], tiles=tally[2])
+        body, fplan = task.selected_chore.body_fn, sig[1]
+        staged = self._stage_span([task], fplan)
+        dev_args = staged[0][1]
 
         base_key = getattr(body, "_jit_key", body)
         # opt-in body attributes (set by the DSL body author):
@@ -1072,24 +972,17 @@ class TpuDevice(Device):
             split = len(dev_args) - nval
             # no plan on this path: a placeholder becomes zeros on the
             # device (created ON this rank's device, not the default one)
-            arr_args = [jnp.zeros(a.shape, a.dtype, device=self.jdev)
-                        if isinstance(a, jax.ShapeDtypeStruct) else a
-                        for a in dev_args[:split]]
+            call_args = [jnp.zeros(a.shape, a.dtype, device=self.jdev)
+                         if isinstance(a, jax.ShapeDtypeStruct) else a
+                         for a in dev_args[:split]]
             vals = tuple(dev_args[split:])
 
             def _bound(*arrs, _body=body, _vals=vals):
                 return _body(*arrs, *_vals)
-            jitted, _ = self._cached_jit(
+            jitted, plan = self._cached_jit(
                 (base_key, vals),
                 lambda: (("static", self._content_fp(body), vals),
                          _bound, donate, None))
-            # a donating call that raises may have invalidated its input
-            # buffers: the task is no longer safely retryable
-            task._tpu_effects = bool(donate)
-            self._fire_exec(task, pins.EXEC_BEGIN)
-            with self._span("dev:dispatch"):
-                outputs = jitted(*arr_args)
-            self._fire_exec(task, pins.EXEC_END)
         else:
             fused_n = int(getattr(body, "_fused_n", 0) or 0)
             if fused_n > 1:
@@ -1124,267 +1017,37 @@ class TpuDevice(Device):
                         plan.donate(donate), plan)
             jitted, plan = self._cached_jit(
                 (base_key, argsig(dev_args), _placeholders_at(dev_args)), build)
-            task._tpu_effects = bool(donate)
-            self._fire_exec(task, pins.EXEC_BEGIN)
-            with self._span("dev:dispatch"):
-                outputs = jitted(*plan.flatten((dev_args,)))
-            self._fire_exec(task, pins.EXEC_END)
-            self._count_values(plan, 1, span, len(out_specs))
+            call_args = plan.flatten((dev_args,))
+        # a donating call that raises may have invalidated its input
+        # buffers: the task is no longer safely retryable
+        task._tpu_effects = bool(donate)
+        self._fire_exec(task, pins.EXEC_BEGIN)
+        with self._span("dev:dispatch"):
+            outputs = jitted(*call_args)
+        self._fire_exec(task, pins.EXEC_END)
+        if plan is not None:
+            self._count_values(plan, 1, span, fplan.nout)
         if not isinstance(outputs, (tuple, list)):
             outputs = (outputs,)
-        outputs = list(outputs)
-        if len(outputs) != len(out_specs):
+        if len(outputs) != fplan.nout:
             raise ValueError(
                 f"device body of {task!r} returned {len(outputs)} outputs "
-                f"for {len(out_specs)} writable flows")
-        inflight = _InFlight(task, outputs, out_specs, out_hooks,
-                             donated=bool(donate))
-        if self._eager:
-            from ..core import scheduling
-
-            # the epilog mutates output tiles one by one (rebind +
-            # version bump): once entered, a retry would double-apply
-            task._tpu_effects = True
-            with self._span("dev:epilog") as sp:
-                sp.note(n=1, outs=len(out_specs),
-                        home=self._epilog(inflight))
-                task._tpu_completed = True
-                if complete:
-                    scheduling.complete_execution(self.context, es, task)
-            return
-        lane = self._lanes[self._rr % self._nlanes]
-        self._rr += 1
-        lane.append(inflight)
-
-    def _placeholder(self, data: Data) -> Any:
-        """What stands in for a tile there is nothing to stage for: its
-        shape and dtype alone (``device/value_args.py`` turns that into
-        zeros inside the trace)."""
-        newest = data.newest_copy()
-        held = getattr(newest, "payload", None)
-        shape = data.shape if data.shape is not None \
-            else getattr(held, "shape", None)
-        dtype = data.dtype if data.dtype is not None \
-            else getattr(held, "dtype", None)
-        if shape is None or dtype is None:
-            return self._stage_in(data)  # shape unknown: fall back
-        return jax.ShapeDtypeStruct(tuple(shape), np.dtype(dtype))
-
-    def _stage_in_custom(self, data: Data, hook) -> Any:
-        """Stage via a user hook: ``hook(data, device) -> jax.Array``.
-        The hook's result becomes the flow's device copy (the reference's
-        stage_in writes into the GPU copy buffer the same way); residency
-        is accounted at the STAGED size, which may differ from the home
-        tile's (packed subtile)."""
-        with self._res_lock:
-            mine = data.get_copy(self.data_index)
-            newest = data.newest_copy()
-            if mine is not None and newest is not None \
-                    and mine.version >= newest.version and mine.payload is not None \
-                    and getattr(mine, "staged_by", None) is hook:
-                # reusable ONLY if this same hook produced it: a current
-                # device copy staged by the default path (prefetch, a prior
-                # epilog) holds the HOME representation, not the packed one
-                self._lru_touch(data, dirty=mine.coherency is Coherency.OWNED)
-                return mine.payload
-            if mine is not None and mine.payload is not None \
-                    and getattr(mine, "staged_by", None) is None:
-                host = data.get_copy(0)
-                if host is None or host.payload is None \
-                        or host.version < mine.version:
-                    # the device copy is the ONLY up-to-date home-layout
-                    # replica: flush it home BEFORE the packed staging
-                    # replaces it, or that data exists nowhere (and the
-                    # hook itself typically reads the host copy).  A
-                    # deferred commit may still be pending for this tile —
-                    # the synchronous flush lands the same version first
-                    # and the committer's guarded commit drops as stale.
-                    self._writeback(data)
-            arr = hook(data, self)
-            old = mine.nbytes if (mine is not None and mine.payload is not None) else 0
-            self._hbm_realloc(data, old, arr.nbytes)
-            arr = jax.device_put(arr, self.jdev)
-            self.stats["bytes_in"] += arr.nbytes
-            self.stats["custom_stage_in"] = self.stats.get("custom_stage_in", 0) + 1
-            c = data.attach_copy(self.data_index, arr)
-            c.version = newest.version if newest is not None else 0
-            c.staged_by = hook
-            self._lru_touch(data, dirty=False)
-            return arr
-
-    def _stage_in(self, data: Data,
-                  tally: Optional[List[int]] = None) -> Any:
-        """Materialize the newest version of ``data`` on this device."""
-        with self._res_lock:
-            mine = data.get_copy(self.data_index)
-            if mine is not None and getattr(mine, "staged_by", None) is not None:
-                # a custom-staged PACKED representation must never be served
-                # as the home layout: drop it and restage from the host copy
-                # (which _stage_in_custom flushed to the same version)
-                self._drop_copy(data, evicted=False)
-                mine = None
-            newest = data.newest_copy()
-            if mine is not None and newest is not None and mine.version >= newest.version and mine.payload is not None:
-                self._lru_touch(data, dirty=mine.coherency is Coherency.OWNED)
-                return mine.payload
-            if newest is None:
-                raise RuntimeError(f"{data!r}: no valid copy to stage in")
-            # re-staging over a stale device copy replaces it: account the delta
-            old = mine.nbytes if (mine is not None and mine.payload is not None) else 0
-            if isinstance(newest.payload, jax.Array):
-                # device-resident arrival (device-capable fabric): land it
-                # with a direct device_put — device-to-device, ICI-class on
-                # multi-chip, no host numpy bounce (SURVEY §5.8)
-                self._hbm_realloc(data, old, newest.payload.nbytes)
-                arr = jax.device_put(newest.payload, self.jdev)
-                self.stats["bytes_d2d"] += newest.payload.nbytes
-            else:
-                host = np.asarray(newest.payload)
-                with self._span("dev:h2d", tiles=1, bytes=host.nbytes):
-                    self._hbm_realloc(data, old, host.nbytes)
-                    # guard: the host copy RETAINS this buffer at version
-                    # v — a zero-copy put followed by a donating task
-                    # would overwrite it in place while its version still
-                    # claims v
-                    arr = private_device_put(host, self.jdev, guard=host)
-                self.stats["bytes_in"] += host.nbytes
-                if data.scratch is not None:
-                    self.stats["scratch_bytes_in"] += host.nbytes
-                if tally is not None:
-                    tally[0] += 1
-                    tally[1] += host.nbytes
-            c = data.attach_copy(self.data_index, arr)
-            c.version = newest.version
-            self._lru_touch(data, dirty=False)
-            return arr
+                f"for {fplan.nout} writable flows")
+        # NOT sent home, a donating program's outputs: the successor of
+        # an in-place chain consumes this very buffer, so an eager get
+        # either stalls the chain behind a device->host copy of every
+        # intermediate version (on the chip: 4 GiB per panel step of the
+        # N=32768 segmented dpotrf, 15 s each) or loses the race and
+        # reads a deleted array.  Such tiles stay dirty-resident;
+        # detach/flush/eviction carry the final version home through the
+        # synchronous guarded path.
+        self._finish(staged, list(outputs), fplan.nout, es, complete,
+                     out_hooks=fplan.out_hooks, donated=bool(donate),
+                     alone=True)
 
     # ------------------------------------------------------------------
     # async staging pipeline: prefetch lane + batched puts
     # ------------------------------------------------------------------
-    def _collect_stage_tiles(self, tasks: List[Task]) -> List[Data]:
-        """The unique PLAIN input tiles of ``tasks`` — flows the default
-        stage-in path will serve: readable, not custom-staged (a hook's
-        packed layout is the hook's business), deduplicated per tile."""
-        out: List[Data] = []
-        seen = set()
-        for task in tasks:
-            chore = task.selected_chore
-            body = chore.body_fn if chore is not None else None
-            si_hooks = getattr(body, "_stage_in", None) or {}
-            data_idx = -1
-            for spec in task.body_args or ():
-                kind, payload, mode = spec
-                if kind != "data":
-                    continue
-                data_idx += 1
-                if payload is None or si_hooks.get(data_idx) is not None:
-                    continue
-                if (mode & AccessMode.INOUT) == AccessMode.OUT:
-                    continue  # write-only: no H2D needed
-                if scratch.unborn(payload):
-                    continue  # a scratch tile nobody has written
-                if payload.data_id in seen:
-                    continue
-                seen.add(payload.data_id)
-                out.append(payload)
-        return out
-
-    def _stage_in_batch(self, datas: List[Data],
-                        tally: Optional[List[int]] = None) -> int:
-        """Batched :meth:`_stage_in`: resident tiles are touched, stale
-        host-side tiles are coalesced into ONE ``jax.device_put`` call
-        (tentpole (c) — one enqueue RPC for the wave's transfers instead
-        of one per tile), each result re-checked against the per-tile
-        aliasing guard.  Returns bytes moved host->device; the put is
-        the ``dev:h2d`` span.
-
-        The residency lock is held to decide what moves and to make room
-        for it, and again to attach what arrived — NOT over the put: the
-        transfer lane's put of the next batch (10 ms for 27 tiles of 1
-        MiB on a v5e) used to hold the pump's staging and epilog of the
-        current one for its whole length (``PERF.md`` §6, PR 27).  A tile
-        that somebody else staged or wrote in between keeps their copy.
-        (The pump's own call, from ``_stage_chunk``, holds the lock
-        around all of it, as it always did: nobody waits for it.)"""
-        moved = 0
-        idx = self.data_index
-        puts: List[Tuple[Data, np.ndarray, int]] = []
-        with self._res_lock:
-            for data in datas:
-                mine = data.get_copy(idx)
-                if mine is not None and getattr(mine, "staged_by", None) is not None:
-                    self._drop_copy(data, evicted=False)
-                    mine = None
-                newest = data.newest_copy()
-                if mine is not None and newest is not None \
-                        and mine.version >= newest.version \
-                        and mine.payload is not None:
-                    self._lru_touch(
-                        data, dirty=mine.coherency is Coherency.OWNED)
-                    continue
-                if newest is None:
-                    raise RuntimeError(f"{data!r}: no valid copy to stage in")
-                old = mine.nbytes if (mine is not None
-                                      and mine.payload is not None) else 0
-                if isinstance(newest.payload, jax.Array):
-                    # device-resident arrival: direct d2d put, uncoalesced
-                    self._hbm_realloc(data, old, newest.payload.nbytes)
-                    arr = jax.device_put(newest.payload, self.jdev)
-                    self.stats["bytes_d2d"] += newest.payload.nbytes
-                    c = data.attach_copy(idx, arr)
-                    c.version = newest.version
-                    self._lru_touch(data, dirty=False)
-                    moved += newest.payload.nbytes
-                    continue
-                host = np.asarray(newest.payload)
-                self._hbm_realloc(data, old, host.nbytes)
-                puts.append((data, host, newest.version))
-        if not puts:
-            return moved
-        nbytes = sum(h.nbytes for (_d, h, _v) in puts)
-        try:
-            with self._span("dev:h2d", tiles=len(puts), bytes=nbytes):
-                try:
-                    arrs = jax.device_put([h for (_d, h, _v) in puts],
-                                          self.jdev)
-                except Exception:
-                    # backend rejected the coalesced put: per-tile path
-                    self.stats["stage_batch_fallbacks"] += 1
-                    arrs = [private_device_put(h, self.jdev, guard=h)
-                            for (_d, h, _v) in puts]
-                else:
-                    arrs = [_unalias(a, h, h, self.jdev)
-                            for a, (_d, h, _v) in zip(arrs, puts)]
-        except BaseException:
-            with self._res_lock:  # the room made for what never arrived
-                for (data, _h, _v) in puts:
-                    mine = data.get_copy(idx)
-                    if mine is None or mine.payload is None:
-                        self._hbm_free(data, 0)
-            raise
-        if tally is not None:
-            tally[0] += len(puts)
-            tally[1] += nbytes
-        with self._res_lock:
-            for (data, host, ver), arr in zip(puts, arrs):
-                self.stats["bytes_in"] += host.nbytes
-                if data.scratch is not None:
-                    self.stats["scratch_bytes_in"] += host.nbytes
-                moved += host.nbytes
-                mine = data.get_copy(idx)
-                if mine is not None and mine.payload is not None \
-                        and mine.version >= ver \
-                        and getattr(mine, "staged_by", None) is None:
-                    continue  # staged or written meanwhile: theirs stands
-                c = data.attach_copy(idx, arr)
-                c.version = ver
-                self._lru_touch(data, dirty=False)
-            self.stats["stage_batched_puts"] = \
-                self.stats.get("stage_batched_puts", 0) + 1
-            self.stats["stage_batched_tiles"] = \
-                self.stats.get("stage_batched_tiles", 0) + len(puts)
-        return moved
-
     def prestage_tiles(self, tasks: List[Task]) -> Tuple[List[Data], int]:
         """The pump's look ahead at a ready batch: the tiles a prestage
         of ``tasks`` would move — read by one of them and not current on
@@ -1392,38 +1055,30 @@ class TpuDevice(Device):
         for the transfer lane; the bytes are the pump's intra-wave split
         heuristic (re-slicing a ready batch across the prefetch window
         only pays when there is real transfer work to hide).  One pass
-        by the tasks' signatures, without the residency lock: a stale
-        read merely mis-sizes the hint, and the submit path stages what
-        the lane did not."""
+        by the tasks' plans, without the residency lock: a stale read
+        merely mis-sizes the hint, and the submit path stages what the
+        lane did not."""
         idx = self.data_index
         seen = set()
-        alone: List[Task] = []
-        tiles: List[Data] = []
+        moving: List[Data] = []
+        nbytes = 0
         for task in tasks:
             sig = self._signature_of(task)
             if sig is None:
-                alone.append(task)
                 continue
             specs = task.body_args
             for pos in sig[1].reads:
                 data = specs[pos][1]
-                if data.data_id not in seen:
-                    seen.add(data.data_id)
-                    tiles.append(data)
-        # a task that goes out alone (hooks, donation, ...) names its
-        # plain input tiles the long way
-        tiles += [d for d in self._collect_stage_tiles(alone)
-                  if d.data_id not in seen]
-        moving: List[Data] = []
-        nbytes = 0
-        for data in tiles:
-            if data.current_copy(idx) is not None:
-                continue  # residency hit: no transfer
-            newest = data.newest_copy()
-            if newest is None or newest.payload is None:
-                continue
-            moving.append(data)
-            nbytes += int(getattr(newest.payload, "nbytes", 0))
+                if data.data_id in seen:
+                    continue
+                seen.add(data.data_id)
+                if data.current_copy(idx) is not None:
+                    continue  # residency hit: no transfer
+                newest = data.newest_copy()
+                if newest is None or newest.payload is None:
+                    continue
+                moving.append(data)
+                nbytes += int(getattr(newest.payload, "nbytes", 0))
         return moving, nbytes
 
     def prestage_batch(self, tasks: List[Task], batch_no: int,
@@ -1435,15 +1090,13 @@ class TpuDevice(Device):
         ``dev:stage_in`` span (critpath's transfer bucket; ``batch`` is
         the pump's number for the batch) and publishes the lane's clock
         into each task's hb token — stage_in happens-before exec."""
-        from .staging import _SPAN_SEQ
-
         if tasks:  # the lane runs ahead of the batch's own submit
             self._span_pool = _pool_of(tasks[0])
-        with self._span("dev:stage_in", id=next(_SPAN_SEQ),
+        with self._span("dev:stage_in", id=span_id(),
                         tiles=len(tiles), batch=batch_no) as sp:
             # a batch with nothing to move: the span, for whoever reads
             # the lane by batch, and neither the lock nor a walk
-            sp.note(bytes=self._stage_in_batch(tiles) if tiles else 0)
+            sp.note(bytes=self._h2d.batch(tiles) if tiles else 0)
         if not tiles:
             return
         self.stats["prefetched_tiles"] = \
@@ -1460,9 +1113,7 @@ class TpuDevice(Device):
             return None
         com = self._committer
         if com is None:
-            from .staging import WritebackCommitter
-
-            com = self._committer = WritebackCommitter(self)
+            com = self._committer = WritebackCommitter(self._wb)
         return com
 
     def flush(self, timeout: float = 300.0) -> None:
@@ -1477,49 +1128,14 @@ class TpuDevice(Device):
             with self._span("dev:flush"):
                 com.flush(timeout=timeout)
 
-    # ------------------------------------------------------------------
-    # HBM budget + dual LRU eviction
-    # ------------------------------------------------------------------
-    def _reserve(self, nbytes: int) -> None:
-        """Make room: evict clean first, then write back dirty tiles
-        (reference device_gpu.c:978-1120 retry/evict loops)."""
-        with self._res_lock:
-            guard = 0
-            while self.hbm_used + nbytes > self.hbm_budget and guard < 10000:
-                guard += 1
-                if not self._evict_one():
-                    break  # nothing evictable; trust the PJRT allocator
-
-    def _evict_one(self) -> bool:
-        with self._res_lock:
-            if self._lru_clean:
-                _, victim = self._lru_clean.popitem(last=False)
-                mine = victim.get_copy(self.data_index)
-                host = victim.get_copy(0)
-                if mine is not None and (host is None or host.payload is None
-                                         or host.version < mine.version):
-                    # a CLEAN device copy can still be the ONLY valid copy:
-                    # device-native arrivals (_deposit_payload, bytes_d2d)
-                    # attach no host copy — dropping without write-back would
-                    # destroy the data
-                    self._writeback_evict(victim)
-                self._drop_copy(victim)
-                return True
-            if self._lru_dirty:
-                _, victim = self._lru_dirty.popitem(last=False)
-                self._writeback_evict(victim)
-                self._drop_copy(victim)
-                return True
-            return False
-
     def _writeback_evict(self, victim: Data) -> None:
         """Eviction write-back, routed through the async committer when
-        the pipeline is on (satellite fix: the synchronous ``_writeback``
-        inside ``_stage_in`` blocked the whole staging path on a D2H
-        get).  The wait is a CAPACITY wait, bounded: the victim's bytes
-        must exist at home before its device copy drops, so a wedged or
-        failed committer falls back to the synchronous path — data
-        safety first, the version guard makes the duplicate a no-op."""
+        the pipeline is on (a synchronous get here would block the whole
+        staging path).  The wait is a CAPACITY wait, bounded: the
+        victim's bytes must exist at home before its device copy drops,
+        so a wedged or failed committer falls back to the synchronous
+        path — data safety first, the version guard makes the duplicate
+        a no-op."""
         com = self._committer
         if com is not None and com.healthy:
             try:
@@ -1528,7 +1144,7 @@ class TpuDevice(Device):
                 # committer died between the check and the enqueue: the
                 # sync fallback still flushes the victim; the sticky
                 # error surfaces at the next epilog enqueue/flush
-                self._writeback(victim)
+                self._wb.writeback(victim)
                 return
             if com.wait_for(victim.data_id, timeout=self._wb_wait):
                 return
@@ -1536,225 +1152,47 @@ class TpuDevice(Device):
                 "async write-back of eviction victim %r did not land in "
                 "%.0fs; falling back to a synchronous flush",
                 victim, self._wb_wait)
-        self._writeback(victim)
-
-    def _hbm_realloc(self, data: Data, old_nbytes: int, new_nbytes: int) -> None:
-        """(Re)account ``data``'s residency slot, evicting for space. With
-        the native zone, alignment + fragmentation are modelled for real:
-        an allocation can fail even under budget and trigger eviction."""
-        with self._res_lock:
-            self._hbm_realloc_locked(data, old_nbytes, new_nbytes)
-
-    def _hbm_realloc_locked(self, data: Data, old_nbytes: int,
-                            new_nbytes: int) -> None:
-        held = (self._offsets.get(data.data_id, (0, 0))[1]
-                if self._zone is not None
-                else self._accounted.get(data.data_id, 0))
-        if new_nbytes > 0 and held == new_nbytes:
-            # the same bytes rebound (an epilog's output over its input):
-            # the slot stays, nothing is allocated, nobody is evicted
-            return
-        # the allocatee must not be its own eviction victim (either mode):
-        # callers re-touch the LRU right after accounting
-        self._lru_clean.pop(data.data_id, None)
-        self._lru_dirty.pop(data.data_id, None)
-        if self._zone is not None:
-            slot = self._offsets.pop(data.data_id, None)
-            if slot is not None:
-                self._zone.release(slot[0])
-            if new_nbytes > 0:
-                guard = 0
-                while True:
-                    off = self._zone.alloc(new_nbytes)
-                    if off is not None or guard > 10000 or not self._evict_one():
-                        break
-                    guard += 1
-                if off is not None:
-                    self._offsets[data.data_id] = (off, new_nbytes)
-            self.hbm_used = self._zone.used
-        else:
-            # truth for what this device accounted lives in _accounted, not
-            # in the caller's view: copies attached from outside (e.g. a
-            # benchmark pre-placing tiles) enter the LRU via _stage_in
-            # without ever being accounted, and freeing them must not
-            # underflow the budget
-            old_acc = self._accounted.pop(data.data_id, 0)
-            self._reserve(max(0, new_nbytes - old_acc))
-            self.hbm_used += new_nbytes - old_acc
-            if new_nbytes > 0:
-                self._accounted[data.data_id] = new_nbytes
-
-    def _hbm_free(self, data: Data, nbytes: int) -> None:
-        with self._res_lock:
-            if self._zone is not None:
-                slot = self._offsets.pop(data.data_id, None)
-                if slot is not None:
-                    self._zone.release(slot[0])
-                self.hbm_used = self._zone.used
-            else:
-                self.hbm_used -= self._accounted.pop(data.data_id, 0)
-
-    def _drop_copy(self, data: Data, *, evicted: bool = True) -> None:
-        with self._res_lock:
-            c = data.detach_copy(self.data_index)
-            if c is not None:
-                self._hbm_free(data, c.nbytes)
-                if evicted:
-                    self.stats["evictions"] += 1
-
-    def _wb_snapshot(self, data: Data):
-        """Version-guarded snapshot of a dirty device copy: returns
-        ``(payload, version)`` to commit home, or None when the commit
-        would be wrong or redundant.  Taken under the Data lock so a
-        concurrent epilog rebind cannot tear payload from version."""
-        with data.lock:
-            c = data.get_copy(self.data_index)
-            if c is None or c.payload is None:
-                return None
-            if getattr(c, "staged_by", None) is not None:
-                # packed custom-staged representation: flushing it home
-                # would corrupt the home tile; the host copy already holds
-                # the same version in home layout (_stage_in_custom
-                # pre-flushes)
-                return None
-            hc = data.get_copy(0)
-            if hc is not None and hc.payload is not None \
-                    and hc.version >= c.version:
-                # the host already holds this version OR NEWER (a CPU body
-                # consumed the device output and bumped past it — the mixed
-                # native_device DAG shape): flushing the stale device copy
-                # would roll the tile back
-                return None
-            return (c.payload, c.version)
-
-    def _commit_host(self, data: Data, version: int, host) -> bool:
-        """Land a D2H'd payload as the host copy at ``version``.  The
-        guard re-checks under the Data lock: a newer commit that landed
-        while our get was in flight wins and ours drops (stale commits
-        are safe to drop — the PR 3 version guard).  Deliberately NO
-        version_bump: the committed value is the same write the device
-        epilog already bumped for, and a second bump would make every
-        deferred commit an RT001 unordered-writer false positive."""
-        if not host.flags.writeable:
-            host = host.copy()  # host copies must be mutable for CPU bodies
-        with data.lock:
-            hc = data.get_copy(0)
-            if hc is not None and hc.payload is not None \
-                    and hc.version >= version:
-                return False
-            hc = data.attach_copy(0, host)
-            hc.version = version
-            hc.coherency = Coherency.SHARED
-        self.stats["bytes_out"] += host.nbytes
-        if data.scratch is not None:  # spilled by an eviction
-            self.stats["scratch_bytes_out"] += host.nbytes
-        return True
-
-    def _d2h_batch(self, payloads: List[Any]) -> List[Optional[np.ndarray]]:
-        """Batched device->host gets: ONE device sync for the whole
-        batch, then the (now-ready) buffers convert without further
-        blocking — the coalesced-gets half of tentpole (c).  A payload
-        that a donating task consumed since it was snapshotted comes
-        back as None: that version no longer exists anywhere, and the
-        consumer's own output supersedes it."""
-        try:
-            jax.block_until_ready(payloads)
-        except Exception:
-            pass  # non-jax or consumed payloads: asarray below decides
-        hosts: List[Optional[np.ndarray]] = []
-        for p in payloads:
-            try:
-                hosts.append(np.asarray(p))
-            except RuntimeError:
-                if not (isinstance(p, jax.Array) and p.is_deleted()):
-                    raise
-                hosts.append(None)
-        return hosts
-
-    def _writeback(self, data: Data) -> None:
-        """Synchronous write-back-to-rest of a dirty tile (reference w2r
-        tasks, ``parsec_gpu_create_w2r_task``); the pipeline's deferred
-        path shares its snapshot/commit halves."""
-        snap = self._wb_snapshot(data)
-        if snap is None:
-            return
-        payload, version = snap
-        host = np.asarray(payload)  # D2H
-        self._commit_host(data, version, host)
-
-    def _writeback_batch(self, datas: List[Data]) -> int:
-        """Batched synchronous flush (the ``detach()`` path): snapshot
-        every dirty tile, ONE device sync + coalesced gets, guarded
-        commits — instead of one blocking get per tile in dict order.
-        Returns the number of tiles actually committed."""
-        from .staging import _SPAN_SEQ
-
-        snaps = []
-        for d in datas:
-            s = self._wb_snapshot(d)
-            if s is not None:
-                snaps.append((d, s[0], s[1]))
-        if not snaps:
-            return 0
-        with self._span("dev:writeback", id=next(_SPAN_SEQ),
-                        tiles=len(snaps), batch=self._span_batch,
-                        bytes=sum(int(getattr(p, "nbytes", 0))
-                                  for (_d, p, _v) in snaps)):
-            hosts = self._d2h_batch([p for (_d, p, _v) in snaps])
-            committed = 0
-            for (data, _p, version), host in zip(snaps, hosts):
-                if host is not None and self._commit_host(data, version,
-                                                          host):
-                    committed += 1
-        self.stats["wb_batches"] = self.stats.get("wb_batches", 0) + 1
-        return committed
-
-    def _lru_touch(self, data: Data, *, dirty: bool) -> None:
-        with self._res_lock:
-            self._lru_clean.pop(data.data_id, None)
-            self._lru_dirty.pop(data.data_id, None)
-            (self._lru_dirty if dirty else self._lru_clean)[data.data_id] = data
+        self._wb.writeback(victim)
 
     # ------------------------------------------------------------------
     # completion / stage_out / epilog
     # ------------------------------------------------------------------
-    def _poll_lanes(self, es) -> bool:
-        """Retire completed computations, in order per lane (reference
-        per-stream event polling)."""
-        from ..core import scheduling
-
+    def _poll_deferred(self, es) -> bool:
+        """Retire completed computations, in submit order (reference
+        per-stream event polling; one stream here)."""
         progressed = False
-        for lane in self._lanes:
-            while lane:
-                inflight = None
-                try:
-                    if not lane[0].ready():
-                        break
-                    inflight = lane.popleft()
-                    self._epilog(inflight)
-                except Exception as e:
-                    # the async computation itself died (device error
-                    # surfacing at poll) or the epilog could not commit
-                    # outputs: the task must NOT complete — successors
-                    # would consume garbage.  Fail the pool loudly.
-                    if inflight is None:
-                        inflight = lane.popleft()  # ready() raised
-                    debug.error("tpu lane retirement failed: %s", e)
-                    self._fail_task_pool(
-                        inflight.task,
-                        f"device lane retirement raised: {e!r}")
-                    progressed = True
-                    continue
-                scheduling.complete_execution(self.context, es, inflight.task)
+        queue = self._deferred
+        while queue:
+            staged, outputs, out_hooks, donated = queue[0]
+            try:
+                if not all(o.is_ready() for o in outputs):
+                    break
+            except Exception as e:
+                # the async computation itself died (device error
+                # surfacing at poll): the task must NOT complete —
+                # successors would consume garbage.  Fail the pool loudly.
+                debug.error("tpu deferred retirement failed: %s", e)
+                queue.popleft()
+                self._fail_task_pool(
+                    staged[0], f"device lane retirement raised: {e!r}")
                 progressed = True
+                continue
+            queue.popleft()
+            # (a commit that cannot land its outputs fails the pool and
+            # completes nothing, by _commit_chunk's discipline)
+            self._commit_chunk([staged], outputs, len(outputs), es, True,
+                               None, out_hooks=out_hooks, donated=donated,
+                               alone=True)
+            progressed = True
         return progressed
 
     def _commit_output(self, data: Data, arr, nbytes: int,
                        bumps_heard: bool) -> None:
         """One output of one task, committed (the caller holds the
         residency lock): rebind the device copy, account its residency,
-        bump the version, keep the tile resident and dirty.  Shared by
-        the per-task epilog and the wave's."""
+        bump the version, keep the tile resident and dirty (reference
+        kernel_epilog device_gpu.c:2343 — data stays OWNED on device;
+        host pulls on demand)."""
         idx = self.data_index
         if data.scratch is not None and scratch.unborn(data):
             self.stats["scratch_tiles_born"] += 1
@@ -1766,9 +1204,9 @@ class TpuDevice(Device):
         # the committed value is HOME-layout (stage_out already
         # unpacked): a packed stage_in marker must not survive it
         c.staged_by = None
-        self._hbm_realloc_locked(data, 0, nbytes)
+        self._res.account(data, nbytes)
         data.version_bump(idx, bumps_heard)
-        self._lru_touch(data, dirty=True)
+        self._res.touch(data, dirty=True)
 
     def _release_scratch(self, tiles) -> None:
         """A task that was one declared user of each of these scratch
@@ -1777,63 +1215,13 @@ class TpuDevice(Device):
         enqueued keeps its buffer)."""
         for data in tiles:
             if scratch.release(data):
-                self._lru_clean.pop(data.data_id, None)
-                self._lru_dirty.pop(data.data_id, None)
-                self._drop_copy(data, evicted=False)
+                self._res.release(data)
                 self.stats["scratch_tiles_freed"] += 1
 
-    def _epilog(self, inflight: _InFlight) -> int:
-        """Commit ONE task's outputs: rebind device copies, bump
-        versions, keep tiles resident & dirty (reference kernel_epilog
-        device_gpu.c:2343 — data stays OWNED on device; host pulls on
-        demand).  A flow's custom stage_out hook transforms the body
-        output first (scatter a packed subtile back — reference
-        stage_custom.jdf).  The path of whatever is not a wave: a task
-        that went out alone, a donating program, hooks, the lanes.
-        Returns the number of outputs handed to the committer."""
-        if pins.active(pins.DEVICE_EPILOG_BEGIN):
-            # happens-before join point: the manager thread is about to
-            # commit this task's outputs (version bumps) — hb-check must
-            # order them after the task's exec, which may have run on a
-            # different (worker) thread (analysis/hb.py)
-            pins.fire(pins.DEVICE_EPILOG_BEGIN, None, inflight.task)
-        bumps_heard = pins.active(pins.DATA_VERSION_BUMP)
-        with self._res_lock:
-            for (pos, data), arr, so in zip(inflight.out_specs,
-                                            inflight.outputs,
-                                            inflight.out_hooks):
-                if so is not None:
-                    # commit to THIS device: a hook building from host data
-                    # would otherwise land on the process default device
-                    arr = jax.device_put(so(arr, data, self), self.jdev)
-                    self.stats["custom_stage_out"] = self.stats.get("custom_stage_out", 0) + 1
-                self._commit_output(data, arr, arr.nbytes, bumps_heard)
-            # outputs grew residency: re-settle under the budget (zone mode
-            # already evicted during allocation)
-            if self._zone is None:
-                self._reserve(0)
-            self._release_scratch(inflight.task._tpu_scratch)
-        self.stats["task_commits"] += 1
-        if inflight.donated:
-            # NOT a donating program's outputs: the successor of an
-            # in-place chain consumes this very buffer, so an eager get
-            # either stalls the chain behind a device->host copy of
-            # every intermediate version (on the chip: 4 GiB per panel
-            # step of the N=32768 segmented dpotrf, 15 s each) or loses
-            # the race and reads a deleted array.  Such tiles stay
-            # dirty-resident; detach/flush/eviction carry the final
-            # version home through the synchronous guarded path.
-            return 0
-        home = inflight.task._tpu_home
-        return self._send_home(
-            [data for (pos, data) in inflight.out_specs
-             if data.scratch is None and (home is None or pos in home)],
-            bool(home))
-
     def _send_home(self, going: List[Data], last: bool) -> int:
-        """Tentpole (b) of the staging pipeline: hand just-committed
-        outputs to the async committer OUTSIDE _res_lock (its capacity
-        wait must not stall residency), in one call.  The committer
+        """Hand just-committed outputs to the async committer OUTSIDE
+        the residency lock (its capacity wait must not stall
+        residency), in one call.  The committer
         dedups per data_id and drains on its byte watermark, so a tile
         rewritten by a later task commits its FINAL version once; the
         version guard drops anything superseded in flight.  A sticky
@@ -1856,35 +1244,63 @@ class TpuDevice(Device):
             com.kick()
         return len(going)
 
-    def _commit_chunk(self, staged, outs, nout: int, es, complete: bool,
-                      sp) -> None:
-        """The epilog of one chunk of a wave: ONE hold of the residency
-        lock commits every output of every task (``_commit_output``, as
-        the per-task epilog does) and releases the scratch tiles whose
-        last user was here; outside the lock the outputs that go home
-        reach the committer in ONE call; then, and only then, the tasks
+    def _commit_chunk(self, staged: List[_Staged], outs, nout: int, es,
+                      complete: bool, sp, *, out_hooks=None,
+                      donated: bool = False, alone: bool = False) -> None:
+        """THE commit — of one chunk of a wave, or of one task that went
+        out alone (``alone``: counted in ``task_commits``, not
+        ``wave_commits``): a flow's custom ``stage_out`` hook transforms
+        the body's output first (``out_hooks``, one a task output;
+        scatter a packed subtile back — reference stage_custom.jdf);
+        then ONE hold of the residency lock commits every output of
+        every task (``_commit_output``) and releases the scratch tiles
+        whose last user was here; outside the lock the outputs that go
+        home reach the committer in ONE call (none of a ``donated``
+        program: see ``_submit``); then, and only then, the tasks
         complete, in order (a task's outputs are committed before its
         ``complete_execution``).  The tools' sites fire as they did — an
         epilog a task, a bump an output, a ticket an enqueue — asked once
-        a chunk whether anybody listens.
+        a chunk whether anybody listens.  ``sp``: the ``dev:epilog``
+        span, where there is one.
 
         Once the commit has begun nothing here may raise: an error fails
         the pool loudly and marks the chunk's tasks completed, so that
         the per-task fallback neither retries (double-apply) nor hangs."""
         from ..core import scheduling
 
+        # happens-before join point: the manager thread is about to
+        # commit these tasks' outputs (version bumps) — hb-check must
+        # order them after each task's exec, which may have run on a
+        # different (worker) thread (analysis/hb.py)
         epilogs_heard = pins.active(pins.DEVICE_EPILOG_BEGIN)
         bumps_heard = pins.active(pins.DATA_VERSION_BUMP)
-        # the k-th output of every task has one shape: its bytes, once
-        sizes = [o.nbytes for o in outs[:nout]]
+        res = self._res
         going: List[Data] = []
         kick = False
         done: List[Task] = []
         try:
-            with self._res_lock:
+            with res.lock:
+                if out_hooks is not None:
+                    outs = list(outs)
+                    for k, so in enumerate(out_hooks * len(staged)):
+                        if so is not None:
+                            # commit to THIS device: a hook building from
+                            # host data would otherwise land on the
+                            # process default device
+                            data = staged[k // nout][2][k % nout][1]
+                            outs[k] = jax.device_put(
+                                so(outs[k], data, self), self.jdev)
+                            self.stats["custom_stage_out"] = \
+                                self.stats.get("custom_stage_out", 0) + 1
+                # the k-th output of every task has one shape: its bytes,
+                # once
+                sizes = [o.nbytes for o in outs[:nout]]
                 for k, (task, _args, ospecs) in enumerate(staged):
                     if getattr(task.taskpool, "failed", False):
                         continue  # a sibling's failure already took the pool
+                    # the commit mutates output tiles one by one (rebind
+                    # + version bump): once entered, a retry would
+                    # double-apply
                     task._tpu_effects = True
                     if epilogs_heard:
                         pins.fire(pins.DEVICE_EPILOG_BEGIN, None, task)
@@ -1901,13 +1317,14 @@ class TpuDevice(Device):
                     if task._tpu_scratch:
                         self._release_scratch(task._tpu_scratch)
                     done.append(task)
-                if self._zone is None:
-                    self._reserve(0)
-            self.stats["wave_commits"] += 1
-            sp.note(n=len(done), outs=len(done) * nout,
-                    home=self._send_home(going, kick))
+                # outputs grew residency: re-settle under the budget
+                res.settle()
+            self.stats["task_commits" if alone else "wave_commits"] += 1
+            home = 0 if donated else self._send_home(going, kick)
+            if sp is not None:
+                sp.note(n=len(done), outs=len(done) * nout, home=home)
         except Exception as e:
-            debug.error("wave epilog of %d x %r failed: %s",
+            debug.error("device epilog of %d x %r failed: %s",
                         len(staged), staged[0][0].task_class.name, e)
             for (task, _args, _o) in staged:
                 if not getattr(task.taskpool, "failed", False):
@@ -1922,7 +1339,7 @@ class TpuDevice(Device):
             try:
                 scheduling.complete_execution(self.context, es, task)
             except Exception as e:
-                debug.error("wave completion of %r failed: %s", task, e)
+                debug.error("device completion of %r failed: %s", task, e)
                 self._fail_task_pool(
                     task, f"device epilog/completion raised: {e!r}")
 
@@ -1943,15 +1360,11 @@ class TpuDevice(Device):
             with self._lock:
                 if self._manager_active:
                     return
-                if advice == ADVICE_PREFETCH:
-                    if data.newest_copy() is None:
-                        return  # nothing materialized yet: hint, not a command
-                    self._stage_in(data)
-                else:
-                    mine = data.get_copy(self.data_index)
-                    if mine is not None and mine.payload is not None:
-                        self._lru_touch(
-                            data, dirty=mine.coherency is Coherency.OWNED)
+                if advice == ADVICE_WARMUP:
+                    self._res.warm(data)
+                elif data.newest_copy() is not None:
+                    # (nothing materialized yet: a hint, not a command)
+                    self._h2d.one(data)
         else:
             super().data_advise(data, advice)
 
@@ -1963,22 +1376,14 @@ class TpuDevice(Device):
         result and hands the buffer on — without this, every completed
         run's output stays dirty-resident until LRU pressure forces a
         full D2H write-back."""
-        with self._lock, self._res_lock:
-            self._lru_clean.pop(data.data_id, None)
-            self._lru_dirty.pop(data.data_id, None)
-            self._drop_copy(data, evicted=False)  # handed over, not evicted
+        with self._lock:
+            self._res.release(data)  # handed over, not evicted
 
     # ------------------------------------------------------------------
     def resident_data(self, task: Task) -> int:
-        total = 0
-        for spec in task.body_args or ():
-            if spec[0] != "data" or spec[1] is None:
-                continue
-            c = spec[1].get_copy(self.data_index)
-            newest = spec[1].newest_copy()
-            if c is not None and c.payload is not None and (newest is None or c.version >= newest.version):
-                total += c.nbytes
-        return total
+        return sum(self._res.resident_bytes(spec[1])
+                   for spec in task.body_args or ()
+                   if spec[0] == "data" and spec[1] is not None)
 
     def detach(self) -> None:
         with self._span("dev:detach"):
@@ -1999,30 +1404,19 @@ class TpuDevice(Device):
                 raise
             com.close(flush=False)
             self._committer = None
-        with self._res_lock:
+        with self._res.lock:
             # flush remaining dirty tiles home as ONE batched device->host
-            # get (satellite 2) — the version guard makes tiles the
+            # get — the version guard makes tiles the
             # committer already landed a no-op, so each dirty tile
             # commits exactly once
             # (a scratch tile has no home: it is dropped, never copied)
-            self._writeback_batch([d for d in self._lru_dirty.values()
-                                   if d.scratch is None])
-            self._lru_dirty.clear()
-            self._lru_clean.clear()
-            # release residency ACCOUNTING with the LRUs: the payloads stay
-            # attached to their Data objects (a later stage-in reuses them,
-            # unaccounted — same rule as externally pre-placed copies), but a
-            # slot no LRU tracks can never be evicted, so leaving it charged
-            # would leak phantom hbm_used across device reuse (the shared
-            # `device=` amortization pattern) until eviction stops working
-            if self._zone is not None:
-                for (off, _nb) in self._offsets.values():
-                    self._zone.release(off)
-                self._offsets.clear()
-                self.hbm_used = self._zone.used
-            else:
-                self._accounted.clear()
-                self.hbm_used = 0
+            _n, got = self._wb.writeback_batch(
+                [d for d in self._res.dirty.values() if d.scratch is None],
+                self._span_pool, self._span_batch)
+            if got:
+                self.stats["wb_batches"] = self.stats.get("wb_batches", 0) + 1
+            # the LRUs and the residency ACCOUNTING go together
+            self._res.clear()
 
 
 def device_body(chore, fn):

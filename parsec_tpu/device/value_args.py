@@ -37,8 +37,9 @@ A :class:`FlowPlan` is the same idea one step earlier, for the device
 module's own host work: what the tasks of one wave signature share about
 their argument lists (which positions are tiles to stage, which are
 written, which are no argument at all) is worked out once a signature,
-in plain ints and bools, and the staging walk and the epilog of every
-chunk read it instead of asking each task's ``AccessMode`` again.
+in plain ints and bools, and the staging walk and the commit of every
+chunk (and of every task that goes out alone) read it instead of asking
+each task's ``AccessMode`` again.
 """
 
 from typing import Any, Iterator, List, Sequence, Tuple
@@ -71,13 +72,14 @@ def _read_leaves(body, args: Sequence[Any]) -> List[bool]:
 
 
 #: what a :class:`FlowPlan` step does with one position of ``body_args``
-VALUE, SCRATCH, ABSENT, PLACEHOLDER, READ = range(5)
+VALUE, SCRATCH, ABSENT, PLACEHOLDER, READ, HOOKED, UNPAIRED, LATE = range(8)
 _OUT, _INOUT = int(AccessMode.OUT), int(AccessMode.INOUT)
 
 
 class FlowPlan:
-    """What one wave signature (``TpuDevice._wave_signature``) fixes
-    about its tasks' ``body_args``, for the staging walk of a chunk.
+    """What one signature (``TpuDevice._wave_signature``) fixes about
+    its tasks' ``body_args``, for THE staging walk (of a chunk of a
+    wave, or of a task that goes out alone) and the commit.
 
     ``steps`` has one ``(how, position, access, extra)`` per
     position that contributes an argument, in order: a ``VALUE`` rides
@@ -88,13 +90,26 @@ class FlowPlan:
     the ``jax.ShapeDtypeStruct`` every task hands the program's
     :class:`ValuePlan`; ``READ`` is a tile to find on the device or stage
     in.  ``access`` is the flow's ``IN``/``OUT`` bits as a plain int:
-    with the ``OUT`` bit the epilog commits an output for it."""
+    with the ``OUT`` bit the commit takes an output for it.
 
-    __slots__ = ("steps", "nout", "reads")
+    Three more, of tasks that never ride a wave: ``HOOKED`` is a flow
+    with a custom ``stage_in`` hook (``extra``: the hook's result IS the
+    flow's device copy); ``UNPAIRED`` is such a flow that is written
+    and has no ``stage_out`` hook, which the walk refuses; ``LATE`` is a
+    write-only flow whose shape neither its tile nor a copy of it says:
+    the walk stages the tile itself.  ``out_hooks`` holds one ``stage_out`` hook (or None)
+    per output, or is None when no output has one."""
+
+    __slots__ = ("steps", "nout", "reads", "out_hooks")
 
     def __init__(self, flows: Sequence[Any]):
-        """``flows``: the signature without its body key."""
+        """``flows``: the signature without its body key.  A tile is
+        ``(shape, dtype, mode)``, with ``"unborn"`` before them for a
+        scratch tile nobody has written and the flow's ``(stage_in,
+        stage_out)`` hooks after them where it has any; ``shape`` is
+        None where nothing says it."""
         steps: List[Tuple[int, int, int, Any]] = []
+        out_hooks: List[Any] = []
         for pos, f in enumerate(flows):
             if f is None:
                 steps.append((ABSENT, pos, 0, None))
@@ -106,19 +121,33 @@ class FlowPlan:
                 steps.append((SCRATCH, pos, 0, (f[1], f[2])))
             else:
                 unborn = f[0] == "unborn"
-                shape, dtype, mode = f[1:] if unborn else f
+                shape, dtype, mode, *hooks = f[1:] if unborn else f
+                si, so = hooks or (None, None)
                 access = int(mode) & _INOUT
-                if unborn or access == _OUT:
+                if si is not None:
+                    # the body would compute on the PACKED representation
+                    # and the commit would take it for the home-layout
+                    # tile — silently wrong; loud is the contract
+                    how = UNPAIRED if access & _OUT and so is None \
+                        else HOOKED
+                    extra = si
+                elif not (unborn or access == _OUT):
+                    how, extra = READ, None
+                elif shape is None:
+                    how, extra = LATE, None
+                else:
                     how, extra = PLACEHOLDER, jax.ShapeDtypeStruct(
                         shape, np.dtype(dtype))
-                else:
-                    how, extra = READ, None
                 steps.append((how, pos, access, extra))
+                if access & _OUT:
+                    out_hooks.append(so)
         self.steps = tuple(steps)
-        #: outputs a task's epilog commits
+        #: outputs a task's commit takes
         self.nout = sum(1 for s in steps if s[2] & _OUT)
         #: positions of the tiles a task needs resident before it runs
+        #: (a hooked flow's packed layout is the hook's business)
         self.reads = tuple(s[1] for s in steps if s[0] == READ)
+        self.out_hooks = tuple(out_hooks) if any(out_hooks) else None
 
 
 class ValuePlan:
@@ -130,9 +159,9 @@ class ValuePlan:
                  "packed", "positional", "tiles_dropped", "tag")
 
     def __init__(self, body, args: Sequence[Any], nvalues: int):
-        """``args``: one task's staged argument list (``_stage_task_
-        args``); ``nvalues``: how many of them are value specs.  A Python
-        scalar among ``args`` can only be a value."""
+        """``args``: one task's staged argument list (``TpuDevice.
+        _stage_chunk``); ``nvalues``: how many of them are value specs.
+        A Python scalar among ``args`` can only be a value."""
         scalars = [i for i, a in enumerate(args)
                    if type(a) in (int, float, bool)]
         unborn = [i for i, a in enumerate(args)

@@ -345,7 +345,7 @@ class GraphExecutor:
             # through to the caller's array (device/tpu.py
             # private_device_put has the full story)
             if isinstance(ins[i], np.ndarray):
-                from ..device.tpu import private_device_put
+                from ..device.staging import private_device_put
 
                 ins[i] = private_device_put(ins[i], guard=ins[i])
         outs = self._fn(*ins)
@@ -377,7 +377,7 @@ class GraphExecutor:
                 # current version: a donated zero-copy view would let
                 # the program overwrite it in place (device/tpu.py
                 # private_device_put)
-                from ..device.tpu import private_device_put
+                from ..device.staging import private_device_put
 
                 feeds[(cname, key)] = private_device_put(
                     c.payload, guard=c.payload)

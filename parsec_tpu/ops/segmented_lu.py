@@ -481,7 +481,7 @@ class SegmentedLU:
         return payload
 
     def __call__(self, A_np: np.ndarray):
-        from ..device.tpu import private_device_put
+        from ..device.staging import private_device_put
 
         # guard=A_np: the donating in-place pipeline must never write
         # through a zero-copy transfer into the CALLER's matrix

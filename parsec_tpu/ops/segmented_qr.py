@@ -278,7 +278,7 @@ class SegmentedQR:
         return out[0], out[1]
 
     def __call__(self, A_np: np.ndarray):
-        from ..device.tpu import private_device_put
+        from ..device.staging import private_device_put
 
         # guard=A_np: the donating in-place pipeline must never write
         # through a zero-copy transfer into the CALLER's matrix

@@ -100,8 +100,10 @@ def test_static_values_rejects_interleaved_args(ctx):
     (DTD-style interleaving) must be rejected loudly, not silently baked
     wrong (suffix split would treat a trailing array as the static
     value)."""
-    from parsec_tpu.core.lifecycle import AccessMode
-    from parsec_tpu.core.task import Task
+    from types import SimpleNamespace
+
+    from parsec_tpu.core.lifecycle import AccessMode, DEV_TPU
+    from parsec_tpu.core.task import Chore, Task, TaskClass
     from parsec_tpu.data import LocalCollection
 
     dev = next(d for d in ctx.devices if d.mca_name == "tpu")
@@ -111,20 +113,12 @@ def test_static_values_rejects_interleaved_args(ctx):
 
     body._static_values = True
     dc = LocalCollection("Z", shape=(4,), dtype=np.float32)
-
-    class FakeChore:
-        body_fn = body
-
-    class FakeTC:
-        name = "interleaved"
-
-    t = Task.__new__(Task)
-    t.task_class = FakeTC()
-    t.locals = ()
+    t = Task(SimpleNamespace(failed=False), TaskClass("interleaved"))
     t.body_args = [("data", dc.data_of(0), AccessMode.INOUT),
                    ("value", 3, AccessMode.VALUE),
                    ("data", dc.data_of(1), AccessMode.INOUT)]
-    t.selected_chore = FakeChore()
+    t.selected_chore = Chore(DEV_TPU, hook=lambda es, task: None)
+    t.selected_chore.body_fn = body
     with pytest.raises(RuntimeError, match="must.*trail|trail all data"):
         dev._submit(t)
 

@@ -75,7 +75,7 @@ def test_segmented_qr_two_flow_residency(ctx):
     Q, R = sq.run(A_dev)
     np.asarray(Q), np.asarray(R)
     assert sq.device.stats["bytes_in"] == 0
-    assert not sq.device._lru_dirty and not sq.device._lru_clean
+    assert not sq.device._res.dirty and not sq.device._res.clean
 
 
 def test_generic_partial_strip_coverage(ctx):

@@ -23,6 +23,7 @@ on:
   pipeline on vs off.
 """
 
+import collections
 import threading
 import time
 
@@ -31,7 +32,7 @@ import pytest
 
 from parsec_tpu import Context, DEV_TPU
 from parsec_tpu.data import data_create
-from parsec_tpu.device.staging import WritebackCommitter
+from parsec_tpu.device.staging import HostWriter, WritebackCommitter
 from parsec_tpu.dsl import DTDTaskpool, INOUT
 from parsec_tpu.utils import mca_param
 
@@ -62,46 +63,28 @@ def tpu_dev(ctx):
 # WritebackCommitter unit surface (stub device)
 # ---------------------------------------------------------------------------
 
-class _StubDev:
-    """The exact surface the committer drives: name for the thread,
-    data_index for dirty-copy lookup, snapshot/D2H/commit halves."""
-
-    name = "stub"
-    data_index = 1
-    context = None
+class _StubDev(HostWriter):
+    """The exact surface the committer drives — the device's write-back
+    halves (``device/staging.py``) — with the D2H counted and failable
+    and the commits recorded."""
 
     def __init__(self):
+        super().__init__(1, collections.Counter(), name="stub")
         self.commits = []  # (data_id, version) in commit order
         self.fail: BaseException = None
         self.d2h_calls = 0
 
-    def _wb_snapshot(self, data):
-        with data.lock:
-            c = data.get_copy(self.data_index)
-            if c is None or c.payload is None:
-                return None
-            hc = data.get_copy(0)
-            if hc is not None and hc.payload is not None \
-                    and hc.version >= c.version:
-                return None
-            return (c.payload, c.version)
-
-    def _d2h_batch(self, payloads):
+    def d2h_batch(self, payloads):
         self.d2h_calls += 1
         if self.fail is not None:
             raise self.fail
         return [np.asarray(p) for p in payloads]
 
-    def _commit_host(self, data, version, host):
-        with data.lock:
-            hc = data.get_copy(0)
-            if hc is not None and hc.payload is not None \
-                    and hc.version >= version:
-                return False
-            hc = data.attach_copy(0, host)
-            hc.version = version
-        self.commits.append((data.data_id, version))
-        return True
+    def commit(self, data, version, host):
+        landed = super().commit(data, version, host)
+        if landed:
+            self.commits.append((data.data_id, version))
+        return landed
 
 
 def _dirty(key, value, version=2, n=16):
@@ -344,7 +327,7 @@ def test_committer_death_fails_pool_not_hang():
         dev = tpu_dev(ctx)
         com = dev._wb_committer()
         assert com is not None
-        orig = dev._d2h_batch
+        orig = dev._wb.d2h_batch
         state = {"boomed": False}
 
         def boom(payloads):
@@ -353,7 +336,7 @@ def test_committer_death_fails_pool_not_hang():
                 raise RuntimeError("injected D2H failure")
             return orig(payloads)
 
-        dev._d2h_batch = boom
+        dev._wb.d2h_batch = boom
         d = data_create("chain", payload=np.zeros((512, 512)))  # 2 MB
         tp = DTDTaskpool(ctx)
         for _ in range(10):
@@ -376,7 +359,7 @@ def test_committer_death_fails_pool_not_hang():
         assert not com.healthy
         # teardown below must not trip over the dead committer: drop it
         # (detach then takes the synchronous batch path) and restore D2H
-        dev._d2h_batch = orig
+        dev._wb.d2h_batch = orig
         com.close(flush=False)
         dev._committer = None
     finally:
@@ -489,11 +472,11 @@ def test_committer_drops_a_version_consumed_by_a_donating_task(ctx):
     dev = tpu_dev(ctx)
     live, gone = jnp.ones(8), jnp.ones(8)
     gone.delete()
-    hosts = dev._d2h_batch([live, gone])
+    hosts = dev._wb.d2h_batch([live, gone])
     assert hosts[1] is None
     np.testing.assert_allclose(hosts[0], 1.0)
 
-    com = WritebackCommitter(dev)
+    com = WritebackCommitter(dev._wb)
     try:
         d = data_create("donated", payload=np.zeros(8))
         c = d.attach_copy(dev.data_index, gone)

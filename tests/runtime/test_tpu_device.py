@@ -508,8 +508,20 @@ def _commit_tasks(layout, n, home):
             out.append(t)
         return out
 
-    if layout == "potrf1":
-        return [stage("potrf1", lambda x, o, i: o + 2.0 * x,
+    if layout in ("potrf1", "hooked", "donate"):
+        def body(x, o, i):
+            return o + 2.0 * x[:o.shape[0]]
+
+        if layout == "hooked":
+            # the body sees the top half of ``o``, packed; the home
+            # layout lives in the host copy
+            body._stage_in = {1: lambda data, device: jnp.asarray(
+                np.asarray(data.newest_copy().payload)[:4])}
+            body._stage_out = {1: lambda arr, data, device: jnp.asarray(
+                np.asarray(data.get_copy(0).payload)).at[:4].set(arr)}
+        elif layout == "donate":
+            body._donate_args = (1,)
+        return [stage(layout, body,
                       [[(tile("x", i, i + 1.0), IN),
                         (tile("o", i, 0.5), INOUT)] for i in range(n)])], \
             tiles
@@ -570,13 +582,16 @@ def _run_commit_tasks(layout, n, complete, waves):
                         cp.payload is None) for i, cp in d.copies.items()))
         seen.update(
             hbm_used=dev.hbm_used,
-            clean=sorted(names[i] for i in dev._lru_clean),
-            dirty=sorted(names[i] for i in dev._lru_dirty),
-            enqueued=com.stats["enqueued"], committed=com.stats["committed"],
+            clean=sorted(names[i] for i in dev._res.clean),
+            dirty=sorted(names[i] for i in dev._res.dirty),
+            # (a device that sent nothing home never armed a committer)
+            enqueued=com.stats["enqueued"] if com else 0,
+            committed=com.stats["committed"] if com else 0,
             host={k: None if d.scratch is not None
                   else np.asarray(d.get_copy(0).payload).tolist()
                   for k, d in tiles.items()},
-            stats={k: dev.stats[k] for k in (
+            stats={k: dev.stats.get(k, 0) for k in (
+                "custom_stage_in", "custom_stage_out",
                 "executed_tasks", "bytes_in", "bytes_out",
                 "scratch_tiles_born", "scratch_tiles_freed",
                 "scratch_bytes_in", "scratch_bytes_out", "evictions",
@@ -588,39 +603,106 @@ def _run_commit_tasks(layout, n, complete, waves):
         c.fini()
 
 
+def _what_the_per_task_epilog_left(layout, n):
+    """What the per-task ``_epilog`` left behind these tasks (PR 27's
+    tree, recorded before it went): the reference the one commit is held
+    to, by tile name — its value, who owns it, ``(on the device,
+    coherency, version, payload is None)`` of each copy, whether it is
+    resident and in which LRU, what the host holds."""
+    S, O, I = Coherency.SHARED, Coherency.OWNED, Coherency.INVALID
+    full = lambda v: np.full((8, 8), v, np.float32).tolist()
+    # written here, sent home once: the device owns version 1, the host
+    # holds it too
+    home = (True, [(False, S, 1, False), (True, O, 1, False)])
+    read = (False, [(False, S, 0, False), (True, S, 0, False)])
+    kept = (True, [(False, I, 0, False), (True, O, 1, False)])  # not sent
+    gone = (False, [])   # scratch: born, used, dropped with its last user
+    rows = {   # name: (state, value on the device, value on the host)
+        "potrf1": {"x": (read, lambda i: i + 1.0, lambda i: i + 1.0),
+                   "o": (home, lambda i: 2.5 + 2 * i, lambda i: 2.5 + 2 * i)},
+        "donate": {"x": (read, lambda i: i + 1.0, lambda i: i + 1.0),
+                   "o": (kept, lambda i: 2.5 + 2 * i, lambda i: 0.5)},
+        "qr2": {"a": (home, lambda i: 2 * i + 2.0, lambda i: 2 * i + 2.0),
+                "q": (gone, None, None)},
+    }
+    rows["qr3"] = dict(rows["qr2"], w=(gone, None, None),
+                       c1=(home, lambda i: i + 3.0, lambda i: i + 3.0),
+                       c2=(home, lambda i: -1.0 * i, lambda i: -1.0 * i))
+    if layout == "hooked":   # the top half went through the body
+        half = lambda i: np.vstack([np.full((4, 8), 2.5 + 2 * i, np.float32),
+                                    np.full((4, 8), 0.5, np.float32)]).tolist()
+        table = {"x": (read, lambda i: full(i + 1.0), lambda i: full(i + 1.0)),
+                 "o": (home, half, half)}
+    else:
+        table = {name: (st, dv and (lambda i, f=dv: full(f(i))),
+                        hv and (lambda i, f=hv: full(f(i))))
+                 for name, (st, dv, hv) in rows[layout].items()}
+    names = [(name, i) for name in table for i in range(n)]
+    resident = [k for k in names if table[k[0]][1] is not None]
+    written = [k for k in resident if table[k[0]][0] is not read]
+    sent = [k for k in written if table[k[0]][0] is home]
+    nscratch = sum(st is gone for st, _d, _h in table.values()) * n
+    nstages = 2 if layout == "qr3" else 1
+    # a packed stage-in moves the top half of ``o``
+    staged_in = 256 * len(resident) - 128 * n * (layout == "hooked")
+    return dict(
+        resident={k: table[k[0]][1](k[1]) for k in resident},
+        tile={k: table[k[0]][0] for k in names},
+        hbm_used=256 * len(resident),
+        clean=sorted(k for k in resident if k not in written),
+        dirty=sorted(written),
+        enqueued=len(sent), committed=len(sent),
+        host={k: table[k[0]][2] and table[k[0]][2](k[1]) for k in names},
+        stats=dict(custom_stage_in=n * (layout == "hooked"),
+                   custom_stage_out=n * (layout == "hooked"),
+                   executed_tasks=nstages * n, bytes_in=staged_in,
+                   bytes_out=256 * len(sent), scratch_tiles_born=nscratch,
+                   scratch_tiles_freed=nscratch, scratch_bytes_in=0,
+                   scratch_bytes_out=0, evictions=0, wave_fallbacks=0,
+                   submit_retries=0))
+
+
 @pytest.mark.parametrize("complete", [False, True], ids=["pump", "context"])
 @pytest.mark.parametrize("n", [2, 4, 64, 7])
 @pytest.mark.parametrize("layout", ["potrf1", "qr2", "qr3"])
 def test_wave_commit_leaves_what_the_per_task_epilog_leaves(layout, n,
                                                             complete):
-    """One commit a chunk (``_commit_chunk``) against one a task
-    (``_epilog``): after the same tasks every tile's version, owner,
-    coherency and payload, the residency accounting and the LRUs'
-    membership, the scratch counters, the bytes that went home and the
-    committer's dedup read the same; the two commit counters add up to
-    the tasks executed."""
+    """A chunk of n against n chunks of one, through the one commit
+    (``_commit_chunk``): what differs between them — the outputs' sizes
+    asked once a chunk, one hold of the residency lock, one call to the
+    committer — is what this guards.  After the same tasks every tile's
+    version, owner, coherency and payload, the residency accounting and
+    the LRUs' membership, the scratch counters, the bytes that went home
+    and the committer's dedup read the same, and both read what the
+    per-task ``_epilog`` left while there was one; the two commit
+    counters add up to the tasks executed."""
     nstages = 2 if layout == "qr3" else 1
     wave, counts = _run_commit_tasks(layout, n, complete, waves=True)
     alone, alone_counts = _run_commit_tasks(layout, n, complete, waves=False)
-    assert wave == alone
+    assert wave == alone == _what_the_per_task_epilog_left(layout, n)
     ntasks = nstages * n
-    assert wave["stats"]["executed_tasks"] == ntasks
-    assert wave["stats"]["scratch_tiles_born"] \
-        == wave["stats"]["scratch_tiles_freed"] \
-        == {"potrf1": 0, "qr2": n, "qr3": 2 * n}[layout]
-    assert wave["stats"]["scratch_bytes_in"] \
-        == wave["stats"]["scratch_bytes_out"] == 0
-    # every tile with a home went there once, whoever enqueued it
-    homes = sum(1 for v in wave["host"].values() if v is not None)
-    written = {"potrf1": n, "qr2": n, "qr3": 3 * n}[layout]
-    assert wave["enqueued"] == wave["committed"] == written
-    assert wave["stats"]["bytes_out"] == written * 8 * 8 * 4 and homes >= written
     # a chunk a power of two: 7 = 4 + 2 + 1
     chunks = nstages * bin(n).count("1")
     assert counts == {"wave_commits": chunks, "wave_submits": chunks,
                       "wave_tasks": ntasks, "task_commits": 0}
     assert alone_counts == {"wave_commits": 0, "wave_submits": 0,
                             "wave_tasks": 0, "task_commits": ntasks}
+
+
+@pytest.mark.parametrize("complete", [False, True], ids=["pump", "context"])
+@pytest.mark.parametrize("layout", ["hooked", "donate"])
+def test_a_task_that_goes_out_alone_leaves_what_the_per_task_epilog_left(
+        layout, complete):
+    """The two layouts only the lone path has, through the one walk and
+    the one commit: a ``stage_in`` / ``stage_out`` hook pair (the body
+    computes on the packed half, the home layout is committed and sent
+    home, both hooks counted) and a donating body (its outputs stay
+    dirty on the device: nothing is enqueued to the committer, which is
+    never armed)."""
+    alone, counts = _run_commit_tasks(layout, 3, complete, waves=True)
+    assert alone == _what_the_per_task_epilog_left(layout, 3)
+    assert counts == {"wave_commits": 0, "wave_submits": 0,
+                      "wave_tasks": 0, "task_commits": 3}
 
 
 @pytest.mark.parametrize("where", ["staging", "trace"])
@@ -641,7 +723,7 @@ def test_a_wave_that_fails_before_dispatch_touches_no_task(ctx, where):
         return failing
 
     if where == "staging":
-        dev._stage_in_batch = once(dev._stage_in_batch)
+        dev._h2d.batch = once(dev._h2d.batch)
     else:
         chore = tasks[0].selected_chore
         chore.body_fn = once(chore.body_fn)
@@ -792,10 +874,10 @@ def test_the_lane_and_the_pump_share_residency_without_losing_a_tile(ctx):
             continue
         resident += 1
         assert mine.version == d.newest_copy().version, d
-        assert (d.data_id in dev._lru_clean) != (d.data_id in dev._lru_dirty)
-    assert resident == len(dev._lru_clean) + len(dev._lru_dirty)
-    slots = dev._offsets if dev._zone is not None else dev._accounted
-    assert set(slots) == set(dev._lru_clean) | set(dev._lru_dirty)
+        assert (d.data_id in dev._res.clean) != (d.data_id in dev._res.dirty)
+    assert resident == len(dev._res.clean) + len(dev._res.dirty)
+    assert set(dev._res.accounted()) \
+        == set(dev._res.clean) | set(dev._res.dirty)
     assert dev.hbm_used == resident * 8 * 8 * 4
     for i in range(n):   # o(r) = 0.5 + 2 o(r-1), o(-1) = x = i + 1
         want = i + 1.0
