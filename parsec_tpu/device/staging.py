@@ -4,7 +4,9 @@ device moves here, synchronously or on the pipeline's two threads.
 * :class:`StageIn` — the host->device half: decide, make room, put,
   attach, for one tile or for a batch in one coalesced ``device_put``.
 * :class:`HostWriter` — the write-back halves: version-guarded snapshot,
-  batched device->host get, guarded commit of the host copy.
+  device->host copies STARTED for a whole batch before one is waited
+  for (or earlier, at hand-over, for a version known to be the last),
+  one wait, guarded commit of the host copy.
 * :class:`StageLane` — a dedicated transfer thread the native pump
   hands the NEXT ready batch to while the current wave computes, so by
   the time the pump submits the batch every plain input is a residency
@@ -17,7 +19,11 @@ device moves here, synchronously or on the pipeline's two threads.
   (``runtime_wb_window_mb``) is crossed, on :meth:`~WritebackCommitter.
   kick` (an eviction needs a victim home; a last version has no later
   one to wait for), or at the :meth:`~WritebackCommitter.flush` barrier
-  ``detach()``/redistribute/remote sends take.  The version guard makes
+  ``detach()``/redistribute/remote sends take.  A drain that somebody
+  waits for, or of last versions, starts every copy before it collects
+  one; the watermark's own drain keeps a round trip a tile, its rate
+  being what bounds the bytes of versions still to be superseded.  The
+  version guard makes
   a stale commit safe to drop, so the committer never takes the device
   residency lock — commits are pure Data-level operations and cannot
   deadlock against eviction waits.
@@ -376,6 +382,22 @@ class StageIn:
             return arr
 
 
+def _start_copy(payload) -> bool:
+    """Start the device->host copy of ``payload`` without waiting for it
+    (``jax.Array.copy_to_host_async``: non-blocking, ordered behind the
+    program that writes the array, a no-op once started).  False where
+    there is nothing to start: a host array, or an array that a donating
+    task consumed (the collect drops that one)."""
+    begin = getattr(payload, "copy_to_host_async", None)
+    if begin is None:
+        return False
+    try:
+        begin()
+    except RuntimeError:
+        return False
+    return True
+
+
 class HostWriter:
     """The write-back halves of one device: snapshot a dirty device copy
     under the version guard, get it (alone or as one batch), land it as
@@ -428,7 +450,13 @@ class HostWriter:
         epilog already bumped for, and a second bump would make every
         deferred commit an RT001 unordered-writer false positive."""
         if not host.flags.writeable:
-            host = host.copy()  # host copies must be mutable for CPU bodies
+            # host copies must be mutable for CPU bodies, and a device
+            # array's host value is not ours to adopt: the array keeps it
+            # cached, so a later ``np.asarray`` of it (a remote send)
+            # would read what a CPU body writes.  ``copy`` gives up the
+            # GIL for the memcpy, on purpose: a landing that keeps it
+            # held the pump's solve up by half (``PERF.md`` §6, PR 29)
+            host = host.copy()
         with data.lock:
             hc = data.get_copy(0)
             if hc is not None and hc.payload is not None \
@@ -442,25 +470,40 @@ class HostWriter:
             self.stats["scratch_bytes_out"] += host.nbytes
         return True
 
+    def start(self, data) -> Optional[int]:
+        """Start, without waiting for it, the device->host copy of the
+        dirty device copy of ``data`` as it stands (the copy is ordered
+        behind the program that writes the tile): for the thread that
+        just committed a version it knows to be the tile's LAST — there
+        is no later one the committer's dedup could save the bytes of.
+        Returns the version the copy was started for (None: nothing to
+        take home, or nothing to start); counted in ``wb_started_early``."""
+        snap = self.snapshot(data)
+        if snap is None or not _start_copy(snap[0]):
+            return None
+        self.stats["wb_started_early"] += 1
+        return snap[1]
+
     def d2h_batch(self, payloads: List[Any]) -> List[Optional[np.ndarray]]:
-        """Batched device->host gets: ONE device sync for the whole
-        batch, then the (now-ready) buffers convert without further
-        blocking.  A payload
-        that a donating task consumed since it was snapshotted comes
-        back as None: that version no longer exists anywhere, and the
-        consumer's own output supersedes it."""
-        try:
-            jax.block_until_ready(payloads)
-        except Exception:
-            pass  # non-jax or consumed payloads: asarray below decides
-        hosts: List[Optional[np.ndarray]] = []
-        for p in payloads:
+        """Collect a batch's device->host copies: the last one first —
+        where the copies were started (:meth:`writeback_batch`,
+        :meth:`start`) they complete in the order they were started, so
+        that is the ONE wait and the rest convert without blocking,
+        hence without a hand-back of the GIL from a busy pump a tile.
+        No device sync: a copy waits for the program that writes its
+        tile.  A payload that a donating task consumed since it was
+        snapshotted comes back as None: that version no longer exists
+        anywhere, and the consumer's own output supersedes it.  A failed
+        computation raises here."""
+        hosts: List[Optional[np.ndarray]] = [None] * len(payloads)
+        last = len(payloads) - 1
+        for k in (last, *range(last)) if payloads else ():
+            p = payloads[k]
             try:
-                hosts.append(np.asarray(p))
+                hosts[k] = np.asarray(p)
             except RuntimeError:
                 if not (isinstance(p, jax.Array) and p.is_deleted()):
                     raise
-                hosts.append(None)
         return hosts
 
     def writeback(self, data) -> None:
@@ -469,32 +512,57 @@ class HostWriter:
         its snapshot/commit halves."""
         snap = self.snapshot(data)
         if snap is not None:
-            self.commit(data, snap[1], np.asarray(snap[0]))  # D2H
+            host = self.d2h_batch([snap[0]])[0]
+            if host is not None:  # None: consumed by a donating task
+                self.commit(data, snap[1], host)
 
     def writeback_batch(self, datas, pool: int = 0, batch: int = 0,
-                        tickets=()) -> Tuple[int, int]:
-        """One batch home: snapshot every tile (version guard), ONE
-        device sync + coalesced gets, guarded commits — under one
-        ``dev:writeback`` span that names ``(pool, batch)`` as its
-        cause.  ``tickets``: per tile, the hb tickets of the enqueues
-        that fed it (the committer's).  Returns ``(tiles committed, tiles
+                        tickets=(), early=(),
+                        ahead: bool = True) -> Tuple[int, int]:
+        """One batch home: snapshot every tile (version guard), START
+        every copy before the first is collected (they run behind one
+        another on the link, not a round trip each), ONE wait, guarded
+        commits — under one ``dev:writeback`` span that names ``(pool,
+        batch)`` as its cause and notes ``wait_us`` (the collect,
+        :meth:`d2h_batch`) and ``early`` (tiles whose copy :meth:`start`
+        had started for the very version collected here:
+        ``wb_early_hits``).  ``tickets``: per tile, the hb tickets of
+        the enqueues that fed it; ``early``: per tile, the version
+        :meth:`start` returned at hand-over (both the committer's).
+        ``ahead=False`` starts nothing: the committer's watermark drain
+        of versions that a later task may supersede keeps a round trip a
+        tile, because on that path its RATE is what bounds the bytes
+        that go home (started ahead, `tile_ctx_n8192` sent 4.37 versions
+        of a tile home for 1.89 and its solve took 14% longer:
+        ``PERF.md`` §6, PR 29).  Returns ``(tiles committed, tiles
         got)``: the others were stale, or consumed by a donating task."""
         snaps = []
         joined: List[int] = []
+        hits = 0
         for k, d in enumerate(datas):
             s = self.snapshot(d)
             if s is not None:
                 snaps.append((d, s[0], s[1]))
                 if tickets:
                     joined.extend(tickets[k])
+                if early and early[k] == s[1]:
+                    hits += 1
         if not snaps:
             return 0, 0
+        self.stats["wb_early_hits"] += hits
         committed = 0
         with pins.span("dev:writeback", pool=pool, rank=self.rank,
                        id=span_id(), tiles=len(snaps), batch=batch,
                        bytes=sum(int(getattr(p, "nbytes", 0))
-                                 for (_d, p, _v) in snaps)):
-            hosts = self.d2h_batch([p for (_d, p, _v) in snaps])
+                                 for (_d, p, _v) in snaps)) as sp:
+            payloads = [p for (_d, p, _v) in snaps]
+            t0 = time.perf_counter_ns()
+            if ahead:
+                for p in payloads:
+                    _start_copy(p)
+            hosts = self.d2h_batch(payloads)
+            sp.note(wait_us=(time.perf_counter_ns() - t0) // 1000,
+                    early=hits)
             for (data, _p, version), host in zip(snaps, hosts):
                 # host is None: a donating task consumed that version
                 if host is not None and self.commit(data, version, host):
@@ -520,8 +588,9 @@ class WritebackCommitter:
     def __init__(self, writer: HostWriter):
         self._writer = writer
         self._cv = threading.Condition()
-        #: data_id -> (Data, [hb tickets], nbytes at enqueue)
-        self._pending: "collections.OrderedDict[int, Tuple[Any, List[int], int]]" = \
+        #: data_id -> (Data, [hb tickets], nbytes at enqueue, the version
+        #: whose copy home was started at enqueue or None)
+        self._pending: "collections.OrderedDict[int, Tuple[Any, List[int], int, Optional[int]]]" = \
             collections.OrderedDict()
         self._inflight: Dict[int, Any] = {}
         self._pending_bytes = 0
@@ -532,8 +601,8 @@ class WritebackCommitter:
                  "are pending (flush/eviction drain sooner)"))) << 20
         self._batch = max(1, int(mca_param.register(
             "runtime", "wb_batch", 32,
-            help="max tiles per committer drain batch (one device sync "
-                 "+ coalesced D2H gets per batch)")))
+            help="max tiles per committer drain batch (its D2H copies "
+                 "collected in one wait)")))
         self._tickets = itertools.count(1)
         #: (pool, batch) of the newest enqueue: the cause a commit names
         self._cause = (0, 0)
@@ -555,12 +624,18 @@ class WritebackCommitter:
         return self.enqueue_all((data,), pool, batch)[0]
 
     def enqueue_all(self, datas, pool: int = 0, batch: int = 0,
-                    kick: bool = False) -> List[int]:
+                    last: bool = False) -> List[int]:
         """Queue deferred write-backs of the dirty device copies of
         ``datas`` in ONE round of the condition variable (``pool`` and
         ``batch``: the batch whose epilog wrote them, which the
-        ``dev:writeback`` span of the commit names as its cause;
-        ``kick``: drain them now, below the watermark).
+        ``dev:writeback`` span of the commit names as its cause).
+        ``last``: the caller knows these to be the LAST versions of
+        their tiles.  Their copies home are started here, before any
+        wait (:meth:`HostWriter.start`), and the committer drains them
+        now, below its watermark: the watermark exists to let a tile
+        that is rewritten commit once.  Of a version that may be
+        superseded nothing is started before its drain: an early copy
+        would move bytes that the dedup saves.
         Deduplicated per tile; bounded by a capacity wait at 4x the
         drain watermark so a stalled committer applies backpressure
         instead of accumulating unbounded dirty state.  Raises the
@@ -580,12 +655,13 @@ class WritebackCommitter:
                 pins.fire(pins.HB_WB_ENQUEUE, None,
                           {"ticket": ticket, "data": data.data_id})
             c = data.get_copy(index)
-            entries.append((data, ticket, c.nbytes if c is not None else 0))
+            entries.append((data, ticket, c.nbytes if c is not None else 0,
+                            self._writer.start(data) if last else None))
             tickets.append(ticket)
         cap = 4 * self._window
         with self._cv:
             self._raise_if_dead()
-            for data, ticket, nb in entries:
+            for data, ticket, nb, early in entries:
                 while (self._pending_bytes + nb > cap and self._pending
                        and self.error is None and not self._stop):
                     self.stats["capacity_waits"] += 1
@@ -594,12 +670,14 @@ class WritebackCommitter:
                 self._raise_if_dead()
                 entry = self._pending.get(data.data_id)
                 if entry is None:
-                    self._pending[data.data_id] = (data, [ticket], nb)
+                    self._pending[data.data_id] = (data, [ticket], nb, early)
                     self._pending_bytes += nb
                 else:
                     entry[1].append(ticket)
+                    if early is not None:  # the newest start stands
+                        self._pending[data.data_id] = entry[:3] + (early,)
                 self.stats["enqueued"] += 1
-            if kick:
+            if last:
                 self._kick = True
             self._cv.notify_all()
         return tickets
@@ -685,6 +763,9 @@ class WritebackCommitter:
                     self._cv.wait(timeout=0.25)
                 if self._stop and not self._pending:
                     return
+                # somebody waits for these tiles, or they are last
+                # versions: nothing a slower drain could still save
+                forced = self._kick or self._flushing or self._stop
                 self._kick = False
                 grab = list(itertools.islice(
                     self._pending.items(), self._batch))
@@ -695,7 +776,7 @@ class WritebackCommitter:
             if not grab:
                 continue
             try:
-                self._commit([entry for _did, entry in grab])
+                self._commit([entry for _did, entry in grab], forced)
             except BaseException as e:
                 with self._cv:
                     self.error = e
@@ -715,14 +796,16 @@ class WritebackCommitter:
         return (self._pending_bytes >= self._window or self._kick
                 or self._flushing or self._stop)
 
-    def _commit(self, entries) -> None:
-        """One drain batch (:meth:`HostWriter.writeback_batch`).  Runs
+    def _commit(self, entries, forced: bool) -> None:
+        """One drain batch (:meth:`HostWriter.writeback_batch`; its
+        copies start ahead of the collect when the drain is ``forced``:
+        a kick, a flush or the stop, not the watermark alone).  Runs
         entirely at the Data level — never takes the device residency
         lock."""
         pool, batch = self._cause  # the newest; earlier ones ride along
         committed, got = self._writer.writeback_batch(
-            [data for (data, _tks, _nb) in entries], pool, batch,
-            [tks for (_data, tks, _nb) in entries])
+            [e[0] for e in entries], pool, batch,
+            [e[1] for e in entries], [e[3] for e in entries], forced)
         self.stats["committed"] += committed
         self.stats["dropped_stale"] += len(entries) - committed
         if got:

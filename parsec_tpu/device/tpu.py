@@ -143,6 +143,11 @@ class TpuDevice(Device):
         #: for the transfer lane to move
         self.stats.update(wave_commits=0, task_commits=0,
                           prestage_skipped=0)
+        #: copies home started at hand-over, for a version the task's
+        #: builder knows to be the tile's last, and those of them that
+        #: were the version the committer's drain collected
+        #: (device/staging.py)
+        self.stats.update(wb_started_early=0, wb_early_hits=0)
         #: one :class:`FlowPlan` per distinct list of flows a wave
         #: signature names (bounded by the task classes' layouts)
         self._flow_plans: Dict[Any, FlowPlan] = {}
@@ -1229,19 +1234,16 @@ class TpuDevice(Device):
         _fail_task_pool discipline: pool failure, not a hang.
         The callers leave out a scratch tile (it has no home to go to)
         and, where the task's builder knows the DAG (``_tpu_home``), a
-        version that a later task overwrites; ``last`` says such a last
-        version is among ``going``: it has no later one to wait for, so
-        the committer starts on it now, below its watermark (which
-        exists to let a tile that is rewritten commit once).  Returns
-        the number handed over."""
+        version that a later task overwrites; ``last`` says that is so
+        of every task here, so ``going`` holds last versions only: their
+        copies home start now, behind the programs that write them, and
+        the committer collects them below its watermark
+        (``WritebackCommitter.enqueue_all``).  Returns the number handed
+        over."""
         com = self._wb_committer()
-        if com is None:
+        if com is None or not going:
             return 0
-        if going:
-            com.enqueue_all(going, self._span_pool, self._span_batch,
-                            kick=last)
-        elif last:
-            com.kick()
+        com.enqueue_all(going, self._span_pool, self._span_batch, last=last)
         return len(going)
 
     def _commit_chunk(self, staged: List[_Staged], outs, nout: int, es,
@@ -1276,7 +1278,8 @@ class TpuDevice(Device):
         bumps_heard = pins.active(pins.DATA_VERSION_BUMP)
         res = self._res
         going: List[Data] = []
-        kick = False
+        #: every task here knows which of its outputs are last versions
+        last = True
         done: List[Task] = []
         try:
             with res.lock:
@@ -1312,15 +1315,15 @@ class TpuDevice(Device):
                         if data.scratch is None \
                                 and (home is None or pos in home):
                             going.append(data)
-                    if home:
-                        kick = True
+                    if home is None:
+                        last = False
                     if task._tpu_scratch:
                         self._release_scratch(task._tpu_scratch)
                     done.append(task)
                 # outputs grew residency: re-settle under the budget
                 res.settle()
             self.stats["task_commits" if alone else "wave_commits"] += 1
-            home = 0 if donated else self._send_home(going, kick)
+            home = 0 if donated else self._send_home(going, last)
             if sp is not None:
                 sp.note(n=len(done), outs=len(done) * nout, home=home)
         except Exception as e:

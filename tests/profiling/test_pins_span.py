@@ -169,3 +169,40 @@ def test_the_new_spans_reach_the_rank_traces_through_one_table(tmp_path):
                for r, p in enumerate(paths)}
     assert by_rank[1] == [("pump:pop", "B", 7, 0), ("pump:pop", "E", 7, 5)]
     assert by_rank[0] == [("dev:wave", "B", 7, 4), ("dev:wave", "E", 7, 4)]
+
+
+def test_the_write_back_says_how_often_a_copy_was_started_ahead():
+    """``dev.stats`` carries ``wb_started_early`` / ``wb_early_hits`` and
+    every ``dev:writeback`` span ``wait_us`` (its one wait) and ``early``
+    (tiles whose copy home was started at hand-over for the version
+    collected): a pump solve starts every tile that goes home ahead."""
+    import numpy as np
+
+    from parsec_tpu.datadist import TiledMatrix
+    from parsec_tpu.dsl.native_exec import NativeExecutor
+    from parsec_tpu.ops.cholesky import cholesky_ptg
+
+    n, nb = 64, 16
+    M = np.random.default_rng(2).standard_normal((n, n))
+    A = TiledMatrix(n, n, nb, nb, name="A", dtype=np.float64).from_array(
+        M @ M.T + n * np.eye(n))
+    tp = cholesky_ptg(use_tpu=True, use_cpu=False).taskpool(NT=A.mt, A=A)
+    log = []
+    _record(pins.WRITEBACK_BEGIN, log)
+    _record(pins.WRITEBACK_END, log)
+    ex = NativeExecutor(tp, native_device=True)
+    dev = ex.device
+    assert dev.stats["wb_started_early"] == dev.stats["wb_early_hits"] == 0
+    ex.run()
+    ex.close()
+    ends = [p for site, _es, p in log if site == pins.WRITEBACK_END]
+    assert ends and len(ends) * 2 == len(log)
+    for p in ends:
+        assert p["wait_us"] >= 0 and 0 <= p["early"] <= p["tiles"]
+        assert {"id", "tiles", "bytes", "batch", "pool", "rank"} <= set(p)
+    assert all("wait_us" not in p for site, _es, p in log
+               if site == pins.WRITEBACK_BEGIN)  # known at the end only
+    tiles_home = A.mt * (A.mt + 1) // 2
+    assert sum(p["tiles"] for p in ends) == tiles_home
+    assert sum(p["early"] for p in ends) == dev.stats["wb_early_hits"] \
+        == dev.stats["wb_started_early"] == tiles_home

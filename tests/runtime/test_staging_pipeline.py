@@ -487,3 +487,257 @@ def test_committer_drops_a_version_consumed_by_a_donating_task(ctx):
         assert com.stats["dropped_stale"] == 1 and com.stats["committed"] == 0
     finally:
         com.close()
+
+
+# ---------------------------------------------------------------------------
+# a copy home is started before anybody waits for it (PR 29)
+# ---------------------------------------------------------------------------
+
+class _Tile:
+    """A device payload double that records, in one shared log, when its
+    copy home is started and when it is collected; its host value is
+    read-only, as a ``jax.Array``'s is."""
+
+    def __init__(self, log, name, value, n=16, nbytes=None):
+        self.log, self.name = log, name
+        self._host = np.full(n, float(value))
+        self._host.flags.writeable = False
+        #: (what the committer's watermark counts: a tile may claim more)
+        self.nbytes = self._host.nbytes if nbytes is None else nbytes
+
+    def copy_to_host_async(self):
+        self.log.append(("start", self.name))
+
+    def __array__(self, dtype=None, copy=None):
+        self.log.append(("collect", self.name))
+        return self._host
+
+
+def _device_dirty(log, key, value, version=2, nbytes=None):
+    d = data_create(key, payload=np.zeros(16))
+    c = d.attach_copy(1, _Tile(log, key, value, nbytes=nbytes))
+    c.version = version
+    return d
+
+
+class _GatedWriter(HostWriter):
+    """A writer whose drain waits at a gate: what the hand-over did is
+    read before the committer thread adds to the log."""
+
+    def __init__(self):
+        super().__init__(1, collections.Counter(), name="gated")
+        self.gate = threading.Event()
+
+    def writeback_batch(self, *args, **kw):
+        assert self.gate.wait(timeout=30)
+        return super().writeback_batch(*args, **kw)
+
+
+def test_every_copy_of_a_drain_is_started_before_the_first_is_collected():
+    log = []
+    w = HostWriter(1, collections.Counter())
+    datas = [_device_dirty(log, k, i + 1.0) for i, k in enumerate("abcde")]
+    assert w.writeback_batch(datas) == (5, 5)
+    assert log[:5] == [("start", k) for k in "abcde"]
+    # ONE wait: the last started is the first collected, the rest follow
+    assert log[5:] == [("collect", k) for k in "eabcd"]
+    for i, d in enumerate(datas):
+        host = d.get_copy(0)
+        assert host.version == 2 and host.payload.flags.writeable
+        np.testing.assert_allclose(host.payload, i + 1.0)
+        host.payload += 1.0  # a CPU body may write it: it is no view
+        np.testing.assert_allclose(np.asarray(d.get_copy(1).payload), i + 1.0)
+    assert w.stats["wb_started_early"] == w.stats["wb_early_hits"] == 0
+    # the synchronous write-back of one tile: the same collect and landing
+    log.clear()
+    lone = _device_dirty(log, "z", 7.0)
+    w.writeback(lone)
+    assert log == [("collect", "z")]
+    assert lone.get_copy(0).payload.flags.writeable
+
+
+@pytest.mark.parametrize("last", [True, False], ids=["last", "unknown"])
+def test_a_last_version_starts_its_copy_at_hand_over_and_no_other(last):
+    """What the task carries decides (``_tpu_home``, through
+    ``TpuDevice._send_home``): a version known to be the tile's last is
+    started as it is handed over; of one that may be superseded nothing
+    is started before its drain — and both are collected the same way."""
+    log = []
+    w = _GatedWriter()
+    com = WritebackCommitter(w)
+    try:
+        datas = [_device_dirty(log, k, 3.0) for k in "ab"]
+        com.enqueue_all(datas, last=last)
+        handed = list(log)  # (the gate is shut: the drain added nothing)
+        w.gate.set()
+        com.flush()
+        if last:
+            assert handed == [("start", "a"), ("start", "b")]
+        else:
+            assert handed == []
+        assert log[len(handed):] == [("start", "a"), ("start", "b"),
+                                     ("collect", "b"), ("collect", "a")]
+        assert w.stats["wb_started_early"] == w.stats["wb_early_hits"] \
+            == (2 if last else 0)
+        assert com.stats["committed"] == 2
+        for d in datas:
+            np.testing.assert_allclose(d.get_copy(0).payload, 3.0)
+    finally:
+        w.gate.set()
+        com.close(flush=False)
+
+
+def test_a_watermark_drain_of_versions_that_may_be_superseded_keeps_its_pace():
+    """Nobody waits for these tiles and a later task may rewrite them:
+    the drain starts nothing ahead (a round trip a tile, as it was),
+    because on this path the committer's rate is what bounds the bytes
+    that go home.  A kick, a flush or a last version lifts that."""
+    log = []
+    w = _GatedWriter()
+    _set("runtime", "wb_window_mb", 1)
+    try:
+        com = WritebackCommitter(w)
+    finally:
+        _unset("runtime", "wb_window_mb")
+    try:
+        datas = [_device_dirty(log, k, 4.0, nbytes=1 << 20) for k in "ab"]
+        com.enqueue_all(datas)  # 2 MiB pending: over the watermark
+        deadline = time.monotonic() + 30
+        while com.pending_bytes() and time.monotonic() < deadline:
+            time.sleep(0.005)
+        assert com.pending_bytes() == 0 and com.pending() == 2  # grabbed
+        w.gate.set()
+        com.flush()
+        assert log == [("collect", "b"), ("collect", "a")]
+        assert com.stats["committed"] == 2
+        # ... and a tile below the watermark, behind a kick, is started
+        # before it is collected
+        log.clear()
+        w.gate.clear()
+        com.enqueue(_device_dirty(log, "c", 5.0))
+        com.kick()
+        w.gate.set()
+        com.flush()
+        assert log == [("start", "c"), ("collect", "c")]
+    finally:
+        w.gate.set()
+        com.close(flush=False)
+
+
+def test_a_tile_rebound_after_hand_over_lands_its_newest_version_once():
+    """The early copy is simply not the one collected: the drain's
+    snapshot takes the version that stands, and ``wb_early_hits`` says
+    the start was for another."""
+    log = []
+    w = _GatedWriter()
+    com = WritebackCommitter(w)
+    try:
+        d = _device_dirty(log, "v2", 2.0, version=2)
+        com.enqueue_all([d], last=True)
+        with d.lock:  # a later task rebinds the device copy
+            c = d.get_copy(1)
+            c.payload, c.version = _Tile(log, "v3", 3.0), 3
+        w.gate.set()
+        com.flush()
+        assert log == [("start", "v2"), ("start", "v3"), ("collect", "v3")]
+        assert w.stats["wb_started_early"] == 1
+        assert w.stats["wb_early_hits"] == 0
+        assert com.stats["committed"] == 1 and w.stats["bytes_out"] == 128
+        host = d.get_copy(0)
+        assert host.version == 3
+        np.testing.assert_allclose(host.payload, 3.0)
+        # handed over again as a last version: the newest start stands
+        with d.lock:
+            c.payload, c.version = _Tile(log, "v4", 4.0), 4
+        w.gate.clear()
+        com.enqueue_all([d], last=False)
+        com.enqueue_all([d], last=True)
+        w.gate.set()
+        com.flush()
+        assert w.stats["wb_started_early"] == 2
+        assert w.stats["wb_early_hits"] == 1
+        assert d.get_copy(0).version == 4
+    finally:
+        w.gate.set()
+        com.close(flush=False)
+
+
+def test_a_version_consumed_after_its_copy_was_started_is_dropped(ctx):
+    """A donating task took the buffer between the start and the
+    collect: that version is gone, its consumer's output supersedes it —
+    dropped as stale, never a dead committer."""
+    import jax.numpy as jnp
+
+    dev = tpu_dev(ctx)
+    w = _GatedWriter()
+    w.index = dev.data_index
+    com = WritebackCommitter(w)
+    try:
+        taken, kept = jnp.ones(8) * 2.0, jnp.ones(8) * 5.0
+        datas = []
+        for key, arr in (("taken", taken), ("kept", kept)):
+            d = data_create(key, payload=np.zeros(8))
+            d.attach_copy(dev.data_index, arr).version = 2
+            datas.append(d)
+        com.enqueue_all(datas, last=True)
+        assert w.stats["wb_started_early"] == 2
+        taken.delete()
+        w.gate.set()
+        com.flush()
+        assert com.healthy
+        assert com.stats["dropped_stale"] == 1 and com.stats["committed"] == 1
+        assert datas[0].get_copy(0).version == 0
+        np.testing.assert_allclose(datas[1].get_copy(0).payload, 5.0)
+        assert datas[1].get_copy(0).payload.flags.writeable
+        # ... and one consumed BEFORE the hand-over starts nothing
+        gone = data_create("gone", payload=np.zeros(8))
+        gone.attach_copy(dev.data_index, taken).version = 2
+        com.enqueue_all([gone], last=True)
+        com.flush()
+        assert w.stats["wb_started_early"] == 2 and com.healthy
+    finally:
+        w.gate.set()
+        com.close(flush=False)
+
+
+@pytest.mark.parametrize("path", ["pump", "context"])
+def test_the_task_says_where_the_copy_starts(path):
+    """The pump's tasks carry ``_tpu_home`` (the DAG's last versions):
+    every tile that goes home was started at hand-over and collected at
+    that version.  The ``Context`` path's tasks do not know: the same
+    DAG starts nothing early and moves no more bytes than tiles written
+    (the committer's dedup keeps saving the superseded versions)."""
+    from parsec_tpu.datadist import TiledMatrix
+    from parsec_tpu.ops.cholesky import cholesky_ptg
+
+    n, nb = 96, 24
+    rng = np.random.default_rng(11)
+    M = rng.standard_normal((n, n))
+    S = M @ M.T + n * np.eye(n)
+    A = TiledMatrix(n, n, nb, nb, name="A", dtype=np.float64).from_array(S)
+    tp = cholesky_ptg(use_tpu=True, use_cpu=False).taskpool(NT=A.mt, A=A)
+    lower = A.mt * (A.mt + 1) // 2
+    if path == "pump":
+        from parsec_tpu.dsl.native_exec import NativeExecutor
+
+        ex = NativeExecutor(tp, native_device=True)
+        dev = ex.device
+        assert ex.run() == 20
+        ex.close()
+        assert dev.stats["wb_started_early"] == lower
+        assert dev.stats["wb_early_hits"] == lower
+    else:
+        c = Context(nb_cores=2)
+        try:
+            dev = tpu_dev(c)
+            c.add_taskpool(tp)
+            assert c.wait(timeout=120)
+        finally:
+            c.fini()
+        assert dev.stats["wb_started_early"] == 0
+        assert dev.stats["wb_early_hits"] == 0
+    assert dev.stats["bytes_out"] == lower * nb * nb * 8
+    L = np.tril(A.to_array())
+    np.testing.assert_allclose(L @ L.T, S, rtol=1e-10, atol=1e-10)
+    for (i, j) in ((0, 0), (A.mt - 1, 0), (A.mt - 1, A.mt - 1)):
+        assert A.data_of(i, j).get_copy(0).payload.flags.writeable
