@@ -5,9 +5,26 @@ The reference's ``gpu_mem_lru`` / ``gpu_mem_owned_lru`` and its
 ``zone_malloc`` slab (``device_gpu.h:240-243``) as one unit that knows
 neither JAX programs nor tasks: a dual LRU of resident ``Data`` (clean,
 and dirty = owned here), byte accounting against a budget, and eviction
-— clean tiles first, then dirty ones, each written back first when the
-device holds the only valid copy, by a callable handed in at
-construction.  "Allocation" is accounting: PJRT owns the real placement.
+— clean tiles first, then dirty ones, a BATCH at a time: as many victims
+as the room asked for takes, those of which the device holds the only
+valid copy written back together first (one wait a batch, not a round
+trip a tile) by a callable handed in at construction.  "Allocation" is
+accounting: PJRT owns the real placement.
+
+**Pins** keep the accounting honest when the matrix is larger than the
+budget (``PERF.md`` §6, PR 30): a tile staged for the chunk in flight,
+or prestaged by the transfer lane for the next batch, is no victim until
+its chunk is committed (:meth:`~Residency.pin` /
+:meth:`~Residency.unpin`): room for one tile of a chunk is never made at
+the expense of its neighbour.  When everything left is pinned,
+:meth:`~Residency.reserve` says so (``reserve_gave_up``, warned once)
+and the staging walk fails the chunk loudly instead of running past the
+budget.  What the accounting does NOT see: a slot is free the moment a
+copy is dropped or rebound, but PJRT keeps the buffer until the last
+program that reads it has run.  Nothing here waits for that: on the chip
+PJRT holds an allocation back until programs in flight have freed the
+memory (the out-of-core cell peaks at 16.84 of 16.91 GB and completes,
+my chip run, PR 30), and runs out of memory loudly if it ever cannot.
 
 Which accounting a device has is decided ONCE, in the constructor: the
 native zone allocator (alignment and fragmentation modelled for real —
@@ -19,18 +36,28 @@ Callers see :meth:`~Residency.account` / :meth:`~Residency.free` /
 single-threaded once the transfer lane prestages wave N+1 while the
 pump thread commits wave N.  RLock — the stage/evict/account paths
 nest.  Order: the device's ``_lock`` -> ``lock`` -> ``Data.lock``; the
-write-back committer takes only ``Data.lock``, so an eviction waiting
-on it under ``lock`` cannot deadlock.
+write-back committer takes only ``Data.lock``, so an eviction writing
+its victims home under ``lock`` cannot deadlock against it.
 """
 
 from __future__ import annotations
 
 import collections
+import contextlib
 import threading
-from typing import Callable, Dict, MutableMapping
+from typing import (Any, Callable, Dict, Iterable, List, MutableMapping,
+                    Tuple)
 
 from ..data.data import Coherency, Data
-from ..utils import mca_param
+from ..utils import debug, mca_param
+
+#: Declared capability, for whoever must refuse a program without it
+#: (``benchmark/drivers/pump_ooc.py``): a taskpool whose tiles exceed the
+#: device's budget runs to its end in bounded device AND host memory —
+#: victims leave a batch at a time, a chunk's tiles are pinned while it
+#: is staged, and a copy home leaves neither a cached host value beside
+#: the resident tile nor a landing copy (``staging.HostWriter._alias``)
+OUT_OF_CORE = True
 
 
 def native_zone(platform: str) -> bool:
@@ -59,13 +86,22 @@ class Residency:
 
     def __init__(self, data_index: int, budget: int,
                  stats: MutableMapping[str, int],
-                 writeback: Callable[[Data], None], zone: bool = False):
+                 writeback: Callable[[List[Data]], int], zone: bool = False,
+                 span: Callable[..., Any] = None):
         """``data_index``: the device's slot in ``Data.copies``;
-        ``writeback(victim)``: bring a victim's device copy home before
-        it drops; ``stats``: where ``evictions`` are counted; ``zone``:
-        account in the native zone allocator (:func:`native_zone`)."""
+        ``writeback(victims)``: bring the victims' device copies home, as
+        one batch, before they drop, and return the microseconds it
+        waited; ``stats``: where ``evictions`` and their kin are counted;
+        ``zone``: account in the native zone allocator
+        (:func:`native_zone`); ``span``: what opens a span of the device
+        (``dev:evict``)."""
         self.index = data_index
         self.stats = stats
+        for k in ("evictions", "evict_clean", "evict_dirty",
+                  "evict_bytes_home", "evict_batches", "restaged_tiles",
+                  "reserve_gave_up", "unaccounted_tiles"):
+            stats.setdefault(k, 0)
+        self._span = span or (lambda name, **info: contextlib.nullcontext())
         self.lock = threading.RLock()
         #: dual LRU keyed by data_id, oldest first
         self.clean: "collections.OrderedDict[int, Data]" = \
@@ -82,6 +118,12 @@ class Residency:
         #: accounted, and freeing it must not underflow the budget
         self._held: Dict[int, int] = {}
         self._offsets: Dict[int, int] = {}
+        #: data_id -> how many stagings hold the tile: no victim
+        self._pins: Dict[int, int] = {}
+        #: tiles an eviction dropped, until they are staged in again
+        #: (``restaged_tiles``)
+        self._evicted: set = set()
+        self._warned: set = set()
         #: the native zone allocator (offset-based: PJRT owns the memory)
         self.zone = self._new_zone() if zone else None
 
@@ -120,38 +162,54 @@ class Residency:
             return dict(self._held)
 
     # -- accounting --------------------------------------------------------
-    def account(self, data: Data, nbytes: int) -> None:
+    def _in_use(self) -> int:
+        return self.used if self.zone is None else self.zone.used
+
+    def account(self, data: Data, nbytes: int) -> bool:
         """(Re)account ``data``'s slot at ``nbytes``, evicting for
         space.  The same bytes rebound (an epilog's output over its
-        input) keep the slot: nothing is allocated, nobody is evicted."""
+        input) keep the slot: nothing is allocated, nobody is evicted.
+        False when the room could not be made (everything left is
+        pinned; counted by :meth:`reserve`) or, with the zone, when no
+        slot could be had at all (``unaccounted_tiles``): the tile is
+        then resident and charged to nobody."""
         did = data.data_id
         with self.lock:
             if nbytes > 0 and self._held.get(did, 0) == nbytes:
-                return
+                return True
             # the allocatee must not be its own eviction victim (either
             # accounting): callers re-touch the LRU right after
             self.forget(data)
             old = self._held.pop(did, 0)
             if self.zone is None:
-                self.reserve(max(0, nbytes - old))
+                ok = self.reserve(max(0, nbytes - old))
                 self.used += nbytes - old
                 if nbytes > 0:
                     self._held[did] = nbytes
-                return
+                return ok
             off = self._offsets.pop(did, None)
             if off is not None:
                 self.zone.release(off)
+            ok = True
             if nbytes > 0:
-                guard = 0
-                while True:
+                ok = self.reserve(nbytes)
+                off = self.zone.alloc(nbytes)
+                # (under the budget and no slot: the zone is fragmented;
+                # one more batch of victims a try)
+                while off is None and self._evict(nbytes):
                     off = self.zone.alloc(nbytes)
-                    if off is not None or guard > 10000 \
-                            or not self.evict_one():
-                        break
-                    guard += 1
                 if off is not None:
                     self._held[did], self._offsets[did] = nbytes, off
+                else:
+                    ok = False
+                    self.stats["unaccounted_tiles"] += 1
+                    self._warn_once(
+                        "unaccounted", "no slot of %d bytes for %r in the "
+                        "zone (%d of %d used): the tile stays resident "
+                        "and unaccounted", nbytes, data, self.zone.used,
+                        self._budget)
             self.used = self.zone.used
+            return ok
 
     def free(self, data: Data) -> None:
         """Release ``data``'s slot (none: a no-op, never an underflow)."""
@@ -186,6 +244,8 @@ class Residency:
                     self.zone.release(off)
             self._offsets.clear()
             self._held.clear()
+            self._pins.clear()
+            self._evicted.clear()
             self.used = 0 if self.zone is None else self.zone.used
 
     # -- the LRUs ----------------------------------------------------------
@@ -216,37 +276,119 @@ class Residency:
         return c.nbytes if newest is None or c.version >= newest.version \
             else 0
 
-    # -- making room -------------------------------------------------------
-    def reserve(self, nbytes: int) -> None:
-        """Make room: evict clean first, then write back dirty tiles
-        (reference device_gpu.c:978-1120 retry/evict loops)."""
-        with self.lock:
-            guard = 0
-            while self.used + nbytes > self._budget and guard < 10000:
-                guard += 1
-                if not self.evict_one():
-                    break  # nothing evictable; trust the PJRT allocator
+    # -- pins --------------------------------------------------------------
+    def pin(self, data: Data) -> None:
+        """``data`` is staged for a chunk that has not been committed
+        (or for the batch the lane runs ahead of): no victim until
+        :meth:`unpin` (the caller holds the lock)."""
+        did = data.data_id
+        self._pins[did] = self._pins.get(did, 0) + 1
 
-    def evict_one(self) -> bool:
+    def unpin(self, datas: Iterable[Data]) -> None:
         with self.lock:
-            if self.clean:
-                _, victim = self.clean.popitem(last=False)
+            pins = self._pins
+            for data in datas:
+                did = data.data_id
+                n = pins.get(did, 0) - 1
+                if n > 0:
+                    pins[did] = n
+                else:
+                    pins.pop(did, None)
+
+    @property
+    def chunk_limit(self) -> int:
+        """Bytes of tiles (read and written) one device program may
+        take: a sixteenth of the budget, so that a chunk's pins, the
+        lane's and the outputs in flight together leave the budget most
+        of its room (a 64-task gemm wave of 16 MiB tiles is 4 GiB)."""
+        return self._budget // 16
+
+    # -- making room -------------------------------------------------------
+    def _warn_once(self, what: str, msg: str, *args) -> None:
+        if what not in self._warned:
+            self._warned.add(what)
+            debug.warning(msg, *args)
+
+    def reserve(self, nbytes: int) -> bool:
+        """Make room for ``nbytes`` under the budget: one batch of
+        victims, clean first, then dirty ones written back together
+        (reference device_gpu.c:978-1120 retry/evict loops).  False when
+        the room is not there and nothing is left to evict (every
+        resident tile is pinned, or the budget is smaller than what one
+        chunk needs): counted in ``reserve_gave_up`` and warned once; the
+        staging walk fails its chunk on it, a commit (whose outputs
+        exist already) goes on over the budget."""
+        with self.lock:
+            need = self._in_use() + nbytes - self._budget
+            if need <= 0:
+                return True
+            self._evict(need)
+            if self._in_use() + nbytes <= self._budget:
+                return True
+            self.stats["reserve_gave_up"] += 1
+            self._warn_once(
+                "gave_up", "residency: no room for %d bytes (%d of %d in "
+                "use, %d tiles pinned, nothing evictable)", nbytes,
+                self._in_use(), self._budget, len(self._pins))
+            return False
+
+    def _victims(self, need: int) -> List[Tuple[Data, bool]]:
+        """Out of the LRUs, unpinned, until their slots cover ``need``
+        bytes, as ``(tile, was dirty)``: oldest first, clean before
+        dirty."""
+        out: List[Tuple[Data, bool]] = []
+        pins, held = self._pins, self._held
+        for lru in (self.clean, self.dirty):
+            for did in [d for d in lru if d not in pins]:
+                if need <= 0:
+                    return out
+                out.append((lru.pop(did), lru is self.dirty))
+                need -= held.get(did, 0)
+        return out
+
+    def _evict(self, need: int) -> bool:
+        """One batch of victims for ``need`` bytes (the caller holds the
+        lock): those whose copy here is the only valid one go home
+        first, together, then every victim drops.  A ``dev:evict`` span
+        with ``victims``, ``dirty`` (written home), ``bytes_home`` and
+        ``wait_us`` (of the write-back's one wait).  False: no victim."""
+        victims = self._victims(need)
+        if not victims:
+            return False
+        with self._span("dev:evict", need=need) as sp:
+            home: List[Data] = []
+            bytes_home = 0
+            for victim, dirty in victims:
                 mine = victim.get_copy(self.index)
+                if mine is None or mine.payload is None:
+                    continue
                 host = victim.get_copy(0)
-                if mine is not None and (host is None or host.payload is None
-                                         or host.version < mine.version):
-                    # a CLEAN device copy can still be the ONLY valid
-                    # copy: device-native arrivals (_deposit_payload,
-                    # bytes_d2d) attach no host copy — dropping without
-                    # write-back would destroy the data
-                    self._writeback(victim)
-            elif self.dirty:
-                _, victim = self.dirty.popitem(last=False)
-                self._writeback(victim)
-            else:
-                return False
-            self.drop(victim)
-            return True
+                # a CLEAN device copy can still be the ONLY valid copy:
+                # device-native arrivals (_deposit_payload, bytes_d2d)
+                # attach no host copy — dropping without write-back
+                # would destroy the data
+                if dirty or host is None or host.payload is None \
+                        or host.version < mine.version:
+                    home.append(victim)
+                    bytes_home += mine.nbytes
+            wait_us = self._writeback(home) if home else 0
+            for victim, _dirty in victims:
+                self.drop(victim)
+            self.stats["evict_batches"] += 1
+            self.stats["evict_dirty"] += len(home)
+            self.stats["evict_clean"] += len(victims) - len(home)
+            self.stats["evict_bytes_home"] += bytes_home
+            if sp is not None:
+                sp.note(victims=len(victims), dirty=len(home),
+                        bytes_home=bytes_home, wait_us=wait_us)
+        return True
+
+    def restaged(self, data: Data) -> None:
+        """``data`` is staged in (the caller holds the lock): counted,
+        once, when an eviction had dropped it (``restaged_tiles``)."""
+        if data.data_id in self._evicted:
+            self._evicted.discard(data.data_id)
+            self.stats["restaged_tiles"] += 1
 
     def drop(self, data: Data, *, evicted: bool = True) -> None:
         """Detach ``data``'s copy here and release its slot."""
@@ -256,6 +398,7 @@ class Residency:
                 self.free(data)
                 if evicted:
                     self.stats["evictions"] += 1
+                    self._evicted.add(data.data_id)
 
     def release(self, data: Data) -> None:
         """Hand ``data``'s copy on WITHOUT a write-back and without

@@ -17,16 +17,18 @@ device moves here, synchronously or on the pipeline's two threads.
   per tile, so a re-dirtied tile goes home ONCE, at its newest version);
   it drains in batched gets when the pending-bytes watermark
   (``runtime_wb_window_mb``) is crossed, on :meth:`~WritebackCommitter.
-  kick` (an eviction needs a victim home; a last version has no later
-  one to wait for), or at the :meth:`~WritebackCommitter.flush` barrier
-  ``detach()``/redistribute/remote sends take.  A drain that somebody
+  kick` (a last version has no later one to wait for), or at the
+  :meth:`~WritebackCommitter.flush` barrier ``detach()``/redistribute/
+  remote sends take.  (An eviction does not wait for it: it writes its
+  victims home itself, a batch at a time, ``HostWriter.writeback_batch``
+  on the thread that needs the room.)  A drain that somebody
   waits for, or of last versions, starts every copy before it collects
   one; the watermark's own drain keeps a round trip a tile, its rate
   being what bounds the bytes of versions still to be superseded.  The
   version guard makes
   a stale commit safe to drop, so the committer never takes the device
   residency lock — commits are pure Data-level operations and cannot
-  deadlock against eviction waits.
+  deadlock against an eviction that holds it.
 
 A committer failure is STICKY: the stored exception re-raises on the
 next ``enqueue`` (failing the task pool through the device layer's
@@ -154,6 +156,18 @@ class StageLane:
                 self._jobs.popleft().done.set()
 
 
+class NoRoom(RuntimeError):
+    """No room under the device's budget for one staging batch, and
+    nothing left to evict: what is resident is pinned."""
+
+
+def out_of_memory(e: BaseException) -> bool:
+    """Whether ``e`` says the device has no memory left — PJRT's
+    ``RESOURCE_EXHAUSTED``, or :class:`NoRoom` under the budget: nothing
+    a slower path could cure."""
+    return isinstance(e, NoRoom) or "RESOURCE_EXHAUSTED" in str(e)
+
+
 def unalias(arr, x, guard, jdev):
     """Rerun a host->device transfer from a throwaway copy when the
     result aliases ``guard`` (shared by :func:`private_device_put` and
@@ -222,7 +236,8 @@ class StageIn:
 
     def batch(self, datas, tally: Optional[List[int]] = None,
               coalesce: bool = True,
-              got: Optional[Dict[int, Any]] = None) -> int:
+              got: Optional[Dict[int, Any]] = None,
+              keep: Optional[List[Data]] = None) -> int:
         """Resident tiles are touched, stale host-side tiles are
         coalesced into ONE ``jax.device_put`` call (one enqueue RPC for
         a wave's transfers instead of one per tile; ``coalesce=False``:
@@ -231,6 +246,15 @@ class StageIn:
         host->device, and in ``got`` each tile's array here by data_id;
         ``tally[0:2]`` count the tiles put and their bytes; the put is
         the ``dev:h2d`` span.
+
+        The room for everything that moves is made ONCE, for the sum of
+        the bytes (one batch of victims, ``Residency.reserve``), with
+        every tile of the batch pinned: neither one that is resident
+        already nor one just accounted makes room for its neighbour.
+        The pins go with the call unless the caller takes them over
+        (``keep``: it unpins once its chunk is committed, or its batch
+        submitted).  No room (everything else is pinned too):
+        :class:`NoRoom`.
 
         The residency lock is held to decide what moves and to make room
         for it, and again to attach what arrived — NOT over the put: the
@@ -244,47 +268,81 @@ class StageIn:
         idx, res, jdev, stats = self.index, self.res, self.jdev, self.stats
         if got is None:
             got = {}
-        puts: List[Tuple[Data, np.ndarray, int]] = []
-        with res.lock:
-            for data in datas:
-                mine = data.get_copy(idx)
-                if mine is not None and getattr(mine, "staged_by", None) is not None:
-                    # a custom-staged PACKED representation must never be
-                    # served as the home layout: drop it and restage from
-                    # the host copy (which :meth:`custom` flushed to
-                    # the same version)
-                    res.drop(data, evicted=False)
-                    mine = None
-                newest = data.newest_copy()
-                if mine is not None and newest is not None \
-                        and mine.version >= newest.version \
-                        and mine.payload is not None:
-                    res.touch(data, dirty=mine.coherency is Coherency.OWNED)
-                    got[data.data_id] = mine.payload
-                    continue
-                if newest is None:
-                    raise RuntimeError(f"{data!r}: no valid copy to stage in")
-                # (re-staging over a stale device copy replaces it: the
-                # accounting charges the delta)
-                if isinstance(newest.payload, jax.Array):
-                    # device-resident arrival (device-capable fabric):
-                    # land it with a direct device_put — device-to-device,
-                    # ICI-class on multi-chip, no host numpy bounce
-                    # (SURVEY §5.8), uncoalesced
-                    res.account(data, newest.payload.nbytes)
-                    arr = got[data.data_id] = jax.device_put(
-                        newest.payload, jdev)
-                    stats["bytes_d2d"] += newest.payload.nbytes
-                    c = data.attach_copy(idx, arr)
-                    c.version = newest.version
-                    res.touch(data, dirty=False)
-                    moved += newest.payload.nbytes
-                    continue
-                host = np.asarray(newest.payload)
-                res.account(data, host.nbytes)
-                puts.append((data, host, newest.version))
-        if not puts:
-            return moved
+        #: (tile, its newest copy's payload, that version)
+        moving: List[Tuple[Data, Any, int]] = []
+        pinned: List[Data] = []
+        try:
+            with res.lock:
+                for data in datas:
+                    mine = data.get_copy(idx)
+                    if mine is not None \
+                            and getattr(mine, "staged_by", None) is not None:
+                        # a custom-staged PACKED representation must never
+                        # be served as the home layout: drop it and restage
+                        # from the host copy (which :meth:`custom` flushed
+                        # to the same version)
+                        res.drop(data, evicted=False)
+                        mine = None
+                    newest = data.newest_copy()
+                    if mine is not None and newest is not None \
+                            and mine.version >= newest.version \
+                            and mine.payload is not None:
+                        res.touch(data,
+                                  dirty=mine.coherency is Coherency.OWNED)
+                        res.pin(data)
+                        pinned.append(data)
+                        got[data.data_id] = mine.payload
+                        continue
+                    if newest is None:
+                        raise RuntimeError(
+                            f"{data!r}: no valid copy to stage in")
+                    payload = newest.payload
+                    if not isinstance(payload, jax.Array):
+                        payload = np.asarray(payload)
+                    moving.append((data, payload, newest.version))
+                need = sum(p.nbytes for (_d, p, _v) in moving)
+                if need and not res.reserve(need):
+                    raise NoRoom(
+                        f"no room on the device for {len(moving)} tiles "
+                        f"({need} bytes) of one staging batch: the budget "
+                        f"is {res.budget} bytes and what is resident is "
+                        "pinned by the chunk in flight")
+                puts: List[Tuple[Data, np.ndarray, int]] = []
+                for data, payload, version in moving:
+                    # (re-staging over a stale device copy replaces it:
+                    # the accounting charges the delta)
+                    res.account(data, payload.nbytes)
+                    res.pin(data)
+                    pinned.append(data)
+                    if isinstance(payload, jax.Array):
+                        # device-resident arrival (device-capable fabric):
+                        # land it with a direct device_put — device-to-
+                        # device, ICI-class on multi-chip, no host numpy
+                        # bounce (SURVEY §5.8), uncoalesced
+                        arr = got[data.data_id] = jax.device_put(
+                            payload, jdev)
+                        stats["bytes_d2d"] += payload.nbytes
+                        c = data.attach_copy(idx, arr)
+                        c.version = version
+                        res.touch(data, dirty=False)
+                        moved += payload.nbytes
+                    else:
+                        puts.append((data, payload, version))
+            if puts:
+                moved += self._put(puts, tally, coalesce, got)
+        except BaseException:
+            res.unpin(pinned)
+            raise
+        if keep is None:
+            res.unpin(pinned)
+        else:
+            keep.extend(pinned)
+        return moved
+
+    def _put(self, puts, tally, coalesce: bool, got) -> int:
+        """The put of :meth:`batch` and the attach of what arrived."""
+        idx, res, jdev, stats = self.index, self.res, self.jdev, self.stats
+        moved = 0
         nbytes = sum(h.nbytes for (_d, h, _v) in puts)
         try:
             with self.span("dev:h2d", tiles=len(puts), bytes=nbytes):
@@ -293,7 +351,9 @@ class StageIn:
                 if coalesce:
                     try:
                         arrs = jax.device_put(hosts, jdev)
-                    except Exception:
+                    except Exception as e:
+                        if out_of_memory(e):
+                            raise  # no slower path has more memory
                         # backend rejected the coalesced put: per tile
                         stats["stage_batch_fallbacks"] += 1
                 # guard: the host copy RETAINS each buffer at version v —
@@ -321,6 +381,7 @@ class StageIn:
                 if data.scratch is not None:
                     stats["scratch_bytes_in"] += host.nbytes
                 moved += host.nbytes
+                res.restaged(data)
                 mine = data.get_copy(idx)
                 if mine is not None and mine.payload is not None \
                         and mine.version >= ver \
@@ -406,15 +467,72 @@ class HostWriter:
     and the custom stage-in's pre-flush."""
 
     def __init__(self, data_index: int, stats, name: str = "",
-                 rank: int = 0):
+                 rank: int = 0, adopt: bool = False):
         """``data_index``: the device's slot in ``Data.copies``;
         ``stats``: the device's counters (``bytes_out``,
         ``scratch_bytes_out``, ``wb_batches``); ``name`` and ``rank``:
-        what the committer's thread and the spans carry."""
+        what the committer's thread and the spans carry; ``adopt``: the
+        device's host values are copies, never views of its memory (any
+        platform but the CPU backend), so a tile comes home through an
+        alias of its array and its host value IS the home tile
+        (:meth:`_alias`)."""
         self.index = data_index
         self.stats = stats
         self.name = name
         self.rank = rank
+        self.adopt = adopt
+        #: write-backs that did NOT go through an alias whose host value
+        #: became the home tile although ``adopt`` is on: each leaves a
+        #: host value cached beside a resident tile, or costs a landing
+        #: copy — the second matrix on the host that an out-of-core solve
+        #: has no room for (0 on a healthy run; warned once)
+        stats.setdefault("wb_alias_fallbacks", 0)
+        self._warned = False
+
+    def _fell_back(self, why: str, *args) -> None:
+        self.stats["wb_alias_fallbacks"] += 1
+        if not self._warned:
+            self._warned = True
+            debug.warning("write-back without an adopted alias (host "
+                          "memory grows by a copy a tile): " + why, *args)
+
+    def _alias(self, payload):
+        """A second ``jax.Array`` over the SAME device buffer, for the
+        copy home to go through.  A ``jax.Array`` keeps the host value
+        of a copy it made for as long as it lives: 16 MiB cached beside
+        every resident 16 MiB tile that went home is a second matrix on
+        the host when the matrix is larger than the chip (``PERF.md``
+        §6, PR 30), and a landing copy of it a third.  The alias dies
+        with the write-back; its host value, which nobody else can
+        reach, is adopted as the home tile without a copy
+        (:meth:`_adopt`).  The payload itself where there is nothing to
+        alias (``adopt`` off; a host array, a test's double, an array a
+        donating task consumed) — and where JAX refuses the alias:
+        counted in ``wb_alias_fallbacks`` and warned once, never
+        silent."""
+        if not self.adopt or not isinstance(payload, jax.Array) \
+                or payload.is_deleted():
+            return payload
+        try:
+            return jax.make_array_from_single_device_arrays(
+                payload.shape, payload.sharding, [payload])
+        except Exception as e:
+            if not payload.is_deleted():  # (consumed meanwhile: no copy)
+                self._fell_back("no alias of %r: %r", payload.shape, e)
+            return payload
+
+    def _adopt(self, host: np.ndarray) -> np.ndarray:
+        """The host value of an alias that died with its write-back,
+        made writable: it becomes the home tile as it is.  Where numpy
+        refuses — the value is a view of memory that is not its own
+        after all — :meth:`commit` copies it, and the fallback is
+        counted."""
+        try:
+            host.flags.writeable = True
+        except ValueError:
+            self._fell_back("the host value of a %r alias is a view",
+                            host.shape)
+        return host
 
     def snapshot(self, data):
         """Version-guarded snapshot of a dirty device copy: returns
@@ -476,13 +594,19 @@ class HostWriter:
         behind the program that writes the tile): for the thread that
         just committed a version it knows to be the tile's LAST — there
         is no later one the committer's dedup could save the bytes of.
-        Returns the version the copy was started for (None: nothing to
-        take home, or nothing to start); counted in ``wb_started_early``."""
+        Returns ``(version, array)``: the version the copy was started
+        for and the array it was started on (:meth:`_alias`), which the
+        drain collects if that version still stands (None: nothing to
+        take home, or nothing to start); counted in
+        ``wb_started_early``."""
         snap = self.snapshot(data)
-        if snap is None or not _start_copy(snap[0]):
+        if snap is None:
+            return None
+        arr = self._alias(snap[0])
+        if not _start_copy(arr):
             return None
         self.stats["wb_started_early"] += 1
-        return snap[1]
+        return snap[1], arr
 
     def d2h_batch(self, payloads: List[Any]) -> List[Optional[np.ndarray]]:
         """Collect a batch's device->host copies: the last one first —
@@ -512,9 +636,11 @@ class HostWriter:
         its snapshot/commit halves."""
         snap = self.snapshot(data)
         if snap is not None:
-            host = self.d2h_batch([snap[0]])[0]
+            arr = self._alias(snap[0])
+            host = self.d2h_batch([arr])[0]
             if host is not None:  # None: consumed by a donating task
-                self.commit(data, snap[1], host)
+                self.commit(data, snap[1],
+                            host if arr is snap[0] else self._adopt(host))
 
     def writeback_batch(self, datas, pool: int = 0, batch: int = 0,
                         tickets=(), early=(),
@@ -527,7 +653,7 @@ class HostWriter:
         :meth:`d2h_batch`) and ``early`` (tiles whose copy :meth:`start`
         had started for the very version collected here:
         ``wb_early_hits``).  ``tickets``: per tile, the hb tickets of
-        the enqueues that fed it; ``early``: per tile, the version
+        the enqueues that fed it; ``early``: per tile, what
         :meth:`start` returned at hand-over (both the committer's).
         ``ahead=False`` starts nothing: the committer's watermark drain
         of versions that a later task may supersede keeps a round trip a
@@ -537,6 +663,9 @@ class HostWriter:
         ``PERF.md`` §6, PR 29).  Returns ``(tiles committed, tiles
         got)``: the others were stale, or consumed by a donating task."""
         snaps = []
+        #: what each copy goes through: the array a copy was started on
+        #: at hand-over, an alias of the payload, or the payload
+        arrays = []
         joined: List[int] = []
         hits = 0
         for k, d in enumerate(datas):
@@ -545,8 +674,11 @@ class HostWriter:
                 snaps.append((d, s[0], s[1]))
                 if tickets:
                     joined.extend(tickets[k])
-                if early and early[k] == s[1]:
+                if early and early[k] is not None and early[k][0] == s[1]:
                     hits += 1
+                    arrays.append(early[k][1])
+                else:
+                    arrays.append(self._alias(s[0]))
         if not snaps:
             return 0, 0
         self.stats["wb_early_hits"] += hits
@@ -555,17 +687,20 @@ class HostWriter:
                        id=span_id(), tiles=len(snaps), batch=batch,
                        bytes=sum(int(getattr(p, "nbytes", 0))
                                  for (_d, p, _v) in snaps)) as sp:
-            payloads = [p for (_d, p, _v) in snaps]
             t0 = time.perf_counter_ns()
             if ahead:
-                for p in payloads:
-                    _start_copy(p)
-            hosts = self.d2h_batch(payloads)
+                for a in arrays:
+                    _start_copy(a)
+            hosts = self.d2h_batch(arrays)
             sp.note(wait_us=(time.perf_counter_ns() - t0) // 1000,
                     early=hits)
-            for (data, _p, version), host in zip(snaps, hosts):
+            for (data, p, version), a, host in zip(snaps, arrays, hosts):
                 # host is None: a donating task consumed that version
-                if host is not None and self.commit(data, version, host):
+                if host is None:
+                    continue
+                if a is not p:
+                    host = self._adopt(host)
+                if self.commit(data, version, host):
                     committed += 1
             if joined and pins.active(pins.HB_WB_COMMIT):
                 # acquire edge: the committer joins every enqueue that
@@ -577,20 +712,21 @@ class HostWriter:
 class WritebackCommitter:
     """Background committer for version-guarded deferred write-backs.
 
-    ``enqueue`` is called by the device epilog (and eviction) with the
-    Data whose device copy is dirty; entries deduplicate per tile and
+    ``enqueue`` is called by the device epilog with the Data whose
+    device copy is dirty; entries deduplicate per tile and
     the committer snapshots the NEWEST device version at commit time,
     so a tile re-dirtied while pending commits once.  Draining is
     watermark-driven — batched D2H gets once ``runtime_wb_window_mb``
-    of dirty bytes are pending — plus on :meth:`kick` (eviction wants a
-    victim home NOW) and at the :meth:`flush` barrier."""
+    of dirty bytes are pending — plus on :meth:`kick` (last versions)
+    and at the :meth:`flush` barrier."""
 
     def __init__(self, writer: HostWriter):
         self._writer = writer
         self._cv = threading.Condition()
-        #: data_id -> (Data, [hb tickets], nbytes at enqueue, the version
-        #: whose copy home was started at enqueue or None)
-        self._pending: "collections.OrderedDict[int, Tuple[Any, List[int], int, Optional[int]]]" = \
+        #: data_id -> (Data, [hb tickets], nbytes at enqueue, what
+        #: ``HostWriter.start`` returned at enqueue: None, or the version
+        #: whose copy home was started and the array it was started on)
+        self._pending: "collections.OrderedDict[int, Tuple[Any, List[int], int, Optional[Tuple[int, Any]]]]" = \
             collections.OrderedDict()
         self._inflight: Dict[int, Any] = {}
         self._pending_bytes = 0
@@ -598,7 +734,7 @@ class WritebackCommitter:
             "runtime", "wb_window_mb", 32,
             help="deferred write-back watermark (MB): the committer "
                  "drains batched D2H gets once this many dirty bytes "
-                 "are pending (flush/eviction drain sooner)"))) << 20
+                 "are pending (a flush or a last version drains sooner)"))) << 20
         self._batch = max(1, int(mca_param.register(
             "runtime", "wb_batch", 32,
             help="max tiles per committer drain batch (its D2H copies "
@@ -637,8 +773,9 @@ class WritebackCommitter:
         superseded nothing is started before its drain: an early copy
         would move bytes that the dedup saves.
         Deduplicated per tile; bounded by a capacity wait at 4x the
-        drain watermark so a stalled committer applies backpressure
-        instead of accumulating unbounded dirty state.  Raises the
+        drain watermark (or the bytes of this one hand-over, if more) so
+        a stalled committer applies backpressure instead of
+        accumulating unbounded dirty state.  Raises the
         stored committer error if the committer died — the caller's
         fail-loudly discipline turns that into a pool failure.
         Returns one ticket a tile."""
@@ -658,7 +795,11 @@ class WritebackCommitter:
             entries.append((data, ticket, c.nbytes if c is not None else 0,
                             self._writer.start(data) if last else None))
             tickets.append(ticket)
-        cap = 4 * self._window
+        # the capacity: 4x the watermark, or what this one hand-over
+        # brings if that is more (a chunk's last versions of 16 MiB
+        # tiles are 256 MiB against 128): the wait is for what was
+        # pending BEFORE, never for room this very call fills
+        cap = max(4 * self._window, sum(e[2] for e in entries))
         with self._cv:
             self._raise_if_dead()
             for data, ticket, nb, early in entries:
@@ -689,18 +830,18 @@ class WritebackCommitter:
                 from self.error
 
     def kick(self) -> None:
-        """Ask the committer to drain below-watermark pending entries
-        (eviction pressure: a victim must be home before its device
-        copy drops)."""
+        """Ask the committer to drain below-watermark pending entries."""
         with self._cv:
             self._kick = True
             self._cv.notify_all()
 
     def wait_for(self, data_id: int, timeout: float = 60.0) -> bool:
-        """Block until ``data_id`` is neither pending nor in flight.
-        Returns False on committer death or timeout — the caller falls
-        back to a synchronous write-back (the version guard makes the
-        duplicate safe)."""
+        """Block until ``data_id`` is neither pending nor in flight
+        (kicking the committer first).  Returns False on committer death
+        or timeout — the caller falls back to a synchronous write-back
+        (the version guard makes the duplicate safe).  For whoever
+        needs ONE tile home without a whole flush (an eviction writes
+        its batch of victims home itself)."""
         deadline = time.monotonic() + timeout
         with self._cv:
             self._kick = True

@@ -71,8 +71,8 @@ from ..data.data import Coherency, Data
 from . import scratch
 from .device import Device
 from .residency import Residency, native_zone
-from .staging import (HostWriter, StageIn, WritebackCommitter, span_id,
-                      stage_depth_param)
+from .staging import (HostWriter, StageIn, WritebackCommitter,
+                      out_of_memory, span_id, stage_depth_param)
 from .value_args import (ABSENT, HOOKED, LATE, PLACEHOLDER, READ, SCRATCH,
                          VALUE, FlowPlan, ValuePlan)
 
@@ -268,10 +268,15 @@ class TpuDevice(Device):
         #: tiles with their accounting (device/residency.py); the lock
         #: order is residency.py's: _lock -> _res.lock -> Data.lock
         self._wb = HostWriter(self.data_index, self.stats, self.name,
-                              self._rank)
+                              self._rank,
+                              adopt=self.jdev.platform != "cpu")
         self._res = Residency(self.data_index, budget, self.stats,
                               self._writeback_evict,
-                              zone=native_zone(self.jdev.platform))
+                              zone=native_zone(self.jdev.platform),
+                              span=self._span)
+        #: pump batch number -> the tiles the transfer lane staged (and
+        #: pinned) ahead of it: unpinned once the batch is submitted
+        self._prestaged: Dict[int, List[Data]] = {}
         self._h2d = StageIn(self._res, self._wb, self.jdev, self.stats,
                             self._span)
         #: pipeline depth (runtime_stage_depth): 1 = synchronous
@@ -288,9 +293,6 @@ class TpuDevice(Device):
                  "staged before the pump re-slices it across the "
                  "prefetch window (intra-wave double buffering)"))) << 10
         self._committer = None
-        #: eviction's bounded wait for an async victim commit before the
-        #: synchronous fallback (a capacity wait, not a hang)
-        self._wb_wait = 60.0
 
     def _span(self, name: str, **info):
         """A ``pins.span`` of this module, with the ``pool`` and ``rank``
@@ -414,6 +416,8 @@ class TpuDevice(Device):
                     # (staging/trace/enqueue — no task side effects
                     # yet); per-task epilog/completion errors are
                     # contained inside it with a loud pool fail
+                    if self._no_memory(group[0], e):
+                        continue
                     self.stats["wave_fallbacks"] += 1
                     debug.warning(
                         "wave submit of %d tasks failed (%s); "
@@ -441,7 +445,12 @@ class TpuDevice(Device):
             self._span_pool = _pool_of(tasks[0])
         self._span_batch = batch_no
         with self._span("dev:submit_batch", batch=batch_no, n=len(tasks)):
-            self._submit_units(self._units_of(tasks), es, False)
+            try:
+                self._submit_units(self._units_of(tasks), es, False)
+            finally:
+                ahead = self._prestaged.pop(batch_no, None)
+                if ahead:
+                    self._res.unpin(ahead)
             # a transient-submit retry re-queues through ``_pending``
             # (the manager loop's channel); there is no manager in pump
             # mode, so drain retries here before handing the batch back
@@ -542,6 +551,9 @@ class TpuDevice(Device):
                             batch=self._span_batch, waited_us=waited) as sp:
                 self._submit(task, es, complete=complete, span=sp)
         except Exception as e:
+            if not getattr(task, "_tpu_completed", False) \
+                    and self._no_memory(task, e):
+                return
             debug.error("tpu submit of %r failed: %s", task, e)
             import traceback
 
@@ -581,6 +593,19 @@ class TpuDevice(Device):
             # unreleased.
             self._fail_task_pool(
                 task, f"device submit failed after retry: {e!r}")
+
+    def _no_memory(self, task: Task, e: BaseException) -> bool:
+        """A submit failed for want of device memory — PJRT's
+        ``RESOURCE_EXHAUSTED``, or a staging batch for which no room
+        could be made under the budget (``staging.NoRoom``): the pool
+        fails here and now.
+        Neither a per-task fallback nor a retry has more memory, and a
+        solve that limps on past its budget is a different result."""
+        if not out_of_memory(e):
+            return False
+        debug.error("device out of memory submitting %r: %s", task, e)
+        self._fail_task_pool(task, f"device out of memory: {e!r}")
+        return True
 
     def _fail_task_pool(self, task: Task, why: str) -> None:
         """Device execution failed unrecoverably: fail the task's pool so
@@ -700,7 +725,11 @@ class TpuDevice(Device):
         Inputs are staged PER CHUNK, immediately before that chunk's
         dispatch: peak HBM holds one chunk's inputs plus its in-flight
         outputs, never the whole wave's — a large wave of large tiles
-        must not OOM where per-task dispatch would not.
+        must not OOM where per-task dispatch would not.  A chunk is
+        bounded by BYTES as well as by the power of two: the tiles its
+        tasks read and write (``FlowPlan.nbytes`` a task) stay under
+        ``Residency.chunk_limit``, a share of the budget — 64 gemm tasks
+        over 16 MiB tiles are 4 GiB — down to a chunk of one task.
 
         Failure containment is a PER-CHUNK invariant: a chunk's
         staging/trace/enqueue errors RAISE before any task of THAT chunk
@@ -733,8 +762,11 @@ class TpuDevice(Device):
         base_key = getattr(body, "_jit_key", None) or body
         start = 0
         remaining = len(tasks)
+        most = max(1, self._res.chunk_limit // max(1, plan.nbytes))
+        most = 1 << (most.bit_length() - 1)
         while remaining:
-            cnt = 1 << (remaining.bit_length() - 1)  # largest pow2 chunk
+            # the largest power of two the tasks left and the bytes allow
+            cnt = min(1 << (remaining.bit_length() - 1), most)
             grp = tasks[start:start + cnt]
             start += cnt
             remaining -= cnt
@@ -753,7 +785,20 @@ class TpuDevice(Device):
         the program's :class:`ValuePlan` says."""
         cnt = len(grp)
         cls = grp[0].task_class.name
-        staged = self._stage_span(grp, fplan)
+        pinned: List[Data] = []
+        try:
+            self._run_chunk(grp, cnt, cls, body, base_key, fplan, es,
+                            complete, wave_span, pinned)
+        finally:
+            self._res.unpin(pinned)
+
+    def _run_chunk(self, grp: List[Task], cnt: int, cls: str, body,
+                   base_key, fplan: FlowPlan, es, complete: bool, wave_span,
+                   pinned: List[Data]) -> None:
+        """:meth:`_submit_chunk` between the pins: ``pinned`` takes the
+        chunk's tiles as they are staged, the caller lets go of them
+        once the chunk is committed."""
+        staged = self._stage_span(grp, fplan, pinned)
         args0, nout = staged[0][1], fplan.nout
 
         def build():
@@ -792,12 +837,13 @@ class TpuDevice(Device):
         self.stats["wave_tasks"] = self.stats.get("wave_tasks", 0) + cnt
         self._finish(staged, outs, nout, es, complete)
 
-    def _stage_span(self, grp: List[Task], fplan: FlowPlan) -> List[_Staged]:
+    def _stage_span(self, grp: List[Task], fplan: FlowPlan,
+                    pinned: List[Data]) -> List[_Staged]:
         """:meth:`_stage_chunk` under its ``dev:stage_args`` span."""
         with self._span("dev:stage_args") as sp:
             # host tiles, their bytes, tiles staged, residency hits
             tally = [0, 0, 0, 0]
-            staged = self._stage_chunk(grp, fplan, tally)
+            staged = self._stage_chunk(grp, fplan, tally, pinned)
             sp.note(host_tiles=tally[0], bytes=tally[1], tiles=tally[2],
                     hits=tally[3])
         return staged
@@ -823,7 +869,7 @@ class TpuDevice(Device):
                 one[0]._tpu_completed = True  # the queue's from here on
 
     def _stage_chunk(self, grp: List[Task], fplan: FlowPlan,
-                     tally: List[int]) -> List[_Staged]:
+                     tally: List[int], pinned: List[Data]) -> List[_Staged]:
         """kernel_push (reference device_gpu.c:2015-2164 stage-in
         phase) — THE staging walk: for the tasks of one chunk of a wave,
         or for one task that goes out alone, in ONE pass under ONE hold
@@ -834,7 +880,12 @@ class TpuDevice(Device):
         by tile in the synchronous regime); a flow with a custom
         ``stage_in`` hook gets the hook's result; ownership moves only
         once every tile of the chunk is resident, so an error in here
-        raises with no task of the chunk touched.  ``tally`` counts for
+        raises with no task of the chunk touched.  Every tile of the
+        chunk is PINNED as it is found or staged (``pinned`` takes them:
+        the caller unpins once the chunk is committed): room for one is
+        never made at the expense of another, and a chunk whose tiles do
+        not fit the budget together raises (``StageIn.batch``) instead
+        of running past it.  ``tally`` counts for
         the ``dev:stage_args`` span: ``[tiles copied from the host,
         their bytes, tiles staged, of them found resident]``."""
         idx = self.data_index
@@ -877,6 +928,8 @@ class TpuDevice(Device):
                                 res.touch(
                                     data,
                                     dirty=c.coherency is Coherency.OWNED)
+                                res.pin(data)
+                                pinned.append(data)
                             else:
                                 slot = missing.get(did)
                                 if slot is None:
@@ -911,15 +964,11 @@ class TpuDevice(Device):
                 task._tpu_scratch = mine
             if missing:
                 tiles = [slot[0] for slot in missing.values()]
-                # (the arrays as they arrive, not read back from the
-                # tiles: under a budget smaller than the chunk the room
-                # for one tile is made at the expense of its neighbour)
-                if self.stage_depth > 1:
-                    # the chunk's host->device transfers as one batched put
-                    self._h2d.batch(tiles, tally, got=found)
-                else:
-                    for data in tiles:
-                        found[data.data_id] = self._h2d.one(data, tally)
+                # the chunk's host->device transfers as one batched put
+                # (a put a tile in the synchronous regime), the room for
+                # all of them made at once
+                self._h2d.batch(tiles, tally, got=found, keep=pinned,
+                                coalesce=self.stage_depth > 1)
                 for did, slot in missing.items():
                     for args, at in slot[1:]:
                         args[at] = found[did]
@@ -940,7 +989,16 @@ class TpuDevice(Device):
             # DTD/PTG store the raw device body on the chore at build time
             raise RuntimeError(f"chore of {task!r} has no body_fn for device execution")
         body, fplan = task.selected_chore.body_fn, sig[1]
-        staged = self._stage_span([task], fplan)
+        pinned: List[Data] = []
+        try:
+            self._run_one(task, body, fplan, es, complete, span, pinned)
+        finally:
+            self._res.unpin(pinned)
+
+    def _run_one(self, task: Task, body, fplan: FlowPlan, es,
+                 complete: bool, span, pinned: List[Data]) -> None:
+        """:meth:`_submit` between the pins (as :meth:`_run_chunk`)."""
+        staged = self._stage_span([task], fplan, pinned)
         dev_args = staged[0][1]
 
         base_key = getattr(body, "_jit_key", body)
@@ -1101,7 +1159,22 @@ class TpuDevice(Device):
                         tiles=len(tiles), batch=batch_no) as sp:
             # a batch with nothing to move: the span, for whoever reads
             # the lane by batch, and neither the lock nor a walk
-            sp.note(bytes=self._h2d.batch(tiles) if tiles else 0)
+            moved = 0
+            if tiles:
+                # what the lane stages stays pinned until its batch is
+                # submitted, so it stages no more than a quarter of the
+                # budget ahead (the submit path stages the rest, a chunk
+                # at a time)
+                room, ahead = self._res.budget // 4, []
+                for k, data in enumerate(tiles):
+                    room -= int(getattr(data.newest_copy().payload,
+                                        "nbytes", 0))
+                    if room < 0:
+                        tiles = tiles[:k]
+                        break
+                moved = self._h2d.batch(tiles, keep=ahead)
+                self._prestaged[batch_no] = ahead
+            sp.note(bytes=moved)
         if not tiles:
             return
         self.stats["prefetched_tiles"] = \
@@ -1133,31 +1206,22 @@ class TpuDevice(Device):
             with self._span("dev:flush"):
                 com.flush(timeout=timeout)
 
-    def _writeback_evict(self, victim: Data) -> None:
-        """Eviction write-back, routed through the async committer when
-        the pipeline is on (a synchronous get here would block the whole
-        staging path).  The wait is a CAPACITY wait, bounded: the
-        victim's bytes must exist at home before its device copy drops,
-        so a wedged or failed committer falls back to the synchronous
-        path — data safety first, the version guard makes the duplicate
-        a no-op."""
-        com = self._committer
-        if com is not None and com.healthy:
-            try:
-                com.enqueue(victim, self._span_pool, self._span_batch)
-            except Exception:
-                # committer died between the check and the enqueue: the
-                # sync fallback still flushes the victim; the sticky
-                # error surfaces at the next epilog enqueue/flush
-                self._wb.writeback(victim)
-                return
-            if com.wait_for(victim.data_id, timeout=self._wb_wait):
-                return
-            debug.warning(
-                "async write-back of eviction victim %r did not land in "
-                "%.0fs; falling back to a synchronous flush",
-                victim, self._wb_wait)
-        self._wb.writeback(victim)
+    def _writeback_evict(self, victims: List[Data]) -> int:
+        """Eviction's write-back (``Residency``'s callable): the victims
+        whose copy here is the only valid one, as ONE batch on the
+        thread that needs the room — every copy home started before one
+        is waited for, one wait, guarded commits
+        (``HostWriter.writeback_batch``, its ``dev:writeback`` span
+        inside the eviction's ``dev:evict``).  Not through the committer:
+        it would take the same copies one drain at a time while this
+        thread waited for each tile in turn, and the version guard makes
+        a copy the committer also holds (a last version on its way) land
+        once whoever is first.  The caller holds the residency lock: the
+        victims must be home before their device copies drop, and nobody
+        may rewrite one in between.  Returns the microseconds waited."""
+        t0 = time.perf_counter_ns()
+        self._wb.writeback_batch(victims, self._span_pool, self._span_batch)
+        return (time.perf_counter_ns() - t0) // 1000
 
     # ------------------------------------------------------------------
     # completion / stage_out / epilog

@@ -100,7 +100,7 @@ class FlowPlan:
     the walk stages the tile itself.  ``out_hooks`` holds one ``stage_out`` hook (or None)
     per output, or is None when no output has one."""
 
-    __slots__ = ("steps", "nout", "reads", "out_hooks")
+    __slots__ = ("steps", "nout", "reads", "out_hooks", "nbytes")
 
     def __init__(self, flows: Sequence[Any]):
         """``flows``: the signature without its body key.  A tile is
@@ -110,6 +110,7 @@ class FlowPlan:
         None where nothing says it."""
         steps: List[Tuple[int, int, int, Any]] = []
         out_hooks: List[Any] = []
+        nbytes = 0
         for pos, f in enumerate(flows):
             if f is None:
                 steps.append((ABSENT, pos, 0, None))
@@ -139,6 +140,10 @@ class FlowPlan:
                     how, extra = PLACEHOLDER, jax.ShapeDtypeStruct(
                         shape, np.dtype(dtype))
                 steps.append((how, pos, access, extra))
+                if shape is not None and dtype is not None:
+                    tile = int(np.prod(shape)) * np.dtype(dtype).itemsize
+                    # a tile read takes a buffer, a tile written a new one
+                    nbytes += tile * ((how == READ) + bool(access & _OUT))
                 if access & _OUT:
                     out_hooks.append(so)
         self.steps = tuple(steps)
@@ -148,6 +153,9 @@ class FlowPlan:
         #: (a hooked flow's packed layout is the hook's business)
         self.reads = tuple(s[1] for s in steps if s[0] == READ)
         self.out_hooks = tuple(out_hooks) if any(out_hooks) else None
+        #: device bytes one task's tiles take, read and written (where
+        #: the signature says their shapes): what bounds a wave's chunk
+        self.nbytes = nbytes
 
 
 class ValuePlan:

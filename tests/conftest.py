@@ -10,6 +10,7 @@ strategy of testing "multi-node" as multi-process on one node,
 import atexit
 import os
 import shutil
+import sys
 import tempfile
 
 os.environ["JAX_PLATFORMS"] = "cpu"
@@ -38,6 +39,16 @@ jax.config.update("jax_num_cpu_devices", 8)
 jax.config.update("jax_compilation_cache_dir", _cache_tmp)
 
 import pytest  # noqa: E402
+
+# the benchmark's rehearsal tests look every cell's tiny traffic up by its
+# driver (``tests/benchmark_harness/bench_testlib.TINY``); a driver added
+# since that table was written registers its own.  Here and not in that
+# directory's conftest: a PR may add benchmark files and edit none
+sys.path.insert(0, os.path.join(os.path.dirname(os.path.abspath(__file__)),
+                                "benchmark_harness"))
+import bench_testlib  # noqa: E402
+
+bench_testlib.TINY.setdefault("pump_ooc", "tiny_pump_ooc")
 
 
 def pytest_configure(config):

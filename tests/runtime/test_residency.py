@@ -30,10 +30,13 @@ def make(request):
     def make(room):
         victims = []
 
-        def writeback(data):
-            mine = data.get_copy(DEV)
-            victims.append((data.key, mine.version))
-            data.attach_copy(0, np.array(mine.payload)).version = mine.version
+        def writeback(batch):
+            for data in batch:
+                mine = data.get_copy(DEV)
+                victims.append((data.key, mine.version))
+                data.attach_copy(0, np.array(mine.payload)).version = \
+                    mine.version
+            return 0  # microseconds waited
 
         res = Residency(DEV, room * TILE, collections.Counter(), writeback,
                         zone=zone)

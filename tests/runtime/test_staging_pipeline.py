@@ -17,8 +17,8 @@ on:
   host copy already carries the version) and numerics stay exact;
 * a committer death surfaces as a POOL failure through the epilog
   enqueue, not a hang;
-* LRU eviction routes its write-back through the committer
-  (``runtime_stage_depth`` >= 2) and data survives budget pressure;
+* LRU eviction writes its victims home itself, a batch at a time, and
+  data survives budget pressure;
 * the dynamic runtime's tile digests are bit-identical with the
   pipeline on vs off.
 """
@@ -368,13 +368,15 @@ def test_committer_death_fails_pool_not_hang():
 
 
 # ---------------------------------------------------------------------------
-# eviction routes through the committer
+# eviction writes its victims home itself, in batches
 # ---------------------------------------------------------------------------
 
-def test_eviction_writeback_routes_through_committer(ctx):
-    """Under budget pressure the LRU victim's dirty copy is committed by
-    the async committer (kick + wait), not the blocking per-tile get —
-    and every tile's data survives eviction."""
+def test_eviction_writes_its_victims_home_a_batch_at_a_time(ctx):
+    """Under budget pressure the victims whose only valid copy is on the
+    device go home as ONE batch on the thread that needs the room (PR
+    30: not a committer round trip a tile under the residency lock) —
+    every tile's data survives eviction, and what the committer still
+    holds of them drops as stale."""
     dev = tpu_dev(ctx)
     com = dev._wb_committer()
     assert com is not None, "stage_depth default engages the committer"
@@ -385,8 +387,13 @@ def test_eviction_writeback_routes_through_committer(ctx):
     for t in tiles:
         tp.insert_task({DEV_TPU: lambda x: x + 0.0}, (t, INOUT))
     assert tp.wait(timeout=120)
-    assert dev.stats["evictions"] > 0
-    assert com.drained() > 0, "eviction write-backs bypassed the committer"
+    s = dev.stats
+    assert s["evictions"] > 0 and s["evict_batches"] > 0
+    assert s["evict_dirty"] > 0
+    assert s["evict_bytes_home"] == s["evict_dirty"] * 1024 * 8
+    assert s["evict_clean"] + s["evict_dirty"] == s["evictions"]
+    dev.flush()
+    assert com.healthy
     from parsec_tpu.dsl.dtd import stage_to_cpu
 
     for i, t in enumerate(tiles):

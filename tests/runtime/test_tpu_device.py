@@ -412,10 +412,10 @@ def test_wave_staging_is_per_chunk(ctx):
 
     orig_stage = dev._stage_chunk
 
-    def recording_stage(grp, fplan, tally):
+    def recording_stage(grp, *rest):
         # the chunk's ONE residency pass: one event a task it stages
         events.extend(("stage", id(task)) for task in grp)
-        return orig_stage(grp, fplan, tally)
+        return orig_stage(grp, *rest)
 
     dev._stage_chunk = recording_stage
 
