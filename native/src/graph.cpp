@@ -551,6 +551,46 @@ void pz_graph_task_commit(void* gp, int64_t id) {
         push_ready(g, t->priority, t->tenant, id, -1);  // inserter: global
 }
 
+// Bulk declaration (the attach plan's bind, dsl/native_exec.py): ``n``
+// tasks with priorities ``prio[i]``, user tags ``i`` and one tenant,
+// then ``nedges`` edges whose ids count from the first of these tasks;
+// returns that first id.  The same state n pz_graph_add_task and nedges
+// pz_graph_add_dep calls leave, in one crossing of the ctypes boundary.
+// Nothing is committed: pz_graph_commit_range arms the tasks once the
+// ready queue is configured.  -1 on an edge that leaves [0, n).
+int64_t pz_graph_add_bulk(void* gp, int64_t n, const int32_t* prio,
+                          int32_t tenant, int64_t nedges,
+                          const int64_t* pred, const int64_t* succ) {
+    Graph* g = static_cast<Graph*>(gp);
+    for (int64_t e = 0; e < nedges; ++e)
+        if (pred[e] < 0 || succ[e] < 0 || pred[e] >= n || succ[e] >= n)
+            return -1;
+    std::lock_guard<std::mutex> lk(g->graph_mu);
+    const int64_t base = static_cast<int64_t>(g->tasks.size());
+    g->tasks.reserve(g->tasks.size() + static_cast<size_t>(n));
+    for (int64_t i = 0; i < n; ++i) {
+        Task* t = new Task();
+        t->priority = prio[i];
+        t->user_tag = i;
+        t->tenant = tenant < 0 ? 0 : tenant;
+        t->missing.store(1, std::memory_order_relaxed);  // commit token
+        g->tasks.push_back(t);
+    }
+    g->n_inserted.fetch_add(n, std::memory_order_acq_rel);
+    for (int64_t e = 0; e < nedges; ++e) {
+        g->tasks[base + succ[e]]->missing.fetch_add(
+            1, std::memory_order_acq_rel);
+        g->tasks[base + pred[e]]->succs.push_back(base + succ[e]);
+    }
+    return base;
+}
+
+// Commit tasks [first, first + n) in id order (see pz_graph_task_commit).
+void pz_graph_commit_range(void* gp, int64_t first, int64_t n) {
+    for (int64_t id = first; id < first + n; ++id)
+        pz_graph_task_commit(gp, id);
+}
+
 // Reset a QUIESCED graph for re-execution over the same structure: every
 // task returns to uncommitted (missing = commit token + in-degree), the
 // caller then re-commits exactly as after construction (local tasks by

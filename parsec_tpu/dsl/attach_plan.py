@@ -1,0 +1,489 @@
+"""The attach plan: what ``NativeExecutor(native_device=True)`` derives of
+a taskpool that does not depend on its tiles, computed once per *shape*.
+
+The reference pays none of this at run time: a PTG is compiled ahead of
+time and ``parsec_dpotrf_New`` enumerates nothing.  Here the DAG is
+captured by evaluating every dependency expression
+(:func:`parsec_tpu.dsl.graph.capture`) and every flow is resolved to the
+tile behind it; a loop over factorizations of one shape used to pay that
+at the head of every solve.  An :class:`AttachPlan` keeps the answers in
+native-id order and in flat tuples and arrays; a later executor over a
+taskpool of the same shape only *binds* it (``NativeExecutor._bind``):
+one ``data_of`` a distinct tile, one ``scratch.new`` a ``NEW`` chain, one
+task object a task, one bulk call into the native graph.
+
+A plan holds **no** taskpool, collection, ``Data``, array, device or
+executor: a plan that kept the previous solve's collection alive would
+keep its host tiles alive.
+
+:func:`plan_key` is what a captured graph is a function of, as far as the
+program can vouch for it: the PTG's source text, every constant by value,
+every collection by what placement reads of it, the captured ranks and
+the fusion configuration.  Whatever it cannot vouch for makes the pool
+*uncacheable*: captured, resolved and bound as any first solve, stored
+nowhere.  Plans live in a small LRU (:data:`PLAN_CACHE_SIZE`); nothing
+switches this off, behaviour depends only on whether the key was seen.
+"""
+
+from __future__ import annotations
+
+import ast
+import collections
+import ctypes
+import functools
+import sys
+import threading
+from typing import Any, Dict, Iterable, List, Optional, Tuple
+
+import numpy as np
+
+from ..core.lifecycle import AccessMode, DEV_CPU
+from .graph import TaskGraph, source_tile
+from .ptg import (CTL, PTG, _ArgExpr, _DataRef, _SAFE_BUILTINS, _TaskRef,
+                  _c_to_py)
+
+TaskId = Tuple[str, Tuple]
+
+#: plans kept (least recently bound goes first).  The out-of-core DAG
+#: (15,180 tasks, 42,570 edges) is 11.5 MB of small tuples (PERF.md §6)
+PLAN_CACHE_SIZE = 8
+
+#: ``AttachPlan.tasks[i][3]``: a flow with no tile behind it
+NO_DATA = -1
+#: ... and a CTL flow (a placeholder in ``body_args``)
+CTL_FLOW = -2
+
+
+class Uncacheable(Exception):
+    """The fingerprint cannot vouch for this taskpool; ``args[0]`` says
+    for what."""
+
+
+class AttachPlan:
+    """One shape's resolved DAG.  Positions count tasks in capture
+    order; a tile *slot* is an index into :attr:`tiles`; a native node is
+    a task or a fused region, numbered from 0 (the bind adds the id the
+    native graph gave the first)."""
+
+    __slots__ = (
+        "key", "ptg_name", "classes", "device_class", "has_cpu_bodies",
+        "nodes", "tasks", "succs", "tiles", "tile_users",
+        "node_of", "native", "native_prio", "edge_pred", "edge_succ",
+        "roots", "fused")
+
+    def __init__(self):
+        #: the fingerprint it is stored under; None = bound, never stored
+        self.key: Optional[Tuple] = None
+        self.ptg_name = ""
+        #: class names, by the class index the task rows carry
+        self.classes: Tuple[str, ...] = ()
+        #: per class index: the class has an accelerator BODY
+        self.device_class: Tuple[bool, ...] = ()
+        self.has_cpu_bodies = False
+        #: task id ``(class name, locals)`` -> position
+        self.nodes: Dict[TaskId, int] = {}
+        #: per task ``(class index, locals, priority, flow slots, value
+        #: specs, home positions, write-backs)``: one slot (or
+        #: :data:`NO_DATA` / :data:`CTL_FLOW`) per declared flow, the
+        #: ready-made ``("value", v, VALUE)`` specs of params then defs,
+        #: ``_tpu_home``, and ``(source slot, home slot)`` pairs
+        self.tasks: Tuple[Tuple, ...] = ()
+        #: per task its successors' positions, one per captured edge
+        #: (what ``_emit_trace_edges`` publishes)
+        self.succs: Tuple[Tuple[int, ...], ...] = ()
+        #: per slot ``("data", collection name, key)`` or ``("new",
+        #: producer tid, flow name)``: ``source_tile``'s answers
+        self.tiles: Tuple[Tuple, ...] = ()
+        #: per slot the users a scratch tile is born with (0: has a home)
+        self.tile_users: Tuple[int, ...] = ()
+        #: per task its native node
+        self.node_of: Tuple[int, ...] = ()
+        #: per native node the task's position, or ``~i`` for
+        #: ``fused[i]``
+        self.native: Tuple[int, ...] = ()
+        self.native_prio = (ctypes.c_int32 * 0)()
+        #: the de-duplicated native edges, in declaration order
+        self.edge_pred = (ctypes.c_int64 * 0)()
+        self.edge_succ = (ctypes.c_int64 * 0)()
+        self.roots: Tuple[int, ...] = ()
+        #: per fused region ``(FusedPlan, slot per program argument,
+        #: member positions, write-backs)``
+        self.fused: Tuple[Tuple, ...] = ()
+
+    def nbytes(self) -> int:
+        """Bytes the plan keeps (containers and the small values in
+        them; shared objects once)."""
+        seen = set()
+
+        def size(o) -> int:
+            if id(o) in seen or isinstance(o, (type, AccessMode)):
+                return 0
+            seen.add(id(o))
+            n = sys.getsizeof(o)
+            if isinstance(o, dict):
+                n += sum(size(k) + size(v) for k, v in o.items())
+            elif isinstance(o, (tuple, list)):
+                n += sum(size(x) for x in o)
+            return n
+
+        return sum(size(getattr(self, s)) for s in self.__slots__)
+
+
+# ---------------------------------------------------------------------------
+# the key
+# ---------------------------------------------------------------------------
+
+_AST_OK = (ast.Expression, ast.BoolOp, ast.BinOp, ast.UnaryOp, ast.Compare,
+           ast.IfExp, ast.Call, ast.Name, ast.Constant, ast.Tuple,
+           ast.Subscript, ast.Slice, ast.expr_context, ast.operator,
+           ast.unaryop, ast.boolop, ast.cmpop)
+
+
+@functools.lru_cache(maxsize=4096)
+def _expr_ok(src: str) -> bool:
+    """The expression is arithmetic over names: no attribute, no call
+    but the evaluator's own builtins (an inline call may read anything)."""
+    try:
+        tree = ast.parse(_c_to_py(src).strip(), mode="eval")
+    except SyntaxError:
+        return False
+    for n in ast.walk(tree):
+        if not isinstance(n, _AST_OK):
+            return False
+        if isinstance(n, ast.Call) and not (
+                isinstance(n.func, ast.Name) and n.func.id in _SAFE_BUILTINS
+                and not n.keywords):
+            return False
+    return True
+
+
+def _src_fp(e) -> Optional[str]:
+    """An ``_Expr``'s text, once :func:`_expr_ok` has vouched for it."""
+    if e is None:
+        return None
+    if not _expr_ok(e.src):
+        raise Uncacheable(f"expression {e.src!r}")
+    return e.src
+
+
+def _arg_fp(a: _ArgExpr) -> Tuple:
+    return (_src_fp(a.lo), _src_fp(a.hi), _src_fp(a.step))
+
+
+def _dep_fp(dep) -> str:
+    if not dep.src:
+        raise Uncacheable("a dependency without its source text")
+    _src_fp(dep.guard)
+    for t in (dep.then, dep.otherwise):
+        if isinstance(t, (_TaskRef, _DataRef)):
+            for a in t.args:
+                _arg_fp(a)
+    return dep.src
+
+
+def _body_fp(fn) -> Tuple:
+    from .fusion import _body_fp as content_fp
+
+    try:
+        return (content_fp(fn), bool(getattr(fn, "_static_values", False)),
+                repr(getattr(fn, "_donate_args", None)))
+    except Exception as e:
+        raise Uncacheable(f"body {fn!r}: {type(e).__name__}") from None
+
+
+def _ptg_fp(ptg: PTG) -> Tuple:
+    out: List[Tuple] = [("ptg", ptg.name)]
+    for pc in ptg.classes.values():
+        aff = pc._affinity
+        out.append((
+            pc.name,
+            tuple((n, _arg_fp(e), p) for n, e, p in pc.decls),
+            tuple((f.name, int(f.mode),
+                   tuple(_dep_fp(d) for d in f.deps_in),
+                   tuple(_dep_fp(d) for d in f.deps_out))
+                  for f in pc.flows),
+            _src_fp(pc._priority),
+            None if aff is None else (
+                aff.collection_name, tuple(_arg_fp(a) for a in aff.args)),
+            tuple(sorted((dt, _body_fp(fn))
+                         for dt, fn in pc.bodies.items())),
+            tuple(pc.body_globals),
+            tuple(sorted(pc.stage_hooks)), tuple(sorted(pc.chore_evaluate)),
+            tuple(sorted((str(k), _const_fp(k, v))
+                         for k, v in pc.properties.items()))))
+    return tuple(out)
+
+
+_PLAIN = (int, float, complex, str, bytes, bool, type(None))
+
+
+def _collection_fp(dc) -> Optional[Tuple]:
+    """What placement (and a ``NEW`` tile's default shape) reads of a
+    collection of a known kind; None for any other object.  Exact types:
+    a subclass may place its tiles by a rule of its own."""
+    from ..data.collection import LocalCollection
+    from ..datadist.matrix import (SymTwoDimBlockCyclic, TiledMatrix,
+                                   TwoDimBlockCyclic, VectorTwoDimCyclic)
+
+    t = type(dc)
+    if t in (TiledMatrix, TwoDimBlockCyclic, SymTwoDimBlockCyclic,
+             VectorTwoDimCyclic):
+        return (t.__name__, dc.m, dc.n, dc.mb, dc.nb, dc.mt, dc.nt,
+                np.dtype(dc.default_dtype).str, dc.uplo, dc.nodes, dc.myrank,
+                tuple(getattr(dc, a, None) for a in ("p", "q", "kp", "kq")))
+    if t is LocalCollection:
+        return (t.__name__, dc.tile_shape, np.dtype(dc.default_dtype).str,
+                dc.nodes, dc.myrank)
+    return None
+
+
+def _const_fp(name, v) -> Tuple:
+    t = type(v)
+    if t in _PLAIN:
+        return (t.__name__, v)
+    if t is tuple:
+        return ("tuple",) + tuple(_const_fp(name, x) for x in v)
+    if isinstance(v, np.generic):
+        return ("np", v.dtype.str, v.item())
+    if isinstance(v, np.dtype):
+        return ("dtype", v.str)
+    if isinstance(v, type):
+        return ("type", v.__module__, v.__qualname__)
+    fp = _collection_fp(v)
+    if fp is None:
+        raise Uncacheable(f"constant {name!r} ({t.__name__})")
+    return fp
+
+
+def plan_key(tp, ranks: Iterable[int], fusion: Tuple) -> Tuple:
+    """The fingerprint of everything a captured, partitioned and
+    resolved graph of ``tp`` is a function of; raises
+    :class:`Uncacheable` where it cannot vouch for some part."""
+    return ("attach-plan-1", _ptg_fp(tp.ptg),
+            tuple(sorted((str(k), _const_fp(k, v))
+                         for k, v in tp.constants.items())),
+            tuple(sorted(ranks)), fusion)
+
+
+# ---------------------------------------------------------------------------
+# the store
+# ---------------------------------------------------------------------------
+
+_plans: "collections.OrderedDict[Tuple, AttachPlan]" = \
+    collections.OrderedDict()
+_plans_lock = threading.Lock()
+
+
+def lookup(key: Tuple) -> Optional[AttachPlan]:
+    with _plans_lock:
+        plan = _plans.get(key)
+        if plan is not None:
+            _plans.move_to_end(key)
+        return plan
+
+
+def store(plan: AttachPlan) -> None:
+    with _plans_lock:
+        _plans[plan.key] = plan
+        _plans.move_to_end(plan.key)
+        while len(_plans) > PLAN_CACHE_SIZE:
+            _plans.popitem(last=False)
+
+
+def clear() -> None:
+    """Forget every stored plan (tests; a process that is done with a
+    family of shapes)."""
+    with _plans_lock:
+        _plans.clear()
+
+
+def stored() -> List[AttachPlan]:
+    with _plans_lock:
+        return list(_plans.values())
+
+
+# ---------------------------------------------------------------------------
+# capture -> plan
+# ---------------------------------------------------------------------------
+
+_VALUE = AccessMode.VALUE
+
+
+def build_plan(tp, g: TaskGraph, regions=()) -> AttachPlan:
+    """Resolve the captured graph ``g`` of ``tp`` (and its fused
+    ``regions``) into a plan: every per-task fact the bind needs, with
+    flows resolved to tile slots, and the contracted native graph."""
+    ptg_classes = tp.ptg.classes
+    consts = tp.constants
+    plan = AttachPlan()
+    plan.ptg_name = tp.ptg.name
+    order = list(g.nodes)
+    nodes = plan.nodes = {tid: i for i, tid in enumerate(order)}
+
+    tiles: List[Tuple] = []
+    users: List[int] = []
+    slot_of: Dict[Tuple, int] = {}
+
+    def slot(srckey: Tuple, use: int = 0) -> int:
+        """The slot of a resolved chain, with ``use`` more users of it
+        where it is a scratch tile."""
+        if srckey[0] == "remote":
+            # a chain that leaves the captured partition: this single-
+            # rank executor cannot resolve it
+            raise RuntimeError(
+                f"flow source {srckey[1]}/{srckey[2]} is on another rank; "
+                "use NativeDistExecutor for rank-filtered captures")
+        s = slot_of.get(srckey)
+        if s is None:
+            s = slot_of[srckey] = len(tiles)
+            tiles.append(srckey)
+            users.append(0)
+        if srckey[0] == "new":
+            users[s] += use
+        return s
+
+    # per class: index, flow table and which flows a successor rewrites
+    cls_index: Dict[str, int] = {}
+    cls_flows: Dict[str, Tuple] = {}
+    flow_mode: Dict[Tuple[str, str], Any] = {}
+    for name, pc in ptg_classes.items():
+        for f in pc.flows:
+            flow_mode[(name, f.name)] = f.mode
+    device_class: List[bool] = []
+
+    region_of = {m: r for r in regions for m in r.members}
+    rows: List[Tuple] = []
+    succs: List[Tuple[int, ...]] = []
+    wbs_of: List[Tuple] = []
+    for tid in order:
+        cname, locs = tid
+        pc = ptg_classes[cname]
+        ci = cls_index.get(cname)
+        if ci is None:
+            ci = cls_index[cname] = len(cls_index)
+            cls_flows[cname] = tuple(pc.flows)
+            device_class.append(any(dt != DEV_CPU for dt in pc.bodies))
+            if not pc.bodies:
+                raise ValueError(f"native_exec: class {cname} has no body")
+        node = g.nodes[tid]
+        fused = tid in region_of
+        # a task's own arguments use its scratch tiles once each; a
+        # fused member's are the region's (declared below, per slot)
+        use = 0 if fused else 1
+        flows = []
+        for f in cls_flows[cname]:
+            if f.mode == CTL:
+                flows.append(CTL_FLOW)
+            elif node.flow_sources.get(f.name) is None \
+                    and not (f.mode & AccessMode.OUT):
+                flows.append(NO_DATA)
+            else:
+                flows.append(slot(source_tile(g, tid, f.name), use))
+        env = pc.env_of(locs, consts) if pc.def_names else None
+        values = tuple(("value", v, _VALUE) for v in locs) + tuple(
+            ("value", env[n], _VALUE) for n in pc.def_names)
+        # the outputs that go home: declared written back and taken on
+        # by no successor that writes the tile again
+        rewritten = {f for (f, succ, sf) in node.out_edges
+                     if flow_mode[(succ[0], sf)] & AccessMode.OUT}
+        home_names = {f for (f, _c, _k) in node.write_backs} - rewritten
+        home = tuple(f.index for f in cls_flows[cname]
+                     if f.name in home_names)
+        # cross-tile write-backs (the chain's source is not the home
+        # tile): the source is read after the task's epilog, so it is
+        # one more user, never released
+        wbs = []
+        for (fname, cname2, key) in node.write_backs:
+            src = source_tile(g, tid, fname)
+            hkey = ("data", cname2, tuple(key))
+            if src != hkey:
+                wbs.append((slot(src, use), slot(hkey)))
+        wbs_of.append(tuple(wbs))
+        rows.append((ci, locs, node.priority, tuple(flows), values, home,
+                     () if fused else tuple(wbs)))
+        succs.append(tuple(nodes[s] for (_f, s, _sf) in node.out_edges))
+    plan.classes = tuple(cls_index)
+    plan.device_class = tuple(device_class)
+    plan.has_cpu_bodies = not all(device_class)
+    plan.tasks = tuple(rows)
+    plan.succs = tuple(succs)
+
+    # native nodes: one a task, ONE a fused region (at its first member)
+    node_of: List[int] = []
+    native: List[int] = []
+    prio: List[int] = []
+    fused_rows: List[Tuple] = []
+    region_node: Dict[int, int] = {}
+    for i, tid in enumerate(order):
+        reg = region_of.get(tid)
+        if reg is None:
+            node_of.append(len(native))
+            native.append(i)
+            prio.append(rows[i][2])
+            continue
+        nid = region_node.get(reg.index)
+        if nid is None:
+            nid = region_node[reg.index] = len(native)
+            native.append(~len(fused_rows))
+            prio.append(max(g.nodes[m].priority for m in reg.members))
+            fused_rows.append(
+                _fused_row(tp, g, reg, slot, tiles, nodes, wbs_of))
+        node_of.append(nid)
+    plan.node_of = tuple(node_of)
+    plan.native = tuple(native)
+    plan.fused = tuple(fused_rows)
+    plan.tiles = tuple(tiles)
+    plan.tile_users = tuple(users)
+
+    # contracted edges are DEDUPLICATED: add_dep is symmetric (one
+    # in-degree per declared edge, one release per succs entry), so
+    # collapsing parallel region->target edges to one stays balanced
+    # while shaving native succs slots and atomic releases
+    pred: List[int] = []
+    succ: List[int] = []
+    seen = set()
+    has_pred = set()
+    for i in range(len(order)):
+        me = node_of[i]
+        for s in succs[i]:
+            tgt = node_of[s]
+            if tgt == me:
+                continue  # intra-region edge: runs inside the program
+            if regions and (me, tgt) in seen:
+                continue
+            seen.add((me, tgt))
+            pred.append(me)
+            succ.append(tgt)
+            has_pred.add(tgt)
+    plan.native_prio = (ctypes.c_int32 * len(prio))(*prio)
+    plan.edge_pred = (ctypes.c_int64 * len(pred))(*pred)
+    plan.edge_succ = (ctypes.c_int64 * len(succ))(*succ)
+    plan.roots = tuple(n for n in range(len(native)) if n not in has_pred)
+    return plan
+
+
+def _fused_row(tp, g: TaskGraph, region, slot, tiles, nodes,
+               wbs_of) -> Tuple:
+    """One fused region: its program (:class:`..dsl.fusion.FusedPlan`,
+    which keeps no taskpool), a slot per program argument, and the
+    cross-tile write-backs of EVERY member, landed at the one
+    completion; per home tile only the LAST member's landing survives
+    (earlier ones would be superseded anyway)."""
+    from .fusion import FusedPlan
+
+    fp = FusedPlan(tp, g, region)
+    slots = []
+    for key in fp.slot_keys:
+        if key[0] == "ext":
+            # ("ext", producer tid, producer flow): the producer's
+            # threaded tile, as its own dispatch would resolve it
+            key = source_tile(g, key[1], key[2])
+        slots.append(slot(tuple(key), 1))
+    members = tuple(nodes[m] for m in region.members)
+    last: Dict[int, Tuple[int, int]] = {}
+    for m in members:
+        for (src, home) in wbs_of[m]:
+            last[home] = (src, home)
+    for (src, _home) in last.values():
+        slot(tiles[src], 1)  # read after the epilog: never released
+    return (fp, tuple(slots), members, tuple(last.values()))

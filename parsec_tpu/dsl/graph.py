@@ -13,8 +13,12 @@ three subsystems that the reference implements as separate machinery:
   one jitted program — the analogue of CUDA-graph capture, but done by the
   XLA compiler with full fusion/overlap freedom).
 
-Capture cost is O(tasks + edges) expression evaluations; it is a test/
-lowering tool, not a hot path.
+Capture cost is O(tasks + edges) expression evaluations (284,000
+``eval`` calls for the 11,440 tasks of a tile QR at NT=32).  It began as
+a test/lowering tool; since the pump path (``dsl/native_exec.py``) it
+stood at the head of every solve, and since the attach plan
+(``dsl/attach_plan.py``) at the head of every FIRST solve of a shape: a
+later solve of that shape binds the stored plan and captures nothing.
 """
 
 from __future__ import annotations

@@ -47,11 +47,12 @@ ms/task of host-side Python bookkeeping).
 
 from __future__ import annotations
 
+import contextlib
+import gc
+import types
 from typing import Any, Callable, Dict, List, Optional, Tuple
 
 import numpy as np
-
-import types
 
 from ..core.lifecycle import AccessMode, HookReturn, DEV_CPU, DEV_TPU
 from ..core.task import Chore, Task, TaskClass
@@ -90,6 +91,32 @@ def _conformance_on() -> bool:
              "happens-before drain order); divergence raises LintError "
              "with ENG014 findings.  Diagnostic mode: the capture and "
              "replay cost O(events)")))
+
+
+def _new_stats() -> Dict[str, int]:
+    """An executor's counters.  In pump mode ``trampoline_entries`` and
+    ``completion_callbacks`` MUST stay 0 (every per-task interpreter
+    entry increments one of them); ``attach_plan_*`` say how the attach
+    came by its plan (dsl/attach_plan.py)."""
+    return {"trampoline_entries": 0, "completion_callbacks": 0,
+            "pop_batches": 0, "done_batches": 0, "pumped_tasks": 0,
+            "events_drained": 0, "prefetched_batches": 0,
+            "attach_plan_hits": 0, "attach_plan_misses": 0,
+            "attach_plan_uncacheable": 0}
+
+
+@contextlib.contextmanager
+def _collector_paused():
+    """The cyclic collector held off a stretch that allocates many
+    objects and no garbage: it would only walk them, again and again
+    (the bind of 11,440 tasks: 200 -> 63 ms on the sandbox's CPU)."""
+    collecting = gc.isenabled()
+    gc.disable()
+    try:
+        yield
+    finally:
+        if collecting:
+            gc.enable()
 
 
 class _TaskInfo:
@@ -428,79 +455,117 @@ class NativeExecutor:
         self.taskpool = tp
         self.native_device = bool(native_device)
         self.device = device
-        #: control-plane counters the zero-entry pin reads: in pump mode
-        #: ``trampoline_entries`` and ``completion_callbacks`` MUST stay 0
-        #: (every per-task interpreter entry increments one of them)
-        self.stats: Dict[str, int] = {
-            "trampoline_entries": 0, "completion_callbacks": 0,
-            "pop_batches": 0, "done_batches": 0, "pumped_tasks": 0,
-            "events_drained": 0, "prefetched_batches": 0}
+        #: control-plane counters (the zero-entry pin reads them)
+        self.stats: Dict[str, int] = _new_stats()
         #: serve mode (NativeServeExecutor): build into ITS shared native
         #: graph under this tenant id instead of owning one
         self._shared_graph = _shared_graph
         self._tenant = int(_tenant)
         self._pump = False          # zero-entry lifecycle configured
         self._events_on = False     # native event buffer armed at build
-        self._has_cpu_bodies = False
         #: native id -> prebuilt device task, the pump loop's dispatch map
         self._pump_index: Dict[int, _NativeDeviceTask] = {}
         self._roots: List[int] = []
-        #: native-id edges as declared to add_dep, retained only under
-        #: runtime_native_conformance for the post-run stream replay
+        #: native-id edges as declared to the native graph, kept only
+        #: under runtime_native_conformance for the post-run stream replay
         self._conformance = False
         self._edges: List[Tuple[int, int]] = []
-        self._pool_shim: Optional[_NativePoolShim] = None
-        if self.native_device:
-            if device is None:
-                self.device = self._make_device()
-            self._pool_shim = _NativePoolShim(self, f"native:{tp.ptg.name}")
-        with pins.span("attach:build", pool=tp.taskpool_id, rank=0) as sp:
-            self._attach(tp, graph, fusion)
-            sp.note(tasks=len(self.graph.nodes), regions=len(self._regions))
-
-    def _attach(self, tp: PTGTaskpool, graph: Optional[TaskGraph],
-                fusion: Optional[str]) -> None:
-        """Capture the DAG, partition it into fused regions and build the
-        native graph: the ``attach:build`` span."""
-        self.graph = graph if graph is not None else capture(tp, ranks=[0])
         self._new_tiles: Dict[Tuple, np.ndarray] = {}
-        self._new_data: Dict[Tuple, Any] = {}
-        #: tid -> the object PINS observers see for that task (device
-        #: tasks: the Task itself; CPU bodies: a _TaskInfo) — the static
-        #: dep-edge emitter walks this
-        self._trace_objs: Dict[Tuple, Any] = {}
+        #: the trampoline's bodies, by user tag (the numpy path and the
+        #: legacy ASYNC-chore protocol; the pump calls none, and has none)
         self._bodies: List[Callable[[], Any]] = []
+        #: the native nodes this executor declared (a fused region is
+        #: one) and the id of the first
+        self._n_native = 0
+        self._native_base = 0
         #: supertask fusion (dsl.fusion): regions of the captured graph
         #: collapsed to ONE native node each — one device dispatch, one
         #: pz_task_done retiring N member tasks.  ``fusion=None`` reads
         #: the runtime_fusion MCA param; device dispatch only (the win
         #: is the per-task device enqueue, which CPU bodies don't pay).
         self._regions: List[Any] = []
-        self._region_of: Dict[Tuple, Any] = {}
+        self._pool_shim: Optional[_NativePoolShim] = None
         if self.native_device:
-            with pins.span("attach:partition", pool=tp.taskpool_id, rank=0):
-                self._partition_regions(fusion)
-        self._build()
+            if device is None:
+                self.device = self._make_device()
+            self._pool_shim = _NativePoolShim(self, f"native:{tp.ptg.name}")
+        with pins.span("attach:build", pool=tp.taskpool_id, rank=0) as sp:
+            if self.native_device:
+                with pins.span("attach:partition", pool=tp.taskpool_id,
+                               rank=0):
+                    plan, how = self._plan_for(tp, graph, fusion)
+                with pins.span("attach:bind", pool=tp.taskpool_id, rank=0), \
+                        _collector_paused():
+                    self._bind(plan)
+                sp.note(tasks=len(plan.tasks), regions=len(plan.fused),
+                        plan=how)
+            else:
+                # the numpy path captures and builds every time;
+                # rebind() is what amortizes it there
+                self.graph = graph if graph is not None \
+                    else capture(tp, ranks=[0])
+                self._trace_objs: Dict[Tuple, Any] = {}
+                self._build()
+                sp.note(tasks=len(self.graph.nodes), regions=0)
 
-    def _partition_regions(self, fusion: Optional[str]) -> None:
-        from ..utils import debug
-        from .fusion import fusion_mode, fusion_max_tasks, partition
+    # -- the device path: an attach plan, bound to this pool's tiles -----
+    def _plan_for(self, tp: PTGTaskpool, graph: Optional[TaskGraph],
+                  fusion: Optional[str]):
+        """This pool's native nodes, tasks and fused regions (the
+        ``attach:partition`` span): the stored :class:`AttachPlan` of its
+        shape, or a new one — captured, partitioned and resolved under
+        ``attach:plan`` — which is stored when the shape has a key.
+        Returns ``(plan, "hit" | "miss" | "uncacheable")``."""
+        from . import attach_plan
+        from .fusion import fusion_mode, fusion_max_tasks, fusion_scan_mode
 
         mode = fusion if fusion is not None else fusion_mode()
         if mode in ("", "off"):
-            return
+            cfg: Tuple = ("off",)
+        else:
+            cfg = (mode, fusion_max_tasks(device=self.device),
+                   fusion_scan_mode())
+        key = None
+        if graph is None:
+            # (a handed-in graph is the caller's: nothing here can vouch
+            # for what it was captured from)
+            try:
+                key = attach_plan.plan_key(tp, (0,), cfg)
+            except attach_plan.Uncacheable as e:
+                from ..utils import debug
+
+                debug.verbose(2, "attach", "%s: no attach plan kept (%s)",
+                              tp.ptg.name, e)
+        if key is not None:
+            plan = attach_plan.lookup(key)
+            if plan is not None:
+                self.stats["attach_plan_hits"] += 1
+                return plan, "hit"
+        with pins.span("attach:plan", pool=tp.taskpool_id, rank=0):
+            g = graph if graph is not None else capture(tp, ranks=[0])
+            plan = attach_plan.build_plan(
+                tp, g, self._partition_regions(g, cfg))
+        if key is None:
+            self.stats["attach_plan_uncacheable"] += 1
+            return plan, "uncacheable"
+        plan.key = key
+        attach_plan.store(plan)
+        self.stats["attach_plan_misses"] += 1
+        return plan, "miss"
+
+    def _partition_regions(self, g: TaskGraph, cfg: Tuple) -> List[Any]:
+        from ..utils import debug
+        from .fusion import partition
+
+        if cfg[0] == "off":
+            return []
         try:
-            self._regions = partition(
-                self.graph, self.taskpool.ptg.classes, mode=mode,
-                max_tasks=fusion_max_tasks(device=self.device))
-            for r in self._regions:
-                for m in r.members:
-                    self._region_of[m] = r
+            return partition(g, self.taskpool.ptg.classes, mode=cfg[0],
+                             max_tasks=cfg[1])
         except Exception as e:
             debug.warning("native fusion disabled (%s: %s)",
                           type(e).__name__, e)
-            self._regions = []
-            self._region_of = {}
+            return []
 
     @staticmethod
     def _make_device():
@@ -544,86 +609,36 @@ class NativeExecutor:
             t = self._new_tiles[srckey] = np.zeros(shape, dtype)
         return t
 
-    def _build(self) -> None:
+    def _bind(self, plan) -> None:
+        """Bind ``plan`` to this pool's tiles (the ``attach:bind`` span):
+        one ``data_of`` a distinct tile and one ``scratch.new`` a ``NEW``
+        chain, one task object a task, the native graph from the plan's
+        arrays in one call.  The first solve of a shape and the hundredth
+        run this same code; nothing here reads a dependency expression."""
+        from ..device import scratch
+        from .attach_plan import CTL_FLOW
+
         tp = self.taskpool
-        g = self.graph
         consts = tp.constants
+        classes = tp.ptg.classes
+        self.graph = plan
         ng = self._shared_graph if self._shared_graph is not None \
             else self._native.NativeGraph()
         self._ng = ng
-        index = self._index = {}
-        # conformance mode retains the declared edges so the post-run
-        # replay can rebuild the DAG in native-id space
         self._conformance = _conformance_on()
-
-        order = list(g.nodes)
-        region_native: Dict[int, int] = {}
-        for tid in order:
-            reg = self._region_of.get(tid)
-            if reg is not None:
-                # fused region: ONE native node for all members — one
-                # device dispatch, one pz_task_done (dsl.fusion)
-                rid = region_native.get(reg.index)
-                if rid is None:
-                    rid = ng.add_task(
-                        priority=max(g.nodes[m].priority
-                                     for m in reg.members),
-                        user_tag=len(self._bodies))
-                    if self._tenant:
-                        ng.set_task_tenant(rid, self._tenant)
-                    region_native[reg.index] = rid
-                    self._bodies.append(self._make_fused_dispatch(reg, rid))
-                index[tid] = rid
-                continue
-            node = g.nodes[tid]
-            index[tid] = ng.add_task(priority=node.priority,
-                                     user_tag=len(self._bodies))
-            if self._tenant:
-                ng.set_task_tenant(index[tid], self._tenant)
-            self._bodies.append(self._make_body(tid))
-            if self.native_device:
-                # the completion callback needs the native id the task
-                # must signal; assigned here because _make_body built the
-                # task before the edge pass ran
-                obj = self._trace_objs.get(tid)
-                if isinstance(obj, _NativeDeviceTask):
-                    obj.native_id = index[tid]
-                    self._pump_index[index[tid]] = obj
-        # contracted edges are DEDUPLICATED: add_dep is symmetric (one
-        # in-degree per declared edge, one release per succs entry), so
-        # collapsing parallel region->target edges to one stays balanced
-        # while shaving native succs slots and atomic releases
-        seen_edges = set()
-        has_pred = set()
-        for tid in order:
-            me = index[tid]
-            for (_f, succ, _sf) in g.nodes[tid].out_edges:
-                tgt = index[succ]
-                if tgt == me:
-                    continue  # intra-region edge: runs inside the program
-                if self._region_of and (me, tgt) in seen_edges:
-                    continue
-                seen_edges.add((me, tgt))
-                ng.add_dep(me, tgt)
-                if self._conformance:
-                    self._edges.append((me, tgt))
-                has_pred.add(tgt)
-        self._roots = [nid for nid in dict.fromkeys(index.values())
-                       if nid not in has_pred]
         # pump mode (zero-interpreter lifecycle): decided BEFORE the
-        # commit pass because committing pushes source tasks, and those
-        # pushes must land in the configured native SchedQ
+        # commit because committing pushes source tasks, and those pushes
+        # must land in the configured native SchedQ
         if self._shared_graph is not None:
             # the serve executor already called sched_config("wdrr") on
             # the shared graph; a CPU-fallback body would need the
             # trampoline protocol the pump never runs
-            if self._has_cpu_bodies:
+            if plan.has_cpu_bodies:
                 raise RuntimeError(
                     "NativeServeExecutor requires all-device task "
                     f"classes ({tp.ptg.name} has CPU-only classes)")
             self._pump = True
-        elif (self.native_device and not self._has_cpu_bodies
-                and _native_sched_mode() != "off"
+        elif (not plan.has_cpu_bodies and _native_sched_mode() != "off"
                 and getattr(self.device, "_eager", True)):
             from ..utils import mca_param
 
@@ -636,6 +651,108 @@ class NativeExecutor:
                      "explorer's replay hook; -1 = unseeded fuzzing)"))
             ng.sched_config(policy="prio", quantum=0, seed=seed)
             self._pump = True
+
+        # the tiles: a collection's Data, or a scratch tile
+        # (device/scratch.py: no payload, born where its first task
+        # runs) with the users the plan counted
+        datas: List[Any] = []
+        new_spec: Dict[Tuple[str, str], Tuple] = {}
+        for srckey, users in zip(plan.tiles, plan.tile_users):
+            if srckey[0] == "data":
+                datas.append(consts[srckey[1]].data_of(*srckey[2]))
+                continue
+            _, (pc_name, _locs), fname = srckey
+            spec = new_spec.get((pc_name, fname))
+            if spec is None:
+                # per-flow NEW shape (dep [type=...] props), resolved
+                # by the taskpool
+                spec = new_spec[(pc_name, fname)] = \
+                    tp.new_tile_spec(pc_name, fname)
+            d = scratch.new(("native_new",) + tuple(srckey[1:]), *spec)
+            scratch.add_users(d, users)
+            datas.append(d)
+
+        # the native graph, edges and all, uncommitted
+        n = self._n_native = len(plan.native)
+        base = self._native_base = ng.add_bulk(
+            plan.native_prio, self._tenant, plan.edge_pred, plan.edge_succ)
+        self._regions = [row[0].region for row in plan.fused]
+        self._roots = [base + r for r in plan.roots]
+        if self._conformance:
+            # the post-run replay rebuilds the DAG in native-id space
+            self._edges = [(base + a, base + b) for a, b in
+                           zip(plan.edge_pred, plan.edge_succ)]
+
+        # per class: the vtable, the chore, the flow modes and the
+        # body_globals' value specs (the plan keeps no constant)
+        per_class = []
+        for cname, on_device in zip(plan.classes, plan.device_class):
+            pc = classes[cname]
+            gvals = [("value", consts[g], AccessMode.VALUE)
+                     for g in pc.body_globals]
+            per_class.append((
+                pc, TaskClass(cname),
+                self._device_chore(pc) if on_device else None,
+                tuple(f.mode for f in pc.flows), gvals))
+        fused_classes: Dict[str, TaskClass] = {}
+        ctl = ("ctl", None, CTL)
+        shim = self._pool_shim
+        dev = self.device
+        pump = self._pump
+        index = self._pump_index
+        #: position -> the object PINS observers see for that task
+        #: (device tasks: the Task itself, a fused region's members its
+        #: supertask; CPU bodies: a _TaskInfo) — the static dep-edge
+        #: emitter walks this
+        objs: List[Any] = [None] * len(plan.tasks)
+        self._trace_objs = objs
+        bodies = self._bodies
+        for nid, pos in enumerate(plan.native, base):
+            if pos < 0:
+                row = plan.fused[~pos]
+                task = self._fused_task(row, datas, fused_classes)
+                for m in row[2]:
+                    objs[m] = task
+            else:
+                ci, locs, prio, slots, values, home, wbs = plan.tasks[pos]
+                pc, tclass, chore, modes, gvals = per_class[ci]
+                if chore is None:
+                    # a CPU-fallback body needs the trampoline protocol
+                    # (its presence kept the DAG out of the pump)
+                    info = objs[pos] = _TaskInfo(pc.name, locs)
+                    bodies.append(self._cpu_data_body(
+                        pc, info, slots, values, gvals, wbs, datas))
+                    continue
+                task = objs[pos] = _NativeDeviceTask(shim, tclass, locs,
+                                                     prio)
+                task.selected_chore = chore
+                # body_args in prepare_input layout: flows by declaration
+                # order (CTL placeholders keep f.index alignment), then
+                # values in the POSITIONAL contract order params, defs,
+                # body_globals — the order _wrap_device_body zips its
+                # names against (ptg.py; the dynamic path's
+                # prepare_input emits the same order)
+                task.body_args = [
+                    ctl if s == CTL_FLOW else
+                    ("data", datas[s] if s >= 0 else None, m)
+                    for s, m in zip(slots, modes)]
+                task.body_args += values
+                task.body_args += gvals
+                task._tpu_home = home
+                if wbs:
+                    # write-backs PRE-RESOLVED to (source Data, home
+                    # Data) pairs: whoever retires the task lands them
+                    # without touching the taskpool
+                    task._wbs = [(datas[a], datas[b]) for a, b in wbs]
+            task.selected_device = dev
+            task.native_id = nid
+            index[nid] = task
+            if not pump:
+                # legacy ASYNC-chore protocol: the trampoline enqueues,
+                # the device manager's completion signals pz_task_done
+                task.on_complete = self._on_complete
+                bodies.append(self._enqueue_body(task))
+
         if self._pump and (self._conformance
                            or pins.active(pins.DEP_DECREMENT)
                            or pins.active(pins.NATIVE_TASK_DONE)):
@@ -648,297 +765,49 @@ class NativeExecutor:
         # it, and a task whose in-edges arrive after arming would release
         # early (the commit token covers a task's own declaration window,
         # which for this whole-DAG build is the full edge pass)
-        committed = set()
-        for tid in order:
-            nid = index[tid]
-            if nid not in committed:
-                committed.add(nid)
-                ng.commit(nid)
+        ng.commit_range(base, n)
         if self._shared_graph is None:
             ng.seal()
 
-    def _make_fused_dispatch(self, region, native_id: int) -> Callable[[], Any]:
-        """Enqueue-only trampoline for a FUSED region: one prebuilt
-        supertask whose chore body is the region's jitted program
-        (:class:`..dsl.fusion.FusedPlan`); the completion callback lands
-        every member's cross-tile write-backs and signals ONE
-        ``pz_task_done`` that retires all N members natively."""
-        from ..core.lifecycle import AccessMode
-        from .fusion import FusedPlan
-        from .graph import source_tile
-
-        tp = self.taskpool
-        g = self.graph
-        plan = FusedPlan(tp, g, region)
-
-        def data_of_slot(key):
-            if key[0] == "data":
-                return tp.constants[key[1]].data_of(*key[2])
-            if key[0] == "new":
-                return self._data_for(("new", key[1], key[2]))
-            # ("ext", producer tid, producer flow): the producer's
-            # threaded Data — same resolution its own dispatch would use
-            _, ptid, pflow = key
-            return self._data_for(source_tile(g, ptid, pflow))
-
-        task = _NativeDeviceTask(self._pool_shim,
-                                 self._fused_tclass(plan),
-                                 (region.index,), plan.priority)
-        task.fused_n = len(region.members)
-        chore = Chore(plan.device_type,
-                      hook=lambda es, task: HookReturn.ASYNC)
-        chore.body_fn = plan.body_fn
-        task.selected_chore = chore
-        task.selected_device = self.device
-        task.body_args = [
-            ("data", data_of_slot(k),
-             AccessMode(m) if m else AccessMode.IN)
-            for k, m in zip(plan.slot_keys, plan.slot_modes)]
-        self._use_scratch(s[1] for s in task.body_args)
-        task.native_id = native_id
-
-        # cross-tile write-backs of EVERY member, landed at the one
-        # completion; per home tile only the LAST member's landing
-        # survives (earlier ones would be superseded anyway)
-        wb_map: Dict[Tuple, Tuple] = {}
-        for tid in region.members:
-            for (src_data, cname2, key) in self._write_back_plan(tid):
-                wb_map[(cname2, key)] = (src_data, cname2, key)
-        wbs = list(wb_map.values())
-        ng = self._ng
-        stats = self.stats
-        # write-backs PRE-RESOLVED to (source Data, home Data) pairs: the
-        # pump loop lands them without touching the taskpool (no rebind
-        # with native_device, so build-time resolution is final)
-        task._wbs = [(src_data,
-                      self.taskpool.constants[cname2].data_of(*key))
-                     for (src_data, cname2, key) in wbs]
-        self._use_scratch(src for (src, _c, _k) in wbs)  # never released
-        self._pump_index[native_id] = task
-
-        def on_complete(t: Task) -> None:
-            stats["completion_callbacks"] += 1
-            if wbs:
-                from ..data.data import land_into_home
-
-                for (src_data, cname2, key) in wbs:
-                    home = self.taskpool.constants[cname2].data_of(*key)
-                    newest = src_data.newest_copy()
-                    land_into_home(home, newest.payload)
-            ng.task_done(t.native_id)
-
-        task.on_complete = on_complete
-        for tid in region.members:
-            self._trace_objs[tid] = task
-        dev = self.device
-        shim = self._pool_shim
-
-        def body():
-            stats["trampoline_entries"] += 1
-            if shim.failed:
-                raise RuntimeError(
-                    f"native device pool failed: {shim.fail_reason}")
-            dev.kernel_scheduler(None, task)
-            return True  # ASYNC: pz_task_done releases the successors
-
-        return body
-
-    def _fused_tclass(self, plan) -> TaskClass:
-        """Bare vtable for a fused supertask (same contract as
-        :meth:`_device_tclass`: every completion-path slot is None)."""
-        cache = self.__dict__.setdefault("_ftclass_cache", {})
-        tc = cache.get(plan.name)
+    def _fused_task(self, row, datas, tclasses) -> "_NativeDeviceTask":
+        """The ONE prebuilt supertask of a fused region: its chore body
+        is the region's jitted program (:class:`..dsl.fusion.FusedPlan`);
+        one completion lands every member's cross-tile write-backs and
+        retires all N members natively."""
+        fp, slots, members, wbs = row
+        tc = tclasses.get(fp.name)
         if tc is None:
-            tc = cache[plan.name] = TaskClass(plan.name)
-        return tc
-
-    def _make_body(self, tid: Tuple) -> Callable[[], Any]:
-        """Body dispatcher: numpy in-place (default), device enqueue
-        (native_device + accelerator BODY), or Data-staged CPU fallback
-        (native_device, CPU-only class in a mixed DAG)."""
-        if self.native_device:
-            pc = self.taskpool.ptg.classes[tid[0]]
-            if any(dt != DEV_CPU for dt in pc.bodies):
-                return self._make_device_dispatch(tid)
-            # a CPU-fallback body needs the trampoline protocol: its
-            # presence disqualifies the DAG from the zero-entry pump
-            self._has_cpu_bodies = True
-            return self._make_cpu_data_body(tid)
-        return self._make_numpy_body(tid)
-
-    # -- native device dispatch ------------------------------------------
-    def _flow_data(self, tid: Tuple, pc) -> List[Tuple[str, Any, Any]]:
-        """(flow name, Data-or-None, mode) per non-CTL flow, resolving
-        each flow's chain to its backing :class:`Data` (home collection
-        tile, or a synthesized NEW tile shared along the chain)."""
-        node = self.graph.nodes[tid]
-        out: List[Tuple[str, Any, Any]] = []
-        for f in pc.flows:
-            if f.mode == CTL:
-                continue
-            src = node.flow_sources.get(f.name)
-            if src is None and not (f.mode & AccessMode.OUT):
-                out.append((f.name, None, f.mode))
-                continue
-            out.append((f.name, self._data_for(source_tile(
-                self.graph, tid, f.name)), f.mode))
-        return out
-
-    def _data_for(self, srckey: Tuple):
-        """Data object behind a resolved flow chain (the device-path
-        sibling of :meth:`_payload`).  A chain that starts at ``NEW`` is
-        a scratch tile (device/scratch.py): no payload, born where its
-        first task runs; every task built over it declares itself one
-        of its users (:meth:`_use_scratch`)."""
-        from ..device import scratch
-
-        if srckey[0] == "remote":
-            raise RuntimeError(
-                f"flow source {srckey[1]}/{srckey[2]} is on another rank; "
-                "use NativeDistExecutor for rank-filtered captures")
-        if srckey[0] == "data":
-            _, cname, key = srckey
-            return self.taskpool.constants[cname].data_of(*key)
-        d = self._new_data.get(srckey)
-        if d is None:
-            _, (pc_name, _locs), fname = srckey
-            shape, dtype = self.taskpool.new_tile_spec(pc_name, fname)
-            d = self._new_data[srckey] = scratch.new(
-                ("native_new",) + tuple(srckey[1:]), shape, dtype)
-        return d
-
-    @staticmethod
-    def _use_scratch(datas) -> None:
-        """Declare one user of each scratch tile among ``datas``.  A
-        device task releases it in its epilog; a user that never does (a
-        CPU body, a write-back of the tile into a collection) keeps the
-        tile for as long as its Data lives."""
-        from ..device import scratch
-
-        for d in datas:
-            if d is not None and d.scratch is not None:
-                scratch.add_users(d)
-
-    def _home_flows(self, pc, node) -> Tuple[int, ...]:
-        """Positions (in ``body_args``) of the flows whose output is the
-        version of its tile that the DAG sends home: the flow declares a
-        write-back into the collection and no successor takes it on to
-        write it again.  The device module hands only these to its
-        write-back committer; a version some later task overwrites stays
-        on the device (``detach`` still flushes whatever is dirty and
-        not home, so a flow this rule misses is late, never lost)."""
-        classes = self.taskpool.ptg.classes
-        rewritten = {
-            f for (f, succ, sf) in node.out_edges
-            if next(x.mode for x in classes[succ[0]].flows
-                    if x.name == sf) & AccessMode.OUT}
-        home = {f for (f, _c, _k) in node.write_backs} - rewritten
-        return tuple(f.index for f in pc.flows if f.name in home)
-
-    def _scalars_of(self, pc, locs) -> Dict[str, Any]:
-        consts = self.taskpool.constants
-        scalars = {n: consts[n] for n in pc.body_globals}
-        scalars.update(zip(pc.param_names, locs))
-        if pc.def_names:
-            env = pc.env_of(locs, consts)
-            for n in pc.def_names:
-                scalars[n] = env[n]
-        return scalars
-
-    def _write_back_plan(self, tid: Tuple) -> List[Tuple[Any, str, Tuple]]:
-        """Cross-tile write-backs (flow chain source != home tile) that
-        the completion callback must land; in the common threading case
-        (dpotrf-style flows living in their home tiles) this is empty."""
-        node = self.graph.nodes[tid]
-        plan = []
-        for (fname, cname2, key) in node.write_backs:
-            src = source_tile(self.graph, tid, fname)
-            if src != ("data", cname2, tuple(key)):
-                plan.append((self._data_for(src), cname2, tuple(key)))
-        return plan
+            # bare vtable (every completion-path slot is None: successor
+            # release belongs to the native engine)
+            tc = tclasses[fp.name] = TaskClass(fp.name)
+        task = _NativeDeviceTask(self._pool_shim, tc, (fp.region.index,),
+                                 fp.priority)
+        task.fused_n = len(members)
+        chore = Chore(fp.device_type, hook=lambda es, task: HookReturn.ASYNC)
+        chore.body_fn = fp.body_fn
+        task.selected_chore = chore
+        task.body_args = [
+            ("data", datas[s], AccessMode(m) if m else AccessMode.IN)
+            for s, m in zip(slots, fp.slot_modes)]
+        task._wbs = [(datas[a], datas[b]) for a, b in wbs]
+        return task
 
     def _device_chore(self, pc) -> Chore:
-        """One Chore per class carrying the wrapped accelerator body
+        """The Chore of a class, carrying the wrapped accelerator body
         (jit-cache identity preserved via ``_jit_key``)."""
-        cache = self.__dict__.setdefault("_chore_cache", {})
-        chore = cache.get(pc.name)
-        if chore is None:
-            dev_type, fn = next(
-                (dt, f) for dt, f in pc.bodies.items() if dt != DEV_CPU)
-            chore = Chore(dev_type, hook=lambda es, task: HookReturn.ASYNC)
-            chore.body_fn = _wrap_device_body(pc, fn)
-            cache[pc.name] = chore
+        dev_type, fn = next(
+            (dt, f) for dt, f in pc.bodies.items() if dt != DEV_CPU)
+        chore = Chore(dev_type, hook=lambda es, task: HookReturn.ASYNC)
+        chore.body_fn = _wrap_device_body(pc, fn)
         return chore
 
-    def _device_tclass(self, pc) -> TaskClass:
-        """Bare per-class vtable for device tasks: every slot the
-        completion path consults (release_deps, prepare_output, ...) is
-        None — successor release belongs to the native engine."""
-        cache = self.__dict__.setdefault("_tclass_cache", {})
-        tc = cache.get(pc.name)
-        if tc is None:
-            tc = cache[pc.name] = TaskClass(pc.name)
-        return tc
-
-    def _make_device_dispatch(self, tid: Tuple) -> Callable[[], Any]:
+    # -- legacy ASYNC-chore protocol (the pump calls neither) -------------
+    def _enqueue_body(self, task: "_NativeDeviceTask") -> Callable[[], Any]:
         """Enqueue-only trampoline body: hand the prebuilt Task to the
         device manager and return ASYNC.  Everything per-task beyond this
-        enqueue and the completion callback (which signals
-        ``pz_task_done``) runs either natively or inside the device
-        manager — never per-task interpreter bookkeeping."""
-        tp = self.taskpool
-        cname, locs = tid
-        pc = tp.ptg.classes[cname]
-        node = self.graph.nodes[tid]
-
-        task = _NativeDeviceTask(self._pool_shim, self._device_tclass(pc),
-                                 locs, node.priority)
-        task.selected_chore = self._device_chore(pc)
-        task.selected_device = self.device
-        # body_args in prepare_input layout: flows by declaration order
-        # (CTL placeholders keep f.index alignment), then values in the
-        # POSITIONAL contract order params, defs, body_globals — the
-        # order _wrap_device_body zips its names against (ptg.py; the
-        # dynamic path's prepare_input emits the same order)
-        specs: List[Tuple[str, Any, Any]] = []
-        flow_iter = iter(self._flow_data(tid, pc))
-        for f in pc.flows:
-            if f.mode == CTL:
-                specs.append(("ctl", None, CTL))
-            else:
-                _, data, mode = next(flow_iter)
-                specs.append(("data", data, mode))
-        scalars = self._scalars_of(pc, locs)
-        for name in pc.param_names + pc.def_names + pc.body_globals:
-            specs.append(("value", scalars[name], AccessMode.VALUE))
-        task.body_args = specs
-        self._use_scratch(s[1] for s in specs if s[0] == "data")
-        task._tpu_home = self._home_flows(pc, node)
-
-        wbs = self._write_back_plan(tid)
-        ng = self._ng
+        enqueue and the completion callback runs either natively or
+        inside the device manager."""
         stats = self.stats
-        task._wbs = [(src_data,
-                      self.taskpool.constants[cname2].data_of(*key))
-                     for (src_data, cname2, key) in wbs]
-        self._use_scratch(src for (src, _c, _k) in wbs)  # never released
-
-        def on_complete(t: Task) -> None:
-            # the ONLY per-task Python on the completion side (legacy
-            # protocol; the pump never calls it): land rare cross-tile
-            # write-backs, then signal the native release
-            stats["completion_callbacks"] += 1
-            if wbs:
-                from ..data.data import land_into_home
-
-                for (src_data, cname2, key) in wbs:
-                    home = self.taskpool.constants[cname2].data_of(*key)
-                    newest = src_data.newest_copy()
-                    land_into_home(home, newest.payload)
-            ng.task_done(t.native_id)
-
-        task.on_complete = on_complete
-        self._trace_objs[tid] = task
         dev = self.device
         shim = self._pool_shim
 
@@ -952,25 +821,32 @@ class NativeExecutor:
 
         return body
 
-    def _make_cpu_data_body(self, tid: Tuple) -> Callable[[], Any]:
+    def _on_complete(self, t: Task) -> None:
+        """The ONLY per-task Python on the completion side: land rare
+        cross-tile write-backs, then signal the native release."""
+        self.stats["completion_callbacks"] += 1
+        if t._wbs:
+            from ..data.data import land_into_home
+
+            for (src, home) in t._wbs:
+                land_into_home(home, src.newest_copy().payload)
+        self._ng.task_done(t.native_id)
+
+    def _cpu_data_body(self, pc, info: _TaskInfo, slots, values, gvals,
+                       wbs, datas) -> Callable[[], Any]:
         """CPU-only class in a native_device DAG: run its CPU body through
         the Data staging discipline (stage_to_cpu + version bumps) so
         host and device copies stay coherent across the mixed graph."""
         from .dtd import stage_to_cpu
 
-        tp = self.taskpool
-        cname, locs = tid
-        pc = tp.ptg.classes[cname]
         fn = pc.bodies.get(DEV_CPU)
         if fn is None:
-            raise ValueError(f"native_exec: class {cname} has no body")
-        flow_specs = self._flow_data(tid, pc)
-        scalars = self._scalars_of(pc, locs)
-        wbs = self._write_back_plan(tid)
-        self._use_scratch([d for (_f, d, _m) in flow_specs]
-                          + [src for (src, _c, _k) in wbs])  # never released
-        info = _TaskInfo(cname, locs)
-        self._trace_objs[tid] = info
+            raise ValueError(f"native_exec: class {pc.name} has no body")
+        flow_specs = [(f.name, datas[s] if s >= 0 else None, f.mode)
+                      for f, s in zip(pc.flows, slots) if f.mode != CTL]
+        names = pc.param_names + pc.def_names + pc.body_globals
+        scalars = dict(zip(names, (v[1] for v in (*values, *gvals))))
+        lands = [(datas[a], datas[b]) for a, b in wbs]
 
         def body():
             pins.fire(pins.EXEC_BEGIN, None, info)
@@ -995,16 +871,38 @@ class NativeExecutor:
                 data.version_bump(0)
             pins.fire(pins.EXEC_END, None, info)
             pins.fire(pins.COMPLETE_EXEC_BEGIN, None, info)
-            if wbs:
+            if lands:
                 from ..data.data import land_into_home
 
-                for (src_data, cname2, key) in wbs:
-                    home = self.taskpool.constants[cname2].data_of(*key)
-                    land_into_home(home, src_data.newest_copy().payload)
+                for (src, home) in lands:
+                    land_into_home(home, src.newest_copy().payload)
             pins.fire(pins.COMPLETE_EXEC_END, None, info)
             return False  # synchronous: the worker completes it inline
 
         return body
+
+    def _build(self) -> None:
+        """The numpy path's native graph: a task, a body and a commit a
+        node, an ``add_dep`` an edge."""
+        g = self.graph
+        ng = self._ng = self._native.NativeGraph()
+        index = self._index = {}
+        for tid, node in g.nodes.items():
+            index[tid] = ng.add_task(priority=node.priority,
+                                     user_tag=len(self._bodies))
+            self._bodies.append(self._make_body(tid))
+        for tid, node in g.nodes.items():
+            me = index[tid]
+            for (_f, succ, _sf) in node.out_edges:
+                if index[succ] != me:
+                    ng.add_dep(me, index[succ])
+        # commit only after EVERY edge is declared: committing a task arms
+        # it, and a task whose in-edges arrive after arming would release
+        # early
+        for nid in index.values():
+            ng.commit(nid)
+        ng.seal()
+        self._n_native = len(self._bodies)
 
     def _emit_trace_edges(self) -> None:
         """Bulk dep_edge emission for trace observers: the native path
@@ -1013,17 +911,25 @@ class NativeExecutor:
         RELEASE_DEPS_END site (payload shape matches the dynamic
         runtime's) — profiling.critpath gets its predecessor map without
         any hot-loop instrumentation."""
-        for tid, node in self.graph.nodes.items():
-            if not node.out_edges:
+        objs = self._trace_objs
+        if self.native_device:
+            # (an attach plan keeps the edges by position)
+            edges = enumerate(self.graph.succs)
+        else:
+            edges = ((tid, [s for (_f, s, _sf) in node.out_edges])
+                     for tid, node in self.graph.nodes.items())
+        for t, out in edges:
+            if not out:
                 continue
-            me = self._trace_objs[tid]
-            succs = [self._trace_objs[s] for (_f, s, _sf) in node.out_edges
-                     if self._trace_objs[s] is not me]
+            me = objs[t]
+            succs = [objs[s] for s in out if objs[s] is not me]
             if succs:
                 pins.fire(pins.RELEASE_DEPS_END, None, (me, succs))
 
     # -- default numpy path ----------------------------------------------
-    def _make_numpy_body(self, tid: Tuple) -> Callable[[], None]:
+    def _make_body(self, tid: Tuple) -> Callable[[], None]:
+        """One node's trampoline body: its CPU BODY over in-place numpy
+        tiles."""
         tp = self.taskpool
         g = self.graph
         consts = tp.constants
@@ -1129,9 +1035,9 @@ class NativeExecutor:
             if self._pool_shim is not None and self._pool_shim.failed:
                 raise RuntimeError(
                     f"native device run failed: {self._pool_shim.fail_reason}")
-        if n != len(bodies):
+        if n != self._n_native:
             raise RuntimeError(
-                f"native engine retired {n}/{len(bodies)} tasks")
+                f"native engine retired {n}/{self._n_native} tasks")
         # fused regions collapse N graph tasks into one native node:
         # report LOGICAL task progress (callers compare against the
         # taskpool's task count; without fusion the two are equal)
@@ -1185,7 +1091,7 @@ class NativeExecutor:
         from ..analysis import engine_verify
         from ..analysis.findings import LintError
 
-        n_tasks = max(dict.fromkeys(self._index.values()), default=-1) + 1
+        n_tasks = self._native_base + self._n_native
         dag = engine_verify.SeedDag(
             f"pump:{self.taskpool.ptg.name}", n_tasks, tuple(self._edges))
         fs = engine_verify.conformance_findings(
@@ -1230,12 +1136,16 @@ class NativeExecutor:
         re-running the old DAG over a larger problem would factor a
         corner and report success."""
         if self.native_device:
-            # device tasks bind Data objects and completion flags at build
-            # time; rewinding them safely would need a re-resolution pass.
-            # Build a fresh executor and pass device= to keep the jit cache.
+            # device tasks bind Data objects at build time.  What rebind
+            # amortizes here, the attach plan (dsl/attach_plan.py)
+            # amortizes there: a fresh executor over a same-shape pool
+            # only binds the stored plan to the new tiles.
             raise NotImplementedError(
                 "rebind is not supported with native_device=True; build a "
-                "fresh NativeExecutor(tp, native_device=True, device=dev)")
+                "fresh NativeExecutor(tp, native_device=True, device=dev): "
+                "over a taskpool of a shape already seen it binds the "
+                "stored attach plan (dsl/attach_plan.py) and captures "
+                "nothing")
         self._check_same_shape(tp)
         self.taskpool = tp
         self._new_tiles.clear()
@@ -1344,10 +1254,7 @@ class NativeServeExecutor:
         # BEFORE any child builds: commit-time source pushes must land
         # in the configured wdrr bins
         self.ng.sched_config(policy="wdrr", quantum=quantum, seed=seed)
-        self.stats: Dict[str, int] = {
-            "trampoline_entries": 0, "completion_callbacks": 0,
-            "pop_batches": 0, "done_batches": 0, "pumped_tasks": 0,
-            "events_drained": 0, "prefetched_batches": 0}
+        self.stats: Dict[str, int] = _new_stats()
         self.children: List[NativeExecutor] = []
         self.retire_log: List[Tuple[int, int, float]] = []
         self._pos = 0
@@ -1369,8 +1276,11 @@ class NativeServeExecutor:
             self._pump_index.update(ch._pump_index)
             for nid in ch._pump_index:
                 self._tenant_of[nid] = i
-            # the union pump owns the counters; children share the dict
-            # so their factories' legacy paths (never taken) still count
+            # the union pump owns the counters (each child's attach has
+            # counted its plan); children share the dict so their legacy
+            # paths (never taken) still count
+            for k, v in ch.stats.items():
+                self.stats[k] += v
             ch.stats = self.stats
 
     def run(self) -> List[int]:
@@ -1416,7 +1326,7 @@ class NativeServeExecutor:
         n = _pump_loop(ng, self.device, self._pump_index, self.stats,
                        [ch._pool_shim for ch in self.children], ev,
                        retire_cb)
-        expected = sum(len(ch._bodies) for ch in self.children)
+        expected = sum(ch._n_native for ch in self.children)
         if n != expected:
             raise RuntimeError(
                 f"native serve pump retired {n}/{expected} tasks")

@@ -299,6 +299,24 @@ class NativeGraph:
     def commit(self, task_id: int) -> None:
         self._lib.pz_graph_task_commit(self._g, task_id)
 
+    def add_bulk(self, prio, tenant: int, pred, succ) -> int:
+        """Declare ``len(prio)`` tasks (user tag = position, one tenant)
+        and the edges ``pred[i] -> succ[i]``, which count from the first
+        of them, in ONE call; returns the first task's id.  ``prio`` is a
+        ``ctypes.c_int32`` array, ``pred``/``succ`` ``ctypes.c_int64``
+        arrays (an attach plan keeps them ready).  Nothing is committed:
+        :meth:`commit_range` arms the tasks."""
+        n = len(prio)
+        base = self._lib.pz_graph_add_bulk(self._g, n, prio, int(tenant),
+                                           len(pred), pred, succ)
+        if base < 0:
+            raise ValueError("bad task id in a bulk edge")
+        self._n += n
+        return base
+
+    def commit_range(self, first: int, n: int) -> None:
+        self._lib.pz_graph_commit_range(self._g, first, n)
+
     def seal(self) -> None:
         self._lib.pz_graph_seal(self._g)
 

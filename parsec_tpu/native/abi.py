@@ -127,6 +127,11 @@ SPEC: Dict[str, Dict[str, Any]] = {
     "pz_graph_add_dep": _e("int", ["voidp", "i64", "i64"],
                            note="-1 bad id, 0 pred already ran, 1 edge"),
     "pz_graph_task_commit": _e("void", ["voidp", "i64"]),
+    "pz_graph_add_bulk": _e("i64", ["voidp", "i64", "i32cp", "i32", "i64",
+                                    "i64cp", "i64cp"], CALLER,
+                            "n tasks + edges counted from the first; "
+                            "returns the first id, -1 = bad edge"),
+    "pz_graph_commit_range": _e("void", ["voidp", "i64", "i64"]),
     "pz_graph_reset": _e("int", ["voidp"],
                          note="nonzero = tasks still outstanding"),
     "pz_graph_set_policy": _e("void", ["voidp", "i32"]),
