@@ -67,11 +67,12 @@ def _ops_qr():
 
 
 def _ops_stencil():
-    from ..ops.stencil import StencilBuffers, stencil_ptg
+    from ..ops.stencil import stencil_grid, stencil_ptg
 
-    bufs = StencilBuffers(np.zeros((4, 4)), 2, 2)
+    A = stencil_grid(np.zeros((4, 4)), 2, 2)
     return stencil_ptg(use_cpu=True), \
-        {"T": 3, "MT": 2, "NT": 2, "A": bufs}
+        {"T": 3, "MT": 2, "NT": 2, "A": A, "B": A,
+         "TILE_SHAPE": (A.mb, A.nb), "TILE_DTYPE": A.default_dtype}
 
 
 def _ops_segmented_chol():

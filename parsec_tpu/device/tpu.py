@@ -131,6 +131,11 @@ class TpuDevice(Device):
         #: (device/value_args.py)
         self.stats.update(value_args_dropped=0, value_args_packed=0,
                           value_args_positional=0, tile_args_dropped=0)
+        #: tile operands handed to device programs, and those of them
+        #: that were the same resident array as an earlier operand of
+        #: the same program (a wave of a stencil's generation passes
+        #: every tile to up to five of its tasks)
+        self.stats.update(tile_args_passed=0, tile_args_repeated=0)
         #: scratch tiles (device/scratch.py): first written / dropped
         #: with their last user on this device, and the bytes of them
         #: that crossed the host after all (0 unless one was evicted or
@@ -525,21 +530,27 @@ class TpuDevice(Device):
         return ValuePlan(body, dev_args, sum(
             1 for s in task.body_args or () if s[0] == "value"))
 
-    def _count_values(self, plan: ValuePlan, ntasks: int, sp,
+    def _count_values(self, plan: ValuePlan, ntasks: int, sp, flat,
                       nouts: int = 0) -> None:
-        """``ntasks`` tasks went out under ``plan``: the counters, and
-        the same four on the ``dev:wave`` / ``dev:submit_one`` span with
-        ``outs``, the outputs its epilog commits."""
+        """``ntasks`` tasks went out under ``plan`` with the program's
+        arguments ``flat``: the counters, and the same on the
+        ``dev:wave`` / ``dev:submit_one`` span with ``outs``, the
+        outputs its epilog commits; ``rep`` counts the tile operands
+        that are an earlier operand's array again."""
         drop, pack, pos, tdrop = (
             plan.dropped * ntasks, plan.packed * ntasks,
             plan.positional * ntasks, plan.tiles_dropped * ntasks)
+        tiles = [id(a) for a in flat if isinstance(a, jax.Array)]
+        rep = len(tiles) - len(set(tiles))
         self.stats["value_args_dropped"] += drop
         self.stats["value_args_packed"] += pack
         self.stats["value_args_positional"] += pos
         self.stats["tile_args_dropped"] += tdrop
+        self.stats["tile_args_passed"] += len(tiles)
+        self.stats["tile_args_repeated"] += rep
         if sp is not None:
             sp.note(vdrop=drop, vpack=pack, vpos=pos, tdrop=tdrop,
-                    outs=nouts)
+                    outs=nouts, rep=rep)
 
     def _submit_one(self, task: Task, es, complete: bool = True,
                     drained_ns: int = 0) -> None:
@@ -828,7 +839,7 @@ class TpuDevice(Device):
         if pins.active(pins.EXEC_END):
             for t in grp:
                 self._fire_exec(t, pins.EXEC_END, wave=cnt)
-        self._count_values(plan, cnt, wave_span, len(outs))
+        self._count_values(plan, cnt, wave_span, flat, len(outs))
         if len(outs) != nout * cnt:
             raise ValueError(
                 f"wave of {grp[0].task_class.name}: bodies returned "
@@ -1089,7 +1100,7 @@ class TpuDevice(Device):
             outputs = jitted(*call_args)
         self._fire_exec(task, pins.EXEC_END)
         if plan is not None:
-            self._count_values(plan, 1, span, fplan.nout)
+            self._count_values(plan, 1, span, call_args, fplan.nout)
         if not isinstance(outputs, (tuple, list)):
             outputs = (outputs,)
         if len(outputs) != fplan.nout:

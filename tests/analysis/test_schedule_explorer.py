@@ -98,26 +98,25 @@ T_ITERS = 2
 
 
 def _build_stencil(rank, ctx):
-    from parsec_tpu.ops.stencil import StencilBuffers, stencil_ptg
+    from parsec_tpu.ops.stencil import stencil_grid, stencil_taskpool
 
-    A = StencilBuffers(GRID, 2, 2, nodes=2, myrank=rank,
-                       rank_of=lambda i, j: i % 2)  # row distribution:
-    # UP/DOWN halos cross the ranks every iteration
-    tp = stencil_ptg(use_cpu=True).taskpool(T=T_ITERS, MT=2, NT=2, A=A)
-    return tp, A
+    # row distribution (rank = i % 2): UP/DOWN halos cross the ranks
+    # every iteration
+    A = stencil_grid(GRID, 2, 2, p=2, myrank=rank)
+    return stencil_taskpool(A, T_ITERS, use_cpu=True), A
 
 
 def _stencil_snapshot(users):
-    # digest each rank's OWN tiles of the final parity (remote tiles of
-    # an in-process StencilBuffers hold stale halo landings)
+    # digest each rank's OWN tiles (a remote tile is generation 0 still:
+    # every rank holds the whole input)
     out = []
     for rank, A in enumerate(users):
         tiles = {}
         for i in range(A.mt):
             for j in range(A.nt):
-                if A.rank_of(T_ITERS % 2, i, j) != rank:
+                if A.rank_of(i, j) != rank:
                     continue
-                c = A.data_of(T_ITERS % 2, i, j).newest_copy()
+                c = A.data_of(i, j).newest_copy()
                 arr = np.asarray(c.payload)
                 tiles[(i, j)] = (arr.shape, str(arr.dtype), arr.tobytes())
         out.append(tiles)

@@ -162,16 +162,16 @@ def test_segmented_builders_reject_readably():
             builder(100, 48)
 
 
-def test_stencil_buffers_raise_instead_of_truncating():
+def test_stencil_grid_raises_instead_of_truncating():
     """A non-dividing stencil grid used to be a bare assert (silent
     truncation under -O) — now the shared readable error."""
-    from parsec_tpu.ops.stencil import StencilBuffers
+    from parsec_tpu.ops.stencil import stencil_grid
 
     with pytest.raises(ValueError, match="stencil.*not divisible"):
-        StencilBuffers(np.zeros((9, 8)), 2, 2)
+        stencil_grid(np.zeros((9, 8)), 2, 2)
     # dividing grids still construct
-    b = StencilBuffers(np.zeros((8, 8)), 2, 2)
-    assert (b.th, b.tw) == (4, 4)
+    b = stencil_grid(np.zeros((8, 8)), 2, 2)
+    assert (b.mb, b.nb) == (4, 4)
 
 
 # ---------------------------------------------------------------------------

@@ -37,17 +37,18 @@ def test_native_cholesky_matches_numpy():
 
 def test_native_stencil_matches_reference():
     from parsec_tpu.dsl.native_exec import run_native
-    from parsec_tpu.ops.stencil import StencilBuffers, reference_stencil, stencil_ptg
+    from parsec_tpu.ops.stencil import (reference_stencil, stencil_grid,
+                                        stencil_taskpool)
 
     rng = np.random.default_rng(1)
     grid = rng.standard_normal((24, 36))
     mt, nt, iters = 3, 3, 4
-    A = StencilBuffers(grid, mt, nt)
-    tp = stencil_ptg().taskpool(T=iters, MT=mt, NT=nt, A=A)
+    A = stencil_grid(grid, mt, nt)
+    tp = stencil_taskpool(A, iters)
     ran = run_native(tp, nthreads=4)
     assert ran == iters * mt * nt
     np.testing.assert_allclose(
-        A.to_array(iters % 2), reference_stencil(grid, iters), rtol=1e-12)
+        A.to_array(), reference_stencil(grid, iters), rtol=1e-12)
 
 
 def test_native_matches_dynamic_runtime_results():

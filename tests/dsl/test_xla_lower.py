@@ -166,15 +166,16 @@ def test_batched_levels_cholesky_matches(use_pallas):
 
 
 def test_batched_levels_stencil_matches():
-    from parsec_tpu.ops.stencil import StencilBuffers, reference_stencil, stencil_ptg
+    from parsec_tpu.ops.stencil import (reference_stencil, stencil_grid,
+                                        stencil_taskpool)
 
     rng = np.random.default_rng(8)
     grid = rng.standard_normal((32, 32)).astype(np.float32)
-    A = StencilBuffers(grid, 4, 4)
-    tp = stencil_ptg(use_tpu=True, use_cpu=False).taskpool(T=4, MT=4, NT=4, A=A)
+    A = stencil_grid(grid, 4, 4)
+    tp = stencil_taskpool(A, 4, use_tpu=True, use_cpu=False)
     ex = GraphExecutor(tp, batch_levels=True)
     ex(block=True)
-    np.testing.assert_allclose(A.to_array(4 % 2), reference_stencil(grid, 4),
+    np.testing.assert_allclose(A.to_array(), reference_stencil(grid, 4),
                                rtol=1e-5, atol=1e-5)
 
 

@@ -11,7 +11,7 @@ import pytest
 
 from parsec_tpu import Context, native
 from parsec_tpu.comm import InprocFabric
-from parsec_tpu.ops.stencil import StencilBuffers, stencil_ptg
+from parsec_tpu.ops.stencil import stencil_grid, stencil_taskpool
 from parsec_tpu.profiling import pins
 from parsec_tpu.profiling.binary import BinaryTaskProfiler, to_chrome_events
 from parsec_tpu.profiling.tools import comm_overlap_fraction
@@ -56,11 +56,11 @@ def test_stencil_overlap_fraction_from_trace(tmp_path):
         def worker(r):
             rng = np.random.default_rng(5)
             g = rng.standard_normal((MT * tile, NT * tile))
-            A = StencilBuffers(g, MT, NT, nodes=nranks, myrank=r,
-                               rank_of=lambda i, j: i % nranks)  # row dist:
-            # UP/DOWN halos cross ranks every iteration
+            # row distribution (rank = i % nranks): UP/DOWN halos cross
+            # ranks every iteration
+            A = stencil_grid(g, MT, NT, p=nranks, myrank=r)
             grids[r] = A
-            tp = stencil_ptg(use_cpu=True).taskpool(T=T, MT=MT, NT=NT, A=A)
+            tp = stencil_taskpool(A, T, use_cpu=True)
             ctxs[r].add_taskpool(tp)
             oks[r] = tp.wait(timeout=120)
 

@@ -17,7 +17,7 @@ from parsec_tpu.comm import InprocFabric
 from parsec_tpu.datadist import TiledMatrix, TwoDimBlockCyclic
 from parsec_tpu.ops.cholesky import cholesky_ptg
 from parsec_tpu.ops.lu import lu_ptg
-from parsec_tpu.ops.stencil import StencilBuffers, stencil_ptg
+from parsec_tpu.ops.stencil import stencil_grid, stencil_taskpool
 
 N, NB = 64, 16
 
@@ -49,24 +49,21 @@ def _build_pool(kind: str, rank: int = 0, nranks: int = 1):
         A.from_array(LUIN)
         return lu_ptg(use_tpu=False).taskpool(NT=A.mt, A=A), A
     if kind.startswith("stencil"):
-        bufs = StencilBuffers(
-            GRID, 4, 3, nodes=nranks, myrank=rank,
-            rank_of=(lambda i, j: i % nranks) if nranks > 1 else None)
-        tp = stencil_ptg().taskpool(T=ST_ITERS, MT=4, NT=3, A=bufs)
-        return tp, bufs
+        # rows over the ranks: rank = i % nranks
+        bufs = stencil_grid(GRID, 4, 3, p=nranks, myrank=rank)
+        return stencil_taskpool(bufs, ST_ITERS), bufs
     raise ValueError(kind)
 
 
 def _digest(kind, user):
     if kind.startswith("stencil"):
-        # this rank's tiles of the final parity buffer, bit-exact
+        # this rank's tiles of the grid after the sweeps, bit-exact
         out = {}
-        parity = ST_ITERS % 2
         for i in range(user.mt):
             for j in range(user.nt):
-                if user.rank_of(parity, i, j) != user.myrank:
+                if user.rank_of(i, j) != user.myrank:
                     continue
-                c = user.data_of(parity, i, j).newest_copy()
+                c = user.data_of(i, j).newest_copy()
                 arr = np.asarray(c.payload)
                 out[(i, j)] = (arr.shape, str(arr.dtype), arr.tobytes())
         return out
