@@ -152,6 +152,7 @@ class Task:
         "_tpu_enq",
         "_tpu_scratch",
         "_tpu_home",
+        "_tpu_next",
         "_tpu_sig",
     )
 
@@ -208,6 +209,11 @@ class Task:
         #: device module's write-back committer takes only these); None
         #: where whoever built the task does not know: then every one
         self._tpu_home: Optional[Tuple[int, ...]] = None
+        #: where the task's row starts in its pool's table of next uses
+        #: (``taskpool.next_use[_tpu_next + position in body_args]``: the
+        #: rank of the tile's next reader, ``device/residency.py``); -1
+        #: where whoever built the task does not know
+        self._tpu_next = -1
         #: the task's signature ``(wave key, FlowPlan)``, once the device
         #: module has worked it out (a ready task's flows no longer
         #: change): the key is None when it cannot ride a wave; None

@@ -32,6 +32,20 @@ an allocation can fail under budget and evict) or a byte counter.
 Callers see :meth:`~Residency.account` / :meth:`~Residency.free` /
 :meth:`~Residency.settle` / :meth:`~Residency.clear` and never ask which.
 
+**The victim order** (``PERF.md`` §6, PR 33).  Age says little about a
+DAG: a panel tile of a right-looking factorization is read by every
+update of its step and never again, while a trailing tile touched one
+step ago is needed at the next.  Whoever touches a tile may say when it
+is read NEXT (:meth:`~Residency.next_uses`: the rank of its next reader
+in the order its pool's tasks run, ``dsl/attach_plan.py``, or
+:data:`NEVER`).  Victims are then, among the unpinned tiles: those never
+read again, then those nobody said anything about, then the known ones,
+the farthest reader first; ties in the order above (oldest first, clean
+before dirty).  Where nobody says anything (a ``Context``-path pool, a
+pool without a stored plan) that IS the order above.  A tile of unknown
+use goes before every known one: what is known of a tile is that a task
+of the running pool WILL read it.
+
 ``lock`` is the residency lock: LRU and accounting mutations are not
 single-threaded once the transfer lane prestages wave N+1 while the
 pump thread commits wave N.  RLock — the stage/evict/account paths
@@ -58,6 +72,11 @@ from ..utils import debug, mca_param
 #: is staged, and a copy home leaves neither a cached host value beside
 #: the resident tile nor a landing copy (``staging.HostWriter._alias``)
 OUT_OF_CORE = True
+
+#: a next use: no task reads the tile's version here again
+NEVER = 1 << 62
+#: ... and: nobody knows (below every rank)
+UNKNOWN = -1
 
 
 def native_zone(platform: str) -> bool:
@@ -98,7 +117,8 @@ class Residency:
         self.index = data_index
         self.stats = stats
         for k in ("evictions", "evict_clean", "evict_dirty",
-                  "evict_bytes_home", "evict_batches", "restaged_tiles",
+                  "evict_bytes_home", "evict_batches", "evict_next_use",
+                  "evict_never_again", "restaged_tiles",
                   "reserve_gave_up", "unaccounted_tiles"):
             stats.setdefault(k, 0)
         self._span = span or (lambda name, **info: contextlib.nullcontext())
@@ -120,6 +140,12 @@ class Residency:
         self._offsets: Dict[int, int] = {}
         #: data_id -> how many stagings hold the tile: no victim
         self._pins: Dict[int, int] = {}
+        #: data_id -> the rank of the tile's next reader, or
+        #: :data:`NEVER`; absent: unknown.  Only ever raised while the
+        #: copy stays (the readers of a tile may run out of rank order:
+        #: the one staged last must not bring an earlier reader back),
+        #: forgotten with the copy
+        self._next: Dict[int, int] = {}
         #: tiles an eviction dropped, until they are staged in again
         #: (``restaged_tiles``)
         self._evicted: set = set()
@@ -245,6 +271,7 @@ class Residency:
             self._offsets.clear()
             self._held.clear()
             self._pins.clear()
+            self._next.clear()
             self._evicted.clear()
             self.used = 0 if self.zone is None else self.zone.used
 
@@ -254,6 +281,17 @@ class Residency:
         with self.lock:
             self.forget(data)
             (self.dirty if dirty else self.clean)[data.data_id] = data
+
+    def next_uses(self, ranks: Dict[int, int]) -> None:
+        """What the tasks being staged or committed know of their
+        tiles, data_id -> the rank of the NEXT reader, :data:`NEVER`, or
+        :data:`UNKNOWN`, which says nothing (the caller holds the lock).
+        A rank is only raised: of two readers staged out of rank order
+        the later one's answer stands."""
+        nxt = self._next
+        for did, rank in ranks.items():
+            if rank > nxt.get(did, UNKNOWN):
+                nxt[did] = rank
 
     def forget(self, data: Data) -> None:
         """Out of both LRUs (the caller holds the lock): no victim."""
@@ -335,26 +373,37 @@ class Residency:
     def _victims(self, need: int) -> List[Tuple[Data, bool]]:
         """Out of the LRUs, unpinned, until their slots cover ``need``
         bytes, as ``(tile, was dirty)``: oldest first, clean before
-        dirty."""
+        dirty; and where next uses are known, in that order those never
+        read again, those of unknown use, then the known ones, the
+        farthest reader first (the sort is stable)."""
+        pins, held, nxt = self._pins, self._held, self._next
+        order = [(did, lru) for lru in (self.clean, self.dirty)
+                 for did in lru if did not in pins]
+        if nxt:
+            unknown = NEVER - 1
+            order.sort(key=lambda v: -nxt.get(v[0], unknown))
         out: List[Tuple[Data, bool]] = []
-        pins, held = self._pins, self._held
-        for lru in (self.clean, self.dirty):
-            for did in [d for d in lru if d not in pins]:
-                if need <= 0:
-                    return out
-                out.append((lru.pop(did), lru is self.dirty))
-                need -= held.get(did, 0)
+        for did, lru in order:
+            if need <= 0:
+                break
+            out.append((lru.pop(did), lru is self.dirty))
+            need -= held.get(did, 0)
         return out
 
     def _evict(self, need: int) -> bool:
         """One batch of victims for ``need`` bytes (the caller holds the
         lock): those whose copy here is the only valid one go home
         first, together, then every victim drops.  A ``dev:evict`` span
-        with ``victims``, ``dirty`` (written home), ``bytes_home`` and
-        ``wait_us`` (of the write-back's one wait).  False: no victim."""
+        with ``victims``, ``dirty`` (written home), ``bytes_home``,
+        ``wait_us`` (of the write-back's one wait), ``known`` (victims
+        whose next use somebody had said) and ``never`` (of them, those
+        with no reader left).  False: no victim."""
         victims = self._victims(need)
         if not victims:
             return False
+        uses = [self._next.get(v.data_id, UNKNOWN) for v, _d in victims]
+        known = sum(1 for u in uses if u >= 0)
+        never = uses.count(NEVER)
         with self._span("dev:evict", need=need) as sp:
             home: List[Data] = []
             bytes_home = 0
@@ -378,9 +427,12 @@ class Residency:
             self.stats["evict_dirty"] += len(home)
             self.stats["evict_clean"] += len(victims) - len(home)
             self.stats["evict_bytes_home"] += bytes_home
+            self.stats["evict_next_use"] += known
+            self.stats["evict_never_again"] += never
             if sp is not None:
                 sp.note(victims=len(victims), dirty=len(home),
-                        bytes_home=bytes_home, wait_us=wait_us)
+                        bytes_home=bytes_home, wait_us=wait_us,
+                        known=known, never=never)
         return True
 
     def restaged(self, data: Data) -> None:
@@ -394,6 +446,7 @@ class Residency:
         """Detach ``data``'s copy here and release its slot."""
         with self.lock:
             c = data.detach_copy(self.index)
+            self._next.pop(data.data_id, None)
             if c is not None:
                 self.free(data)
                 if evicted:

@@ -908,9 +908,14 @@ class TpuDevice(Device):
         #: data_id -> [tile, (argument list, position) it still misses in]
         missing: Dict[int, List[Any]] = {}
         ntiles = nread = nmiss = 0
+        #: data_id -> the rank of the tile's next reader after this
+        #: chunk, as the tasks' pools know it (``Residency.next_uses``)
+        nexts: Dict[int, int] = {}
         with res.lock:
             for task in grp:
                 specs = task.body_args
+                at = task._tpu_next
+                uses = task.taskpool.next_use if at >= 0 else None
                 args: List[Any] = []
                 ospecs: List[Tuple[int, Data]] = []
                 mine: List[Data] = []  # scratch tiles: one user each
@@ -931,6 +936,10 @@ class TpuDevice(Device):
                     if how == READ:
                         nread += 1
                         did = data.data_id
+                        if uses is not None:
+                            use = uses[at + pos]
+                            if use > nexts.get(did, -1):
+                                nexts[did] = use
                         arr = found.get(did)
                         if arr is None:
                             c = data.current_copy(idx)
@@ -986,6 +995,8 @@ class TpuDevice(Device):
                     nmiss += len(slot) - 1
             for data, access in owns:
                 data.transfer_ownership(idx, access)
+            if nexts:
+                res.next_uses(nexts)
         tally[2] += ntiles
         tally[3] += nread - nmiss
         return staged
@@ -1353,6 +1364,9 @@ class TpuDevice(Device):
         bumps_heard = pins.active(pins.DATA_VERSION_BUMP)
         res = self._res
         going: List[Data] = []
+        #: data_id -> the rank of an output's next reader, as the
+        #: tasks' pools know it (``Residency.next_uses``)
+        nexts: Dict[int, int] = {}
         #: every task here knows which of its outputs are last versions
         last = True
         done: List[Task] = []
@@ -1383,10 +1397,15 @@ class TpuDevice(Device):
                     if epilogs_heard:
                         pins.fire(pins.DEVICE_EPILOG_BEGIN, None, task)
                     home = task._tpu_home
+                    nx = task._tpu_next
+                    uses = task.taskpool.next_use if nx >= 0 else None
                     at = k * nout
                     for j, (pos, data) in enumerate(ospecs):
                         self._commit_output(data, outs[at + j], sizes[j],
                                             bumps_heard)
+                        if uses is not None:
+                            # (the new version's first reader)
+                            nexts[data.data_id] = uses[nx + pos]
                         if data.scratch is None \
                                 and (home is None or pos in home):
                             going.append(data)
@@ -1395,6 +1414,8 @@ class TpuDevice(Device):
                     if task._tpu_scratch:
                         self._release_scratch(task._tpu_scratch)
                     done.append(task)
+                if nexts:
+                    res.next_uses(nexts)
                 # outputs grew residency: re-settle under the budget
                 res.settle()
             self.stats["task_commits" if alone else "wave_commits"] += 1

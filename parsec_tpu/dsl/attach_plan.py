@@ -31,6 +31,7 @@ import ast
 import collections
 import ctypes
 import functools
+import heapq
 import sys
 import threading
 from typing import Any, Dict, Iterable, List, Optional, Tuple
@@ -38,6 +39,7 @@ from typing import Any, Dict, Iterable, List, Optional, Tuple
 import numpy as np
 
 from ..core.lifecycle import AccessMode, DEV_CPU
+from ..device.residency import NEVER, UNKNOWN
 from .graph import TaskGraph, source_tile
 from .ptg import (CTL, PTG, _ArgExpr, _DataRef, _SAFE_BUILTINS, _TaskRef,
                   _c_to_py)
@@ -47,6 +49,10 @@ TaskId = Tuple[str, Tuple]
 #: plans kept (least recently bound goes first).  The out-of-core DAG
 #: (15,180 tasks, 42,570 edges) is 11.5 MB of small tuples (PERF.md §6)
 PLAN_CACHE_SIZE = 8
+
+#: the pump's ``(batch, depth)`` where nobody says (the defaults of
+#: ``runtime_native_drain`` and ``runtime_stage_depth``)
+PUMP_WINDOW = (128, 2)
 
 #: ``AttachPlan.tasks[i][3]``: a flow with no tile behind it
 NO_DATA = -1
@@ -69,7 +75,7 @@ class AttachPlan:
         "key", "ptg_name", "classes", "device_class", "has_cpu_bodies",
         "nodes", "tasks", "succs", "tiles", "tile_users",
         "node_of", "native", "native_prio", "edge_pred", "edge_succ",
-        "roots", "fused")
+        "roots", "fused", "next_use", "next_at")
 
     def __init__(self):
         #: the fingerprint it is stored under; None = bound, never stored
@@ -109,6 +115,14 @@ class AttachPlan:
         #: per fused region ``(FusedPlan, slot per program argument,
         #: member positions, write-backs)``
         self.fused: Tuple[Tuple, ...] = ()
+        #: when a tile is read NEXT (:func:`_next_uses`): per native
+        #: node one entry per position of its ``body_args`` that may hold
+        #: a tile (a task's declared flows, a fused region's program
+        #: arguments), the node's at ``next_at[node]``: the rank of the
+        #: next node that reads the tile behind that position,
+        #: ``residency.NEVER``, or ``residency.UNKNOWN``
+        self.next_use: Tuple[int, ...] = ()
+        self.next_at: Tuple[int, ...] = ()
 
     def nbytes(self) -> int:
         """Bytes the plan keeps (containers and the small values in
@@ -255,14 +269,16 @@ def _const_fp(name, v) -> Tuple:
     return fp
 
 
-def plan_key(tp, ranks: Iterable[int], fusion: Tuple) -> Tuple:
+def plan_key(tp, ranks: Iterable[int], fusion: Tuple,
+             window: Tuple[int, int]) -> Tuple:
     """The fingerprint of everything a captured, partitioned and
-    resolved graph of ``tp`` is a function of; raises
+    resolved graph of ``tp`` is a function of (``window``: the pump's
+    batches, which rank the tasks: :func:`build_plan`); raises
     :class:`Uncacheable` where it cannot vouch for some part."""
-    return ("attach-plan-1", _ptg_fp(tp.ptg),
+    return ("attach-plan-2", _ptg_fp(tp.ptg),
             tuple(sorted((str(k), _const_fp(k, v))
                          for k, v in tp.constants.items())),
-            tuple(sorted(ranks)), fusion)
+            tuple(sorted(ranks)), fusion, tuple(window))
 
 
 # ---------------------------------------------------------------------------
@@ -309,10 +325,14 @@ def stored() -> List[AttachPlan]:
 _VALUE = AccessMode.VALUE
 
 
-def build_plan(tp, g: TaskGraph, regions=()) -> AttachPlan:
+def build_plan(tp, g: TaskGraph, regions=(),
+               window: Optional[Tuple[int, int]] = PUMP_WINDOW) -> AttachPlan:
     """Resolve the captured graph ``g`` of ``tp`` (and its fused
     ``regions``) into a plan: every per-task fact the bind needs, with
-    flows resolved to tile slots, and the contracted native graph."""
+    flows resolved to tile slots, the contracted native graph, and when
+    each tile is read next in the order a pump of ``window = (batch,
+    depth)`` runs that graph (``native_exec._pump_window``; None: a
+    plan without that table, whose tiles' next uses nobody knows)."""
     ptg_classes = tp.ptg.classes
     consts = tp.constants
     plan = AttachPlan()
@@ -459,7 +479,122 @@ def build_plan(tp, g: TaskGraph, regions=()) -> AttachPlan:
     plan.edge_pred = (ctypes.c_int64 * len(pred))(*pred)
     plan.edge_succ = (ctypes.c_int64 * len(succ))(*succ)
     plan.roots = tuple(n for n in range(len(native)) if n not in has_pred)
+
+    if window is None:
+        return plan
+    # when each tile is read next: per native node its tile positions'
+    # (slot, the flow reads it, the node's class runs on the device)
+    def reads(mode) -> bool:
+        return int(mode) & int(AccessMode.INOUT) != int(AccessMode.OUT)
+
+    cls_reads = [tuple(reads(f.mode) for f in cls_flows[cname])
+                 for cname in plan.classes]
+    touches: List[List[Tuple[int, bool, bool]]] = []
+    for pos in native:
+        if pos < 0:
+            fp, slots = fused_rows[~pos][:2]
+            touches.append([(s, reads(m), True)
+                            for s, m in zip(slots, fp.slot_modes)])
+        else:
+            ci, flows = rows[pos][0], rows[pos][3]
+            on_device = device_class[ci]
+            touches.append([(s, r, on_device)
+                            for s, r in zip(flows, cls_reads[ci])])
+    plan.next_use, plan.next_at = _next_uses(
+        touches, _pump_rank(prio, pred, succ, plan.roots, *window),
+        len(tiles))
     return plan
+
+
+def _pump_rank(prio: List[int], pred: List[int], succ: List[int],
+               roots: Iterable[int], batch: int, depth: int) -> List[int]:
+    """Per native node its place in the order the pump runs the graph
+    (``native_exec._pump_loop``), replayed: up to ``depth`` batches of
+    up to ``batch`` ready nodes are popped — the highest priority first,
+    the one ready longest among equals (the native ``SchedQ``'s ``prio``
+    discipline, ``native/src/graph.cpp``) — before the oldest batch
+    runs and releases its successors.  On one device the pump's order is
+    a function of the graph and these two numbers alone.  (Replayed one
+    node at a time, the priority-greedy linearisation, a ready frontier
+    drains in another order than the pump's: 45% more evictions in the
+    out-of-core dpotrf, ``PERF.md`` §6, PR 33.)"""
+    n = len(prio)
+    succs: List[List[int]] = [[] for _ in range(n)]
+    missing = [0] * n
+    for a, b in zip(pred, succ):
+        succs[a].append(b)
+        missing[b] += 1
+    ready = [(-prio[r], k, r) for k, r in enumerate(roots)]
+    heapq.heapify(ready)
+    seq = len(ready)
+    rank = [0] * n
+    at = 0
+    window: "collections.deque[List[int]]" = collections.deque()
+    while True:
+        while ready and len(window) < depth:
+            window.append([heapq.heappop(ready)[2]
+                           for _ in range(min(batch, len(ready)))])
+        if not window:
+            return rank
+        nodes = window.popleft()
+        for node in nodes:
+            rank[node] = at
+            at += 1
+        for node in nodes:
+            for s in succs[node]:
+                missing[s] -= 1
+                if not missing[s]:
+                    heapq.heappush(ready, (-prio[s], seq, s))
+                    seq += 1
+
+
+def _next_uses(touches: List[List[Tuple[int, bool, bool]]], rank: List[int],
+               nslots: int) -> Tuple[Tuple[int, ...], Tuple[int, ...]]:
+    """``(next_use, next_at)`` of a plan.  ``touches``: per native node,
+    per position of its ``body_args`` that may hold a tile, ``(slot,
+    the node reads it, the node runs on the device)``; ``rank``: per node
+    its place in the order the nodes tend to run.
+
+    The next use of a tile after a node is the rank of the next node, in
+    rank order, that touches its slot — :data:`NEVER` where there is
+    none, or where that node only overwrites it (the version here is
+    dead by then).  A slot that a CPU body touches is :data:`UNKNOWN`
+    throughout: such a task never passes the device's staging walk, so
+    nothing would move the tile's next use past it."""
+    #: per slot its readers and writers as (rank, reads)
+    users: List[List[Tuple[int, bool]]] = [[] for _ in range(nslots)]
+    host_slots = set()
+    for node, flows in enumerate(touches):
+        for s, reads, on_device in flows:
+            if s < 0:
+                continue
+            users[s].append((rank[node], reads))
+            if not on_device:
+                host_slots.add(s)
+    #: per slot, rank -> what follows it
+    after: List[Dict[int, int]] = []
+    for s, us in enumerate(users):
+        nxt: Dict[int, int] = {}
+        if s not in host_slots:
+            # (a node that touches a slot by two flows reads it if either
+            # does: from the far end its reading flow comes first)
+            us.sort()
+            follows, at, at_reads = NEVER, -1, False
+            for r, reads in reversed(us):
+                if r != at:
+                    if at >= 0:
+                        follows = at if at_reads else NEVER
+                    at, at_reads = r, reads
+                    nxt[r] = follows
+        after.append(nxt)
+    next_use: List[int] = []
+    next_at: List[int] = []
+    for node, flows in enumerate(touches):
+        next_at.append(len(next_use))
+        r = rank[node]
+        next_use.extend(after[s].get(r, UNKNOWN) if s >= 0 else UNKNOWN
+                        for s, _reads, _dev in flows)
+    return tuple(next_use), tuple(next_at)
 
 
 def _fused_row(tp, g: TaskGraph, region, slot, tiles, nodes,

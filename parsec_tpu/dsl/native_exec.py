@@ -80,6 +80,19 @@ def _drain_batch() -> int:
              "batch call (also floors the lifecycle-event drain buffer)"))
 
 
+def _pump_window(dev) -> Tuple[int, int]:
+    """``(batch, depth)`` of the pump over ``dev``: ready tasks a
+    ``pop_batch`` and batches popped before the oldest is submitted.
+    With the staging pipeline (``stage_depth > 1``) the pop buffer
+    shrinks to ``runtime_native_drain // stage_depth``, so that one wide
+    ready wave splits into ``stage_depth`` batches."""
+    cap = max(1, _drain_batch())
+    depth = max(1, int(getattr(dev, "stage_depth", 1) or 1))
+    if depth == 1 or not hasattr(dev, "prestage_tiles"):
+        return cap, 1
+    return max(1, cap // depth), depth
+
+
 def _conformance_on() -> bool:
     from ..utils import mca_param
 
@@ -150,6 +163,9 @@ class _NativePoolShim:
         self.failed = False
         self.fail_reason: Optional[str] = None
         self.context = None
+        #: the attach plan's table of next uses, which the pool's tasks
+        #: index by ``_tpu_next`` (the device module's victim order)
+        self.next_use: Tuple[int, ...] = ()
 
     def _force_fail(self) -> bool:
         if self.failed:
@@ -300,15 +316,11 @@ def _pump_loop(ng, dev, pump_index: Dict[int, Any], stats: Dict[str, int],
     from ..core import scheduling
     from ..data.data import land_into_home
 
-    cap = max(1, _drain_batch())
-    depth = max(1, int(getattr(dev, "stage_depth", 1) or 1))
+    chunk, depth = _pump_window(dev)
     lane = None
-    if depth > 1 and hasattr(dev, "prestage_tiles"):
+    if depth > 1:
         from ..device.staging import StageLane
         lane = StageLane(dev)
-    else:
-        depth = 1
-    chunk = max(1, cap // depth) if lane is not None else cap
     free = deque((ctypes.c_int64 * chunk)() for _ in range(depth))
     window: deque = deque()  # (buf, n, batch, stage_job|None, seq)
     rank = getattr(dev.context, "rank", 0)
@@ -455,6 +467,8 @@ class NativeExecutor:
         self.taskpool = tp
         self.native_device = bool(native_device)
         self.device = device
+        #: the device is this executor's to detach when nobody closed it
+        self._own_device = device is None
         #: control-plane counters (the zero-entry pin reads them)
         self.stats: Dict[str, int] = _new_stats()
         #: serve mode (NativeServeExecutor): build into ITS shared native
@@ -525,12 +539,15 @@ class NativeExecutor:
         else:
             cfg = (mode, fusion_max_tasks(device=self.device),
                    fusion_scan_mode())
+        #: the pump's batches, which the plan's table of next uses
+        #: ranks the tasks by
+        window = _pump_window(self.device)
         key = None
         if graph is None:
             # (a handed-in graph is the caller's: nothing here can vouch
             # for what it was captured from)
             try:
-                key = attach_plan.plan_key(tp, (0,), cfg)
+                key = attach_plan.plan_key(tp, (0,), cfg, window)
             except attach_plan.Uncacheable as e:
                 from ..utils import debug
 
@@ -543,8 +560,12 @@ class NativeExecutor:
                 return plan, "hit"
         with pins.span("attach:plan", pool=tp.taskpool_id, rank=0):
             g = graph if graph is not None else capture(tp, ranks=[0])
+            # (a plan that is stored nowhere is built again at every
+            # solve: it gets no table of next uses, which a solve would
+            # pay for each time)
             plan = attach_plan.build_plan(
-                tp, g, self._partition_regions(g, cfg))
+                tp, g, self._partition_regions(g, cfg),
+                window if key is not None else None)
         if key is None:
             self.stats["attach_plan_uncacheable"] += 1
             return plan, "uncacheable"
@@ -697,6 +718,8 @@ class NativeExecutor:
         fused_classes: Dict[str, TaskClass] = {}
         ctl = ("ctl", None, CTL)
         shim = self._pool_shim
+        shim.next_use = plan.next_use
+        next_at = plan.next_at
         dev = self.device
         pump = self._pump
         index = self._pump_index
@@ -746,6 +769,8 @@ class NativeExecutor:
                     task._wbs = [(datas[a], datas[b]) for a, b in wbs]
             task.selected_device = dev
             task.native_id = nid
+            if next_at:
+                task._tpu_next = next_at[nid - base]
             index[nid] = task
             if not pump:
                 # legacy ASYNC-chore protocol: the trampoline enqueues,
@@ -1209,6 +1234,14 @@ class NativeExecutor:
 
     def __del__(self):  # pragma: no cover
         try:
+            if not getattr(self, "_own_device", True):
+                # a device that was handed in is its sharers': a
+                # finalizer, on whatever thread the collector happens to
+                # run, does not detach it under them (on the write-back
+                # committer's own thread the detach's flush could not
+                # return before its timeout, with the committer stuck
+                # under everybody else's flush meanwhile)
+                self.device = None
             self.close()
         except Exception:
             pass

@@ -341,3 +341,34 @@ def test_native_device_values_dropped_or_packed(tmp_path, exported, graph):
         assert bool(dev._ccache.stats["bytes_written"]) == exported
     finally:
         dev.detach()
+
+
+@pytest.mark.parametrize("handed_in", [True, False])
+def test_a_finalizer_detaches_only_a_device_the_executor_made(handed_in):
+    """An executor nobody closed is finalized wherever the collector
+    runs — once on the write-back committer's own thread, where the
+    detach's flush waited 300 s for itself under everybody else's flush.
+    A device that was handed in (``device=``) is its sharers' to detach:
+    the finalizer leaves its tiles and its committer alone.  A device the
+    executor made itself is still flushed home and detached."""
+    import gc
+
+    from parsec_tpu.dsl.native_exec import NativeExecutor
+
+    dev = NativeExecutor._make_device() if handed_in else None
+    S, A, tp = _dpotrf_taskpool(64, 16, seed=5)
+    ex = NativeExecutor(tp, native_device=True, device=dev)
+    dev = ex.device
+    assert ex.run() == 20
+    resident = len(dev._res.clean) + len(dev._res.dirty)
+    assert resident == 10
+    del ex, tp
+    gc.collect()
+    left = len(dev._res.clean) + len(dev._res.dirty)
+    if handed_in:
+        assert left == resident and dev._committer is not None
+        dev.detach()
+    else:
+        assert left == 0 and dev._committer is None
+    L = np.tril(A.to_array())
+    np.testing.assert_allclose(L @ L.T, S, rtol=1e-10, atol=1e-10)

@@ -18,7 +18,7 @@ import pytest
 from parsec_tpu import native
 from parsec_tpu.data import data_create
 from parsec_tpu.datadist import TiledMatrix
-from parsec_tpu.device.residency import Residency
+from parsec_tpu.device.residency import NEVER, UNKNOWN, Residency
 from parsec_tpu.dsl.native_exec import NativeExecutor
 from parsec_tpu.ops import cholesky_ptg
 from parsec_tpu.utils import mca_param
@@ -182,6 +182,199 @@ def test_victims_are_the_oldest_unpinned_clean_before_dirty():
         [True, False, True, True, False, False]
     assert stats["evict_clean"] == 2 and stats["evict_dirty"] == 1
     assert stats["evict_batches"] == 1
+
+
+# ---------------------------------------------------------------------------
+# victims by next use (PR 33): who touches a tile may say when it is read
+# next; without that the order above stands
+# ---------------------------------------------------------------------------
+
+def _victim_order(tiles, pinned=(), room=None):
+    """``tiles``: ``(key, dirty, next use or None)`` in the order they
+    were touched; returns the keys in the order eviction takes them all
+    (or as many as ``room`` tiles of need take)."""
+    stats = collections.Counter()
+    order = []
+    res = Residency(1, len(tiles) * 1024, stats,
+                    lambda victims: order.append(None) or 0)
+    datas = {}
+    for key, dirty, use in tiles:
+        d = datas[key] = _resident(res, key, 1024, dirty=dirty)
+        if use is not None:
+            with res.lock:
+                res.next_uses({d.data_id: use})
+    drop = res.drop
+
+    def spy(data, **kw):
+        order.append(data.key)
+        return drop(data, **kw)
+
+    res.drop = spy
+    with res.lock:
+        for key in pinned:
+            res.pin(datas[key])
+        left = len(tiles) - len(pinned)
+        res.reserve((room if room is not None else left) * 1024)
+    return [k for k in order if k is not None], stats
+
+
+VICTIM_ORDERS = {
+    # never read again first, whatever their age: clean before dirty
+    "never_again_first": (
+        [("soon", False, 3), ("dead_dirty", True, NEVER), ("far", True, 90),
+         ("dead", False, NEVER)],
+        (), ["dead", "dead_dirty", "far", "soon"]),
+    # then the farthest next reader, clean or dirty, young or old
+    "farthest_rank_first": (
+        [("r5", False, 5), ("r70", True, 70), ("r20", False, 20),
+         ("r71", False, 71), ("r6", True, 6)],
+        (), ["r71", "r70", "r20", "r6", "r5"]),
+    # a pinned tile is passed over, however far its next reader
+    "pinned_passed_over": (
+        [("a", False, NEVER), ("b", True, 99), ("c", False, 7),
+         ("d", True, 50)],
+        ("a", "b"), ["d", "c"]),
+    # nobody said anything: today's order, to the tile
+    "unknown_as_today": (
+        [("c1", False, None), ("d1", True, None), ("c2", False, None),
+         ("d2", True, None), ("c3", False, None)],
+        (), ["c1", "c2", "c3", "d1", "d2"]),
+    # equal ranks: oldest first, clean before dirty
+    "ties_as_today": (
+        [("d1", True, 8), ("c1", False, 8), ("d2", True, 8),
+         ("c2", False, 8)],
+        (), ["c1", "c2", "d1", "d2"]),
+    # a mixed set: never, then unknown (clean before dirty, oldest
+    # first), then known ones from the far end; the pinned one stays
+    "mixed": (
+        [("k9", True, 9), ("u_d", True, None), ("dead", True, NEVER),
+         ("u_c2", False, None), ("k40", False, 40), ("pinned", False, NEVER),
+         ("u_c1", False, None), ("k2", False, 2)],
+        ("pinned",),
+        ["dead", "u_c2", "u_c1", "u_d", "k40", "k9", "k2"]),
+}
+
+
+@pytest.mark.parametrize("case", sorted(VICTIM_ORDERS))
+def test_the_victim_order(case):
+    tiles, pinned, want = VICTIM_ORDERS[case]
+    got, stats = _victim_order(tiles, pinned)
+    assert got == want
+    known = sum(1 for k, _d, use in tiles
+                if use is not None and k not in pinned)
+    never = sum(1 for k, _d, use in tiles if use == NEVER
+                and k not in pinned)
+    assert stats["evictions"] == len(want)
+    assert stats["evict_next_use"] == known
+    assert stats["evict_never_again"] == never
+    assert stats["evict_clean"] + stats["evict_dirty"] == len(want)
+
+
+def test_only_as_many_victims_as_the_room_takes():
+    tiles, _pinned, want = VICTIM_ORDERS["mixed"]
+    got, stats = _victim_order(tiles, ("pinned",), room=3)
+    assert got == want[:3] and stats["evictions"] == 3
+    assert stats["evict_next_use"] == stats["evict_never_again"] == 1
+
+
+def test_a_next_use_is_only_raised_and_goes_with_the_copy():
+    """Readers of a tile may be staged out of rank order: the answer of
+    the one that ran LAST in rank order stands (an earlier reader's
+    ``next`` has run already).  ``UNKNOWN`` says nothing; a drop, a
+    release and a detach forget."""
+    res = Residency(1, 8 * 1024, collections.Counter(), lambda victims: 0)
+    a, b, c = (_resident(res, k, 1024) for k in "abc")
+    with res.lock:
+        res.next_uses({a.data_id: 20, b.data_id: NEVER})
+        res.next_uses({a.data_id: 9, b.data_id: 4})     # out of order
+        assert res._next == {a.data_id: 20, b.data_id: NEVER}
+        res.touch(a, dirty=True)                         # says nothing
+        assert res._next == {a.data_id: 20, b.data_id: NEVER}
+        res.next_uses({c.data_id: 0})                    # rank 0 is a rank
+        assert res._next[c.data_id] == 0
+        res.next_uses({a.data_id: UNKNOWN})              # says nothing
+        assert res._next[a.data_id] == 20
+        res.drop(a)
+        assert a.data_id not in res._next
+        res.drop(b)
+        assert b.data_id not in res._next
+        res.release(c)
+        assert not res._next
+        res.next_uses({c.data_id: 5})
+    res.clear()
+    assert not res._next
+
+
+def test_the_evict_span_says_how_many_victims_had_a_known_use():
+    notes = []
+
+    class _Span:
+        def __enter__(self):
+            return self
+
+        def __exit__(self, *exc):
+            return False
+
+        def note(self, **kw):
+            notes.append(kw)
+
+    res = Residency(1, 4 * 1024, collections.Counter(),
+                    lambda victims: 11, span=lambda name, **info: _Span())
+    for key, use in (("a", NEVER), ("b", 3), ("c", None), ("d", NEVER)):
+        d = _resident(res, key, 1024, dirty=key == "b")
+        if use is not None:
+            with res.lock:
+                res.next_uses({d.data_id: use})
+    with res.lock:
+        assert res.reserve(4 * 1024)
+    assert notes == [{"victims": 4, "dirty": 1, "bytes_home": 1024,
+                      "wait_us": 11, "known": 3, "never": 2}]
+
+
+class _Forgetful(Residency):
+    """The residency as it was: it is told, and forgets."""
+
+    def next_uses(self, ranks):
+        pass
+
+
+def _ooc_solve(monkeypatch, residency, nt=10, nb=32, tiles=40, seed=11):
+    from parsec_tpu.device import tpu
+    from parsec_tpu.dsl import attach_plan
+
+    monkeypatch.setattr(tpu, "Residency", residency)
+    attach_plan.clear()
+    spd = _spd(nt * nb, seed)
+    ran, dev, A = _solve(spd, nb, budget=tiles * nb * nb * 4)
+    attach_plan.clear()
+    assert type(dev._res) is residency
+    assert ran == nt + nt * (nt - 1) + nt * (nt - 1) * (nt - 2) // 6
+    want = np.linalg.cholesky(spd.astype(np.float64))
+    got = np.tril(A.to_array().astype(np.float64))
+    assert np.max(np.abs(got - want)) / np.max(np.abs(want)) < 2e-6
+    s = dev.stats
+    assert s["reserve_gave_up"] == s["unaccounted_tiles"] == 0
+    assert s["wave_fallbacks"] == s["submit_retries"] == 0
+    assert s["evict_clean"] + s["evict_dirty"] == s["evictions"] > 0
+    return s
+
+
+@needs_native
+def test_by_next_use_less_comes_in_and_fewer_tiles_are_evicted(monkeypatch):
+    """55 lower tiles against room for 40: the same solve, both right,
+    with the residency as it is and with one that forgets what it is
+    told (the order of PR 30): fewer evictions, fewer bytes in, fewer
+    victims written home."""
+    new = dict(_ooc_solve(monkeypatch, Residency))
+    old = dict(_ooc_solve(monkeypatch, _Forgetful))
+    assert old["evict_next_use"] == old["evict_never_again"] == 0
+    assert new["evict_next_use"] == new["evictions"]      # none unknown
+    assert 0 < new["evict_never_again"] < new["evictions"]
+    assert new["evictions"] < 0.7 * old["evictions"]
+    assert new["bytes_in"] < 0.8 * old["bytes_in"]
+    assert new["evict_bytes_home"] < old["evict_bytes_home"]
+    assert new["restaged_tiles"] < old["restaged_tiles"]
+    assert new["bytes_out"] < old["bytes_out"]
 
 
 def test_the_chunk_limit_follows_the_budget():
