@@ -63,6 +63,7 @@ from typing import (Any, Callable, Dict, Iterable, List, MutableMapping,
                     Tuple)
 
 from ..data.data import Coherency, Data
+from ..profiling import pins
 from ..utils import debug, mca_param
 
 #: Declared capability, for whoever must refuse a program without it
@@ -323,15 +324,18 @@ class Residency:
         self._pins[did] = self._pins.get(did, 0) + 1
 
     def unpin(self, datas: Iterable[Data]) -> None:
-        with self.lock:
-            pins = self._pins
+        # (the one method here that the solve path calls WITHOUT the
+        # lock: after a chunk, a batch, a lane's walk; taken as the
+        # device module takes it, so that a wait for it is seen)
+        with pins.held(self.lock, "res_lock"):
+            pinned = self._pins
             for data in datas:
                 did = data.data_id
-                n = pins.get(did, 0) - 1
+                n = pinned.get(did, 0) - 1
                 if n > 0:
-                    pins[did] = n
+                    pinned[did] = n
                 else:
-                    pins.pop(did, None)
+                    pinned.pop(did, None)
 
     @property
     def chunk_limit(self) -> int:

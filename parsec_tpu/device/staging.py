@@ -30,6 +30,15 @@ device moves here, synchronously or on the pipeline's two threads.
   residency lock — commits are pure Data-level operations and cannot
   deadlock against an eviction that holds it.
 
+The lock order is ``residency.py``'s: the device's ``_lock`` -> the
+residency lock -> ``Data.lock``.  On the solve path the residency lock
+is taken with ``pins.held(res.lock, "res_lock")``, here and in the
+device module: the same acquisition, which in a profiler session leaves
+a ``wait:res_lock`` event, naming the holder's span, wherever a thread
+had to wait for it (``docs/TRACING.md`` "Waits").  A bare ``with
+res.lock:`` on that path is the exception: whoever waits behind it
+waits unseen, and whoever waits for it names no holder.
+
 A committer failure is STICKY: the stored exception re-raises on the
 next ``enqueue`` (failing the task pool through the device layer's
 fail-loudly discipline) and on ``flush`` (failing ``detach()``), so a
@@ -230,7 +239,7 @@ class StageIn:
         over the put (the synchronous regime's way, and
         ``data_advise``'s: nobody waits for it)."""
         got: Dict[int, Any] = {}
-        with self.res.lock:
+        with pins.held(self.res.lock, "res_lock"):
             self.batch((data,), tally, coalesce=False, got=got)
         return got[data.data_id]
 
@@ -272,7 +281,7 @@ class StageIn:
         moving: List[Tuple[Data, Any, int]] = []
         pinned: List[Data] = []
         try:
-            with res.lock:
+            with pins.held(res.lock, "res_lock"):
                 for data in datas:
                     mine = data.get_copy(idx)
                     if mine is not None \
@@ -366,7 +375,8 @@ class StageIn:
                     arrs = [unalias(a, h, h, jdev)
                             for a, h in zip(arrs, hosts)]
         except BaseException:
-            with res.lock:  # the room made for what never arrived
+            # the room made for what never arrived
+            with pins.held(res.lock, "res_lock"):
                 for (data, _h, _v) in puts:
                     mine = data.get_copy(idx)
                     if mine is None or mine.payload is None:
@@ -375,7 +385,7 @@ class StageIn:
         if tally is not None:
             tally[0] += len(puts)
             tally[1] += nbytes
-        with res.lock:
+        with pins.held(res.lock, "res_lock"):
             for (data, host, ver), arr in zip(puts, arrs):
                 stats["bytes_in"] += host.nbytes
                 if data.scratch is not None:
@@ -407,7 +417,7 @@ class StageIn:
         stage_in writes into the GPU copy buffer the same way); residency
         is accounted at the STAGED size, which may differ from the home
         tile's (packed subtile)."""
-        with self.res.lock:
+        with pins.held(self.res.lock, "res_lock"):
             mine = data.get_copy(self.index)
             newest = data.newest_copy()
             if mine is not None and newest is not None \
@@ -683,14 +693,15 @@ class HostWriter:
             return 0, 0
         self.stats["wb_early_hits"] += hits
         committed = 0
+        nbytes = sum(int(getattr(p, "nbytes", 0)) for (_d, p, _v) in snaps)
         with pins.span("dev:writeback", pool=pool, rank=self.rank,
                        id=span_id(), tiles=len(snaps), batch=batch,
-                       bytes=sum(int(getattr(p, "nbytes", 0))
-                                 for (_d, p, _v) in snaps)) as sp:
+                       bytes=nbytes) as sp:
             t0 = time.perf_counter_ns()
             if ahead:
-                for a in arrays:
-                    _start_copy(a)
+                with pins.wait("d2h_start") as w:
+                    n = sum(map(_start_copy, arrays))
+                    w.note(n=n, bytes=nbytes)
             hosts = self.d2h_batch(arrays)
             sp.note(wait_us=(time.perf_counter_ns() - t0) // 1000,
                     early=hits)
@@ -792,9 +803,20 @@ class WritebackCommitter:
                 pins.fire(pins.HB_WB_ENQUEUE, None,
                           {"ticket": ticket, "data": data.data_id})
             c = data.get_copy(index)
-            entries.append((data, ticket, c.nbytes if c is not None else 0,
-                            self._writer.start(data) if last else None))
+            entries.append([data, ticket, c.nbytes if c is not None else 0,
+                            None])
             tickets.append(ticket)
+        if last:
+            # the thread is inside the runtime for as long as the copies
+            # take to start, and can do nothing else: a wait
+            with pins.wait("d2h_start") as w:
+                n = nbytes = 0
+                for e in entries:
+                    e[3] = self._writer.start(e[0])
+                    if e[3] is not None:
+                        n += 1
+                        nbytes += e[2]
+                w.note(n=n, bytes=nbytes)
         # the capacity: 4x the watermark, or what this one hand-over
         # brings if that is more (a chunk's last versions of 16 MiB
         # tiles are 256 MiB against 128): the wait is for what was
@@ -803,11 +825,13 @@ class WritebackCommitter:
         with self._cv:
             self._raise_if_dead()
             for data, ticket, nb, early in entries:
-                while (self._pending_bytes + nb > cap and self._pending
-                       and self.error is None and not self._stop):
-                    self.stats["capacity_waits"] += 1
-                    self._cv.notify_all()  # what is queued may drain
-                    self._cv.wait(timeout=1.0)
+                if self._over(nb, cap):
+                    with pins.wait("wb_capacity",
+                                   pending_mb=self._pending_bytes >> 20):
+                        while self._over(nb, cap):
+                            self.stats["capacity_waits"] += 1
+                            self._cv.notify_all()  # what is queued may drain
+                            self._cv.wait(timeout=1.0)
                 self._raise_if_dead()
                 entry = self._pending.get(data.data_id)
                 if entry is None:
@@ -822,6 +846,12 @@ class WritebackCommitter:
                 self._kick = True
             self._cv.notify_all()
         return tickets
+
+    def _over(self, nb: int, cap: int) -> bool:
+        """Whether ``nb`` more bytes have to wait for a drain (called
+        with ``_cv`` held)."""
+        return (self._pending_bytes + nb > cap and bool(self._pending)
+                and self.error is None and not self._stop)
 
     def _raise_if_dead(self) -> None:
         if self.error is not None:

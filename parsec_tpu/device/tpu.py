@@ -329,7 +329,7 @@ class TpuDevice(Device):
         """Reference ``parsec_device_kernel_scheduler``
         (device_gpu.c:2510-2730)."""
         task._tpu_enq = time.perf_counter_ns()  # ready-queue wait starts
-        with self._lock:
+        with pins.held(self._lock, "dev_lock"):
             self._pending.append(task)
             if self._manager_active:
                 return HookReturn.ASYNC  # a manager is already running
@@ -352,7 +352,7 @@ class TpuDevice(Device):
         # instead of one per task); everything else goes per-task.
         while True:
             drained: List[Task] = []
-            with self._lock:
+            with pins.held(self._lock, "dev_lock"):
                 while self._pending:
                     drained.append(self._pending.popleft())
             drained_ns = time.perf_counter_ns()  # ready-queue wait ends
@@ -371,7 +371,7 @@ class TpuDevice(Device):
             # phase: get_data_out — retire ready computations in order
             with self._span("dev:poll"):
                 progressed = self._poll_deferred(es)
-            with self._lock:
+            with pins.held(self._lock, "dev_lock"):
                 if not self._pending and not self._deferred:
                     self._manager_active = False
                     return
@@ -461,7 +461,7 @@ class TpuDevice(Device):
             # mode, so drain retries here before handing the batch back
             # for retirement
             while True:
-                with self._lock:
+                with pins.held(self._lock, "dev_lock"):
                     if not self._pending:
                         return
                     retry = list(self._pending)
@@ -911,7 +911,7 @@ class TpuDevice(Device):
         #: data_id -> the rank of the tile's next reader after this
         #: chunk, as the tasks' pools know it (``Residency.next_uses``)
         nexts: Dict[int, int] = {}
-        with res.lock:
+        with pins.held(res.lock, "res_lock"):
             for task in grp:
                 specs = task.body_args
                 at = task._tpu_next
@@ -1238,7 +1238,11 @@ class TpuDevice(Device):
         it would take the same copies one drain at a time while this
         thread waited for each tile in turn, and the version guard makes
         a copy the committer also holds (a last version on its way) land
-        once whoever is first.  The caller holds the residency lock: the
+        once whoever is first.  The caller holds the residency lock —
+        taken with ``pins.held(res.lock, "res_lock")``, as every site on
+        the solve path takes it, so that whoever waits for it meanwhile
+        leaves a ``wait:res_lock`` event naming this eviction as the
+        holder (a bare ``with res.lock:`` there would wait unseen): the
         victims must be home before their device copies drop, and nobody
         may rewrite one in between.  Returns the microseconds waited."""
         t0 = time.perf_counter_ns()
@@ -1371,7 +1375,7 @@ class TpuDevice(Device):
         last = True
         done: List[Task] = []
         try:
-            with res.lock:
+            with pins.held(res.lock, "res_lock"):
                 if out_hooks is not None:
                     outs = list(outs)
                     for k, so in enumerate(out_hooks * len(staged)):
@@ -1503,7 +1507,7 @@ class TpuDevice(Device):
                 raise
             com.close(flush=False)
             self._committer = None
-        with self._res.lock:
+        with pins.held(self._res.lock, "res_lock"):
             # flush remaining dirty tiles home as ONE batched device->host
             # get — the version guard makes tiles the
             # committer already landed a no-op, so each dirty tile

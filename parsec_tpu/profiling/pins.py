@@ -10,7 +10,8 @@ registered for the site (the reference gates with an enable mask,
 from __future__ import annotations
 
 import threading
-from typing import Any, Callable, Dict, Tuple
+import time
+from typing import Any, Callable, Dict, List, Tuple
 
 # callback sites (reference PARSEC_PINS_FLAG enum)
 SELECT_BEGIN = "select_begin"
@@ -179,8 +180,9 @@ def fire(site: str, es: Any, payload: Any) -> None:
             debug.warning("pins callback for %s raised: %s", site, e)
 
 
-#: span name -> (profiler name, begin site, end site), built once per name
-_span_names: Dict[str, Tuple[str, str, str]] = {}
+#: span name -> (profiler name, begin site, end site, the name a lock's
+#: holder is named by), built once per name
+_span_names: Dict[str, Tuple[str, str, str, str]] = {}
 _annotation: Any = None
 
 
@@ -196,17 +198,68 @@ def _tracing() -> bool:
     return _tracing()
 
 
-def _names_of(name: str) -> Tuple[str, str, str]:
+def _names_of(name: str) -> Tuple[str, str, str, str]:
+    if name.startswith("wait:"):
+        # a wait (:func:`wait`) is no ``parsec:*`` span, has no site and
+        # is nobody's holder
+        names = _span_names[name] = ("parsec-" + name, "", "", "")
+        return names
     base = _LEGACY_SPAN_SITES.get(name, name)
     names = _span_names[name] = ("parsec:" + name, base + "_begin",
-                                 base + "_end")
+                                 base + "_end", name)
     return names
+
+
+_thread_cpu_ns = time.thread_time_ns
+_wall_ns = time.perf_counter_ns
+_thread = threading.local()
+
+
+def _open_spans() -> List[str]:
+    """The ``parsec:*`` spans open on the calling thread, outermost
+    first, kept while a profiler session runs (what :func:`held` names a
+    lock's holder by)."""
+    try:
+        return _thread.open
+    except AttributeError:
+        names = _thread.open = []
+        return names
+
+
+#: The thread-CPU clock is a system call: 0.25 us on a plain kernel; under
+#: the sandboxed kernel of the benchmark's machine 6 us in a loop, about
+#: as much again in what the thread does next, and a tick of 10 ms.  Two
+#: reads a span made a traced pump solve a fifth longer there
+#: (``PERF.md`` §6, PR 34).  So the reads have a budget: at most this
+#: share of the wall time, each read debited with what it took (one
+#: under a microsecond is free: a plain kernel times every span; what is
+#: over 50 us was a wait for the GIL or the CPU inside the bracket, not
+#: the read), saved up to the burst.  Who decides is the OUTERMOST span
+#: of a thread, for everything inside it: a span carries ``cpu_us`` if
+#: and only if its parent does.
+_CPU_SHARE = 0.005
+_CPU_FREE_NS = 1_000
+_CPU_DEAR_NS = 50_000
+_CPU_BURST_NS = 2_000_000
+_cpu_credit_ns = float(_CPU_BURST_NS)  # one account for all threads: under
+_cpu_credit_at = 0                     # one GIL a read holds them all up
+
+
+def _cpu_tree() -> bool:
+    """Whether the spans of the tree that begins on the calling thread
+    now may read the thread-CPU clock."""
+    global _cpu_credit_ns, _cpu_credit_at
+    now = _wall_ns()
+    _cpu_credit_ns = min(_CPU_BURST_NS, _cpu_credit_ns
+                         + (now - _cpu_credit_at) * _CPU_SHARE)
+    _cpu_credit_at = now
+    return _cpu_credit_ns > 0
 
 
 class _Span:
     """A span that at least one sink receives (see :func:`span`)."""
 
-    __slots__ = ("_names", "_es", "_payload", "_ann")
+    __slots__ = ("_names", "_es", "_payload", "_ann", "_cpu0")
 
     def __init__(self, name: str, es: Any, payload: Any,
                  info: Dict[str, Any]):
@@ -216,9 +269,29 @@ class _Span:
         self._ann = _annotation(names[0], **info)
 
     def __enter__(self) -> "_Span":
+        global _cpu_credit_ns
         self._ann.__enter__()
-        if _enabled and _subscribers.get(self._names[1]):
-            fire(self._names[1], self._es, self._payload)
+        names = self._names
+        if _enabled and _subscribers.get(names[1]):
+            fire(names[1], self._es, self._payload)
+        if _tracing():
+            stack = _open_spans()
+            if not stack:
+                _thread.cpu = _cpu_tree()
+            if names[3]:
+                stack.append(names[3])
+            if _thread.cpu:
+                # (read last, and first in __exit__: the CPU time lies
+                # inside the event's wall time)
+                t = _wall_ns()
+                self._cpu0 = _thread_cpu_ns()
+                t = _wall_ns() - t
+                if t > _CPU_FREE_NS:  # this read and the end's
+                    _cpu_credit_ns -= 2 * min(t, _CPU_DEAR_NS)
+            else:
+                self._cpu0 = -1
+        else:
+            self._cpu0 = None
         return self
 
     def note(self, **more: Any) -> None:
@@ -231,6 +304,13 @@ class _Span:
         self._payload = payload
 
     def __exit__(self, *exc: Any) -> bool:
+        cpu0 = self._cpu0
+        if cpu0 is not None:  # a session ran when the span began
+            if cpu0 >= 0:
+                self._ann.set_metadata(
+                    cpu_us=(_thread_cpu_ns() - cpu0) / 1e3)
+            if self._names[3]:
+                _open_spans().pop()
         if _enabled and _subscribers.get(self._names[2]):
             fire(self._names[2], self._es, self._payload)
         self._ann.__exit__(*exc)
@@ -272,12 +352,88 @@ def span(name: str, es: Any = None, payload: Any = None, **info: Any):
     the site carries one) or else the keyword arguments.  ``sp.note``
     adds counts known only at the end to both sinks; ``sp.end`` gives
     the END site a payload of its own (``release_deps_end``'s ``(task,
-    ready)``).  With no session and no subscriber at all the span is a
-    shared no-op (0.4-0.6 us; 1.4-1.8 us with a sink on; CPU of the
-    sandbox, a count of the constant)."""
+    ready)``).  While a session runs the event also carries ``cpu_us``,
+    the CPU time of the calling thread between begin and end
+    (``time.thread_time_ns``): duration minus ``cpu_us`` is what the
+    thread spent off the CPU inside the span — blocked in a call, or
+    waiting for a lock or the GIL.  (Every event where that clock is
+    cheap; where it is dear, whole trees of spans within a budget of
+    0.5% of the wall time: :func:`_cpu_tree`.)  With no session and no subscriber at
+    all the span is a shared no-op (0.4-0.6 us; 1.4-1.8 us with a sink
+    on; CPU of the sandbox, a count of the constant)."""
     if _tracing() or _enabled:  # (_tracing first: it loads jax's class)
         return _Span(name, es, payload, info)
     return _QUIET
+
+
+def wait(what: str, **info: Any):
+    """A stretch in which the calling thread can do nothing but wait:
+    ``with pins.wait("wb_capacity", pending_mb=..) as w:`` is the event
+    ``parsec-wait:wb_capacity`` of a profiler session (the same
+    primitive as :func:`span`, ``w.note`` and ``cpu_us`` included) and
+    the shared no-op without one.  Not ``parsec:``: a wait is a child
+    that the readers of the ``parsec:*`` spans' self times must not see
+    (``docs/TRACING.md`` "Waits"); it fires no PINS site."""
+    if _tracing():
+        return _Span("wait:" + what, None, None, info)
+    return _QUIET
+
+
+#: id(lock) -> [the open spans of the thread that holds it through
+#: :func:`held` (:func:`_open_spans`: the list itself, so a waiter reads
+#: what the holder is in NOW), how many times it took it]; written only
+#: by the thread that holds the lock
+_holders: Dict[int, List[Any]] = {}
+
+
+class _Held:
+    """What :func:`held` hands out while a profiler session runs."""
+
+    __slots__ = ("_lock", "_what")
+
+    def __init__(self, lock: Any, what: str):
+        self._lock = lock
+        self._what = what
+
+    def __enter__(self) -> "_Held":
+        lock = self._lock
+        if not lock.acquire(False):
+            entry = _holders.get(id(lock))
+            inner = entry[0][-1:] if entry is not None else ()
+            with wait(self._what, holder=inner[0] if inner else "none"):
+                lock.acquire()
+        mine = _open_spans()
+        entry = _holders.get(id(lock))
+        if entry is not None and entry[0] is mine:
+            entry[1] += 1  # an RLock, taken again by its holder
+        else:
+            _holders[id(lock)] = [mine, 1]
+        return self
+
+    def __exit__(self, *exc: Any) -> bool:
+        lock = self._lock
+        entry = _holders.get(id(lock))
+        if entry is not None and entry[0] is _open_spans():
+            entry[1] -= 1
+            if not entry[1]:
+                del _holders[id(lock)]
+        lock.release()
+        return False
+
+
+def held(lock: Any, what: str):
+    """``with pins.held(res.lock, "res_lock"):`` takes ``lock`` as ``with
+    lock:`` does, and says how long the thread waited for it.  With no
+    profiler session it returns ``lock`` itself.  With one, an
+    acquisition that has to block is a ``parsec-wait:<what>`` event
+    (:func:`wait`) whose ``holder`` is the innermost ``parsec:*`` span
+    open, when the wait began, on the thread that held the lock (``none``
+    where that thread was in no span, or took the lock bare: the
+    profiler drops an empty argument); one that does not block, a
+    re-entrant one included, leaves no event."""
+    if _tracing():
+        return _Held(lock, what)
+    return lock
 
 
 def clear() -> None:
