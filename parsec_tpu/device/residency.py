@@ -52,15 +52,30 @@ pump thread commits wave N.  RLock — the stage/evict/account paths
 nest.  Order: the device's ``_lock`` -> ``lock`` -> ``Data.lock``; the
 write-back committer takes only ``Data.lock``, so an eviction writing
 its victims home under ``lock`` cannot deadlock against it.
+
+**An eviction and the lock** (``PERF.md`` §6, PR 35).  A copy home is
+waited for by the thread that needs its room, with no lock held that
+the pump's commit takes.  Whoever comes WITHOUT the lock (the transfer
+lane: :meth:`~Residency.make_room`) evicts in two holds of it: the
+victims are chosen under the first (out of the LRUs, so nobody picks
+them twice; still accounted, so the budget holds), go home with the
+lock FREE, and drop under the second, each only if nobody staged,
+pinned or rewrote it meanwhile and its host copy stands
+(``evict_cancelled`` counts the others, which stay).  Whoever holds
+the lock around a whole walk (the pump's own staging walk, a commit's
+:meth:`~Residency.settle`, the synchronous regime) evicts inside that
+hold, as ever: an RLock cannot be let go from inside, and nobody waits
+for those.  So the write-back callable is NOT promised the lock.
 """
 
 from __future__ import annotations
 
 import collections
 import contextlib
+import dataclasses
 import threading
 from typing import (Any, Callable, Dict, Iterable, List, MutableMapping,
-                    Tuple)
+                    Optional, Tuple)
 
 from ..data.data import Coherency, Data
 from ..profiling import pins
@@ -101,6 +116,24 @@ def native_zone(platform: str) -> bool:
     return built
 
 
+@dataclasses.dataclass
+class _Leaving:
+    """The victims of one eviction between their choice and their drop."""
+
+    #: (tile, was dirty, the version of its copy here or -1 for none,
+    #: the bytes that go home for it: 0 where its host copy stands)
+    victims: List[Tuple[Data, bool, int, int]]
+    #: victims whose next use somebody had said; of them, never again
+    known: int = 0
+    never: int = 0
+
+    @property
+    def home(self) -> List[Data]:
+        """The victims whose copy here is the only valid one: they go
+        home, as one batch, before anything drops."""
+        return [v for v, _dirty, _version, nbytes in self.victims if nbytes]
+
+
 class Residency:
     """The resident tiles of one device and their bytes."""
 
@@ -111,15 +144,16 @@ class Residency:
         """``data_index``: the device's slot in ``Data.copies``;
         ``writeback(victims)``: bring the victims' device copies home, as
         one batch, before they drop, and return the microseconds it
-        waited; ``stats``: where ``evictions`` and their kin are counted;
-        ``zone``: account in the native zone allocator
+        waited (called with the lock held or free: the module's "An
+        eviction and the lock"); ``stats``: where ``evictions`` and
+        their kin are counted; ``zone``: account in the native zone allocator
         (:func:`native_zone`); ``span``: what opens a span of the device
         (``dev:evict``)."""
         self.index = data_index
         self.stats = stats
         for k in ("evictions", "evict_clean", "evict_dirty",
                   "evict_bytes_home", "evict_batches", "evict_next_use",
-                  "evict_never_again", "restaged_tiles",
+                  "evict_never_again", "evict_cancelled", "restaged_tiles",
                   "reserve_gave_up", "unaccounted_tiles"):
             stats.setdefault(k, 0)
         self._span = span or (lambda name, **info: contextlib.nullcontext())
@@ -394,50 +428,146 @@ class Residency:
             need -= held.get(did, 0)
         return out
 
+    def make_room(self, nbytes: int) -> None:
+        """One eviction towards ``nbytes`` of room for a caller that
+        comes WITHOUT the lock and will account what it brings under a
+        hold of its own (the transfer lane, ``StageIn.batch``): the
+        victims go home with the lock free, so that the pump's commit
+        does not wait for their copies (the module's "An eviction and
+        the lock").  Advisory: what was cancelled, or taken by somebody
+        else meanwhile, the caller's :meth:`reserve` makes up for under
+        its hold, which is the one more round, and the one that gives
+        up."""
+        with pins.held(self.lock, "res_lock"):
+            need = self._in_use() + nbytes - self._budget
+            leaving = self._leaving(need) if need > 0 else None
+        if leaving is None:
+            return
+        home = leaving.home
+        with self._span("dev:evict", need=need) as sp:
+            wait_us = 0
+            try:
+                if home:
+                    wait_us = self._writeback(home)
+            finally:
+                # (also after a write-back that raised: the victims are
+                # in no LRU, and what did not get home stays)
+                with pins.held(self.lock, "res_lock"):
+                    self._leave(leaving, wait_us, sp, check=True)
+
     def _evict(self, need: int) -> bool:
         """One batch of victims for ``need`` bytes (the caller holds the
-        lock): those whose copy here is the only valid one go home
-        first, together, then every victim drops.  A ``dev:evict`` span
-        with ``victims``, ``dirty`` (written home), ``bytes_home``,
-        ``wait_us`` (of the write-back's one wait), ``known`` (victims
-        whose next use somebody had said) and ``never`` (of them, those
-        with no reader left).  False: no victim."""
+        lock, and keeps it): those whose copy here is the only valid one
+        go home first, together, then every victim drops.  A
+        ``dev:evict`` span with ``victims``, ``dirty`` (written home),
+        ``bytes_home``, ``wait_us`` (of the write-back's one wait),
+        ``known`` (victims whose next use somebody had said), ``never``
+        (of them, those with no reader left) and ``cancelled`` (victims
+        that stayed: only where the lock was free meanwhile,
+        :meth:`make_room`).  False: no victim."""
+        leaving = self._leaving(need)
+        if leaving is None:
+            return False
+        home = leaving.home
+        with self._span("dev:evict", need=need) as sp:
+            wait_us = self._writeback(home) if home else 0
+            self._leave(leaving, wait_us, sp, check=False)
+        return True
+
+    def _leaving(self, need: int) -> Optional["_Leaving"]:
+        """The first half of an eviction (the caller holds the lock):
+        the victims for ``need`` bytes, each with the version its copy
+        here has, and those of them that have to go home first.  None:
+        no victim."""
         victims = self._victims(need)
         if not victims:
-            return False
-        uses = [self._next.get(v.data_id, UNKNOWN) for v, _d in victims]
-        known = sum(1 for u in uses if u >= 0)
-        never = uses.count(NEVER)
-        with self._span("dev:evict", need=need) as sp:
-            home: List[Data] = []
-            bytes_home = 0
-            for victim, dirty in victims:
-                mine = victim.get_copy(self.index)
-                if mine is None or mine.payload is None:
-                    continue
-                host = victim.get_copy(0)
-                # a CLEAN device copy can still be the ONLY valid copy:
-                # device-native arrivals (_deposit_payload, bytes_d2d)
-                # attach no host copy — dropping without write-back
-                # would destroy the data
-                if dirty or host is None or host.payload is None \
-                        or host.version < mine.version:
-                    home.append(victim)
-                    bytes_home += mine.nbytes
-            wait_us = self._writeback(home) if home else 0
-            for victim, _dirty in victims:
-                self.drop(victim)
-            self.stats["evict_batches"] += 1
-            self.stats["evict_dirty"] += len(home)
-            self.stats["evict_clean"] += len(victims) - len(home)
-            self.stats["evict_bytes_home"] += bytes_home
-            self.stats["evict_next_use"] += known
-            self.stats["evict_never_again"] += never
-            if sp is not None:
-                sp.note(victims=len(victims), dirty=len(home),
-                        bytes_home=bytes_home, wait_us=wait_us,
-                        known=known, never=never)
-        return True
+            return None
+        idx = self.index
+        out = _Leaving([])
+        for victim, dirty in victims:
+            use = self._next.get(victim.data_id, UNKNOWN)
+            out.known += use >= 0
+            out.never += use == NEVER
+            mine = victim.get_copy(idx)
+            if mine is None or mine.payload is None:
+                out.victims.append((victim, dirty, -1, 0))
+                continue
+            host = victim.get_copy(0)
+            # a CLEAN device copy can still be the ONLY valid copy:
+            # device-native arrivals (_deposit_payload, bytes_d2d)
+            # attach no host copy — dropping without write-back
+            # would destroy the data
+            goes = dirty or host is None or host.payload is None \
+                or host.version < mine.version
+            out.victims.append((victim, dirty, mine.version,
+                                mine.nbytes if goes else 0))
+        return out
+
+    def _stays(self, victim: Data, version: int) -> bool:
+        """Whether a victim that went home with the lock free is no
+        longer this eviction's to drop (the caller holds the lock):
+        somebody staged, warmed or rewrote it meanwhile (it is back in
+        an LRU) or pinned it; its copy here is not the one that went
+        home, or is gone (released by its last user, or staged, let go
+        and evicted by a walk that held the lock: counted there); or its
+        host copy does not stand at that version, and the copy here is
+        still the only valid one."""
+        did = victim.data_id
+        if did in self._pins or did in self.clean or did in self.dirty:
+            return True
+        mine = victim.get_copy(self.index)
+        host = victim.get_copy(0)
+        return mine is None or mine.payload is None \
+            or mine.version != version or host is None \
+            or host.payload is None or host.version < version
+
+    def _leave(self, leaving: "_Leaving", wait_us: int, sp,
+               check: bool) -> None:
+        """The second half of an eviction (the caller holds the lock):
+        the victims drop — with ``check`` (the lock was free
+        meanwhile), those that :meth:`_stays` lets go; the others are
+        counted, and one that is resident and in no LRU goes back as the
+        oldest of its own — and the span and the counters say what
+        happened."""
+        cancelled = left_clean = left_dirty = bytes_left = bytes_home = 0
+        went_home = 0
+        back: List[Tuple[Data, bool]] = []
+        for victim, dirty, version, nbytes in leaving.victims:
+            bytes_home += nbytes
+            went_home += bool(nbytes)
+            if check and self._stays(victim, version):
+                cancelled += 1
+                did = victim.data_id
+                if did not in self.clean and did not in self.dirty \
+                        and victim.get_copy(self.index) is not None:
+                    back.append((victim, dirty))
+                continue
+            if nbytes:
+                left_dirty += 1
+                bytes_left += nbytes
+            else:
+                left_clean += 1
+            self.drop(victim)
+        for victim, dirty in reversed(back):  # (in their old order)
+            lru = self.dirty if dirty else self.clean
+            lru[victim.data_id] = victim
+            lru.move_to_end(victim.data_id, last=False)
+        # the counters are of the victims that LEFT (``evict_clean`` +
+        # ``evict_dirty`` = ``evictions``), the span is of what was
+        # chosen and what went over the link; ``cancelled`` is between
+        stats = self.stats
+        stats["evict_batches"] += 1
+        stats["evict_clean"] += left_clean
+        stats["evict_dirty"] += left_dirty
+        stats["evict_bytes_home"] += bytes_left
+        stats["evict_next_use"] += leaving.known
+        stats["evict_never_again"] += leaving.never
+        stats["evict_cancelled"] += cancelled
+        if sp is not None:
+            sp.note(victims=len(leaving.victims), dirty=went_home,
+                    bytes_home=bytes_home, wait_us=wait_us,
+                    known=leaving.known, never=leaving.never,
+                    cancelled=cancelled)
 
     def restaged(self, data: Data) -> None:
         """``data`` is staged in (the caller holds the lock): counted,

@@ -246,7 +246,8 @@ class StageIn:
     def batch(self, datas, tally: Optional[List[int]] = None,
               coalesce: bool = True,
               got: Optional[Dict[int, Any]] = None,
-              keep: Optional[List[Data]] = None) -> int:
+              keep: Optional[List[Data]] = None,
+              unlocked: bool = False) -> int:
         """Resident tiles are touched, stale host-side tiles are
         coalesced into ONE ``jax.device_put`` call (one enqueue RPC for
         a wave's transfers instead of one per tile; ``coalesce=False``:
@@ -272,7 +273,17 @@ class StageIn:
         current one for its whole length (``PERF.md`` §6, PR 27).  A tile
         that somebody else staged or wrote in between keeps their copy.
         (The pump's own call, from the staging walk, holds the lock
-        around all of it, as it always did: nobody waits for it.)"""
+        around all of it, as it always did: nobody waits for it.)
+
+        Nor is it held while the victims of that room go home, where
+        the caller says that it comes without the lock (``unlocked``:
+        the transfer lane, the one caller that does): the eviction
+        lets go of it between the choice of its victims and their drop
+        (``Residency.make_room``), so that the pump's commit does not
+        wait for up to 54 copies of 16 MiB (``PERF.md`` §6, PR 35).
+        The room is then taken under the hold that accounts the tiles:
+        what a cancelled victim or somebody else's staging left short
+        is evicted there, as everybody else evicts."""
         moved = 0
         idx, res, jdev, stats = self.index, self.res, self.jdev, self.stats
         if got is None:
@@ -309,7 +320,10 @@ class StageIn:
                     if not isinstance(payload, jax.Array):
                         payload = np.asarray(payload)
                     moving.append((data, payload, newest.version))
-                need = sum(p.nbytes for (_d, p, _v) in moving)
+            need = sum(p.nbytes for (_d, p, _v) in moving)
+            if need and unlocked:
+                res.make_room(need)
+            with pins.held(res.lock, "res_lock"):
                 if need and not res.reserve(need):
                     raise NoRoom(
                         f"no room on the device for {len(moving)} tiles "
@@ -786,7 +800,13 @@ class WritebackCommitter:
         Deduplicated per tile; bounded by a capacity wait at 4x the
         drain watermark (or the bytes of this one hand-over, if more) so
         a stalled committer applies backpressure instead of
-        accumulating unbounded dirty state.  Raises the
+        accumulating unbounded dirty state — but for a last version
+        whose copy was started: it is queued once, its copy is on its
+        way whether the queue is long or short, and what its entry pins
+        is the tile's own resident, accounted buffer, so a wait would
+        save no byte and no memory and only make the committing thread
+        wait for the chip once a wave (``PERF.md`` §6, PR 35: 1.9 s of
+        an out-of-core solve).  Raises the
         stored committer error if the committer died — the caller's
         fail-loudly discipline turns that into a pool failure.
         Returns one ticket a tile."""
@@ -825,7 +845,7 @@ class WritebackCommitter:
         with self._cv:
             self._raise_if_dead()
             for data, ticket, nb, early in entries:
-                if self._over(nb, cap):
+                if early is None and self._over(nb, cap):
                     with pins.wait("wb_capacity",
                                    pending_mb=self._pending_bytes >> 20):
                         while self._over(nb, cap):

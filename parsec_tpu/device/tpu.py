@@ -1194,7 +1194,10 @@ class TpuDevice(Device):
                     if room < 0:
                         tiles = tiles[:k]
                         break
-                moved = self._h2d.batch(tiles, keep=ahead)
+                # (the one caller that comes without the residency
+                # lock: an eviction for this room lets go of it while
+                # its victims go home)
+                moved = self._h2d.batch(tiles, keep=ahead, unlocked=True)
                 self._prestaged[batch_no] = ahead
             sp.note(bytes=moved)
         if not tiles:
@@ -1238,13 +1241,15 @@ class TpuDevice(Device):
         it would take the same copies one drain at a time while this
         thread waited for each tile in turn, and the version guard makes
         a copy the committer also holds (a last version on its way) land
-        once whoever is first.  The caller holds the residency lock —
-        taken with ``pins.held(res.lock, "res_lock")``, as every site on
-        the solve path takes it, so that whoever waits for it meanwhile
+        once whoever is first.  The caller may or may not hold the
+        residency lock (``residency.py``, "An eviction and the lock":
+        the lane's eviction comes without it, so that nobody waits
+        behind these copies; a walk that holds it took it with
+        ``pins.held(res.lock, "res_lock")``, so that whoever does wait
         leaves a ``wait:res_lock`` event naming this eviction as the
-        holder (a bare ``with res.lock:`` there would wait unseen): the
-        victims must be home before their device copies drop, and nobody
-        may rewrite one in between.  Returns the microseconds waited."""
+        holder): what keeps a victim from being dropped before it is
+        home, or after somebody rewrote it, is the residency's own
+        check at the drop.  Returns the microseconds waited."""
         t0 = time.perf_counter_ns()
         self._wb.writeback_batch(victims, self._span_pool, self._span_batch)
         return (time.perf_counter_ns() - t0) // 1000

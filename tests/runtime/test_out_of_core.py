@@ -11,6 +11,10 @@ an alias that becomes the home tile, and never silently another way.
 """
 
 import collections
+import contextlib
+import sys
+import threading
+import time
 
 import numpy as np
 import pytest
@@ -328,7 +332,8 @@ def test_the_evict_span_says_how_many_victims_had_a_known_use():
     with res.lock:
         assert res.reserve(4 * 1024)
     assert notes == [{"victims": 4, "dirty": 1, "bytes_home": 1024,
-                      "wait_us": 11, "known": 3, "never": 2}]
+                      "wait_us": 11, "known": 3, "never": 2,
+                      "cancelled": 0}]
 
 
 class _Forgetful(Residency):
@@ -515,3 +520,381 @@ def test_a_consumed_array_is_no_alias_fallback():
     x = jnp.ones(8)
     x.delete()
     assert w._alias(x) is x and stats["wb_alias_fallbacks"] == 0
+
+
+# ---------------------------------------------------------------------------
+# an eviction in two holds of the lock (PR 35): whoever comes WITHOUT the
+# residency lock (the transfer lane) lets its victims go home with the
+# lock free; whoever holds it around a walk evicts as ever
+# ---------------------------------------------------------------------------
+
+class _Notes:
+    """A span double that keeps what ``dev:evict`` notes."""
+
+    def __init__(self):
+        self.notes = []
+
+    def __call__(self, name, **info):
+        assert name == "dev:evict"
+        return self
+
+    def __enter__(self):
+        return self
+
+    def __exit__(self, *exc):
+        return False
+
+    def note(self, **kw):
+        self.notes.append(kw)
+
+
+class _SlowHome:
+    """A write-back double that blocks on an event: the victims' device
+    copies land as their host copies (``land``) once it is let."""
+
+    def __init__(self, land=True):
+        self.land = land
+        self.begun, self.let = threading.Event(), threading.Event()
+        self.batches = []
+
+    def __call__(self, victims):
+        self.batches.append([v.key for v in victims])
+        snaps = [(v, np.array(v.get_copy(1).payload), v.get_copy(1).version)
+                 for v in victims]
+        self.begun.set()
+        assert self.let.wait(timeout=30)
+        if self.land:
+            for v, payload, version in snaps:
+                v.attach_copy(0, payload).version = version
+        return 5
+
+
+def _owned(res, key, nbytes=1024, version=1):
+    """A resident dirty tile whose copy on the device is the only valid
+    one (``version`` ahead of the host copy's 0)."""
+    d = _resident(res, key, nbytes, dirty=True)
+    d.get_copy(res.index).version = version
+    return d
+
+
+def _lane(res, nbytes):
+    """``make_room`` on a thread of its own, as the transfer lane calls
+    it: without the lock."""
+    t = threading.Thread(target=res.make_room, args=(nbytes,), daemon=True)
+    t.start()
+    return t
+
+
+def _joined(t):
+    t.join(timeout=30)
+    assert not t.is_alive()
+
+
+def test_the_lanes_victims_go_home_with_the_residency_lock_free():
+    stats, home, span = collections.Counter(), _SlowHome(), _Notes()
+    res = Residency(1, 4 * 1024, stats, home, span=span)
+    a, b, c, d = (_owned(res, k) for k in "abcd")
+    lane = _lane(res, 2 * 1024)
+    assert home.begun.wait(timeout=30)
+    # the victims are on their way home: anybody may take the lock, and
+    # finds them accounted (the budget holds), attached and in no LRU
+    assert res.lock.acquire(timeout=10)
+    try:
+        assert res.used == 4 * 1024 and list(res.dirty.values()) == [c, d]
+        assert a.get_copy(1) is not None and b.get_copy(1) is not None
+        assert stats["evictions"] == 0
+    finally:
+        res.lock.release()
+    home.let.set()
+    _joined(lane)
+    # the room asked for is there on return; ONE batch, ONE span
+    assert res.used + 2 * 1024 <= res.budget
+    assert home.batches == [["a", "b"]]
+    assert a.get_copy(1) is None and b.get_copy(1) is None
+    assert a.get_copy(0).version == b.get_copy(0).version == 1
+    assert stats["evictions"] == stats["evict_dirty"] == 2
+    assert stats["evict_bytes_home"] == 2 * 1024
+    assert stats["evict_batches"] == 1 and stats["evict_cancelled"] == 0
+    assert span.notes == [{"victims": 2, "dirty": 2, "bytes_home": 2048,
+                           "wait_us": 5, "known": 0, "never": 0,
+                           "cancelled": 0}]
+
+
+def _pin(res, d):
+    res.pin(d)
+
+
+def _restage(res, d):
+    res.touch(d, dirty=True)   # what a staging walk does with a hit
+
+
+def _rewrite(res, d):
+    c = d.get_copy(1)          # what a commit does with an output
+    c.payload = c.payload + 1.0
+    d.version_bump(1)
+    res.touch(d, dirty=True)
+
+
+@pytest.mark.parametrize("meanwhile", [_pin, _restage, _rewrite],
+                         ids=["pinned", "restaged", "rewritten"])
+def test_a_victim_somebody_took_during_the_write_back_stays(meanwhile):
+    stats, home, span = collections.Counter(), _SlowHome(), _Notes()
+    res = Residency(1, 4 * 1024, stats, home, span=span)
+    a, b, c, d = (_owned(res, k) for k in "abcd")
+    lane = _lane(res, 2 * 1024)
+    assert home.begun.wait(timeout=30)
+    with res.lock:
+        meanwhile(res, a)
+    home.let.set()
+    _joined(lane)
+    mine = a.get_copy(1)
+    assert mine is not None and mine.payload is not None
+    assert res.accounted()[a.data_id] == 1024 and res.used == 3 * 1024
+    assert b.get_copy(1) is None
+    assert stats["evict_cancelled"] == 1
+    assert stats["evictions"] == stats["evict_dirty"] == 1
+    assert stats["evict_clean"] == 0 and stats["evict_bytes_home"] == 1024
+    # (the span: what was chosen and what went over the link)
+    assert span.notes[0]["victims"] == span.notes[0]["dirty"] == 2
+    assert span.notes[0]["bytes_home"] == 2048
+    assert span.notes[0]["cancelled"] == 1
+    if meanwhile is _rewrite:
+        # the landing that was overtaken is harmless: the device's
+        # version is the newest, and it is a victim again later
+        assert a.get_copy(0).version == 1 and mine.version == 2
+        assert a.newest_copy() is mine
+    # what is short the caller's reserve makes up under its hold, as
+    # everybody else evicts: the next victim, not the one that stayed
+    with res.lock:
+        assert res.reserve(2 * 1024)
+    assert stats["reserve_gave_up"] == 0 and res.used == 2 * 1024
+    assert a.get_copy(1) is not None and c.get_copy(1) is None
+
+
+def test_the_only_valid_copy_is_never_dropped_before_its_host_copy_stands():
+    """A write-back that lands nothing (a committer that died, a copy
+    that a donating task consumed): with the lock free meanwhile nobody
+    vouches for the victims, so they stay, the oldest of their LRU."""
+    stats, home = collections.Counter(), _SlowHome(land=False)
+    res = Residency(1, 4 * 1024, stats, home)
+    a, b, c, d = (_owned(res, k) for k in "abcd")
+    home.let.set()
+    res.make_room(2 * 1024)
+    assert home.batches == [["a", "b"]]
+    assert all(t.get_copy(1).payload is not None for t in (a, b))
+    assert all(t.get_copy(0).version == 0 for t in (a, b))
+    assert stats["evict_cancelled"] == 2 and stats["evictions"] == 0
+    assert stats["evict_bytes_home"] == 0 and res.used == 4 * 1024
+    assert list(res.dirty.values()) == [a, b, c, d] and not res.clean
+
+
+def test_a_victim_evicted_by_a_walk_that_held_the_lock_is_not_counted_twice():
+    """Staged, let go and evicted again under the pump's own hold while
+    its first copy home was on its way: that eviction counted it."""
+    stats, home = collections.Counter(), _SlowHome()
+    res = Residency(1, 4 * 1024, stats, home)
+    a, b, c, d = (_owned(res, k) for k in "abcd")
+    lane = _lane(res, 1024)
+    assert home.begun.wait(timeout=30)
+    assert home.batches == [["a"]]
+    with res.lock:
+        res.touch(a, dirty=True)
+        for t in (b, c, d):
+            res.pin(t)
+        home.let.set()            # (the second batch does not block)
+        assert res.reserve(1024)  # under the lock: a, once more
+        res.unpin([b, c, d])
+    _joined(lane)
+    assert home.batches == [["a"], ["a"]] and a.get_copy(1) is None
+    assert stats["evictions"] == stats["evict_dirty"] == 1
+    assert stats["evict_cancelled"] == 1 and stats["evict_batches"] == 2
+    assert res.used == 3 * 1024
+
+
+def test_a_caller_that_holds_the_lock_evicts_as_before():
+    """The pump's own staging walk, a commit's ``settle()``, the
+    synchronous regime: the lock is held from the choice of the victims
+    to their drop (nobody else gets it), and they drop whatever the
+    write-back did, as they always have."""
+    stats, span = collections.Counter(), _Notes()
+    tried = []
+
+    def home(victims):
+        t = threading.Thread(
+            target=lambda: tried.append(res.lock.acquire(timeout=0.2)))
+        t.start()
+        _joined(t)
+        return 3
+
+    res = Residency(1, 4 * 1024, stats, home, span=span)
+    a, b, c, d = (_owned(res, k) for k in "abcd")
+    with res.lock:
+        assert res.reserve(2 * 1024)
+    assert tried == [False]
+    assert a.get_copy(1) is None and b.get_copy(1) is None
+    assert stats["evictions"] == stats["evict_dirty"] == 2
+    assert stats["evict_cancelled"] == 0 and res.used == 2 * 1024
+    assert span.notes == [{"victims": 2, "dirty": 2, "bytes_home": 2048,
+                           "wait_us": 3, "known": 0, "never": 0,
+                           "cancelled": 0}]
+
+
+def _staging(budget, blocked=None):
+    """A residency, the write-back halves and the stage-in of one CPU
+    device, wired as the device module wires them; ``blocked``: an event
+    the eviction's write-back waits for (its begin is ``begun``)."""
+    import jax
+
+    from parsec_tpu.device.staging import HostWriter, StageIn
+
+    stats = collections.Counter()
+    writer = HostWriter(1, stats, name="cpu")
+    begun = threading.Event()
+
+    def home(victims):
+        begun.set()
+        assert blocked is None or blocked.wait(timeout=30)
+        writer.writeback_batch(victims)
+        return 0
+
+    res = Residency(1, budget, stats, home)
+    h2d = StageIn(res, writer, jax.devices("cpu")[0], stats,
+                  lambda name, **info: contextlib.nullcontext())
+    return res, h2d, stats, begun
+
+
+def _on_device(res, key, value, n=256):
+    """A tile (``n`` float32) that a task wrote on the device: resident,
+    dirty, one version ahead of its host copy."""
+    import jax.numpy as jnp
+
+    d = data_create(key, payload=np.zeros(n, np.float32))
+    d.attach_copy(1, jnp.full(n, value, jnp.float32)).version = 1
+    with res.lock:
+        assert res.account(d, 4 * n)
+        res.touch(d, dirty=True)
+    return d
+
+
+def test_the_lanes_batch_has_its_room_on_return_whatever_was_cancelled():
+    """``StageIn.batch(unlocked=True)``: room for two tiles, one victim
+    pinned by the pump while it went home: the hold that accounts the
+    batch evicts the next one, and the budget holds."""
+    let = threading.Event()
+    res, h2d, stats, begun = _staging(4 * 1024, blocked=let)
+    a, b, c, d = (_on_device(res, k, i + 1.0) for i, k in enumerate("abcd"))
+    x, y = (data_create(k, payload=np.full(256, v, np.float32))
+            for k, v in (("x", 8.0), ("y", 9.0)))
+    keep, got = [], {}
+    lane = threading.Thread(
+        target=lambda: h2d.batch([x, y], got=got, keep=keep, unlocked=True),
+        daemon=True)
+    lane.start()
+    assert begun.wait(timeout=30)
+    with res.lock:                      # (free: the victims are going home)
+        res.touch(a, dirty=True)
+        res.pin(a)
+    let.set()
+    _joined(lane)
+    assert keep == [x, y] and res.used == 4 * 1024 == res.budget
+    assert set(res.accounted()) == {t.data_id for t in (a, d, x, y)}
+    np.testing.assert_array_equal(np.asarray(got[x.data_id]), 8.0)
+    assert stats["evict_cancelled"] == 1 and stats["evict_batches"] == 2
+    assert stats["evictions"] == stats["evict_dirty"] == 2
+    assert stats["reserve_gave_up"] == 0
+    for t, v in ((b, 2.0), (c, 3.0)):   # gone, and home at their version
+        assert t.get_copy(1) is None and t.get_copy(0).version == 1
+        np.testing.assert_array_equal(t.get_copy(0).payload, v)
+    assert a.get_copy(1).payload is not None
+
+
+def test_nothing_is_lost_while_the_lane_evicts_beside_the_pump():
+    """A time-bounded stress: one lane staging tiles in without the
+    lock, four threads staging, pinning and rewriting tiles under it as
+    the pump does, in a budget of a quarter of the tiles.  Every write
+    is counted: a tile dropped while its newest version was the chip's
+    alone, or put back over a newer one, would lose some."""
+    import jax
+
+    from parsec_tpu.device.staging import NoRoom
+
+    n_tiles, n = 32, 256
+    res, h2d, stats, _begun = _staging(8 * 4 * n)
+    tiles = [data_create(k, payload=np.zeros(n, np.float32))
+             for k in range(n_tiles)]
+    writes = [0] * n_tiles
+    stop = time.monotonic() + 2.0
+    errors = []
+
+    def lane(seed):
+        rng = np.random.default_rng(seed)
+        while time.monotonic() < stop:
+            batch = [tiles[k] for k in rng.choice(n_tiles, 3, replace=False)]
+            try:
+                h2d.batch(batch, unlocked=True)
+            except NoRoom:
+                pass
+
+    def pump(seed):
+        rng = np.random.default_rng(seed)
+        while time.monotonic() < stop:
+            k = int(rng.integers(n_tiles))
+            pinned = []
+            try:
+                with res.lock:
+                    got = {}
+                    h2d.batch([tiles[k]], got=got, keep=pinned)
+                    c = tiles[k].get_copy(1)
+                    assert c.payload is got[tiles[k].data_id]
+                    tiles[k].transfer_ownership(1, 3)   # INOUT
+                    c.payload = c.payload + 1.0
+                    tiles[k].version_bump(1)
+                    res.touch(tiles[k], dirty=True)
+                    writes[k] += 1
+                    res.settle()
+            except NoRoom:
+                pass
+            finally:
+                res.unpin(pinned)
+
+    def guarded(fn, seed):
+        try:
+            fn(seed)
+        except BaseException as e:
+            errors.append(e)
+
+    old = sys.getswitchinterval()
+    sys.setswitchinterval(1e-5)
+    try:
+        threads = [threading.Thread(target=guarded, args=(lane, 0))] + \
+            [threading.Thread(target=guarded, args=(pump, s))
+             for s in range(1, 5)]
+        for t in threads:
+            t.start()
+        for t in threads:
+            _joined(t)
+    finally:
+        sys.setswitchinterval(old)
+    assert not errors, errors
+    assert sum(writes) > 0 and stats["evictions"] > 0
+    assert res.used <= res.budget
+    assert stats["evict_clean"] + stats["evict_dirty"] == stats["evictions"]
+    for k, tile in enumerate(tiles):
+        newest = tile.newest_copy()
+        np.testing.assert_array_equal(
+            np.asarray(jax.device_get(newest.payload)),
+            np.full(n, float(writes[k]), np.float32), err_msg=str(k))
+
+
+def test_a_write_back_that_raises_leaves_its_victims_evictable():
+    stats = collections.Counter()
+
+    def home(victims):
+        raise RuntimeError("the link is down")
+
+    res = Residency(1, 4 * 1024, stats, home)
+    tiles = [_owned(res, k) for k in "abcd"]
+    with pytest.raises(RuntimeError, match="the link is down"):
+        res.make_room(2 * 1024)
+    assert list(res.dirty.values()) == tiles and res.used == 4 * 1024
+    assert stats["evict_cancelled"] == 2 and stats["evictions"] == 0

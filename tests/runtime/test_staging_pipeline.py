@@ -748,3 +748,70 @@ def test_the_task_says_where_the_copy_starts(path):
     np.testing.assert_allclose(L @ L.T, S, rtol=1e-10, atol=1e-10)
     for (i, j) in ((0, 0), (A.mt - 1, 0), (A.mt - 1, A.mt - 1)):
         assert A.data_of(i, j).get_copy(0).payload.flags.writeable
+
+
+# ---------------------------------------------------------------------------
+# a hand-over of last versions does not wait for the committer's capacity
+# (PR 35)
+# ---------------------------------------------------------------------------
+
+def _held_committer(log):
+    """A committer inside ``_commit`` (its writer's gate is shut) with
+    200 MiB queued behind it: over its capacity of 128 for whatever
+    comes next."""
+    w = _GatedWriter()
+    com = WritebackCommitter(w)
+    com.enqueue(_device_dirty(log, "first", 1.0, nbytes=64 << 20))
+    deadline = time.monotonic() + 30
+    while com.pending_bytes() and time.monotonic() < deadline:
+        time.sleep(0.005)
+    assert com.pending_bytes() == 0 and com.pending() == 1  # grabbed
+    # (a hand-over never waits for room it fills itself)
+    com.enqueue_all([_device_dirty(log, k, 2.0, nbytes=100 << 20)
+                     for k in ("q1", "q2")])
+    assert com.pending_bytes() == 200 << 20
+    assert com.stats["capacity_waits"] == 0
+    return w, com
+
+
+@pytest.mark.parametrize("how", ["last", "unknown", "last_not_started"])
+def test_only_a_started_last_version_skips_the_capacity_wait(how):
+    """The pump's hand-over (``last``: the copies are started, each tile
+    is queued once, an entry pins the tile's own accounted buffer)
+    returns while the committer is held; a version that may be
+    superseded (the ``Context`` path: the committer's rate bounds what
+    goes home) and one whose copy could not be started still wait."""
+    log = []
+    w, com = _held_committer(log)
+    try:
+        if how == "last_not_started":
+            # a host array: nothing to start (``_start_copy``)
+            d = _dirty("plain", 3.0)
+            d.get_copy(1).payload = np.full(16 << 20, 3.0, np.float32)
+        else:
+            d = _device_dirty(log, "new", 3.0, nbytes=100 << 20)
+        handed = threading.Thread(
+            target=com.enqueue_all, args=([d],),
+            kwargs={"last": how != "unknown"}, daemon=True)
+        handed.start()
+        if how == "last":
+            handed.join(timeout=30)
+            assert not handed.is_alive()
+            assert com.stats["capacity_waits"] == 0
+            assert ("start", "new") in log
+            assert com.pending_bytes() == 300 << 20
+        else:
+            deadline = time.monotonic() + 30
+            while not com.stats["capacity_waits"] \
+                    and time.monotonic() < deadline:
+                time.sleep(0.005)
+            assert com.stats["capacity_waits"] > 0 and handed.is_alive()
+            assert com.pending_bytes() == 200 << 20
+        w.gate.set()
+        handed.join(timeout=30)
+        assert not handed.is_alive()
+        com.flush()
+        assert com.stats["committed"] == 4 and d.get_copy(0).version == 2
+    finally:
+        w.gate.set()
+        com.close(flush=False)
