@@ -26,6 +26,13 @@ if TYPE_CHECKING:  # pragma: no cover
 
 _OUT = int(AccessMode.OUT)
 
+#: ``Data.scratch`` of a tile that has no home and that nobody's
+#: retirement frees: a tile of a collection that is born on the device
+#: (``datadist.matrix.TiledMatrix(device_born=True)``).  It lives as long
+#: as its collection, is never written home (only an eviction spills it)
+#: and is read where it lives
+KEPT = -1
+
 
 class Coherency(enum.Enum):
     """Reference PARSEC_DATA_COHERENCY_* (data.h:39-44)."""
@@ -120,7 +127,8 @@ class Data:
         self.data_id = next(self._ids)
         self.user: Any = None
         #: None for a tile that has a home; for a scratch tile, the Data
-        #: of a ``NEW`` flow, its declared users left (device/scratch.py)
+        #: of a ``NEW`` flow, its declared users left (device/scratch.py);
+        #: :data:`KEPT` for a device-born collection's tile
         self.scratch: Optional[int] = None
 
     # -- copy management --------------------------------------------------

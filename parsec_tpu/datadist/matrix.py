@@ -18,7 +18,7 @@ from typing import Any, Callable, Dict, Optional, Tuple
 import numpy as np
 
 from ..data.collection import DataCollection
-from ..data.data import Data, data_create
+from ..data.data import KEPT, Data, data_create
 
 LOWER = "lower"
 UPPER = "upper"
@@ -27,7 +27,25 @@ FULL = "full"
 
 class TiledMatrix(DataCollection):
     """Base tiled-matrix collection: an ``m×n`` matrix cut into ``mb×nb``
-    tiles (ragged edge tiles allowed), keys are ``(i, j)`` tile indices."""
+    tiles (ragged edge tiles allowed), keys are ``(i, j)`` tile indices.
+
+    ``tile_dtype`` gives a tile a precision of its own: a callable
+    ``(i, j) -> dtype`` (None: the matrix's ``dtype``), which answers the
+    same for the matrix's whole life.  ``dtype`` stays the matrix's widest
+    precision, the one :meth:`to_array` gathers into; :meth:`dtype_of` is
+    a tile's, which :meth:`data_of` and :meth:`from_array` store it in.
+    The map is data: two matrices that differ only in it are two shapes
+    to whoever fingerprints a collection (``dsl/attach_plan.py``).
+
+    ``device_born=True``: no tile has a host value until somebody asks
+    for one.  :meth:`data_of` makes a ``Data`` that knows its shape and
+    dtype and holds no copy; the tile is born where its first writer
+    runs, lives as long as the matrix, and the device module never writes
+    it home (``device/scratch.py``, ``data.KEPT``): whoever wants rows of
+    it reads them where they live (``newest_copy().payload``), and
+    :meth:`to_array` fetches what it gathers.  The lazily created zero
+    tile of an ordinary matrix is a host array a tile: 990 of them are
+    16 GB for a matrix whose first task overwrites every one."""
 
     def __init__(
         self,
@@ -42,6 +60,8 @@ class TiledMatrix(DataCollection):
         myrank: int = 0,
         uplo: str = FULL,
         init: Optional[Callable[[int, int, Tuple[int, int]], np.ndarray]] = None,
+        tile_dtype=None,
+        device_born: bool = False,
     ):
         super().__init__(name, nodes=nodes, myrank=myrank)
         self.m, self.n, self.mb, self.nb = m, n, mb, nb
@@ -49,7 +69,13 @@ class TiledMatrix(DataCollection):
         self.nt = (n + nb - 1) // nb
         self.default_dtype = np.dtype(dtype)
         self.uplo = uplo
+        if device_born and init is not None:
+            raise ValueError(f"matrix {name}: a device-born matrix has no "
+                             "host value to initialise")
         self._init = init
+        self._tile_dtype = tile_dtype
+        self._dtype_map = None
+        self.device_born = bool(device_born)
         self._store: Dict[Tuple[int, int], Data] = {}
         self._lock = threading.Lock()
 
@@ -59,6 +85,30 @@ class TiledMatrix(DataCollection):
             min(self.mb, self.m - i * self.mb),
             min(self.nb, self.n - j * self.nb),
         )
+
+    def dtype_of(self, i: int, j: int) -> np.dtype:
+        """The precision tile ``(i, j)`` is stored in."""
+        pick = self._tile_dtype
+        if pick is None:
+            return self.default_dtype
+        dt = pick(i, j)
+        return self.default_dtype if dt is None else np.dtype(dt)
+
+    def dtype_map(self) -> Optional[Tuple[Tuple[str, int], ...]]:
+        """The precision map as run lengths over :meth:`tiles` — what a
+        fingerprint of the collection reads of it; None without a map."""
+        if self._tile_dtype is None:
+            return None
+        if self._dtype_map is None:  # (asked at every attach)
+            runs = []
+            for key in self.tiles():
+                name = self.dtype_of(*key).name
+                if runs and runs[-1][0] == name:
+                    runs[-1][1] += 1
+                else:
+                    runs.append([name, 1])
+            self._dtype_map = tuple((name, n) for name, n in runs)
+        return self._dtype_map
 
     def stored(self, i: int, j: int) -> bool:
         if not (0 <= i < self.mt and 0 <= j < self.nt):
@@ -96,11 +146,16 @@ class TiledMatrix(DataCollection):
             d = self._store.get(k)
             if d is None:
                 shape = self.tile_shape(*k)
-                if self._init is not None:
-                    payload = np.asarray(self._init(k[0], k[1], shape), dtype=self.default_dtype)
+                dtype = self.dtype_of(*k)
+                if self.device_born:
+                    d = Data(k, self, shape=shape, dtype=dtype)
+                    d.scratch = KEPT
+                elif self._init is not None:
+                    d = data_create(k, self, payload=np.asarray(
+                        self._init(k[0], k[1], shape), dtype=dtype))
                 else:
-                    payload = np.zeros(shape, self.default_dtype)
-                d = data_create(k, self, payload=payload)
+                    d = data_create(k, self,
+                                    payload=np.zeros(shape, dtype))
                 self._store[k] = d
             return d
 
@@ -117,13 +172,16 @@ class TiledMatrix(DataCollection):
             if self.rank_of(i, j) != self.myrank:
                 continue
             c = self.data_of(i, j).newest_copy()
-            if c is None:
-                continue
+            if c is None or c.payload is None:
+                continue  # a device-born tile nobody has written
             h, w = self.tile_shape(i, j)
             out[i * self.mb : i * self.mb + h, j * self.nb : j * self.nb + w] = np.asarray(c.payload)[:h, :w]
         return out
 
     def from_array(self, a: np.ndarray) -> "TiledMatrix":
+        if self.device_born:
+            raise ValueError(f"matrix {self.name}: a device-born matrix "
+                             "takes no host value")
         for (i, j) in self.tiles():
             if self.rank_of(i, j) != self.myrank:
                 continue
@@ -131,7 +189,7 @@ class TiledMatrix(DataCollection):
             # copy (not a view): the runtime mutates tiles in place and must
             # never alias the caller's array
             tile = a[i * self.mb : i * self.mb + h, j * self.nb : j * self.nb + w].astype(
-                self.default_dtype, copy=True)
+                self.dtype_of(i, j), copy=True)
             d = self.data_of(i, j)
             copy = d.get_copy(0) or d.attach_copy(0, tile)
             copy.payload = tile

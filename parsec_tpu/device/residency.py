@@ -81,6 +81,9 @@ from ..data.data import Coherency, Data
 from ..profiling import pins
 from ..utils import debug, mca_param
 
+#: dtype -> its name, as ``stats["tiles_by_dtype"]`` spells it
+_DTYPE_NAMES: Dict[Any, str] = {None: "?"}
+
 #: Declared capability, for whoever must refuse a program without it
 #: (``benchmark/drivers/pump_ooc.py``): a taskpool whose tiles exceed the
 #: device's budget runs to its end in bounded device AND host memory —
@@ -173,6 +176,14 @@ class Residency:
         #: accounted, and freeing it must not underflow the budget
         self._held: Dict[int, int] = {}
         self._offsets: Dict[int, int] = {}
+        #: the charges by the tiles' precision, and the most that was
+        #: ever charged since the last :meth:`clear`: at each new most,
+        #: ``stats["tiles_by_dtype"]`` takes a snapshot (dtype name ->
+        #: bytes resident at the peak; it outlives the clear, for whoever
+        #: reads a solve's peak after the detach)
+        self._by_dtype: Dict[str, int] = {}
+        self._charges = self._most = 0
+        stats.setdefault("tiles_by_dtype", {})
         #: data_id -> how many stagings hold the tile: no victim
         self._pins: Dict[int, int] = {}
         #: data_id -> the rank of the tile's next reader, or
@@ -226,6 +237,22 @@ class Residency:
     def _in_use(self) -> int:
         return self.used if self.zone is None else self.zone.used
 
+    def _charged(self, data: Data, old: int, new: int) -> None:
+        """``data``'s charge went from ``old`` to ``new`` bytes (the
+        caller holds the lock): the same by its precision, and the
+        snapshot at a new peak."""
+        if new == old:
+            return
+        name = _DTYPE_NAMES.get(data.dtype)
+        if name is None:  # (numpy spells a dtype's name in Python: once)
+            name = _DTYPE_NAMES[data.dtype] = str(data.dtype)
+        by = self._by_dtype
+        by[name] = by.get(name, 0) + new - old
+        self._charges += new - old
+        if self._charges > self._most:
+            self._most = self._charges
+            self.stats["tiles_by_dtype"] = {k: v for k, v in by.items() if v}
+
     def account(self, data: Data, nbytes: int) -> bool:
         """(Re)account ``data``'s slot at ``nbytes``, evicting for
         space.  The same bytes rebound (an epilog's output over its
@@ -247,6 +274,7 @@ class Residency:
                 self.used += nbytes - old
                 if nbytes > 0:
                     self._held[did] = nbytes
+                self._charged(data, old, nbytes)
                 return ok
             off = self._offsets.pop(did, None)
             if off is not None:
@@ -270,12 +298,14 @@ class Residency:
                         "and unaccounted", nbytes, data, self.zone.used,
                         self._budget)
             self.used = self.zone.used
+            self._charged(data, old, self._held.get(did, 0))
             return ok
 
     def free(self, data: Data) -> None:
         """Release ``data``'s slot (none: a no-op, never an underflow)."""
         with self.lock:
             old = self._held.pop(data.data_id, 0)
+            self._charged(data, old, 0)
             if self.zone is None:
                 self.used -= old
                 return
@@ -305,6 +335,8 @@ class Residency:
                     self.zone.release(off)
             self._offsets.clear()
             self._held.clear()
+            self._by_dtype.clear()
+            self._charges = self._most = 0
             self._pins.clear()
             self._next.clear()
             self._evicted.clear()

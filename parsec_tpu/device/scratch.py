@@ -23,6 +23,15 @@ count of the tasks that still have to use it:
   A user that never releases (a CPU body, a write-back of the tile into
   a collection) only keeps the tile until its ``Data`` dies, as before.
 
+A tile of a collection that is BORN ON THE DEVICE
+(``TiledMatrix(device_born=True)``) is the same thing with one
+difference: its collection keeps it (``Data.scratch`` is
+:data:`~parsec_tpu.data.data.KEPT`), so no task's retirement frees it
+and :func:`add_users` / :func:`release` leave it alone.  It is born by
+its first writer, never written home, and read after the pool — by the
+next pool, or by its owner, who asks for the rows he wants — where it
+lives.
+
 No option switches any of this: a ``Data`` is a scratch tile or has a
 home.
 """
@@ -33,7 +42,7 @@ from typing import Any
 
 import numpy as np
 
-from ..data.data import Data
+from ..data.data import KEPT, Data
 
 
 def new(key: Any, shape, dtype) -> Data:
@@ -54,12 +63,16 @@ def unborn(data: Data) -> bool:
 
 def add_users(data: Data, n: int = 1) -> None:
     with data.lock:
-        data.scratch += n
+        if data.scratch != KEPT:
+            data.scratch += n
 
 
 def release(data: Data) -> bool:
-    """One declared user has completed; True when it was the last."""
+    """One declared user has completed; True when it was the last (never
+    of a tile its collection keeps)."""
     with data.lock:
+        if data.scratch == KEPT:
+            return False
         data.scratch -= 1
         return data.scratch == 0
 

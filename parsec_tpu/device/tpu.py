@@ -153,6 +153,19 @@ class TpuDevice(Device):
         #: were the version the committer's drain collected
         #: (device/staging.py)
         self.stats.update(wb_started_early=0, wb_early_hits=0)
+        #: precisions (``TiledMatrix(tile_dtype=...)``): tiles a body
+        #: marked ``_converts`` wrote (a lower-precision twin made ONCE,
+        #: where its source is produced) and their bytes; reads of such a
+        #: twin by later tasks (each would have been a conversion of its
+        #: own, had the readers converted); distinct wave signatures
+        #: since the device was attached (a flow's dtype is part of a
+        #: signature: what the precisions cost the batching)
+        self.stats.update(convert_tiles=0, convert_bytes=0,
+                          convert_shared_hits=0, wave_signatures=0)
+        #: data_id of every converted twin made, and the signatures seen,
+        #: since the device was attached (both forgotten at detach)
+        self._converted: set = set()
+        self._sigs_seen: set = set()
         #: one :class:`FlowPlan` per distinct list of flows a wave
         #: signature names (bounded by the task classes' layouts)
         self._flow_plans: Dict[Any, FlowPlan] = {}
@@ -552,14 +565,24 @@ class TpuDevice(Device):
             sp.note(vdrop=drop, vpack=pack, vpos=pos, tdrop=tdrop,
                     outs=nouts, rep=rep)
 
+    def _count_converts(self, staged: List[_Staged], outs) -> None:
+        """A program of a body marked ``_converts`` went out: its outputs
+        are lower-precision twins, each made once for all its readers."""
+        self.stats["convert_tiles"] += len(outs)
+        self.stats["convert_bytes"] += sum(o.nbytes for o in outs)
+        self._converted.update(data.data_id for (_t, _a, ospecs) in staged
+                               for (_pos, data) in ospecs)
+
     def _submit_one(self, task: Task, es, complete: bool = True,
                     drained_ns: int = 0) -> None:
         """Per-task submit with the retry/fail-loudly discipline."""
         self._span_pool = _pool_of(task)
         waited = (drained_ns - task._tpu_enq) // 1000 if drained_ns else 0
         try:
+            sig = self._signature_of(task)
             with self._span("dev:submit_one", cls=task.task_class.name, n=1,
-                            batch=self._span_batch, waited_us=waited) as sp:
+                            batch=self._span_batch, waited_us=waited,
+                            dtypes=sig[1].dtypes if sig else "") as sp:
                 self._submit(task, es, complete=complete, span=sp)
         except Exception as e:
             if not getattr(task, "_tpu_completed", False) \
@@ -645,6 +668,9 @@ class TpuDevice(Device):
         sig = task._tpu_sig
         if sig is False:
             sig = task._tpu_sig = self._wave_signature(task)
+            if sig is not None and sig not in self._sigs_seen:
+                self._sigs_seen.add(sig)
+                self.stats["wave_signatures"] += 1
         return sig
 
     @staticmethod
@@ -784,7 +810,8 @@ class TpuDevice(Device):
             waited = sum(drained_ns - t._tpu_enq
                          for t in grp) // 1000 if drained_ns else 0
             with self._span("dev:wave", cls=cls, n=cnt,
-                            batch=self._span_batch, waited_us=waited) as sp:
+                            batch=self._span_batch, waited_us=waited,
+                            dtypes=plan.dtypes) as sp:
                 self._submit_chunk(grp, body, base_key, plan, es, complete,
                                    sp)
 
@@ -840,6 +867,8 @@ class TpuDevice(Device):
             for t in grp:
                 self._fire_exec(t, pins.EXEC_END, wave=cnt)
         self._count_values(plan, cnt, wave_span, flat, len(outs))
+        if getattr(body, "_converts", False):
+            self._count_converts(staged, outs)
         if len(outs) != nout * cnt:
             raise ValueError(
                 f"wave of {grp[0].task_class.name}: bodies returned "
@@ -907,7 +936,8 @@ class TpuDevice(Device):
         found: Dict[int, Any] = {}  # data_id -> payload on this device
         #: data_id -> [tile, (argument list, position) it still misses in]
         missing: Dict[int, List[Any]] = {}
-        ntiles = nread = nmiss = 0
+        ntiles = nread = nmiss = twins = 0
+        converted = self._converted
         #: data_id -> the rank of the tile's next reader after this
         #: chunk, as the tasks' pools know it (``Residency.next_uses``)
         nexts: Dict[int, int] = {}
@@ -936,6 +966,8 @@ class TpuDevice(Device):
                     if how == READ:
                         nread += 1
                         did = data.data_id
+                        if converted and did in converted:
+                            twins += 1
                         if uses is not None:
                             use = uses[at + pos]
                             if use > nexts.get(did, -1):
@@ -999,6 +1031,8 @@ class TpuDevice(Device):
                 res.next_uses(nexts)
         tally[2] += ntiles
         tally[3] += nread - nmiss
+        if twins:
+            self.stats["convert_shared_hits"] += twins
         return staged
 
     def _submit(self, task: Task, es=None, complete: bool = True,
@@ -1114,6 +1148,8 @@ class TpuDevice(Device):
             self._count_values(plan, 1, span, call_args, fplan.nout)
         if not isinstance(outputs, (tuple, list)):
             outputs = (outputs,)
+        if getattr(body, "_converts", False):
+            self._count_converts(staged, outputs)
         if len(outputs) != fplan.nout:
             raise ValueError(
                 f"device body of {task!r} returned {len(outputs)} outputs "
@@ -1525,6 +1561,8 @@ class TpuDevice(Device):
                 self.stats["wb_batches"] = self.stats.get("wb_batches", 0) + 1
             # the LRUs and the residency ACCOUNTING go together
             self._res.clear()
+        self._converted.clear()
+        self._sigs_seen.clear()
 
 
 def device_body(chore, fn):

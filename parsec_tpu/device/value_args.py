@@ -100,7 +100,7 @@ class FlowPlan:
     the walk stages the tile itself.  ``out_hooks`` holds one ``stage_out`` hook (or None)
     per output, or is None when no output has one."""
 
-    __slots__ = ("steps", "nout", "reads", "out_hooks", "nbytes")
+    __slots__ = ("steps", "nout", "reads", "out_hooks", "nbytes", "dtypes")
 
     def __init__(self, flows: Sequence[Any]):
         """``flows``: the signature without its body key.  A tile is
@@ -111,6 +111,7 @@ class FlowPlan:
         steps: List[Tuple[int, int, int, Any]] = []
         out_hooks: List[Any] = []
         nbytes = 0
+        dtypes: List[str] = []
         for pos, f in enumerate(flows):
             if f is None:
                 steps.append((ABSENT, pos, 0, None))
@@ -140,7 +141,9 @@ class FlowPlan:
                     how, extra = PLACEHOLDER, jax.ShapeDtypeStruct(
                         shape, np.dtype(dtype))
                 steps.append((how, pos, access, extra))
+                dtypes.append("?" if dtype is None else np.dtype(dtype).name)
                 if shape is not None and dtype is not None:
+                    # (a tile counts at its own precision's bytes)
                     tile = int(np.prod(shape)) * np.dtype(dtype).itemsize
                     # a tile read takes a buffer, a tile written a new one
                     nbytes += tile * ((how == READ) + bool(access & _OUT))
@@ -156,6 +159,9 @@ class FlowPlan:
         #: device bytes one task's tiles take, read and written (where
         #: the signature says their shapes): what bounds a wave's chunk
         self.nbytes = nbytes
+        #: the tile flows' precisions in order, for the program's span
+        #: (no comma: an event's arguments are a comma-separated list)
+        self.dtypes = "/".join(dtypes)
 
 
 class ValuePlan:

@@ -242,9 +242,12 @@ def _collection_fp(dc) -> Optional[Tuple]:
     t = type(dc)
     if t in (TiledMatrix, TwoDimBlockCyclic, SymTwoDimBlockCyclic,
              VectorTwoDimCyclic):
+        # (the precision map and where the tiles are born shape the
+        # tasks' signatures and what a bind makes of a tile)
         return (t.__name__, dc.m, dc.n, dc.mb, dc.nb, dc.mt, dc.nt,
                 np.dtype(dc.default_dtype).str, dc.uplo, dc.nodes, dc.myrank,
-                tuple(getattr(dc, a, None) for a in ("p", "q", "kp", "kq")))
+                tuple(getattr(dc, a, None) for a in ("p", "q", "kp", "kq")),
+                dc.dtype_map(), dc.device_born)
     if t is LocalCollection:
         return (t.__name__, dc.tile_shape, np.dtype(dc.default_dtype).str,
                 dc.nodes, dc.myrank)

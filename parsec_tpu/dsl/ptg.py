@@ -1560,7 +1560,8 @@ def _wrap_device_body(pc: PTGTaskClass, fn: Callable):
     wrapped._jit_key = getattr(fn, "_jit_key", (fn, tuple(names)))
     # forward the device-module opt-ins (see TpuDevice._submit): local
     # values baked statically into the trace / donated array positions
-    for attr in ("_static_values", "_donate_args"):
+    # ... and ``_converts``: its outputs are lower-precision twins
+    for attr in ("_static_values", "_donate_args", "_converts"):
         if hasattr(fn, attr):
             setattr(wrapped, attr, getattr(fn, attr))
     if pc.stage_hooks:

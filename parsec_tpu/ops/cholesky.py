@@ -51,12 +51,11 @@ def cholesky_ptg(*, use_tpu: bool = True, use_cpu: bool = True,
     it).
 
     ``bf16_updates`` (requires ``use_pallas``) feeds the syrk/gemm panel
-    operands to the MXU in bfloat16 with f32 accumulation — the standard
-    mixed-precision recipe. Only the operand cast rounds (~4e-3 per
-    element; bf16 x bf16 products are exact in f32): measured end-to-end
-    last-tile error at N=8192 is ~2e-5, passing the bench's 1e-3 gate;
-    small ill-conditioned problems can see worse (tests allow 2e-2).
-    Opt-in speed mode, not the default."""
+    operands to the MXU in bfloat16 with f32 accumulation: one MXU pass
+    where ``highest`` makes six, and the operand cast rounds to 8 bits.
+    It is the lower-precision control path of the benchmark's tile
+    configurations (their ``control.options``), which their limits have
+    to fail; nothing else uses it."""
     ptg = PTG("dpotrf")
 
     def bodies(cpu, tpu):
@@ -69,16 +68,63 @@ def cholesky_ptg(*, use_tpu: bool = True, use_cpu: bool = True,
             kw["tpu"] = tpu
         return kw
 
+    syrk_dev = tiles.syrk_tpu
+    gemm_dev = tiles.gemm_update_tpu
+    if use_pallas:
+        syrk_dev = tiles.syrk_pallas_bf16 if bf16_updates else tiles.syrk_pallas
+        gemm_dev = (tiles.gemm_update_pallas_bf16 if bf16_updates
+                    else tiles.gemm_update_pallas)
+    elif bf16_updates:
+        raise ValueError("bf16_updates requires use_pallas")
+    if use_trtri:
+        trsm_body = bodies(tiles.trsm_inv_cpu,
+                           tiles.trsm_inv_pallas if use_pallas
+                           else tiles.trsm_inv_tpu)
+    else:
+        trsm_body = bodies(tiles.trsm_cpu, tiles.trsm_tpu)
+    add_dpotrf_classes(ptg, {
+        "potrf": bodies(tiles.potrf_cpu, tiles.potrf_tpu),
+        "trtri": bodies(tiles.trtri_cpu, tiles.trtri_tpu),
+        "trsm": trsm_body,
+        "syrk": bodies(tiles.syrk_cpu, syrk_dev),
+        "gemm": bodies(tiles.gemm_update_cpu, gemm_dev),
+    }, use_trtri=use_trtri)
+    return ptg
+
+
+def add_dpotrf_classes(ptg: PTG, bodies, *, first="A({m}, {n})",
+                       potrf_out=(), trsm_out=(),
+                       trsm_b2_out=("-> B2 gemm(k, m+1 .. NT-1, m)",),
+                       gemm_b2=("<- C trsm(k, n)",),
+                       use_trtri: bool = False) -> None:
+    """The four dpotrf task classes (five with ``use_trtri``) on ``ptg``,
+    with their priorities and dependencies: :func:`cholesky_ptg`'s, and
+    whoever composes the factorization into a larger pool (``ops/mle.py``:
+    the matrix comes from a generator class and the factor feeds a
+    solve).  ``bodies``: class name -> the keywords of its ``body()``.
+
+    What a composer may say, all in dependency text: ``first``, the
+    source of tile ``{m}, {n}`` before its first update (default: the
+    collection's tile); ``potrf_out`` / ``trsm_out``, further
+    readers of a factored diagonal / panel tile; ``trsm_b2_out`` and
+    ``gemm_b2``, the two ends of the edge that hands a panel tile to the
+    ``gemm`` tasks of its column as their ``B2`` (a composer that gives
+    some of them a converted twin instead narrows the one and guards the
+    other)."""
+    def tile(m, n):
+        return first.format(m=m, n=n)
+
     potrf = ptg.task_class("potrf", k="0 .. NT-1")
     potrf.affinity("A(k, k)")
     potrf.priority("(NT - k) * 1000")
     potrf.flow("T", INOUT,
-               "<- (k == 0) ? A(k, k) : A syrk(k-1, k)",
+               f"<- (k == 0) ? {tile('k', 'k')} : A syrk(k-1, k)",
                # trtri mode: the factored block feeds the inverter, which
                # fans the inverse out to the column's trsms
                "-> T trtri(k)" if use_trtri else "-> T trsm(k, k+1 .. NT-1)",
+               *potrf_out,
                "-> A(k, k)")
-    potrf.body(**bodies(tiles.potrf_cpu, tiles.potrf_tpu))
+    potrf.body(**bodies["potrf"])
 
     if use_trtri:
         trtri = ptg.task_class("trtri", k="0 .. NT-2")
@@ -88,7 +134,7 @@ def cholesky_ptg(*, use_tpu: bool = True, use_cpu: bool = True,
         trtri.flow("I", INOUT,
                    "<- NEW",
                    "-> I trsm(k, k+1 .. NT-1)")
-        trtri.body(**bodies(tiles.trtri_cpu, tiles.trtri_tpu))
+        trtri.body(**bodies["trtri"])
 
     trsm = ptg.task_class("trsm", k="0 .. NT-2", m="k+1 .. NT-1")
     trsm.affinity("A(m, k)")
@@ -100,47 +146,33 @@ def cholesky_ptg(*, use_tpu: bool = True, use_cpu: bool = True,
         trsm.flow("T", IN,
                   "<- T potrf(k)")
     trsm.flow("C", INOUT,
-              "<- (k == 0) ? A(m, k) : A gemm(k-1, m, k)",
+              f"<- (k == 0) ? {tile('m', 'k')} : A gemm(k-1, m, k)",
               "-> B syrk(k, m)",
               "-> B1 gemm(k, m, k+1 .. m-1)",
-              "-> B2 gemm(k, m+1 .. NT-1, m)",
+              *trsm_b2_out,
+              *trsm_out,
               "-> A(m, k)")
-    if use_trtri:
-        trsm.body(**bodies(tiles.trsm_inv_cpu,
-                           tiles.trsm_inv_pallas if use_pallas
-                           else tiles.trsm_inv_tpu))
-    else:
-        trsm.body(**bodies(tiles.trsm_cpu, tiles.trsm_tpu))
+    trsm.body(**bodies["trsm"])
 
     syrk = ptg.task_class("syrk", k="0 .. NT-2", m="k+1 .. NT-1")
     syrk.affinity("A(m, m)")
     syrk.priority("(NT - m) * 100 + 10")
     syrk.flow("A", INOUT,
-              "<- (k == 0) ? A(m, m) : A syrk(k-1, m)",
+              f"<- (k == 0) ? {tile('m', 'm')} : A syrk(k-1, m)",
               "-> (k == m-1) ? T potrf(m) : A syrk(k+1, m)")
     syrk.flow("B", IN,
               "<- C trsm(k, m)")
-    syrk_dev = tiles.syrk_tpu
-    gemm_dev = tiles.gemm_update_tpu
-    if use_pallas:
-        syrk_dev = tiles.syrk_pallas_bf16 if bf16_updates else tiles.syrk_pallas
-        gemm_dev = (tiles.gemm_update_pallas_bf16 if bf16_updates
-                    else tiles.gemm_update_pallas)
-    elif bf16_updates:
-        raise ValueError("bf16_updates requires use_pallas")
-    syrk.body(**bodies(tiles.syrk_cpu, syrk_dev))
+    syrk.body(**bodies["syrk"])
 
     gemm = ptg.task_class("gemm", k="0 .. NT-3", m="k+2 .. NT-1", n="k+1 .. m-1")
     gemm.affinity("A(m, n)")
     gemm.priority("(NT - m) * 10")
     gemm.flow("A", INOUT,
-              "<- (k == 0) ? A(m, n) : A gemm(k-1, m, n)",
+              f"<- (k == 0) ? {tile('m', 'n')} : A gemm(k-1, m, n)",
               "-> (k == n-1) ? C trsm(n, m) : A gemm(k+1, m, n)")
     gemm.flow("B1", IN, "<- C trsm(k, m)")
-    gemm.flow("B2", IN, "<- C trsm(k, n)")
-    gemm.body(**bodies(tiles.gemm_update_cpu, gemm_dev))
-
-    return ptg
+    gemm.flow("B2", IN, *gemm_b2)
+    gemm.body(**bodies["gemm"])
 
 
 def run_cholesky(context, A, *, use_tpu: bool = True, use_cpu: bool = True) -> None:
