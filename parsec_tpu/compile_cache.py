@@ -182,14 +182,25 @@ def code_fingerprint(fn: Callable) -> str:
     return hashlib.sha256("|".join(out).encode()).hexdigest()[:24]
 
 
+#: dtype -> its name as :func:`argsig` spells it (numpy spells a dtype's
+#: name in Python, 2.6 us of the 3.4 an array argument cost: once a dtype)
+_DTYPE_NAMES: Dict[Any, str] = {}
+
+
 def _argsig_one(a) -> Tuple:
     if a is None:
         return ("none",)
     shape = getattr(a, "shape", None)
     dtype = getattr(a, "dtype", None)
     if shape is not None and dtype is not None:
+        try:
+            name = _DTYPE_NAMES.get(dtype)
+            if name is None:
+                name = _DTYPE_NAMES[dtype] = str(dtype)
+        except TypeError:  # a dtype-like that does not hash
+            name = str(dtype)
         wk = bool(getattr(a, "weak_type", False))
-        return ("a", tuple(shape), str(dtype), wk)
+        return ("a", tuple(shape), name, wk)
     if isinstance(a, (tuple, list)):
         return ("t", tuple(_argsig_one(x) for x in a))
     return ("s", type(a).__name__)
@@ -198,7 +209,9 @@ def _argsig_one(a) -> Tuple:
 def argsig(args: Tuple) -> Tuple:
     """Light per-call signature: shapes/dtypes of array args, types of
     scalars.  Computed on the dispatch hot path — attribute access only,
-    no tracing."""
+    no tracing.  The tuple is part of :func:`fingerprint`, the name of
+    every entry of the disk store: its spelling never changes without
+    ``CACHE_FORMAT``."""
     return tuple(_argsig_one(a) for a in args)
 
 
@@ -515,6 +528,14 @@ class _CachedFunction:
         return self._plain
 
     def __call__(self, *args):
+        return self.call(args)[0]
+
+    def call(self, args: Tuple) -> Tuple[Any, Any]:
+        """One call, and the executable that ran it: ``(result,
+        executable)``.  A caller whose every call has this signature (an
+        entry of ``TpuDevice._jit_cache``) keeps the executable and calls
+        it from then on, without the signature, the look-up and the
+        fallback below (:meth:`retryable` is its half of the last)."""
         sig = argsig(args)
         exe = self._memo.get(sig)
         if exe is None:
@@ -527,9 +548,9 @@ class _CachedFunction:
             # are pinned on hits growing while misses stay flat
             self.cache.stats["hits_mem"] += 1
         try:
-            return exe(*args)
+            return exe(*args), exe
         except Exception as e:
-            if exe is self._plain or not self._retryable(e):
+            if not self.retryable(exe, e):
                 raise
             # AOT dispatch mismatch (sharding/weak-type nuance the light
             # signature missed): fall back to plain jit — correctness
@@ -541,17 +562,20 @@ class _CachedFunction:
             plain = self._plain_jit()
             with self._lock:
                 self._memo[sig] = plain
-            return plain(*args)
+            return plain(*args), plain
 
-    def _retryable(self, e: Exception) -> bool:
-        """Only argument/aval/structure mismatches the light cache
-        signature could not see may retry through a plain jit — a
-        genuine compute-side failure must surface as itself, not as a
-        second run's error.  TypeError/ValueError are raised at
-        argument validation, BEFORE any buffer is donated, so retrying
-        them is safe even for donating programs; a runtime status error
-        from a donating program must never re-execute (the failed
-        attempt may already have consumed its inputs)."""
+    def retryable(self, exe, e: Exception) -> bool:
+        """May a call of ``exe`` that raised ``e`` be made again through
+        the plain jit?  Only argument/aval/structure mismatches the
+        light cache signature could not see may — a genuine compute-side
+        failure must surface as itself, not as a second run's error (and
+        the plain jit has nothing behind it).  TypeError/ValueError are
+        raised at argument validation, BEFORE any buffer is donated, so
+        retrying them is safe even for donating programs; a runtime
+        status error from a donating program must never re-execute (the
+        failed attempt may already have consumed its inputs)."""
+        if exe is self._plain:
+            return False
         if isinstance(e, (TypeError, ValueError)):
             return True
         if self.donate:

@@ -136,6 +136,11 @@ class TpuDevice(Device):
         #: the same program (a wave of a stencil's generation passes
         #: every tile to up to five of its tasks)
         self.stats.update(tile_args_passed=0, tile_args_repeated=0)
+        #: calls of device programs: of the executable its ``_jit_cache``
+        #: entry holds, and through the cache by the arguments' signature
+        #: (an entry's first call, every call of a ``_static_values``
+        #: body; :meth:`_dispatch`)
+        self.stats.update(calls_bound=0, calls_signed=0)
         #: scratch tiles (device/scratch.py): first written / dropped
         #: with their last user on this device, and the bytes of them
         #: that crossed the host after all (0 unless one was evicted or
@@ -521,11 +526,12 @@ class TpuDevice(Device):
         ``_jit_cache`` keeps the fast id-keyed lookup the dispatch loop
         had, while the executable cache behind it adds the persistent
         disk store and the cross-rank compile broadcast.  An entry is
-        ``(program, ValuePlan or None)``; ``build()`` gives a new one's
-        ``(content key, function, donated positions, plan)``.  The
-        ``dev:jit`` span notes ``miss=1`` on a program's first use by
-        this device; the compile or load itself comes at its first call,
-        as a ``cc:compile`` span under ``dev:dispatch``."""
+        ``(program, ValuePlan or None, executable or None)``;
+        ``build()`` gives a new one's ``(content key, function, donated
+        positions, plan)``.  The ``dev:jit`` span notes ``miss=1`` on a
+        program's first use by this device; the compile or load itself
+        comes at its first call (:meth:`_dispatch`), as a ``cc:compile``
+        span under ``dev:dispatch``."""
         with self._span("dev:jit") as sp:
             entry = self._jit_cache.get(local_key)
             if entry is None:
@@ -533,8 +539,45 @@ class TpuDevice(Device):
                 content_key, fn, donate, plan = build()
                 entry = self._jit_cache[local_key] = (self._ccache.jit(
                     fn, key=content_key, donate_argnums=tuple(donate)),
-                    plan)
+                    plan, None)
         return entry
+
+    def _dispatch(self, local_key, entry, flat):
+        """THE call of a device program, under its ``dev:dispatch``
+        span.  An entry whose local key holds its arguments' signature
+        has ONE signature for its whole life (the tasks of a chunk share
+        an interned ``FlowPlan``, and the key names ``cnt`` and the
+        placeholders), so its first call asks the cache for it
+        (``compile_cache._CachedFunction.call``: ``argsig``, the layers,
+        ``cc:compile``) and the entry keeps the executable that gave:
+        every later call is ``executable(*flat)`` (``bound=1`` on the
+        span).  An entry without a plan is a ``_static_values``
+        program's: its key says nothing of the shapes, every call asks.
+        What the cache's own call would retry through the plain jit
+        (``_CachedFunction.retryable``) unbinds the entry and goes that
+        way, once; anything else raises as it did."""
+        program, plan, exe = entry
+        with self._span("dev:dispatch") as sp:
+            if exe is not None:
+                try:
+                    outs = exe(*flat)
+                except Exception as e:
+                    if not program.retryable(exe, e):
+                        raise
+                    self._jit_cache[local_key] = (program, plan, None)
+                else:
+                    # a call that needed no compile is a hit, as the
+                    # cache's own look-up counts it
+                    self._ccache.stats["hits_mem"] += 1
+                    self.stats["calls_bound"] += 1
+                    sp.note(bound=1)
+                    return outs
+            outs, exe = program.call(flat)
+            if plan is not None:
+                self._jit_cache[local_key] = (program, plan, exe)
+            self.stats["calls_signed"] += 1
+            sp.note(bound=0)
+        return outs
 
     @staticmethod
     def _value_plan(task: Task, body, dev_args) -> ValuePlan:
@@ -854,15 +897,15 @@ class TpuDevice(Device):
             _wave.__name__ = f"_wave_{cls}"
             return (("wave", cls, self._content_fp(body), len(args0), nout,
                      cnt) + plan.tag, _wave, (), plan)
-        jitted, plan = self._cached_jit(
-            ("wave", cls, base_key, argsig(args0), _placeholders_at(args0), nout,
-             cnt), build)
+        local_key = ("wave", cls, base_key, argsig(args0),
+                     _placeholders_at(args0), nout, cnt)
+        entry = self._cached_jit(local_key, build)
+        plan = entry[1]
         flat = plan.flatten([args for (_t, args, _o) in staged])
         if pins.active(pins.EXEC_BEGIN):
             for t in grp:
                 self._fire_exec(t, pins.EXEC_BEGIN, wave=cnt)
-        with self._span("dev:dispatch"):
-            outs = jitted(*flat)
+        outs = self._dispatch(local_key, entry, flat)
         if pins.active(pins.EXEC_END):
             for t in grp:
                 self._fire_exec(t, pins.EXEC_END, wave=cnt)
@@ -1098,8 +1141,9 @@ class TpuDevice(Device):
 
             def _bound(*arrs, _body=body, _vals=vals):
                 return _body(*arrs, *_vals)
-            jitted, plan = self._cached_jit(
-                (base_key, vals),
+            local_key = (base_key, vals)
+            entry = self._cached_jit(
+                local_key,
                 lambda: (("static", self._content_fp(body), vals),
                          _bound, donate, None))
         else:
@@ -1134,16 +1178,17 @@ class TpuDevice(Device):
                 # its own name, as it always was
                 return (content_key + plan.tag, _one if plan.tag else body,
                         plan.donate(donate), plan)
-            jitted, plan = self._cached_jit(
-                (base_key, argsig(dev_args), _placeholders_at(dev_args)), build)
-            call_args = plan.flatten((dev_args,))
+            local_key = (base_key, argsig(dev_args),
+                         _placeholders_at(dev_args))
+            entry = self._cached_jit(local_key, build)
+            call_args = entry[1].flatten((dev_args,))
         # a donating call that raises may have invalidated its input
         # buffers: the task is no longer safely retryable
         task._tpu_effects = bool(donate)
         self._fire_exec(task, pins.EXEC_BEGIN)
-        with self._span("dev:dispatch"):
-            outputs = jitted(*call_args)
+        outputs = self._dispatch(local_key, entry, call_args)
         self._fire_exec(task, pins.EXEC_END)
+        plan = entry[1]  # (None: a ``_static_values`` program)
         if plan is not None:
             self._count_values(plan, 1, span, call_args, fplan.nout)
         if not isinstance(outputs, (tuple, list)):

@@ -54,6 +54,69 @@ def test_fingerprint_misses_on_shape_dtype_backend_change():
     assert cc.fingerprint(("body", "cafe"), sig32) != base  # program
 
 
+#: what ``argsig`` spells for one argument, as every executable in every
+#: disk store was named: literal, so a changed spelling fails here and not
+#: as a cold start of every run
+_ARGSIG_PINS = [
+    ("f32_array", lambda: jnp.zeros((8, 4), jnp.float32),
+     ("a", (8, 4), "float32", False)),
+    ("bf16_array", lambda: jnp.zeros((2,), jnp.bfloat16),
+     ("a", (2,), "bfloat16", False)),
+    ("i32_array", lambda: jnp.zeros((3, 1, 2), jnp.int32),
+     ("a", (3, 1, 2), "int32", False)),
+    ("bool_array", lambda: jnp.zeros((4,), jnp.bool_),
+     ("a", (4,), "bool", False)),
+    ("numpy_f64", lambda: np.zeros((5, 6), np.float64),
+     ("a", (5, 6), "float64", False)),
+    ("numpy_i8", lambda: np.zeros((7,), np.int8),
+     ("a", (7,), "int8", False)),
+    ("numpy_scalar", lambda: np.float32(1.5), ("a", (), "float32", False)),
+    ("struct_bf16", lambda: jax.ShapeDtypeStruct((9, 9), jnp.bfloat16),
+     ("a", (9, 9), "bfloat16", False)),
+    ("struct_np_dtype",
+     lambda: jax.ShapeDtypeStruct((1,), np.dtype("float32")),
+     ("a", (1,), "float32", False)),
+    # (the tests run with x64 on)
+    ("weak_float", lambda: jnp.asarray(2.0), ("a", (), "float64", True)),
+    ("weak_int", lambda: jnp.asarray(3), ("a", (), "int64", True)),
+    ("python_int", lambda: 2, ("s", "int")),
+    ("python_float", lambda: 2.5, ("s", "float")),
+    ("python_bool", lambda: True, ("s", "bool")),
+    ("python_str", lambda: "x", ("s", "str")),
+    ("none", lambda: None, ("none",)),
+    ("nested", lambda: (jnp.zeros((2, 2), jnp.float32), None,
+                        [1, np.zeros(3, np.uint8)]),
+     ("t", (("a", (2, 2), "float32", False), ("none",),
+            ("t", (("s", "int"), ("a", (3,), "uint8", False)))))),
+]
+
+
+@pytest.mark.parametrize("make,want", [p[1:] for p in _ARGSIG_PINS],
+                         ids=[p[0] for p in _ARGSIG_PINS])
+def test_argsig_spelling_is_pinned(make, want):
+    arg = make()
+    assert cc.argsig((arg,)) == (want,)
+    assert cc.argsig((arg, arg)) == (want, want)   # from the memo too
+
+
+def test_argsig_pins_name_the_same_store_entries(monkeypatch):
+    """The fingerprint over a pinned signature: the name such an entry
+    of the disk store has had since ``CACHE_FORMAT`` 2 (under the one
+    installation's versions, held here)."""
+    monkeypatch.setattr(cc, "_versions", lambda: "0.9.0/0.9.0")
+    sig = cc.argsig(tuple(p[1]() for p in _ARGSIG_PINS[:3]))
+    assert sig == tuple(p[2] for p in _ARGSIG_PINS[:3])
+    assert cc.fingerprint(("body", "deadbeef"), sig, backend="tpu") == \
+        "150f4ba9eb76ae8a3fe5a8d1afd038a124821e3f"
+
+
+def test_argsig_of_an_unhashable_dtype_is_still_spelled():
+    class Odd:
+        shape = (2,)
+        dtype = ["float32"]   # no hash: spelled, never memoized
+    assert cc.argsig((Odd(),)) == (("a", (2,), "['float32']", False),)
+
+
 def test_code_fingerprint_tracks_code_and_closures():
     def mk(k):
         def f(x):

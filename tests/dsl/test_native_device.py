@@ -320,7 +320,7 @@ def test_native_device_values_dropped_or_packed(tmp_path, exported, graph):
         ex = NativeExecutor(tp, native_device=True, device=dev)
         ran = ex.run(nthreads=2)
         ex.close()
-        sigs = [sig for (cf, _plan) in dev._jit_cache.values()
+        sigs = [sig for (cf, _plan, _exe) in dev._jit_cache.values()
                 for sig in cf._memo]
         assert not any(e[0] == "s" for sig in sigs for e in sig), sigs
         if graph == "dpotrf_ignores_its_values":

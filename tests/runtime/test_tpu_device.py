@@ -965,7 +965,7 @@ def _run_value_tasks(dev, tasks, alone):
 
 def _call_signatures(dev):
     """The argument signature of every call a program of ``dev`` got."""
-    return [sig for (cf, _plan) in dev._jit_cache.values()
+    return [sig for (cf, _plan, _exe) in dev._jit_cache.values()
             for sig in cf._memo]
 
 
@@ -1173,7 +1173,7 @@ def test_a_wave_program_carries_its_class_in_its_module_name(
             rows = [(np.ones((8, 8), np.float32),
                      np.ones((8, 8), np.float32), 2) for _ in range(4)]
             _run_value_tasks(dev, _value_tasks(body, rows), alone=False)
-            (cf, _plan), = dev._jit_cache.values()
+            (cf, _plan, _exe), = dev._jit_cache.values()
             exe, = cf._memo.values()
             assert exe.as_text().startswith("HloModule jit__wave_valuetest")
             assert ctx.compile_cache.store.count() == int(exported)
