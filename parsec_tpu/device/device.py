@@ -87,6 +87,15 @@ class Device(Component):
         """Accelerators override: take ownership of the task (ASYNC)."""
         raise NotImplementedError
 
+    def flush_home(self, datas, wait: bool = True) -> None:
+        """A DSL's flush (DTD's ``data_flush`` / ``flush_all``): ``datas``
+        are tiles whose newest version lives in this module's memory and
+        is, the caller knows, their last for now — bring them home
+        together.  ``wait=False`` only starts them on their way: they are
+        at home after the module's own flush or its ``detach``.  A module
+        that keeps no copy of its own has nothing to do (the base), and
+        the caller pulls what is still not at home."""
+
     def add_load(self, dt: float) -> None:
         with self._load_lock:
             self.device_load += dt

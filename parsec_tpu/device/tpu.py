@@ -1312,6 +1312,25 @@ class TpuDevice(Device):
             with self._span("dev:flush"):
                 com.flush(timeout=timeout)
 
+    def flush_home(self, datas: List[Data], wait: bool = True) -> None:
+        """``Device.flush_home``: the dirty copies of ``datas`` here are
+        last versions that nobody sent home when they were committed (a
+        DTD task's outputs: ``Task._tpu_home = ()``).  They go the way
+        the pump's last versions go (``WritebackCommitter.enqueue_all``,
+        ``last=True``): every copy home started now, before one is
+        waited for (``wait:d2h_start``), the committer collecting them a
+        batch at a time (``dev:writeback``), and with ``wait`` the
+        barrier of :meth:`flush` behind them.  One guarded batch on the
+        calling thread in the synchronous regime."""
+        com = self._wb_committer()
+        if com is None:
+            self._wb.writeback_batch(datas, self._span_pool,
+                                     self._span_batch)
+            return
+        com.enqueue_all(datas, self._span_pool, self._span_batch, last=True)
+        if wait:
+            self.flush()
+
     def _writeback_evict(self, victims: List[Data]) -> int:
         """Eviction's write-back (``Residency``'s callable): the victims
         whose copy here is the only valid one, as ONE batch on the

@@ -24,6 +24,21 @@ Differences from the reference, by design:
   return replacement arrays (functional style, required for JAX device
   execution): a non-None return rebinds the writable flows in order.
 
+A tile goes home when it is flushed, and not before: a task's output
+stays where the task ran (``Task._tpu_home = ()`` tells the device
+module that no version of it is the committer's), a host reader pulls
+what it needs (:func:`stage_to_cpu`), and ``data_flush`` / ``flush_all``
+start the copies home of the newest versions together
+(``Device.flush_home``).  A tile the user never flushes is handed to its
+device's committer when the closed pool terminates, and a tile the
+residency evicts is written home first, so nothing is lost at
+``detach``.
+
+What a trace shows of it (``docs/TRACING.md`` "DTD"): ``core:dtd_insert``
+a task, ``wait:dtd_window`` while the inserter is held at the full
+window, ``core:dtd_wait``, ``core:dtd_flush``; the counters are
+:meth:`DTDTaskpool.counters`.
+
 Usage::
 
     dtd = DTDTaskpool(ctx)
@@ -39,6 +54,7 @@ Usage::
 from __future__ import annotations
 
 import threading
+import time
 import types
 from typing import Any, Callable, Dict, List, Optional, Sequence, Tuple, Union
 
@@ -47,7 +63,8 @@ import numpy as np
 from ..core.lifecycle import AccessMode, HookReturn, DEV_CPU, DEV_TPU
 from ..core.task import Chore, Flow, Task, TaskClass
 from ..core.taskpool import Taskpool
-from ..data.data import Data
+from ..data.data import Coherency, Data
+from ..profiling import pins
 from ..utils import debug, mca_param
 
 IN = AccessMode.IN
@@ -59,6 +76,14 @@ SCRATCH = AccessMode.SCRATCH
 ATOMIC_WRITE = AccessMode.ATOMIC_WRITE
 AFFINITY = AccessMode.AFFINITY
 DONT_TRACK = AccessMode.DONT_TRACK
+
+# the same flags as plain ints, for insert_task: an ``IntFlag``'s ``&`` is
+# a Python-level call (half of an insertion's time before these)
+_IN, _OUT, _CTL, _SCRATCH, _VALUE = (
+    int(IN), int(OUT), int(CTL), int(SCRATCH), int(VALUE))
+_ATOMIC, _AFFINITY, _DONT_TRACK = (
+    int(ATOMIC_WRITE), int(AFFINITY), int(DONT_TRACK))
+_WRITES = _OUT | _ATOMIC
 
 
 class _TileState:
@@ -137,6 +162,13 @@ def stage_to_cpu(data: Data) -> np.ndarray:
         return scratch.host_zeros(data)
     if newest is None:
         raise RuntimeError(f"{data!r} has no valid copy")
+    if newest.device_index != 0:
+        # a flush landed this very version at home: no second copy
+        hc = data.get_copy(0)
+        if hc is not None and hc.payload is not None \
+                and hc.coherency is not Coherency.INVALID \
+                and hc.version >= newest.version:
+            newest = hc
     if newest.device_index == 0:
         if isinstance(newest.payload, np.ndarray):
             return newest.payload
@@ -179,6 +211,18 @@ class DTDTaskpool(Taskpool):
             "dtd", "war_rename", True,
             help="break WAR hazards by renaming (fresh writer buffer) instead of serializing")
         self._rename_tc: Optional[TaskClass] = None
+        #: what :meth:`counters` reports (``dtd_*``): dependency edges
+        #: found at insertion, WAR renames, fills of the window with the
+        #: seconds the inserter was held and the tasks it executed or
+        #: handed over meanwhile, tiles flushed home
+        self._edges = 0
+        self._renames = 0
+        self._stalls = 0
+        self._stall_s = 0.0
+        self._helped = 0
+        self._flushed = 0
+        #: the pool's birth and the return of its newest insertion
+        self._t_born = self._t_inserted = time.perf_counter()
         # -- multi-rank state (shadow-task protocol) ---------------------
         #: (wire_key, epoch) -> {"payload": arr|None, "task": recv Task|None}
         self._recv: Dict[Tuple[Any, int], Dict[str, Any]] = {}
@@ -188,6 +232,26 @@ class DTDTaskpool(Taskpool):
         self._comm_seq = 0
         if context is not None and auto_add:
             context.add_taskpool(self)
+
+    def counters(self) -> Dict[str, float]:
+        """The pool's own counts, cumulative since its birth:
+        ``dtd_inserted`` tasks (the runtime's own copy and communication
+        tasks among them), ``dtd_edges`` dependencies found at
+        insertion, ``dtd_renames`` WAR hazards broken by a fresh buffer,
+        ``dtd_window_stalls`` fills of the window and
+        ``dtd_window_stall_s`` seconds the inserter was held there,
+        ``dtd_helped`` tasks it executed or handed to a device
+        meanwhile, ``dtd_flushed_tiles`` tiles brought home by
+        ``data_flush`` / ``flush_all``, ``dtd_insert_done_s`` seconds
+        from the pool's creation to the return of its newest insertion
+        (discovery's end, once the user inserts no more)."""
+        return {"dtd_inserted": self._inserted, "dtd_edges": self._edges,
+                "dtd_renames": self._renames,
+                "dtd_window_stalls": self._stalls,
+                "dtd_window_stall_s": self._stall_s,
+                "dtd_helped": self._helped,
+                "dtd_flushed_tiles": self._flushed,
+                "dtd_insert_done_s": self._t_inserted - self._t_born}
 
     def attached(self, context) -> None:
         super().attached(context)
@@ -201,15 +265,16 @@ class DTDTaskpool(Taskpool):
     def _class_of(
         self,
         bodies: Dict[str, Callable],
-        modes: Tuple[AccessMode, ...],
+        modes: Tuple[int, ...],
         name: Optional[str],
     ) -> TaskClass:
+        """``modes``: the arguments' access modes, as ints."""
         key = (tuple((d, id(f)) for d, f in sorted(bodies.items())), modes, name)
         tc = self._classes.get(key)
         if tc is not None:
             return tc
         flows = [
-            Flow(f"arg{i}", m & ~(AFFINITY | DONT_TRACK), i)
+            Flow(f"arg{i}", AccessMode(m & ~(_AFFINITY | _DONT_TRACK)), i)
             for i, m in enumerate(modes)
         ]
         cname = name or next(
@@ -354,7 +419,20 @@ class DTDTaskpool(Taskpool):
         Returns the inserted :class:`Task`, or ``None`` when the task's
         affinity places it on another rank (shadow insertion — the
         reference's remote tasks are likewise not handed back).
+
+        One ``core:dtd_insert`` span a call (``cls``; ``deps``: the
+        dependency edges found; ``ready``: inserted with none left).  A
+        full window holds the caller AFTER it, in ``wait:dtd_window``.
         """
+        with pins.span("core:dtd_insert") as sp:
+            task = self._insert(body, args, priority, name, sp)
+        self._throttle_window()
+        self._t_inserted = time.perf_counter()
+        return task
+
+    def _insert(self, body, args, priority: int, name: Optional[str],
+                sp) -> Optional[Task]:
+        """:meth:`insert_task` inside its span."""
         if not self._open:
             raise RuntimeError("taskpool closed for insertion")
         if self.failed:
@@ -368,27 +446,28 @@ class DTDTaskpool(Taskpool):
         myrank = self.context.rank
 
         specs: List[Tuple[str, Any, AccessMode]] = []
-        modes: List[AccessMode] = []
+        modes: List[int] = []   # the arguments' modes as ints
         affinity_data: Optional[Data] = None
         for a in args:
             if isinstance(a, tuple) and len(a) == 2 and isinstance(a[1], AccessMode):
                 val, mode = a
             else:
                 val, mode = a, VALUE
-            if mode & AccessMode.SCRATCH:
+            m = int(mode)
+            if m & _SCRATCH:
                 specs.append(("scratch", val, mode))
-            elif mode & AccessMode.CTL and isinstance(val, Data):
+            elif m & _CTL and isinstance(val, Data):
                 # control-only dependency on a tile: tracked like a reader,
                 # but contributes no body argument
                 specs.append(("ctl", val, mode))
-            elif mode & AccessMode.VALUE or not isinstance(val, Data):
+            elif m & _VALUE or not isinstance(val, Data):
                 specs.append(("value", val, VALUE))
-                mode = VALUE
+                m = _VALUE
             else:
                 specs.append(("data", val, mode))
-                if mode & AFFINITY and affinity_data is None:
+                if m & _AFFINITY and affinity_data is None:
                     affinity_data = val
-            modes.append(mode)
+            modes.append(m)
 
         # rank placement (owner computes, reference PARSEC_AFFINITY flag):
         # the task executes on the rank owning the AFFINITY-tagged tile
@@ -416,9 +495,13 @@ class DTDTaskpool(Taskpool):
         tc = self._class_of(bodies, tuple(modes), name)
         task = Task(self, tc, (self._inserted,), priority)
         task.body_args = specs
+        #: no output of an inserted task is the device committer's: a
+        #: tile goes home at its flush (module docstring)
+        task._tpu_home = ()
         state = _DTDTaskState()
         task.user = state
         task.on_complete = self._task_retired
+        deps = 0
 
         # dependency inference per tracked data argument (CTL args track
         # like readers: they order after the last writer). Multi-rank runs
@@ -427,7 +510,8 @@ class DTDTaskpool(Taskpool):
         # 1:1 onto the home buffer).
         rename_on = bool(self._war_rename) and nranks == 1
         for i, (kind, data, mode) in enumerate(specs):
-            if kind not in ("data", "ctl") or (mode & DONT_TRACK):
+            m = modes[i]
+            if kind not in ("data", "ctl") or (m & _DONT_TRACK):
                 continue
             st = self._tile_state(data)
             copy_src = copy_dst = None
@@ -438,20 +522,19 @@ class DTDTaskpool(Taskpool):
                 if nranks > 1:
                     # content of the current epoch must be materialized
                     # locally before any consuming local task can run
-                    needs_in = bool(mode & (AccessMode.IN | AccessMode.ATOMIC_WRITE)) \
-                        or not (mode & AccessMode.OUT)
+                    needs_in = bool(m & (_IN | _ATOMIC)) or not (m & _OUT)
                     if needs_in and not st.have_local:
                         self._ensure_recv_locked(st, st.epoch)
                 buf = st.current if st.current is not None else data
                 last = [st.last_writer] if st.last_writer is not None else []
-                if (mode & AccessMode.ATOMIC_WRITE) and nranks == 1:
+                if (m & _ATOMIC) and nranks == 1:
                     # commutative writer: after readers + exclusive writer,
                     # unordered among atomic peers
                     for p in st.readers + last:
                         if p is not task:
-                            self._add_edge(p, task, state)
+                            deps += self._add_edge(p, task, state)
                     st.atomic.append(task)
-                elif mode & (AccessMode.OUT | AccessMode.ATOMIC_WRITE):
+                elif m & _WRITES:
                     # exclusive writer (OUT/INOUT; multi-rank also routes
                     # ATOMIC_WRITE here — commutativity is a local
                     # optimization, cross-rank epochs need a total order)
@@ -461,9 +544,10 @@ class DTDTaskpool(Taskpool):
                         # writer proceeds on a fresh buffer while pending
                         # readers/atomics keep the old one
                         st.renames += 1
+                        self._renames += 1
                         newd = Data((data.key, "war", st.renames),
                                     shape=buf.shape, dtype=buf.dtype)
-                        if mode & AccessMode.IN:
+                        if m & _IN:
                             # INOUT: the new buffer needs the old contents —
                             # a copy task ordered after the old buffer's
                             # producers (but NOT after its readers)
@@ -479,7 +563,7 @@ class DTDTaskpool(Taskpool):
                     else:
                         for p in pending + last:
                             if p is not task:
-                                self._add_edge(p, task, state)
+                                deps += self._add_edge(p, task, state)
                         st.last_writer = task
                         st.readers = []
                         st.atomic = []
@@ -490,14 +574,15 @@ class DTDTaskpool(Taskpool):
                 else:  # reader: after exclusive writer + atomic writers
                     for p in st.atomic + last:
                         if p is not task:
-                            self._add_edge(p, task, state)
+                            deps += self._add_edge(p, task, state)
                     st.readers.append(task)
             if kind == "data":
                 specs[i] = (kind, buf, mode)  # bind the version's buffer
             if copy_src is not None:
                 cpy = self._insert_rename_copy(copy_src, copy_dst, copy_preds)
-                self._add_edge(cpy, task, state)
+                deps += self._add_edge(cpy, task, state)
 
+        self._edges += deps
         with self._quiesce:
             self._inserted += 1
         # release the insertion-in-progress dependency
@@ -508,15 +593,18 @@ class DTDTaskpool(Taskpool):
         if ready:
             es = self.context.current_es()
             self.context.schedule([task], es=es)
-        self._throttle_window()
+        sp.note(cls=tc.name, deps=deps, ready=int(ready))
         return task
 
     @staticmethod
     def _attach_blank(newd: Data, like: Data) -> None:
         """Allocate a pure-OUT rename target shaped like the old buffer."""
         c = like.newest_copy()
-        if c is not None:
-            arr = np.zeros_like(np.asarray(c.payload))
+        p = c.payload if c is not None else None
+        if p is not None:
+            # (its shape and dtype, not its value: the tile may live on a
+            # device, and this runs under the tile's lock)
+            arr = np.zeros(p.shape, p.dtype)
         else:
             arr = np.zeros(like.shape or (1,), like.dtype or np.float64)
         newd.attach_copy(0, arr)
@@ -713,7 +801,9 @@ class DTDTaskpool(Taskpool):
             self._quiesce.notify_all()
 
     @staticmethod
-    def _add_edge(pred: Task, succ: Task, succ_state: "_DTDTaskState") -> None:
+    def _add_edge(pred: Task, succ: Task, succ_state: "_DTDTaskState") -> bool:
+        """``succ`` runs after ``pred``; False where there is nothing to
+        wait for (``pred`` has completed, or the edge is there)."""
         # bump pending BEFORE publishing the edge: a predecessor completing
         # between publish and bump would double-schedule the successor. The
         # insertion-in-progress dependency keeps pending >= 1 throughout, so
@@ -729,6 +819,7 @@ class DTDTaskpool(Taskpool):
         if not added:  # pred already done, or duplicate edge
             with succ_state.lock:
                 succ_state.pending -= 1
+        return added
 
     def _release_deps(self, es, task: Task) -> List[Task]:
         state: _DTDTaskState = task.user
@@ -752,25 +843,38 @@ class DTDTaskpool(Taskpool):
 
     def _throttle_window(self) -> None:
         """Bound in-flight tasks (reference window throttling): the inserter
-        thread helps execute until the backlog drains to the threshold."""
+        thread helps execute until the backlog drains to the threshold.
+        One ``wait:dtd_window`` event a fill of the window
+        (``in_flight`` when it began; ``helped``: the tasks the inserter
+        executed meanwhile — an accelerator task it takes is handed to
+        its device's queue, or makes the inserter that device's manager
+        until the queue is empty)."""
         if self.context is None:
             return
         in_flight = self._inserted - self._retired
         if in_flight < self.window:
             return
         self.context.start()
-        while True:
-            if self.failed:
-                return  # aborted: the backlog will never drain
-            with self._quiesce:
-                if self._inserted - self._retired <= self.threshold:
-                    return
-            if not self.context.help_execute_one():
+        helped = 0
+        t0 = time.perf_counter()
+        with pins.wait("dtd_window", in_flight=in_flight) as w:
+            while not self.failed:  # (aborted: the backlog never drains)
+                with self._quiesce:
+                    if self._inserted - self._retired <= self.threshold:
+                        break
+                if self.context.help_execute_one():
+                    helped += 1
+                    continue
                 # the backlog may be recv tasks blocked on remote arrivals:
                 # drain the comm engine or a full window deadlocks the rank
                 self.context._progress_comm()
                 with self._quiesce:
-                    self._quiesce.wait(0.001)
+                    if self._inserted - self._retired > self.threshold:
+                        self._quiesce.wait(0.001)
+            w.note(helped=helped)
+        self._stalls += 1
+        self._stall_s += time.perf_counter() - t0
+        self._helped += helped
 
     # -----------------------------------------------------------------
     # quiescence / flush
@@ -780,34 +884,50 @@ class DTDTaskpool(Taskpool):
         open for more insertion (reference ``parsec_taskpool_wait``)."""
         if self.context is not None:
             self.context.start()
-        import time
+        with pins.span("core:dtd_wait") as sp:
+            done, helped = self._wait(timeout)
+            sp.note(done=int(done), helped=helped)
+        return done
 
+    def _wait(self, timeout: Optional[float]) -> Tuple[bool, int]:
+        """:meth:`wait` inside its ``core:dtd_wait`` span: whether the
+        pool quiesced, and the tasks the caller executed meanwhile.  The
+        stretches in which it finds nothing to execute are the span's
+        ``dtd:parked`` children (a sleep of up to a millisecond each,
+        ended by the next retirement): nobody schedules there, so they
+        are no ``core:*`` span's self time."""
         deadline = (time.monotonic() + timeout) if timeout is not None else None
+        helped = 0
         while True:
             if self.failed:
-                return False  # Context.abort(): discarded tasks never retire
+                return False, helped  # abort(): discarded tasks never retire
             with self._quiesce:
                 if self._retired >= self._inserted:
-                    return True
+                    return True, helped
                 if deadline is not None and time.monotonic() > deadline:
-                    return False
+                    return False, helped
             if self.context is not None and self.context.help_execute_one():
+                helped += 1
                 continue
-            if self.context is not None:
-                # drive the comm engine: pending recv tasks need arrivals
-                self.context._progress_comm()
-            with self._quiesce:
-                if self._retired >= self._inserted:
-                    return True
-                self._quiesce.wait(0.001)
+            with pins.span("dtd:parked"):
+                if self.context is not None:
+                    # drive the comm engine: pending recv tasks need
+                    # arrivals
+                    self.context._progress_comm()
+                with self._quiesce:
+                    if self._retired >= self._inserted:
+                        return True, helped
+                    self._quiesce.wait(0.001)
 
     def data_flush(self, data: Data) -> None:
         """Push the final version of ``data`` home to its owner rank
         (reference ``parsec_dtd_data_flush``, insert_function.h:351-360).
 
-        Single-rank: materialize the newest version on the CPU device —
-        copying it back from a rename buffer if WAR renaming redirected the
-        tile — and drop tracking state. Multi-rank: asynchronous like the
+        Single-rank: bring the newest version home — through the device
+        that holds it (``Device.flush_home``), or copied back from a
+        rename buffer if WAR renaming redirected the tile — and drop
+        tracking state; the caller has waited for the tile's tasks
+        (``flush_all`` does). Multi-rank: asynchronous like the
         reference — inserts the home-bound send on the producing rank and
         the matching recv on the owner; completed by ``wait()``. All ranks
         must flush the same tiles (SPMD, as they inserted)."""
@@ -825,45 +945,97 @@ class DTDTaskpool(Taskpool):
                 elif owner == myrank and not st.have_local:
                     self._ensure_recv_locked(st, st.epoch)
             return
-        with self._tiles_lock:
-            st = self._tiles.get(data.data_id)
-        cur = st.current if st is not None and st.current is not None else data
-        if cur is not data:
-            copy_home(cur, data)
-        else:
-            stage_to_cpu(data)
-        with self._tiles_lock:
-            self._tiles.pop(data.data_id, None)
+        self._flush_home([data])
+
+    def _send_home(self, datas: List[Data], wait: bool) -> int:
+        """The tiles of ``datas`` whose newest version lives on a device
+        go home TOGETHER, a device at a time (``Device.flush_home``: every
+        copy started before one is waited for).  Returns their bytes."""
+        by_dev: Dict[int, List[Data]] = {}
+        nbytes = 0
+        for d in datas:
+            c = d.newest_copy()
+            # (a scratch tile has no home: whoever wants it pulls it)
+            if c is not None and c.device_index != 0 \
+                    and c.payload is not None and d.scratch is None:
+                by_dev.setdefault(c.device_index, []).append(d)
+                nbytes += c.nbytes
+        for idx, group in by_dev.items():
+            self.context.devices[idx].flush_home(group, wait=wait)
+        return nbytes
+
+    def _flush_home(self, datas: List[Data]) -> None:
+        """The single-rank flush of ``datas``, under one ``core:dtd_flush``
+        span (``n`` tiles; ``bytes`` that crossed from a device)."""
+        with pins.span("core:dtd_flush", n=len(datas)) as sp:
+            with self._tiles_lock:
+                states = [self._tiles.get(d.data_id) for d in datas]
+            plain: List[Data] = []
+            for d, st in zip(datas, states):
+                cur = st.current if st is not None else None
+                if cur is not None and cur is not d:
+                    copy_home(cur, d)  # the tile's value is a rename buffer's
+                else:
+                    plain.append(d)
+            nbytes = self._send_home(plain, wait=True)
+            for d in plain:
+                # what no device brought home (a module without a flush
+                # of its own, a scratch tile, a payload a fabric left at
+                # the host slot)
+                stage_to_cpu(d)
+            with self._tiles_lock:
+                for d in datas:
+                    self._tiles.pop(d.data_id, None)
+            self._flushed += len(datas)
+            sp.note(bytes=nbytes)
 
     def flush_all(self, collection=None) -> None:
         """Reference ``parsec_dtd_data_flush_all``: flush every tracked tile
-        home (of one collection, or all)."""
+        home (of one collection, or all).  Single-rank: waits for the
+        pool, then ONE flush of them all."""
         multirank = self.context is not None and self.context.nranks > 1
         if not multirank:
             self.wait()
         with self._tiles_lock:
-            states = list(self._tiles.values())
-        flushed = []
+            states = [st for st in self._tiles.values()
+                      if st.data is not None and (
+                          collection is None
+                          or st.data.collection is collection)]
+        if not multirank:
+            self._flush_home([st.data for st in states])
+            return
         for st in states:
-            if st.data is None:
-                continue
-            if collection is not None and st.data.collection is not collection:
-                continue
             self.data_flush(st.data)
-            flushed.append(st)
-        if multirank:
-            self.wait()
-            myrank = self.context.rank
-            for st in flushed:
-                owner = self._rank_of_data(st.data)
-                if owner is None or owner == myrank:
-                    stage_to_cpu(st.data)  # materialize home tiles on CPU
-                with self._tiles_lock:
-                    self._tiles.pop(st.data.data_id, None)
+        self.wait()
+        myrank = self.context.rank
+        for st in states:
+            owner = self._rank_of_data(st.data)
+            if owner is None or owner == myrank:
+                stage_to_cpu(st.data)  # materialize home tiles on CPU
+            with self._tiles_lock:
+                self._tiles.pop(st.data.data_id, None)
 
     def close(self) -> None:
         """End insertion; after this, ``context.wait()`` can terminate the
-        pool."""
+        pool.  A tile that was never flushed goes to its device's
+        committer when the pool terminates (:meth:`_termination_detected`)."""
         if self._open:
             self._open = False
             self.tdm.taskpool_addto_runtime_actions(self, -1)
+
+    def _termination_detected(self, tp) -> None:
+        """The closed pool's last task has retired: the tiles the user
+        never flushed are handed to their devices' committers, without a
+        wait — they land at the device's ``flush()`` or ``detach``, as a
+        version did when every one of them was the committer's."""
+        if not self.failed and self.context is not None \
+                and self.context.nranks == 1:
+            with self._tiles_lock:
+                left = [st.data for st in self._tiles.values()
+                        if st.data is not None and (
+                            st.current is None or st.current is st.data)]
+            try:
+                self._send_home(left, wait=False)
+            except Exception as e:  # the committer died: detach says so
+                debug.warning("dtd: unflushed tiles not handed home: %s", e)
+        super()._termination_detected(tp)

@@ -140,8 +140,10 @@ class NativeDTD:
                 t._wr = None  # non-weakreffable objects: caller keeps alive
         return t
 
-    def insert_task(self, body: Callable, *args: Any, priority: int = 0) -> int:
-        """Insert one task; returns its native id. Dependencies are
+    def insert_task(self, body: Callable, *args: Any, priority: int = 0,
+                    name: Optional[str] = None) -> int:
+        """Insert one task; returns its native id (``name``: what the
+        tools call its class; the body's own by default). Dependencies are
         inferred from tracked ``(ndarray, mode)`` arguments: readers order
         after the last writer, writers after last writer + all readers.
         ``(arr, mode | DONT_TRACK)`` passes the array untracked;
@@ -174,7 +176,7 @@ class NativeDTD:
         if pins.active(pins.EXEC_BEGIN) or pins.active(pins.COMPLETE_EXEC_END):
             from .native_exec import _TaskInfo
 
-            info = _TaskInfo(getattr(body, "__name__", "dtd_task"),
+            info = _TaskInfo(name or getattr(body, "__name__", "dtd_task"),
                              f"#{self._inserted}")
 
             def task_body(_body=body, _args=tuple(call_args)) -> None:

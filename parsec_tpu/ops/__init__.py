@@ -11,7 +11,7 @@ from .attention import (
     run_flash_attention_native,
     run_ring_attention_graph,
 )
-from .cholesky import cholesky_ptg, run_cholesky
+from .cholesky import cholesky_dtd, cholesky_ptg, run_cholesky
 from .lu import lu_ptg, run_lu
 from .panel_chol import PanelCholesky, WholeCholesky
 from .segmented_chol import SegmentedCholesky, segmented_cholesky_ptg
@@ -19,7 +19,7 @@ from .segmented_lu import SegmentedLU, segmented_lu_ptg
 from .segmented_qr import SegmentedQR, segmented_qr_ptg
 from .qr import qr_ptg, run_qr
 
-__all__ = ["tiles", "cholesky_ptg", "run_cholesky", "lu_ptg", "run_lu",
+__all__ = ["tiles", "cholesky_ptg", "cholesky_dtd", "run_cholesky", "lu_ptg", "run_lu",
            "flash_attention_ptg", "ring_attention_ptg",
            "build_flash_attention", "run_flash_attention",
            "run_flash_attention_native", "run_ring_attention_graph",

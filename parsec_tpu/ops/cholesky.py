@@ -13,18 +13,37 @@ classic right-looking tiled algorithm expressed in the PTG DSL:
 Dataflow: each tile's value threads through the update chain as a flow, so
 lookahead across iterations emerges from dependencies alone — the classic
 PTG win over fork-join loops.
+
+The same mathematics in the other DSL is :func:`cholesky_dtd`: DPLASMA's
+``testing_dpotrf_dtd.c``, the loop nest above written as sequential task
+insertion, the graph discovered by the runtime task by task.  Both forms
+take their bodies from :func:`dpotrf_bodies` and their priorities from
+:data:`PRIORITY`.
 """
 
 from __future__ import annotations
 
 import numpy as np
 
-from ..core.lifecycle import AccessMode
+from ..core.lifecycle import AccessMode, DEV_CPU, DEV_TPU
 from ..dsl.ptg import PTG
 from . import tiles
 
 IN = AccessMode.IN
 INOUT = AccessMode.INOUT
+AFFINITY = AccessMode.AFFINITY
+
+#: a task's priority by its class, as the PTG's expression text over
+#: ``NT`` and the task's parameters (``k``: the step; ``m``: the tile row):
+#: the panel first, then the updates of the rows it unlocks soonest.
+#: ``cholesky_dtd`` evaluates the same text at insertion.
+PRIORITY = {
+    "potrf": "(NT - k) * 1000",
+    "trtri": "(NT - k) * 1000 - 1",  # right behind its potrf
+    "trsm": "(NT - m) * 100",
+    "syrk": "(NT - m) * 100 + 10",
+    "gemm": "(NT - m) * 10",
+}
 
 
 def cholesky_ptg(*, use_tpu: bool = True, use_cpu: bool = True,
@@ -57,15 +76,28 @@ def cholesky_ptg(*, use_tpu: bool = True, use_cpu: bool = True,
     configurations (their ``control.options``), which their limits have
     to fail; nothing else uses it."""
     ptg = PTG("dpotrf")
+    add_dpotrf_classes(ptg, dpotrf_bodies(
+        use_tpu=use_tpu, use_cpu=use_cpu, use_pallas=use_pallas,
+        use_trtri=use_trtri, bf16_updates=bf16_updates),
+        use_trtri=use_trtri)
+    return ptg
 
+
+def dpotrf_bodies(*, use_tpu: bool = True, use_cpu: bool = True,
+                  use_pallas: bool = False, use_trtri: bool = False,
+                  bf16_updates: bool = False):
+    """Class name -> ``{"cpu": body, "tpu": body}`` (the incarnations
+    asked for) of the dpotrf task classes, as :func:`cholesky_ptg`'s
+    options say: what a PTG class's ``body()`` takes as keywords and
+    what :func:`cholesky_dtd` hands ``insert_task`` by device type."""
     def bodies(cpu, tpu):
         kw = {}
         if use_cpu:
-            kw["cpu"] = cpu
+            kw[DEV_CPU] = cpu
         if use_tpu or use_pallas:
             # a pallas chore is a device chore: requesting it implies the
             # device incarnation even when use_tpu wasn't set explicitly
-            kw["tpu"] = tpu
+            kw[DEV_TPU] = tpu
         return kw
 
     syrk_dev = tiles.syrk_tpu
@@ -82,14 +114,13 @@ def cholesky_ptg(*, use_tpu: bool = True, use_cpu: bool = True,
                            else tiles.trsm_inv_tpu)
     else:
         trsm_body = bodies(tiles.trsm_cpu, tiles.trsm_tpu)
-    add_dpotrf_classes(ptg, {
+    return {
         "potrf": bodies(tiles.potrf_cpu, tiles.potrf_tpu),
         "trtri": bodies(tiles.trtri_cpu, tiles.trtri_tpu),
         "trsm": trsm_body,
         "syrk": bodies(tiles.syrk_cpu, syrk_dev),
         "gemm": bodies(tiles.gemm_update_cpu, gemm_dev),
-    }, use_trtri=use_trtri)
-    return ptg
+    }
 
 
 def add_dpotrf_classes(ptg: PTG, bodies, *, first="A({m}, {n})",
@@ -116,7 +147,7 @@ def add_dpotrf_classes(ptg: PTG, bodies, *, first="A({m}, {n})",
 
     potrf = ptg.task_class("potrf", k="0 .. NT-1")
     potrf.affinity("A(k, k)")
-    potrf.priority("(NT - k) * 1000")
+    potrf.priority(PRIORITY["potrf"])
     potrf.flow("T", INOUT,
                f"<- (k == 0) ? {tile('k', 'k')} : A syrk(k-1, k)",
                # trtri mode: the factored block feeds the inverter, which
@@ -129,7 +160,7 @@ def add_dpotrf_classes(ptg: PTG, bodies, *, first="A({m}, {n})",
     if use_trtri:
         trtri = ptg.task_class("trtri", k="0 .. NT-2")
         trtri.affinity("A(k, k)")
-        trtri.priority("(NT - k) * 1000 - 1")  # right behind its potrf
+        trtri.priority(PRIORITY["trtri"])
         trtri.flow("T", IN, "<- T potrf(k)")
         trtri.flow("I", INOUT,
                    "<- NEW",
@@ -138,7 +169,7 @@ def add_dpotrf_classes(ptg: PTG, bodies, *, first="A({m}, {n})",
 
     trsm = ptg.task_class("trsm", k="0 .. NT-2", m="k+1 .. NT-1")
     trsm.affinity("A(m, k)")
-    trsm.priority("(NT - m) * 100")
+    trsm.priority(PRIORITY["trsm"])
     if use_trtri:
         trsm.flow("I", IN,
                   "<- I trtri(k)")
@@ -156,7 +187,7 @@ def add_dpotrf_classes(ptg: PTG, bodies, *, first="A({m}, {n})",
 
     syrk = ptg.task_class("syrk", k="0 .. NT-2", m="k+1 .. NT-1")
     syrk.affinity("A(m, m)")
-    syrk.priority("(NT - m) * 100 + 10")
+    syrk.priority(PRIORITY["syrk"])
     syrk.flow("A", INOUT,
               f"<- (k == 0) ? {tile('m', 'm')} : A syrk(k-1, m)",
               "-> (k == m-1) ? T potrf(m) : A syrk(k+1, m)")
@@ -166,7 +197,7 @@ def add_dpotrf_classes(ptg: PTG, bodies, *, first="A({m}, {n})",
 
     gemm = ptg.task_class("gemm", k="0 .. NT-3", m="k+2 .. NT-1", n="k+1 .. m-1")
     gemm.affinity("A(m, n)")
-    gemm.priority("(NT - m) * 10")
+    gemm.priority(PRIORITY["gemm"])
     gemm.flow("A", INOUT,
               f"<- (k == 0) ? {tile('m', 'n')} : A gemm(k-1, m, n)",
               "-> (k == n-1) ? C trsm(n, m) : A gemm(k+1, m, n)")
@@ -182,3 +213,83 @@ def run_cholesky(context, A, *, use_tpu: bool = True, use_cpu: bool = True) -> N
     ok = tp.wait(timeout=None)
     if not ok:
         raise RuntimeError("cholesky taskpool did not quiesce")
+
+
+def cholesky_dtd(tp, A, *, use_tpu: bool = True, use_cpu: bool = False,
+                 use_pallas: bool = False, use_trtri: bool = False,
+                 bf16_updates: bool = False, tile=None) -> int:
+    """Insert the tile Cholesky of ``A`` (lower, in place) into the DTD
+    pool ``tp``, task by task: the loop nest of DPLASMA's
+    ``testing_dpotrf_dtd.c``.  The runtime is told nothing of the graph;
+    it finds every dependency from the access mode of each tile argument
+    as the task arrives.  The user's calling sequence is the
+    reference's::
+
+        tp = DTDTaskpool(ctx)
+        cholesky_dtd(tp, A)       # returns while tasks still run
+        tp.wait()
+        tp.flush_all(A)           # the factor comes home here, not before
+        tp.close()
+
+    Bodies and priorities are :func:`cholesky_ptg`'s (same options), so
+    the two DSLs run one mathematics; the right-looking order has no
+    write-after-read hazard, so nothing is renamed.  ``tile(m, n)`` names
+    tile ``(m, n)`` for ``tp.insert_task`` (default: ``A.data_of``; a
+    pool that tracks host arrays, ``NativeDTD``, is given the arrays).
+    Returns the number of tasks inserted."""
+    from ..device import scratch
+
+    if use_trtri and tile is not None:
+        raise ValueError("use_trtri makes scratch tiles of its own: it "
+                         "cannot name them through a caller's tile()")
+    NT = A.mt
+    if tile is None:
+        tile = A.data_of
+    bodies = dpotrf_bodies(use_tpu=use_tpu, use_cpu=use_cpu,
+                           use_pallas=use_pallas, use_trtri=use_trtri,
+                           bf16_updates=bf16_updates)
+
+    def body(cls):
+        b = bodies[cls]
+        # a pool that knows no device types takes the one CPU body
+        return b if len(b) > 1 or DEV_CPU not in b else b[DEV_CPU]
+
+    potrf, trtri, trsm, syrk, gemm = (
+        body(c) for c in ("potrf", "trtri", "trsm", "syrk", "gemm"))
+    prio = {c: compile(text, f"<priority of {c}>", "eval")
+            for c, text in PRIORITY.items()}
+    inserted = 0
+    for k in range(NT):
+        env = {"NT": NT, "k": k}
+        tp.insert_task(potrf, (tile(k, k), INOUT | AFFINITY),
+                       priority=eval(prio["potrf"], env), name="potrf")
+        inserted += 1
+        panel = tile(k, k)
+        if use_trtri and k < NT - 1:
+            # the inverse of the factored block: a scratch tile that
+            # lives where it is made and dies with the column's last trsm
+            panel = scratch.new(("trtri", k), A.tile_shape(k, k),
+                                A.dtype_of(k, k))
+            scratch.add_users(panel, NT - k)
+            tp.insert_task(trtri, (tile(k, k), IN),
+                           (panel, INOUT | AFFINITY),
+                           priority=eval(prio["trtri"], env), name="trtri")
+            inserted += 1
+        for m in range(k + 1, NT):
+            env["m"] = m
+            tp.insert_task(trsm, (panel, IN),
+                           (tile(m, k), INOUT | AFFINITY),
+                           priority=eval(prio["trsm"], env), name="trsm")
+        for m in range(k + 1, NT):
+            env["m"] = m
+            tp.insert_task(syrk, (tile(m, m), INOUT | AFFINITY),
+                           (tile(m, k), IN),
+                           priority=eval(prio["syrk"], env), name="syrk")
+            p = eval(prio["gemm"], env)
+            for n in range(k + 1, m):
+                tp.insert_task(gemm, (tile(m, n), INOUT | AFFINITY),
+                               (tile(m, k), IN), (tile(n, k), IN),
+                               priority=p, name="gemm")
+            inserted += m - k
+        inserted += NT - k - 1
+    return inserted

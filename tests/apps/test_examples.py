@@ -29,6 +29,7 @@ ALL = [
     os.path.join("dtd", "dtd_helloworld.py"),
     os.path.join("dtd", "dtd_hello_arg.py"),
     os.path.join("dtd", "dtd_untied.py"),
+    os.path.join("dtd", "dtd_potrf.py"),
 ]
 
 

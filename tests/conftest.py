@@ -51,6 +51,7 @@ import bench_testlib  # noqa: E402
 bench_testlib.TINY.setdefault("pump_ooc", "tiny_pump_ooc")
 bench_testlib.TINY.setdefault("pump_stencil", "tiny_pump_stencil")
 bench_testlib.TINY.setdefault("pump_mle", "tiny_pump_mle")
+bench_testlib.TINY.setdefault("dtd", "tiny_dtd")
 
 
 def pytest_configure(config):
@@ -68,14 +69,19 @@ _OVERTAKEN = {
     "test_the_new_entries_of_benchmark_json":
         "pins the out-of-core cell as the LAST workload and as the last "
         "cell of tile_solve_s / tile_home_s (PR 30); PR 32 appends the "
-        "stencil cell after it, as a new cell must: a benchmark PR has "
-        "to pin by membership, not by position",
+        "stencil cell after it, PR 36 the likelihood cell and PR 39 the "
+        "DTD cell, as a new cell must: a benchmark PR has to pin by "
+        "membership, not by position (as PR 39's own "
+        "test_bench_dtd.py::test_the_new_entries_of_benchmark_json_by_"
+        "membership does)",
     "benchmark_harness/test_bench_waits.py::"
     "test_every_new_metric_is_an_entry_with_a_reader_of_its_own":
         "pins the workloads of PR 34's five metrics to the six tile cells "
         "there were, and the five as the LAST of per_layer; PR 36 appends "
         "the likelihood cell to their lists (it reports tile_solve_s, so "
-        "it has to report them) and its own five metrics after them",
+        "it has to report them) and its own five metrics after them, PR "
+        "39 the DTD cell and its four: the queued benchmark PR has to pin "
+        "by membership",
 }
 
 

@@ -210,9 +210,12 @@ def test_detach_after_async_writeback_commits_exactly_once():
         assert tp.wait(timeout=120)
         com = dev._wb_committer()
         assert com is not None, "stage_depth default engages the committer"
-        com.flush()
-        committed_async = com.stats["committed"]
-        assert committed_async > 0, "watermark never drained mid-run"
+        # an inserted task's tile goes home at its flush: two of the four
+        # through the committer now, the others at detach
+        assert com.stats["committed"] == 0 and dev.stats["bytes_out"] == 0
+        tp.data_flush(tiles[0])
+        tp.data_flush(tiles[1])
+        assert com.stats["committed"] == 2
     finally:
         ctx.fini()  # detach: flush barrier + sync batch for the rest
         _unset("runtime", "wb_window_mb")
@@ -318,8 +321,8 @@ def test_packed_read_copy_never_flushed_home(ctx):
 
 
 def test_committer_death_fails_pool_not_hang():
-    """An injected D2H failure inside the committer thread surfaces as
-    a pool failure (the next epilog enqueue re-raises the sticky error)
+    """An injected D2H failure inside the committer thread surfaces at
+    the flush that needed it (the barrier re-raises the sticky error)
     — the run terminates, it does not wedge."""
     _set("runtime", "wb_window_mb", 1)
     ctx = Context(nb_cores=2)
@@ -341,21 +344,13 @@ def test_committer_death_fails_pool_not_hang():
         tp = DTDTaskpool(ctx)
         for _ in range(10):
             tp.insert_task({DEV_TPU: lambda x: x + 1.0}, (d, INOUT))
-        ok = tp.wait(timeout=120)
-        if ok:
-            # the pool drained before the committer's first (failing)
-            # drain hit an enqueue: force it — the failure must still
-            # surface loudly at the flush barrier
-            com.kick()
-            deadline = time.monotonic() + 30
-            while com.error is None and time.monotonic() < deadline:
-                time.sleep(0.01)
-            with pytest.raises(RuntimeError, match="committer"):
-                com.flush()
-        else:
-            # the sticky error re-raised at an epilog enqueue: pool
-            # failure, not a hang
-            assert state["boomed"]
+        # nothing of an inserted task's is the committer's before its
+        # flush: the pool drains, and the failure surfaces, loudly, at
+        # the flush barrier
+        assert tp.wait(timeout=120)
+        with pytest.raises(RuntimeError, match="committer"):
+            tp.data_flush(d)
+        assert state["boomed"]
         assert not com.healthy
         # teardown below must not trip over the dead committer: drop it
         # (detach then takes the synchronous batch path) and restore D2H

@@ -761,7 +761,10 @@ def test_an_error_inside_the_wave_commit_fails_the_pool_loudly():
 
         body._jit_key = ("dead_committer_body",)
         for t in tiles:
-            tp.insert_task({dev.device_type: body}, (t, INOUT))
+            task = tp.insert_task({dev.device_type: body}, (t, INOUT))
+            # every version the committer's, as of a task whose builder
+            # said nothing (an inserted task's goes home at its flush)
+            task._tpu_home = None
         with dev._lock:
             dev._manager_active = False
         assert tp.wait(timeout=60) is False
