@@ -29,7 +29,8 @@ from .taskpool import Taskpool
 class ExecutionStream:
     """Per-worker state (reference ``parsec_execution_stream_t``)."""
 
-    __slots__ = ("worker_id", "vp_id", "context", "next_task", "stats", "sched_obj", "profile")
+    __slots__ = ("worker_id", "vp_id", "context", "next_task", "stats", "sched_obj", "profile",
+                 "managing")
 
     def __init__(self, worker_id: int, context: "Context", vp_id: int = 0):
         self.worker_id = worker_id
@@ -39,6 +40,11 @@ class ExecutionStream:
         self.stats: Dict[str, int] = {"executed": 0, "selected": 0, "steals": 0}
         self.sched_obj = None  # scheduler-private
         self.profile = None    # profiling stream
+        #: the accelerator device whose manager this stream's thread is,
+        #: while it is (``kernel_scheduler`` sets and clears it): of what
+        #: the thread releases then, the device is asked first
+        #: (``keep_released``; ``scheduling.schedule_ready``)
+        self.managing = None
 
 
 class Context:

@@ -25,7 +25,15 @@ if TYPE_CHECKING:  # pragma: no cover
 
 def schedule_ready(context: "Context", es: Optional["ExecutionStream"], tasks: Iterable["Task"], distance: int = 0) -> None:
     """Make tasks runnable; if called from a worker, keep the best one as
-    the worker's immediately-next task (cache-warm successor execution)."""
+    the worker's immediately-next task (cache-warm successor execution).
+
+    A thread that is an accelerator device's manager (``es.managing``)
+    keeps none: it would not look at ``es.next_task`` before its device's
+    queue has run dry, and the best successor of a completion is the
+    critical path's.  What such a thread releases and only its device can
+    run stays with the device, which queues it itself
+    (``keep_released``); the rest goes to the scheduler, and a worker is
+    woken for it."""
     batch: List["Task"] = [t for t in tasks if t is not None]
     if not batch:
         return
@@ -35,12 +43,17 @@ def schedule_ready(context: "Context", es: Optional["ExecutionStream"], tasks: I
             t.counted = True
             tp.tdm.taskpool_addto_nb_tasks(tp, 1)
     # BEGIN sees the full batch, END what was pushed (the kept-next task
-    # popped): the same list, as the sites' subscribers expect
+    # popped, the tasks a managed device kept left out): the same list,
+    # as the sites' subscribers expect
     with pins.span("core:schedule", es, batch, pool=getattr(tp, "taskpool_id", 0),
                    rank=context.rank, n=len(batch)):
-        if es is not None and es.next_task is None and distance == 0:
-            best = max(range(len(batch)), key=lambda i: batch[i].priority)
-            es.next_task = batch.pop(best)
+        if es is not None and distance == 0:
+            dev = es.managing
+            if dev is not None:
+                batch[:] = [t for t in batch if not dev.keep_released(t)]
+            elif es.next_task is None:
+                best = max(range(len(batch)), key=lambda i: batch[i].priority)
+                es.next_task = batch.pop(best)
         if batch:
             context.scheduler.schedule(es, batch, distance)
             # only a task actually pushed to the scheduler warrants waking
@@ -183,6 +196,7 @@ def task_progress(context: "Context", es: "ExecutionStream", task: "Task") -> Ho
             task.selected_device.enabled = False
         elif task.selected_chore is not None:
             task.selected_chore.enabled = False
+            tc.chores_changed()
         _deselect(task)
         schedule_ready(context, es, [task], distance=1)
     elif rc == HookReturn.ERROR:

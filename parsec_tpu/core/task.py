@@ -92,6 +92,8 @@ class TaskClass:
             if f.index < 0:
                 f.index = i
         self.chores: List[Chore] = list(chores)
+        #: ``only_device_type``'s answer (None: not asked yet)
+        self._only_device: Optional[str] = None
         self.nb_parameters = nb_parameters
         #: number of input dependencies a task must see released before it
         #: becomes ready (counter-mode tracking); front-ends may instead use
@@ -114,6 +116,31 @@ class TaskClass:
 
     def add_chore(self, chore: Chore) -> None:
         self.chores.append(chore)
+        self.chores_changed()
+
+    def chores_changed(self) -> None:
+        """A chore was added or switched off: what was worked out from
+        the chores is asked again."""
+        self._only_device = None
+
+    def only_device_type(self) -> str:
+        """The one accelerator device type whose module alone can run
+        this class's tasks: every enabled chore is of that type and none
+        asks an ``evaluate`` task by task.  ``""`` where a CPU can run
+        them, where several types can, or where no chore is enabled.
+        Worked out once a class: what a device manager releases goes
+        straight into its own queue by this
+        (``scheduling.schedule_ready``)."""
+        only = self._only_device
+        if only is None:
+            live = [c for c in self.chores if c.enabled]
+            types = {c.device_type for c in live}
+            only = ""
+            if len(types) == 1 and DEV_CPU not in types \
+                    and all(c.evaluate is None for c in live):
+                only, = types
+            self._only_device = only
+        return only
 
     def chores_for(self, device_types: Sequence[str]) -> List[Chore]:
         return [c for c in self.chores if c.enabled and c.device_type in device_types]
@@ -150,6 +177,7 @@ class Task:
         "_tpu_attempts",
         "_tpu_effects",
         "_tpu_enq",
+        "_tpu_direct",
         "_tpu_scratch",
         "_tpu_home",
         "_tpu_next",
@@ -202,6 +230,9 @@ class Task:
         #: ``perf_counter_ns`` at the moment the device module queued the
         #: task (its ready-queue wait: the ``waited_us`` of ``dev:wave``)
         self._tpu_enq = 0
+        #: the device's manager queued the task itself, on the thread
+        #: that released it (``direct`` on ``dev:wave``)
+        self._tpu_direct = False
         #: the scratch tiles among the task's flows, as the device module
         #: staged them: it releases one user of each in the task's epilog
         self._tpu_scratch: Tuple = ()

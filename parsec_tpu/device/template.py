@@ -27,7 +27,12 @@ To build a real backend from this template:
      ``scheduling.complete_execution(...)`` from your manager thread —
      the reference GPU manager-thread state machine
      (``device_gpu.c:2510-2730``; see ``tpu.py`` for the full version
-     with stage-in/out phases, dual-LRU HBM residency and async lanes);
+     with stage-in/out phases, dual-LRU HBM residency and async lanes).
+     A manager that completes tasks on a worker's stream may mark it
+     (``es.managing = self`` for the time of its loop) and answer
+     ``keep_released(task)``: ``schedule_ready`` then asks it first about
+     every task that thread releases, and parks no successor on the
+     stream;
    * **stage in/out**: move ``Data`` copies to/from your memory space,
      bump ``data.attach_copy(self.data_index, ...)`` versions, and
      account ``stats["bytes_in"/"bytes_out"]``;

@@ -158,6 +158,10 @@ class TpuDevice(Device):
         #: were the version the committer's drain collected
         #: (device/staging.py)
         self.stats.update(wb_started_early=0, wb_early_hits=0)
+        #: tasks that this device's manager released, by the way they
+        #: took from there: into this queue on the manager's own thread,
+        #: or through the scheduler and a worker (:meth:`keep_released`)
+        self.stats.update(handed_direct=0, handed_sched=0)
         #: precisions (``TiledMatrix(tile_dtype=...)``): tiles a body
         #: marked ``_converts`` wrote (a lower-precision twin made ONCE,
         #: where its source is produced) and their bytes; reads of such a
@@ -225,6 +229,10 @@ class TpuDevice(Device):
         #: order (``tpu_eager_complete=0``): JAX executes one device
         #: queue in order, so one queue is what there is to poll
         self._deferred: Deque[_InFlight] = collections.deque()
+        #: what the manager's completions released and this device alone
+        #: can run (:meth:`keep_released`), until the manager hands it
+        #: over between two drains; the manager's thread alone touches it
+        self._released: List[Task] = []
         #: eager completion: a single-controller JAX device queue already
         #: orders computations by data dependencies, so successor release
         #: does not need to wait for device events — the runtime completes
@@ -345,14 +353,25 @@ class TpuDevice(Device):
     # ------------------------------------------------------------------
     def kernel_scheduler(self, es, task: Task) -> HookReturn:
         """Reference ``parsec_device_kernel_scheduler``
-        (device_gpu.c:2510-2730)."""
+        (device_gpu.c:2510-2730).  The manager's own thread comes here
+        too, with a task that one of its completions released and that
+        only this device can run (:meth:`keep_released`): it queues the
+        task for its next drain."""
         task._tpu_enq = time.perf_counter_ns()  # ready-queue wait starts
+        if es is not None and es.managing is self:
+            # (no lock round: nobody but this thread takes from the
+            # queue, and the check that ends its loop is its own)
+            task._tpu_direct = True
+            self._pending.append(task)
+            return HookReturn.ASYNC
         with pins.held(self._lock, "dev_lock"):
             self._pending.append(task)
             if self._manager_active:
                 return HookReturn.ASYNC  # a manager is already running
             self._manager_active = True
         # this worker becomes the manager
+        if es is not None:
+            es.managing = self
         try:
             self._manager_loop(es)
         except BaseException:
@@ -361,7 +380,40 @@ class TpuDevice(Device):
             with self._lock:
                 self._manager_active = False
             raise
+        finally:
+            if es is not None:
+                es.managing = None
         return HookReturn.ASYNC  # completions were issued by the manager
+
+    def keep_released(self, task: Task) -> bool:
+        """``scheduling.schedule_ready`` on the thread that is this
+        device's manager: one of its completions released ``task``.
+        Where the task's class can run on this device alone, the device
+        keeps it (True) and its manager hands it over itself
+        (:meth:`_hand_over`): no push to the scheduler, no wake-up, no
+        worker carries it here.  False sends the task through the
+        scheduler: a class that a CPU or another device can run (a DTD
+        comm task among them), a failed pool's task (``_next_task``
+        discards it), a device switched off."""
+        if self.enabled and not task.taskpool.failed \
+                and task.task_class.only_device_type() == self.device_type:
+            self.stats["handed_direct"] += 1
+            self._released.append(task)
+            return True
+        self.stats["handed_sched"] += 1
+        return False
+
+    def _hand_over(self, es) -> None:
+        """The tasks this thread's completions released and the device
+        kept, progressed as a worker would progress them
+        (``Context._run_task``: ``core:prepare_input``, the selection,
+        the chore hook) — which ends in :meth:`kernel_scheduler` queueing
+        each for the next drain.  Between two drains and under no span
+        of this module: a ``prepare_input`` that raises fails the
+        task's own pool there, and no commit knows of it."""
+        released, self._released = self._released, []
+        for task in released:
+            self.context._run_task(es, task)
 
     def _manager_loop(self, es) -> None:
         # phase: check_in_deps + exec — submit everything pending.
@@ -369,6 +421,7 @@ class TpuDevice(Device):
         # (one jitted multi-body program per wave — one enqueue RPC
         # instead of one per task); everything else goes per-task.
         while True:
+            self._hand_over(es)
             drained: List[Task] = []
             with pins.held(self._lock, "dev_lock"):
                 while self._pending:
@@ -390,7 +443,8 @@ class TpuDevice(Device):
             with self._span("dev:poll"):
                 progressed = self._poll_deferred(es)
             with pins.held(self._lock, "dev_lock"):
-                if not self._pending and not self._deferred:
+                if not self._pending and not self._deferred \
+                        and not self._released:
                     self._manager_active = False
                     return
             if not progressed and self._deferred:
@@ -625,6 +679,7 @@ class TpuDevice(Device):
             sig = self._signature_of(task)
             with self._span("dev:submit_one", cls=task.task_class.name, n=1,
                             batch=self._span_batch, waited_us=waited,
+                            direct=int(task._tpu_direct),
                             dtypes=sig[1].dtypes if sig else "") as sp:
                 self._submit(task, es, complete=complete, span=sp)
         except Exception as e:
@@ -854,6 +909,8 @@ class TpuDevice(Device):
                          for t in grp) // 1000 if drained_ns else 0
             with self._span("dev:wave", cls=cls, n=cnt,
                             batch=self._span_batch, waited_us=waited,
+                            direct=sum(t._tpu_direct for t in grp)
+                            if drained_ns else 0,
                             dtypes=plan.dtypes) as sp:
                 self._submit_chunk(grp, body, base_key, plan, es, complete,
                                    sp)
