@@ -61,8 +61,9 @@ class Chore:
         self.body_fn = None
         #: the device module's memo for this chore: ``(body_fn it was
         #: worked out for, what a wave signature starts with, the body's
-        #: per-flow staging hooks)``
-        self.wave_key: Optional[Tuple[Any, Any, Any]] = None
+        #: per-flow staging hooks, whether its signatures may name
+        #: donated flows: the body names no donated arguments itself)``
+        self.wave_key: Optional[Tuple[Any, ...]] = None
 
 
 class TaskClass:
@@ -180,6 +181,7 @@ class Task:
         "_tpu_direct",
         "_tpu_scratch",
         "_tpu_home",
+        "_tpu_donate",
         "_tpu_next",
         "_tpu_sig",
     )
@@ -240,6 +242,14 @@ class Task:
         #: device module's write-back committer takes only these); None
         #: where whoever built the task does not know: then every one
         self._tpu_home: Optional[Tuple[int, ...]] = None
+        #: positions in ``body_args`` of the read-write flows whose INPUT
+        #: version this task is the only consumer of (no other task reads
+        #: it, its producer does not send it home): the device module may
+        #: give that tile's array to the task's program to write the
+        #: output over, where it finds nobody else holding the array
+        #: (``TpuDevice._not_sole``); None where whoever built the task
+        #: does not know: then nothing is donated, the body is functional
+        self._tpu_donate: Optional[Tuple[int, ...]] = None
         #: where the task's row starts in its pool's table of next uses
         #: (``taskpool.next_use[_tpu_next + position in body_args]``: the
         #: rank of the tile's next reader, ``device/residency.py``); -1

@@ -186,6 +186,10 @@ class Residency:
         stats.setdefault("tiles_by_dtype", {})
         #: data_id -> how many stagings hold the tile: no victim
         self._pins: Dict[int, int] = {}
+        #: data_ids of an eviction's victims while they go home with the
+        #: lock free (:meth:`make_room`): their copies run through an
+        #: alias of the array, which nobody may donate meanwhile
+        self.going_home: set = set()
         #: data_id -> the rank of the tile's next reader, or
         #: :data:`NEVER`; absent: unknown.  Only ever raised while the
         #: copy stays (the readers of a tile may run out of rank order:
@@ -338,6 +342,7 @@ class Residency:
             self._by_dtype.clear()
             self._charges = self._most = 0
             self._pins.clear()
+            self.going_home.clear()
             self._next.clear()
             self._evicted.clear()
             self.used = 0 if self.zone is None else self.zone.used
@@ -382,6 +387,13 @@ class Residency:
             else 0
 
     # -- pins --------------------------------------------------------------
+    @property
+    def pins(self) -> Dict[int, int]:
+        """data_id -> how many stagings pin the tile (read under the
+        lock: who would donate a tile's array asks whether anybody but
+        its own chunk holds it)."""
+        return self._pins
+
     def pin(self, data: Data) -> None:
         """``data`` is staged for a chunk that has not been committed
         (or for the batch the lane runs ahead of): no victim until
@@ -473,9 +485,11 @@ class Residency:
         with pins.held(self.lock, "res_lock"):
             need = self._in_use() + nbytes - self._budget
             leaving = self._leaving(need) if need > 0 else None
+            if leaving is not None:
+                home = leaving.home
+                self.going_home.update(v.data_id for v in home)
         if leaving is None:
             return
-        home = leaving.home
         with self._span("dev:evict", need=need) as sp:
             wait_us = 0
             try:
@@ -485,6 +499,8 @@ class Residency:
                 # (also after a write-back that raised: the victims are
                 # in no LRU, and what did not get home stays)
                 with pins.held(self.lock, "res_lock"):
+                    self.going_home.difference_update(
+                        v.data_id for v in home)
                     self._leave(leaving, wait_us, sp, check=True)
 
     def _evict(self, need: int) -> bool:

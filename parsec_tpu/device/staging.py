@@ -928,6 +928,17 @@ class WritebackCommitter:
                 self._flushing = False
             self._raise_if_dead()
 
+    def holding(self, data_ids) -> set:
+        """Of ``data_ids``, the tiles queued here or in a drain: a copy
+        home of theirs may be on its way through an alias of the array
+        (a last version's was started at its hand-over), so nobody may
+        donate that array now."""
+        with self._cv:
+            if not self._pending and not self._inflight:
+                return set()
+            return {d for d in data_ids
+                    if d in self._pending or d in self._inflight}
+
     # -- gauges ----------------------------------------------------------
     def pending(self) -> int:
         with self._cv:

@@ -40,7 +40,12 @@ Departures from the reference, by TPU design:
   ``device_put`` and "free" is dropping the reference;
 * task bodies are **functional**: a TPU chore body maps input arrays to
   fresh output arrays (XLA semantics), instead of mutating tile memory;
-  outputs rebind the device copies of writable flows in declaration order;
+  outputs rebind the device copies of writable flows in declaration order.
+  Where the task's builder knows that a read-write flow's input version
+  has no other consumer (``Task._tpu_donate``) and the staging walk finds
+  nobody else holding its array, the program is compiled with that
+  argument DONATED: XLA writes the output over the input's buffer, and a
+  call allocates nothing for it (``_stage_chunk``, "Donation");
 * kernels are jit-compiled once per (body, shapes, dtypes) by XLA and
   cached — the analogue of the reference's per-task-class dyld/cubin
   function lookup (``device_cuda_module.c`` find_function).  Compiles
@@ -136,6 +141,12 @@ class TpuDevice(Device):
         #: the same program (a wave of a stencil's generation passes
         #: every tile to up to five of its tasks)
         self.stats.update(tile_args_passed=0, tile_args_repeated=0)
+        #: tile operands DONATED to their program (a read-write flow's
+        #: input version that nobody else reads: its output is written
+        #: where it stands), and tasks that went out under the functional
+        #: program because the staging walk found somebody else holding
+        #: such a tile's array (0 on a healthy run)
+        self.stats.update(tile_args_donated=0, donation_refused=0)
         #: calls of device programs: of the executable its ``_jit_cache``
         #: entry holds, and through the cache by the arguments' signature
         #: (an entry's first call, every call of a ``_static_values``
@@ -294,6 +305,15 @@ class TpuDevice(Device):
                 # per-rank explosion the auto-disable dodged is gone.
                 self._wave_min = 0
         self._jit_cache: Dict[Any, Any] = {}
+        #: a signature names donated flows only where the commit follows
+        #: the call at once (eager completion: until its commit a donated
+        #: tile's copy here is a deleted array) and no peer may hold the
+        #: array (device-capable fabrics ship ``jax.Array``s uncopied)
+        self._may_donate = self._eager \
+            and getattr(context, "nranks", 1) <= 1
+        #: data_ids the transfer lane pinned for the batch being
+        #: submitted: those pins are this batch's own
+        self._ahead_ids: frozenset = frozenset()
         # -- residency and the staging pipeline ---------------------------
         #: the write-back halves (device/staging.py) and the resident
         #: tiles with their accounting (device/residency.py); the lock
@@ -521,6 +541,9 @@ class TpuDevice(Device):
         if tasks:
             self._span_pool = _pool_of(tasks[0])
         self._span_batch = batch_no
+        ahead = self._prestaged.get(batch_no)
+        if ahead:
+            self._ahead_ids = frozenset(d.data_id for d in ahead)
         with self._span("dev:submit_batch", batch=batch_no, n=len(tasks)):
             try:
                 self._submit_units(self._units_of(tasks), es, False)
@@ -528,6 +551,7 @@ class TpuDevice(Device):
                 ahead = self._prestaged.pop(batch_no, None)
                 if ahead:
                     self._res.unpin(ahead)
+                    self._ahead_ids = frozenset()
             # a transient-submit retry re-queues through ``_pending``
             # (the manager loop's channel); there is no manager in pump
             # mode, so drain retries here before handing the batch back
@@ -640,13 +664,14 @@ class TpuDevice(Device):
         return ValuePlan(body, dev_args, sum(
             1 for s in task.body_args or () if s[0] == "value"))
 
-    def _count_values(self, plan: ValuePlan, ntasks: int, sp, flat,
-                      nouts: int = 0) -> None:
+    def _count_values(self, plan: ValuePlan, ntasks: int, flat,
+                      nouts: int = 0, don: int = 0) -> Dict[str, int]:
         """``ntasks`` tasks went out under ``plan`` with the program's
-        arguments ``flat``: the counters, and the same on the
-        ``dev:wave`` / ``dev:submit_one`` span with ``outs``, the
-        outputs its epilog commits; ``rep`` counts the tile operands
-        that are an earlier operand's array again."""
+        arguments ``flat``: the counters, and the same for the
+        ``dev:wave`` / ``dev:submit_one`` span (returned) with ``outs``,
+        the outputs its epilog commits; ``rep`` counts the tile operands
+        that are an earlier operand's array again, ``don`` those the
+        program was given to write its outputs over."""
         drop, pack, pos, tdrop = (
             plan.dropped * ntasks, plan.packed * ntasks,
             plan.positional * ntasks, plan.tiles_dropped * ntasks)
@@ -658,9 +683,9 @@ class TpuDevice(Device):
         self.stats["tile_args_dropped"] += tdrop
         self.stats["tile_args_passed"] += len(tiles)
         self.stats["tile_args_repeated"] += rep
-        if sp is not None:
-            sp.note(vdrop=drop, vpack=pack, vpos=pos, tdrop=tdrop,
-                    outs=nouts, rep=rep)
+        self.stats["tile_args_donated"] += don
+        return dict(vdrop=drop, vpack=pack, vpos=pos, tdrop=tdrop,
+                    outs=nouts, rep=rep, don=don)
 
     def _count_converts(self, staged: List[_Staged], outs) -> None:
         """A program of a body marked ``_converts`` went out: its outputs
@@ -775,8 +800,13 @@ class TpuDevice(Device):
     def _wave_body_key(body):
         """What a wave signature starts with, or None for a body whose
         tasks go out alone: bodies with baked static values (per-task
-        traces), donation (aliasing across a shared program is unsafe)
-        or custom staging hooks; fused supertasks (dsl.fusion) are
+        traces), bodies that name donated arguments THEMSELVES
+        (``_donate_args``: the body's author vouches for whole-matrix
+        in-place chains, positions no signature compares, and their
+        outputs never go home) or custom staging hooks — what a wave
+        donates is decided a flow at a time, by the graph and the staging
+        walk, and is part of the signature (:meth:`_wave_signature`);
+        fused supertasks (dsl.fusion) are
         already coarse-grained multi-body programs with their own cache
         key — re-batching them into waves would nest programs for no
         dispatch win."""
@@ -797,7 +827,11 @@ class TpuDevice(Device):
         walk and THE commit.  What the body fixes is asked once a chore;
         shapes, dtypes and modes are compared as the objects they are,
         and the list of flows is interned as its :class:`FlowPlan`, so a
-        signature hashes and compares by identity from then on."""
+        signature hashes and compares by identity from then on.  The
+        flows whose input version the task may donate (``_tpu_donate``,
+        where this device donates at all and the body names no donated
+        arguments of its own) are part of what is interned: the tasks of
+        one wave donate the same positions."""
         chore = task.selected_chore
         body = chore.body_fn if chore is not None else None
         if body is None:
@@ -810,8 +844,9 @@ class TpuDevice(Device):
             so = getattr(body, "_stage_out", None) or {}
             memo = chore.wave_key = (
                 body, self._wave_body_key(body),
-                {n: (si.get(n), so.get(n)) for n in {*si, *so}} or None)
-        _body, wave_key, hooks = memo
+                {n: (si.get(n), so.get(n)) for n in {*si, *so}} or None,
+                not getattr(body, "_donate_args", None))
+        _body, wave_key, hooks, by_flow = memo
         flows: List[Any] = []
         nth = -1
         for kind, payload, mode in (task.body_args or ()):
@@ -845,10 +880,12 @@ class TpuDevice(Device):
                 flows.append(("scratch", tuple(payload[0]), payload[1]))
             else:
                 flows.append(kind)
-        key = tuple(flows)
+        donate = (task._tpu_donate or ()) \
+            if by_flow and self._may_donate else ()
+        key = (tuple(flows), donate)
         plan = self._flow_plans.get(key)
         if plan is None:
-            plan = self._flow_plans[key] = FlowPlan(key)
+            plan = self._flow_plans[key] = FlowPlan(*key)
         return (wave_key, plan)
 
     def _submit_wave(self, tasks: List[Task], es, complete: bool = True,
@@ -869,7 +906,14 @@ class TpuDevice(Device):
         Failure containment is a PER-CHUNK invariant: a chunk's
         staging/trace/enqueue errors RAISE before any task of THAT chunk
         has side effects, so the manager's per-task fallback is safe for
-        every not-yet-committed task (functional bodies, no donation).
+        every not-yet-committed task — of a chunk that donates nothing.
+        A chunk whose program was given donated tiles (the read-write
+        flows of ``FlowPlan.donates``: versions that the tasks' builder
+        says nobody else reads and that the staging walk found held by
+        nobody else) marks its tasks ``_tpu_effects`` before the call: a
+        call that raises may have consumed its inputs, so such a chunk
+        is never retried task by task; it fails its pool there and then
+        (:meth:`_launch`).
         Earlier chunks of the same wave may already have committed their
         epilogs by then — the fallback does not double-run them only
         because each committed task is marked ``_tpu_completed``, which
@@ -900,6 +944,8 @@ class TpuDevice(Device):
         most = max(1, self._res.chunk_limit // max(1, plan.nbytes))
         most = 1 << (most.bit_length() - 1)
         while remaining:
+            if getattr(tasks[0].taskpool, "failed", False):
+                return  # (a chunk that failed it stages no further one)
             # the largest power of two the tasks left and the bytes allow
             cnt = min(1 << (remaining.bit_length() - 1), most)
             grp = tasks[start:start + cnt]
@@ -936,11 +982,45 @@ class TpuDevice(Device):
         """:meth:`_submit_chunk` between the pins: ``pinned`` takes the
         chunk's tiles as they are staged, the caller lets go of them
         once the chunk is committed."""
-        staged = self._stage_span(grp, fplan, pinned)
+        staged, refused = self._stage_span(grp, fplan, pinned)
+        if not refused:
+            wave_span.note(**self._launch(staged, cls, body, base_key, fplan,
+                                          fplan.donates, es, complete))
+            return
+        # somebody else holds a tile that a task of the chunk would have
+        # donated: those tasks leave the chunk's program and go out, as
+        # they are staged, under the functional one (no donated
+        # position); the others keep theirs, in powers of two
+        notes: Dict[str, int] = {}
+        for part, donates in (
+                ([one for k, one in enumerate(staged) if k not in refused],
+                 fplan.donates),
+                ([staged[k] for k in sorted(refused)], ())):
+            at = 0
+            while at < len(part):
+                n = 1 << ((len(part) - at).bit_length() - 1)
+                for key, v in self._launch(
+                        part[at:at + n], cls, body, base_key, fplan, donates,
+                        es, complete).items():
+                    notes[key] = notes.get(key, 0) + v
+                at += n
+        wave_span.note(**notes)
+
+    def _launch(self, staged: List[_Staged], cls: str, body, base_key,
+                fplan: FlowPlan, donates, es, complete: bool) -> Dict[str, int]:
+        """ONE wave program over staged tasks: look it up, call it,
+        commit every task's outputs; returns what the ``dev:wave`` span
+        notes of it (:meth:`_count_values`).  ``donates``: the flows of
+        every task whose input tile the program is given to write the
+        matching output over (``FlowPlan.donates``, or none: the
+        functional program).  An entry keeps one set of donated
+        positions for its life: they are in its local key, and in its
+        content key through the executable cache's fingerprint."""
+        cnt = len(staged)
         args0, nout = staged[0][1], fplan.nout
 
         def build():
-            plan = self._value_plan(grp[0], body, args0)
+            plan = self._value_plan(staged[0][0], body, args0)
 
             def _wave(*flat):
                 outs: List[Any] = []
@@ -953,40 +1033,62 @@ class TpuDevice(Device):
             # ``XLA Modules`` line then splits the chip's time by class
             _wave.__name__ = f"_wave_{cls}"
             return (("wave", cls, self._content_fp(body), len(args0), nout,
-                     cnt) + plan.tag, _wave, (), plan)
+                     cnt) + plan.tag, _wave,
+                    plan.donate(plan.aliased(args0, donates), cnt), plan)
         local_key = ("wave", cls, base_key, argsig(args0),
-                     _placeholders_at(args0), nout, cnt)
+                     _placeholders_at(args0), nout, donates, cnt)
         entry = self._cached_jit(local_key, build)
-        plan = entry[1]
+        program, plan = entry[0], entry[1]
         flat = plan.flatten([args for (_t, args, _o) in staged])
+        grp = [one[0] for one in staged]
         if pins.active(pins.EXEC_BEGIN):
             for t in grp:
                 self._fire_exec(t, pins.EXEC_BEGIN, wave=cnt)
-        outs = self._dispatch(local_key, entry, flat)
+        don = len(program.donate)
+        if not don:
+            outs = self._dispatch(local_key, entry, flat)
+        else:
+            # a donating call that raises may have consumed its inputs:
+            # no task of the chunk can be run again
+            for t in grp:
+                t._tpu_effects = True
+            try:
+                outs = self._dispatch(local_key, entry, flat)
+            except Exception as e:
+                debug.error("wave of %d x %s with %d donated tiles failed: "
+                            "%s", cnt, cls, don, e)
+                for t in grp:
+                    if not getattr(t.taskpool, "failed", False):
+                        self._fail_task_pool(
+                            t, f"device program with donated tiles "
+                               f"raised: {e!r}")
+                    t._tpu_completed = True  # never resubmit
+                return {}
         if pins.active(pins.EXEC_END):
             for t in grp:
                 self._fire_exec(t, pins.EXEC_END, wave=cnt)
-        self._count_values(plan, cnt, wave_span, flat, len(outs))
+        notes = self._count_values(plan, cnt, flat, len(outs), don)
         if getattr(body, "_converts", False):
             self._count_converts(staged, outs)
         if len(outs) != nout * cnt:
             raise ValueError(
-                f"wave of {grp[0].task_class.name}: bodies returned "
+                f"wave of {cls}: bodies returned "
                 f"{len(outs)} outputs for {nout * cnt} writable flows")
         self.stats["wave_submits"] = self.stats.get("wave_submits", 0) + 1
         self.stats["wave_tasks"] = self.stats.get("wave_tasks", 0) + cnt
         self._finish(staged, outs, nout, es, complete)
+        return notes
 
     def _stage_span(self, grp: List[Task], fplan: FlowPlan,
-                    pinned: List[Data]) -> List[_Staged]:
+                    pinned: List[Data]) -> Tuple[List[_Staged], set]:
         """:meth:`_stage_chunk` under its ``dev:stage_args`` span."""
         with self._span("dev:stage_args") as sp:
             # host tiles, their bytes, tiles staged, residency hits
             tally = [0, 0, 0, 0]
-            staged = self._stage_chunk(grp, fplan, tally, pinned)
+            staged, refused = self._stage_chunk(grp, fplan, tally, pinned)
             sp.note(host_tiles=tally[0], bytes=tally[1], tiles=tally[2],
                     hits=tally[3])
-        return staged
+        return staged, refused
 
     def _finish(self, staged: List[_Staged], outs, nout: int, es,
                 complete: bool, *, out_hooks=None, donated: bool = False,
@@ -1027,7 +1129,15 @@ class TpuDevice(Device):
         not fit the budget together raises (``StageIn.batch``) instead
         of running past it.  ``tally`` counts for
         the ``dev:stage_args`` span: ``[tiles copied from the host,
-        their bytes, tiles staged, of them found resident]``."""
+        their bytes, tiles staged, of them found resident]``.
+
+        **Donation.**  Where the signature names donated flows
+        (``fplan.donates``), the second value returned is the set of
+        the chunk's tasks (by their place in it) that may NOT donate
+        after all: under the same hold, each such tile's array has to be
+        held by this chunk alone (:meth:`_sole_holder`).  What the walk
+        stages is the same either way; a refused task goes out under the
+        functional program (``donation_refused``)."""
         idx = self.data_index
         res = self._res
         steps = fplan.steps
@@ -1036,6 +1146,10 @@ class TpuDevice(Device):
         found: Dict[int, Any] = {}  # data_id -> payload on this device
         #: data_id -> [tile, (argument list, position) it still misses in]
         missing: Dict[int, List[Any]] = {}
+        donates = fplan.donates
+        #: data_id -> how many tile operands of the chunk it is
+        reads: Dict[int, int] = {}
+        refused: set = set()
         ntiles = nread = nmiss = twins = 0
         converted = self._converted
         #: data_id -> the rank of the tile's next reader after this
@@ -1066,6 +1180,8 @@ class TpuDevice(Device):
                     if how == READ:
                         nread += 1
                         did = data.data_id
+                        if donates:
+                            reads[did] = reads.get(did, 0) + 1
                         if converted and did in converted:
                             twins += 1
                         if uses is not None:
@@ -1125,6 +1241,8 @@ class TpuDevice(Device):
                     for args, at in slot[1:]:
                         args[at] = found[did]
                     nmiss += len(slot) - 1
+            if donates:
+                refused = self._not_sole(staged, donates, reads)
             for data, access in owns:
                 data.transfer_ownership(idx, access)
             if nexts:
@@ -1133,7 +1251,49 @@ class TpuDevice(Device):
         tally[3] += nread - nmiss
         if twins:
             self.stats["convert_shared_hits"] += twins
-        return staged
+        return staged, refused
+
+    def _not_sole(self, staged: List[_Staged], donates,
+                  reads: Dict[int, int]) -> set:
+        """The tasks of a staged chunk (by their place in it) one of
+        whose donated tiles somebody else holds (the caller holds the
+        residency lock, and the chunk's pins are taken).  The task's
+        builder said that no other TASK reads the version; what only
+        this device can see is who else holds the ARRAY: the tile is an
+        operand of the chunk's program more than once (``reads``: data_id
+        -> times); it is pinned by more than this chunk and the lane's
+        stage-in for this very batch; an eviction's victim on its way
+        home with the lock free (``Residency.going_home``: taken back by
+        this walk, its copy home still runs through an alias of the
+        array); queued with the committer or in one of its drains (a
+        last version started early among them); or the payload of
+        another copy of the tile too (a device-resident arrival is
+        attached as it came)."""
+        res, idx = self._res, self.data_index
+        pins_, going, ahead = res.pins, res.going_home, self._ahead_ids
+        com = self._committer
+        busy = com.holding(
+            [task.body_args[pos][1].data_id
+             for (task, _a, _o) in staged for (pos, _ai, _oi) in donates]) \
+            if com is not None else ()
+        refused = set()
+        for k, (task, args, _ospecs) in enumerate(staged):
+            specs = task.body_args
+            for pos, ai, _oi in donates:
+                data = specs[pos][1]
+                did = data.data_id
+                arr = args[ai]
+                if reads[did] == 1 and did not in going \
+                        and did not in busy \
+                        and pins_.get(did, 0) == 1 + (did in ahead) \
+                        and not any(c.payload is arr
+                                    for di, c in data.copies.items()
+                                    if di != idx):
+                    continue
+                refused.add(k)
+                break
+        self.stats["donation_refused"] += len(refused)
+        return refused
 
     def _submit(self, task: Task, es=None, complete: bool = True,
                 span=None) -> None:
@@ -1154,7 +1314,7 @@ class TpuDevice(Device):
     def _run_one(self, task: Task, body, fplan: FlowPlan, es,
                  complete: bool, span, pinned: List[Data]) -> None:
         """:meth:`_submit` between the pins (as :meth:`_run_chunk`)."""
-        staged = self._stage_span([task], fplan, pinned)
+        staged, refused = self._stage_span([task], fplan, pinned)
         dev_args = staged[0][1]
 
         base_key = getattr(body, "_jit_key", body)
@@ -1167,8 +1327,14 @@ class TpuDevice(Device):
         #   _donate_args — donate these positional array args to XLA so
         #     in-place updates alias instead of allocating (a whole-matrix
         #     INOUT flow would otherwise hold one fresh HBM buffer per
-        #     enqueued async step).
+        #     enqueued async step).  The BODY's author vouches for these
+        #     (and no output of such a program goes home: below).  Every
+        #     other body donates what its signature names
+        #     (``fplan.donates``: the read-write flows whose input version
+        #     the task's builder knows nobody else reads, checked by the
+        #     staging walk), as a wave program of it does.
         donate = tuple(getattr(body, "_donate_args", ()) or ())
+        by_body = bool(donate)
         if donate and getattr(self.context, "nranks", 1) > 1:
             # device-capable fabrics ship jax.Arrays UNCOPIED across
             # ranks (comm/payload.py): donating a buffer a peer may still
@@ -1234,20 +1400,25 @@ class TpuDevice(Device):
                 # a body with no scalar value is its own program, under
                 # its own name, as it always was
                 return (content_key + plan.tag, _one if plan.tag else body,
-                        plan.donate(donate), plan)
+                        plan.donate(donate if by_body else
+                                    plan.aliased(dev_args, donates)), plan)
+            donates = () if by_body or refused else fplan.donates
             local_key = (base_key, argsig(dev_args),
-                         _placeholders_at(dev_args))
+                         _placeholders_at(dev_args), donates)
             entry = self._cached_jit(local_key, build)
             call_args = entry[1].flatten((dev_args,))
         # a donating call that raises may have invalidated its input
         # buffers: the task is no longer safely retryable
-        task._tpu_effects = bool(donate)
+        don = len(entry[0].donate)
+        task._tpu_effects = bool(don)
         self._fire_exec(task, pins.EXEC_BEGIN)
         outputs = self._dispatch(local_key, entry, call_args)
         self._fire_exec(task, pins.EXEC_END)
         plan = entry[1]  # (None: a ``_static_values`` program)
         if plan is not None:
-            self._count_values(plan, 1, span, call_args, fplan.nout)
+            notes = self._count_values(plan, 1, call_args, fplan.nout, don)
+            if span is not None:
+                span.note(**notes)
         if not isinstance(outputs, (tuple, list)):
             outputs = (outputs,)
         if getattr(body, "_converts", False):
@@ -1265,7 +1436,8 @@ class TpuDevice(Device):
         # detach/flush/eviction carry the final version home through the
         # synchronous guarded path.
         self._finish(staged, list(outputs), fplan.nout, es, complete,
-                     out_hooks=fplan.out_hooks, donated=bool(donate),
+                     out_hooks=fplan.out_hooks,
+                     donated=by_body and bool(donate),
                      alone=True)
 
     # ------------------------------------------------------------------

@@ -56,10 +56,12 @@ _DROP, _INT, _BOOL, _FLOAT = "d", "i", "b", "f"
 _UNBORN, _UNREAD = "z", "t"   # a tile: nothing was staged / never read
 
 
-def _read_leaves(body, args: Sequence[Any]) -> List[bool]:
+def _read_leaves(body, args: Sequence[Any]) -> Tuple[List[bool], Tuple]:
     """For each argument of ``body``: does the trace read any of its
     leaves?  One abstract trace (``make_jaxpr`` moves no data); an
-    argument that is input to no equation and is no output is unread."""
+    argument that is input to no equation and is no output is unread.
+    With them, ``(shape, dtype)`` of every output of the body, in
+    order."""
     jaxpr = jax.make_jaxpr(body)(*args).jaxpr
     used = {id(v) for eqn in jaxpr.eqns for v in eqn.invars}
     used.update(id(v) for v in jaxpr.outvars)
@@ -68,7 +70,8 @@ def _read_leaves(body, args: Sequence[Any]) -> List[bool]:
         n = len(jax.tree_util.tree_leaves(a))
         read.append(any(id(v) in used for v in jaxpr.invars[at:at + n]))
         at += n
-    return read
+    return read, tuple((tuple(v.aval.shape), v.aval.dtype)
+                       for v in jaxpr.outvars)
 
 
 #: what a :class:`FlowPlan` step does with one position of ``body_args``
@@ -98,17 +101,28 @@ class FlowPlan:
     and has no ``stage_out`` hook, which the walk refuses; ``LATE`` is a
     write-only flow whose shape neither its tile nor a copy of it says:
     the walk stages the tile itself.  ``out_hooks`` holds one ``stage_out`` hook (or None)
-    per output, or is None when no output has one."""
+    per output, or is None when no output has one.
 
-    __slots__ = ("steps", "nout", "reads", "out_hooks", "nbytes", "dtypes")
+    ``donates``: the read-write tiles whose INPUT version the program
+    may write where it stands, as ``(position in body_args, index in the
+    staged argument list, index among the task's outputs)`` — of the
+    positions whoever built the tasks named (``Task._tpu_donate``: this
+    task is the version's only consumer), those that are a plain ``READ``
+    with the ``OUT`` bit and a known shape.  Part of the signature: the
+    tasks of one chunk donate the same positions."""
 
-    def __init__(self, flows: Sequence[Any]):
+    __slots__ = ("steps", "nout", "reads", "out_hooks", "nbytes", "dtypes",
+                 "donates")
+
+    def __init__(self, flows: Sequence[Any], donate: Sequence[int] = ()):
         """``flows``: the signature without its body key.  A tile is
         ``(shape, dtype, mode)``, with ``"unborn"`` before them for a
         scratch tile nobody has written and the flow's ``(stage_in,
         stage_out)`` hooks after them where it has any; ``shape`` is
-        None where nothing says it."""
+        None where nothing says it.  ``donate``: ``Task._tpu_donate``
+        of the signature's tasks."""
         steps: List[Tuple[int, int, int, Any]] = []
+        donates: List[Tuple[int, int, int]] = []
         out_hooks: List[Any] = []
         nbytes = 0
         dtypes: List[str] = []
@@ -140,6 +154,9 @@ class FlowPlan:
                 else:
                     how, extra = PLACEHOLDER, jax.ShapeDtypeStruct(
                         shape, np.dtype(dtype))
+                if how == READ and access & _OUT and pos in donate \
+                        and shape is not None and dtype is not None:
+                    donates.append((pos, len(steps), len(out_hooks)))
                 steps.append((how, pos, access, extra))
                 dtypes.append("?" if dtype is None else np.dtype(dtype).name)
                 if shape is not None and dtype is not None:
@@ -162,6 +179,7 @@ class FlowPlan:
         #: the tile flows' precisions in order, for the program's span
         #: (no comma: an event's arguments are a comma-separated list)
         self.dtypes = "/".join(dtypes)
+        self.donates = tuple(donates)
 
 
 class ValuePlan:
@@ -170,7 +188,7 @@ class ValuePlan:
     how every other position is rebuilt inside the trace."""
 
     __slots__ = ("routes", "keep", "int_at", "float_at", "dropped",
-                 "packed", "positional", "tiles_dropped", "tag")
+                 "packed", "positional", "tiles_dropped", "tag", "outs")
 
     def __init__(self, body, args: Sequence[Any], nvalues: int):
         """``args``: one task's staged argument list (``TpuDevice.
@@ -181,7 +199,9 @@ class ValuePlan:
         unborn = [i for i, a in enumerate(args)
                   if isinstance(a, jax.ShapeDtypeStruct)]
         tiles = [i for i, a in enumerate(args) if isinstance(a, jax.Array)]
-        read = _read_leaves(body, args) if scalars or tiles else ()
+        #: ``(shape, dtype)`` of the body's outputs (None: not traced)
+        read, self.outs = _read_leaves(body, args) if scalars or tiles \
+            else ((), None)
         #: per position: None (an argument of the program), or (how,
         #: index in its vector | placeholder)
         routes: List[Any] = [None] * len(args)
@@ -269,9 +289,25 @@ class ValuePlan:
                     args.append(_weak(ivec[t * nint + x]))
             yield args
 
-    def donate(self, argnums: Tuple[int, ...]) -> Tuple[int, ...]:
-        """A one-task program's donated positions, in its argument list."""
-        return tuple(self.keep.index(i) for i in argnums if i in self.keep)
+    def donate(self, argnums: Sequence[int],
+               ntasks: int = 1) -> Tuple[int, ...]:
+        """The donated positions of a program of ``ntasks`` tasks, in
+        its argument list: every task's ``argnums`` (positions of its
+        staged argument list) that are arguments of the program."""
+        keep = self.keep
+        one = [keep.index(i) for i in argnums if i in keep]
+        return tuple(t * len(keep) + k for t in range(ntasks) for k in one)
+
+    def aliased(self, args: Sequence[Any], donates) -> List[int]:
+        """Of a :class:`FlowPlan`'s ``donates``, the positions in the
+        staged argument list ``args`` whose tile the body's matching
+        output can be written over: the same shape and dtype (anything
+        else XLA could alias to nothing, and the input would die for no
+        buffer saved)."""
+        outs = self.outs or ()
+        return [ai for (_pos, ai, oi) in donates
+                if oi < len(outs) and outs[oi] == (
+                    tuple(args[ai].shape), args[ai].dtype)]
 
 
 def _weak(x):

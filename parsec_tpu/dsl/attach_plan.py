@@ -89,10 +89,11 @@ class AttachPlan:
         #: task id ``(class name, locals)`` -> position
         self.nodes: Dict[TaskId, int] = {}
         #: per task ``(class index, locals, priority, flow slots, value
-        #: specs, home positions, write-backs)``: one slot (or
-        #: :data:`NO_DATA` / :data:`CTL_FLOW`) per declared flow, the
-        #: ready-made ``("value", v, VALUE)`` specs of params then defs,
-        #: ``_tpu_home``, and ``(source slot, home slot)`` pairs
+        #: specs, home positions, write-backs, donated positions)``: one
+        #: slot (or :data:`NO_DATA` / :data:`CTL_FLOW`) per declared flow,
+        #: the ready-made ``("value", v, VALUE)`` specs of params then
+        #: defs, ``_tpu_home``, ``(source slot, home slot)`` pairs, and
+        #: ``_tpu_donate`` (:func:`_donations`)
         self.tasks: Tuple[Tuple, ...] = ()
         #: per task its successors' positions, one per captured edge
         #: (what ``_emit_trace_edges`` publishes)
@@ -278,7 +279,7 @@ def plan_key(tp, ranks: Iterable[int], fusion: Tuple,
     resolved graph of ``tp`` is a function of (``window``: the pump's
     batches, which rank the tasks: :func:`build_plan`); raises
     :class:`Uncacheable` where it cannot vouch for some part."""
-    return ("attach-plan-2", _ptg_fp(tp.ptg),
+    return ("attach-plan-3", _ptg_fp(tp.ptg),
             tuple(sorted((str(k), _const_fp(k, v))
                          for k, v in tp.constants.items())),
             tuple(sorted(ranks)), fusion, tuple(window))
@@ -378,6 +379,9 @@ def build_plan(tp, g: TaskGraph, regions=(),
     rows: List[Tuple] = []
     succs: List[Tuple[int, ...]] = []
     wbs_of: List[Tuple] = []
+    #: per task the flows whose OUTPUT version somebody besides its task
+    #: readers holds: it goes home, or a cross-tile write-back reads it
+    kept_of: Dict[TaskId, set] = {}
     for tid in order:
         cname, locs = tid
         pc = ptg_classes[cname]
@@ -421,10 +425,15 @@ def build_plan(tp, g: TaskGraph, regions=(),
             hkey = ("data", cname2, tuple(key))
             if src != hkey:
                 wbs.append((slot(src, use), slot(hkey)))
+                home_names.add(fname)
+        kept_of[tid] = home_names
         wbs_of.append(tuple(wbs))
         rows.append((ci, locs, node.priority, tuple(flows), values, home,
                      () if fused else tuple(wbs)))
         succs.append(tuple(nodes[s] for (_f, s, _sf) in node.out_edges))
+    donated = _donations(g, order, cls_flows, flow_mode, kept_of)
+    rows = [row + (() if tid in region_of else donate,)
+            for row, tid, donate in zip(rows, order, donated)]
     plan.classes = tuple(cls_index)
     plan.device_class = tuple(device_class)
     plan.has_cpu_bodies = not all(device_class)
@@ -507,6 +516,58 @@ def build_plan(tp, g: TaskGraph, regions=(),
         touches, _pump_rank(prio, pred, succ, plan.roots, *window),
         len(tiles))
     return plan
+
+
+def _donations(g: TaskGraph, order: List[TaskId], cls_flows, flow_mode,
+               kept_of) -> List[Tuple[int, ...]]:
+    """Per task (in ``order``) its ``_tpu_donate``: the positions of the
+    read-write flows whose INPUT version the task is the only consumer
+    of, so that a device program may write the flow's output over it.
+
+    A version is born where a task WRITES a flow (or is the tile as its
+    collection holds it) and is read by whoever names that flow as the
+    source of one of its own.  The task is its only consumer when the
+    producer's flow has this one task reader — one captured edge, none
+    that leaves a rank-filtered capture — the producer neither sends the
+    version home nor lands it in another tile (``kept_of``: of a chain's
+    write-back only the LAST version goes home, the one no successor
+    rewrites), and the producer wrote it (a flow it only read forwards a
+    version its own producer's other readers share).  A tile's first
+    version counts when one flow in the whole graph names the
+    collection's tile as its source.  What the graph cannot know — who
+    else holds the array on the device — is the staging walk's to check
+    (``TpuDevice._not_sole``)."""
+    inout = int(AccessMode.INOUT)
+    out = int(AccessMode.OUT)
+    first: Dict[Tuple, int] = collections.Counter(
+        src for node in g.nodes.values()
+        for src in node.flow_sources.values()
+        if src is not None and src[0] == "data")
+    readers = {tid: collections.Counter(f for (f, _s, _sf) in node.out_edges)
+               for tid, node in g.nodes.items()}
+    donated: List[Tuple[int, ...]] = []
+    for tid in order:
+        node = g.nodes[tid]
+        mine = []
+        for f in cls_flows[tid[0]]:
+            if f.mode == CTL or int(f.mode) & inout != inout:
+                continue
+            src = node.flow_sources.get(f.name)
+            if src is None or src[0] == "new":
+                continue
+            if src[0] == "data":
+                sole = first[src] == 1
+            else:
+                _, ptid, pflow = src
+                prod = g.nodes.get(ptid)
+                sole = (prod is not None and not prod.remote_out
+                        and readers[ptid][pflow] == 1
+                        and int(flow_mode[(ptid[0], pflow)]) & out
+                        and pflow not in kept_of[ptid])
+            if sole:
+                mine.append(f.index)
+        donated.append(tuple(mine))
+    return donated
 
 
 def _pump_rank(prio: List[int], pred: List[int], succ: List[int],

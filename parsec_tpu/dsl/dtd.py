@@ -498,6 +498,9 @@ class DTDTaskpool(Taskpool):
         #: no output of an inserted task is the device committer's: a
         #: tile goes home at its flush (module docstring)
         task._tpu_home = ()
+        #: the read-write tiles whose version this task is the last to
+        #: read (below): the device module may write the new one over it
+        donate: List[int] = []
         state = _DTDTaskState()
         task.user = state
         task.on_complete = self._task_retired
@@ -539,6 +542,13 @@ class DTDTaskpool(Taskpool):
                     # ATOMIC_WRITE here — commutativity is a local
                     # optimization, cross-rank epochs need a total order)
                     pending = [r for r in st.readers + st.atomic if r is not task]
+                    if kind == "data" and m & _IN and nranks == 1:
+                        # an exclusive writer is ordered behind every
+                        # reader of the version it overwrites (or takes a
+                        # renamed copy that is its alone), and whoever is
+                        # inserted later reads ITS version: nobody else
+                        # consumes the one it reads
+                        donate.append(i)
                     if rename_on and kind == "data" and pending:
                         # WAR hazard: rename (overlap_strategies.c) — the
                         # writer proceeds on a fresh buffer while pending
@@ -582,6 +592,7 @@ class DTDTaskpool(Taskpool):
                 cpy = self._insert_rename_copy(copy_src, copy_dst, copy_preds)
                 deps += self._add_edge(cpy, task, state)
 
+        task._tpu_donate = tuple(donate)
         self._edges += deps
         with self._quiesce:
             self._inserted += 1

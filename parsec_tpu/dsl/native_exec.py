@@ -737,7 +737,8 @@ class NativeExecutor:
                 for m in row[2]:
                     objs[m] = task
             else:
-                ci, locs, prio, slots, values, home, wbs = plan.tasks[pos]
+                ci, locs, prio, slots, values, home, wbs, donate = \
+                    plan.tasks[pos]
                 pc, tclass, chore, modes, gvals = per_class[ci]
                 if chore is None:
                     # a CPU-fallback body needs the trampoline protocol
@@ -762,6 +763,7 @@ class NativeExecutor:
                 task.body_args += values
                 task.body_args += gvals
                 task._tpu_home = home
+                task._tpu_donate = donate
                 if wbs:
                     # write-backs PRE-RESOLVED to (source Data, home
                     # Data) pairs: whoever retires the task lands them
