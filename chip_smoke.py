@@ -72,9 +72,9 @@ class Sizes:
     interpret: bool = False
 
 
-#: the f32 bar bench.py holds every dpotrf variant to (max abs error of
-#: the lower factor over max |L_ref|), and its bar for whatever runs at
-#: the chip's default matmul precision (one bf16 MXU pass)
+#: the f32 bar every dpotrf variant is held to (max abs error of the
+#: lower factor over max |L_ref|), and the bar for whatever runs at the
+#: chip's default matmul precision (one bf16 MXU pass)
 F32_BAR = 1e-3
 BF16_BAR = 1e-2
 
@@ -179,7 +179,7 @@ def make_spd(n: int, seed: int, jdev):
 
 
 def factor_error(L: np.ndarray, L_ref: np.ndarray) -> float:
-    """bench.py's dpotrf gate: max |tril(L) - L_ref| over max |L_ref|."""
+    """The dpotrf gate: max |tril(L) - L_ref| over max |L_ref|."""
     scale = max(1.0, float(np.max(np.abs(L_ref))))
     return float(np.max(np.abs(np.tril(L) - L_ref))) / scale
 

@@ -27,7 +27,7 @@ from __future__ import annotations
 
 import os
 import re
-from typing import Dict, List, Set, Tuple
+from typing import Dict, List, Optional, Sequence, Set, Tuple
 
 from .findings import Finding
 
@@ -35,7 +35,8 @@ from .findings import Finding
 #: registered under other frameworks (e.g. test-local ones) are exempt
 FRAMEWORKS = ("runtime", "sched", "serve", "comm", "coll", "profiling")
 
-#: ``register("fw", "name"`` — module alias, method, and keyword forms
+#: a ``register(`` call whose first two arguments are string literals,
+#: framework then name — module alias, method, and keyword forms
 _REGISTER_RE = re.compile(
     r"""\bregister\(\s*
         ['"](?P<fw>[a-z_]+)['"]\s*,\s*
@@ -55,13 +56,18 @@ def _repo_root() -> str:
         os.path.abspath(__file__))))
 
 
-def registered_params(src_root: str = None) -> Dict[Tuple[str, str], str]:
+def registered_params(src_root: str = None,
+                      frameworks: Optional[Sequence[str]] = FRAMEWORKS
+                      ) -> Dict[Tuple[str, str], List[str]]:
     """Scan ``parsec_tpu/**/*.py`` for register() call sites; returns
-    ``(framework, name) -> relative source path`` (first site wins)."""
+    ``(framework, name) -> ["relative/source.py:line", ...]``, every
+    site in walk order, of the ``frameworks`` asked for (``None``: all
+    of them, the census of ``tests/analysis/test_doc_lint.py``)."""
     if src_root is None:
         src_root = os.path.join(_repo_root(), "parsec_tpu")
-    out: Dict[Tuple[str, str], str] = {}
-    for dirpath, _dirs, files in os.walk(src_root):
+    out: Dict[Tuple[str, str], List[str]] = {}
+    for dirpath, dirs, files in os.walk(src_root):
+        dirs.sort()
         for fn in sorted(files):
             if not fn.endswith(".py"):
                 continue
@@ -71,10 +77,12 @@ def registered_params(src_root: str = None) -> Dict[Tuple[str, str], str]:
                     text = f.read()
             except OSError:
                 continue
+            rel = os.path.relpath(path, src_root)
             for m in _REGISTER_RE.finditer(text):
                 key = (m.group("fw"), m.group("name"))
-                if key[0] in FRAMEWORKS:
-                    out.setdefault(key, os.path.relpath(path, src_root))
+                if frameworks is None or key[0] in frameworks:
+                    line = text.count("\n", 0, m.start()) + 1
+                    out.setdefault(key, []).append(f"{rel}:{line}")
     return out
 
 
@@ -99,11 +107,11 @@ def doc_findings(src_root: str = None, ops_path: str = None
     regs = registered_params(src_root)
     rows, ticked = documented_params(ops_path)
     out: List[Finding] = []
-    for (fw, name), src in sorted(regs.items()):
+    for (fw, name), sites in sorted(regs.items()):
         full = f"{fw}_{name}"
         if full not in ticked and name not in ticked:
             out.append(Finding(
-                "DOC001", f"MCA param {full} (registered in {src}) is "
+                "DOC001", f"MCA param {full} (registered in {sites[0]}) is "
                 "not documented in docs/OPERATIONS.md",
                 dep=full))
     row_fw_ok = {(fw, name) for fw, name in regs}

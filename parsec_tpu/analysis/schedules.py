@@ -158,13 +158,6 @@ class ExplorationResult:
         return all(_digest_equal(vals[0], v) for v in vals[1:]) if vals \
             else True
 
-    def divergent_seeds(self) -> List[int]:
-        if not self.seeds:
-            return []
-        ref = self.digests[self.seeds[0]]
-        return [s for s in self.seeds[1:]
-                if not _digest_equal(ref, self.digests[s])]
-
     def race_findings(self) -> List[Finding]:
         return [f for fs in self.findings.values() for f in errors_of(fs)]
 

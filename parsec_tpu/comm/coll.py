@@ -15,7 +15,7 @@ BytePool` slot — so an N-rank allreduce of a large tile streams at ring
 bandwidth (each rank moves ~2·nbytes/N per step, all links busy) instead
 of gather-reduce-rebroadcast through one root.
 
-Algorithms (MCA ``runtime_coll_algo``):
+Algorithms (``algo=`` of the call):
 
 * ``ring`` (default) — reduce-scatter + allgather pipeline, 2(N-1)
   steps, memory-lean (one landing block + one staging block beyond the
@@ -24,8 +24,8 @@ Algorithms (MCA ``runtime_coll_algo``):
   (power-of-two groups; falls back to ring otherwise), latency-optimal
   for small payloads;
 * ``gather`` — the naive gather-reduce-rebroadcast baseline (root pulls
-  every contribution, reduces, re-broadcasts).  Kept selectable so the
-  bench can A/B the ring against it honestly.
+  every contribution, reduces, re-broadcasts): the reference the tests
+  hold the ring against.
 
 The reduction step runs on-device (jitted through the PR-7 executable
 cache when a context is attached) when the contribution was a
@@ -34,7 +34,7 @@ cache when a context is attached) when the contribution was a
 Wire discipline:
 
 * control messages (block adverts, acks) ride the shared ``TAG_CTL``
-  channel (op ``"coll"``) at MCA ``runtime_coll_priority`` (default -1:
+  channel (op ``"coll"``) at :data:`COLL_PRIORITY` (-1:
   BELOW dependency activations, so bulk collectives never starve the
   critical path) and are counted by distributed termination detection on
   both sides like any app message — a collective embedded in a taskpool
@@ -52,7 +52,7 @@ redistribution: per-destination region batches staged under a byte
 budget, moved in linear-shift rounds with single-slot admission on the
 receive side, in the style of "Memory-efficient array redistribution
 through portable collective communication" (PAPERS.md) — peak extra
-memory per rank stays under ``runtime_redistribute_mem_budget``.
+memory per rank stays under the caller's ``mem_budget``.
 """
 
 from __future__ import annotations
@@ -73,6 +73,11 @@ from .engine import TAG_CTL
 from .payload import as_bytes, is_device_array
 
 __all__ = ["CollManager", "CollOp", "RedistOp", "CollError", "REDUCERS"]
+
+#: send priority of collective control/data messages unless the call
+#: gives ``priority=``: below 0 = after dependency activations in a
+#: shared frame, so bulk collectives never starve the critical path
+COLL_PRIORITY = -1
 
 #: host-side reducers (in-place capable numpy ufuncs)
 REDUCERS: Dict[str, Any] = {
@@ -292,7 +297,8 @@ class _BaseOp:
             raise CollError(
                 f"rank {self.ce.rank} is not in collective group "
                 f"{self.group}")
-        self.priority = (mgr.priority if priority is None else int(priority))
+        self.priority = (COLL_PRIORITY if priority is None
+                         else int(priority))
         #: job trace context (profiling.jobtrace): a collective issued
         #: from inside a task body inherits the running job's trace id
         #: off the worker thread (dsl.CollectiveTask's rendezvous shape),
@@ -1362,12 +1368,6 @@ class CollManager:
 
     def __init__(self, ce):
         self.ce = ce
-        self.algo = str(mca_param.register(
-            "runtime", "coll_algo", "auto",
-            choices=["auto", "ring", "rd", "gather"],
-            help="collective algorithm: ring (segmented, bandwidth-"
-                 "optimal) | rd (recursive doubling, power-of-two "
-                 "groups) | gather (naive gather+bcast baseline) | auto"))
         seg = int(mca_param.register(
             "runtime", "coll_segment", 0,
             help="collective segment size in bytes (0 = follow "
@@ -1376,12 +1376,6 @@ class CollManager:
         self.segment = seg if seg > 0 else int(getattr(
             ce, "rdv_chunk", 256 << 10))
         self.pipeline_depth = max(1, int(getattr(ce, "pipeline_depth", 4)))
-        self.priority = int(mca_param.register(
-            "runtime", "coll_priority", -1,
-            help="send priority for collective control/data messages "
-                 "(below 0 = after dependency activations in a shared "
-                 "frame, so bulk collectives never starve the critical "
-                 "path)"))
         self.err_grace = float(mca_param.register(
             "runtime", "coll_err_grace", 5.0,
             help="seconds a locally-detected segment-pull failure waits "
@@ -1463,9 +1457,10 @@ class CollManager:
         return list(group)
 
     def _pick_algo(self, algo: Optional[str], n: int) -> str:
-        a = algo or self.algo
-        if a == "auto":
-            return "ring"
+        """``algo=`` of the call: ring (segmented, bandwidth-optimal, the
+        default) | rd (recursive doubling, power-of-two groups) | gather
+        (naive gather+bcast baseline)."""
+        a = "ring" if algo in (None, "auto") else algo
         if a == "rd" and n & (n - 1):
             debug.verbose(2, "coll", "recursive doubling needs a power-"
                           "of-two group (N=%d); using ring", n)

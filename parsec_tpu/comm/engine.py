@@ -22,7 +22,7 @@ from __future__ import annotations
 
 import contextlib
 import threading
-from typing import Any, Callable, Dict, Optional, TYPE_CHECKING
+from typing import Any, Callable, Dict, Optional, Tuple, TYPE_CHECKING
 
 from ..utils import Component, debug, mca_param
 
@@ -39,12 +39,31 @@ TAG_DTD = 5             # DTD tile-version transfers (shadow-task protocol)
 TAG_USER_BASE = 6
 MAX_AM_TAGS = 12
 
-#: wire-protocol defaults — single source of truth for the engine class
-#: attributes, ``_init_protocol``'s registrations, and the protocol
-#: layer's own (idempotent) re-registrations in ``remote_dep``
+#: wire-protocol defaults: of the engine class attributes and of
+#: :func:`protocol_params`
 EAGER_LIMIT_DEFAULT = 8192
 PIPELINE_DEPTH_DEFAULT = 4
 RDV_CHUNK_DEFAULT = 256 << 10
+
+
+def protocol_params() -> Tuple[int, int, int]:
+    """``(comm_eager_limit, comm_pipeline_depth, comm_rdv_chunk)`` as
+    configured, not validated: THE registration, for the engines
+    (``_init_protocol``) and for ``remote_dep``, which reads the registry
+    so that an engine that never ran ``_init_protocol`` resolves alike."""
+    return (
+        int(mca_param.register(
+            "runtime", "comm_eager_limit", EAGER_LIMIT_DEFAULT,
+            help="payloads at or below this many bytes ship inline with "
+                 "the activation (eager regime, zero extra round trips); "
+                 "larger ones use the pipelined chunked rendezvous")),
+        int(mca_param.register(
+            "runtime", "comm_pipeline_depth", PIPELINE_DEPTH_DEFAULT,
+            help="in-flight chunk requests per rendezvous transfer")),
+        int(mca_param.register(
+            "runtime", "comm_rdv_chunk", RDV_CHUNK_DEFAULT,
+            help="rendezvous chunk size (bytes); each chunk is one "
+                 "get round-trip, pipeline_depth of them in flight")))
 
 
 class CommEngine(Component):
@@ -64,7 +83,6 @@ class CommEngine(Component):
     eager_limit: int = EAGER_LIMIT_DEFAULT
     pipeline_depth: int = PIPELINE_DEPTH_DEFAULT
     rdv_chunk: int = RDV_CHUNK_DEFAULT
-    coalesce_enabled: bool = True
     #: True when one-sided pull traffic rides AM frames (and is therefore
     #: already inside ``stats["am_bytes"]``) — wire-byte accounting must
     #: not add ``get_bytes`` on top for such engines (TCP's GET answers),
@@ -73,25 +91,10 @@ class CommEngine(Component):
     pull_bytes_in_frames: bool = False
 
     def _init_protocol(self) -> None:
-        """Register the comm-protocol MCA params (env-overridable as
-        ``PARSEC_MCA_runtime_comm_*``) and validate them.  Called by every
-        backend's constructor."""
-        self.eager_limit = int(mca_param.register(
-            "runtime", "comm_eager_limit", EAGER_LIMIT_DEFAULT,
-            help="payloads at or below this many bytes ship inline with "
-                 "the activation (eager regime, zero extra round trips); "
-                 "larger ones use the pipelined chunked rendezvous"))
-        self.pipeline_depth = int(mca_param.register(
-            "runtime", "comm_pipeline_depth", PIPELINE_DEPTH_DEFAULT,
-            help="in-flight chunk requests per rendezvous transfer"))
-        self.rdv_chunk = int(mca_param.register(
-            "runtime", "comm_rdv_chunk", RDV_CHUNK_DEFAULT,
-            help="rendezvous chunk size (bytes); each chunk is one "
-                 "get round-trip, pipeline_depth of them in flight"))
-        self.coalesce_enabled = bool(mca_param.register(
-            "runtime", "comm_coalesce", True,
-            help="coalesce all messages queued for one destination in "
-                 "one progress cycle into a single frame"))
+        """Read the comm-protocol MCA params (:func:`protocol_params`)
+        and validate them.  Called by every backend's constructor."""
+        self.eager_limit, self.pipeline_depth, self.rdv_chunk = \
+            protocol_params()
         if self.eager_limit < 0:
             raise ValueError(
                 f"runtime_comm_eager_limit must be >= 0 (0 sends every "

@@ -137,8 +137,7 @@ class InprocComm(CommEngine):
             self._out_seq += 1
             self._outbox[dst_rank].append(
                 (priority, self._out_seq, tag, copied))
-        if (self.coalesce_enabled
-                and getattr(self._win_tls, "depth", 0) > 0):
+        if getattr(self._win_tls, "depth", 0) > 0:
             return  # flushed when THIS thread's outermost window closes
         self._flush(dst_rank)
 

@@ -46,8 +46,7 @@ from ..data.arena import BytePool
 from ..data.data import data_create
 from ..profiling import pins
 from .engine import (
-    CommEngine, EAGER_LIMIT_DEFAULT, PIPELINE_DEPTH_DEFAULT,
-    RDV_CHUNK_DEFAULT, TAG_ACTIVATE, TAG_DTD,
+    CommEngine, TAG_ACTIVATE, TAG_DTD, protocol_params,
 )
 from .payload import as_bytes, from_wire, is_device_array, wire_header
 
@@ -275,8 +274,7 @@ class RemoteDepManager:
         # idempotent, so engines that ran _init_protocol and bare test
         # doubles resolve identically — and an explicitly configured
         # legacy comm_short_limit is honored either way
-        eager = int(mca_param.register(
-            "runtime", "comm_eager_limit", EAGER_LIMIT_DEFAULT))
+        eager, depth, chunk = protocol_params()
         if (mca_param.source("runtime", "comm_short_limit") != "default"
                 and mca_param.source("runtime", "comm_eager_limit")
                 == "default"):
@@ -286,10 +284,8 @@ class RemoteDepManager:
         self.eager_limit = self.short_limit = eager
         # engines validate at construction; the max() guards only cover
         # engines that never ran _init_protocol
-        self.pipeline_depth = max(1, int(mca_param.register(
-            "runtime", "comm_pipeline_depth", PIPELINE_DEPTH_DEFAULT)))
-        self.rdv_chunk = max(1, int(mca_param.register(
-            "runtime", "comm_rdv_chunk", RDV_CHUNK_DEFAULT)))
+        self.pipeline_depth = max(1, depth)
+        self.rdv_chunk = max(1, chunk)
         #: landing buffers for rendezvous payloads (recycled size
         #: classes).  Rank-qualified name: slot lifecycle events
         #: (pins.ARENA_ALLOC/RECYCLE — the hb-check double-recycle

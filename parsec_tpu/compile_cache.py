@@ -64,6 +64,9 @@ from .utils import debug, mca_param
 #: purge --stale``)
 CACHE_FORMAT = 2
 _MAGIC = b"PZEXE1"
+#: in-process LRU capacity of the executable cache (live compiled
+#: programs); the largest set a cell compiles is 66
+MEM_ENTRIES = 512
 _CTL_OP = "compile"
 
 _ADDR_RE = re.compile(r"0x[0-9a-fA-F]+")
@@ -594,18 +597,11 @@ class ExecutableCache:
 
     def __init__(self, *, rank: int = 0, nranks: int = 1, ce=None,
                  store: Optional[DiskStore] = "default",
-                 mem_entries: Optional[int] = None,
                  min_disk_s: Optional[float] = None,
                  bcast: Optional[bool] = None):
         self.rank = rank
         self.nranks = nranks
         self.stats: collections.Counter = collections.Counter()
-        if mem_entries is None:
-            mem_entries = int(mca_param.register(
-                "runtime", "compile_cache_mem_entries", 512,
-                help="in-process LRU capacity of the executable cache "
-                     "(live compiled programs)"))
-        self.mem_entries = max(1, mem_entries)
         if min_disk_s is None:
             min_disk_s = float(mca_param.register(
                 "runtime", "compile_cache_min_share_s", 0.05,
@@ -723,7 +719,7 @@ class ExecutableCache:
         with self._lock:
             self._lru[fp] = exe
             self._lru.move_to_end(fp)
-            while len(self._lru) > self.mem_entries:
+            while len(self._lru) > MEM_ENTRIES:
                 self._lru.popitem(last=False)
 
     def _resolve(self, cf: _CachedFunction, sig: Tuple, args: Tuple):

@@ -25,6 +25,38 @@ from .lifecycle import HookReturn
 from .task import Task
 from .taskpool import Taskpool
 
+#: max seconds an idle worker sleeps between scheduler polls (reference
+#: exponential nanosleep cap, scheduling.c:768-771).  Every work source
+#: notifies the cv (schedule_ready, taskpool termination, comm arrivals),
+#: so the cap only bounds staleness of the POLLED fallbacks
+#: (progress_comm).  It must be generous: each idle wake runs a scheduler
+#: select under the GIL, and at a 1 ms cap a handful of idle threads
+#: measurably slows an active worker's async device dispatch (5x on
+#: jit-call enqueue) — the exact hot path the device manager lives on.
+IDLE_BACKOFF_MAX = 0.02
+
+
+def configured_vpmap(nb_workers: int):
+    """The ``runtime_vpmap`` parameter's map of ``nb_workers`` workers
+    into virtual processes (reference vpmap.c + bindthread.c), ``None``
+    for ``flat``; a spec that cannot be read raises ``ValueError``."""
+    from ..utils.binding import VPMap
+
+    spec = str(mca_param.register(
+        "runtime", "vpmap", "flat",
+        help="vp map: flat | nb:<k> | explicit '0,1;2,3' worker lists"))
+    try:
+        if spec.startswith("nb:"):
+            k = int(spec[3:])
+            if k < 1:
+                raise ValueError("vp count must be >= 1")
+            return VPMap.from_nb_vps(nb_workers, k)
+        if ";" in spec or "," in spec:
+            return VPMap.from_spec(spec)
+    except Exception as e:
+        raise ValueError(f"invalid runtime_vpmap {spec!r}: {e}") from e
+    return None
+
 
 class ExecutionStream:
     """Per-worker state (reference ``parsec_execution_stream_t``)."""
@@ -105,25 +137,13 @@ class Context:
         self.scheduler = open_component("sched", sched_name)
         self.scheduler.install(self)
 
-        # virtual-process map + optional core binding (reference vpmap.c +
-        # bindthread.c; see utils/binding.py)
         from ..utils.binding import VPMap, available_cores
 
-        vspec = str(mca_param.register(
-            "runtime", "vpmap", "flat",
-            help="vp map: flat | nb:<k> | explicit '0,1;2,3' worker lists"))
         try:
-            if vspec.startswith("nb:"):
-                k = int(vspec[3:])
-                if k < 1:
-                    raise ValueError("vp count must be >= 1")
-                self.vpmap = VPMap.from_nb_vps(self.nb_workers, k)
-            elif ";" in vspec or "," in vspec:
-                self.vpmap = VPMap.from_spec(vspec)
-            else:
-                self.vpmap = VPMap.flat(self.nb_workers)
+            self.vpmap = configured_vpmap(self.nb_workers) \
+                or VPMap.flat(self.nb_workers)
         except ValueError as e:
-            debug.fatal("invalid runtime_vpmap parameter %r: %s", vspec, e)
+            debug.fatal("%s", e)
         self._bind_threads = mca_param.register(
             "runtime", "bind_threads", False,
             help="pin worker threads to cores round-robin")
@@ -141,20 +161,6 @@ class Context:
         self.devices = devmod.attach_devices(self, devices)
 
         self._cv = threading.Condition()
-        #: idle-wait cap (reference exponential nanosleep cap,
-        #: scheduling.c:768-771).  Every work source notifies the cv
-        #: (schedule_ready, taskpool termination, comm arrivals), so the
-        #: cap only bounds staleness of the POLLED fallbacks
-        #: (progress_comm).  It must be generous: each idle wake runs a
-        #: scheduler select under the GIL, and at a 1 ms cap a handful of
-        #: idle threads measurably slows an active worker's async device
-        #: dispatch (5x on jit-call enqueue) — the exact hot path the
-        #: device manager lives on.
-        self._idle_backoff_max = mca_param.register(
-            "runtime", "idle_backoff_max", 0.02,
-            help="max seconds an idle worker sleeps between scheduler "
-                 "polls (wakeups are notify-driven; this caps staleness "
-                 "of polled fallbacks)")
         #: exclusive ownership of execution stream 0 (the "master" stream):
         #: contended between a wait()-ing thread and non-worker helpers
         self._es0_lock = threading.Lock()
@@ -399,7 +405,7 @@ class Context:
                     if done():
                         return True
                     self._cv.wait(backoff)
-                backoff = min(backoff * 2, self._idle_backoff_max)
+                backoff = min(backoff * 2, IDLE_BACKOFF_MAX)
         finally:
             if own_es0:
                 self._tls.es = None
@@ -450,7 +456,7 @@ class Context:
                     if self._shutdown:
                         return
                     self._cv.wait(backoff)
-                backoff = min(backoff * 2, self._idle_backoff_max)
+                backoff = min(backoff * 2, IDLE_BACKOFF_MAX)
                 continue
             backoff = 1e-6
             self._run_task(es, task)
@@ -469,9 +475,9 @@ class Context:
 
         With nranks > 1 the failure is broadcast through
         ``remote_dep._fail_pool_everywhere`` so healthy peer ranks abort
-        fast instead of blocking until their full wait() timeout
-        (ADVICE.md round-5 item 3) — the abort path discriminates
-        parked / completed / live pools per rank, so a peer that never
+        fast instead of blocking until their full wait() timeout — the
+        abort path discriminates parked / completed / live pools per
+        rank, so a peer that never
         instantiated the pool parks the abort and a peer that already
         finished drops it.  Single-rank (or comm-less) contexts keep the
         local fail."""

@@ -15,8 +15,11 @@ import collections
 import threading
 from typing import List, Optional
 
-from ...utils import register_component, mca_param
+from ...utils import register_component
 from .base import Scheduler
+
+#: max tasks in a worker-local queue before spilling to the global dequeue
+LOCAL_CAP = 256
 
 
 @register_component("sched")
@@ -26,10 +29,6 @@ class SchedLFQ(Scheduler):
 
     def install(self, context) -> None:
         super().install(context)
-        self._local_cap = mca_param.register(
-            "sched", "lfq_local_cap", 256,
-            help="max tasks in a worker-local queue before spilling to the global dequeue",
-        )
         self._locals: List[collections.deque] = []
         self._local_locks: List[threading.Lock] = []
         self._global: collections.deque = collections.deque()
@@ -54,7 +53,7 @@ class SchedLFQ(Scheduler):
         if distance == 0 and es is not None and i < len(self._locals):
             dq, lk = self._locals[i], self._local_locks[i]
             with lk:
-                room = self._local_cap - len(dq)
+                room = LOCAL_CAP - len(dq)
                 take = tasks[:room] if room > 0 else []
                 for t in reversed(take):
                     dq.appendleft(t)  # LIFO end

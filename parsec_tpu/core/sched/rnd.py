@@ -19,6 +19,16 @@ from ...utils import mca_param, register_component
 from .base import Scheduler
 
 
+def rnd_seed() -> int:
+    """The ``sched_rnd_seed`` parameter: this scheduler's seed, and the
+    native pump's seeded pop-order perturbation."""
+    return int(mca_param.register(
+        "sched", "rnd_seed", -1,
+        help="seed for the rnd scheduler's RNG (>=0 replays one "
+             "schedule deterministically — the schedule explorer's "
+             "replay hook; -1 = unseeded fuzzing)"))
+
+
 @register_component("sched")
 class SchedRND(Scheduler):
     mca_name = "rnd"
@@ -28,11 +38,7 @@ class SchedRND(Scheduler):
         super().install(context)
         self._items: list = []
         self._lock = threading.Lock()
-        seed = int(mca_param.register(
-            "sched", "rnd_seed", -1,
-            help="seed for the rnd scheduler's RNG (>=0 replays one "
-                 "schedule deterministically — the schedule explorer's "
-                 "replay hook; -1 = unseeded fuzzing)"))
+        seed = rnd_seed()
         self.seed: Optional[int] = None if seed < 0 else seed
         self._rng = random.Random(self.seed)  # Random(None) = fresh entropy
 

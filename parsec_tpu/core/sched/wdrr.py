@@ -35,8 +35,12 @@ import itertools
 import threading
 from typing import Dict, List, Optional
 
-from ...utils import register_component, mca_param
+from ...utils import register_component
 from .base import Scheduler, native_ready_queue
+
+#: task credits a tenant's deficit gains per round-robin visit, scaled
+#: by the tenant's weight (the native pump's wdrr mode reads it too)
+QUANTUM = 4
 
 #: tenant bin for tasks whose pool was never admitted by a service
 _DEFAULT = "_"
@@ -59,13 +63,6 @@ class SchedWDRR(Scheduler):
 
     def install(self, context) -> None:
         super().install(context)
-        self._quantum = int(mca_param.register(
-            "sched", "wdrr_quantum", 4,
-            help="task credits a tenant's deficit gains per round-robin "
-                 "visit, scaled by the tenant's weight"))
-        if self._quantum < 1:
-            raise ValueError(
-                f"sched_wdrr_quantum must be >= 1 (got {self._quantum})")
         self._lock = threading.Lock()
         self._seq = itertools.count()
         self._tenants: Dict[str, _TenantQ] = {}
@@ -73,7 +70,7 @@ class SchedWDRR(Scheduler):
         self._ring: List[str] = []
         self._cur = 0
         self._count = 0
-        self._nq = native_ready_queue("wdrr", quantum=self._quantum)
+        self._nq = native_ready_queue("wdrr", quantum=QUANTUM)
         self._owned: Dict[int, object] = {}
         #: tenant key -> native tenant index (and its last-set weight)
         self._nq_tenants: Dict[str, int] = {}
@@ -140,7 +137,7 @@ class SchedWDRR(Scheduler):
                     self._ring.pop(self._cur)
                     continue
                 if tq.deficit <= 0:
-                    tq.deficit += self._quantum * tq.weight
+                    tq.deficit += QUANTUM * tq.weight
                 task = heapq.heappop(tq.heap)[2]
                 tq.deficit -= 1
                 self._count -= 1

@@ -2,7 +2,7 @@
 
 Reference: ``/root/reference/parsec/arena.{c,h}`` — one arena per
 (datatype, shape); allocations are cached on a freelist up to
-``arena_max_cached`` and capped at ``arena_max_used`` outstanding
+``ARENA_MAX_CACHED`` and capped at ``runtime_arena_max_used`` outstanding
 (``parsec.c:656-665`` MCA params).
 """
 
@@ -52,6 +52,10 @@ def global_stats() -> Dict[str, int]:
 #: silently corrupt each other (the finalizer-vs-explicit-release race).
 RECYCLED_FLAG = 0x1
 
+#: max buffers cached per arena freelist (reference ``arena_max_cached``,
+#: ``parsec.c:656-665``)
+ARENA_MAX_CACHED = 64
+
 
 class ArenaRecycleError(RuntimeError):
     """A pooled buffer was recycled twice (double release of one
@@ -68,9 +72,6 @@ class Arena:
         self.name = name
         self._free: List[np.ndarray] = []
         self._lock = threading.Lock()
-        self.max_cached = mca_param.register(
-            "runtime", "arena_max_cached", 64,
-            help="max buffers cached per arena freelist")
         self.max_used = mca_param.register(
             "runtime", "arena_max_used", 0,
             help="max outstanding buffers per arena (0=unlimited)")
@@ -131,7 +132,7 @@ class Arena:
         copy.payload = None
         with self._lock:
             self.nb_used -= 1
-            if buf is not None and len(self._free) < self.max_cached:
+            if buf is not None and len(self._free) < ARENA_MAX_CACHED:
                 self._free.append(buf)
             if pins.active(pins.ARENA_RECYCLE):
                 # fired under the freelist lock: the hb checker chains

@@ -23,8 +23,9 @@ Two data paths, selectable with ``algo=`` (MCA
   communication"): regions are staged into budget-capped batches, walked
   in linear-shift order, pulled in pipelined chunks, and scattered
   straight into the target tiles — peak extra memory per rank stays
-  under ``runtime_redistribute_mem_budget`` and each byte crosses the
-  wire exactly once.  ``auto`` picks this path on multi-rank meshes.
+  under the budget (``mem_budget=``, :data:`MEM_BUDGET_DEFAULT`) and
+  each byte crosses the wire exactly once.  ``auto`` picks this path on
+  multi-rank meshes.
 
 Both paths produce bit-identical targets (pure copies)."""
 
@@ -38,7 +39,8 @@ from ..dsl.dtd import AFFINITY, CTL, DTDTaskpool, IN, INOUT
 from ..utils import debug, mca_param
 from .matrix import TiledMatrix
 
-#: default peak-extra-memory budget for the collective path (bytes)
+#: peak extra bytes per rank (staging + landing buffers) the collective
+#: path may hold at once, unless the caller gives ``mem_budget=``
 MEM_BUDGET_DEFAULT = 16 << 20
 
 
@@ -99,9 +101,10 @@ def redistribute(
     """Copy ``S[ia:ia+m, ja:ja+n]`` into ``T[ib:ib+m, jb:jb+n]`` as a
     taskpool (reference ``parsec_redistribute``). Defaults copy the full
     common window. Returns the taskpool; ``wait()`` it (or compose it).
-    ``algo``/``mem_budget`` override the MCA parameters (see module
-    docstring); the taskpool's ``user`` dict reports the path taken and,
-    for the collective path, the measured ``peak_extra_bytes``."""
+    ``algo`` overrides ``runtime_redistribute_algo`` and ``mem_budget``
+    :data:`MEM_BUDGET_DEFAULT` (see module docstring); the taskpool's
+    ``user`` dict reports the path taken and, for the collective path,
+    the measured ``peak_extra_bytes``."""
     m = m if m is not None else min(S.m - ia, T.m - ib)
     n = n if n is not None else min(S.n - ja, T.n - jb)
     if m <= 0 or n <= 0:
@@ -259,10 +262,8 @@ def _redistribute_coll(context, S, T, *, m, n, ia, ja, ib, jb,
     T tiles; receive side: remote regions scattered straight into the
     INOUT tile buffers; rank-local regions copy directly).  It pumps
     the comm engine while it waits, so a 1-worker rank cannot wedge."""
-    budget = int(mem_budget if mem_budget is not None else mca_param.register(
-        "runtime", "redistribute_mem_budget", MEM_BUDGET_DEFAULT,
-        help="peak extra bytes per rank (staging + landing buffers) the "
-             "collective redistribution path may hold at once"))
+    budget = int(mem_budget if mem_budget is not None
+                 else MEM_BUDGET_DEFAULT)
     if budget <= 0:
         raise ValueError(
             f"redistribute mem budget must be positive, got {budget}")
@@ -365,8 +366,7 @@ def _redistribute_coll(context, S, T, *, m, n, ia, ja, ib, jb,
                     debug.warning(
                         "redistribute %s: peak extra memory %dB exceeded "
                         "the %dB budget (an oversized single region "
-                        "forces this; raise "
-                        "runtime_redistribute_mem_budget)",
+                        "forces this; pass a larger mem_budget=)",
                         tp.name, op.result()["peak_extra_bytes"], budget)
             else:
                 tp.user.setdefault("peak_extra_bytes", 0)

@@ -26,6 +26,10 @@ ADVICE_PREFETCH = 0x01
 ADVICE_PREFERRED_DEVICE = 0x02
 ADVICE_WARMUP = 0x03
 
+#: multiplier applied to accelerator ETAs in :func:`select_device`
+#: (<1 favours accelerators; reference ``device_load_balance_skew``)
+LOAD_BALANCE_SKEW = 0.9
+
 
 class Device(Component):
     """Base device module (reference device vtable, ``device.h:142-158``)."""
@@ -154,10 +158,6 @@ def attach_devices(context: "Context", names: Optional[List[str]] = None) -> Lis
         devices.append(dev)
     if not devices or devices[0].device_type != DEV_CPU:
         raise RuntimeError("CPU device must attach first")
-    context._device_skew = mca_param.register(
-        "device", "load_balance_skew", 0.9,
-        help="multiplier applied to accelerator ETAs (<1 favours accelerators)",
-    )
     return devices
 
 
@@ -188,10 +188,9 @@ def select_best_device(context: "Context", task: "Task") -> HookReturn:
       1. data affinity — an accelerator already holding the task's inputs
          wins outright (saves HBM traffic);
       2. minimal ETA = device_load + time_estimate, accelerators discounted
-         by the load-balance skew parameter.
+         by :data:`LOAD_BALANCE_SKEW`.
     """
     tc = task.task_class
-    skew = getattr(context, "_device_skew", 0.9)
     eligible = []
     for dev in context.devices:
         if not dev.enabled:
@@ -232,7 +231,7 @@ def select_best_device(context: "Context", task: "Task") -> HookReturn:
             est = chore.time_estimate(task, dev) if chore.time_estimate else dev.time_estimate(task)
             eta = dev.device_load + est
             if dev.device_type != DEV_CPU:
-                eta *= skew
+                eta *= LOAD_BALANCE_SKEW
             if best_eta is None or eta < best_eta:
                 best_eta, best = eta, (dev, chore, ci)
     dev, chore, ci = best

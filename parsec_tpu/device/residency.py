@@ -79,7 +79,7 @@ from typing import (Any, Callable, Dict, Iterable, List, MutableMapping,
 
 from ..data.data import Coherency, Data
 from ..profiling import pins
-from ..utils import debug, mca_param
+from ..utils import debug
 
 #: dtype -> its name, as ``stats["tiles_by_dtype"]`` spells it
 _DTYPE_NAMES: Dict[Any, str] = {None: "?"}
@@ -100,22 +100,17 @@ UNKNOWN = -1
 
 def native_zone(platform: str) -> bool:
     """Which accounting a device on ``platform`` gets: True for the
-    native zone allocator (``device_tpu_native_zone``, the default,
-    where the native core is built)."""
-    if not mca_param.register(
-            "device", "tpu_native_zone", 1,
-            help="use the native zone allocator for HBM accounting"):
-        return False
+    native zone allocator, wherever the native core is built."""
     from .. import native
 
     built = native.available()
     if not built and platform == "tpu":
         # on the CPU backend byte-counter accounting stands in (the
-        # native-off CI leg); on a chip the configured allocator missing
-        # is a broken installation
+        # native-off CI leg); on a chip the allocator missing is a
+        # broken installation
         raise RuntimeError(
-            "device_tpu_native_zone=1 but the native core is "
-            f"unavailable: {native.build_error()}")
+            "a TPU's HBM is accounted in the native zone allocator, but "
+            f"the native core is unavailable: {native.build_error()}")
     return built
 
 

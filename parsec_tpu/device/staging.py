@@ -66,6 +66,10 @@ from ..data.data import Coherency, Data
 from ..profiling import pins
 from ..utils import debug, mca_param
 
+#: max tiles per committer drain batch (its D2H copies collected in one
+#: wait)
+WB_BATCH = 32
+
 #: process-wide span ids for STAGE_IN/WRITEBACK begin/end pairing
 _SPAN_SEQ = itertools.count(1)
 
@@ -760,10 +764,6 @@ class WritebackCommitter:
             help="deferred write-back watermark (MB): the committer "
                  "drains batched D2H gets once this many dirty bytes "
                  "are pending (a flush or a last version drains sooner)"))) << 20
-        self._batch = max(1, int(mca_param.register(
-            "runtime", "wb_batch", 32,
-            help="max tiles per committer drain batch (its D2H copies "
-                 "collected in one wait)")))
         self._tickets = itertools.count(1)
         #: (pool, batch) of the newest enqueue: the cause a commit names
         self._cause = (0, 0)
@@ -885,26 +885,6 @@ class WritebackCommitter:
             self._kick = True
             self._cv.notify_all()
 
-    def wait_for(self, data_id: int, timeout: float = 60.0) -> bool:
-        """Block until ``data_id`` is neither pending nor in flight
-        (kicking the committer first).  Returns False on committer death
-        or timeout — the caller falls back to a synchronous write-back
-        (the version guard makes the duplicate safe).  For whoever
-        needs ONE tile home without a whole flush (an eviction writes
-        its batch of victims home itself)."""
-        deadline = time.monotonic() + timeout
-        with self._cv:
-            self._kick = True
-            self._cv.notify_all()
-            while data_id in self._pending or data_id in self._inflight:
-                if self.error is not None:
-                    return False
-                left = deadline - time.monotonic()
-                if left <= 0:
-                    return False
-                self._cv.wait(timeout=min(left, 1.0))
-            return self.error is None
-
     def flush(self, timeout: float = 300.0) -> None:
         """Barrier: every deferred write-back enqueued so far is
         committed (or provably stale) on return.  ``detach()``,
@@ -970,7 +950,7 @@ class WritebackCommitter:
                 forced = self._kick or self._flushing or self._stop
                 self._kick = False
                 grab = list(itertools.islice(
-                    self._pending.items(), self._batch))
+                    self._pending.items(), WB_BATCH))
                 for did, entry in grab:
                     del self._pending[did]
                     self._pending_bytes -= entry[2]

@@ -70,14 +70,10 @@ class TemplateDevice(Device):
 
     @classmethod
     def available(cls) -> bool:
-        """Inert unless explicitly enabled (the reference template never
-        builds by default either): set PARSEC_MCA_device_template_enabled=1
-        or pass ``devices=[..., "template"]`` to Context."""
-        from ..utils import mca_param
-
-        return bool(mca_param.register(
-            "device", "template_enabled", 0,
-            help="attach the template (host-exec) device module"))
+        """Inert unless asked for by name (the reference template never
+        builds by default either): pass ``devices=[..., "template"]`` to
+        Context, or name it in ``device_enabled``."""
+        return False
 
     def __init__(self, context, index: int):
         super().__init__(context, index)

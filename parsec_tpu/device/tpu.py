@@ -100,6 +100,14 @@ def _pool_of(task: Task) -> int:
     return getattr(task.taskpool, "taskpool_id", 0)
 
 
+#: the pump's intra-wave split threshold: a lone ready batch is
+#: re-sliced across the prefetch window (intra-wave double buffering)
+#: only when its prestage would move at least this many host->device
+#: bytes — splitting shrinks vmappable waves, so it must buy real
+#: transfer overlap; below it a lone ready frontier ships as one wave
+STAGE_SPLIT_BYTES = 256 << 10
+
+
 #: one task as ``_stage_chunk`` leaves it: the task, its staged argument
 #: list, its ``(position in body_args, tile)`` outputs
 _Staged = Tuple[Task, List[Any], List[Tuple[int, Data]]]
@@ -334,15 +342,9 @@ class TpuDevice(Device):
         #: transfers (no prefetch lane, no committer — the A/B OFF arm);
         #: >= 2 arms the prefetch window and the write-back committer
         self.stage_depth = stage_depth_param()
-        #: the pump's intra-wave split threshold: a lone ready batch is
-        #: re-sliced across the prefetch window only when its prestage
-        #: would move at least this many bytes — splitting shrinks
-        #: vmappable waves, so it must buy real transfer overlap
-        self.stage_split_bytes = max(0, int(mca_param.register(
-            "runtime", "stage_split_kb", 256,
-            help="min host->device bytes (KB) a ready batch must need "
-                 "staged before the pump re-slices it across the "
-                 "prefetch window (intra-wave double buffering)"))) << 10
+        #: the pump's intra-wave split threshold, read by the pump
+        #: from the device it drives
+        self.stage_split_bytes = STAGE_SPLIT_BYTES
         self._committer = None
 
     def _span(self, name: str, **info):
@@ -1856,9 +1858,3 @@ class TpuDevice(Device):
             self._res.clear()
         self._converted.clear()
         self._sigs_seen.clear()
-
-
-def device_body(chore, fn):
-    """Attach the raw functional body to an accelerator chore."""
-    chore.body_fn = fn
-    return chore

@@ -20,7 +20,7 @@ The payoff of the task form over calling ``ce.coll_allreduce`` by hand:
   completes; the collective's control messages are themselves counted by
   the four-counter protocol on both sides;
 * **priority isolation** — collective traffic rides below dependency
-  activations (MCA ``runtime_coll_priority``), so a bulk allreduce
+  activations (``comm.coll.COLL_PRIORITY``), so a bulk allreduce
   never starves the critical path of the surrounding graph.
 
 Usage (identical on every rank — SPMD)::

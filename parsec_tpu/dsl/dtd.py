@@ -188,6 +188,20 @@ def stage_to_cpu(data: Data) -> np.ndarray:
     return host
 
 
+def window_params() -> Tuple[int, int]:
+    """``(dtd_window_size, dtd_threshold_size)``: the insertion throttle
+    of every inserter (this pool and ``dtd_native``'s streaming one)."""
+    return (
+        mca_param.register(
+            "dtd", "window_size", 2048,
+            help="max in-flight inserted tasks before the inserter helps "
+                 "execute"),
+        mca_param.register(
+            "dtd", "threshold_size", 1024,
+            help="in-flight level the inserter drains down to when the "
+                 "window fills"))
+
+
 class DTDTaskpool(Taskpool):
     """Reference ``parsec_dtd_taskpool_new`` (insert_function.h:332)."""
 
@@ -201,12 +215,7 @@ class DTDTaskpool(Taskpool):
         self._retired = 0
         self._quiesce = threading.Condition()
         self._open = True
-        self.window = mca_param.register(
-            "dtd", "window_size", 2048,
-            help="max in-flight inserted tasks before the inserter helps execute")
-        self.threshold = mca_param.register(
-            "dtd", "threshold_size", 1024,
-            help="in-flight level the inserter drains down to when the window fills")
+        self.window, self.threshold = window_params()
         self._war_rename = mca_param.register(
             "dtd", "war_rename", True,
             help="break WAR hazards by renaming (fresh writer buffer) instead of serializing")

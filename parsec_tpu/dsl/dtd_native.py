@@ -29,7 +29,7 @@ import numpy as np
 
 from ..core.lifecycle import AccessMode
 from ..profiling import pins
-from ..utils.mca_param import params as mca_param
+from .dtd import window_params
 
 IN = AccessMode.IN
 OUT = AccessMode.OUT
@@ -86,12 +86,7 @@ class NativeDTD:
         # insertion throttle, same knobs as the Python DTD (reference
         # window/threshold MCA params): bounds live closures + their
         # argument arrays to tasks in flight, not tasks ever inserted
-        self.window = mca_param.register(
-            "dtd", "window_size", 2048,
-            help="max in-flight inserted tasks before the inserter helps execute")
-        self.threshold = mca_param.register(
-            "dtd", "threshold_size", 1024,
-            help="in-flight level the inserter drains down to when the window fills")
+        self.window, self.threshold = window_params()
 
         def trampoline(_tid: int, user_tag: int) -> None:
             body = self._bodies[user_tag]

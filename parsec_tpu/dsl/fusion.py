@@ -51,8 +51,6 @@ MCA knobs (framework ``runtime``):
   consults the PR-7 :class:`~parsec_tpu.tuning.TuningStore` for the
   fusion horizon (op ``fusion``, param ``max_tasks``) so the
   granularity is autotunable per device generation;
-* ``runtime_fusion_max_tasks`` — hard cap on members per region
-  (0 = consult the tuning store, falling back to 16);
 * ``runtime_fusion_scan`` = ``auto`` | ``off`` | ``on`` — lower uniform
   chains as one ``lax.scan`` instead of unrolling (compile time O(1)
   in chain length); ``auto`` requires equal member shapes.
@@ -99,8 +97,8 @@ def _body_fp(body) -> str:
             pass
     return fp
 
-#: fusion horizon used when runtime_fusion_max_tasks=0 and the tuning
-#: store has no entry for this device generation
+#: fusion horizon (max member tasks per fused region) used when the
+#: tuning store has no entry for this device generation
 DEFAULT_HORIZON = 16
 #: minimum uniform-chain length worth rolling into a lax.scan
 SCAN_MIN = 4
@@ -122,14 +120,8 @@ def fusion_mode() -> str:
 
 
 def fusion_max_tasks(device=None) -> int:
-    """Region-size horizon: the MCA cap, or (when 0) the tuning store's
-    per-device-generation entry, or :data:`DEFAULT_HORIZON`."""
-    cap = int(mca_param.register(
-        "runtime", "fusion_max_tasks", 0, level=3,
-        help="max member tasks per fused region (0 = consult the "
-             "autotuner store, default 16)"))
-    if cap > 0:
-        return cap
+    """Region-size horizon: the tuning store's per-device-generation
+    entry, or :data:`DEFAULT_HORIZON`."""
     try:
         from .. import tuning
 
@@ -804,13 +796,6 @@ class FusionTable:
         if lr is None:
             return None
         return lr.ext_goals[(name, tuple(locs))]
-
-    def same_region(self, a: TaskId, b: TaskId) -> bool:
-        lr = self._member.get(a)
-        return lr is not None and (b in lr.region.member_set)
-
-    def is_member(self, name: str, locs: Tuple) -> bool:
-        return (name, tuple(locs)) in self._member
 
     def route_ready(self, name: str, locs: Tuple):
         """One external-readiness event for a member (counter fired, or

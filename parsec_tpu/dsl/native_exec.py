@@ -18,8 +18,7 @@ Scope: single-rank.  Two body regimes:
   (staging, wave batching, jit cache all intact) under one of two
   protocols:
 
-  - **pump mode** (the default for all-device DAGs,
-    ``runtime_native_sched=auto``): the native engine owns the ENTIRE
+  - **pump mode** (all-device DAGs): the native engine owns the ENTIRE
     per-task lifecycle — ready-queue ordering (spq priority order, the
     serve plane's wdrr tenant bins, or the schedule explorer's seeded
     perturbation), dep-counter decrement on completion, successor
@@ -32,8 +31,8 @@ Scope: single-rank.  Two body regimes:
     Lifecycle events (dep decrements, publishes, retires) buffer
     natively and drain in batches into the existing PINS sites when
     observers (hb-check, binary traces, SLO plane) are installed.
-  - **legacy ASYNC chores** (``runtime_native_sched=off``, or mixed
-    DAGs with CPU-fallback bodies): native worker threads enter Python
+  - **legacy ASYNC chores** (mixed DAGs with CPU-fallback bodies, the
+    input the pump cannot run): native worker threads enter Python
     once to enqueue (chore returns ASYNC) and once per completion
     callback (``pz_task_done``) — exactly two entries per task, never
     for dependency bookkeeping (the PR-3 protocol; the reference keeps
@@ -55,20 +54,11 @@ from typing import Any, Callable, Dict, List, Optional, Tuple
 import numpy as np
 
 from ..core.lifecycle import AccessMode, HookReturn, DEV_CPU, DEV_TPU
+from ..core.sched.wdrr import QUANTUM as WDRR_QUANTUM
 from ..core.task import Chore, Task, TaskClass
 from ..profiling import pins
 from .graph import TaskGraph, capture, source_tile
 from .ptg import CTL, PTGTaskpool, _wrap_device_body
-
-
-def _native_sched_mode() -> str:
-    from ..utils import mca_param
-
-    return str(mca_param.register(
-        "runtime", "native_sched", "auto",
-        help="native-device lifecycle protocol: auto (pump mode — zero "
-             "interpreter entries per task for all-device DAGs) | off "
-             "(legacy ASYNC-chore protocol: two entries per task)"))
 
 
 def _drain_batch() -> int:
@@ -356,8 +346,7 @@ def _pump_loop(ng, dev, pump_index: Dict[int, Any], stats: Dict[str, int],
                 tiles, nbytes = (dev.prestage_tiles(batch)
                                  if lane is not None else ((), 0))
                 if (tiles and not window and free and n >= 4
-                        and nbytes
-                        >= getattr(dev, "stage_split_bytes", 1 << 18)):
+                        and nbytes >= dev.stage_split_bytes):
                     # the whole ready frontier fit ONE buffer, the
                     # window is otherwise idle, and there is REAL
                     # transfer work to hide: re-slice the batch across
@@ -659,18 +648,13 @@ class NativeExecutor:
                     "NativeServeExecutor requires all-device task "
                     f"classes ({tp.ptg.name} has CPU-only classes)")
             self._pump = True
-        elif (not plan.has_cpu_bodies and _native_sched_mode() != "off"
+        elif (not plan.has_cpu_bodies
                 and getattr(self.device, "_eager", True)):
-            from ..utils import mca_param
+            from ..core.sched.rnd import rnd_seed
 
             # the schedule explorer's seed reaches the native scheduler
             # through the SAME param the Python rnd scheduler reads
-            seed = int(mca_param.register(
-                "sched", "rnd_seed", -1,
-                help="seed for the rnd scheduler's RNG (>=0 replays one "
-                     "schedule deterministically — the schedule "
-                     "explorer's replay hook; -1 = unseeded fuzzing)"))
-            ng.sched_config(policy="prio", quantum=0, seed=seed)
+            ng.sched_config(policy="prio", quantum=0, seed=rnd_seed())
             self._pump = True
 
         # the tiles: a collection's Data, or a scratch tile
@@ -1130,27 +1114,14 @@ class NativeExecutor:
         self.stats["conformance_events"] = len(events)
 
     def _apply_vpmap(self, nthreads: int) -> None:
-        from ..utils import mca_param
-        from ..utils.binding import VPMap
+        from ..core.context import configured_vpmap
 
-        spec = str(mca_param.register(
-            "runtime", "vpmap", "flat",
-            help="virtual-process map: flat | nb:K | explicit '0,1;2,3'"))
-        try:
-            if spec.startswith("nb:"):
-                k = int(spec[3:])
-                if k < 1:
-                    raise ValueError("nb:K needs K >= 1")
-                vm = VPMap.from_nb_vps(nthreads, k)
-            elif ";" in spec or "," in spec:
-                vm = VPMap.from_spec(spec)
-            else:
-                return  # flat: no hierarchy to express
-        except Exception as e:
-            # loud: a silently-flat run would masquerade as a perfect-
-            # locality hierarchical measurement (steals_remote == 0)
-            raise ValueError(f"invalid runtime_vpmap {spec!r}: {e}")
-        self._ng.set_vpmap([vm.vp_of(w) for w in range(nthreads)])
+        # (a spec that cannot be read raises, loud: a silently-flat run
+        # would masquerade as a perfect-locality hierarchical
+        # measurement, steals_remote == 0)
+        vm = configured_vpmap(nthreads)
+        if vm is not None:  # flat: no hierarchy to express
+            self._ng.set_vpmap([vm.vp_of(w) for w in range(nthreads)])
 
     def rebind(self, tp: PTGTaskpool) -> "NativeExecutor":
         """Re-aim this executor at a SAME-SHAPE taskpool (identical task
@@ -1282,13 +1253,9 @@ class NativeServeExecutor:
         self.ng = native.NativeGraph()
         self.device = device if device is not None \
             else NativeExecutor._make_device()
-        quantum = int(mca_param.register(
-            "sched", "wdrr_quantum", 4,
-            help="task credits a tenant's deficit gains per round-robin "
-                 "visit, scaled by the tenant's weight"))
         # BEFORE any child builds: commit-time source pushes must land
         # in the configured wdrr bins
-        self.ng.sched_config(policy="wdrr", quantum=quantum, seed=seed)
+        self.ng.sched_config(policy="wdrr", quantum=WDRR_QUANTUM, seed=seed)
         self.stats: Dict[str, int] = _new_stats()
         self.children: List[NativeExecutor] = []
         self.retire_log: List[Tuple[int, int, float]] = []

@@ -93,7 +93,7 @@ _EVAL_GLOBALS = {"__builtins__": _SAFE_BUILTINS}
 #: process-wide, incremented under the GIL; read via
 #: :func:`exists_eval_count` and difference around a run — the
 #: deterministic replacement for the wall-clock scaling assertion of
-#: tests/dsl/test_exists_stress.py (ADVICE.md round-5 item 5).
+#: tests/dsl/test_exists_stress.py.
 _exists_evals = 0
 
 
@@ -616,8 +616,8 @@ class PTGTaskClass:
 
         Every DIRECT evaluation (memo miss included) bumps the module
         counter read by :func:`exists_eval_count` — tests pin the O(1)
-        law on that counter instead of wall-clock (ADVICE.md round-5
-        item 5: timing-ratio assertions flake on loaded hosts)."""
+        law on that counter instead of wall-clock (timing-ratio
+        assertions flake on loaded hosts)."""
         global _exists_evals
         if memo is not None:
             mk = (self.name, key)

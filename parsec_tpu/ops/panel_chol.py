@@ -21,10 +21,10 @@ The update is a single (R x nb) x (nb x R) MXU gemm — both triangles are
 written, which keeps the trailing matrix symmetric (so no masking is
 needed anywhere) at the cost of ~2x update flops vs a tile-wise syrk.
 At north-star sizes the raw MXU rate on these huge gemms more than
-covers it (measure, don't guess: bench_panel below prints useful-flops
-TFLOPS = N^3/3 / t).  ``bf16=True`` feeds the gemm operands in bfloat16
-with f32 accumulation — the same mixed-precision recipe as the Pallas
-graph path, same numerics gate.
+covers it (the benchmark's ``panel_n32768`` cell measures it).
+``bf16=True`` feeds the gemm operands in bfloat16 with f32 accumulation
+— the same mixed-precision recipe as the Pallas graph path, same
+numerics gate.
 
 The matrix is padded to a bucket multiple with an identity diagonal:
 padded panel rows are zero => their updates are zero; the slices stay

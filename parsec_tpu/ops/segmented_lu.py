@@ -94,8 +94,7 @@ def _make_lu_body(n: int, nb: int, strip: int, prec, kt: int, bf16=False,
     (default: ``prec``).  The round-5 change dropped them from HIGHEST
     to the 3-pass HIGH for throughput (they otherwise cost ~the whole
     trailing update); callers who relied on HIGHEST solves pass
-    ``solve_prec=Precision.HIGHEST`` to restore the old numerics
-    (ADVICE.md round-5 item 4).
+    ``solve_prec=Precision.HIGHEST`` to restore the old numerics.
 
     ``fused_update`` (f32 path only; round-4 VERDICT #5): the trailing
     update runs as the fused single-kernel Pallas 3-pass
@@ -373,7 +372,7 @@ def segmented_lu_ptg(n: int, nb: int, *, strip: int = 4096,
     trailing gemm is ~all the flops); ``"storage"`` = the whole matrix
     lives in bf16 (panel math upcast to f32), HALF the HBM traffic.
     bf16-class numerics (~1e-3 on off-diagonal entries) — callers gate
-    at the 1e-2 bf16 bar and label fields accordingly (bench.py).
+    at the 1e-2 bf16 bar and label fields accordingly.
 
     ``pivot``: ``"block"`` (default) = NOPIV-CLASS mode — the pivot
     search is restricted to the nb diagonal rows; exact for the

@@ -2,7 +2,7 @@
 
 The reference's dependency broadcasts travel down host-chosen topology
 trees — star, chain-pipeline, binomial — re-rooted at the sender
-(``/root/reference/parsec/remote_dep.c:262-345``, MCA
+(``/root/reference/parsec/remote_dep.c:262-345``, the reference's MCA
 ``runtime_comm_coll_bcast``). On TPU the transport is ICI and the
 primitives are XLA collectives; these helpers express the same three
 topologies as rounds of ``lax.ppermute`` inside ``shard_map``, plus thin
@@ -20,8 +20,6 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 from jax import lax
-
-from ..utils import mca_param
 
 
 def my_index(axis: str) -> jax.Array:
@@ -81,12 +79,11 @@ def bcast_binomial(x, axis: str, root: int = 0):
 
 
 def bcast(x, axis: str, root: int = 0, topology: Optional[str] = None):
-    """Topology-selectable broadcast (reference ``runtime_comm_coll_bcast``:
-    0=star 1=chain 2=binomial)."""
-    topo = topology or mca_param.register(
-        "runtime", "comm_coll_bcast", "binomial",
-        help="broadcast topology: star|chain|binomial")
-    fn = {"star": bcast_star, "chain": bcast_chain, "binomial": bcast_binomial}[topo]
+    """Broadcast by ``topology`` (star | chain | binomial, the
+    default; reference ``runtime_comm_coll_bcast``: 0=star 1=chain
+    2=binomial)."""
+    fn = {"star": bcast_star, "chain": bcast_chain,
+          "binomial": bcast_binomial}[topology or "binomial"]
     return fn(x, axis, root)
 
 

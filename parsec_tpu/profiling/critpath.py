@@ -113,8 +113,7 @@ def analyze(events: List[dict], *, exec_name: str = "exec",
             compile_names: Sequence[str] = COMPILE_SPAN_NAMES,
             coll_names: Sequence[str] = COLL_SPAN_NAMES,
             transfer_names: Sequence[str] = TRANSFER_SPAN_NAMES,
-            job=None, straggler_factor: Optional[float] = None,
-            straggler_min_samples: Optional[int] = None) -> dict:
+            job=None, straggler_factor: Optional[float] = None) -> dict:
     """Reconstruct the dependency critical path and attribute its wall
     time.  Returns a report dict::
 
@@ -144,8 +143,8 @@ def analyze(events: List[dict], *, exec_name: str = "exec",
     against the mesh median of per-rank means over the WHOLE trace:
     the offline counterpart of the live OBS010 finding, through the
     SAME comparison (``profiling.slo.mesh_stragglers``) and the same
-    MCA-tuned thresholds (``runtime_straggler_factor`` /
-    ``runtime_straggler_min_samples``) unless overridden here."""
+    thresholds (``runtime_straggler_factor`` unless overridden here,
+    ``slo.STRAGGLER_MIN_SAMPLES``)."""
     from .jobtrace import hex_id, job_index, parse_trace_id
 
     job_id: Optional[int] = None
@@ -267,8 +266,7 @@ def analyze(events: List[dict], *, exec_name: str = "exec",
     # offline straggler attribution over the WHOLE trace (before any
     # job slicing): per-(class, rank) mean exec vs the mesh median of
     # per-rank means — the offline counterpart of the live OBS010
-    stragglers = _find_stragglers(tasks, classes, straggler_factor,
-                                  straggler_min_samples)
+    stragglers = _find_stragglers(tasks, classes, straggler_factor)
 
     empty = {"wall_us": 0.0, "n_tasks": 0, "coverage": 0.0,
              "buckets": {"compute_us": 0.0, "comm_us": 0.0,
@@ -460,18 +458,15 @@ def analyze(events: List[dict], *, exec_name: str = "exec",
 
 def _find_stragglers(tasks: Dict[Tuple[Any, int], dict],
                      classes: Dict[Tuple[Any, int], str],
-                     factor: Optional[float],
-                     min_samples: Optional[int]) -> List[dict]:
+                     factor: Optional[float]) -> List[dict]:
     """Per-(class, rank) exec-mean outliers over the trace — the SAME
-    comparison and MCA thresholds as the live OBS010 plane
+    comparison and thresholds as the live OBS010 plane
     (``profiling.slo.mesh_stragglers``), fed trace-derived means."""
-    from .slo import mesh_stragglers, straggler_params
+    from .slo import (STRAGGLER_MIN_SAMPLES, mesh_stragglers,
+                      straggler_factor)
 
-    mca_factor, mca_min = straggler_params()
     if factor is None:
-        factor = mca_factor
-    if min_samples is None:
-        min_samples = mca_min
+        factor = straggler_factor()
     acc: Dict[Tuple[str, Any], List[float]] = defaultdict(
         lambda: [0, 0.0])  # (cls, pid) -> [count, sum_us]
     for key, t in tasks.items():
@@ -488,7 +483,7 @@ def _find_stragglers(tasks: Dict[Tuple[Any, int], dict],
              "mesh_median_us": round(med, 1),
              "factor": round(ratio, 2)}
             for cls, pid, mean, med, ratio in mesh_stragglers(
-                by_class, factor, min_samples)]
+                by_class, factor, STRAGGLER_MIN_SAMPLES)]
 
 
 def render(report: dict) -> str:

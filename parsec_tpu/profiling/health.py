@@ -50,7 +50,6 @@ Env wiring (read by ``Context.__init__``):
 from __future__ import annotations
 
 import json
-import re
 import threading
 import time
 from http.server import BaseHTTPRequestHandler, ThreadingHTTPServer
@@ -380,13 +379,6 @@ def register_context_gauges(ctx) -> Callable[[], None]:
 # ---------------------------------------------------------------------------
 # Prometheus rendering
 # ---------------------------------------------------------------------------
-
-_NAME_RE = re.compile(r"[^a-zA-Z0-9_]")
-
-
-def _sanitize(name: str) -> str:
-    return _NAME_RE.sub("_", name).strip("_").lower()
-
 
 def _esc(v: Any) -> str:
     return str(v).replace("\\", "\\\\").replace('"', '\\"')

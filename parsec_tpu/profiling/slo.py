@@ -156,19 +156,19 @@ def mesh_stragglers(by_class: Dict[str, Dict[Any, Tuple[int, float]]],
     return out
 
 
-def straggler_params() -> Tuple[float, int]:
-    """The MCA-tuned (factor, min_samples) thresholds — one source for
-    the live OBS010 plane and the offline critpath report."""
-    factor = float(mca_param.register(
+#: per-(class, rank) exec samples required before the straggler
+#: comparison considers the pair
+STRAGGLER_MIN_SAMPLES = 5
+
+
+def straggler_factor() -> float:
+    """The ``runtime_straggler_factor`` threshold — one source for the
+    live OBS010 plane and the offline critpath report."""
+    return float(mca_param.register(
         "runtime", "straggler_factor", 3.0,
         help="a rank running a task class this many times slower "
              "than the mesh median of per-rank means is flagged as "
              "a straggler (OBS010)"))
-    min_samples = int(mca_param.register(
-        "runtime", "straggler_min_samples", 5,
-        help="per-(class, rank) exec samples required before the "
-             "straggler comparison considers the pair"))
-    return factor, min_samples
 
 
 def prometheus_histogram_lines(name: str, labels: Dict[str, Any],
@@ -216,7 +216,7 @@ class SloPlane:
 
     def __init__(self, context):
         self.context = context
-        self.factor, self.min_samples = straggler_params()
+        self.factor = straggler_factor()
         self.default_slo_ms = float(mca_param.register(
             "serve", "slo_p95_ms", 0.0,
             help="default per-tenant p95 job-latency SLO target in "
@@ -396,7 +396,7 @@ class SloPlane:
             "factor": round(ratio, 2),
             "jobs": self._jobs_with_class(cls),
         } for cls, r, mean, med, ratio in mesh_stragglers(
-            self._mesh_exec(), self.factor, self.min_samples)]
+            self._mesh_exec(), self.factor, STRAGGLER_MIN_SAMPLES)]
 
     def _jobs_with_class(self, cls: str) -> List[str]:
         """In-flight serve jobs whose pools carry ``cls`` — the 'jobs it

@@ -101,11 +101,6 @@ def history_dump(file=None) -> None:
         print(f"{ts:.6f} [{subsystem}:{level}] {msg}", file=file)
 
 
-def history_clear() -> None:
-    with _lock:
-        _history.clear()
-
-
 class FatalError(RuntimeError):
     """Raised on unrecoverable runtime errors (reference parsec_fatal)."""
 

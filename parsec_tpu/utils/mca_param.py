@@ -22,7 +22,7 @@ from __future__ import annotations
 import os
 import threading
 from dataclasses import dataclass, field
-from typing import Any, Callable, Dict, List, Optional
+from typing import Any, Dict, List, Optional
 
 _ENV_PREFIX = "PARSEC_MCA_"
 
@@ -97,7 +97,6 @@ class ParamRegistry:
     def __init__(self) -> None:
         self._params: Dict[str, _Param] = {}
         self._lock = threading.RLock()
-        self._watchers: Dict[str, List[Callable[[Any], None]]] = {}
 
     # -- registration -----------------------------------------------------
     def register(
@@ -180,8 +179,6 @@ class ParamRegistry:
                 self._params[key] = p
             p.set_value = _coerce(value, p.type)
             p.has_set = True
-            for cb in self._watchers.get(key, ()):
-                cb(p.set_value)
 
     def source(self, framework: str, name: str) -> str:
         """Where the current value came from: ``api`` | ``env`` | ``file``
@@ -201,10 +198,6 @@ class ParamRegistry:
             if p is not None:
                 p.has_set = False
                 p.set_value = None
-
-    def watch(self, framework: str, name: str, cb: Callable[[Any], None]) -> None:
-        with self._lock:
-            self._watchers.setdefault(f"{framework}_{name}", []).append(cb)
 
     # -- files ------------------------------------------------------------
     def load_file(self, path: str) -> int:
@@ -307,7 +300,6 @@ class ParamRegistry:
         """Drop all registrations (test isolation helper)."""
         with self._lock:
             self._params.clear()
-            self._watchers.clear()
 
 
 #: process-wide registry instance

@@ -8,7 +8,7 @@ references a nonexistent instance, so any O(span) behavior in
 ``instance_exists``/``valid`` shows up as predicate WORK scaling
 with M.
 
-Round-5 ADVICE item 5: the original wall-clock 5x ratio assertion was
+The original wall-clock 5x ratio assertion was
 host-load dependent; the assertion now reads the deterministic
 predicate-work counter (``dsl.ptg.exists_eval_count`` — direct
 evaluations plus materialized candidate values), which an O(span) scan

@@ -43,7 +43,7 @@ def test_jdf_cholesky_dynamic():
 
 def test_jdf_cholesky_whole_dag_capture():
     """The same JDF lowered to ONE jitted XLA computation via its tpu
-    incarnations (bench.py's fast path, from a .jdf source)."""
+    incarnations (the whole-DAG fast path, from a .jdf source)."""
     from parsec_tpu.dsl.xla_lower import GraphExecutor
 
     N, NB = 128, 32

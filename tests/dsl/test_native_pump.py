@@ -1,8 +1,8 @@
 """Pump-mode (zero-interpreter lifecycle) coverage beyond the PINS pin
 in test_native_device.py (ISSUE 18):
 
-* the ``runtime_native_sched=off`` escape hatch restores the legacy
-  two-entry ASYNC protocol;
+* a mixed DAG (one class with a CPU body only) takes the legacy
+  two-entry ASYNC protocol by itself: the input the pump cannot run;
 * seeded pop-order perturbation reaches the native scheduler
   (``sched_rnd_seed`` drives the SchedQ's xorshift mode) with
   bit-identical tile digests vs the Python ``rnd`` scheduler — the
@@ -54,31 +54,54 @@ def _unset(framework, name):
 
 
 # ---------------------------------------------------------------------------
-# the escape hatch: runtime_native_sched=off -> legacy ASYNC protocol
+# a mixed DAG takes the legacy ASYNC protocol by itself
 # ---------------------------------------------------------------------------
 
-def test_native_sched_off_switch_uses_legacy_protocol():
-    """With the pump disabled the PR 3 protocol still runs the DAG
-    (two interpreter entries per task: trampoline + completion), and
-    numerics stay exact — the A/B the bench measures is real."""
-    from parsec_tpu.dsl.native_exec import NativeExecutor
+def _scale(T, k):
+    return T * 2
 
-    S, A, tp = _dpotrf_device_tp(96, 24, seed=3)
-    _set("runtime", "native_sched", "off")
-    try:
-        ex = NativeExecutor(tp, native_device=True)
-        assert not ex._pump
-        ran = ex.run(nthreads=2)
-        stats = dict(ex.stats)
-        ex.close()
-    finally:
-        _unset("runtime", "native_sched")
-    assert ran == 20
-    assert stats["trampoline_entries"] == 20
-    assert stats["completion_callbacks"] == 20
-    assert stats["pop_batches"] == 0
-    L = np.tril(A.to_array())
-    np.testing.assert_allclose(L @ L.T, S, rtol=1e-10, atol=1e-10)
+
+def _shift(T, k):
+    T += 1
+
+
+def test_mixed_dag_takes_the_legacy_protocol_by_itself():
+    """A class with a CPU body only keeps the DAG out of the pump: what
+    the plan shows decides, no switch.  The PR 3 protocol runs it: two
+    interpreter entries per DEVICE task (trampoline + completion), the
+    CPU bodies inline in the native workers, no batch ever popped, and
+    the numerics exact."""
+    from parsec_tpu.core.lifecycle import AccessMode
+    from parsec_tpu.datadist import TiledMatrix
+    from parsec_tpu.dsl.native_exec import NativeExecutor
+    from parsec_tpu.dsl.ptg import PTG
+
+    nt, nb = 10, 8
+    A = TiledMatrix(nt * nb, nb, nb, nb, name="A", dtype=np.float64) \
+        .from_array(np.arange(nt * nb * nb, dtype=np.float64)
+                    .reshape(nt * nb, nb))
+    before = A.to_array()
+    ptg = PTG("mixed")
+    scale = ptg.task_class("scale", k="0 .. NT-1")
+    scale.affinity("A(k, 0)")
+    scale.flow("T", AccessMode.INOUT, "<- A(k, 0)", "-> T shift(k)")
+    scale.body(tpu=_scale)
+    shift = ptg.task_class("shift", k="0 .. NT-1")
+    shift.affinity("A(k, 0)")
+    shift.flow("T", AccessMode.INOUT, "<- T scale(k)", "-> A(k, 0)")
+    shift.body(cpu=_shift)
+    tp = ptg.taskpool(NT=A.mt, A=A)
+
+    ex = NativeExecutor(tp, native_device=True)
+    assert ex.graph.has_cpu_bodies and not ex._pump
+    ran = ex.run(nthreads=2)
+    stats = dict(ex.stats)
+    ex.close()
+    assert ran == 2 * nt
+    assert stats["trampoline_entries"] == nt
+    assert stats["completion_callbacks"] == nt
+    assert stats["pop_batches"] == 0 and stats["pumped_tasks"] == 0
+    np.testing.assert_array_equal(A.to_array(), 2 * before + 1)
 
 
 # ---------------------------------------------------------------------------

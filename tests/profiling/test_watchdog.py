@@ -4,6 +4,7 @@ fail-fast, and no false positives on healthy runs."""
 
 import random
 import threading
+import time
 
 import numpy as np
 import pytest
@@ -42,7 +43,11 @@ def test_watchdog_diagnoses_wedged_run_strict():
     strict watchdog must fail the pools within the window, and the
     diagnosis must name the blocked dependency counter (OBS002 with the
     dpotrf class) and the silent rank (OBS004: rank 1 never hears rank
-    0's heartbeats through the wedged inbox)."""
+    0's heartbeats through the wedged inbox).  The order of the two
+    diagnoses is the test's, not the host's: only rank 0 ever holds a
+    half-counted dependency (nothing reaches rank 1 to count), and a
+    strict rank 1 that fired first would abort rank 0 before it had
+    looked, so rank 1's window opens once rank 0 has reported."""
     fabric = ExplorerFabric(2, seed=3, delay_prob=0.0, max_delay=0)
     # wedge: every frame toward rank 1 defers for ~forever (bounded in
     # name only — the budget decrements one per empty pop)
@@ -51,7 +56,7 @@ def test_watchdog_diagnoses_wedged_run_strict():
     ces = fabric.endpoints()
     ctxs = [Context(nb_cores=2, rank=r, nranks=2, comm=ces[r])
             for r in range(2)]
-    wds = [Watchdog(ctx, window=1.5, poll=0.25, strict=True).start()
+    wds = [Watchdog(ctx, window=1.5, poll=0.25, strict=True)
            for ctx in ctxs]
     for ctx, wd in zip(ctxs, wds):
         ctx.watchdog = wd
@@ -69,6 +74,11 @@ def test_watchdog_diagnoses_wedged_run_strict():
                    for r in range(2)]
         for t in threads:
             t.start()
+        wds[0].start()
+        deadline = time.monotonic() + 45
+        while wds[0].last_report is None and time.monotonic() < deadline:
+            time.sleep(0.05)
+        wds[1].start()
         for t in threads:
             t.join(timeout=90)
         assert all(not t.is_alive() for t in threads), \

@@ -76,7 +76,7 @@ def test_activation_aggregation_one_message_per_rank():
 def test_failed_get_fails_pool_fast():
     """A permanently lost payload (GET against a never-registered handle)
     must FAIL the taskpool promptly on EVERY rank — wait() returns False
-    in seconds, not after the full timeout (ADVICE r2: the runtime knows
+    in seconds, not after the full timeout (the runtime knows
     the payload is gone; callers must not discover it via timeout).
     Rank 2 owns the home tile of the dead consumer's write-back (a
     pre-counted termdet runtime action) — without the abort broadcast it

@@ -6,7 +6,7 @@ transfers.  These tests pin the correctness contracts the design rests
 on:
 
 * the :class:`WritebackCommitter` unit surface against a stub device —
-  per-tile dedup, the drain watermark, ``wait_for``, and the STICKY
+  per-tile dedup, the drain watermark, the flush barrier, and the STICKY
   failure discipline (a dead committer fails enqueuers and ``flush``,
   it never hangs them);
 * ``detach()`` after async write-backs commits every dirty tile home
@@ -139,13 +139,17 @@ def test_committer_watermark_defers_below_window():
         com.close(flush=False)
 
 
-def test_committer_wait_for_drains_one_tile():
+def test_committer_flush_brings_one_tile_home():
+    """One tile, far below the watermark: ``flush`` (the barrier every
+    caller in the runtime takes) returns with it home and nothing held."""
     dev = _StubDev()
     com = WritebackCommitter(dev)
     try:
         d = _dirty("v", 5.0)
         com.enqueue(d)
-        assert com.wait_for(d.data_id, timeout=30.0)
+        assert com.holding([d.data_id]) == {d.data_id}
+        com.flush(timeout=30.0)
+        assert com.holding([d.data_id]) == set()
         np.testing.assert_allclose(np.asarray(d.get_copy(0).payload), 5.0)
     finally:
         com.close(flush=False)

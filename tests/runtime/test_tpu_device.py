@@ -396,7 +396,7 @@ def test_wave_batching_disabled_by_param():
 
 
 def test_wave_staging_is_per_chunk(ctx):
-    """ADVICE round-5 #1 pin: _submit_wave stages each pow2 chunk's
+    """_submit_wave stages each pow2 chunk's
     inputs immediately before THAT chunk's dispatch — never the whole
     wave up front — so peak HBM holds one chunk's inputs, not the
     wave's.  Observed through the chunk's staging walk + the native-path
