@@ -15,7 +15,7 @@ import numpy as np
 
 from parsec_tpu import Context
 from parsec_tpu.datadist import TiledMatrix
-from parsec_tpu.ops import run_lu, run_qr
+from parsec_tpu.ops import QRTree, run_lu, run_qr
 
 N, NB = 128, 32
 
@@ -31,6 +31,17 @@ def main() -> None:
         R = A.to_array()
         resid = np.abs(R.T @ R - A0.T @ A0).max() / np.abs(A0.T @ A0).max()
         print(f"qr: {A.mt}x{A.nt} tiles, A^T A vs R^T R rel residual {resid:.2e}")
+        assert resid < 1e-10
+
+        # a tall matrix over a reduction tree: TS domains of 4 tile rows
+        # under a binary TT tree (DPLASMA's hierarchical QR)
+        T0 = rng.standard_normal((16 * NB, 2 * NB))
+        T = TiledMatrix(16 * NB, 2 * NB, NB, NB, name="A",
+                        dtype=np.float64).from_array(T0)
+        run_qr(ctx, T, tree=QRTree(T.mt, T.nt, 4), use_tpu=False)
+        R = T.to_array()[:2 * NB]
+        resid = np.abs(R.T @ R - T0.T @ T0).max() / np.abs(T0.T @ T0).max()
+        print(f"hqr: {T.mt}x{T.nt} tiles, A^T A vs R^T R rel residual {resid:.2e}")
         assert resid < 1e-10
 
         # LU (no pivoting, diagonally dominant): L @ U reconstructs A

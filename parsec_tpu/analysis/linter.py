@@ -741,6 +741,7 @@ def verify_ptg(ptg: PTG, constants: Optional[Dict[str, Any]] = None, *,
     ignored = {ignore} if isinstance(ignore, str) else set(ignore)
     known_names = set(_SAFE_BUILTINS) | set(known)
     if constants is not None:
+        constants = ptg.globals_of(constants)  # (and the defaults)
         known_names |= set(constants)
     # the ignore filter applies BEFORE the static-error gate: suppressing
     # a static code must not silently disable the instance checks (an

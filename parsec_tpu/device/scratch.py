@@ -77,6 +77,11 @@ def release(data: Data) -> bool:
         return data.scratch == 0
 
 
+def nbytes(data: Data) -> int:
+    """The bytes of one copy of the tile."""
+    return int(np.prod(data.shape)) * data.dtype.itemsize
+
+
 def host_zeros(data: Data) -> np.ndarray:
     """Birth on the host: the zeroed array a CPU body gets for a scratch
     tile nobody has written, attached as the host copy."""

@@ -163,9 +163,12 @@ class TpuDevice(Device):
         #: scratch tiles (device/scratch.py): first written / dropped
         #: with their last user on this device, and the bytes of them
         #: that crossed the host after all (0 unless one was evicted or
-        #: a CPU body wrote it)
+        #: a CPU body wrote it); the most bytes of them alive at once
+        #: (born here and not yet freed: what a DAG's width costs)
         self.stats.update(scratch_tiles_born=0, scratch_tiles_freed=0,
-                          scratch_bytes_in=0, scratch_bytes_out=0)
+                          scratch_bytes_in=0, scratch_bytes_out=0,
+                          scratch_bytes_peak=0)
+        self._scratch_live = 0
         #: who committed the outputs: chunks by the wave epilog, tasks
         #: one by one (together: ``executed_tasks``); and the pump's
         #: batches whose tiles were all resident, with nothing in them
@@ -1627,6 +1630,10 @@ class TpuDevice(Device):
         idx = self.data_index
         if data.scratch is not None and scratch.unborn(data):
             self.stats["scratch_tiles_born"] += 1
+            if data.scratch != scratch.KEPT:  # (a kept tile never dies)
+                self._scratch_live += scratch.nbytes(data)
+                if self._scratch_live > self.stats["scratch_bytes_peak"]:
+                    self.stats["scratch_bytes_peak"] = self._scratch_live
         c = data.get_copy(idx)
         if c is None:
             c = data.attach_copy(idx, arr)
@@ -1648,6 +1655,7 @@ class TpuDevice(Device):
             if scratch.release(data):
                 self._res.release(data)
                 self.stats["scratch_tiles_freed"] += 1
+                self._scratch_live -= scratch.nbytes(data)
 
     def _send_home(self, going: List[Data], last: bool) -> int:
         """Hand just-committed outputs to the async committer OUTSIDE

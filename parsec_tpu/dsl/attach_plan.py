@@ -155,45 +155,32 @@ _AST_OK = (ast.Expression, ast.BoolOp, ast.BinOp, ast.UnaryOp, ast.Compare,
 
 
 @functools.lru_cache(maxsize=4096)
-def _expr_ok(src: str) -> bool:
-    """The expression is arithmetic over names: no attribute, no call
-    but the evaluator's own builtins (an inline call may read anything)."""
+def _expr_asks(src: str) -> Optional[frozenset]:
+    """The names whose methods the expression calls (``TREE.nextpiv(k,
+    p, m)``: ``{"TREE"}``), when it is otherwise arithmetic over names:
+    no other attribute, no other call but the evaluator's own builtins
+    (an inline call may read anything).  None: nothing vouches for it.
+    An object asked this way has to say what its answers are a function
+    of (:func:`_const_fp`: ``plan_fingerprint``)."""
     try:
         tree = ast.parse(_c_to_py(src).strip(), mode="eval")
     except SyntaxError:
-        return False
-    for n in ast.walk(tree):
-        if not isinstance(n, _AST_OK):
-            return False
-        if isinstance(n, ast.Call) and not (
-                isinstance(n.func, ast.Name) and n.func.id in _SAFE_BUILTINS
-                and not n.keywords):
-            return False
-    return True
-
-
-def _src_fp(e) -> Optional[str]:
-    """An ``_Expr``'s text, once :func:`_expr_ok` has vouched for it."""
-    if e is None:
         return None
-    if not _expr_ok(e.src):
-        raise Uncacheable(f"expression {e.src!r}")
-    return e.src
-
-
-def _arg_fp(a: _ArgExpr) -> Tuple:
-    return (_src_fp(a.lo), _src_fp(a.hi), _src_fp(a.step))
-
-
-def _dep_fp(dep) -> str:
-    if not dep.src:
-        raise Uncacheable("a dependency without its source text")
-    _src_fp(dep.guard)
-    for t in (dep.then, dep.otherwise):
-        if isinstance(t, (_TaskRef, _DataRef)):
-            for a in t.args:
-                _arg_fp(a)
-    return dep.src
+    asked, methods = set(), set()
+    for n in ast.walk(tree):
+        if isinstance(n, ast.Call):
+            f = n.func
+            if n.keywords:
+                return None
+            if isinstance(f, ast.Attribute) and isinstance(f.value, ast.Name):
+                asked.add(f.value.id)
+                methods.add(id(f))
+            elif not (isinstance(f, ast.Name) and f.id in _SAFE_BUILTINS):
+                return None
+    for n in ast.walk(tree):
+        if not isinstance(n, _AST_OK) and id(n) not in methods:
+            return None
+    return frozenset(asked)
 
 
 def _body_fp(fn) -> Tuple:
@@ -206,27 +193,53 @@ def _body_fp(fn) -> Tuple:
         raise Uncacheable(f"body {fn!r}: {type(e).__name__}") from None
 
 
-def _ptg_fp(ptg: PTG) -> Tuple:
+def _ptg_fp(ptg: PTG) -> Tuple[Tuple, set]:
+    """The definition's text, every expression vouched for by
+    :func:`_expr_asks`, and the names of the objects they ask."""
+    asked: set = set()
+
+    def src(e) -> Optional[str]:
+        if e is None:
+            return None
+        names = _expr_asks(e.src)
+        if names is None:
+            raise Uncacheable(f"expression {e.src!r}")
+        asked.update(names)
+        return e.src
+
+    def arg(a: _ArgExpr) -> Tuple:
+        return (src(a.lo), src(a.hi), src(a.step))
+
+    def dep(d) -> str:
+        if not d.src:
+            raise Uncacheable("a dependency without its source text")
+        src(d.guard)
+        for t in (d.then, d.otherwise):
+            if isinstance(t, (_TaskRef, _DataRef)):
+                for a in t.args:
+                    arg(a)
+        return d.src
+
     out: List[Tuple] = [("ptg", ptg.name)]
     for pc in ptg.classes.values():
         aff = pc._affinity
         out.append((
             pc.name,
-            tuple((n, _arg_fp(e), p) for n, e, p in pc.decls),
+            tuple((n, arg(e), p) for n, e, p in pc.decls),
             tuple((f.name, int(f.mode),
-                   tuple(_dep_fp(d) for d in f.deps_in),
-                   tuple(_dep_fp(d) for d in f.deps_out))
+                   tuple(dep(d) for d in f.deps_in),
+                   tuple(dep(d) for d in f.deps_out))
                   for f in pc.flows),
-            _src_fp(pc._priority),
+            src(pc._priority),
             None if aff is None else (
-                aff.collection_name, tuple(_arg_fp(a) for a in aff.args)),
+                aff.collection_name, tuple(arg(a) for a in aff.args)),
             tuple(sorted((dt, _body_fp(fn))
                          for dt, fn in pc.bodies.items())),
             tuple(pc.body_globals),
             tuple(sorted(pc.stage_hooks)), tuple(sorted(pc.chore_evaluate)),
             tuple(sorted((str(k), _const_fp(k, v))
                          for k, v in pc.properties.items()))))
-    return tuple(out)
+    return tuple(out), asked
 
 
 _PLAIN = (int, float, complex, str, bytes, bool, type(None))
@@ -269,7 +282,12 @@ def _const_fp(name, v) -> Tuple:
         return ("type", v.__module__, v.__qualname__)
     fp = _collection_fp(v)
     if fp is None:
-        raise Uncacheable(f"constant {name!r} ({t.__name__})")
+        # an object the expressions ask (a reduction tree) vouches for
+        # itself: everything its answers depend on
+        own = getattr(t, "plan_fingerprint", None)
+        if own is None:
+            raise Uncacheable(f"constant {name!r} ({t.__name__})")
+        fp = ("asks", t.__module__, t.__qualname__) + tuple(own(v))
     return fp
 
 
@@ -279,7 +297,13 @@ def plan_key(tp, ranks: Iterable[int], fusion: Tuple,
     resolved graph of ``tp`` is a function of (``window``: the pump's
     batches, which rank the tasks: :func:`build_plan`); raises
     :class:`Uncacheable` where it cannot vouch for some part."""
-    return ("attach-plan-3", _ptg_fp(tp.ptg),
+    ptg_fp, asked = _ptg_fp(tp.ptg)
+    for name in sorted(asked):
+        v = tp.constants.get(name)
+        if getattr(type(v), "plan_fingerprint", None) is None:
+            raise Uncacheable(f"a method of {name!r} "
+                              f"({type(v).__name__}) is called")
+    return ("attach-plan-3", ptg_fp,
             tuple(sorted((str(k), _const_fp(k, v))
                          for k, v in tp.constants.items())),
             tuple(sorted(ranks)), fusion, tuple(window))

@@ -30,7 +30,16 @@ Dependency syntax (reference JDF dependency grammar, ``parsec.y``):
   output deps broadcast to many successors;
   a trailing ``[key=value ...]`` property block is accepted (JDF parity)
   and stashed on the dep;
-  expressions are Python, evaluated over task params + taskpool constants.
+  expressions are Python, evaluated over task params + taskpool constants;
+  an argument may CALL a constant's method — ``C1 tsmqr(k,
+  TREE.getikill(k, nextp), n)``: arguments split at depth 0 only — and a
+  parameter may run over an irregular set through a definition (``i = 0
+  .. TREE.getnbgeqrf(k)-1`` then ``m = TREE.getm(k, i)``), as the
+  reference's ``inline_c`` calls into a ``dplasma_qrtree_t`` do
+  (``ops/qr.py``).  An object called this way says what its answers are
+  a function of (``plan_fingerprint()``), or the pool gets no attach plan;
+  a global may have a default worked out from the others
+  (:meth:`PTG.default`: JDF's ``[hidden = on default = ...]``).
 
 Execution model (mirrors SURVEY.md §3.2/§3.3):
 * startup: enumerate the parameter space, schedule every task whose active
@@ -656,7 +665,28 @@ class PTG:
         #: dynamic-hash-table vs index-array, ``ptg-compiler/main.c:37``)
         self.dep_storage = dep_storage
         self.constants: Dict[str, Any] = dict(constants)
+        #: name -> fn(constants): a global nobody has to give (reference
+        #: JDF ``NAME [hidden = on default = "descA->mt"]``), worked out
+        #: from the others, in declaration order, when a taskpool is made
+        self.defaults: Dict[str, Callable[[Dict[str, Any]], Any]] = {}
         self.classes: Dict[str, PTGTaskClass] = {}
+
+    def default(self, name: str,
+                fn: Callable[[Dict[str, Any]], Any]) -> "PTG":
+        """Declare the global ``name`` optional: ``fn(constants)`` gives
+        its value where ``taskpool()`` (or ``verify()``) was given none."""
+        self.defaults[name] = fn
+        return self
+
+    def globals_of(self, given: Dict[str, Any]) -> Dict[str, Any]:
+        """The globals a taskpool made with ``given`` has: the
+        definition's own, ``given`` over them, the defaults of the rest."""
+        merged = dict(self.constants)
+        merged.update(given)
+        for name, fn in self.defaults.items():
+            if name not in merged:
+                merged[name] = fn(merged)
+        return merged
 
     def task_class(self, name: str, **params: str) -> PTGTaskClass:
         c = PTGTaskClass(self, name, params)
@@ -665,9 +695,7 @@ class PTG:
 
     def taskpool(self, termdet: Optional[str] = None,
                  **constants: Any) -> "PTGTaskpool":
-        merged = dict(self.constants)
-        merged.update(constants)
-        return PTGTaskpool(self, merged, termdet=termdet)
+        return PTGTaskpool(self, self.globals_of(constants), termdet=termdet)
 
     def verify(self, globals_: Optional[Dict[str, Any]] = None, *,
                level: str = "full", ignore: Sequence[str] = (),
@@ -717,10 +745,7 @@ class PTG:
             kw["known"] = known
         if collections is not None:
             kw["collections"] = collections
-        merged = dict(self.constants)
-        merged.update(globals_ or {})
-        merged.update(more)
-        return verify_ptg(self, merged, **kw)
+        return verify_ptg(self, {**(globals_ or {}), **more}, **kw)
 
 
 # ---------------------------------------------------------------------------

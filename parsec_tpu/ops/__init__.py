@@ -18,6 +18,7 @@ from .segmented_chol import SegmentedCholesky, segmented_cholesky_ptg
 from .segmented_lu import SegmentedLU, segmented_lu_ptg
 from .segmented_qr import SegmentedQR, segmented_qr_ptg
 from .qr import qr_ptg, run_qr
+from .qr_tree import QRTree, flat_tree
 
 __all__ = ["tiles", "cholesky_ptg", "cholesky_dtd", "run_cholesky", "lu_ptg", "run_lu",
            "flash_attention_ptg", "ring_attention_ptg",
@@ -28,4 +29,4 @@ __all__ = ["tiles", "cholesky_ptg", "cholesky_dtd", "run_cholesky", "lu_ptg", "r
            "SegmentedCholesky", "segmented_cholesky_ptg",
            "SegmentedLU", "segmented_lu_ptg",
            "SegmentedQR", "segmented_qr_ptg",
-           "qr_ptg", "run_qr"]
+           "qr_ptg", "run_qr", "QRTree", "flat_tree"]

@@ -1,24 +1,36 @@
 """Tiled Householder QR factorization as a PTG — the second flagship.
 
 The reference ecosystem's dense-QR lives in DPLASMA (like dpotrf, not in
-the PaRSEC repo itself — SURVEY.md §6); this is the classic PLASMA-style
-tiled QR task graph, re-derived TPU-first:
+the PaRSEC repo itself — SURVEY.md §6); this is its tile QR over a
+reduction TREE (``dplasma_dgeqrf_param`` over a ``dplasma_qrtree_t``:
+:mod:`.qr_tree`), re-derived TPU-first.  In panel k every row m >= k is
+either a domain head (it gets a ``geqrt``) or is killed as a square by
+its head (TS); the heads but row k are then killed as triangles by other
+heads (TT), row k last:
 
-  for k:  geqrt(k):       A[k,k]          -> Q_k, R_kk
-          unmqr(k, n):    A[k,n]          <- Q_k^T A[k,n]        (n > k)
-          tsqrt(k, m):    [R_kk; A[m,k]]  -> Q_km, R_kk'         (m > k)
-          tsmqr(k, m, n): [A[k,n]; A[m,n]] <- Q_km^T [ . ; . ]   (m,n > k)
+  geqrt(k, m):    A[m,k]              -> Q, R_m              (m a head)
+  unmqr(k, m, n): A[m,n]              <- Q^T A[m,n]          (n > k)
+  tsqrt(k, m):    [R_p; A[m,k]]       -> Q, R_p'   (p = currpiv(k, m))
+  tsmqr(k, m, n): [A[p,n]; A[m,n]]    <- Q^T [ . ; . ]       (n > k)
+  ttqrt(k, m):    [R_p; R_m]          -> Q, R_p'             (m a head)
+  ttmqr(k, m, n): [A[p,n]; A[m,n]]    <- Q^T [ . ; . ]       (n > k)
+
+A pivot's kills follow one another (``nextpiv`` / ``prevpiv``), TS before
+TT; row (m, n)'s last update of panel k feeds its first task of panel
+k+1.  With the flat tree (one domain: row k kills k+1 .. MT-1 in order)
+this is the classic PLASMA tile QR, to the task.  The matrix may be tall:
+MT >= NT tile rows and columns.
 
 Representation choice (TPU-first): instead of the LAPACK compact-WY
 (V, T) storage the reference consumers use, the orthogonal factors are
 materialised as small dense Q blocks passed along NEW flows — every
 update becomes a plain MXU matmul, which is the fast shape on this
-hardware; the cost is extra FLOPs in tsqrt (complete QR of a 2nb x nb
-stack) amortised across the row's tsmqr updates.
+hardware; the cost is extra FLOPs in tsqrt / ttqrt (complete QR of a
+2nb x nb stack) amortised across the row's updates.
 
-The factorization leaves R in the upper triangle of A (below-diagonal
-tiles zeroed). Orthogonality is implicit; the invariant A^T A = R^T R
-verifies the result without tracking Q (tests).
+The factorization leaves R in the upper triangle of A's first NT tile
+rows (every other tile zeroed).  Orthogonality is implicit; the invariant
+A^T A = R^T R verifies the result without tracking Q (tests).
 """
 
 from __future__ import annotations
@@ -27,6 +39,7 @@ import numpy as np
 
 from ..core.lifecycle import AccessMode
 from ..dsl.ptg import PTG
+from .qr_tree import QRTree, flat_tree
 
 IN = AccessMode.IN
 INOUT = AccessMode.INOUT
@@ -87,6 +100,19 @@ def tsmqr_tpu(Q, C1, C2, **_):
     return s[:nb], s[nb:]
 
 
+# The TT kill is the TS kill on two triangles (the dense-Q representation
+# takes no advantage of the second triangle's zeros); ``ttmqr`` runs the
+# ``tsmqr`` bodies as they are.
+
+def ttqrt_cpu(R, B, Q, **_):
+    tsqrt_cpu(R, np.triu(B), Q)
+    B[:] = 0.0
+
+
+def ttqrt_tpu(R, B, Q, **_):
+    return tsqrt_tpu(R, jnp.triu(B), Q)
+
+
 def _dot_bf16(a, b):
     """bf16 operands, f32 accumulation: one MXU pass."""
     return jnp.dot(a.astype(jnp.bfloat16), b.astype(jnp.bfloat16),
@@ -119,22 +145,81 @@ def tsmqr_pallas(Q, C1, C2, **_):
 
 # -- the PTG -----------------------------------------------------------------
 
-def qr_ptg(*, use_tpu: bool = True, use_cpu: bool = True,
-           use_pallas: bool = False, bf16_updates: bool = False) -> PTG:
-    """Build the tiled-QR PTG. Instantiate with ``.taskpool(NT=A.mt, A=A,
-    TILE_SHAPE=(nb, nb), TILE_DTYPE=..., QSHAPE2=(dtype, (2*nb, 2*nb)))``
-    — the NEW-flow Q blocks are allocated from ``TILE_SHAPE`` except
-    tsqrt's, whose ``[type=QSHAPE2]`` dep property resolves the (2nb, 2nb)
-    stacked-Q shape through the constants (device chores are functional
-    and ignore the scratch; the shapes matter for the in-place CPU path).
-    :func:`run_qr` fills these in.
+def _kill_of(arrow, cond, flow, stem, k, row, tail=""):
+    """The task that kills ``row`` in panel ``k`` (``ts<stem>`` or
+    ``tt<stem>`` by the row's type): one guarded dependency for each."""
+    ref = f"({k}, TREE.getikill({k}, {row}){tail})"
+    return (f"{arrow} ({cond} and TREE.gettype({k}, {row}) == 0) "
+            f"? {flow} ts{stem}{ref}",
+            f"{arrow} ({cond} and TREE.gettype({k}, {row}) != 0) "
+            f"? {flow} tt{stem}{ref}")
 
-    ``bf16_updates`` runs the unmqr/tsmqr updates with bf16 operands and
-    f32 accumulation (one MXU pass for the six of ``highest``): the
-    lower-precision path the benchmark's check is held against.
 
-    Square tile grids with uniform tiles (N divisible by nb)."""
+def _from_panel_before(n):
+    """Row m's tile of column ``n`` as panel k-1 left it: its kill's
+    update there was its last."""
+    return (f"<- (k == 0) ? A(m, {n})",
+            *_kill_of("<-", "k > 0", "C2", "mqr", "k-1", "m", f", {n}"))
+
+
+def _pivot_from(flow, first, stem, prev, row, tail=""):
+    """A killer's tile as its kill before this one left it; before its
+    first, as its ``geqrt`` / ``unmqr`` (``first``) did."""
+    return (f"<- ({prev} == MT) ? {first}(k, TREE.geti(k, {row}){tail})",
+            *_kill_of("<-", f"{prev} != MT", flow, stem, "k", prev, tail))
+
+
+def _pivot_to(flow, victim, stem, nxt, row, home, tail=""):
+    """... and where it goes: to the killer's next kill; after its last,
+    home if it is the panel's root, else into its own kill."""
+    return (*_kill_of("->", f"{nxt} != MT", flow, stem, "k", nxt, tail),
+            f"-> ({nxt} == MT and {row} == k) ? {home}",
+            f"-> ({nxt} == MT and {row} != k) "
+            f"? {victim} tt{stem}(k, TREE.getikill(k, {row}){tail})")
+
+
+#: row m's tile of column n goes on to panel k+1, where the row is a head
+#: or a TS victim (a TT victim is a head first)
+_TO_NEXT_PANEL = (
+    "-> (n == k+1 and TREE.gettype(k+1, m) != 0) "
+    "? T geqrt(k+1, TREE.geti(k+1, m))",
+    "-> (n > k+1 and TREE.gettype(k+1, m) != 0) "
+    "? C unmqr(k+1, TREE.geti(k+1, m), n)",
+    "-> (n == k+1 and TREE.gettype(k+1, m) == 0) "
+    "? B tsqrt(k+1, TREE.getikill(k+1, m))",
+    "-> (n > k+1 and TREE.gettype(k+1, m) == 0) "
+    "? C2 tsmqr(k+1, TREE.getikill(k+1, m), n)",
+    "-> A(m, n)")
+
+
+def qr_ptg(tree: QRTree = None, *, use_tpu: bool = True,
+           use_cpu: bool = True, use_pallas: bool = False,
+           bf16_updates: bool = False) -> PTG:
+    """Build the tile-QR PTG over ``tree`` (:class:`.qr_tree.QRTree`;
+    None: the flat tree of the taskpool's grid, the classic tile QR).
+    Instantiate with ``.taskpool(NT=A.nt, A=A, TILE_SHAPE=(nb, nb),
+    TILE_DTYPE=..., QSHAPE2=(dtype, (2*nb, 2*nb)))`` — ``MT`` (tile rows,
+    and the tree's "none") and ``TREE`` default to ``A.mt`` and the
+    tree.  The NEW-flow Q blocks are allocated from ``TILE_SHAPE`` except
+    tsqrt's and ttqrt's, whose ``[type=QSHAPE2]`` dep property resolves
+    the (2nb, 2nb) stacked-Q shape through the constants (device chores
+    are functional and ignore the scratch; the shapes matter for the
+    in-place CPU path).  :func:`run_qr` fills these in.
+
+    A kill task is ``(k, i)``: the ``i``-th TS (or TT) kill of panel k in
+    row order, ``m = TREE.getmkill(k, tt, i)`` its row; a ``geqrt`` is
+    the ``i``-th head's, ``m = TREE.getm(k, i)``, as in DPLASMA's JDF.
+
+    ``bf16_updates`` runs the unmqr/tsmqr/ttmqr updates with bf16
+    operands and f32 accumulation (one MXU pass for the six of
+    ``highest``): the lower-precision path the benchmark's check is held
+    against.
+
+    MT >= NT tile rows and columns of uniform square tiles."""
     ptg = PTG("geqrf")
+    ptg.default("MT", lambda c: c["A"].mt)
+    ptg.default("TREE", lambda c: tree if tree is not None
+                else flat_tree(c["MT"], c["NT"]))
     unmqr_dev, tsmqr_dev = (
         (unmqr_bf16, tsmqr_bf16) if bf16_updates
         else (unmqr_pallas, tsmqr_pallas) if use_pallas
@@ -148,69 +233,107 @@ def qr_ptg(*, use_tpu: bool = True, use_cpu: bool = True,
             kw["tpu"] = tpu
         return kw
 
-    geqrt = ptg.task_class("geqrt", k="0 .. NT-1")
-    geqrt.affinity("A(k, k)")
+    heads = "0 .. TREE.getnbgeqrf(k)-1"
+
+    geqrt = ptg.task_class("geqrt", k="0 .. NT-1", i=heads)
+    geqrt.define("m", "TREE.getm(k, i)")
+    geqrt.define("nextm", "TREE.nextpiv(k, m, MT)")
+    geqrt.affinity("A(m, k)")
     geqrt.priority("(NT - k) * 1000")
     geqrt.flow("T", INOUT,
-               "<- (k == 0) ? A(k, k) : C2 tsmqr(k-1, k, k)",
-               "-> (k < NT-1) ? R tsqrt(k, k+1)",
-               "-> (k == NT-1) ? A(k, k)")
+               *_from_panel_before("k"),
+               *_pivot_to("R", "B", "qrt", "nextm", "m", "A(k, k)"))
     geqrt.flow("Q", INOUT,
                "<- NEW",
-               "-> Q unmqr(k, k+1 .. NT-1)")
+               "-> Q unmqr(k, i, k+1 .. NT-1)")
     geqrt.body(**bodies(geqrt_cpu, geqrt_tpu))
 
-    tsqrt = ptg.task_class("tsqrt", k="0 .. NT-2", m="k+1 .. NT-1")
-    tsqrt.affinity("A(m, k)")
-    tsqrt.priority("(NT - m) * 100 + 500")
-    tsqrt.flow("R", INOUT,
-               "<- (m == k+1) ? T geqrt(k) : R tsqrt(k, m-1)",
-               "-> (m < NT-1) ? R tsqrt(k, m+1) : A(k, k)")
-    tsqrt.flow("B", INOUT,
-               "<- (k == 0) ? A(m, k) : C2 tsmqr(k-1, m, k)",
-               "-> A(m, k)")
-    tsqrt.flow("Q", INOUT,
-               "<- NEW [type=QSHAPE2]",  # (2nb, 2nb): taskpool constant
-               "-> Q tsmqr(k, m, k+1 .. NT-1)")
-    tsqrt.body(**bodies(tsqrt_cpu, tsqrt_tpu))
+    def kill_class(name, tt, rows):
+        c = ptg.task_class(name, k=rows, i=f"0 .. TREE.getnbkill(k, {tt})-1")
+        c.define("m", f"TREE.getmkill(k, {tt}, i)")
+        c.define("p", "TREE.currpiv(k, m)")
+        c.define("prevp", "TREE.prevpiv(k, p, m)")
+        c.define("nextp", "TREE.nextpiv(k, p, m)")
+        if tt:
+            c.define("prevm", "TREE.prevpiv(k, m, m)")
+        return c
 
-    unmqr = ptg.task_class("unmqr", k="0 .. NT-2", n="k+1 .. NT-1")
-    unmqr.affinity("A(k, n)")
+    def qrt_class(tt, cpu, tpu):
+        """``tsqrt`` (tt = 0: the victim is a square, as the panel before
+        left it) or ``ttqrt`` (1: a head's triangle, as its own kills
+        left it)."""
+        ts = "tt" if tt else "ts"
+        c = kill_class(f"{ts}qrt", tt, "0 .. NT-1")
+        c.affinity("A(m, k)")
+        c.priority("(NT - k - TREE.level(k, m)) * 100 + 500")
+        c.flow("R", INOUT,
+               *_pivot_from("R", "T geqrt", "qrt", "prevp", "p"),
+               *_pivot_to("R", "B", "qrt", "nextp", "p", "A(k, k)"))
+        c.flow("B", INOUT,
+               *(_pivot_from("R", "T geqrt", "qrt", "prevm", "m") if tt
+                 else _from_panel_before("k")),
+               "-> A(m, k)")
+        c.flow("Q", INOUT,
+               "<- NEW [type=QSHAPE2]",  # (2nb, 2nb): taskpool constant
+               f"-> Q {ts}mqr(k, i, k+1 .. NT-1)")
+        c.body(**bodies(cpu, tpu))
+
+    def mqr_class(tt):
+        """``tsmqr`` / ``ttmqr``: the kill's update of a trailing column."""
+        ts = "tt" if tt else "ts"
+        c = kill_class(f"{ts}mqr", tt, "0 .. NT-2").param("n", "k+1 .. NT-1")
+        c.affinity("A(m, n)")
+        c.priority("(NT - k - TREE.level(k, m)) * 10")
+        c.flow("Q", IN, f"<- Q {ts}qrt(k, i)")
+        c.flow("C1", INOUT,
+               *_pivot_from("C1", "C unmqr", "mqr", "prevp", "p", ", n"),
+               *_pivot_to("C1", "C2", "mqr", "nextp", "p", "A(k, n)",
+                          ", n"))
+        c.flow("C2", INOUT,
+               *(_pivot_from("C1", "C unmqr", "mqr", "prevm", "m", ", n")
+                 if tt else _from_panel_before("n")),
+               *_TO_NEXT_PANEL)
+        c.body(**bodies(tsmqr_cpu, tsmqr_dev))
+
+    # (declared in the order of the classic tile QR's four classes: the
+    # flat tree's attach plan is that PTG's, to the byte)
+    qrt_class(0, tsqrt_cpu, tsqrt_tpu)
+
+    unmqr = ptg.task_class("unmqr", k="0 .. NT-2", i=heads, n="k+1 .. NT-1")
+    unmqr.define("m", "TREE.getm(k, i)")
+    unmqr.define("nextm", "TREE.nextpiv(k, m, MT)")
+    unmqr.affinity("A(m, n)")
     unmqr.priority("(NT - n) * 100 + 400")
-    unmqr.flow("Q", IN, "<- Q geqrt(k)")
+    unmqr.flow("Q", IN, "<- Q geqrt(k, i)")
     unmqr.flow("C", INOUT,
-               "<- (k == 0) ? A(k, n) : C2 tsmqr(k-1, k, n)",
-               "-> C1 tsmqr(k, k+1, n)")
+               *_from_panel_before("n"),
+               *_pivot_to("C1", "C2", "mqr", "nextm", "m", "A(k, n)",
+                          ", n"))
     unmqr.body(**bodies(unmqr_cpu, unmqr_dev))
 
-    tsmqr = ptg.task_class("tsmqr", k="0 .. NT-2", m="k+1 .. NT-1", n="k+1 .. NT-1")
-    tsmqr.affinity("A(m, n)")
-    tsmqr.priority("(NT - m) * 10")
-    tsmqr.flow("Q", IN, "<- Q tsqrt(k, m)")
-    tsmqr.flow("C1", INOUT,
-               "<- (m == k+1) ? C unmqr(k, n) : C1 tsmqr(k, m-1, n)",
-               "-> (m < NT-1) ? C1 tsmqr(k, m+1, n) : A(k, n)")
-    tsmqr.flow("C2", INOUT,
-               "<- (k == 0) ? A(m, n) : C2 tsmqr(k-1, m, n)",
-               "-> (m == k+1 and n == k+1) ? T geqrt(k+1)",
-               "-> (m == k+1 and n > k+1) ? C unmqr(k+1, n)",
-               "-> (m > k+1 and n == k+1) ? B tsqrt(k+1, m)",
-               "-> (m > k+1 and n > k+1) ? C2 tsmqr(k+1, m, n)",
-               "-> A(m, n)")
-    tsmqr.body(**bodies(tsmqr_cpu, tsmqr_dev))
+    mqr_class(0)
+    qrt_class(1, ttqrt_cpu, ttqrt_tpu)
+    mqr_class(1)
 
     return ptg
 
 
-def run_qr(context, A, *, use_tpu: bool = True, use_cpu: bool = True) -> None:
-    """Factorize TiledMatrix ``A`` in place: A := R (upper), zeros below."""
-    if A.m != A.n or A.mb != A.nb or A.m % A.mb != 0:
+def run_qr(context, A, *, tree: QRTree = None, use_tpu: bool = True,
+           use_cpu: bool = True) -> None:
+    """Factorize TiledMatrix ``A`` (M x N, M >= N) in place: A := R in
+    the upper triangle of its first N rows, zeros everywhere else.
+    ``tree``: the reduction tree of its ``A.mt x A.nt`` grid (None: the
+    flat tree)."""
+    if A.m < A.n or A.mb != A.nb or A.m % A.mb or A.n % A.nb:
         raise ValueError(
-            f"tiled QR needs a square matrix with uniform square tiles "
-            f"(N divisible by nb); got {A.m}x{A.n}, tiles {A.mb}x{A.nb}")
+            f"tiled QR needs M >= N and uniform square tiles (M and N "
+            f"divisible by nb); got {A.m}x{A.n}, tiles {A.mb}x{A.nb}")
+    if tree is not None and (tree.mt, tree.nt) != (A.mt, A.nt):
+        raise ValueError(f"{tree!r} is not a tree of a {A.mt} x {A.nt} "
+                         f"grid of tiles")
     nb = A.mb
-    tp = qr_ptg(use_tpu=use_tpu, use_cpu=use_cpu).taskpool(
-        NT=A.mt, A=A, TILE_SHAPE=(nb, nb), TILE_DTYPE=A.default_dtype,
+    tp = qr_ptg(tree, use_tpu=use_tpu, use_cpu=use_cpu).taskpool(
+        NT=A.nt, A=A, TILE_SHAPE=(nb, nb), TILE_DTYPE=A.default_dtype,
         QSHAPE2=(A.default_dtype, (2 * nb, 2 * nb)))
     context.add_taskpool(tp)
     ok = tp.wait(timeout=None)

@@ -59,7 +59,7 @@ _PBT_TOKEN_SEQ = itertools.count(1)
 #: recorder and the merged timeline by a line here, not by a subscriber.
 SPAN_KEYWORDS = {
     "attach:build": "tasks", "attach:partition": None,
-    "attach:plan": None, "attach:bind": None,
+    "attach:plan": None, "attach:bind": None, "attach:tree": None,
     "pump:pop": "n", "pump:stage_wait": "n", "pump:land": "n",
     "pump:retire": "n", "pump:done": "n", "pump:events": None,
     "dev:submit_batch": "n", "dev:wave": "n", "dev:submit_one": "n",

@@ -106,14 +106,21 @@ def test_qr_via_dtd_replay():
     _check_r(A0, A.to_array(), rtol=1e-9)
 
 
-def test_qr_rejects_ragged_or_rectangular():
+def test_qr_rejects_ragged_or_wide():
+    from parsec_tpu.ops.qr_tree import QRTree
+
     with Context(nb_cores=1) as ctx:
         bad = TiledMatrix(112, 112, 32, 32, name="A", dtype=np.float64)
-        with pytest.raises(ValueError, match="square matrix with uniform"):
+        with pytest.raises(ValueError, match="M >= N and uniform square"):
             run_qr(ctx, bad, use_tpu=False)
-        rect = TiledMatrix(64, 96, 32, 32, name="A", dtype=np.float64)
-        with pytest.raises(ValueError, match="square matrix with uniform"):
-            run_qr(ctx, rect, use_tpu=False)
+        wide = TiledMatrix(64, 96, 32, 32, name="A", dtype=np.float64)
+        with pytest.raises(ValueError, match="M >= N and uniform square"):
+            run_qr(ctx, wide, use_tpu=False)
+        # (tall is accepted: tests/collections/test_qr_tree.py); a tree
+        # of another grid is not
+        tall = TiledMatrix(96, 64, 32, 32, name="A", dtype=np.float64)
+        with pytest.raises(ValueError, match="not a tree of a 3 x 2"):
+            run_qr(ctx, tall, tree=QRTree(4, 2, 2), use_tpu=False)
 
 
 def test_new_tile_spec_guarded_otherwise_branch():

@@ -52,6 +52,7 @@ bench_testlib.TINY.setdefault("pump_ooc", "tiny_pump_ooc")
 bench_testlib.TINY.setdefault("pump_stencil", "tiny_pump_stencil")
 bench_testlib.TINY.setdefault("pump_mle", "tiny_pump_mle")
 bench_testlib.TINY.setdefault("dtd", "tiny_dtd")
+bench_testlib.TINY.setdefault("pump_geqrf_hqr", "tiny_pump_geqrf_hqr")
 
 
 def pytest_configure(config):
