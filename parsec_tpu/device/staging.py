@@ -54,6 +54,7 @@ from __future__ import annotations
 
 import collections
 import itertools
+import mmap
 import threading
 import time
 from typing import Any, Deque, Dict, List, Optional, Tuple
@@ -471,6 +472,23 @@ class StageIn:
             return arr
 
 
+def _fresh_zeros(shape, dtype) -> np.ndarray:
+    """A writable array of zeros over a private anonymous mapping of its
+    own: the kernel hands out zero pages when they are first touched, so
+    making it touches none (3 us a 1 MiB tile).  ``np.zeros`` does that
+    only while the allocator maps a block of this size afresh; once
+    blocks of the size have been freed it recycles heap memory and
+    clears it, 80-260 us a MiB on the thread that asks (my chip run,
+    PR 44: ``dev:epilog`` paid 265 us a landed tile).  PRIVATE: Python's
+    default for an anonymous map is shared memory, whose first read
+    allocates every page (10 s for 4 GiB where this takes 1)."""
+    nbytes = int(np.prod(shape)) * np.dtype(dtype).itemsize
+    if not nbytes:
+        return np.zeros(shape, dtype)
+    block = mmap.mmap(-1, nbytes, flags=mmap.MAP_PRIVATE | mmap.MAP_ANONYMOUS)
+    return np.frombuffer(block, dtype).reshape(shape)
+
+
 def _start_copy(payload) -> bool:
     """Start the device->host copy of ``payload`` without waiting for it
     (``jax.Array.copy_to_host_async``: non-blocking, ordered behind the
@@ -515,6 +533,9 @@ class HostWriter:
         #: copy — the second matrix on the host that an out-of-core solve
         #: has no room for (0 on a healthy run; warned once)
         stats.setdefault("wb_alias_fallbacks", 0)
+        #: home tiles landed as zeros on a body's word, no copy from the
+        #: device (:meth:`land_zeros`)
+        stats.setdefault("wb_zeros_landed", 0)
         self._warned = False
 
     def _fell_back(self, why: str, *args) -> None:
@@ -615,6 +636,24 @@ class HostWriter:
         if data.scratch is not None:  # spilled by an eviction
             self.stats["scratch_bytes_out"] += host.nbytes
         return True
+
+    def land_zeros(self, datas) -> None:
+        """The dirty device copies of ``datas`` are exact zeros by their
+        body's own word (``_zeros``) and the last versions of their
+        tiles: the home tiles become zeros on the calling thread, under
+        the version guard every landing passes (:meth:`snapshot`,
+        :meth:`commit`; counted in ``bytes_out`` like any tile written
+        home, and in ``wb_zeros_landed``).  The copy on the chip stays
+        what the program wrote, resident and current.  A tile costs this
+        thread the landing alone, where its copy home cost the pump's
+        thread 240 us to start and the committer as much to collect
+        (``PERF.md`` §6, PR 44)."""
+        for data in datas:
+            snap = self.snapshot(data)
+            if snap is not None:
+                p = snap[0]
+                if self.commit(data, snap[1], _fresh_zeros(p.shape, p.dtype)):
+                    self.stats["wb_zeros_landed"] += 1
 
     def start(self, data) -> Optional[int]:
         """Start, without waiting for it, the device->host copy of the

@@ -140,6 +140,9 @@ class TpuDevice(Device):
         #: place of the intended one, 0 on a healthy run
         self.stats.update(wave_fallbacks=0, submit_retries=0,
                           stage_batch_fallbacks=0)
+        #: of ``wave_tasks``, those whose program ran them as ONE batched
+        #: kernel (the body names the form: ``_batched``)
+        self.stats["wave_tasks_batched"] = 0
         #: tasks' value arguments by what became of them
         #: (device/value_args.py)
         self.stats.update(value_args_dropped=0, value_args_packed=0,
@@ -1023,25 +1026,45 @@ class TpuDevice(Device):
         content key through the executable cache's fingerprint."""
         cnt = len(staged)
         args0, nout = staged[0][1], fplan.nout
+        # a body whose work is a dependent loop of small steps names the
+        # form that runs a wave of it as ONE kernel (``_batched``): the
+        # program then calls that form once over the stacked tiles where
+        # it would have unrolled the tasks' bodies one after another
+        batched = getattr(body, "_batched", None)
 
         def build():
             plan = self._value_plan(staged[0][0], body, args0)
+            if batched is None:
+                form = ()
 
-            def _wave(*flat):
-                outs: List[Any] = []
-                for args in plan.bodies_args(flat, cnt):
-                    o = body(*args)
-                    outs.extend(o if isinstance(o, (tuple, list))
-                                else (o,))
-                return tuple(outs)
+                def _wave(*flat):
+                    outs: List[Any] = []
+                    for args in plan.bodies_args(flat, cnt):
+                        o = body(*args)
+                        outs.extend(o if isinstance(o, (tuple, list))
+                                    else (o,))
+                    return tuple(outs)
+            else:
+                # never an executable of the unrolled program for this
+                # one, from this process or from the store
+                form = ("batched", self._content_fp(batched))
+
+                def _wave(*flat):
+                    outs = batched(*plan.stacked_args(flat, cnt))
+                    # task-major, as the unrolled program returns them
+                    return tuple(o[t] for t in range(cnt) for o in outs)
             # the task class in the program's name: the device trace's
             # ``XLA Modules`` line then splits the chip's time by class
             _wave.__name__ = f"_wave_{cls}"
             return (("wave", cls, self._content_fp(body), len(args0), nout,
-                     cnt) + plan.tag, _wave,
+                     cnt) + form + plan.tag, _wave,
                     plan.donate(plan.aliased(args0, donates), cnt), plan)
         local_key = ("wave", cls, base_key, argsig(args0),
                      _placeholders_at(args0), nout, donates, cnt)
+        if batched is not None:
+            # (a flag, not the form: ``base_key`` names the body, and a
+            # PTG wraps the form anew for every taskpool)
+            local_key += ("batched",)
         entry = self._cached_jit(local_key, build)
         program, plan = entry[0], entry[1]
         flat = plan.flatten([args for (_t, args, _o) in staged])
@@ -1073,6 +1096,7 @@ class TpuDevice(Device):
             for t in grp:
                 self._fire_exec(t, pins.EXEC_END, wave=cnt)
         notes = self._count_values(plan, cnt, flat, len(outs), don)
+        notes["batched"] = cnt if batched is not None else 0
         if getattr(body, "_converts", False):
             self._count_converts(staged, outs)
         if len(outs) != nout * cnt:
@@ -1081,6 +1105,7 @@ class TpuDevice(Device):
                 f"{len(outs)} outputs for {nout * cnt} writable flows")
         self.stats["wave_submits"] = self.stats.get("wave_submits", 0) + 1
         self.stats["wave_tasks"] = self.stats.get("wave_tasks", 0) + cnt
+        self.stats["wave_tasks_batched"] += notes["batched"]
         self._finish(staged, outs, nout, es, complete)
         return notes
 
@@ -1712,6 +1737,12 @@ class TpuDevice(Device):
         bumps_heard = pins.active(pins.DATA_VERSION_BUMP)
         res = self._res
         going: List[Data] = []
+        #: the outputs the body says are exact zeros whatever it is given
+        #: (``_zeros``: positions among its outputs), and of them the
+        #: last versions of tiles with a home: zeros are landed there as
+        #: they are, no copy from the chip is started or collected
+        zeros = getattr(staged[0][0].selected_chore.body_fn, "_zeros", ())
+        blank: List[Data] = []
         #: data_id -> the rank of an output's next reader, as the
         #: tasks' pools know it (``Residency.next_uses``)
         nexts: Dict[int, int] = {}
@@ -1756,7 +1787,8 @@ class TpuDevice(Device):
                             nexts[data.data_id] = uses[nx + pos]
                         if data.scratch is None \
                                 and (home is None or pos in home):
-                            going.append(data)
+                            (blank if home is not None and j in zeros
+                             else going).append(data)
                     if home is None:
                         last = False
                     if task._tpu_scratch:
@@ -1768,8 +1800,11 @@ class TpuDevice(Device):
                 res.settle()
             self.stats["task_commits" if alone else "wave_commits"] += 1
             home = 0 if donated else self._send_home(going, last)
+            if blank and not donated:
+                self._wb.land_zeros(blank)
             if sp is not None:
-                sp.note(n=len(done), outs=len(done) * nout, home=home)
+                sp.note(n=len(done), outs=len(done) * nout, home=home,
+                        blank=len(blank))
         except Exception as e:
             debug.error("device epilog of %d x %r failed: %s",
                         len(staged), staged[0][0].task_class.name, e)

@@ -289,6 +289,20 @@ class ValuePlan:
                     args.append(_weak(ivec[t * nint + x]))
             yield args
 
+    def stacked_args(self, flat: Sequence[Any], ntasks: int) -> List[Any]:
+        """Inside the trace of a program that runs its tasks as ONE
+        batched call: per position, the tasks' arguments stacked along a
+        new leading axis where they differ from task to task (an argument
+        of the program, a packed value), and the one thing every task
+        gets alike handed over once (a dropped value's placeholder, the
+        zeros of a tile that is no argument, an absent flow)."""
+        tasks = list(self.bodies_args(flat, ntasks))
+        return [tasks[0][i]
+                if tasks[0][i] is None
+                or (r is not None and r[0] in (_DROP, _UNBORN, _UNREAD))
+                else jnp.stack([args[i] for args in tasks])
+                for i, r in enumerate(self.routes)]
+
     def donate(self, argnums: Sequence[int],
                ntasks: int = 1) -> Tuple[int, ...]:
         """The donated positions of a program of ``ntasks`` tasks, in

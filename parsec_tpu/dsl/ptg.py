@@ -1585,10 +1585,20 @@ def _wrap_device_body(pc: PTGTaskClass, fn: Callable):
     wrapped._jit_key = getattr(fn, "_jit_key", (fn, tuple(names)))
     # forward the device-module opt-ins (see TpuDevice._submit): local
     # values baked statically into the trace / donated array positions
-    # ... and ``_converts``: its outputs are lower-precision twins
-    for attr in ("_static_values", "_donate_args", "_converts"):
+    # ... ``_converts``: its outputs are lower-precision twins; ``_zeros``:
+    # the outputs that are exact zeros whatever goes in
+    for attr in ("_static_values", "_donate_args", "_converts", "_zeros"):
         if hasattr(fn, attr):
             setattr(wrapped, attr, getattr(fn, attr))
+    # ... and ``_batched``: the form that runs a wave of the body as one
+    # kernel takes the same keywords, each stacked over the wave's tasks
+    # or handed over once (``ValuePlan.stacked_args``)
+    form = getattr(fn, "_batched", None)
+    if form is not None:
+        def batched(*pos):
+            return form(**dict(zip(names, pos)))
+
+        wrapped._batched = batched
     if pc.stage_hooks:
         # per-flow custom staging, indexed by the data-arg position the
         # device module sees (non-CTL flow declaration order)

@@ -28,12 +28,34 @@ update becomes a plain MXU matmul, which is the fast shape on this
 hardware; the cost is extra FLOPs in tsqrt / ttqrt (complete QR of a
 2nb x nb stack) amortised across the row's updates.
 
+A wave of kills is ONE kernel (PR 44).  A kill's work is a dependent
+loop (512 Householder steps of a few hundred KB each), bound by the
+latency of a step and not by the chip's arithmetic; a wave program that
+unrolls 64 such bodies runs 64 such loops one after another, and so does
+``jnp.linalg.qr`` under ``vmap`` (compiled for a v5e it is hand-written
+kernels that factor one block of one matrix: my chip runs, PR 44).  So
+the kills have a Householder QR of their own, over a STACK of matrices
+with every matrix's step j taken together (:func:`_lockstep_qr`), and
+``geqrt_tpu``, ``tsqrt_tpu`` and ``ttqrt_tpu`` (:func:`_kills`) name, as
+``_batched``, the form the device module calls ONCE for a whole wave
+(``TpuDevice._launch``).  A body is that form over a stack of one: a
+kill alone runs the same kernel (0.24 ms where ``jnp.linalg.qr`` took
+0.66).  The updates name no form: a ``tsmqr`` is one MXU-bound product
+that fills the chip by itself, and stacking its tiles would only add
+copies.  The TS / TT kills also say that their second output, the killed
+tile, is exact zeros whatever goes in (``_zeros``): that version is the
+tile's last, and the device module lands zeros at home without copying
+them from the chip (4,060 of the 4,096 tiles a hierarchical QR of
+512 x 8 tiles sends home).
+
 The factorization leaves R in the upper triangle of A's first NT tile
 rows (every other tile zeroed).  Orthogonality is implicit; the invariant
 A^T A = R^T R verifies the result without tracking Q (tests).
 """
 
 from __future__ import annotations
+
+import math
 
 import numpy as np
 
@@ -45,9 +67,11 @@ IN = AccessMode.IN
 INOUT = AccessMode.INOUT
 
 try:
+    import jax
     import jax.numpy as jnp
+    from jax import lax
 except Exception:  # pragma: no cover
-    jnp = None
+    jax = jnp = lax = None
 
 
 # -- tile bodies -------------------------------------------------------------
@@ -56,11 +80,6 @@ def geqrt_cpu(T, Q, **_):
     q, r = np.linalg.qr(T)
     T[:] = r
     Q[:] = q
-
-
-def geqrt_tpu(T, Q, **_):
-    q, r = jnp.linalg.qr(T)
-    return r, q
 
 
 def unmqr_cpu(Q, C, **_):
@@ -80,13 +99,6 @@ def tsqrt_cpu(R, B, Q, **_):
     Q[:] = q
 
 
-def tsqrt_tpu(R, B, Q, **_):
-    nb = R.shape[0]
-    stacked = jnp.vstack([jnp.triu(R), B])
-    q, r = jnp.linalg.qr(stacked, mode="complete")
-    return r[:nb], jnp.zeros_like(B), q
-
-
 def tsmqr_cpu(Q, C1, C2, **_):
     nb = C1.shape[0]
     s = Q.T @ np.vstack([C1, C2])
@@ -100,17 +112,196 @@ def tsmqr_tpu(Q, C1, C2, **_):
     return s[:nb], s[nb:]
 
 
-# The TT kill is the TS kill on two triangles (the dense-Q representation
-# takes no advantage of the second triangle's zeros); ``ttmqr`` runs the
-# ``tsmqr`` bodies as they are.
+# The TT kill is the TS kill on two triangles; ``ttmqr`` runs the ``tsmqr``
+# bodies as they are.
 
 def ttqrt_cpu(R, B, Q, **_):
     tsqrt_cpu(R, np.triu(B), Q)
     B[:] = 0.0
 
 
-def ttqrt_tpu(R, B, Q, **_):
-    return tsqrt_tpu(R, jnp.triu(B), Q)
+# -- the kills' device bodies: a wave of them as ONE kernel -------------------
+
+def _lockstep_qr(wo):
+    """Blocked Householder QR of every matrix of a stack, in lockstep.
+
+    The column steps of a block of ``wo`` columns run inside ONE Pallas
+    kernel, the block resident in VMEM, four matrices a grid step: their
+    steps are independent chains that the chip's scheduler interleaves,
+    and a step costs its vector work (the same steps as a
+    ``lax.fori_loop`` of XLA operations are a dozen launches a step
+    whatever the stack: 2.23 ms a ``tsqrt`` alone where this takes 0.24,
+    19.6 against 13.1 at 64; my chip run, PR 44).  Only whole blocks
+    touch the trailing columns and Q, as
+    products at ``highest``.  Matrices are held TRANSPOSED (a column is a
+    row: the active rows lie along the lanes).
+
+    (The helpers are nested, the width closed over: a device program's
+    content key walks a callable's nested code and closure cells, not the
+    module's globals, so the whole form is in the key of every program
+    built around it.)"""
+
+    def hdot(spec, a, b):
+        return jnp.einsum(spec, a, b, precision="highest")
+
+    def block_kernel(p_ref, o_ref, tt_ref):
+        """``o_ref``: the block as LAPACK leaves it (R on and before the
+        pivots, the reflectors' tails behind them); ``tt_ref``: its
+        compact-WY T, transposed."""
+        from .pallas_kernels import pl  # (imported where a kill is built:
+        # Pallas costs every importer of this module most of a second)
+
+        _g, b, r = p_ref.shape
+        lane = lax.broadcasted_iota(jnp.int32, (1, 1, r), 2)
+        sub = lax.broadcasted_iota(jnp.int32, (1, b, 1), 1)
+        tlane = lax.broadcasted_iota(jnp.int32, (1, 1, b), 2)
+        o_ref[...] = p_ref[...]
+        tt_ref[...] = jnp.zeros(tt_ref.shape, tt_ref.dtype)
+
+        def step(jj, _):
+            row = o_ref[:, pl.ds(jj, 1), :]
+            alpha = jnp.sum(jnp.where(lane == jj, row, 0.0), -1,
+                            keepdims=True)
+            x = jnp.where(lane > jj, row, 0.0)
+            xx = jnp.sum(x * x, -1, keepdims=True)
+            norm = jnp.sqrt(alpha * alpha + xx)
+            # LAPACK's larfg: beta = -sign(alpha) * norm; a tail of zeros
+            # leaves the column as it is (tau = 0)
+            flat = xx == 0.0
+            beta = jnp.where(flat, alpha,
+                             jnp.where(alpha >= 0.0, -norm, norm))
+            tau = jnp.where(flat, 0.0,
+                            (beta - alpha) / jnp.where(flat, 1.0, beta))
+            v = jnp.where(lane == jj, 1.0,
+                          x / jnp.where(flat, 1.0, alpha - beta))
+            # one pass over the block: a finished column's product with v
+            # is an entry of V^T V (T's recurrence), a later column's is
+            # the update's
+            blk = o_ref[...]
+            z = jnp.sum(blk * v, -1, keepdims=True)
+            blk = blk - jnp.where(sub > jj, tau * z, 0.0) * v
+            done = jnp.where(lane > jj, v, jnp.where(lane == jj, beta, row))
+            o_ref[...] = jnp.where(sub == jj, done, blk)
+            tt = tt_ref[...]
+            tcol = -tau * jnp.sum(tt * jnp.where(sub < jj, z, 0.0), 1,
+                                  keepdims=True)
+            tt_ref[...] = jnp.where(
+                sub == jj, tcol + jnp.where(tlane == jj, tau, 0.0), tt)
+            return 0
+
+        lax.fori_loop(0, b, step, 0)
+
+    def block_steps(pt):
+        """One block (n, b, r), column ``j`` pivoting at active row ``j``:
+        the factored block and its compact-WY ``T`` (n, b, b),
+        ``H_0 .. H_{b-1} = I - V T V^T``."""
+        from .pallas_kernels import _pallas, pl
+
+        n, b, r = pt.shape
+        g = math.gcd(n, 4)
+        blk, tt = _pallas(
+            None, (pt,), block_kernel,
+            out_shape=(jax.ShapeDtypeStruct((n, b, r), pt.dtype),
+                       jax.ShapeDtypeStruct((n, b, b), pt.dtype)),
+            grid=(n // g,),
+            in_specs=[pl.BlockSpec((g, b, r), lambda i: (i, 0, 0))],
+            out_specs=(pl.BlockSpec((g, b, r), lambda i: (i, 0, 0)),
+                       pl.BlockSpec((g, b, b), lambda i: (i, 0, 0))))
+        return blk, jnp.swapaxes(tt, 1, 2)
+
+    def reflectors(pt):
+        """The unit-lower-trapezoidal V (transposed) of a factored block."""
+        _n, b, r = pt.shape
+        lane = lax.broadcasted_iota(jnp.int32, (b, r), 1)
+        piv = lax.broadcasted_iota(jnp.int32, (b, r), 0)
+        return jnp.where(lane > piv, pt, jnp.where(lane == piv, 1.0, 0.0))
+
+    def householder_stack(at, tri):
+        """``at``: (n, c, m), the matrices transposed, m >= c.  ``tri``:
+        rows c .. m-1 are dense and the c x c block above them is upper
+        triangular (a TS / TT kill: reflector j lives on row j and the
+        dense rows alone); else the matrices are dense.  Returns R
+        transposed (n, c, c) and the complete Q (n, m, m), LAPACK's
+        signs."""
+        n, c, m = at.shape
+        q = jnp.broadcast_to(jnp.eye(m, dtype=at.dtype), (n, m, m))
+        for jb in range(0, c, wo):
+            b = min(wo, c - jb)
+            # the active rows, in pieces: the block's own and the dense
+            # ones (the big arrays are read and written piece by piece,
+            # where they stand)
+            rows = [(lo, hi) for lo, hi in
+                    ((jb, jb + b), (c, m) if tri else (jb + b, m))
+                    if hi > lo]
+            pt, T = block_steps(jnp.concatenate(
+                [at[:, jb:jb + b, lo:hi] for lo, hi in rows], -1))
+            vt = reflectors(pt)
+            cuts = np.cumsum([0] + [hi - lo for lo, hi in rows])
+            pieces = []
+            for (lo, hi), s0, s1 in zip(rows, cuts, cuts[1:]):
+                at = at.at[:, jb:jb + b, lo:hi].set(pt[..., s0:s1])
+                pieces.append((lo, hi, vt[..., s0:s1]))
+
+            def reflect(x, pieces=pieces, T=T):
+                """x <- x (I - V T V^T) on the active columns of x."""
+                w = sum(hdot("nmr,nkr->nmk", x[..., lo:hi], v)
+                        for lo, hi, v in pieces)
+                w = hdot("nmk,nkj->nmj", w, T)
+                for lo, hi, v in pieces:
+                    x = x.at[..., lo:hi].add(-hdot("nmj,njr->nmr", w, v))
+                return x
+
+            if jb + b < c:  # the trailing columns: A <- (I - V T^T V^T) A
+                at = at.at[:, jb + b:].set(reflect(at[:, jb + b:]))
+            q = reflect(q)
+        return at[:, :, :c], q
+
+    return householder_stack
+
+
+def _kills(stack_qr):
+    """The three kill bodies over ``stack_qr`` (:func:`_lockstep_qr`).
+    Each names as ``_batched`` the form a wave program calls ONCE for all
+    its tasks: the body's tile keywords stacked task-major ((n, nb, nb)
+    each; the ``NEW`` Q block is nobody's argument), the body's outputs
+    stacked alike; and each body IS that form over a stack of one, so a
+    kill runs one kernel whether it goes out alone or in a wave.
+    Householder QR of the stack, complete Q, R upper triangular, the
+    killed tile exact zeros, every product at ``highest``."""
+    def geqrt_wave(T, **_):
+        rt, q = stack_qr(jnp.swapaxes(T, 1, 2), False)
+        return jnp.triu(jnp.swapaxes(rt, 1, 2)), q
+
+    def tsqrt_wave(R, B, **_):
+        at = jnp.concatenate([jnp.swapaxes(jnp.triu(R), 1, 2),
+                              jnp.swapaxes(B, 1, 2)], -1)
+        rt, q = stack_qr(at, True)
+        return jnp.triu(jnp.swapaxes(rt, 1, 2)), jnp.zeros_like(B), q
+
+    # The TT kill is the TS kill on two triangles (the dense-Q
+    # representation takes no advantage of the second triangle's zeros)
+    def ttqrt_wave(R, B, **_):
+        return tsqrt_wave(R, jnp.triu(B))
+
+    def geqrt_tpu(T, Q, **_):
+        return tuple(o[0] for o in geqrt_wave(T[None]))
+
+    def tsqrt_tpu(R, B, Q, **_):
+        return tuple(o[0] for o in tsqrt_wave(R[None], B[None]))
+
+    def ttqrt_tpu(R, B, Q, **_):
+        return tuple(o[0] for o in ttqrt_wave(R[None], B[None]))
+
+    geqrt_tpu._batched = geqrt_wave
+    tsqrt_tpu._batched = tsqrt_wave
+    ttqrt_tpu._batched = ttqrt_wave
+    # the killed tile is exact zeros whatever went in: its home tile
+    # needs no copy from the chip (``TpuDevice._land_zeros``)
+    tsqrt_tpu._zeros = ttqrt_tpu._zeros = (1,)
+    return geqrt_tpu, tsqrt_tpu, ttqrt_tpu
+
+
+geqrt_tpu, tsqrt_tpu, ttqrt_tpu = _kills(_lockstep_qr(128))
 
 
 def _dot_bf16(a, b):

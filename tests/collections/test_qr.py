@@ -148,3 +148,65 @@ def test_qr_graph_pallas_chores():
         QSHAPE2=(np.float32, (2 * nb, 2 * nb)))
     GraphExecutor(tp)(block=True)
     _check_r(A0, A.to_array(), rtol=5e-3)
+
+
+# -- the kills' batched form (a wave as ONE kernel: ``_lockstep_qr``) -------
+
+def _stack_of(cls, n, nb, seed, plant=None):
+    rng = np.random.default_rng(seed)
+    tiles = [rng.uniform(-1, 1, (n, nb, nb)).astype(np.float32)
+             for _ in range(1 if cls == "geqrt" else 2)]
+    if plant == "zero_column":      # a tail of zeros: tau = 0, H = I
+        for t in tiles:
+            t[:, :, 3] = 0.0
+    if plant == "triangle":         # nothing below the diagonal anywhere
+        tiles = [np.triu(t) for t in tiles]
+    if plant == "zeros":
+        tiles = [np.zeros_like(t) for t in tiles]
+    return tiles
+
+
+@pytest.mark.parametrize("plant", [None, "zero_column", "triangle", "zeros"])
+@pytest.mark.parametrize("cls,n,nb,wo", [
+    ("geqrt", 3, 40, 16),   # blocks of 16, 16, 8; three matrices a grid step
+    ("tsqrt", 4, 40, 16),
+    ("ttqrt", 6, 24, 128),  # one block narrower than its width
+    ("tsqrt", 1, 32, 8),
+    ("geqrt", 8, 16, 8),
+])
+def test_a_wave_of_kills_is_lapacks_householder_qr(cls, n, nb, wo, plant):
+    """Matrix for matrix LAPACK's factorization of the same stack (its
+    signs too, so R and Q themselves agree, not only up to a sign), for
+    any number of matrices, block widths that do not divide the tile, and
+    columns that need no reflector; and the body is the form over a stack
+    of one."""
+    import jax.numpy as jnp
+
+    from parsec_tpu.ops import qr
+
+    tiles = _stack_of(cls, n, nb, seed=n * nb, plant=plant)
+    bodies = dict(zip(("geqrt", "tsqrt", "ttqrt"),
+                      qr._kills(qr._lockstep_qr(wo))))
+    outs = bodies[cls]._batched(*tiles)
+    for t in range(n):
+        if cls == "geqrt":
+            q, r = np.linalg.qr(tiles[0][t], mode="complete")
+            want = (r, q)
+        else:
+            low = np.triu(tiles[1][t]) if cls == "ttqrt" else tiles[1][t]
+            q, r = np.linalg.qr(np.vstack([np.triu(tiles[0][t]), low]),
+                                mode="complete")
+            want = (r[:nb], np.zeros_like(low), q)
+        assert len(outs) == len(want)
+        for got, ref in zip(outs, want):
+            got = np.asarray(got[t])
+            assert got.shape == ref.shape and got.dtype == ref.dtype
+            assert np.max(np.abs(got - ref)) <= 2e-5
+        r, q = np.asarray(outs[0][t]), np.asarray(outs[-1][t])
+        assert not np.tril(r, -1).any()
+        assert np.max(np.abs(q.T @ q - np.eye(len(q)))) <= 1e-5
+        if cls != "geqrt":
+            assert not np.asarray(outs[1][t]).any()
+    alone = bodies[cls](*[jnp.asarray(x[0]) for x in tiles], None)
+    for got, ref in zip(alone, outs):
+        np.testing.assert_array_equal(np.asarray(got), np.asarray(ref[0]))

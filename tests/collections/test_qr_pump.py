@@ -222,7 +222,9 @@ def test_only_the_version_the_dag_sends_home_goes_to_the_committer(
         dag, monkeypatch):
     """The executor knows from the captured graph which output is the
     last of its tile: every tile is enqueued once, however often the DAG
-    rewrites it (tsmqr's C2 declares ``-> A(m, n)`` at every step)."""
+    rewrites it (tsmqr's C2 declares ``-> A(m, n)`` at every step) —
+    but the tiles a kill leaves as zeros: their body says so (``_zeros``)
+    and zeros are landed at home without a copy from the device."""
     from parsec_tpu.device.staging import WritebackCommitter
     from parsec_tpu.ops import cholesky_ptg
 
@@ -233,13 +235,18 @@ def test_only_the_version_the_dag_sends_home_goes_to_the_committer(
         lambda self, datas, *a, **kw: (seen.extend(d.key for d in datas),
                                        enqueue_all(self, datas, *a, **kw))[1])
     a, A = _matrix(4, seed=6)
+    blank = 0
     if dag == "geqrf":
-        tp, tiles = _taskpool(A), 16
+        tp, tiles, blank = _taskpool(A), 16, 6  # (the tiles under R)
     else:
         spd = (a @ a.T + 4 * NB * np.eye(4 * NB)).astype(np.float32)
         A.from_array(spd)
         tp, tiles = cholesky_ptg(use_tpu=True, use_cpu=False).taskpool(
             NT=A.mt, A=A), 10
     _s, d = _pump(A, tp)
-    assert len(seen) == len(set(seen)) == tiles
+    assert len(seen) == len(set(seen)) == tiles - blank
+    assert d["wb_zeros_landed"] == blank
+    assert all(m <= n for (m, n) in seen) or not blank
     assert d["bytes_out"] == tiles * NB * NB * 4
+    if blank:
+        assert not np.tril(A.to_array(), -1).any()
