@@ -20,11 +20,19 @@ the expense of its neighbour.  When everything left is pinned,
 :meth:`~Residency.reserve` says so (``reserve_gave_up``, warned once)
 and the staging walk fails the chunk loudly instead of running past the
 budget.  What the accounting does NOT see: a slot is free the moment a
-copy is dropped or rebound, but PJRT keeps the buffer until the last
+copy is evicted or rebound, but PJRT keeps the buffer until the last
 program that reads it has run.  Nothing here waits for that: on the chip
 PJRT holds an allocation back until programs in flight have freed the
 memory (the out-of-core cell peaks at 16.84 of 16.91 GB and completes,
 my chip run, PR 30), and runs out of memory loudly if it ever cannot.
+What it DOES see since PR 45: a scratch tile let go with its last reader
+(:meth:`~Residency.release` with ``after``) stays charged until the chip
+has run that reader's program.  A pump that leads the chip would
+otherwise hold a generation of them a sweep of its lead, all charged to
+nobody; now the room a program's outputs take is made before its call
+(:meth:`~Residency.wait_for`) out of what costs nobody a copy, and
+waited for (``parsec-wait:chip_lead``, ``lead_waits``) where that is
+not enough: the most the device holds is the budget again.
 
 Which accounting a device has is decided ONCE, in the constructor: the
 native zone allocator (alignment and fragmentation modelled for real —
@@ -152,7 +160,7 @@ class Residency:
         for k in ("evictions", "evict_clean", "evict_dirty",
                   "evict_bytes_home", "evict_batches", "evict_next_use",
                   "evict_never_again", "evict_cancelled", "restaged_tiles",
-                  "reserve_gave_up", "unaccounted_tiles"):
+                  "reserve_gave_up", "unaccounted_tiles", "lead_waits"):
             stats.setdefault(k, 0)
         self._span = span or (lambda name, **info: contextlib.nullcontext())
         self.lock = threading.RLock()
@@ -195,6 +203,10 @@ class Residency:
         #: (``restaged_tiles``)
         self._evicted: set = set()
         self._warned: set = set()
+        #: scratch tiles let go while the program that read them last may
+        #: still run, oldest first: [an output of that program, the
+        #: bytes still charged for them, their slots in the zone]
+        self._limbo: "collections.deque[List[Any]]" = collections.deque()
         #: the native zone allocator (offset-based: PJRT owns the memory)
         self.zone = self._new_zone() if zone else None
 
@@ -216,6 +228,7 @@ class Residency:
             self._budget = int(value)
             if self.zone is None:
                 return
+            self._retire(0, ended=True)  # (their slots are the old zone's)
             fresh = self._new_zone()
             held: Dict[int, int] = {}
             offsets: Dict[int, int] = {}
@@ -315,7 +328,10 @@ class Residency:
 
     def settle(self) -> None:
         """After commits grew residency: back under the budget (the zone
-        already evicted while it allocated)."""
+        already evicted while it allocated), and what the chip has let
+        go of meanwhile is let go of here."""
+        if self._limbo:
+            self._retire(0)
         if self.zone is None:
             self.reserve(0)
 
@@ -327,6 +343,7 @@ class Residency:
         leak phantom ``used`` across device reuse (the shared ``device=``
         pattern) until eviction stops working."""
         with self.lock:
+            self._retire(0, ended=True)
             self.clean.clear()
             self.dirty.clear()
             if self.zone is not None:
@@ -412,10 +429,16 @@ class Residency:
 
     @property
     def chunk_limit(self) -> int:
-        """Bytes of tiles (read and written) one device program may
-        take: a sixteenth of the budget, so that a chunk's pins, the
-        lane's and the outputs in flight together leave the budget most
-        of its room (a 64-task gemm wave of 16 MiB tiles is 4 GiB)."""
+        """Bytes of tiles one device program may BRING onto the device:
+        a sixteenth of the budget, so that a chunk's pins, the lane's
+        and the outputs in flight together leave the budget most of its
+        room (a 64-task gemm wave of 16 MiB tiles is 4 GiB).  Counted
+        (``TpuDevice._submit_wave``): every tile a task reads that has a
+        home, resident at the moment or not, and every tile it writes,
+        donated or not; not counted: a tile read, and not written, that
+        was born on this device (a scratch tile, a tile of a collection
+        born here) and is here still, which is in the accounting
+        already."""
         return self._budget // 16
 
     # -- making room -------------------------------------------------------
@@ -435,6 +458,8 @@ class Residency:
         exist already) goes on over the budget."""
         with self.lock:
             need = self._in_use() + nbytes - self._budget
+            if need > 0 and self._limbo:
+                need = self._free_room(need)
             if need <= 0:
                 return True
             self._evict(need)
@@ -447,16 +472,82 @@ class Residency:
                 self._in_use(), self._budget, len(self._pins))
             return False
 
-    def _victims(self, need: int) -> List[Tuple[Data, bool]]:
+    def wait_for(self, nbytes: int) -> None:
+        """Before a program that brings ``nbytes`` onto the device is
+        called: where scratch tiles let go are still charged, the room
+        is had for free or waited for (:meth:`_free_room`), so that the
+        outputs PJRT allocates in the call find it; nobody who would
+        have to go home or come back is evicted here.  (Without such
+        tiles: nothing; a commit makes the room, as ever.)"""
+        if self._limbo:
+            with pins.held(self.lock, "res_lock"):
+                self._free_room(self._in_use() + nbytes - self._budget)
+
+    def _free_room(self, need: int) -> int:
+        """Room that costs nobody a copy, while scratch tiles let go are
+        still charged behind programs in flight (the caller holds the
+        lock): what the chip has let go of meanwhile; then the clean
+        tiles that no task reads again (generation 0 of a stencil); then
+        what the chip lets go of next, WAITED for.  Returns the bytes
+        still missing."""
+        need = self._retire(need, wait=False)
+        if need > 0 and self.clean:
+            before = self._in_use()
+            self._evict(need, spent=True)
+            need -= before - self._in_use()
+        return self._retire(need) if need > 0 else need
+
+    def _retire(self, need: int, wait: bool = True,
+                ended: bool = False) -> int:
+        """Free the slots of the scratch tiles whose last reader's
+        program the chip has run (the caller holds the lock), oldest
+        first: a device runs its programs in the order of their calls.
+        With ``need`` > 0 and ``wait`` the thread WAITS for the programs
+        still running (``lead_waits``, a ``parsec-wait:chip_lead``
+        event) until that many bytes are free or nothing is left to wait
+        for; returns what is still missing.  ``ended``: every program
+        has (a detach).  An output that a later program was given to
+        write in place can no longer be asked: its tiles count as gone,
+        as every tile let go did before PR 45."""
+        limbo = self._limbo
+        while limbo:
+            after, nbytes, offs = limbo[0]
+            try:
+                if not (ended or after.is_deleted() or after.is_ready()):
+                    if need <= 0 or not wait:
+                        break
+                    self.stats["lead_waits"] += 1
+                    with pins.wait("chip_lead", need=need):
+                        after.block_until_ready()
+            except Exception as e:  # (it has ended: its commit says how)
+                debug.verbose(3, "device", "the program behind %d bytes let "
+                              "go failed: %s", nbytes, e)
+            limbo.popleft()
+            if self.zone is None:
+                self.used -= nbytes
+            else:
+                for off in offs:
+                    self.zone.release(off)
+                self.used = self.zone.used
+            need -= nbytes
+        return need
+
+    def _victims(self, need: int,
+                 spent: bool = False) -> List[Tuple[Data, bool]]:
         """Out of the LRUs, unpinned, until their slots cover ``need``
         bytes, as ``(tile, was dirty)``: oldest first, clean before
         dirty; and where next uses are known, in that order those never
         read again, those of unknown use, then the known ones, the
-        farthest reader first (the sort is stable)."""
+        farthest reader first (the sort is stable).  ``spent``: only the
+        clean tiles that somebody said are never read again."""
         pins, held, nxt = self._pins, self._held, self._next
-        order = [(did, lru) for lru in (self.clean, self.dirty)
-                 for did in lru if did not in pins]
-        if nxt:
+        if spent:
+            order = [(did, self.clean) for did in self.clean
+                     if did not in pins and nxt.get(did) == NEVER]
+        else:
+            order = [(did, lru) for lru in (self.clean, self.dirty)
+                     for did in lru if did not in pins]
+        if nxt and not spent:
             unknown = NEVER - 1
             order.sort(key=lambda v: -nxt.get(v[0], unknown))
         out: List[Tuple[Data, bool]] = []
@@ -498,7 +589,7 @@ class Residency:
                         v.data_id for v in home)
                     self._leave(leaving, wait_us, sp, check=True)
 
-    def _evict(self, need: int) -> bool:
+    def _evict(self, need: int, spent: bool = False) -> bool:
         """One batch of victims for ``need`` bytes (the caller holds the
         lock, and keeps it): those whose copy here is the only valid one
         go home first, together, then every victim drops.  A
@@ -507,8 +598,9 @@ class Residency:
         ``known`` (victims whose next use somebody had said), ``never``
         (of them, those with no reader left) and ``cancelled`` (victims
         that stayed: only where the lock was free meanwhile,
-        :meth:`make_room`).  False: no victim."""
-        leaving = self._leaving(need)
+        :meth:`make_room`).  ``spent``: of :meth:`_victims`.  False: no
+        victim."""
+        leaving = self._leaving(need, spent)
         if leaving is None:
             return False
         home = leaving.home
@@ -517,12 +609,13 @@ class Residency:
             self._leave(leaving, wait_us, sp, check=False)
         return True
 
-    def _leaving(self, need: int) -> Optional["_Leaving"]:
+    def _leaving(self, need: int,
+                 spent: bool = False) -> Optional["_Leaving"]:
         """The first half of an eviction (the caller holds the lock):
         the victims for ``need`` bytes, each with the version its copy
         here has, and those of them that have to go home first.  None:
         no victim."""
-        victims = self._victims(need)
+        victims = self._victims(need, spent)
         if not victims:
             return None
         idx = self.index
@@ -630,10 +723,29 @@ class Residency:
                     self.stats["evictions"] += 1
                     self._evicted.add(data.data_id)
 
-    def release(self, data: Data) -> None:
+    def release(self, data: Data, after: Any = None) -> None:
         """Hand ``data``'s copy on WITHOUT a write-back and without
         counting an eviction: out of the LRUs, detached, its slot freed
-        (``drop_residency``; a scratch tile's last user)."""
+        (``drop_residency``; a scratch tile's last user).  ``after``: an
+        output of the device program that read the tile last (anything
+        with ``is_ready`` / ``block_until_ready`` / ``is_deleted``, as a
+        ``jax.Array``): PJRT keeps the buffer until that program has
+        run, so the slot stays charged until then (:meth:`_retire`)."""
         with self.lock:
             self.forget(data)
-            self.drop(data, evicted=False)
+            did = data.data_id
+            nbytes = self._held.get(did, 0) if after is not None else 0
+            if not nbytes:
+                self.drop(data, evicted=False)
+                return
+            data.detach_copy(self.index)
+            self._next.pop(did, None)
+            del self._held[did]
+            self._charged(data, nbytes, 0)
+            off = self._offsets.pop(did, None)
+            limbo = self._limbo
+            if not limbo or limbo[-1][0] is not after:
+                limbo.append([after, 0, []])
+            limbo[-1][1] += nbytes
+            if off is not None:
+                limbo[-1][2].append(off)

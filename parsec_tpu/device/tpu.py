@@ -158,6 +158,11 @@ class TpuDevice(Device):
         #: program because the staging walk found somebody else holding
         #: such a tile's array (0 on a healthy run)
         self.stats.update(tile_args_donated=0, donation_refused=0)
+        #: wave programs whose width the byte bound set
+        #: (``Residency.chunk_limit``), and the bytes of tiles read that
+        #: the bound did not count, born here as they were
+        #: (:meth:`_born_here`: only of waves that it looks into)
+        self.stats.update(chunks_cut_by_bytes=0, chunk_bytes_born_here=0)
         #: calls of device programs: of the executable its ``_jit_cache``
         #: entry holds, and through the cache by the arguments' signature
         #: (an entry's first call, every call of a ``_static_values``
@@ -906,10 +911,21 @@ class TpuDevice(Device):
         dispatch: peak HBM holds one chunk's inputs plus its in-flight
         outputs, never the whole wave's — a large wave of large tiles
         must not OOM where per-task dispatch would not.  A chunk is
-        bounded by BYTES as well as by the power of two: the tiles its
-        tasks read and write (``FlowPlan.nbytes`` a task) stay under
+        bounded by BYTES as well as by the power of two: what its
+        program brings onto the device stays under
         ``Residency.chunk_limit``, a share of the budget — 64 gemm tasks
-        over 16 MiB tiles are 4 GiB — down to a chunk of one task.
+        over 16 MiB tiles are 4 GiB — down to a chunk of one task.  A
+        task brings at most ``FlowPlan.nbytes``, every tile it reads and
+        every tile it writes; where a wave at that price is cut at all,
+        each task is counted for itself (:meth:`_born_here`): a tile
+        it only reads that was born on this device and is here still is
+        no new memory.  What a task costs is its graph's, not the
+        moment's, as long as no born-here tile is spilled: the same
+        solve is then cut into the same chunks every time.  The room for
+        what a chunk was counted at is had before its program is called
+        (``Residency.wait_for``: by waiting for the chip where scratch
+        tiles let go are still charged, never by an eviction that costs
+        a copy).
 
         Failure containment is a PER-CHUNK invariant: a chunk's
         staging/trace/enqueue errors RAISE before any task of THAT chunk
@@ -935,7 +951,9 @@ class TpuDevice(Device):
         One ``dev:wave`` span per chunk, that is per device program,
         with the children ``dev:stage_args``, ``dev:jit``,
         ``dev:dispatch`` (the host's enqueue of the program, not the
-        chip's execution of it) and ``dev:epilog``."""
+        chip's execution of it) and ``dev:epilog``; ``cut`` says what
+        set the chunk's width (``bytes``: the bound; ``tasks``: the
+        tasks left), ``counted`` the bytes its tasks were counted at."""
         body = tasks[0].selected_chore.body_fn
         cls = tasks[0].task_class.name
         self._span_pool = _pool_of(tasks[0])
@@ -949,13 +967,25 @@ class TpuDevice(Device):
         base_key = getattr(body, "_jit_key", None) or body
         start = 0
         remaining = len(tasks)
-        most = max(1, self._res.chunk_limit // max(1, plan.nbytes))
-        most = 1 << (most.bit_length() - 1)
+        limit = self._res.chunk_limit
+        # each task's bytes up to it, where the most a task can cost
+        # would cut the wave; a wave that fits at that price is not
+        # looked into
+        upto = None if plan.nbytes * remaining <= limit \
+            else self._born_here(tasks, plan)
         while remaining:
             if getattr(tasks[0].taskpool, "failed", False):
                 return  # (a chunk that failed it stages no further one)
             # the largest power of two the tasks left and the bytes allow
-            cnt = min(1 << (remaining.bit_length() - 1), most)
+            by_tasks = cnt = 1 << (remaining.bit_length() - 1)
+            if upto is None:
+                counted = cnt * plan.nbytes
+            else:
+                while cnt > 1 and upto[start + cnt] - upto[start] > limit:
+                    cnt >>= 1
+                counted = upto[start + cnt] - upto[start]
+                if cnt < by_tasks:
+                    self.stats["chunks_cut_by_bytes"] += 1
             grp = tasks[start:start + cnt]
             start += cnt
             remaining -= cnt
@@ -965,9 +995,46 @@ class TpuDevice(Device):
                             batch=self._span_batch, waited_us=waited,
                             direct=sum(t._tpu_direct for t in grp)
                             if drained_ns else 0,
-                            dtypes=plan.dtypes) as sp:
+                            dtypes=plan.dtypes,
+                            cut="bytes" if cnt < by_tasks else "tasks",
+                            counted=counted) as sp:
+                self._res.wait_for(counted)
                 self._submit_chunk(grp, body, base_key, plan, es, complete,
                                    sp)
+
+    def _born_here(self, tasks: List[Task], plan: FlowPlan) -> List[int]:
+        """What the tasks of a wave cost the chunk they ride in, as
+        running sums (``upto[k]``: the first ``k`` tasks together).  A
+        task costs ``plan.nbytes`` less every tile it reads, and does
+        not write, that was BORN on this device and lives nowhere else:
+        a scratch tile (a ``NEW`` flow's) or a tile of a collection born
+        here (``Data.scratch`` is not None) whose current copy is here.
+        The chunk's program brings nothing of it onto the device; it is
+        there, and accounted.  Everything else counts: a tile with a
+        home whether or not it is resident at this moment (that is the
+        schedule's, and a program set must not depend on it), a tile
+        written as a new buffer whether or not its input is donated and,
+        with it, that input (a refused donation keeps both), a born-here
+        tile that an eviction spilled (the walk will stage it: the one
+        case where the moment decides, and ``scratch_bytes_out`` says
+        when)."""
+        idx = self.data_index
+        static, reads = plan.nbytes, plan.read_bytes
+        upto = [0]
+        total = born = 0
+        for task in tasks:
+            specs = task.body_args
+            cost = static
+            for pos, nbytes in reads:
+                data = specs[pos][1]
+                if data.scratch is not None \
+                        and data.current_copy(idx) is not None:
+                    cost -= nbytes
+            born += static - cost
+            total += cost
+            upto.append(total)
+        self.stats["chunk_bytes_born_here"] += born
+        return upto
 
     def _submit_chunk(self, grp: List[Task], body, base_key,
                       fplan: FlowPlan, es, complete: bool, wave_span) -> None:
@@ -1671,14 +1738,15 @@ class TpuDevice(Device):
         data.version_bump(idx, bumps_heard)
         self._res.touch(data, dirty=True)
 
-    def _release_scratch(self, tiles) -> None:
+    def _release_scratch(self, tiles, after) -> None:
         """A task that was one declared user of each of these scratch
         tiles has committed (the caller holds the residency lock): with
-        the last user the tile's copy here is dropped (a program already
-        enqueued keeps its buffer)."""
+        the last user the tile's copy here is dropped.  Its program,
+        enqueued, keeps the buffer until it has run: ``after``, an
+        output of it, is how the accounting learns when."""
         for data in tiles:
             if scratch.release(data):
-                self._res.release(data)
+                self._res.release(data, after)
                 self.stats["scratch_tiles_freed"] += 1
                 self._scratch_live -= scratch.nbytes(data)
 
@@ -1749,6 +1817,9 @@ class TpuDevice(Device):
         #: every task here knows which of its outputs are last versions
         last = True
         done: List[Task] = []
+        #: an output of the program, as it returned it: ready when the
+        #: chip has run it
+        after = outs[0] if len(outs) else None
         try:
             with pins.held(res.lock, "res_lock"):
                 if out_hooks is not None:
@@ -1792,7 +1863,7 @@ class TpuDevice(Device):
                     if home is None:
                         last = False
                     if task._tpu_scratch:
-                        self._release_scratch(task._tpu_scratch)
+                        self._release_scratch(task._tpu_scratch, after)
                     done.append(task)
                 if nexts:
                     res.next_uses(nexts)

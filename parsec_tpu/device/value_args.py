@@ -111,8 +111,8 @@ class FlowPlan:
     with the ``OUT`` bit and a known shape.  Part of the signature: the
     tasks of one chunk donate the same positions."""
 
-    __slots__ = ("steps", "nout", "reads", "out_hooks", "nbytes", "dtypes",
-                 "donates")
+    __slots__ = ("steps", "nout", "reads", "read_bytes", "out_hooks",
+                 "nbytes", "dtypes", "donates")
 
     def __init__(self, flows: Sequence[Any], donate: Sequence[int] = ()):
         """``flows``: the signature without its body key.  A tile is
@@ -125,6 +125,7 @@ class FlowPlan:
         donates: List[Tuple[int, int, int]] = []
         out_hooks: List[Any] = []
         nbytes = 0
+        read_bytes: List[Tuple[int, int]] = []
         dtypes: List[str] = []
         for pos, f in enumerate(flows):
             if f is None:
@@ -164,6 +165,8 @@ class FlowPlan:
                     tile = int(np.prod(shape)) * np.dtype(dtype).itemsize
                     # a tile read takes a buffer, a tile written a new one
                     nbytes += tile * ((how == READ) + bool(access & _OUT))
+                    if how == READ and not access & _OUT:
+                        read_bytes.append((pos, tile))
                 if access & _OUT:
                     out_hooks.append(so)
         self.steps = tuple(steps)
@@ -174,8 +177,16 @@ class FlowPlan:
         self.reads = tuple(s[1] for s in steps if s[0] == READ)
         self.out_hooks = tuple(out_hooks) if any(out_hooks) else None
         #: device bytes one task's tiles take, read and written (where
-        #: the signature says their shapes): what bounds a wave's chunk
+        #: the signature says their shapes): the most a task can cost its
+        #: wave's chunk (``TpuDevice._submit_wave``)
         self.nbytes = nbytes
+        #: ``(position, bytes)`` of the tiles ``nbytes`` counts that are
+        #: read and not written: what a task costs its chunk LESS where
+        #: such a tile was born on the device and is there still
+        #: (``TpuDevice._born_here``; a read-write tile keeps
+        #: counting twice: its input is what a donation gives back, and
+        #: the count does not know of one)
+        self.read_bytes = tuple(read_bytes)
         #: the tile flows' precisions in order, for the program's span
         #: (no comma: an event's arguments are a comma-separated list)
         self.dtypes = "/".join(dtypes)

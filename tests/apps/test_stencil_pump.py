@@ -20,6 +20,7 @@ from parsec_tpu.dsl import attach_plan
 from parsec_tpu.ops import stencil
 from parsec_tpu.ops.stencil import (reference_stencil, stencil_grid,
                                     stencil_taskpool)
+from parsec_tpu.profiling import pins
 
 ROOT = os.path.dirname(os.path.dirname(os.path.dirname(
     os.path.abspath(__file__))))
@@ -74,6 +75,12 @@ def pump(A, iters, dev=None, B=None, **bodies):
     return ran, ex, moved
 
 
+def programs_of(moved):
+    """Device programs of a solve: wave programs and tasks alone."""
+    return moved["wave_submits"] + moved["executed_tasks"] \
+        - moved["wave_tasks"]
+
+
 @needs_native
 @pytest.mark.parametrize("iters", [1, 2, 5])
 @pytest.mark.parametrize("mt,nt", GRIDS)
@@ -125,8 +132,15 @@ def test_one_sweep_in_place_is_refused():
 def test_a_solve_moves_the_grid_in_once_and_home_once(in_place,
                                                       budget_grids):
     """The counts of a solve, on a device whose budget is three grids
-    (generation 0 and two live generations: nothing has to leave) or
-    less (generation 0 has to make room, and it alone)."""
+    (generation 0 and two live generations: nothing has to leave but
+    for what the device has not let go of yet, which stays charged:
+    where the pump leads it, tiles of generation 0 go, for nothing) or
+    less (generation 0 has to make room, and it alone).  Under either a
+    chunk has room for two or three tiles (``Residency.chunk_limit``, a
+    sixteenth of the budget): a task of sweep 0, which reads the host's
+    tiles, goes out alone; from sweep 1 on the operands were born on the
+    device and cost the chunk nothing, the tile written does: two tasks
+    a program."""
     mt = nt = 4
     iters = 6
     grid = hashed_grid(mt, nt)
@@ -151,19 +165,81 @@ def test_a_solve_moves_the_grid_in_once_and_home_once(in_place,
         assert moved["evict_dirty"] == moved["evict_bytes_home"] == 0
         assert moved["reserve_gave_up"] == moved["unaccounted_tiles"] == 0
         # a wave of a generation hands a tile to several of its tasks
-        # (under the small budget a chunk is one task: nothing repeats)
-        if solve == 0:
-            assert 0 < moved["tile_args_repeated"] \
-                < moved["tile_args_passed"]
+        assert 0 < moved["tile_args_repeated"] < moved["tile_args_passed"]
+        if solve:
+            # sweep 0: sixteen programs of one task; then a sweep is the
+            # four interior tasks two by two, four edges of two, four
+            # corners alone
+            assert programs_of(moved) == 16 + (iters - 1) * 10
+            assert moved["chunks_cut_by_bytes"] == 7 + (iters - 1)
+            # five operands an interior task, four an edge task (a
+            # corner goes out alone: nobody cuts it)
+            assert moved["chunk_bytes_born_here"] \
+                == (iters - 1) * (4 * 5 + 8 * 4) * NB * NB * 4
         else:
-            assert moved["tile_args_repeated"] == 0
+            assert moved["chunks_cut_by_bytes"] == 0
         assert ex.stats["attach_plan_uncacheable"] == 0
         assert ex.stats["attach_plan_hits"] == solve
         np.testing.assert_allclose(
             B.to_array(), reference_stencil(grid.astype(np.float64), iters),
             rtol=0, atol=2e-6)
     # under the smaller budget generation 0 made room, clean
-    assert (moved["evict_clean"] > 0) == (budget_grids < 3)
+    # (of generation 0 alone, and never twice: ``evict_dirty`` is 0 and
+    # ``bytes_in`` one grid a solve, above)
+    assert moved["evict_clean"] <= mt * nt
+    assert moved["evict_clean"] > 0 or budget_grids == 3
+
+
+@needs_native
+def test_the_cells_counts_at_tiny_tiles():
+    """``stencil_pump_n32768`` replayed: 8 x 8 tiles, 100 sweeps, the
+    budget at the cell's share of the grid (14.37 of 4.29 GB), on a
+    device that has solved before.  The pump's order does not depend on
+    time, so these are the chip's counts: until PR 45 3,404 programs
+    (two tasks a program: the five resident operands counted as new
+    memory; the ledger's ``tasks_per_program`` 1.8801 and
+    ``repeated_args_pct`` 11.98 to the digit), now eight a program where
+    a sweep has them."""
+    mt = nt = 8
+    iters = 100
+    grid = hashed_grid(mt, nt)
+    attach_plan.clear()
+    _ran, ex, _moved = pump(stencil_grid(hashed_grid(1, 1), 1, 1), 2)
+    dev = ex.device
+    dev.hbm_budget = int(3.35 * grid.nbytes)
+    A = stencil_grid(grid, mt, nt)
+    waves = []
+
+    def note(es, p):
+        waves.append((p["n"], p["cut"]))
+    pins.subscribe("dev:wave_end", note)
+    try:
+        ran, ex, moved = pump(A, iters, dev=dev)
+    finally:
+        pins.unsubscribe("dev:wave_end", note)
+    assert ran == iters * mt * nt == moved["executed_tasks"]
+    # sweep 0, whose operands are the host's tiles, in programs of two
+    # (and of one where the first batches are split for the transfer);
+    # then 17 programs a sweep: the 36 interior tasks 8 + 8 + 8 + 8 + 4,
+    # four edges of 6 as 4 + 2, four corners alone
+    assert programs_of(moved) == 1723  # 3.71 tasks a program
+    assert sorted(set(waves)) == [(1, "tasks"), (2, "bytes"), (2, "tasks"),
+                                  (4, "tasks"), (8, "bytes"), (8, "tasks")]
+    assert waves.count((8, "bytes")) + waves.count((8, "tasks")) \
+        == 4 * (iters - 1) - 1
+    assert moved["chunks_cut_by_bytes"] == 315
+    assert moved["tile_args_passed"] == 28800
+    assert moved["tile_args_repeated"] == 10372
+    # and everything else a solve is held to, as before
+    assert moved["bytes_in"] == moved["bytes_out"] == grid.nbytes
+    assert moved["scratch_tiles_born"] == (iters - 1) * mt * nt \
+        == moved["scratch_tiles_freed"]
+    assert moved["scratch_bytes_in"] == moved["scratch_bytes_out"] == 0
+    assert moved["evict_dirty"] == moved["evict_bytes_home"] == 0
+    assert moved["reserve_gave_up"] == moved["unaccounted_tiles"] == 0
+    np.testing.assert_allclose(
+        A.to_array(), reference_stencil(grid.astype(np.float64), iters),
+        rtol=0, atol=2e-6)
 
 
 @pytest.mark.parametrize("mt,nt", GRIDS)
