@@ -70,8 +70,11 @@ class CompoundTaskpool(Taskpool):
         self.context.add_taskpool(member)
 
 
-def compose(a: Taskpool, b: Taskpool) -> CompoundTaskpool:
-    """Reference ``parsec_compose(compound.c:96)``: folds compounds."""
-    if isinstance(a, CompoundTaskpool):
-        return a.add(b)
-    return CompoundTaskpool(a, b)
+def compose(a: Taskpool, b: Taskpool, *more: Taskpool) -> CompoundTaskpool:
+    """Reference ``parsec_compose(compound.c:96)``: folds compounds
+    (``compose(a, b, c)`` is ``compose(compose(a, b), c)``)."""
+    if not isinstance(a, CompoundTaskpool):
+        a = CompoundTaskpool(a)
+    for tp in (b, *more):
+        a.add(tp)
+    return a

@@ -160,7 +160,8 @@ class Residency:
         for k in ("evictions", "evict_clean", "evict_dirty",
                   "evict_bytes_home", "evict_batches", "evict_next_use",
                   "evict_never_again", "evict_cancelled", "restaged_tiles",
-                  "reserve_gave_up", "unaccounted_tiles", "lead_waits"):
+                  "reserve_gave_up", "unaccounted_tiles", "lead_waits",
+                  "handed_restaged", "owed_home_bytes"):
             stats.setdefault(k, 0)
         self._span = span or (lambda name, **info: contextlib.nullcontext())
         self.lock = threading.RLock()
@@ -202,6 +203,14 @@ class Residency:
         #: tiles an eviction dropped, until they are staged in again
         #: (``restaged_tiles``)
         self._evicted: set = set()
+        #: between the pools of a compound (``TpuDevice.pool_boundary``):
+        #: the ids of the tiles that a pool which has ENDED wrote (one
+        #: staged in from the host again counts in ``handed_restaged``),
+        #: and of the tiles that a pool still to come rewrites (a copy
+        #: home of one counts its bytes in ``owed_home_bytes``: the
+        #: version is not a result)
+        self.handed: frozenset = frozenset()
+        self.owed: frozenset = frozenset()
         self._warned: set = set()
         #: scratch tiles let go while the program that read them last may
         #: still run, oldest first: [an output of that program, the
@@ -711,6 +720,17 @@ class Residency:
         if data.data_id in self._evicted:
             self._evicted.discard(data.data_id)
             self.stats["restaged_tiles"] += 1
+        if data.data_id in self.handed:
+            self.stats["handed_restaged"] += 1
+
+    def owed_home(self, datas: Iterable[Data]) -> None:
+        """``datas`` are about to be copied home: the bytes of those that
+        a later pool of the compound rewrites (``owed``) are counted."""
+        owed = self.owed
+        if owed:
+            self.stats["owed_home_bytes"] += sum(
+                self._held.get(d.data_id, 0) for d in datas
+                if d.data_id in owed)
 
     def drop(self, data: Data, *, evicted: bool = True) -> None:
         """Detach ``data``'s copy here and release its slot."""

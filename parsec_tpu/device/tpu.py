@@ -62,7 +62,7 @@ import contextlib
 import threading
 import time
 import weakref
-from typing import Any, Deque, Dict, List, Tuple
+from typing import Any, Deque, Dict, Iterable, List, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -1677,6 +1677,7 @@ class TpuDevice(Device):
         home, or after somebody rewrote it, is the residency's own
         check at the drop.  Returns the microseconds waited."""
         t0 = time.perf_counter_ns()
+        self._res.owed_home(victims)
         self._wb.writeback_batch(victims, self._span_pool, self._span_batch)
         return (time.perf_counter_ns() - t0) // 1000
 
@@ -1770,6 +1771,7 @@ class TpuDevice(Device):
         com = self._wb_committer()
         if com is None or not going:
             return 0
+        self._res.owed_home(going)
         com.enqueue_all(going, self._span_pool, self._span_batch, last=last)
         return len(going)
 
@@ -1920,6 +1922,30 @@ class TpuDevice(Device):
                     self._h2d.one(data)
         else:
             super().data_advise(data, advice)
+
+    def pool_boundary(self, handed: Iterable[Data],
+                      owed: Iterable[Data]) -> Tuple[int, int]:
+        """Between two pools that run one after the other over this
+        device's residency (a compound's members,
+        ``NativeExecutor._run_members``; ``(), ()`` before the first and
+        after the last).  ``handed``: the tiles that the pools which have
+        ended wrote and a pool still to come names.  Nothing is done to
+        them: a dirty tile whose pool has ended stays resident and the
+        newest version, the next pool's staging walk finds it and may
+        donate it, as it would its own pool's.  Returns how many of them
+        are so, and their bytes; from now on one that is staged in from
+        the host counts in ``stats["handed_restaged"]``.  ``owed``: the
+        tiles that a pool still to come rewrites; their tasks were bound
+        with no home for them, and the bytes of one that goes home all
+        the same (an eviction's victim) count in
+        ``stats["owed_home_bytes"]``."""
+        res = self._res
+        handed = list(handed)
+        with pins.held(res.lock, "res_lock"):
+            res.handed = frozenset(d.data_id for d in handed)
+            res.owed = frozenset(d.data_id for d in owed)
+            here = [n for n in map(res.resident_bytes, handed) if n]
+        return len(here), sum(here)
 
     def drop_residency(self, data: Data) -> None:
         """Release ``data``'s residency slot WITHOUT a host write-back:

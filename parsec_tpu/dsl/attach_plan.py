@@ -75,7 +75,7 @@ class AttachPlan:
         "key", "ptg_name", "classes", "device_class", "has_cpu_bodies",
         "nodes", "tasks", "succs", "tiles", "tile_users",
         "node_of", "native", "native_prio", "edge_pred", "edge_succ",
-        "roots", "fused", "next_use", "next_at")
+        "roots", "fused", "next_use", "next_at", "written")
 
     def __init__(self):
         #: the fingerprint it is stored under; None = bound, never stored
@@ -103,6 +103,10 @@ class AttachPlan:
         self.tiles: Tuple[Tuple, ...] = ()
         #: per slot the users a scratch tile is born with (0: has a home)
         self.tile_users: Tuple[int, ...] = ()
+        #: the slots of the collection tiles that some task writes: what
+        #: a pool composed BEFORE this one must not send home
+        #: (``NativeExecutor._attach_members``)
+        self.written: Tuple[int, ...] = ()
         #: per task its native node
         self.node_of: Tuple[int, ...] = ()
         #: per native node the task's position, or ``~i`` for
@@ -490,6 +494,11 @@ def build_plan(tp, g: TaskGraph, regions=(),
     plan.fused = tuple(fused_rows)
     plan.tiles = tuple(tiles)
     plan.tile_users = tuple(users)
+    out = int(AccessMode.OUT)
+    plan.written = tuple(sorted({
+        s for tid, row in zip(order, rows)
+        for s, f in zip(row[3], cls_flows[tid[0]])
+        if s >= 0 and int(f.mode) & out and tiles[s][0] == "data"}))
 
     # contracted edges are DEDUPLICATED: add_dep is symmetric (one
     # in-degree per declared edge, one release per succs entry), so

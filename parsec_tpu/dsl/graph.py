@@ -209,8 +209,9 @@ def capture(tp: PTGTaskpool, ranks: Optional[Iterable[int]] = None) -> TaskGraph
         loc = tid[1]
         env = pc.env_of(loc, consts)
         for f in pc.flows:
-            # input source
-            src = pc.active_input(f, env)
+            # input source (a CTL flow carries no data and may gather
+            # over ranges: its predecessors' output deps are its edges)
+            src = None if f.mode == CTL else pc.active_input(f, env)
             if src is None or isinstance(src, _NoneRef):
                 node.flow_sources[f.name] = ("new",) if (f.mode & AccessMode.OUT) else None
             elif isinstance(src, _NewRef):

@@ -53,6 +53,7 @@ from typing import Any, Callable, Dict, List, Optional, Tuple
 
 import numpy as np
 
+from ..core.compound import CompoundTaskpool
 from ..core.lifecycle import AccessMode, HookReturn, DEV_CPU, DEV_TPU
 from ..core.sched.wdrr import QUANTUM as WDRR_QUANTUM
 from ..core.task import Chore, Task, TaskClass
@@ -100,12 +101,16 @@ def _new_stats() -> Dict[str, int]:
     """An executor's counters.  In pump mode ``trampoline_entries`` and
     ``completion_callbacks`` MUST stay 0 (every per-task interpreter
     entry increments one of them); ``attach_plan_*`` say how the attach
-    came by its plan (dsl/attach_plan.py)."""
+    came by its plan (dsl/attach_plan.py); ``member*`` are a compound's
+    (:meth:`NativeExecutor._run_members`), 0 of a single pool."""
     return {"trampoline_entries": 0, "completion_callbacks": 0,
             "pop_batches": 0, "done_batches": 0, "pumped_tasks": 0,
             "events_drained": 0, "prefetched_batches": 0,
             "attach_plan_hits": 0, "attach_plan_misses": 0,
-            "attach_plan_uncacheable": 0}
+            "attach_plan_uncacheable": 0,
+            "members_run": 0, "member_kept_tiles": 0,
+            "member_kept_bytes": 0, "member_home_bytes": 0,
+            "member_restaged_tiles": 0}
 
 
 @contextlib.contextmanager
@@ -268,7 +273,7 @@ def _pump_failure(shims) -> Optional[str]:
 
 def _pump_loop(ng, dev, pump_index: Dict[int, Any], stats: Dict[str, int],
                shims, ev: Optional[_EventDrain] = None,
-               retire_cb=None, pool: int = 0) -> int:
+               retire_cb=None, pool: int = 0, first_submit=None) -> int:
     """The zero-interpreter hot loop, shared by :class:`NativeExecutor`
     and :class:`NativeServeExecutor`.  Per iteration: ONE ``pop_batch``
     ctypes call returns up to ``runtime_native_drain`` ready native ids,
@@ -299,7 +304,9 @@ def _pump_loop(ng, dev, pump_index: Dict[int, Any], stats: Dict[str, int],
     ``pump:events``; ``dev:submit_batch`` is the device module's),
     carrying ``pool`` (0 when several pools share the pump), ``rank`` and
     the batch's number ``batch``, which the transfer lane's
-    ``dev:stage_in`` repeats on its thread."""
+    ``dev:stage_in`` repeats on its thread.  ``first_submit`` is called
+    once, before the first batch is handed to the device (a compound's
+    ``pump:member_gap`` ends there)."""
     import ctypes
     from collections import deque
 
@@ -394,6 +401,9 @@ def _pump_loop(ng, dev, pump_index: Dict[int, Any], stats: Dict[str, int],
                     # a job without tiles is nothing to wait for)
                     if job.tiles:
                         job.wait()  # logs prestage errors; submit restages
+            if first_submit is not None:
+                first_submit()
+                first_submit = None
             dev.submit_batch(batch, batch_no=b)
             why = _pump_failure(shims)
             if why is not None:
@@ -441,12 +451,26 @@ class NativeExecutor:
     CPU body through the Data staging discipline (mixed DAGs stay
     coherent across host/device copies).  Pass ``device=`` to reuse one
     device instance (and its jit cache) across executors.
+
+    A :class:`~parsec_tpu.core.compound.CompoundTaskpool` of unstarted PTG
+    taskpools (``compose(a, b, c)``) runs its members in order, member
+    *i+1*'s first task after member *i*'s last has retired
+    (``parsec_compose``), under ONE device, residency and jit cache: every
+    member is planned (each by its own attach-plan key) and bound before
+    :meth:`run`, a member's end neither detaches nor flushes, and of all
+    members only the versions that no LATER member rewrites go home, as
+    they become final.  A tile that member *i* wrote stays on the device,
+    dirty and the newest version, until member *i+1*'s staging walk finds
+    it there.  ``stats["member*"]`` and the ``pump:member`` /
+    ``pump:member_gap`` spans say whether that held (``docs/TRACING.md``).
+    :meth:`run` returns the tasks run over all members; :meth:`close`
+    does what it does for one pool, once.
     """
 
     def __init__(self, tp: PTGTaskpool, *, graph: Optional[TaskGraph] = None,
                  native_device: bool = False, device=None,
                  fusion: Optional[str] = None,
-                 _shared_graph=None, _tenant: int = 0):
+                 _shared_graph=None, _tenant: int = 0, _compound=None):
         from .. import native
 
         if not native.available():
@@ -458,8 +482,20 @@ class NativeExecutor:
         self.device = device
         #: the device is this executor's to detach when nobody closed it
         self._own_device = device is None
+        #: a compound's member: ``(the compound's executor, the ids of the
+        #: tiles that later members rewrite)``; its counters are the
+        #: compound's, its device the compound's to detach
+        self._compound = _compound
         #: control-plane counters (the zero-entry pin reads them)
-        self.stats: Dict[str, int] = _new_stats()
+        self.stats: Dict[str, int] = _new_stats() if _compound is None \
+            else _compound[0].stats
+        #: a compound's executor: one executor a member, in order
+        self._members: List["NativeExecutor"] = []
+        #: a member's collection tiles (device path): the ones its tasks
+        #: write, and all it names; what a compound counts its
+        #: hand-overs by
+        self._written: List[Any] = []
+        self._touched: List[Any] = []
         #: serve mode (NativeServeExecutor): build into ITS shared native
         #: graph under this tenant id instead of owning one
         self._shared_graph = _shared_graph
@@ -488,6 +524,16 @@ class NativeExecutor:
         #: is the per-task device enqueue, which CPU bodies don't pay).
         self._regions: List[Any] = []
         self._pool_shim: Optional[_NativePoolShim] = None
+        if isinstance(tp, CompoundTaskpool):
+            if graph is not None or _shared_graph is not None:
+                raise ValueError("a compound takes no captured graph and "
+                                 "is nobody's tenant: its members are "
+                                 "captured one by one")
+            self._ng = None
+            if self.native_device and device is None:
+                self.device = self._make_device()
+            self._attach_members(tp, fusion)
+            return
         if self.native_device:
             if device is None:
                 self.device = self._make_device()
@@ -499,7 +545,7 @@ class NativeExecutor:
                     plan, how = self._plan_for(tp, graph, fusion)
                 with pins.span("attach:bind", pool=tp.taskpool_id, rank=0), \
                         _collector_paused():
-                    self._bind(plan)
+                    self._bind(plan, _compound[1] if _compound else ())
                 sp.note(tasks=len(plan.tasks), regions=len(plan.fused),
                         plan=how)
             else:
@@ -511,7 +557,40 @@ class NativeExecutor:
                 self._build()
                 sp.note(tasks=len(self.graph.nodes), regions=0)
 
-    # -- the device path: an attach plan, bound to this pool's tiles -----
+    # -- a compound: one executor a member, bound last member first ------
+    def _attach_members(self, tp: CompoundTaskpool,
+                        fusion: Optional[str]) -> None:
+        """One executor a member of ``tp``, all over this executor's
+        device and counters.  The LAST member is bound first: what a
+        member sends home is what its plan says less every tile that a
+        later member writes again, and that is known once the later
+        members' plans are bound to their tiles."""
+        def flat(pool):
+            for m in pool.members:
+                if isinstance(m, CompoundTaskpool):
+                    yield from flat(m)
+                else:
+                    yield m
+
+        pools = list(flat(tp))
+        for m in pools:
+            if not isinstance(m, PTGTaskpool):
+                raise TypeError(
+                    "a compound on the native engine is made of PTG "
+                    f"taskpools: {m!r} is a {type(m).__name__}")
+        held: set = set()
+        members: List[NativeExecutor] = []
+        try:
+            for m in reversed(pools):
+                members.append(NativeExecutor(
+                    m, native_device=self.native_device, device=self.device,
+                    fusion=fusion, _compound=(self, frozenset(held))))
+                held.update(d.data_id for d in members[-1]._written)
+        finally:
+            # (a member that could not be built leaves the others to
+            # close())
+            self._members = members[::-1]
+
     def _plan_for(self, tp: PTGTaskpool, graph: Optional[TaskGraph],
                   fusion: Optional[str]):
         """This pool's native nodes, tasks and fused regions (the
@@ -619,12 +698,16 @@ class NativeExecutor:
             t = self._new_tiles[srckey] = np.zeros(shape, dtype)
         return t
 
-    def _bind(self, plan) -> None:
+    def _bind(self, plan, held=()) -> None:
         """Bind ``plan`` to this pool's tiles (the ``attach:bind`` span):
         one ``data_of`` a distinct tile and one ``scratch.new`` a ``NEW``
         chain, one task object a task, the native graph from the plan's
         arrays in one call.  The first solve of a shape and the hundredth
-        run this same code; nothing here reads a dependency expression."""
+        run this same code; nothing here reads a dependency expression.
+        ``held``: the ids of the tiles that a later member of this pool's
+        compound writes again.  The plan's home set is the shape's, the
+        same whether the pool runs alone or composed; what a TASK sends
+        home is that less the held tiles, decided here, tile by tile."""
         from ..device import scratch
         from .attach_plan import CTL_FLOW
 
@@ -676,6 +759,10 @@ class NativeExecutor:
             d = scratch.new(("native_new",) + tuple(srckey[1:]), *spec)
             scratch.add_users(d, users)
             datas.append(d)
+        if self._compound is not None:
+            self._written = [datas[s] for s in plan.written]
+            self._touched = [d for d, srckey in zip(datas, plan.tiles)
+                             if srckey[0] == "data"]
 
         # the native graph, edges and all, uncommitted
         n = self._n_native = len(plan.native)
@@ -746,6 +833,9 @@ class NativeExecutor:
                     for s, m in zip(slots, modes)]
                 task.body_args += values
                 task.body_args += gvals
+                if held and home:
+                    home = tuple(p for p in home
+                                 if datas[slots[p]].data_id not in held)
                 task._tpu_home = home
                 task._tpu_donate = donate
                 if wbs:
@@ -1015,22 +1105,28 @@ class NativeExecutor:
 
         return body
 
-    def run(self, nthreads: int = 4) -> int:
+    def run(self, nthreads: int = 4, _first_submit=None) -> int:
         """Execute to quiescence; returns the number of tasks run.
         Honors the ``runtime_vpmap`` MCA param: workers split into VP
         locality domains and the native steal path prefers same-VP
-        victims (reference lfq hierarchy)."""
+        victims (reference lfq hierarchy).  ``_first_submit`` (a
+        compound's, for its member): called once before the first batch
+        reaches the device."""
+        if self._members:
+            return self._run_members(nthreads)
         bodies = self._bodies
         self._apply_vpmap(nthreads)
         if pins.active(pins.RELEASE_DEPS_END):
             self._emit_trace_edges()
+        if _first_submit is not None and not self._pump:
+            _first_submit()  # (no pump: the run itself is the hand-over)
         if not self.native_device:
             def trampoline(_task_id: int, user_tag: int) -> None:
                 bodies[user_tag]()
 
             n = self._ng.run(trampoline, nthreads=nthreads)
         elif self._pump:
-            n = self._run_pump()
+            n = self._run_pump(_first_submit)
         else:
             def atrampoline(_task_id: int, user_tag: int):
                 return bodies[user_tag]()
@@ -1054,7 +1150,65 @@ class NativeExecutor:
         # taskpool's task count; without fusion the two are equal)
         return len(self.graph.nodes)
 
-    def _run_pump(self) -> int:
+    def _run_members(self, nthreads: int) -> int:
+        """A compound's run: its members in order, each to quiescence
+        before the next one's first task (``parsec_compose``), nothing
+        detached or flushed in between.  ``pump:member`` is one member's
+        run; its first child from the second member on is
+        ``pump:member_gap``, which lasts until the member hands its first
+        batch to the device and carries what the device kept for it and
+        its successors (``kept_tiles``, ``kept_bytes``: the tiles that
+        the members so far wrote and a later one names, where their
+        newest version is on the device).  On the device path the device
+        counts, while the compound runs, what should not happen: an owed
+        tile's copy home, a handed-over tile staged in from the host
+        (``TpuDevice.pool_boundary``)."""
+        members = self._members
+        dev = self.device if self.native_device else None
+        stats = self.stats
+        faults = ("owed_home_bytes", "handed_restaged")
+        base = [dev.stats[k] for k in faults] if dev is not None else None
+        written: Dict[int, Any] = {}
+        #: the open ``pump:member_gap``, closed by the member's first
+        #: submit (or by its end, where it submits nothing)
+        gap = contextlib.ExitStack()
+        total = 0
+        try:
+            for i, m in enumerate(members):
+                pool = m.taskpool.taskpool_id
+                with pins.span("pump:member", member=i, pool=pool, rank=0,
+                               tasks=len(m.graph.nodes)):
+                    if i:
+                        sp = gap.enter_context(pins.span(
+                            "pump:member_gap", member=i, pool=pool, rank=0))
+                    if dev is not None:
+                        # what the members so far leave for this one and
+                        # the ones after it, and what those write again
+                        named = {d.data_id for later in members[i:]
+                                 for d in later._touched}
+                        kept = dev.pool_boundary(
+                            [d for did, d in written.items()
+                             if did in named],
+                            [d for later in members[i + 1:]
+                             for d in later._written])
+                        if i:
+                            stats["member_kept_tiles"] += kept[0]
+                            stats["member_kept_bytes"] += kept[1]
+                            sp.note(kept_tiles=kept[0], kept_bytes=kept[1])
+                    total += m.run(nthreads, _first_submit=gap.close)
+                    gap.close()
+                stats["members_run"] += 1
+                written.update((d.data_id, d) for d in m._written)
+        finally:
+            gap.close()
+            if dev is not None:
+                dev.pool_boundary((), ())
+                home, restaged = (dev.stats[k] for k in faults)
+                stats["member_home_bytes"] += home - base[0]
+                stats["member_restaged_tiles"] += restaged - base[1]
+        return total
+
+    def _run_pump(self, first_submit=None) -> int:
         """Drive the zero-interpreter lifecycle for this executor's DAG:
         see :func:`_pump_loop`.  Between graph attach (commit) and drain
         (quiescence) NO per-task Python runs — the trampoline and
@@ -1090,7 +1244,7 @@ class NativeExecutor:
 
         n = _pump_loop(ng, self.device, self._pump_index, self.stats,
                        (self._pool_shim,), ev, retire_cb,
-                       pool=tp.taskpool_id)
+                       pool=tp.taskpool_id, first_submit=first_submit)
         if capture is not None:
             self._certify_drain(capture)
         return n
@@ -1182,13 +1336,20 @@ class NativeExecutor:
             self._ng = None
             return
         ng = getattr(self, "_ng", None)
-        if ng is None:
+        members = getattr(self, "_members", ())
+        if ng is None and not members:
             # already closed (or never built): idempotent — ``__del__``
             # calls this again, possibly after the collector finalized
             # parts of a shared device the first call already flushed
             return
-        ng.close()
-        self._ng = None
+        for m in members:
+            m.close()  # its native graph; the device is this executor's
+        self._members = []
+        if ng is not None:
+            ng.close()
+            self._ng = None
+        if getattr(self, "_compound", None) is not None:
+            return
         dev = getattr(self, "device", None)
         if dev is not None:
             # flush dirty device tiles home so host-side readers (e.g.

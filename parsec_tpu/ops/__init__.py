@@ -14,6 +14,7 @@ from .attention import (
 from .cholesky import cholesky_dtd, cholesky_ptg, run_cholesky
 from .lu import lu_ptg, run_lu
 from .panel_chol import PanelCholesky, WholeCholesky
+from .inverse import lauum_ptg, poinv, trtri_ptg
 from .segmented_chol import SegmentedCholesky, segmented_cholesky_ptg
 from .segmented_lu import SegmentedLU, segmented_lu_ptg
 from .segmented_qr import SegmentedQR, segmented_qr_ptg
@@ -29,4 +30,5 @@ __all__ = ["tiles", "cholesky_ptg", "cholesky_dtd", "run_cholesky", "lu_ptg", "r
            "SegmentedCholesky", "segmented_cholesky_ptg",
            "SegmentedLU", "segmented_lu_ptg",
            "SegmentedQR", "segmented_qr_ptg",
-           "qr_ptg", "run_qr", "QRTree", "flat_tree"]
+           "qr_ptg", "run_qr", "QRTree", "flat_tree",
+           "trtri_ptg", "lauum_ptg", "poinv"]

@@ -26,6 +26,7 @@ ALL = [
     "ex12_qr_lu.py",
     "ex13_segmented_native_dist.py",
     "ex14_round4_features.py",
+    "ex15_spd_inverse.py",
     os.path.join("dtd", "dtd_helloworld.py"),
     os.path.join("dtd", "dtd_hello_arg.py"),
     os.path.join("dtd", "dtd_untied.py"),

@@ -53,6 +53,7 @@ bench_testlib.TINY.setdefault("pump_stencil", "tiny_pump_stencil")
 bench_testlib.TINY.setdefault("pump_mle", "tiny_pump_mle")
 bench_testlib.TINY.setdefault("dtd", "tiny_dtd")
 bench_testlib.TINY.setdefault("pump_geqrf_hqr", "tiny_pump_geqrf_hqr")
+bench_testlib.TINY.setdefault("pump_poinv", "tiny_pump_poinv")
 
 
 def pytest_configure(config):
