@@ -240,7 +240,13 @@ class Task:
         self._tpu_scratch: Tuple = ()
         #: positions in ``body_args`` of the outputs that go home (the
         #: device module's write-back committer takes only these); None
-        #: where whoever built the task does not know: then every one
+        #: where whoever built the task does not know: then every one.
+        #: Three builders say: the pump, from the captured graph's plan
+        #: (``dsl/native_exec.py``: the DAG's last versions); a PTG pool
+        #: on the ``Context`` route, from the task's own output
+        #: dependencies (``PTGTaskpool._home_rule``: the versions no
+        #: successor overwrites); ``insert_task`` (``dsl/dtd.py``: ``()``,
+        #: a tile goes home at its flush)
         self._tpu_home: Optional[Tuple[int, ...]] = None
         #: positions in ``body_args`` of the read-write flows whose INPUT
         #: version this task is the only consumer of (no other task reads

@@ -180,9 +180,11 @@ class TpuDevice(Device):
         #: who committed the outputs: chunks by the wave epilog, tasks
         #: one by one (together: ``executed_tasks``); and the pump's
         #: batches whose tiles were all resident, with nothing in them
-        #: for the transfer lane to move
+        #: for the transfer lane to move; and the tasks committed whose
+        #: builder did not say which outputs are last versions
+        #: (``Task._tpu_home`` None: every output went to the committer)
         self.stats.update(wave_commits=0, task_commits=0,
-                          prestage_skipped=0)
+                          prestage_skipped=0, commits_home_unknown=0)
         #: copies home started at hand-over, for a version the task's
         #: builder knows to be the tile's last, and those of them that
         #: were the version the committer's drain collected
@@ -1864,6 +1866,7 @@ class TpuDevice(Device):
                              else going).append(data)
                     if home is None:
                         last = False
+                        self.stats["commits_home_unknown"] += 1
                     if task._tpu_scratch:
                         self._release_scratch(task._tpu_scratch, after)
                     done.append(task)

@@ -168,6 +168,19 @@ class _Expr:
         return f"_Expr({self.src!r})"
 
 
+#: the constants of a pool whose value no body can change under a guard
+_SCALARS = (int, float, str, type(None), np.integer, np.floating)
+
+
+def _reads_only(expr: _Expr, names) -> bool:
+    """True when ``expr`` reads nothing but ``names``: no attribute of
+    an object, no call of a pool's function, no nested scope (a
+    comprehension, a lambda) that could read anything else."""
+    code = expr.code
+    return all(n in names for n in code.co_names) \
+        and not any(hasattr(c, "co_code") for c in code.co_consts)
+
+
 def _split_top(s: str, sep: str) -> List[str]:
     """Split on ``sep`` at paren/bracket depth 0."""
     parts: List[str] = []
@@ -1117,7 +1130,118 @@ class PTGTaskpool(Taskpool):
                     priority=pc.priority_of(locals_, self.constants))
 
     # -- data resolution -------------------------------------------------
+    def _home_rule(self, pc: PTGTaskClass):
+        """Which outputs of a ``pc`` task are LAST versions on this rank
+        (``Task._tpu_home``: the device module sends only those home),
+        as far as the class alone says it: ``(always, guarded)``.  A
+        version nobody overwrites is the tile's last: a writable flow is
+        NOT home where an active output dependency hands the tile to a
+        successor that exists and writes it (local or remote: the
+        writer's version supersedes this one and finds its own way
+        home), and every other writable flow is, whether or not the PTG
+        spells a terminal ``-> A(m, n)``.  ``always``: the positions in
+        ``body_args`` (a flow's index) of the flows none of whose
+        dependencies can name a writer; ``guarded``: for the others,
+        ``(position, ((guard, then, otherwise), ...))`` with a branch
+        that names a writer as ``(its class, its arguments, the
+        arguments as ONE expression where none is a range)`` and any
+        other as None, decided a task by :meth:`_last_versions`.
+        ``always`` is None where a dependency that can name a writer
+        reads anything but the task's key and the pool's scalar
+        constants (dynamic guards, :meth:`_is_startup`): its value at
+        ``prepare_input`` is not its value at release, the class cannot
+        know, and every version goes to the committer as it did."""
+        classes = self.ptg.classes
+        reads: List[_Expr] = []
+
+        def writer(t):
+            spc = classes.get(t.class_name) \
+                if isinstance(t, _TaskRef) else None
+            if spc is None or not any(
+                    sf.name == t.flow_name and sf.mode != CTL
+                    and sf.mode & AccessMode.OUT for sf in spc.flows):
+                return None
+            reads.extend(e for a in t.args for e in (a.lo, a.hi, a.step)
+                         if e is not None)
+            one = None
+            if all(a.hi is None for a in t.args):
+                one = _Expr("(%s)" % "".join(
+                    f"{a.lo.src}, " for a in t.args))
+            return spc, t.args, one
+
+        always: List[int] = []
+        guarded: List[Tuple[int, Tuple]] = []
+        for f in pc.flows:
+            if f.mode == CTL or not (f.mode & AccessMode.OUT):
+                continue
+            deps = []
+            for dep in f.deps_out:
+                then, otherwise = writer(dep.then), writer(dep.otherwise)
+                if then is not None or otherwise is not None:
+                    deps.append((dep.guard, then, otherwise))
+                    if dep.guard is not None:
+                        reads.append(dep.guard)
+            if deps:
+                guarded.append((f.index, tuple(deps)))
+            else:
+                always.append(f.index)
+        if reads:
+            static = self._static_names(pc)
+            if not all(_reads_only(e, static) for e in reads):
+                return None, ()
+        return tuple(always), tuple(guarded)
+
+    def _static_names(self, pc: PTGTaskClass) -> set:
+        """The names whose value is one and the same whenever an
+        expression of a ``pc`` task is evaluated: the scalar constants
+        of the pool, the task's parameters, and the definitions that
+        read only those."""
+        names = set(_SAFE_BUILTINS)
+        names.update(k for k, v in self.constants.items()
+                     if isinstance(v, _SCALARS))
+        for name, expr, is_param in pc.decls:
+            if is_param or all(_reads_only(e, names)
+                               for e in (expr.lo, expr.hi, expr.step)
+                               if e is not None):
+                names.add(name)
+            else:
+                names.discard(name)  # (a definition shadows a constant)
+        return names
+
+    def _last_versions(self, always: Tuple[int, ...], guarded: Tuple,
+                       env: Dict[str, Any]) -> Tuple[int, ...]:
+        """``Task._tpu_home`` of the task whose environment is ``env``:
+        ``always`` and, of the ``guarded`` flows (:meth:`_home_rule`),
+        those whose active output dependencies name no writer that
+        exists — the evaluation :meth:`_release_deps_core` makes, in the
+        same environment, and through the same memo: what is asked here
+        is not worked out again there.  A ranged dependency whose range
+        is empty hands the tile to nobody."""
+        consts, memo = self.constants, self._exists_memo
+        home = always
+        for pos, deps in guarded:
+            for guard, then, otherwise in deps:
+                w = then if guard is None or guard(env) else otherwise
+                if w is None:
+                    continue
+                spc, args, one = w
+                locs = one(env) if one is not None else None
+                if locs is None or range in map(type, locs):
+                    there = any(spc.instance_exists(ls, consts, memo)
+                                for ls in _expand_args(args, env))
+                else:
+                    there = memo.get((spc.name, locs))
+                    if there is None:
+                        there = spc.instance_exists(locs, consts, memo)
+                if there:
+                    break  # superseded: a later task's to send home
+            else:
+                home += (pos,)
+        return home
+
     def _make_prepare_input(self, pc: PTGTaskClass):
+        home_always, home_guarded = self._home_rule(pc)
+
         def prepare_input(es, task: Task) -> HookReturn:
             env = pc.env_of(task.locals, self.constants)
             specs: List[Tuple[str, Any, AccessMode]] = []
@@ -1148,6 +1272,9 @@ class PTGTaskpool(Taskpool):
             for name in pc.param_names + pc.def_names + pc.body_globals:
                 specs.append(("value", env[name], AccessMode.VALUE))
             task.body_args = specs
+            task._tpu_home = self._last_versions(
+                home_always, home_guarded, env) if home_guarded \
+                else home_always
             return HookReturn.DONE
 
         return prepare_input
@@ -1285,6 +1412,7 @@ class PTGTaskpool(Taskpool):
         env = pc.env_of(locals_, self.constants)
         repo = self.repos[pc.name]
         fusion = self._fusion
+        memo = self._exists_memo
         entry = None
         nb_consumers = 0
         myrank = self.context.rank if self.context else 0
@@ -1312,9 +1440,11 @@ class PTGTaskpool(Taskpool):
                     continue
                 succ_pc = self.ptg.classes[t.class_name]
                 for locs in _expand_args(t.args, env):
-                    if len(locs) != len(succ_pc.param_names):
-                        continue
-                    if not succ_pc.valid(locs, self.constants):
+                    # (asked once a successor, whoever asks first: its
+                    # other producers, its consumers' goals, and
+                    # _last_versions for a writer)
+                    if not succ_pc.instance_exists(locs, self.constants,
+                                                   memo):
                         continue
                     if origin_region is not None \
                             and (t.class_name, locs) in origin_region:
