@@ -94,6 +94,11 @@ def _placeholders_at(dev_args) -> Tuple[int, ...]:
                  if isinstance(a, jax.ShapeDtypeStruct))
 
 
+def _no_lap(name: str) -> None:
+    """``lap`` of a span that is not there (a commit from the poll, a
+    task submitted by a caller that opened none)."""
+
+
 def _pool_of(task: Task) -> int:
     """The ``pool`` a span of ``task`` carries: its taskpool's id (0 for
     a stand-in pool that has none)."""
@@ -185,6 +190,9 @@ class TpuDevice(Device):
         #: (``Task._tpu_home`` None: every output went to the committer)
         self.stats.update(wave_commits=0, task_commits=0,
                           prestage_skipped=0, commits_home_unknown=0)
+        #: wave programs called, and outputs that a flow's custom
+        #: ``stage_out`` hook transformed before their commit
+        self.stats.update(wave_submits=0, custom_stage_out=0)
         #: copies home started at hand-over, for a version the task's
         #: builder knows to be the tile's last, and those of them that
         #: were the version the committer's drain collected
@@ -265,6 +273,12 @@ class TpuDevice(Device):
         #: can run (:meth:`keep_released`), until the manager hands it
         #: over between two drains; the manager's thread alone touches it
         self._released: List[Task] = []
+        #: what the manager's loop read of its own time since the last
+        #: task span, while a profiler session runs: ``hand_us``,
+        #: ``handed``, ``units_us``, for the first ``dev:wave`` /
+        #: ``dev:submit_one`` of the drain to carry (empty otherwise,
+        #: and on the pump path)
+        self._drain_stamp: Dict[str, Any] = {}
         #: eager completion: a single-controller JAX device queue already
         #: orders computations by data dependencies, so successor release
         #: does not need to wait for device events — the runtime completes
@@ -438,17 +452,19 @@ class TpuDevice(Device):
         self.stats["handed_sched"] += 1
         return False
 
-    def _hand_over(self, es) -> None:
+    def _hand_over(self, es) -> int:
         """The tasks this thread's completions released and the device
         kept, progressed as a worker would progress them
         (``Context._run_task``: ``core:prepare_input``, the selection,
         the chore hook) — which ends in :meth:`kernel_scheduler` queueing
         each for the next drain.  Between two drains and under no span
         of this module: a ``prepare_input`` that raises fails the
-        task's own pool there, and no commit knows of it."""
+        task's own pool there, and no commit knows of it.  Returns how
+        many it progressed."""
         released, self._released = self._released, []
         for task in released:
             self.context._run_task(es, task)
+        return len(released)
 
     def _manager_loop(self, es) -> None:
         # phase: check_in_deps + exec — submit everything pending.
@@ -456,7 +472,11 @@ class TpuDevice(Device):
         # (one jitted multi-body program per wave — one enqueue RPC
         # instead of one per task); everything else goes per-task.
         while True:
-            self._hand_over(es)
+            # (a profiler session: the hand-over and the bucketing run
+            # under no span, so their time rides on the drain's first
+            # task span: docs/TRACING.md "Laps")
+            began_ns = time.perf_counter_ns() if pins.tracing() else 0
+            handed = self._hand_over(es)
             drained: List[Task] = []
             with pins.held(self._lock, "dev_lock"):
                 while self._pending:
@@ -464,6 +484,10 @@ class TpuDevice(Device):
             drained_ns = time.perf_counter_ns()  # ready-queue wait ends
             self._span_batch += 1
             units = self._units_of(drained)
+            if began_ns:
+                self._stamp_drain(
+                    handed, drained_ns - began_ns,
+                    time.perf_counter_ns() - drained_ns)
             # completions issued below run release_deps inline: a
             # coalescing window batches every activation this drained
             # batch produces into one frame per destination rank (the
@@ -490,6 +514,27 @@ class TpuDevice(Device):
                         self._deferred[0][1][0].block_until_ready()
                     except Exception:
                         pass
+
+    def _stamp_drain(self, handed: int, hand_ns: int, units_ns: int) -> None:
+        """What the next task span carries of the manager's loop: the
+        time from before the hand-over to the queue taken (``hand_us``,
+        ``Context._run_task`` and its ``core:prepare_input`` included)
+        for ``handed`` tasks, and from there to :meth:`_units_of` back
+        (``units_us``).  A drain that opens no task span leaves its
+        share to the next."""
+        stamp = self._drain_stamp or dict(hand_us=0.0, handed=0, units_us=0.0)
+        stamp["hand_us"] += hand_ns / 1e3
+        stamp["handed"] += handed
+        stamp["units_us"] += units_ns / 1e3
+        self._drain_stamp = stamp
+
+    def _take_stamp(self) -> Dict[str, Any]:
+        """The drain's stamp, for the task span that is about to open
+        (empty, and nothing done, unless a session runs)."""
+        stamp = self._drain_stamp
+        if stamp:
+            self._drain_stamp = {}
+        return stamp
 
     def _units_of(self, tasks: List[Task]) -> List[Tuple[str, Any]]:
         """One O(n) bucketing pass: the signature computed ONCE per task,
@@ -559,14 +604,18 @@ class TpuDevice(Device):
         ahead = self._prestaged.get(batch_no)
         if ahead:
             self._ahead_ids = frozenset(d.data_id for d in ahead)
-        with self._span("dev:submit_batch", batch=batch_no, n=len(tasks)):
+        with self._span("dev:submit_batch", batch=batch_no,
+                        n=len(tasks)) as sp:
             try:
-                self._submit_units(self._units_of(tasks), es, False)
+                units = self._units_of(tasks)
+                sp.lap("units")
+                self._submit_units(units, es, False)
             finally:
                 ahead = self._prestaged.pop(batch_no, None)
                 if ahead:
                     self._res.unpin(ahead)
                     self._ahead_ids = frozenset()
+                sp.lap("waves")
             # a transient-submit retry re-queues through ``_pending``
             # (the manager loop's channel); there is no manager in pump
             # mode, so drain retries here before handing the batch back
@@ -574,6 +623,7 @@ class TpuDevice(Device):
             while True:
                 with pins.held(self._lock, "dev_lock"):
                     if not self._pending:
+                        sp.lap("retry")
                         return
                     retry = list(self._pending)
                     self._pending.clear()
@@ -715,13 +765,16 @@ class TpuDevice(Device):
         """Per-task submit with the retry/fail-loudly discipline."""
         self._span_pool = _pool_of(task)
         waited = (drained_ns - task._tpu_enq) // 1000 if drained_ns else 0
+        stamp = self._take_stamp()
         try:
             sig = self._signature_of(task)
             with self._span("dev:submit_one", cls=task.task_class.name, n=1,
                             batch=self._span_batch, waited_us=waited,
                             direct=int(task._tpu_direct),
-                            dtypes=sig[1].dtypes if sig else "") as sp:
+                            dtypes=sig[1].dtypes if sig else "",
+                            **stamp) as sp:
                 self._submit(task, es, complete=complete, span=sp)
+                sp.lap("commit")
         except Exception as e:
             if not getattr(task, "_tpu_completed", False) \
                     and self._no_memory(task, e):
@@ -993,16 +1046,19 @@ class TpuDevice(Device):
             remaining -= cnt
             waited = sum(drained_ns - t._tpu_enq
                          for t in grp) // 1000 if drained_ns else 0
+            stamp = self._take_stamp()
             with self._span("dev:wave", cls=cls, n=cnt,
                             batch=self._span_batch, waited_us=waited,
                             direct=sum(t._tpu_direct for t in grp)
                             if drained_ns else 0,
                             dtypes=plan.dtypes,
                             cut="bytes" if cnt < by_tasks else "tasks",
-                            counted=counted) as sp:
+                            counted=counted, **stamp) as sp:
                 self._res.wait_for(counted)
+                sp.lap("room")
                 self._submit_chunk(grp, body, base_key, plan, es, complete,
                                    sp)
+                sp.lap("commit")
 
     def _born_here(self, tasks: List[Task], plan: FlowPlan) -> List[int]:
         """What the tasks of a wave cost the chunk they ride in, as
@@ -1060,9 +1116,11 @@ class TpuDevice(Device):
         chunk's tiles as they are staged, the caller lets go of them
         once the chunk is committed."""
         staged, refused = self._stage_span(grp, fplan, pinned)
+        wave_span.lap("stage")
         if not refused:
             wave_span.note(**self._launch(staged, cls, body, base_key, fplan,
-                                          fplan.donates, es, complete))
+                                          fplan.donates, es, complete,
+                                          wave_span))
             return
         # somebody else holds a tile that a task of the chunk would have
         # donated: those tasks leave the chunk's program and go out, as
@@ -1078,16 +1136,19 @@ class TpuDevice(Device):
                 n = 1 << ((len(part) - at).bit_length() - 1)
                 for key, v in self._launch(
                         part[at:at + n], cls, body, base_key, fplan, donates,
-                        es, complete).items():
+                        es, complete, wave_span).items():
                     notes[key] = notes.get(key, 0) + v
                 at += n
         wave_span.note(**notes)
 
     def _launch(self, staged: List[_Staged], cls: str, body, base_key,
-                fplan: FlowPlan, donates, es, complete: bool) -> Dict[str, int]:
+                fplan: FlowPlan, donates, es, complete: bool,
+                wave_span) -> Dict[str, int]:
         """ONE wave program over staged tasks: look it up, call it,
         commit every task's outputs; returns what the ``dev:wave`` span
-        notes of it (:meth:`_count_values`).  ``donates``: the flows of
+        notes of it (:meth:`_count_values`), and laps on it the
+        stretches between its children (``key``, ``flatten``, ``call``,
+        ``count``; the caller closes ``commit``).  ``donates``: the flows of
         every task whose input tile the program is given to write the
         matching output over (``FlowPlan.donates``, or none: the
         functional program).  An entry keeps one set of donated
@@ -1136,8 +1197,10 @@ class TpuDevice(Device):
             local_key += ("batched",)
         entry = self._cached_jit(local_key, build)
         program, plan = entry[0], entry[1]
+        wave_span.lap("key")
         flat = plan.flatten([args for (_t, args, _o) in staged])
         grp = [one[0] for one in staged]
+        wave_span.lap("flatten")
         if pins.active(pins.EXEC_BEGIN):
             for t in grp:
                 self._fire_exec(t, pins.EXEC_BEGIN, wave=cnt)
@@ -1164,6 +1227,7 @@ class TpuDevice(Device):
         if pins.active(pins.EXEC_END):
             for t in grp:
                 self._fire_exec(t, pins.EXEC_END, wave=cnt)
+        wave_span.lap("call")
         notes = self._count_values(plan, cnt, flat, len(outs), don)
         notes["batched"] = cnt if batched is not None else 0
         if getattr(body, "_converts", False):
@@ -1172,9 +1236,10 @@ class TpuDevice(Device):
             raise ValueError(
                 f"wave of {cls}: bodies returned "
                 f"{len(outs)} outputs for {nout * cnt} writable flows")
-        self.stats["wave_submits"] = self.stats.get("wave_submits", 0) + 1
+        self.stats["wave_submits"] += 1
         self.stats["wave_tasks"] = self.stats.get("wave_tasks", 0) + cnt
         self.stats["wave_tasks_batched"] += notes["batched"]
+        wave_span.lap("count")
         self._finish(staged, outs, nout, es, complete)
         return notes
 
@@ -1184,9 +1249,11 @@ class TpuDevice(Device):
         with self._span("dev:stage_args") as sp:
             # host tiles, their bytes, tiles staged, residency hits
             tally = [0, 0, 0, 0]
-            staged, refused = self._stage_chunk(grp, fplan, tally, pinned)
+            staged, refused = self._stage_chunk(grp, fplan, tally, pinned,
+                                                sp.lap)
             sp.note(host_tiles=tally[0], bytes=tally[1], tiles=tally[2],
                     hits=tally[3])
+            sp.lap("own")
         return staged, refused
 
     def _finish(self, staged: List[_Staged], outs, nout: int, es,
@@ -1200,6 +1267,7 @@ class TpuDevice(Device):
                 self._commit_chunk(staged, outs, nout, es, complete, sp,
                                    out_hooks=out_hooks, donated=donated,
                                    alone=alone)
+                sp.lap("complete")
                 return
             for k, one in enumerate(staged):
                 if getattr(one[0].taskpool, "failed", False):
@@ -1210,7 +1278,8 @@ class TpuDevice(Device):
                 one[0]._tpu_completed = True  # the queue's from here on
 
     def _stage_chunk(self, grp: List[Task], fplan: FlowPlan,
-                     tally: List[int], pinned: List[Data]) -> List[_Staged]:
+                     tally: List[int], pinned: List[Data],
+                     lap) -> Tuple[List[_Staged], set]:
         """kernel_push (reference device_gpu.c:2015-2164 stage-in
         phase) — THE staging walk: for the tasks of one chunk of a wave,
         or for one task that goes out alone, in ONE pass under ONE hold
@@ -1228,7 +1297,11 @@ class TpuDevice(Device):
         not fit the budget together raises (``StageIn.batch``) instead
         of running past it.  ``tally`` counts for
         the ``dev:stage_args`` span: ``[tiles copied from the host,
-        their bytes, tiles staged, of them found resident]``.
+        their bytes, tiles staged, of them found resident]``; ``lap``
+        is that span's: ``walk`` (the loop over the flows), ``put`` (the
+        batched put and the arguments it fills), ``sole`` (the donation
+        check); the caller closes ``own`` (the ownership moves, the next
+        uses, the tally).
 
         **Donation.**  Where the signature names donated flows
         (``fplan.donates``), the second value returned is the set of
@@ -1329,6 +1402,7 @@ class TpuDevice(Device):
                         ospecs.append((pos, data))
                 staged.append((task, args, ospecs))
                 task._tpu_scratch = mine
+            lap("walk")
             if missing:
                 tiles = [slot[0] for slot in missing.values()]
                 # the chunk's host->device transfers as one batched put
@@ -1340,8 +1414,10 @@ class TpuDevice(Device):
                     for args, at in slot[1:]:
                         args[at] = found[did]
                     nmiss += len(slot) - 1
+            lap("put")
             if donates:
                 refused = self._not_sole(staged, donates, reads)
+            lap("sole")
             for data, access in owns:
                 data.transfer_ownership(idx, access)
             if nexts:
@@ -1414,6 +1490,8 @@ class TpuDevice(Device):
                  complete: bool, span, pinned: List[Data]) -> None:
         """:meth:`_submit` between the pins (as :meth:`_run_chunk`)."""
         staged, refused = self._stage_span([task], fplan, pinned)
+        lap = span.lap if span is not None else _no_lap
+        lap("stage")
         dev_args = staged[0][1]
 
         base_key = getattr(body, "_jit_key", body)
@@ -1468,6 +1546,7 @@ class TpuDevice(Device):
                 local_key,
                 lambda: (("static", self._content_fp(body), vals),
                          _bound, donate, None))
+            lap("key")  # (no plan: nothing to flatten)
         else:
             fused_n = int(getattr(body, "_fused_n", 0) or 0)
             if fused_n > 1:
@@ -1505,7 +1584,9 @@ class TpuDevice(Device):
             local_key = (base_key, argsig(dev_args),
                          _placeholders_at(dev_args), donates)
             entry = self._cached_jit(local_key, build)
+            lap("key")
             call_args = entry[1].flatten((dev_args,))
+            lap("flatten")
         # a donating call that raises may have invalidated its input
         # buffers: the task is no longer safely retryable
         don = len(entry[0].donate)
@@ -1513,6 +1594,7 @@ class TpuDevice(Device):
         self._fire_exec(task, pins.EXEC_BEGIN)
         outputs = self._dispatch(local_key, entry, call_args)
         self._fire_exec(task, pins.EXEC_END)
+        lap("call")
         plan = entry[1]  # (None: a ``_static_values`` program)
         if plan is not None:
             notes = self._count_values(plan, 1, call_args, fplan.nout, don)
@@ -1526,6 +1608,7 @@ class TpuDevice(Device):
             raise ValueError(
                 f"device body of {task!r} returned {len(outputs)} outputs "
                 f"for {fplan.nout} writable flows")
+        lap("count")
         # NOT sent home, a donating program's outputs: the successor of
         # an in-place chain consumes this very buffer, so an eager get
         # either stalls the chain behind a device->host copy of every
@@ -1794,7 +1877,11 @@ class TpuDevice(Device):
         ``complete_execution``).  The tools' sites fire as they did — an
         epilog a task, a bump an output, a ticket an enqueue — asked once
         a chunk whether anybody listens.  ``sp``: the ``dev:epilog``
-        span, where there is one.
+        span, where there is one; its laps are ``hooks``, ``commit``
+        (every task's outputs and scratch tiles, ONE loop: a task's
+        scratch tiles are let go before the next task's outputs are
+        counted, which is what ``scratch_bytes_peak`` reads), ``settle``,
+        ``home``, ``zeros``, and ``complete``, which the caller closes.
 
         Once the commit has begun nothing here may raise: an error fails
         the pool loudly and marks the chunk's tasks completed, so that
@@ -1824,6 +1911,7 @@ class TpuDevice(Device):
         #: an output of the program, as it returned it: ready when the
         #: chip has run it
         after = outs[0] if len(outs) else None
+        lap = sp.lap if sp is not None else _no_lap
         try:
             with pins.held(res.lock, "res_lock"):
                 if out_hooks is not None:
@@ -1836,8 +1924,8 @@ class TpuDevice(Device):
                             data = staged[k // nout][2][k % nout][1]
                             outs[k] = jax.device_put(
                                 so(outs[k], data, self), self.jdev)
-                            self.stats["custom_stage_out"] = \
-                                self.stats.get("custom_stage_out", 0) + 1
+                            self.stats["custom_stage_out"] += 1
+                lap("hooks")
                 # the k-th output of every task has one shape: its bytes,
                 # once
                 sizes = [o.nbytes for o in outs[:nout]]
@@ -1870,14 +1958,18 @@ class TpuDevice(Device):
                     if task._tpu_scratch:
                         self._release_scratch(task._tpu_scratch, after)
                     done.append(task)
+                lap("commit")
                 if nexts:
                     res.next_uses(nexts)
                 # outputs grew residency: re-settle under the budget
                 res.settle()
             self.stats["task_commits" if alone else "wave_commits"] += 1
+            lap("settle")
             home = 0 if donated else self._send_home(going, last)
+            lap("home")
             if blank and not donated:
                 self._wb.land_zeros(blank)
+            lap("zeros")
             if sp is not None:
                 sp.note(n=len(done), outs=len(done) * nout, home=home,
                         blank=len(blank))
