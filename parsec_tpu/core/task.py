@@ -254,7 +254,13 @@ class Task:
         #: give that tile's array to the task's program to write the
         #: output over, where it finds nobody else holding the array
         #: (``TpuDevice._not_sole``); None where whoever built the task
-        #: does not know: then nothing is donated, the body is functional
+        #: does not know: then nothing is donated, the body is functional.
+        #: Three builders say: the pump, from the captured graph's edges
+        #: (``dsl/attach_plan.py`` ``_donations``); ``insert_task``
+        #: (``dsl/dtd.py``: the insertion's exclusive writer); a PTG pool
+        #: on the ``Context`` route in a context of one rank, from the
+        #: classes' own dependencies (``PTGTaskpool._donate_rule``: the
+        #: producer's output dependencies name this task and nobody else)
         self._tpu_donate: Optional[Tuple[int, ...]] = None
         #: where the task's row starts in its pool's table of next uses
         #: (``taskpool.next_use[_tpu_next + position in body_args]``: the

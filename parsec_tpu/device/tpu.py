@@ -42,7 +42,8 @@ Departures from the reference, by TPU design:
   fresh output arrays (XLA semantics), instead of mutating tile memory;
   outputs rebind the device copies of writable flows in declaration order.
   Where the task's builder knows that a read-write flow's input version
-  has no other consumer (``Task._tpu_donate``) and the staging walk finds
+  has no other consumer (``Task._tpu_donate``: the pump's attach plan, a
+  DTD insertion, a PTG pool on the ``Context`` route) and the staging walk finds
   nobody else holding its array, the program is compiled with that
   argument DONATED: XLA writes the output over the input's buffer, and a
   call allocates nothing for it (``_stage_chunk``, "Donation");
@@ -161,8 +162,12 @@ class TpuDevice(Device):
         #: input version that nobody else reads: its output is written
         #: where it stands), and tasks that went out under the functional
         #: program because the staging walk found somebody else holding
-        #: such a tile's array (0 on a healthy run)
-        self.stats.update(tile_args_donated=0, donation_refused=0)
+        #: such a tile's array (0 on a healthy run); and the tasks
+        #: committed whose builder did not say which inputs are the
+        #: task's alone (``Task._tpu_donate`` None, not ``()``: nothing
+        #: could be donated whoever held what)
+        self.stats.update(tile_args_donated=0, donation_refused=0,
+                          commits_donate_unknown=0)
         #: wave programs whose width the byte bound set
         #: (``Residency.chunk_limit``), and the bytes of tiles read that
         #: the bound did not count, born here as they were
@@ -1955,6 +1960,8 @@ class TpuDevice(Device):
                     if home is None:
                         last = False
                         self.stats["commits_home_unknown"] += 1
+                    if task._tpu_donate is None:
+                        self.stats["commits_donate_unknown"] += 1
                     if task._tpu_scratch:
                         self._release_scratch(task._tpu_scratch, after)
                     done.append(task)

@@ -107,7 +107,9 @@ class FlowPlan:
     may write where it stands, as ``(position in body_args, index in the
     staged argument list, index among the task's outputs)`` — of the
     positions whoever built the tasks named (``Task._tpu_donate``: this
-    task is the version's only consumer), those that are a plain ``READ``
+    task is the version's only consumer; the pump's attach plan, a DTD
+    insertion or ``PTGTaskpool._donate_rule`` on the ``Context`` route
+    says so), those that are a plain ``READ``
     with the ``OUT`` bit and a known shape.  Part of the signature: the
     tasks of one chunk donate the same positions."""
 

@@ -367,6 +367,20 @@ def _expand_args(args: Sequence[_ArgExpr], env: Dict[str, Any]) -> Iterable[Tupl
     return itertools.product(*pools)
 
 
+def _instances_of(pc: "PTGTaskClass", args: Sequence[_ArgExpr],
+                  reads: List[_Expr]) -> Tuple:
+    """A dependency's reference to tasks of ``pc``, for whoever asks at
+    ``prepare_input`` which of them exist: ``(the class, the arguments,
+    the arguments as ONE expression where none is a range)``; every
+    expression it evaluates is added to ``reads``."""
+    reads.extend(e for a in args for e in (a.lo, a.hi, a.step)
+                 if e is not None)
+    one = None
+    if all(a.hi is None for a in args):
+        one = _Expr("(%s)" % "".join(f"{a.lo.src}, " for a in args))
+    return pc, args, one
+
+
 # ---------------------------------------------------------------------------
 # declarations (problem-size independent, like a .jdf file)
 # ---------------------------------------------------------------------------
@@ -1161,13 +1175,7 @@ class PTGTaskpool(Taskpool):
                     sf.name == t.flow_name and sf.mode != CTL
                     and sf.mode & AccessMode.OUT for sf in spc.flows):
                 return None
-            reads.extend(e for a in t.args for e in (a.lo, a.hi, a.step)
-                         if e is not None)
-            one = None
-            if all(a.hi is None for a in t.args):
-                one = _Expr("(%s)" % "".join(
-                    f"{a.lo.src}, " for a in t.args))
-            return spc, t.args, one
+            return _instances_of(spc, t.args, reads)
 
         always: List[int] = []
         guarded: List[Tuple[int, Tuple]] = []
@@ -1217,34 +1225,184 @@ class PTGTaskpool(Taskpool):
         same environment, and through the same memo: what is asked here
         is not worked out again there.  A ranged dependency whose range
         is empty hands the tile to nobody."""
-        consts, memo = self.constants, self._exists_memo
         home = always
         for pos, deps in guarded:
             for guard, then, otherwise in deps:
                 w = then if guard is None or guard(env) else otherwise
-                if w is None:
-                    continue
-                spc, args, one = w
-                locs = one(env) if one is not None else None
-                if locs is None or range in map(type, locs):
-                    there = any(spc.instance_exists(ls, consts, memo)
-                                for ls in _expand_args(args, env))
-                else:
-                    there = memo.get((spc.name, locs))
-                    if there is None:
-                        there = spc.instance_exists(locs, consts, memo)
-                if there:
+                if w is not None and self._existing(w, env, 1):
                     break  # superseded: a later task's to send home
             else:
                 home += (pos,)
         return home
 
+    def _existing(self, ref: Tuple, env: Dict[str, Any], enough: int) -> int:
+        """How many of the instances that ``ref`` names
+        (:func:`_instances_of`) exist, as seen from the task whose
+        environment is ``env``; counted no further than ``enough``."""
+        spc, args, one = ref
+        consts, memo = self.constants, self._exists_memo
+        locs = one(env) if one is not None else None
+        if locs is None or range in map(type, locs):
+            n = 0
+            for ls in _expand_args(args, env):
+                if spc.instance_exists(ls, consts, memo):
+                    n += 1
+                    if n >= enough:
+                        break
+            return n
+        there = memo.get((spc.name, locs))
+        if there is None:
+            there = spc.instance_exists(locs, consts, memo)
+        return int(there)
+
+    def _donate_rule(self, pc: PTGTaskClass):
+        """Which read-write inputs of a ``pc`` task are its ALONE
+        (``Task._tpu_donate``: the device module may let the task's
+        program write the output over that version's array), as far as
+        the class alone says it: ``_donations``'s rule
+        (``dsl/attach_plan.py``), read from the classes' dependencies
+        instead of a captured graph.  ``{source: how}`` by the input
+        dependency's target (the ``_TaskRef`` / ``_DataRef`` that
+        ``active_input_dep`` returns: one object a branch), for the
+        sources of the ``INOUT`` flows that can be donated at all:
+
+        * a **producer's flow** that the producer WRITES (a flow it only
+          read forwards a version whose other readers share it):
+          ``(the producer's class, its key as one expression, the
+          producer flow's output dependencies)``, each dependency
+          ``(guard, then, otherwise)`` with a branch that names tasks
+          as :func:`_instances_of` has them, a branch that names a
+          collection's tile as the ``_DataRef`` it is, any other as
+          None; decided a task by :meth:`_sole_reader`;
+        * **the collection's tile** (a first version, staged from its
+          home: what is donated is the device's private copy of it):
+          True, where every direct ``<- X(...)`` source of that
+          collection, in every class of the PTG, is on a flow that
+          writes — two such flows on one tile un-ordered would be a race
+          in the PTG itself, and a PTG that reads a tile of the
+          collection read-only anywhere keeps its first versions.
+
+        A ``NEW`` tile, no source, a ranged source: absent, never
+        donated.  None where a dependency that decides reads anything
+        but a task's key and the pool's scalar constants
+        (:meth:`_home_rule`): the class cannot know, and
+        ``_tpu_donate`` stays None (``commits_donate_unknown``)."""
+        classes, consts = self.ptg.classes, self.constants
+        inout = AccessMode.INOUT
+        #: the collections (as objects: two names may be one) some flow
+        #: reads from memory without writing
+        read_only = {id(consts.get(t.collection_name))
+                     for c in classes.values() for f in c.flows
+                     if f.mode == CTL or not (f.mode & AccessMode.OUT)
+                     for dep in f.deps_in for t in (dep.then, dep.otherwise)
+                     if isinstance(t, _DataRef)}
+        rule: Dict[Any, Any] = {}
+        mine: List[_Expr] = []
+        for f in pc.flows:
+            if f.mode == CTL or f.mode & inout != inout:
+                continue
+            for dep in f.deps_in:
+                if dep.guard is not None:
+                    mine.append(dep.guard)
+                for t in (dep.then, dep.otherwise):
+                    if isinstance(t, _DataRef):
+                        if id(consts.get(t.collection_name)) not in read_only:
+                            rule[t] = True
+                        continue
+                    spc = classes.get(t.class_name) \
+                        if isinstance(t, _TaskRef) else None
+                    sf = next((x for x in spc.flows
+                               if x.name == t.flow_name), None) \
+                        if spc is not None else None
+                    if sf is None or sf.mode == CTL \
+                            or not (sf.mode & AccessMode.OUT):
+                        continue
+                    key = _instances_of(spc, t.args, mine)[2]
+                    if key is None:
+                        continue
+                    theirs: List[_Expr] = []
+                    outs = self._handed_on(sf, theirs)
+                    static = self._static_names(spc)
+                    if outs is None or not all(_reads_only(e, static)
+                                               for e in theirs):
+                        return None
+                    rule[t] = (spc, key, outs)
+        static = self._static_names(pc)
+        if not all(_reads_only(e, static) for e in mine):
+            return None
+        return rule
+
+    def _handed_on(self, sf: _PTGFlow, reads: List[_Expr]):
+        """The output dependencies of a producer's flow as
+        :meth:`_sole_reader` reads them (:meth:`_donate_rule`), every
+        expression they evaluate added to ``reads``; None where one
+        names a class nobody declared."""
+        classes = self.ptg.classes
+        outs = []
+        for out in sf.deps_out:
+            branches = []
+            for r in (out.then, out.otherwise):
+                if isinstance(r, _TaskRef):
+                    rpc = classes.get(r.class_name)
+                    if rpc is None:
+                        return None
+                    r = _instances_of(rpc, r.args, reads)
+                elif isinstance(r, _DataRef):
+                    reads.extend(a.lo for a in r.args)
+                else:
+                    r = None
+                branches.append(r)
+            if branches != [None, None]:
+                outs.append((out.guard, *branches))
+                if out.guard is not None:
+                    reads.append(out.guard)
+        return tuple(outs)
+
+    def _sole_reader(self, how: Tuple, env: Dict[str, Any],
+                     data: Data) -> bool:
+        """Whether the task whose environment is ``env`` is the only
+        consumer of the version it was handed as ``data``
+        (:meth:`_donate_rule`, a producer's flow): the producer's active
+        output dependencies of that flow, evaluated in the PRODUCER's
+        environment — what :meth:`_release_deps_core` evaluated when it
+        handed the version on, through the same memo — name exactly one
+        instance that exists, this one (a ranged dependency counts every
+        instance of its range), and none lands the version in a
+        collection tile other than the flow's own."""
+        spc, key, outs = how
+        consts = self.constants
+        locs = key(env)
+        if not spc.instance_exists(locs, consts, self._exists_memo):
+            return False  # (no producer: the tile was made here)
+        penv = spc.env_of(locs, consts)
+        n = 0
+        for guard, then, otherwise in outs:
+            r = then if guard is None or guard(penv) else otherwise
+            if r is None:
+                continue
+            if type(r) is _DataRef:
+                if consts[r.collection_name].data_of(*r.key(penv)) \
+                        is not data:
+                    return False
+                continue
+            n += self._existing(r, penv, 2 - n)
+            if n > 1:
+                return False
+        return n == 1
+
     def _make_prepare_input(self, pc: PTGTaskClass):
         home_always, home_guarded = self._home_rule(pc)
+        sole_rule = self._donate_rule(pc)
 
         def prepare_input(es, task: Task) -> HookReturn:
             env = pc.env_of(task.locals, self.constants)
             specs: List[Tuple[str, Any, AccessMode]] = []
+            # (several ranks: the device module donates nothing,
+            # TpuDevice._may_donate, and nothing is worked out for it)
+            ctx = self.context
+            sole = sole_rule if ctx is not None and ctx.nranks <= 1 \
+                else None
+            donate: Tuple[int, ...] = ()
             for f in pc.flows:
                 if f.mode == CTL:
                     specs.append(("ctl", None, CTL))
@@ -1269,12 +1427,20 @@ class PTGTaskpool(Taskpool):
                         data = materialize(get_copy_reshape(data, rspec))
                 specs.append(("data", data, f.mode))
                 task.data_in[f.index] = data.newest_copy() if data is not None else None
+                if sole is not None and data is not None:
+                    how = sole.get(target)
+                    if how is not None and (
+                            how is True
+                            or self._sole_reader(how, env, data)):
+                        donate += (f.index,)
             for name in pc.param_names + pc.def_names + pc.body_globals:
                 specs.append(("value", env[name], AccessMode.VALUE))
             task.body_args = specs
             task._tpu_home = self._last_versions(
                 home_always, home_guarded, env) if home_guarded \
                 else home_always
+            if sole is not None:
+                task._tpu_donate = donate
             return HookReturn.DONE
 
         return prepare_input

@@ -494,9 +494,13 @@ CELLS = {
         _through_the_pump, 16, 13704, 123,
         _widths("gemm", 64, 0, 3) + _widths("syrk", 8, 0, 2)
         + _widths("trsm", 8, 1, 2)),
+    # (since PR 49 a ``Context`` PTG task names its donated input as a
+    # pumped one does: the pump cell's positions; widths and the number
+    # of programs as before)
     "tile_ctx_n8192": (
         _through_context, 16, 13704, 123,
-        _widths("gemm", 64) + _widths("syrk", 8) + _widths("trsm", 8)),
+        _widths("gemm", 64, 0, 3) + _widths("syrk", 8, 0, 2)
+        + _widths("trsm", 8, 1, 2)),
     "dtd_potrf_nb1024": (
         _inserted, 24, 3426, 404,
         _widths("gemm", 32, 0, 3) + _widths("syrk", 16, 0, 2)

@@ -552,7 +552,8 @@ def test_who_donates_nothing(ctx, why):
     """Several ranks (a peer may hold the array uncopied), completion
     deferred to the chip's events (a donated tile's copy would be a
     deleted array until its commit), and a task whose builder said
-    nothing (``_tpu_donate`` None: every ``Context`` PTG pool)."""
+    nothing (``_tpu_donate`` None: a hand-built task, a PTG class whose
+    dependencies read more than the class can know)."""
     from parsec_tpu.utils import mca_param
 
     other = None
