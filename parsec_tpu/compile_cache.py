@@ -510,15 +510,21 @@ class _CachedFunction:
     to a plain ``jax.jit`` of the original function — counted, never
     fatal."""
 
-    __slots__ = ("cache", "fn", "key", "donate", "_memo", "_plain",
-                 "_lock")
+    __slots__ = ("cache", "fn", "key", "donate", "place", "_memo",
+                 "_plain", "_lock")
 
     def __init__(self, cache: "ExecutableCache", fn: Callable, key: Any,
-                 donate: Tuple[int, ...]):
+                 donate: Tuple[int, ...], place: Any = None):
         self.cache = cache
         self.fn = fn
         self.key = key
         self.donate = tuple(donate or ())
+        #: which of a context's several accelerators the function's
+        #: arguments live on (None: the one there is).  An executable is
+        #: compiled for the device of its arguments, so each accelerator
+        #: has its own view of the live executables; the stored,
+        #: device-portable form is shared
+        self.place = place
         self._memo: Dict[Tuple, Any] = {}
         self._plain = None
         self._lock = threading.Lock()
@@ -672,12 +678,13 @@ class ExecutableCache:
 
     # -- public API ------------------------------------------------------
     def jit(self, fn: Callable, *, key: Any,
-            donate_argnums: Tuple[int, ...] = ()) -> _CachedFunction:
+            donate_argnums: Tuple[int, ...] = (),
+            place: Any = None) -> _CachedFunction:
         """Cache-aware replacement for ``jax.jit(fn, donate_argnums=…)``.
         ``key`` identifies the *program* (body code fingerprint plus any
         structural parts — wave arity/count, baked static values); the
         concrete input shapes/dtypes complete the cache key per call."""
-        return _CachedFunction(self, fn, key, donate_argnums)
+        return _CachedFunction(self, fn, key, donate_argnums, place)
 
     def clear_memory(self) -> None:
         """Drop live executables and preloaded blobs (the disk store
@@ -724,7 +731,8 @@ class ExecutableCache:
 
     def _resolve(self, cf: _CachedFunction, sig: Tuple, args: Tuple):
         fp = fingerprint(cf.key, sig, donate=cf.donate)
-        exe = self._lru_get(fp)
+        live = fp if cf.place is None else f"{fp}@{cf.place}"
+        exe = self._lru_get(live)
         if exe is not None:
             self.stats["hits_mem"] += 1
             return exe
@@ -744,7 +752,7 @@ class ExecutableCache:
                 dt = time.perf_counter() - t0
                 self.stats["compile_ns_total"] += int(dt * 1e9)
                 sp.note(kind=kind, seconds=dt)
-        self._lru_put(fp, exe)
+        self._lru_put(live, exe)
         return exe
 
     def _resolve_slow(self, cf: _CachedFunction, fp: str, args: Tuple):

@@ -91,7 +91,14 @@ class Context:
         rank: int = 0,
         nranks: int = 1,
         comm=None,
+        accelerators: int = 1,
     ):
+        """``accelerators``: how many chips this context drives (DPLASMA's
+        ``-g``): as many instances of the accelerator module, ``devices ==
+        [cpu, acc_1 .. acc_g]``, instance ``i`` on
+        ``jax.local_devices()[i - 1]`` (with several ranks, on the rank's
+        slice of them), under this one scheduler; more than there are
+        chips raises here.  1 is one module, bound by the rank."""
         # opt-in runtime checkers, installed BEFORE any runtime lock or
         # thread exists so they observe the whole context lifetime:
         # PARSEC_TPU_HBCHECK=1|strict — happens-before race recorder
@@ -119,6 +126,14 @@ class Context:
                 help="number of worker execution streams",
             )
         self.nb_workers = max(1, int(nb_cores))
+        if int(accelerators) < 1:
+            raise ValueError(f"accelerators={accelerators}: at least one")
+        self.accelerators = int(accelerators)
+        #: tasks by the criterion that placed them among several eligible
+        #: accelerators (``device.select_best_device``); all 0 with one
+        self.stats: Dict[str, int] = {
+            "selected_by_owner": 0, "selected_by_advice": 0,
+            "selected_by_bytes": 0, "selected_by_load": 0}
         self.rank = rank
         self.nranks = nranks
         self.comm = comm  # comm engine (None = single process)
@@ -155,10 +170,15 @@ class Context:
         for es in self.streams:
             self.scheduler.flow_init(es)
 
+        # (before the devices: a module with a manager thread of its own
+        # names that thread's stream here)
+        self._tls = threading.local()
+
         # devices (device 0 = CPU; accelerators attach next)
         from ..device import device as devmod
 
-        self.devices = devmod.attach_devices(self, devices)
+        self.devices = devmod.attach_devices(self, devices,
+                                             self.accelerators)
 
         self._cv = threading.Condition()
         #: exclusive ownership of execution stream 0 (the "master" stream):
@@ -170,7 +190,6 @@ class Context:
         self._shutdown = False
         self._fini_cbs = []
         self._abort_reason = None
-        self._tls = threading.local()
 
         self._threads: List[threading.Thread] = []
         for es in self.streams[1:]:
@@ -568,6 +587,15 @@ class Context:
         if isinstance(tasks, Task):
             tasks = [tasks]
         scheduling.schedule_ready(self, es, tasks, distance)
+
+    def flush(self, timeout: float = 300.0) -> None:
+        """Every device module's write-backs are home (``TpuDevice.flush``
+        of each): host tiles are current while the devices stay
+        attached."""
+        for dev in self.devices:
+            flush = getattr(dev, "flush", None)
+            if flush is not None:
+                flush(timeout=timeout)
 
     def on_fini(self, cb) -> None:
         """Register a teardown callback, run at the start of :meth:`fini`

@@ -55,7 +55,11 @@ def schedule_ready(context: "Context", es: Optional["ExecutionStream"], tasks: I
                 best = max(range(len(batch)), key=lambda i: batch[i].priority)
                 es.next_task = batch.pop(best)
         if batch:
-            context.scheduler.schedule(es, batch, distance)
+            # (the stream of a device module's own manager thread is no
+            # worker's: the scheduler knows only the context's streams)
+            context.scheduler.schedule(
+                es if es is None or es.worker_id < context.nb_workers
+                else None, batch, distance)
             # only a task actually pushed to the scheduler warrants waking
             # the idle threads: a kept-next successor is run by THIS
             # worker, and waking everyone per completion makes the idle

@@ -34,6 +34,11 @@ _OUT = int(AccessMode.OUT)
 KEPT = -1
 
 
+class BeingOverwritten(RuntimeError):
+    """A device module asked for a version that another module's program
+    is overwriting in place (``Data.hold_source``)."""
+
+
 class Coherency(enum.Enum):
     """Reference PARSEC_DATA_COHERENCY_* (data.h:39-44)."""
 
@@ -103,6 +108,7 @@ class Data:
         "data_id",
         "user",
         "scratch",
+        "peer_holds",
         "__weakref__",
     )
 
@@ -130,6 +136,13 @@ class Data:
         #: of a ``NEW`` flow, its declared users left (device/scratch.py);
         #: :data:`KEPT` for a device-born collection's tile
         self.scratch: Optional[int] = None
+        #: landings of the newest copy's ARRAY that are between "read the
+        #: reference" and "enqueued" on other device modules of the
+        #: context (:meth:`hold_source`); -1 while the module that holds
+        #: the array has given it to a program to overwrite in place
+        #: (:meth:`claim_for_donation`, until that program's commit).
+        #: Read and written under ``lock``
+        self.peer_holds: int = 0
 
     # -- copy management --------------------------------------------------
     def attach_copy(self, device_index: int, payload: Any) -> DataCopy:
@@ -165,6 +178,62 @@ class Data:
                 if best is None or c.version > best.version:
                     best = c
             return best
+
+    def hold_source(self, device_index: int) -> Optional[DataCopy]:
+        """The copy a staging walk of ``device_index`` takes the tile
+        from: the newest valid one and, among copies at that version, one
+        that lives on a device (a chip-to-chip landing never crosses the
+        host; a tile that went home as a last version is still read from
+        the chip that bore it).  Where that copy is another device
+        module's, the walk HOLDS its array from here until its landing is
+        enqueued (:meth:`release_source`): under one hold of the tile's
+        lock the reference is read and the hold counted, so the module
+        that owns the array can never give it to a donating program in
+        between (:meth:`claim_for_donation`).  A version that its sole
+        consumer is overwriting in place has no reader left by the DAG's
+        own word: :class:`BeingOverwritten`."""
+        with self.lock:
+            best = None
+            for c in self.copies.values():
+                if c.coherency is Coherency.INVALID or c.payload is None:
+                    continue
+                if best is None or c.version > best.version or (
+                        c.version == best.version
+                        and best.device_index == 0 and c.device_index != 0):
+                    best = c
+            if best is not None and best.device_index not in (0, device_index):
+                if self.peer_holds < 0:
+                    raise BeingOverwritten(
+                        f"{self!r}: version {best.version} on device "
+                        f"{best.device_index} is being overwritten in place "
+                        "by the task that alone consumes it")
+                self.peer_holds += 1
+            return best
+
+    def release_source(self) -> None:
+        """The landing that :meth:`hold_source` counted is enqueued (or
+        given up)."""
+        with self.lock:
+            if self.peer_holds > 0:
+                self.peer_holds -= 1
+
+    def claim_for_donation(self) -> bool:
+        """The module that holds the newest copy's array is about to give
+        it to a program that writes its output over it: False where a
+        peer's landing holds the array (the program goes out functional);
+        True marks the tile until the program's commit rebinds the copy
+        (:meth:`donation_committed`), and a peer that asks meanwhile is
+        refused loudly."""
+        with self.lock:
+            if self.peer_holds:
+                return False
+            self.peer_holds = -1
+            return True
+
+    def donation_committed(self) -> None:
+        with self.lock:
+            if self.peer_holds < 0:
+                self.peer_holds = 0
 
     def current_copy(self, device_index: int) -> Optional[DataCopy]:
         """The copy on ``device_index`` when it holds the tile at the

@@ -11,6 +11,7 @@ from .matrix import (
     TwoDimBlockCyclic,
     TwoDimTabular,
     VectorTwoDimCyclic,
+    advise_data_on_devices,
 )
 from .ops import apply_taskpool, map_operator, reduce_cols, reduce_rows, reduce_taskpool
 from .redistribute import redistribute
@@ -26,6 +27,7 @@ __all__ = [
     "TwoDimBlockCyclicBand",
     "TwoDimTabular",
     "VectorTwoDimCyclic",
+    "advise_data_on_devices",
     "apply_taskpool",
     "map_operator",
     "reduce_taskpool",

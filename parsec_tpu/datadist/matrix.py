@@ -196,6 +196,35 @@ class TiledMatrix(DataCollection):
         return self
 
 
+def advise_data_on_devices(A: TiledMatrix, accelerators, grid,
+                           uplo: Optional[str] = None) -> Dict[int, int]:
+    """DPLASMA's ``dplasma_advise_data_on_device`` with its 2D-cyclic
+    map: every local tile ``(m, n)`` of the ``uplo`` triangle of ``A``
+    (``A``'s own storage where not given) is advised to accelerator
+    ``(m mod p) * q + (n mod q)`` of ``accelerators``, the ``p x q``
+    device ``grid`` — ``data_advise(tile, ADVICE_PREFERRED_DEVICE)``, the
+    reference's ``PARSEC_DEV_DATA_ADVICE_PREFERRED_DEVICE``.  A task goes
+    to the accelerator that holds, or failing that is advised to hold,
+    the tile it writes (``device.select_best_device``).  Returns
+    ``{device index: tiles advised to it}``."""
+    from ..device.device import ADVICE_PREFERRED_DEVICE
+
+    p, q = grid
+    accelerators = list(accelerators)
+    if p * q != len(accelerators):
+        raise ValueError(f"a {p} x {q} device grid takes {p * q} "
+                         f"accelerators, got {len(accelerators)}")
+    uplo = A.uplo if uplo is None else uplo
+    advised = {dev.index: 0 for dev in accelerators}
+    for (m, n) in A.local_tiles():
+        if (uplo == LOWER and m < n) or (uplo == UPPER and m > n):
+            continue
+        dev = accelerators[(m % p) * q + (n % q)]
+        dev.data_advise(A.data_of(m, n), ADVICE_PREFERRED_DEVICE)
+        advised[dev.index] += 1
+    return advised
+
+
 class TwoDimBlockCyclic(TiledMatrix):
     """ScaLAPACK-style 2D block-cyclic placement over a P×Q process grid
     with kp/kq k-cyclic super-tiling (reference

@@ -14,7 +14,7 @@ import threading
 from typing import Any, Dict, List, Optional, TYPE_CHECKING
 
 from ..utils import Component, debug, mca_param, register_component
-from ..core.lifecycle import DEV_CPU, HookReturn
+from ..core.lifecycle import AccessMode, DEV_CPU, HookReturn
 
 if TYPE_CHECKING:  # pragma: no cover
     from ..core.context import Context
@@ -36,6 +36,10 @@ class Device(Component):
 
     mca_type = "device"
     device_type: str = DEV_CPU
+    #: a module of this class drives ONE accelerator: a context that is
+    #: given several (``Context(accelerators=g)``) attaches as many
+    #: instances, ``cls(context, index, place=i, of=g)``
+    per_accelerator: bool = False
 
     def __init__(self, context: "Context", index: int):
         self.context = context
@@ -52,9 +56,13 @@ class Device(Component):
             "bytes_in": 0,
             "bytes_out": 0,
             "bytes_d2d": 0,  # device-to-device landings (no host bounce)
+            "d2d_tiles": 0,  # ... and the tiles that landed so
             "evictions": 0,
         }
         self.enabled = True
+        #: the other modules of this class in the context (several
+        #: accelerators under one ``Context``); empty with one
+        self.peers: List["Device"] = []
 
     # -- vtable ---------------------------------------------------------
     def attach(self) -> None:
@@ -126,9 +134,14 @@ class CpuDevice(Device):
         raise AssertionError("CPU chores execute inline")
 
 
-def attach_devices(context: "Context", names: Optional[List[str]] = None) -> List[Device]:
+def attach_devices(context: "Context", names: Optional[List[str]] = None,
+                   accelerators: int = 1) -> List[Device]:
     """Instantiate the CPU device plus every available accelerator module
-    (reference ``parsec_mca_device_init``/``attach``, ``parsec.c:809-815``)."""
+    (reference ``parsec_mca_device_init``/``attach``, ``parsec.c:809-815``:
+    one module per visible accelerator).  ``accelerators``: how many
+    instances of a module that drives one chip each
+    (``Device.per_accelerator``) the context is given, at indices that
+    follow one another; 1 is one instance, built as it always was."""
     from ..utils import components_of_type
 
     sel = names
@@ -149,13 +162,23 @@ def attach_devices(context: "Context", names: Optional[List[str]] = None) -> Lis
         # a module that was asked for — by name, or by reporting itself
         # available — and cannot attach is an ERROR: continuing without
         # it would silently run every device chore somewhere else
-        try:
-            dev = cls(context, len(devices))
-            dev.attach()
-        except Exception as e:
-            raise RuntimeError(
-                f"device module {cls.mca_name!r} failed to attach: {e}") from e
-        devices.append(dev)
+        places = range(accelerators) \
+            if accelerators > 1 and cls.per_accelerator else (None,)
+        group: List[Device] = []
+        for place in places:
+            try:
+                dev = cls(context, len(devices)) if place is None \
+                    else cls(context, len(devices), place=place,
+                             of=accelerators)
+                dev.attach()
+            except Exception as e:
+                raise RuntimeError(
+                    f"device module {cls.mca_name!r} failed to attach: "
+                    f"{e}") from e
+            devices.append(dev)
+            group.append(dev)
+        for dev in group:
+            dev.peers = [d for d in group if d is not dev]
     if not devices or devices[0].device_type != DEV_CPU:
         raise RuntimeError("CPU device must attach first")
     return devices
@@ -181,14 +204,84 @@ def _prefers_device(task: "Task", dev: Device) -> bool:
     return False
 
 
+_OUT = int(AccessMode.OUT)
+
+
+def _written_tile(task: "Task"):
+    """The tile of the task's first read-write (or write) flow, or None:
+    body_args may be an opaque payload for internal tasks (DTD comm
+    tasks carry raw tuples) — only ("data", Data, mode) specs count."""
+    args = task.body_args
+    if not isinstance(args, (list, tuple)):
+        return None
+    for spec in args:
+        if (isinstance(spec, (list, tuple)) and len(spec) >= 3
+                and spec[0] == "data" and spec[1] is not None
+                and int(spec[2]) & _OUT):
+            return spec[1]
+    return None
+
+
+def _place(context: "Context", task: "Task", accs):
+    """One of several eligible accelerators for ``task`` by where its
+    data is, the reference's rule (``device.c:92-266``: "the location of
+    the first data that is used in READ/WRITE, or of one of the READ
+    data"): the accelerator that holds the newest version of the tile of
+    the task's first written flow (``Data.owner_device``), else the one
+    that tile is advised to (``preferred_device``); failing a written
+    flow, the accelerator that holds most of the inputs' bytes.  None
+    where the data says nothing: the load decides.  ``accs``: the
+    eligible ``(device, chore, index)`` of accelerators, more than one.
+    The criterion that placed the task is counted on the context."""
+    stats = context.stats
+    data = _written_tile(task)
+    if data is not None:
+        by_index = {e[0].index: e for e in accs}
+        best = by_index.get(data.owner_device)
+        if best is not None:
+            stats["selected_by_owner"] += 1
+            return best
+        best = by_index.get(data.preferred_device)
+        if best is not None:
+            stats["selected_by_advice"] += 1
+            return best
+    best, best_bytes = None, 0
+    for e in accs:
+        rb = e[0].resident_data(task)
+        if rb > best_bytes:
+            best, best_bytes = e, rb
+    if best is not None:
+        stats["selected_by_bytes"] += 1
+    return best
+
+
+def _least_eta(task: "Task", eligible):
+    best = best_eta = None
+    for dev, chore, ci in eligible:
+        est = chore.time_estimate(task, dev) if chore.time_estimate else dev.time_estimate(task)
+        eta = dev.device_load + est
+        if dev.device_type != DEV_CPU:
+            eta *= LOAD_BALANCE_SKEW
+        if best_eta is None or eta < best_eta:
+            best_eta, best = eta, (dev, chore, ci)
+    return best
+
+
 def select_best_device(context: "Context", task: "Task") -> HookReturn:
     """Pick (device, chore) for a ready task; reference ``device.c:92-266``.
 
-    Order of criteria:
-      1. data affinity — an accelerator already holding the task's inputs
-         wins outright (saves HBM traffic);
-      2. minimal ETA = device_load + time_estimate, accelerators discounted
-         by :data:`LOAD_BALANCE_SKEW`.
+    One eligible device is the answer, before anything is asked of the
+    task's data.  With ONE accelerator among the eligible (and a CPU
+    chore beside it), in this order:
+      0. an input advised to a device (``data_advise`` PREFERRED_DEVICE);
+      1. data affinity — the accelerator already holding the task's
+         inputs wins outright (saves HBM traffic);
+      2. minimal ETA = device_load + time_estimate, accelerators
+         discounted by :data:`LOAD_BALANCE_SKEW`.
+    With SEVERAL accelerators eligible the task goes where its data is
+    (:func:`_place`: the written tile's owner, its advice, the inputs'
+    bytes), and failing that to the least ETA of all the eligible
+    (``selected_by_load``).
     """
     tc = task.task_class
     eligible = []
@@ -207,33 +300,32 @@ def select_best_device(context: "Context", task: "Task") -> HookReturn:
     if not eligible:
         return HookReturn.NEXT
 
-    # 0. explicit preference (data_advise PREFERRED_DEVICE) on any input;
-    # body_args may be an opaque payload for internal tasks (DTD comm
-    # tasks carry raw tuples) — only ("data", Data, mode) specs count
-    best = None
-    for dev, chore, ci in eligible:
-        if _prefers_device(task, dev):
-            best = (dev, chore, ci)
-            break
-    # 1. affinity
-    best_bytes = 0
-    if best is None:
-        for dev, chore, ci in eligible:
-            if dev.device_type == DEV_CPU:
-                continue
-            rb = dev.resident_data(task)
-            if rb > best_bytes:
-                best, best_bytes = (dev, chore, ci), rb
-    # 2. ETA
-    if best is None:
-        best_eta = None
-        for dev, chore, ci in eligible:
-            est = chore.time_estimate(task, dev) if chore.time_estimate else dev.time_estimate(task)
-            eta = dev.device_load + est
-            if dev.device_type != DEV_CPU:
-                eta *= LOAD_BALANCE_SKEW
-            if best_eta is None or eta < best_eta:
-                best_eta, best = eta, (dev, chore, ci)
+    if len(eligible) == 1:
+        best = eligible[0]
+    else:
+        accs = [e for e in eligible if e[0].device_type != DEV_CPU]
+        if len(accs) > 1:
+            best = _place(context, task, accs)
+            if best is None:
+                best = _least_eta(task, eligible)
+                context.stats["selected_by_load"] += 1
+        else:
+            # 0. explicit preference on any input
+            best = None
+            for e in eligible:
+                if _prefers_device(task, e[0]):
+                    best = e
+                    break
+            # 1. affinity
+            best_bytes = 0
+            if best is None:
+                for e in accs:
+                    rb = e[0].resident_data(task)
+                    if rb > best_bytes:
+                        best, best_bytes = e, rb
+            # 2. ETA
+            if best is None:
+                best = _least_eta(task, eligible)
     dev, chore, ci = best
     task.selected_device = dev
     task.selected_chore = chore

@@ -641,9 +641,12 @@ class Residency:
             # a CLEAN device copy can still be the ONLY valid copy:
             # device-native arrivals (_deposit_payload, bytes_d2d)
             # attach no host copy — dropping without write-back
-            # would destroy the data
-            goes = dirty or host is None or host.payload is None \
-                or host.version < mine.version
+            # would destroy the data.  A clean copy of a PEER module's
+            # version is not that: the peer holds the version (or a newer
+            # one) and brings it home itself
+            goes = dirty or ((host is None or host.payload is None
+                              or host.version < mine.version)
+                             and not self._held_by_peer(victim, mine))
             out.victims.append((victim, dirty, mine.version,
                                 mine.nbytes if goes else 0))
         return out
@@ -713,6 +716,40 @@ class Residency:
                     bytes_home=bytes_home, wait_us=wait_us,
                     known=leaving.known, never=leaving.never,
                     cancelled=cancelled)
+
+    def _held_by_peer(self, data: Data, mine) -> bool:
+        """Whether another device module holds ``data`` at ``mine``'s
+        version or a newer one (the caller holds the lock)."""
+        idx = self.index
+        with data.lock:
+            return any(di not in (0, idx) and c.payload is not None
+                       and c.version >= mine.version
+                       for di, c in data.copies.items())
+
+    def drop_stale(self, datas: Iterable[Data]) -> int:
+        """A peer module committed new versions of ``datas``: the copies
+        here that are older are dropped, out of the LRUs, their slots
+        freed, nothing written home (the peer's version supersedes them
+        and finds its own way home); counted in ``peer_copies_dropped``.
+        A program of this device that was given such a copy's array keeps
+        the array; the pin of its chunk lets go of nothing.  Called with
+        no lock of the peer's held."""
+        n = 0
+        with pins.held(self.lock, "res_lock"):
+            for data in datas:
+                mine = data.get_copy(self.index)
+                if mine is None:
+                    continue
+                newest = data.newest_copy()
+                if newest is None or newest.version <= mine.version:
+                    continue
+                self.forget(data)
+                self.drop(data, evicted=False)
+                n += 1
+            if n:
+                self.stats["peer_copies_dropped"] = \
+                    self.stats.get("peer_copies_dropped", 0) + n
+        return n
 
     def restaged(self, data: Data) -> None:
         """``data`` is staged in (the caller holds the lock): counted,

@@ -293,9 +293,13 @@ class StageIn:
         idx, res, jdev, stats = self.index, self.res, self.jdev, self.stats
         if got is None:
             got = {}
-        #: (tile, its newest copy's payload, that version)
-        moving: List[Tuple[Data, Any, int]] = []
+        #: (tile, its source copy's payload, that version, the index of
+        #: the device the source lives on)
+        moving: List[Tuple[Data, Any, int, int]] = []
         pinned: List[Data] = []
+        #: tiles whose source is a peer module's array: held from the
+        #: read of the reference until the landing is enqueued
+        held: List[Data] = []
         try:
             with pins.held(res.lock, "res_lock"):
                 for data in datas:
@@ -321,11 +325,17 @@ class StageIn:
                     if newest is None:
                         raise RuntimeError(
                             f"{data!r}: no valid copy to stage in")
-                    payload = newest.payload
+                    # (at the newest version a copy on a device goes
+                    # before the host's: chip to chip, never over the host)
+                    src = data.hold_source(idx)
+                    if src.device_index not in (0, idx):
+                        held.append(data)
+                    payload = src.payload
                     if not isinstance(payload, jax.Array):
                         payload = np.asarray(payload)
-                    moving.append((data, payload, newest.version))
-            need = sum(p.nbytes for (_d, p, _v) in moving)
+                    moving.append((data, payload, src.version,
+                                   src.device_index))
+            need = sum(p.nbytes for (_d, p, _v, _s) in moving)
             if need and unlocked:
                 res.make_room(need)
             with pins.held(res.lock, "res_lock"):
@@ -336,29 +346,26 @@ class StageIn:
                         f"is {res.budget} bytes and what is resident is "
                         "pinned by the chunk in flight")
                 puts: List[Tuple[Data, np.ndarray, int]] = []
-                for data, payload, version in moving:
+                #: source device -> the tiles that land from there
+                lands: Dict[int, List[Tuple[Data, Any, int]]] = {}
+                for data, payload, version, src in moving:
                     # (re-staging over a stale device copy replaces it:
                     # the accounting charges the delta)
                     res.account(data, payload.nbytes)
                     res.pin(data)
                     pinned.append(data)
                     if isinstance(payload, jax.Array):
-                        # device-resident arrival (device-capable fabric):
-                        # land it with a direct device_put — device-to-
-                        # device, ICI-class on multi-chip, no host numpy
-                        # bounce (SURVEY §5.8), uncoalesced
-                        arr = got[data.data_id] = jax.device_put(
-                            payload, jdev)
-                        stats["bytes_d2d"] += payload.nbytes
-                        c = data.attach_copy(idx, arr)
-                        c.version = version
-                        res.touch(data, dirty=False)
-                        moved += payload.nbytes
+                        lands.setdefault(src, []).append(
+                            (data, payload, version))
                     else:
                         puts.append((data, payload, version))
+                for src, tiles in lands.items():
+                    moved += self._land(src, tiles, got)
+            self._let_go(held)
             if puts:
                 moved += self._put(puts, tally, coalesce, got)
         except BaseException:
+            self._let_go(held)
             res.unpin(pinned)
             raise
         if keep is None:
@@ -366,6 +373,40 @@ class StageIn:
         else:
             keep.extend(pinned)
         return moved
+
+    @staticmethod
+    def _let_go(held: List[Data]) -> None:
+        """The peer arrays a walk held are enqueued, or given up."""
+        while held:
+            held.pop().release_source()
+
+    def _land(self, src: int, tiles, got) -> int:
+        """The device-resident arrivals of one staging walk from one
+        source — a peer module's newest copies (``src``: its index), or a
+        device-capable fabric's arrivals from another rank (0) — landed
+        with a direct ``jax.device_put``: device-to-device, ICI-class on
+        multi-chip, no host numpy bounce (SURVEY §5.8), asynchronous (the
+        landing of a program's output that is not computed yet chains
+        behind it).  A peer module's are one ``dev:d2d`` span with
+        ``src``, ``tiles``, ``bytes``; all are counted in ``bytes_d2d``
+        and ``d2d_tiles``.  The caller holds the residency lock and, of a
+        peer's arrays, the tiles' holds (``Data.hold_source``)."""
+        idx, res, stats = self.index, self.res, self.stats
+        nbytes = sum(p.nbytes for (_d, p, _v) in tiles)
+        if src:
+            with self.span("dev:d2d", src=src, tiles=len(tiles),
+                           bytes=nbytes):
+                arrs = [jax.device_put(p, self.jdev) for (_d, p, _v) in tiles]
+        else:
+            arrs = [jax.device_put(p, self.jdev) for (_d, p, _v) in tiles]
+        for (data, _p, version), arr in zip(tiles, arrs):
+            got[data.data_id] = arr
+            c = data.attach_copy(idx, arr)
+            c.version = version
+            res.touch(data, dirty=False)
+        stats["bytes_d2d"] += nbytes
+        stats["d2d_tiles"] += len(tiles)
+        return nbytes
 
     def _put(self, puts, tally, coalesce: bool, got) -> int:
         """The put of :meth:`batch` and the attach of what arrived."""
@@ -752,8 +793,8 @@ class HostWriter:
         committed = 0
         nbytes = sum(int(getattr(p, "nbytes", 0)) for (_d, p, _v) in snaps)
         with pins.span("dev:writeback", pool=pool, rank=self.rank,
-                       id=span_id(), tiles=len(snaps), batch=batch,
-                       bytes=nbytes) as sp:
+                       dev=self.index, id=span_id(), tiles=len(snaps),
+                       batch=batch, bytes=nbytes) as sp:
             t0 = time.perf_counter_ns()
             if ahead:
                 with pins.wait("d2h_start") as w:

@@ -35,6 +35,14 @@ alone — is staged by ``_stage_chunk`` (by its signature's
 :class:`FlowPlan`) and committed by ``_commit_chunk``; every tile that
 has to come from the host goes through ``staging.StageIn.batch``.
 
+Several accelerators under one context (``Context(accelerators=g)``): an
+instance of this module per chip (``place``), each with a manager thread
+of its own (``_manager_main``), its own residency, lanes, committer and
+view of the live executables.  A peer's newest copy of a tile is a source
+of the staging walk, chip to chip (``StageIn._land``, ``Data.hold_source``);
+a commit here drops the peers' older copies (``_supersede``); a donation
+asks the tile whether a peer's landing holds its array (``_not_sole``).
+
 Departures from the reference, by TPU design:
 * no device pointers — payloads are ``jax.Array``s; "allocation" is
   ``device_put`` and "free" is dropping the reference;
@@ -132,6 +140,7 @@ class TpuDevice(Device):
     mca_name = "tpu"
     mca_priority = 50
     device_type = DEV_TPU
+    per_accelerator = True
 
     @classmethod
     def available(cls) -> bool:
@@ -140,8 +149,12 @@ class TpuDevice(Device):
         # chore on the host
         return len(jax.local_devices()) > 0
 
-    def __init__(self, context, index):
+    def __init__(self, context, index, place=None, of=1):
+        """``place``: this module's place among the ``of`` accelerators
+        of a context that drives several (``Context(accelerators=g)``),
+        from 0; None for the one module of a context that drives one."""
         super().__init__(context, index)
+        self._place = place
         #: "a fallback ran" counters — each is a slower path taken in
         #: place of the intended one, 0 on a healthy run
         self.stats.update(wave_fallbacks=0, submit_retries=0,
@@ -168,6 +181,11 @@ class TpuDevice(Device):
         #: could be donated whoever held what)
         self.stats.update(tile_args_donated=0, donation_refused=0,
                           commits_donate_unknown=0)
+        #: several accelerators under one context: copies here that a
+        #: peer module's commit of a newer version dropped (nothing
+        #: written home), and donations refused because a peer's landing
+        #: held the array (of ``donation_refused``)
+        self.stats.update(peer_copies_dropped=0, peer_holds_refused=0)
         #: wave programs whose width the byte bound set
         #: (``Residency.chunk_limit``), and the bytes of tiles read that
         #: the bound did not count, born here as they were
@@ -236,8 +254,20 @@ class TpuDevice(Device):
             "device", "tpu_device_index", -1,
             help="local JAX device index this rank binds "
                  "(-1 = rank % local device count)")
-        jidx = pref if pref >= 0 else getattr(context, "rank", 0)
-        self.jdev = devs[jidx % len(devs)]
+        if place is None:
+            jidx = pref if pref >= 0 else getattr(context, "rank", 0)
+            self.jdev = devs[jidx % len(devs)]
+        else:
+            # several accelerators under one context: the chip is the
+            # module's place among them, in the rank's slice of the
+            # process's chips; never a chip twice
+            jidx = getattr(context, "rank", 0) * of + place
+            if jidx >= len(devs):
+                raise RuntimeError(
+                    f"accelerator {place + 1} of {of} (rank "
+                    f"{getattr(context, 'rank', 0)}) asks for local device "
+                    f"{jidx}; JAX reports {len(devs)}")
+            self.jdev = devs[jidx]
         # budget: 85% of what PJRT says the chip has.  The CPU backend
         # reports no limit and gets a nominal 4 GiB; a TPU that reports
         # none is an error — eviction would be steered by a made-up size
@@ -347,8 +377,10 @@ class TpuDevice(Device):
         self._jit_cache: Dict[Any, Any] = {}
         #: a signature names donated flows only where the commit follows
         #: the call at once (eager completion: until its commit a donated
-        #: tile's copy here is a deleted array) and no peer may hold the
-        #: array (device-capable fabrics ship ``jax.Array``s uncopied)
+        #: tile's copy here is a deleted array) and no peer RANK may hold
+        #: the array (device-capable fabrics ship ``jax.Array``s uncopied).
+        #: A peer MODULE of this context may: its landing holds the array
+        #: under the tile's own lock, and ``_not_sole`` asks there
         self._may_donate = self._eager \
             and getattr(context, "nranks", 1) <= 1
         #: data_ids the transfer lane pinned for the batch being
@@ -378,11 +410,67 @@ class TpuDevice(Device):
         #: from the device it drives
         self.stage_split_bytes = STAGE_SPLIT_BYTES
         self._committer = None
+        #: several accelerators under one context: each module has a
+        #: manager thread of its own (:meth:`_manager_main`), with an
+        #: execution stream that is no worker's; whoever brings a task
+        #: queues it and wakes that thread
+        self._thread = None
+        self._wake = threading.Event()
+        self._stop = False
+
+    def attach(self) -> None:
+        if self._place is None:
+            return
+        from ..core.context import ExecutionStream
+
+        ctx = self.context
+        es = ExecutionStream(ctx.nb_workers + self._place, ctx)
+        es.managing = self
+        self._thread = threading.Thread(
+            target=self._manager_main, args=(es,),
+            name=f"dev-manager:{self.name}", daemon=True)
+        self._thread.start()
+
+    def _manager_main(self, es) -> None:
+        """The manager thread of one of several accelerators: asleep
+        until a task is queued (:meth:`kernel_scheduler`), then the
+        manager's loop until the queues have run dry.  Nothing a task
+        does gets out of that loop (a failing submit fails its pool
+        there); what does is logged, fails the pools of what is queued,
+        and the thread goes on."""
+        self.context._tls.es = es
+        while True:
+            self._wake.wait()
+            if self._stop:
+                return
+            self._wake.clear()
+            with self._lock:
+                if not self._pending and not self._deferred:
+                    continue
+                self._manager_active = True
+            try:
+                self._manager_loop(es)
+            except BaseException as e:
+                debug.error("manager of %s: %r", self.name, e)
+                import traceback
+
+                traceback.print_exc()
+                with self._lock:
+                    self._manager_active = False
+                    orphans = list(self._pending) + self._released
+                    self._pending.clear()
+                    self._released = []
+                for task in orphans:
+                    if not getattr(task.taskpool, "failed", False):
+                        self._fail_task_pool(
+                            task, f"manager of {self.name} raised: {e!r}")
 
     def _span(self, name: str, **info):
-        """A ``pins.span`` of this module, with the ``pool`` and ``rank``
-        every span carries."""
-        return pins.span(name, pool=self._span_pool, rank=self._rank, **info)
+        """A ``pins.span`` of this module, with the ``pool``, ``rank``
+        and ``dev`` (the module's index in ``context.devices``) every
+        span carries."""
+        return pins.span(name, pool=self._span_pool, rank=self._rank,
+                         dev=self.index, **info)
 
     @property
     def hbm_budget(self) -> int:
@@ -422,6 +510,12 @@ class TpuDevice(Device):
             self._pending.append(task)
             if self._manager_active:
                 return HookReturn.ASYNC  # a manager is already running
+            if self._thread is not None:
+                # one of several accelerators: its own thread manages it,
+                # never the worker, or the peer's manager, that brought
+                # the task
+                self._wake.set()
+                return HookReturn.ASYNC
             self._manager_active = True
         # this worker becomes the manager
         if es is not None:
@@ -685,9 +779,13 @@ class TpuDevice(Device):
             if entry is None:
                 sp.note(miss=1)
                 content_key, fn, donate, plan = build()
+                # (one of several accelerators: its own view of the live
+                # executables, which are compiled for its chip; the one
+                # module of a context makes the call it always made)
+                view = {} if self._place is None else {"place": self._place}
                 entry = self._jit_cache[local_key] = (self._ccache.jit(
-                    fn, key=content_key, donate_argnums=tuple(donate)),
-                    plan, None)
+                    fn, key=content_key, donate_argnums=tuple(donate),
+                    **view), plan, None)
         return entry
 
     def _dispatch(self, local_key, entry, flat):
@@ -1457,8 +1555,10 @@ class TpuDevice(Device):
              for (task, _a, _o) in staged for (pos, _ai, _oi) in donates]) \
             if com is not None else ()
         refused = set()
+        peers = bool(self.peers)
         for k, (task, args, _ospecs) in enumerate(staged):
             specs = task.body_args
+            claimed: List[Data] = []
             for pos, ai, _oi in donates:
                 data = specs[pos][1]
                 did = data.data_id
@@ -1469,7 +1569,17 @@ class TpuDevice(Device):
                         and not any(c.payload is arr
                                     for di, c in data.copies.items()
                                     if di != idx):
-                    continue
+                    if not peers:
+                        continue
+                    # a peer module's landing of this array may be
+                    # between its read of the reference and its enqueue:
+                    # the tile's own lock decides, once, who was first
+                    if data.claim_for_donation():
+                        claimed.append(data)
+                        continue
+                    self.stats["peer_holds_refused"] += 1
+                for data in claimed:
+                    data.donation_committed()
                 refused.add(k)
                 break
         self.stats["donation_refused"] += len(refused)
@@ -1827,7 +1937,21 @@ class TpuDevice(Device):
         c.staged_by = None
         self._res.account(data, nbytes)
         data.version_bump(idx, bumps_heard)
+        if data.peer_holds < 0:  # (claimed for this program's donation)
+            data.donation_committed()
         self._res.touch(data, dirty=True)
+
+    def _supersede(self, staged: List[_Staged]) -> None:
+        """The chunk's outputs are committed here: what the peer modules
+        hold of those tiles is an older version now and is dropped there
+        (``Residency.drop_stale``: accounting freed, nothing written
+        home).  With no lock of this module held: a peer's commit may be
+        doing the same the other way."""
+        wrote = [data for (_t, _a, ospecs) in staged for (_p, data) in ospecs]
+        for peer in self.peers:
+            theirs = [d for d in wrote if peer.data_index in d.copies]
+            if theirs:
+                peer._res.drop_stale(theirs)
 
     def _release_scratch(self, tiles, after) -> None:
         """A task that was one declared user of each of these scratch
@@ -1971,6 +2095,8 @@ class TpuDevice(Device):
                 # outputs grew residency: re-settle under the budget
                 res.settle()
             self.stats["task_commits" if alone else "wave_commits"] += 1
+            if self.peers:
+                self._supersede(staged)
             lap("settle")
             home = 0 if donated else self._send_home(going, last)
             lap("home")
@@ -2071,6 +2197,11 @@ class TpuDevice(Device):
             self._detach()
 
     def _detach(self) -> None:
+        if self._thread is not None:
+            self._stop = True
+            self._wake.set()
+            self._thread.join(timeout=30)
+            self._thread = None
         # drain the async committer FIRST: its flush() barrier is what
         # lets host-side readers (detach, redistribute, remote sends)
         # see committed tiles.  A committer that died mid-run surfaces

@@ -1398,7 +1398,12 @@ class PTGTaskpool(Taskpool):
             env = pc.env_of(task.locals, self.constants)
             specs: List[Tuple[str, Any, AccessMode]] = []
             # (several ranks: the device module donates nothing,
-            # TpuDevice._may_donate, and nothing is worked out for it)
+            # TpuDevice._may_donate, and nothing is worked out for it.
+            # Several ACCELERATORS of one rank: the rule counts the TASKS
+            # that consume a version, whichever chip runs them, so "this
+            # task alone" holds across chips as it stands; who else holds
+            # the ARRAY, a peer module's landing among them, is the device
+            # module's to see: ``_not_sole``, ``Data.claim_for_donation``)
             ctx = self.context
             sole = sole_rule if ctx is not None and ctx.nranks <= 1 \
                 else None

@@ -54,6 +54,7 @@ bench_testlib.TINY.setdefault("pump_mle", "tiny_pump_mle")
 bench_testlib.TINY.setdefault("dtd", "tiny_dtd")
 bench_testlib.TINY.setdefault("pump_geqrf_hqr", "tiny_pump_geqrf_hqr")
 bench_testlib.TINY.setdefault("pump_poinv", "tiny_pump_poinv")
+bench_testlib.TINY.setdefault("context_g4", "tiny_context_g4")
 
 
 def pytest_configure(config):
@@ -84,6 +85,17 @@ _OVERTAKEN = {
         "it has to report them) and its own five metrics after them, PR "
         "39 the DTD cell and its four: the queued benchmark PR has to pin "
         "by membership",
+    "benchmark_harness/test_bench_mle.py::"
+    "test_the_cell_is_what_the_issue_names":
+        "pins the number of four-chip cells at ONE (PR 36); PR 51 adds the "
+        "second the contract allows (tile_g4_n98304: one Context over four "
+        "device modules exists only across chips), as its issue names it: "
+        "a benchmark PR has to pin its own cell's chips, not the count",
+    "benchmark_harness/test_bench_poinv.py::"
+    "test_the_new_entries_of_benchmark_json_by_membership":
+        "pins the number of four-chip cells at ONE (PR 46), as above; the "
+        "rest of it (its own entries, by membership) is held by "
+        "test_bench_g4.py's twin for the cells there are now",
 }
 
 
